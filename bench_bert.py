@@ -31,15 +31,17 @@ def main():
     import jax
 
     sys.path.insert(0, ".")
-    from bench import _resolve_peak, _mark, guarded_devices
+    from bench import _chip_peak_bf16_flops, _mark
     from deepspeed_tpu.config import DeepSpeedConfig
     from deepspeed_tpu.models.bert import BERT_LARGE, BertModel
     from deepspeed_tpu.parallel import build_mesh
     from deepspeed_tpu.runtime.engine import DeepSpeedEngine
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 
-    devices = guarded_devices()
+    enable_compile_cache()
+    devices = jax.devices()
     on_tpu = devices[0].platform != "cpu"
-    peak = _resolve_peak(devices[0]) if on_tpu else 0.0
+    peak = _chip_peak_bf16_flops(devices[0]) if on_tpu else 0.0
 
     import dataclasses
     cases = ([(128, 64), (512, 16)] if on_tpu else [(64, 4)])

@@ -21,13 +21,9 @@ import sys
 import numpy as np
 import jax
 sys.path.insert(0, {repo!r})
-try:  # shared persistent compile cache (bench.py's dir): re-runs skip
-    import os
-    jax.config.update("jax_compilation_cache_dir", os.path.join(
-        {repo!r}, ".jax_cache"))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-except Exception:
-    pass
+import os
+from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+enable_compile_cache()
 from deepspeed_tpu.config import DeepSpeedConfig
 from deepspeed_tpu.models import GPT2Config, GPT2Model
 from deepspeed_tpu.parallel import build_mesh
@@ -53,10 +49,10 @@ if offload and chunks > 1:
 if offload and stream:
     zero["param_streaming"] = True
 # split update by default for offload probes: the fused update program
-# materializes the whole fp32 state as HBM temps on the AOT compile
-# path (the round-5 1.5B OOM), which would cap the measured offload
-# capacity at roughly the no-offload level.  CAPACITY_SPLIT_UPDATE=0
-# measures the fused structure deliberately.
+# materializes the whole fp32 state as HBM temporaries
+# (docs/chip_notes.md), which would cap the measured offload capacity at
+# roughly the no-offload level.  CAPACITY_SPLIT_UPDATE=0 measures the
+# fused structure deliberately.
 if offload and os.environ.get("CAPACITY_SPLIT_UPDATE", "1") == "1":
     zero["offload_split_update"] = True
 ds_cfg = DeepSpeedConfig({{
@@ -100,8 +96,8 @@ def _probe(n_layer: int, offload: bool, timeout: int,
         proc = subprocess.run(argv, capture_output=True, text=True,
                               timeout=timeout, env=env)
     except subprocess.TimeoutExpired:
-        # a wedged probe near the OOM boundary counts as a failed size —
-        # the bisection must continue, not abort
+        # a probe that hangs near the OOM boundary counts as a failed
+        # size — the bisection must continue, not abort
         print(f"  probe n_layer={n_layer} offload={offload} timed out "
               f"after {timeout}s", file=sys.stderr)
         return 0
@@ -120,8 +116,8 @@ EMB = (50257 + 1024) * D_MODEL
 
 
 def _hbm_bytes(timeout: int) -> int:
-    """bytes_limit of the real chip, probed in a subprocess (the probe
-    only initializes a backend — killable without wedging device state)."""
+    """bytes_limit of the real chip, read in a child: this parent never
+    starts a backend, so every probe child gets the chip to itself."""
     code = ("import jax; d = jax.local_devices()[0]; "
             "print('HBM', d.memory_stats().get('bytes_limit', 0))")
     try:

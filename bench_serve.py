@@ -21,8 +21,6 @@ import json
 import os
 import sys
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 # this file is loaded both as a script and via spec_from_file_location
 # (the bench tests) — anchor the repo root so ``tools.loadgen``
 # resolves regardless of the caller's cwd
@@ -55,6 +53,8 @@ def _mode_kwargs(args, **attr_to_kw):
 
 def main():
     import argparse
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     parser = argparse.ArgumentParser(description=__doc__)
     parser.add_argument("--slots", type=int, default=None,
                         help="slot pool size (default 8); with --paged "

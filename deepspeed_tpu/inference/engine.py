@@ -362,10 +362,7 @@ class ServeEngine:
         def _step_scope():
             stack = contextlib.ExitStack()
             stack.enter_context(interpret_scope(self._pallas_interpret))
-            if hasattr(jax, "set_mesh"):
-                stack.enter_context(jax.set_mesh(self.mesh))
-            else:
-                stack.enter_context(self.mesh)
+            stack.enter_context(jax.set_mesh(self.mesh))
             return stack
 
         self._pallas_scope = _step_scope

@@ -379,6 +379,9 @@ def main(argv=None) -> int:
                              "fleet; the router decides who prefills "
                              "and who decodes)")
     args = parser.parse_args(argv)
+    # every replica of a fleet compiles the same programs: share them
+    from ..utils.compile_cache import enable_compile_cache
+    enable_compile_cache()
     host, _, port = args.router.rpartition(":")
     with open(args.config) as f:
         cfg = json.load(f)

@@ -467,9 +467,9 @@ def gpt2_attn_sublayer(cfg: GPT2Config, bp, x, rng, train: bool):
 
     if cfg.attn_impl == "flash":
         # Pallas flash kernel (prob-dropout fused in-kernel).
-        from ..ops.pallas.flash_attention import mha
-        attn = mha(q, k, v,
-                   dropout_rate=drop, dropout_rng=r1, causal=True)
+        from ..parallel.attention import sharded_flash_attention
+        attn = sharded_flash_attention(q, k, v, causal=True,
+                                       dropout_rate=drop, dropout_rng=r1)
     elif cfg.attn_impl == "dense":
         attn = causal_attention(q, k, v,
                                 dropout_rate=drop, dropout_rng=r1)
@@ -568,8 +568,8 @@ def gpt2_block_prefill(cfg: GPT2Config, bp, x):
     additionally returning the per-head K/V for the serving cache."""
     q, k, v = gpt2_qkv_heads(cfg, bp, x)
     if cfg.attn_impl == "flash":
-        from ..ops.pallas.flash_attention import flash_attention
-        attn = flash_attention(q, k, v, causal=True)
+        from ..parallel.attention import sharded_flash_attention
+        attn = sharded_flash_attention(q, k, v, causal=True)
     elif cfg.attn_impl == "dense":
         attn = causal_attention(q, k, v)
     else:
@@ -1084,8 +1084,8 @@ def gpt2_block_prefill_paged(cfg: GPT2Config, bp, x, k_pool, v_pool,
     def _self_arm(_):
         # the pre-page prefill attention, op for op
         if cfg.attn_impl == "flash":
-            from ..ops.pallas.flash_attention import flash_attention
-            return flash_attention(q, k, v, causal=True)
+            from ..parallel.attention import sharded_flash_attention
+            return sharded_flash_attention(q, k, v, causal=True)
         return causal_attention(q, k, v)
 
     def _gather_arm(_):

@@ -720,11 +720,3 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                  kv_length)
     return out.reshape(b, h, t, d)
 
-
-def mha(q, k, v, dropout_rate: float = 0.0, dropout_rng=None,
-        causal: bool = True, **kwargs):
-    """Attention dispatcher (kept for callers of the old dense-fallback
-    API): dropout now runs inside the flash kernel."""
-    return flash_attention(q, k, v, causal=causal,
-                           dropout_rate=dropout_rate,
-                           dropout_rng=dropout_rng, **kwargs)

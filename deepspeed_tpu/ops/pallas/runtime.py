@@ -1,13 +1,16 @@
 """Pallas execution-mode plumbing.
 
-Pallas kernels must run in interpret mode off-TPU (CPU test meshes, the
-driver's virtual-device dryrun).  ``jax.default_backend()`` is not a
-reliable signal on this image — the TPU platform stays registered as
-default even when the computation is placed on CPU devices — so each
-engine declares the execution platform of *its* mesh around the calls
-that trace its compiled steps (runtime/engine.py), and kernels consult
-it at trace time.  A scoped setting (not a set-once global) keeps
-several engines with different meshes in one process honest.
+Mosaic kernels run only on a TPU; anywhere else (the CPU test meshes)
+they are interpreted.  Each engine decides from the devices of *its*
+mesh and declares that around the calls that trace its compiled steps
+(``_pallas_scope`` in runtime/engine.py and inference/engine.py);
+kernels consult it at trace time.  A scoped setting, not a set-once
+global, keeps several engines on different meshes in one process honest.
+With no engine scope, the default backend decides.
+
+A chip run must never interpret unnoticed: ``chip_smoke.py`` asserts
+``engine._pallas_interpret is False`` and finds the kernel
+(``tpu_custom_call``) in the compiled train, prefill and decode programs.
 """
 from __future__ import annotations
 

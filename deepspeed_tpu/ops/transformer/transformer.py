@@ -194,10 +194,10 @@ class DeepSpeedTransformerLayer:
             # as the per-key operand.  attn_dropout_checkpoint is
             # structurally satisfied here — flash never materializes the
             # [T, T] probabilities, in forward OR backward.
-            from ...ops.pallas.flash_attention import flash_attention
+            from ...parallel.attention import sharded_flash_attention
             km = (None if attention_mask is None
                   else self._key_mask_rows(attention_mask, B, H, T))
-            ctx = flash_attention(
+            ctx = sharded_flash_attention(
                 q, k, v, causal=False,
                 dropout_rate=cfg.attn_dropout_ratio if train else 0.0,
                 dropout_rng=rng, key_mask=km)

@@ -73,3 +73,45 @@ def data_sharded(mesh: Mesh, rank: int = 1, batch_dim: int = 0) -> NamedSharding
     spec = [None] * rank
     spec[batch_dim] = DATA_AXIS
     return NamedSharding(mesh, P(*spec))
+
+
+
+
+def _kernel_mesh():
+    """The abstract mesh a kernel call may go manual over, or None: no
+    mesh, one device, or already inside somebody's shard_map.  A nested
+    call stays bare for two reasons.  The TPU lowering refuses a Mosaic
+    kernel under a partial-manual shard_map nested in another even when
+    the two together cover the mesh, so nesting buys nothing on chips.
+    And in the pipeline's stage bodies, which run divergent branches, the
+    extra boundary moved GSPMD to put resharding collective-permutes
+    inside a branch one stage alone executes (BERT pp2 x dp4 deadlocked
+    on the CPU runtime)."""
+    am = jax.sharding.get_abstract_mesh()
+    return None if am.manual_axes or am.size == 1 or am.empty else am
+
+
+def auto_axis(name: str, dim: int) -> Optional[str]:
+    """``name`` if ``manual_over_mesh`` would split over that axis: it is
+    larger than one and divides ``dim``; else None — the spec entry that
+    leaves ``dim`` unsplit."""
+    am = _kernel_mesh()
+    n = 1 if am is None else am.shape.get(name, 1)
+    return name if n > 1 and dim % n == 0 else None
+
+
+def manual_over_mesh(fn, in_specs, out_specs):
+    """``fn`` under a shard_map that is manual over every axis of the mesh
+    in scope.
+
+    A Mosaic kernel cannot be partitioned by GSPMD: on a mesh of several
+    real chips the compiler refuses a bare call whose operands are
+    sharded (interpret mode on the CPU mesh hides this).  The callers of
+    the Pallas kernels therefore run them here, with specs built from
+    ``auto_axis``.  Returns ``fn`` itself where ``_kernel_mesh`` finds
+    nothing to go manual over.
+    """
+    if _kernel_mesh() is None:
+        return fn
+    return jax.shard_map(fn, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=False)

@@ -47,43 +47,27 @@ class CompileMonitor:
 
     # -- jax.monitoring hook --------------------------------------------
     def install(self) -> bool:
-        """Register the duration listener; returns False (and stays a
-        no-op) when jax.monitoring is unavailable."""
+        """Register the duration listener (idempotent)."""
         if self._installed:
             return True
-        try:
-            from jax import monitoring
-        except Exception:
-            return False
-        if not hasattr(monitoring, "register_event_duration_secs_listener"):
-            return False
+        from jax import monitoring
 
         def on_duration(event: str, duration: float, **kwargs):
             if event == _COMPILE_DURATION_EVENT:
                 self.compiles.inc()
                 self.compile_seconds.observe(duration)
 
-        try:
-            monitoring.register_event_duration_secs_listener(on_duration)
-        except Exception:
-            return False
+        monitoring.register_event_duration_secs_listener(on_duration)
         self._listener = on_duration
         self._installed = True
         return True
 
     def uninstall(self):
-        """Best-effort listener removal (the public API has no
-        unregister; the private helper exists on the jax versions we
-        support and a leaked listener is only a few ns per event)."""
         if not self._installed:
             return
         self._installed = False
-        try:
-            from jax._src import monitoring as _mon
-            _mon._unregister_event_duration_listener_by_callback(
-                self._listener)
-        except Exception:
-            pass
+        from jax import monitoring
+        monitoring.unregister_event_duration_listener(self._listener)
         self._listener = None
 
     # -- per-program retrace tracking -----------------------------------

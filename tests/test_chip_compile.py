@@ -1,0 +1,248 @@
+"""The main path's kernels and serve programs, compiled for a TPU v5e
+that is described and not attached — at GPT-2 124M and GPT-2 XL widths.
+
+Nothing runs: this guards against what interpret mode cannot see (tile
+alignment, scalar-prefetch and VMEM budgets, unsupported lowerings) at no
+chip time.  A compile that passes is not a chip run.
+
+All of these live in this ONE file, and the topology is described only
+inside the module-scoped fixture below: the TPU library is loaded by the
+first test that runs, in the one xdist worker that owns this file, and
+never while a module is imported.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
+from deepspeed_tpu.ops.pallas.decode_attention import (
+    decode_attention, decode_attention_multi, decode_attention_paged,
+    decode_attention_paged_multi)
+from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
+from deepspeed_tpu.ops.pallas.runtime import interpret_scope
+
+KERNEL = "tpu_custom_call"
+BF16 = jnp.bfloat16
+SLOTS, SEQ, DH = 8, 1024, 64
+GPT2_124M = GPT2Config(d_model=768, n_layer=12, n_head=12, vocab_size=50257,
+                       n_positions=1024, attn_impl="flash")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    """A described v5e 2x2 host.  The persistent compilation cache is off
+    meanwhile: a program compiled for a described device is written to it
+    but can never be read back here."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, sharding, *shapes):
+    """Compile ``fn`` for the described chip; the program must hold a
+    Mosaic kernel."""
+    args = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sharding),
+        shapes)
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert KERNEL in compiled.as_text()
+    return compiled
+
+
+def _sds(shape, dtype=BF16):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+# ---------------------------------------------------------------------------
+# flash attention, forward and backward, at the train smoke's shape
+# ---------------------------------------------------------------------------
+
+def test_flash_forward_compiles(one_chip):
+    qkv = [_sds((16, 12, SEQ, DH))] * 3
+    _compile(lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                             interpret=False),
+             one_chip, *qkv)
+
+
+def test_flash_backward_compiles(one_chip):
+    qkv = [_sds((16, 12, SEQ, DH))] * 3
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True,
+                               interpret=False).astype(jnp.float32).sum()
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, *qkv)
+
+
+def _flash_grad_on_four_chips(topo, attend, *, dp=1, sp=1, tp=1):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from deepspeed_tpu.parallel import build_mesh
+    mesh = build_mesh(dp=dp, sp=sp, tp=tp, devices=topo.devices)
+
+    def loss(q, k, v):
+        return attend(q, k, v).astype(jnp.float32).sum()
+
+    with jax.set_mesh(mesh):
+        _compile(jax.grad(loss, argnums=(0, 1, 2)),
+                 NamedSharding(mesh, P("data", "model")),
+                 *[_sds((16, 12, SEQ, DH))] * 3)
+
+
+@pytest.mark.parametrize("dp,tp", [(4, 1), (2, 2)], ids=["dp4", "dp2xtp2"])
+def test_flash_backward_compiles_on_four_chips(topo, dp, tp):
+    """Rows over four chips, as under dp=4 ZeRO or dp x tp: GSPMD refuses
+    to partition a bare Mosaic call, so the models call the kernel
+    through ``sharded_flash_attention``, inside a shard_map
+    (chip_smoke.py --chips 4 runs the dp4 path)."""
+    from deepspeed_tpu.parallel.attention import sharded_flash_attention
+    _flash_grad_on_four_chips(
+        topo, lambda q, k, v: sharded_flash_attention(
+            q, k, v, causal=True, interpret=False), dp=dp, tp=tp)
+
+
+def test_flash_nested_in_a_partial_shard_map_is_still_refused(topo):
+    """The known limit, pinned: Ulysses is manual over 'seq' only, and
+    with 'data' larger than one its flash call is refused on real chips,
+    bare or under a second shard_map over the remaining axes
+    (parallel/mesh.py ``_kernel_mesh``).  Sequence parallelism beside
+    data parallelism on chips needs the enclosing shard_map to be manual
+    over the whole mesh; when that lands this test turns into a compile."""
+    from jax.sharding import PartitionSpec as P
+    from deepspeed_tpu.parallel import ulysses_attention
+    seq = P(None, None, "seq", None)
+
+    def attend(q, k, v):
+        with interpret_scope(False):
+            return jax.shard_map(
+                lambda a, b, c: ulysses_attention(a, b, c, causal=True),
+                in_specs=(seq, seq, seq), out_specs=seq,
+                axis_names={"seq"}, check_vma=False)(q, k, v)
+
+    with pytest.raises(NotImplementedError, match="Mosaic kernels cannot"):
+        _flash_grad_on_four_chips(topo, attend, dp=2, sp=2)
+
+
+# ---------------------------------------------------------------------------
+# the four decode arms at 124M (12) and XL (25) head counts
+# ---------------------------------------------------------------------------
+
+def _paged_shapes(heads, page_len, quant):
+    pages, max_pages = 1 + SLOTS * (SEQ // page_len), SEQ // page_len
+    pool = _sds((pages, heads, page_len, DH), jnp.int8 if quant else BF16)
+    scale = _sds((pages, heads, page_len), jnp.float32)
+    table = _sds((SLOTS, max_pages), jnp.int32)
+    return pool, scale, table
+
+
+def _slot(heads, one_chip):
+    cache = _sds((SLOTS, heads, SEQ, DH))
+    _compile(lambda q, k, v, n: decode_attention(q, k, v, n,
+                                                 interpret=False),
+             one_chip, _sds((SLOTS, heads, DH)), cache, cache,
+             _sds((SLOTS,), jnp.int32))
+
+
+def _multi(heads, one_chip, w=5):
+    cache = _sds((SLOTS, heads, SEQ, DH))
+    _compile(lambda q, k, v, n: decode_attention_multi(q, k, v, n,
+                                                       interpret=False),
+             one_chip, _sds((SLOTS, heads, w, DH)), cache, cache,
+             _sds((SLOTS, w), jnp.int32))
+
+
+def _paged(heads, one_chip, page_len, quant=False):
+    pool, scale, table = _paged_shapes(heads, page_len, quant)
+
+    def fn(q, k, v, t, n, *scales):
+        kw = dict(zip(("k_scale", "v_scale"), scales))
+        return decode_attention_paged(q, k, v, t, n, interpret=False, **kw)
+
+    _compile(fn, one_chip, _sds((SLOTS, heads, DH)), pool, pool, table,
+             _sds((SLOTS,), jnp.int32), *([scale, scale] if quant else []))
+
+
+def _paged_multi(heads, one_chip, page_len, quant=False, w=5):
+    pool, scale, table = _paged_shapes(heads, page_len, quant)
+
+    def fn(q, k, v, t, n, *scales):
+        kw = dict(zip(("k_scale", "v_scale"), scales))
+        return decode_attention_paged_multi(q, k, v, t, n, interpret=False,
+                                            **kw)
+
+    _compile(fn, one_chip, _sds((SLOTS, heads, w, DH)), pool, pool, table,
+             _sds((SLOTS, w), jnp.int32), *([scale, scale] if quant else []))
+
+
+ARMS = {
+    "slot": _slot,
+    "multi": _multi,
+    "paged16": lambda h, c: _paged(h, c, 16),
+    "paged64": lambda h, c: _paged(h, c, 64),
+    "paged128": lambda h, c: _paged(h, c, 128),
+    "paged_multi16": lambda h, c: _paged_multi(h, c, 16),
+    "int8_paged16": lambda h, c: _paged(h, c, 16, quant=True),
+    "int8_paged128": lambda h, c: _paged(h, c, 128, quant=True),
+    "int8_paged_multi64": lambda h, c: _paged_multi(h, c, 64, quant=True),
+}
+
+
+@pytest.mark.parametrize("heads", [12, 25], ids=["gpt2_124m", "gpt2_xl"])
+@pytest.mark.parametrize("arm", sorted(ARMS))
+def test_decode_arm_compiles(arm, heads, one_chip):
+    ARMS[arm](heads, one_chip)
+
+
+# ---------------------------------------------------------------------------
+# the serve programs' model entry points at 124M widths, chip_smoke's sizes
+# ---------------------------------------------------------------------------
+
+PAGE_LEN = 16
+MAX_PAGES = SEQ // PAGE_LEN
+
+
+def _serve_shapes():
+    """(model, bf16 param shapes, one layer-stacked K or V pool)."""
+    model = GPT2Model(GPT2_124M)
+    params = jax.tree.map(lambda s: _sds(s.shape),
+                          jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    pool = _sds((GPT2_124M.n_layer, 1 + SLOTS * MAX_PAGES,
+                 GPT2_124M.n_head, PAGE_LEN, DH))
+    return model, params, pool
+
+
+@pytest.mark.parametrize("bucket", [128, 1024])
+def test_prefill_paged_compiles(bucket, one_chip):
+    model, params, pool = _serve_shapes()
+    i32 = _sds((), jnp.int32)
+    with interpret_scope(False):
+        _compile(model.prefill_paged, one_chip, params,
+                 _sds((1, bucket), jnp.int32), i32, i32,
+                 _sds((MAX_PAGES,), jnp.int32), pool, pool)
+
+
+def test_decode_step_paged_compiles(one_chip):
+    model, params, pool = _serve_shapes()
+    with interpret_scope(False):
+        _compile(lambda *a: model.decode_step_paged(*a, impl="pallas"),
+                 one_chip, params, _sds((SLOTS,), jnp.int32), pool, pool,
+                 _sds((SLOTS, MAX_PAGES), jnp.int32),
+                 _sds((SLOTS,), jnp.int32), _sds((SLOTS,), jnp.bool_))

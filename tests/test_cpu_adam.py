@@ -267,6 +267,6 @@ def test_poisoned_host_tier_blocks_save(tmp_path):
     engine = DeepSpeedEngine(SimpleModel(hidden_dim=16), cfg, seed=7)
     engine.train_batch(next(random_batches(
         cfg.train_batch_size, 16, num_batches=1, seed=1)))
-    engine._host_opt._poisoned = ValueError("tunnel died mid-pull")
+    engine._host_opt._poisoned = ValueError("link died mid-pull")
     with pytest.raises(RuntimeError, match="refusing to serialize"):
         engine.save_checkpoint(str(tmp_path))

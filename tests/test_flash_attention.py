@@ -5,13 +5,15 @@ Mirrors the reference's kernel-vs-reference-implementation strategy
 DeepSpeedTransformerLayer vs a vendored HuggingFace BertEncoder over a
 grid of shapes/dtypes).
 """
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from deepspeed_tpu.ops.attention import causal_attention
-from deepspeed_tpu.ops.pallas.flash_attention import flash_attention, mha
+from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 
 
 def _rand_qkv(b, h, t, d, dtype=jnp.float32, seed=0):
@@ -178,10 +180,13 @@ def test_dropout_is_unbiased():
     assert err < 0.15, f"dropout mean deviates {err:.3f} from base"
 
 
-def test_mha_routes_dropout_into_kernel():
+def test_model_entry_routes_dropout_into_kernel():
+    """The models' entry (no mesh here: the bare kernel) draws the same
+    seed from the rng as the kernel's own API."""
+    from deepspeed_tpu.parallel.attention import sharded_flash_attention
     q, k, v = _rand_qkv(1, 1, 64, 32)
     rng = jax.random.PRNGKey(0)
-    out = mha(q, k, v, dropout_rate=0.1, dropout_rng=rng)
+    out = sharded_flash_attention(q, k, v, dropout_rate=0.1, dropout_rng=rng)
     ref = flash_attention(q, k, v, dropout_rate=0.1, dropout_rng=rng)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
@@ -351,3 +356,48 @@ def test_all_masked_key_rows_zero_output_and_grads():
     assert (np.asarray(gk[0]) == 0).all()
     assert (np.asarray(gv[0]) == 0).all()
     assert np.abs(np.asarray(gq[1])).max() > 0
+
+
+# ---------------------------------------------------------------------------
+# on a mesh of several devices the models call the kernel through
+# parallel.attention.sharded_flash_attention, inside a shard_map (a Mosaic
+# kernel cannot be partitioned automatically on real chips)
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("dp,tp", [(4, 1), (2, 2), (1, 4)])
+@pytest.mark.parametrize("dropout,mask", [(0.0, None), (0.2, None),
+                                          (0.2, "batch"), (0.0, "bh")],
+                         ids=["plain", "dropout", "dropout+mask", "bh_mask"])
+def test_mesh_sharded_call_equals_single_device(dp, tp, dropout, mask):
+    """Rows (batch over 'data', heads over 'model') are independent, so
+    the sharded call is the single-device call bit for bit — forward and
+    gradients, including the dropout realization, whose hash must see
+    GLOBAL batch·head ids from every shard."""
+    from deepspeed_tpu.parallel import build_mesh
+    from deepspeed_tpu.parallel.attention import sharded_flash_attention
+    b, h, t, d = 4, 4, 64, 16
+    q, k, v = _rand_qkv(b, h, t, d, seed=3)
+    key_mask = None
+    if mask is not None:
+        rows = b if mask == "batch" else b * h
+        key_mask = jnp.asarray(
+            np.random.RandomState(0).rand(rows, t) > 0.3)
+
+    def loss(attend, q, k, v):
+        out = attend(q, k, v, causal=True, block_q=32, block_k=32,
+                     dropout_rate=dropout, dropout_seed=jnp.uint32(7),
+                     key_mask=key_mask)
+        return (out * out).sum(), out
+
+    grad = jax.value_and_grad(loss, argnums=(1, 2, 3), has_aux=True)
+    (want_l, want_o), want_g = jax.jit(
+        functools.partial(grad, flash_attention))(q, k, v)
+    step = jax.jit(functools.partial(grad, sharded_flash_attention))
+    mesh = build_mesh(dp=dp, tp=tp, devices=jax.devices()[:dp * tp])
+    with jax.set_mesh(mesh):
+        text = step.lower(q, k, v).as_text()
+        (got_l, got_o), got_g = step(q, k, v)
+    assert "shard_map" in text or "manual" in text.lower()
+    np.testing.assert_array_equal(np.asarray(got_o), np.asarray(want_o))
+    for g, w in zip(got_g, want_g):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

@@ -145,10 +145,13 @@ def _decode_chain(cfg, params, toks, t_prompt, t_max, impl):
                                       (TINY_FLASH, "pallas")],
                          ids=["dense", "pallas"])
 def test_prefill_decode_parity_fp32(cfg, impl):
-    """fp32 parity bar: the pallas arm (the production serving path) is
-    BITWISE against the training forward at block-covering shapes; the
-    dense arm is ulp-bounded (XLA lowers the single-query score einsum
-    to a different matmul shape than the batched training one)."""
+    """fp32 parity bar.  Pallas arm (the production serving path): the
+    prefill runs the training forward's own ops and is BITWISE against
+    it; a decode tick is a different program (single-query kernel, one
+    row of every matmul), so it is held to a few float32 ulps at the
+    logits' scale.  The dense arm is ulp-bounded throughout (XLA lowers
+    the single-query score einsum to a different matmul shape than the
+    batched training one)."""
     model = GPT2Model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     toks = _tokens(24, seed=0)[None]
@@ -158,9 +161,11 @@ def test_prefill_decode_parity_fp32(cfg, impl):
     if impl == "pallas":
         np.testing.assert_array_equal(np.asarray(logits_p),
                                       np.asarray(full[:, :8]))
+        tol = 4 * np.finfo(np.float32).eps * max(
+            1.0, float(np.abs(np.asarray(full)).max()))
         for i, lg in enumerate(decs):
-            np.testing.assert_array_equal(np.asarray(lg),
-                                          np.asarray(full[0, 8 + i]))
+            np.testing.assert_allclose(lg, full[0, 8 + i], rtol=0,
+                                       atol=tol)
     else:
         np.testing.assert_allclose(logits_p, full[:, :8], atol=1e-6)
         for i, lg in enumerate(decs):

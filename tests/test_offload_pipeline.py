@@ -351,10 +351,10 @@ def test_adam_failure_with_pipeline_keeps_compute_params(monkeypatch):
     old_params = engine._compute_params
 
     def broken(x):
-        raise ValueError("tunnel is dead")
+        raise ValueError("link is dead")
 
     monkeypatch.setattr(offload.jax, "device_get", broken)
-    with pytest.raises(ValueError, match="tunnel is dead"):
+    with pytest.raises(ValueError, match="link is dead"):
         engine.train_batch(batch)
     monkeypatch.undo()
     assert engine._compute_params is old_params

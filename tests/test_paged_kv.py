@@ -147,16 +147,19 @@ def test_paged_kernel_rejects_unknown_impl():
 
 
 # ---------------------------------------------------------------------------
-# paged prefill: bitwise against the pre-page prefill when no prefix
+# paged prefill: float32-ulp parity with the pre-page prefill when no prefix
 # ---------------------------------------------------------------------------
 
 
 @pytest.mark.parametrize("cfg", [TINY, TINY_FLASH],
                          ids=["dense", "flash"])
-def test_paged_prefill_no_prefix_bitwise(cfg):
+def test_paged_prefill_no_prefix_matches_prepage(cfg):
     """The ``prefix_len == 0`` arm of the paged prefill runs the
-    model's OWN attention (dense or flash) — logits AND the written
-    K/V pages are BITWISE identical to ``gpt2_prefill``."""
+    model's OWN attention (dense or flash).  It is still a different
+    program from ``gpt2_prefill`` (padded to the bucket, K/V scattered
+    into pages, a ``lax.cond`` around the attention), so logits and
+    written K/V are held to a few float32 ulps at their scale, not to
+    equality."""
     model = GPT2Model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     page_len, max_pages = 8, 3
@@ -175,15 +178,17 @@ def test_paged_prefill_no_prefix_bitwise(cfg):
     logits, kp, vp = gpt2_prefill_paged(
         cfg, params, jnp.asarray(pad), np.int32(t_prompt), np.int32(0),
         jnp.asarray(row), kp, vp)
-    np.testing.assert_array_equal(np.asarray(logits[0, :t_prompt]),
-                                  np.asarray(logits_ref[0]))
+    def close(got, want):
+        want = np.asarray(want)
+        tol = 4 * np.finfo(np.float32).eps * max(1.0, np.abs(want).max())
+        np.testing.assert_allclose(np.asarray(got), want, rtol=0, atol=tol)
+
+    close(logits[0, :t_prompt], logits_ref[0])
     for layer in range(L):
         got_k = paged_gather(kp[layer], jnp.asarray(row)[None])[0]
         got_v = paged_gather(vp[layer], jnp.asarray(row)[None])[0]
-        np.testing.assert_array_equal(
-            np.asarray(got_k[:, :t_prompt]), np.asarray(ks[layer, 0]))
-        np.testing.assert_array_equal(
-            np.asarray(got_v[:, :t_prompt]), np.asarray(vs[layer, 0]))
+        close(got_k[:, :t_prompt], ks[layer, 0])
+        close(got_v[:, :t_prompt], vs[layer, 0])
 
 
 # ---------------------------------------------------------------------------

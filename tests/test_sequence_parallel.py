@@ -128,6 +128,44 @@ def test_ulysses_flash_dropout_grads_match_oracle():
                                    err_msg=f"d{name} mismatch")
 
 
+@pytest.mark.parametrize("dp,sp,tp", [(2, 2, 1), (2, 2, 2), (1, 4, 2)])
+def test_ulysses_flash_on_mesh_with_other_automatic_axes(dp, sp, tp):
+    """Ulysses is manual over 'seq' only; 'data' and 'model' stay under
+    GSPMD around its flash call, whose batch·head base is traced from the
+    seq rank.  Forward and gradients equal the dense dropout oracle."""
+    from attention_oracles import dense_dropout_oracle
+    from deepspeed_tpu.parallel import build_mesh
+    q, k, v = make_qkv(B=4, seed=13)
+    seed = jnp.uint32(5)
+    mesh = build_mesh(dp=dp, sp=sp, tp=tp,
+                      devices=jax.devices()[:dp * sp * tp])
+    spec = P(None, None, "seq", None)
+
+    def loss(attend, q, k, v):
+        out = attend(q, k, v)
+        return jnp.sum(out ** 2), out
+
+    def sp_attend(q, k, v):
+        return jax.shard_map(
+            lambda a, b, c: ulysses_attention(a, b, c, "seq", causal=True,
+                                              dropout_rate=0.25,
+                                              dropout_seed=seed),
+            in_specs=(spec, spec, spec), out_specs=spec,
+            axis_names={"seq"}, check_vma=False)(q, k, v)
+
+    grad = jax.value_and_grad(loss, argnums=(1, 2, 3), has_aux=True)
+    with jax.set_mesh(mesh):
+        (_, out), gs = jax.jit(partial(grad, sp_attend))(q, k, v)
+    (_, ref), go = grad(
+        lambda q, k, v: dense_dropout_oracle(q, k, v, 0.25, seed), q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+    for a, b, name in zip(gs, go, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=5e-5, atol=5e-5,
+                                   err_msg=f"d{name} mismatch")
+
+
 @pytest.mark.parametrize("causal", [True, False])
 def test_ulysses_attention_matches_dense(causal):
     q, k, v = make_qkv(seed=2)

@@ -12,17 +12,16 @@ converter: public Azure/Mooncake trace rows → the replayable
 """
 import argparse
 import json
-import os
 import sys
-
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def main() -> None:
     if len(sys.argv) > 1 and sys.argv[1] == "convert":
         from tools.loadgen.convert import main as convert_main
         return convert_main(sys.argv[2:])
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
     from tools.loadgen.scenarios import SCENARIOS
+    enable_compile_cache()
     ap = argparse.ArgumentParser(
         prog="python -m tools.loadgen",
         description="replay one bench scenario over the workload plane")

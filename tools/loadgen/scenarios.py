@@ -1024,6 +1024,12 @@ def run_goodput(n_requests=48, prompt_len=6, gen_tokens=8, slots=4,
     (uniform - burst) — higher means the plane resolves the phenomenon
     a throughput bench can't see.  ``trace_path`` replays an external
     trace (``load_trace`` format) as the burst leg instead."""
+    # the fleet leg first: its replicas are child processes, and a
+    # parent that has started a JAX backend holds the device they need
+    chaos_rec = _run_chaos_leg(
+        n_requests=40, rate=8.0, cv=4.0, gen_tokens=6,
+        tick_delay_s=0.04, kill_after_s=1.0,
+        slo=(1.5, 0.5), seed=seed) if chaos else None
     model, params = _init_model()
     slo = (slo_ttft_s, slo_tpot_s)
     payload = dict(prompt_len=LengthSpec(value=prompt_len),
@@ -1070,10 +1076,7 @@ def run_goodput(n_requests=48, prompt_len=6, gen_tokens=8, slots=4,
         "burst": burst,
     }
     if chaos:
-        rec["chaos"] = _run_chaos_leg(
-            n_requests=40, rate=8.0, cv=4.0, gen_tokens=6,
-            tick_delay_s=0.04, kill_after_s=1.0,
-            slo=(1.5, 0.5), seed=seed)
+        rec["chaos"] = chaos_rec
     _write_bench(out_dir, "BENCH_loadgen_goodput.json", rec)
     return rec
 

@@ -383,7 +383,8 @@ def serve_phase(cfg: GPT2Config, *, slots: int, page_len: int,
             eng.telemetry.compile_monitor.sample()
             reg = eng.telemetry.registry
             recompiles = {p: reg.counter("recompiles_total").value(program=p)
-                          for p in ("decode_step", "prefill", "copy_page")}
+                          for p in ("serve_decode", "serve_prefill",
+                                    "serve_copy_page")}
             programs = (eng._decode_fn._cache_size(),
                         eng._prefill_fn._cache_size())
             texts = _program_texts(eng) if _on_tpu(devices) else {}

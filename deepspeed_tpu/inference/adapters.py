@@ -7,7 +7,7 @@ host-side registry; a small pool of HBM slots holds the hot ones, and
 the compiled decode/prefill/verify programs gather each request's
 adapter by a TRACED int32 slot table — the PR 11 scalar-prefetch
 indirection applied to weights — so tenant mixes ride the SAME
-compiled tick (``recompiles_total{program=decode_step}`` == 0).
+compiled tick (``recompiles_total{program=serve_decode}`` == 0).
 
 The residency pool is managed exactly like KV pages
 (:class:`~deepspeed_tpu.inference.scheduler.PagePool`): refcounted

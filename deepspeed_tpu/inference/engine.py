@@ -96,6 +96,7 @@ from ..config import constants as C
 from ..parallel.mesh import DATA_AXIS, MODEL_AXIS, build_mesh
 from ..runtime.engine_stages import wire_serve_stage_plane
 from ..runtime.stages import Channel, Stage, injected_delay
+from ..telemetry import tracing
 from ..utils.logging import logger
 from .kv_cache import (KVCacheSpec, PagedKVCacheSpec, cache_shardings,
                        init_cache, init_paged_cache,
@@ -327,7 +328,7 @@ class ServeEngine:
 
             # slot-traced donated upload: N uploads, one compiled
             # program (the _copy_fn discipline applied to weights)
-            def adapter_upload_fn(pools, slot, new):
+            def serve_adapter_upload(pools, slot, new):
                 out = {}
                 for t in sorted(pools):
                     ap, bp = pools[t]
@@ -337,7 +338,7 @@ class ServeEngine:
                 return out
 
             self._adapter_upload_fn = jax.jit(
-                adapter_upload_fn, donate_argnums=(0,),
+                serve_adapter_upload, donate_argnums=(0,),
                 out_shardings=self._lora_shardings)
             self.adapter_registry = AdapterRegistry(
                 int(lcfg["max_adapters"]), self._lora_shapes)
@@ -408,8 +409,8 @@ class ServeEngine:
             # delta-aware prefill over the page pool: page_row,
             # prefix_len and delta_len are TRACED, so one program
             # serves full prefills AND prefix-hit deltas
-            def prefill_fn(params, cache, tokens, delta_len, prefix_len,
-                           page_row, slot, *extra):
+            def serve_prefill(params, cache, tokens, delta_len,
+                              prefix_len, page_row, slot, *extra):
                 lkw, rng = split_lora(extra)
                 out = self.model.prefill_paged(
                     params, tokens, delta_len, prefix_len, page_row,
@@ -429,8 +430,8 @@ class ServeEngine:
                     newc["k_scale"], newc["v_scale"] = out[3], out[4]
                 return newc, first_tok
 
-            def decode_fn(params, cache, tokens, active, page_table,
-                          *extra):
+            def serve_decode(params, cache, tokens, active, page_table,
+                             *extra):
                 lkw, rng = split_lora(extra)
                 out = self.model.decode_step_paged(
                     params, tokens, cache["k"], cache["v"], page_table,
@@ -449,7 +450,7 @@ class ServeEngine:
             # shaped leaf is copied — on the quantized cache that
             # includes the scale sidecars, or the COW'd page would
             # dequantize with the wrong scales.
-            def copy_fn(cache, src, dst):
+            def serve_copy_page(cache, src, dst):
                 out = dict(cache)
                 for key in ("k", "v", "k_scale", "v_scale"):
                     if key not in cache:
@@ -460,8 +461,9 @@ class ServeEngine:
                         a, pg, dst, axis=1)
                 return out
 
-            self._copy_fn = jax.jit(copy_fn, donate_argnums=(0,),
-                                    out_shardings=self._cache_shardings)
+            self._copy_fn = jax.jit(
+                serve_copy_page, donate_argnums=(0,),
+                out_shardings=self._cache_shardings)
 
             # KV-page export/import (disaggregated fleet, docs/
             # serving.md): one page's pool rows out to the host / back
@@ -470,7 +472,7 @@ class ServeEngine:
             # or an imported page would dequantize with the wrong
             # scales.  The page index is TRACED like _copy_fn's
             # src/dst, so any page migrates on one compiled pair.
-            def page_out_fn(cache, page):
+            def serve_page_out(cache, page):
                 out = []
                 for key in ("k", "v", "k_scale", "v_scale"):
                     if key not in cache:
@@ -479,7 +481,7 @@ class ServeEngine:
                         cache[key], page, 1, axis=1))
                 return tuple(out)
 
-            def page_in_fn(cache, page, *leaves):
+            def serve_page_in(cache, page, *leaves):
                 out = dict(cache)
                 i = 0
                 for key in ("k", "v", "k_scale", "v_scale"):
@@ -492,7 +494,7 @@ class ServeEngine:
 
             # adoption rebuilds a migrated slot's cache length without
             # a prefill pass (slot + length traced)
-            def set_len_fn(cache, slot, length):
+            def serve_set_len(cache, slot, length):
                 out = dict(cache)
                 out["lengths"] = jax.lax.dynamic_update_slice(
                     cache["lengths"],
@@ -503,15 +505,16 @@ class ServeEngine:
             # exported page slices are host-bound bytes: replicated
             # output (identity on one device) so every host sees the
             # full page, like the other pinned siblings
-            self._page_out_fn = jax.jit(page_out_fn, out_shardings=rep)
+            self._page_out_fn = jax.jit(serve_page_out,
+                                        out_shardings=rep)
             self._page_in_fn = jax.jit(
-                page_in_fn, donate_argnums=(0,),
+                serve_page_in, donate_argnums=(0,),
                 out_shardings=self._cache_shardings)
             self._set_len_fn = jax.jit(
-                set_len_fn, donate_argnums=(0,),
+                serve_set_len, donate_argnums=(0,),
                 out_shardings=self._cache_shardings)
         else:
-            def prefill_fn(params, cache, tokens, length, slot, *rng):
+            def serve_prefill(params, cache, tokens, length, slot, *rng):
                 logits, ks, vs = self.model.prefill(params, tokens)
                 new_k = ks[:, 0][:, None].astype(cache["k"].dtype)
                 new_v = vs[:, 0][:, None].astype(cache["v"].dtype)
@@ -530,7 +533,7 @@ class ServeEngine:
                 return ({"k": k_cache, "v": v_cache, "lengths": lengths},
                         first_tok)
 
-            def decode_fn(params, cache, tokens, active, *rng):
+            def serve_decode(params, cache, tokens, active, *rng):
                 logits, k, v, new_len = self.model.decode_step(
                     params, tokens, cache["k"], cache["v"],
                     cache["lengths"], active, impl=self.decode_impl)
@@ -539,10 +542,10 @@ class ServeEngine:
                 return ({"k": k, "v": v, "lengths": new_len}, next_tok)
 
         self._prefill_fn = jax.jit(
-            prefill_fn, donate_argnums=(1,),
+            serve_prefill, donate_argnums=(1,),
             out_shardings=(self._cache_shardings, rep))
         self._decode_fn = jax.jit(
-            decode_fn, donate_argnums=(1,),
+            serve_decode, donate_argnums=(1,),
             out_shardings=(self._cache_shardings, rep))
         if self.spec_k:
             self._build_spec_plane(cfg, mcfg, kv_dtype, draft_params,
@@ -614,23 +617,8 @@ class ServeEngine:
                 compile_events=cfg.telemetry.compile_events,
                 memory=cfg.telemetry.memory,
                 storm_threshold=cfg.telemetry.recompile_storm_threshold)
-            self.telemetry.track_program("decode_step", self._decode_fn)
-            self.telemetry.track_program("prefill", self._prefill_fn)
-            if self._copy_fn is not None:
-                self.telemetry.track_program("copy_page", self._copy_fn)
-                self.telemetry.track_program("page_out",
-                                             self._page_out_fn)
-                self.telemetry.track_program("page_in", self._page_in_fn)
-            if self.spec_k:
-                self.telemetry.track_program("verify_step",
-                                             self._verify_fn)
-                self.telemetry.track_program("draft_propose",
-                                             self._propose_fn)
-                self.telemetry.track_program("draft_prefill",
-                                             self._draft_prefill_fn)
-            if self.lora:
-                self.telemetry.track_program("adapter_upload",
-                                             self._adapter_upload_fn)
+            for fn in self.programs():
+                self.telemetry.track_program(fn.__name__, fn)
             reg = self.telemetry.registry
             self._tokens_total = reg.counter(
                 "serve_tokens_total", "generated tokens")
@@ -812,7 +800,7 @@ class ServeEngine:
         k_spec = self.spec_k
         W = k_spec + 1
 
-        def draft_prefill_fn(dparams, dcache, tokens, length, slot):
+        def serve_draft_prefill(dparams, dcache, tokens, length, slot):
             _, ks, vs = self.draft_model.prefill(dparams, tokens)
             new_k = ks[:, 0][:, None].astype(dcache["k"].dtype)
             new_v = vs[:, 0][:, None].astype(dcache["v"].dtype)
@@ -826,7 +814,7 @@ class ServeEngine:
                 (slot,))
             return {"k": k_cache, "v": v_cache, "lengths": lengths}
 
-        def propose_fn(dparams, dcache, cur, active, *rng):
+        def serve_draft_propose(dparams, dcache, cur, active, *rng):
             def body(carry, i):
                 cache, tok = carry
                 logits, kk, vv, nl = self.draft_model.decode_step(
@@ -886,8 +874,8 @@ class ServeEngine:
         if self.paged:
             lora_on = self.lora
 
-            def verify_fn(params, cache, cur, proposals, active,
-                          page_table, *s):
+            def serve_verify(params, cache, cur, proposals, active,
+                             page_table, *s):
                 lora, aslots = None, None
                 if lora_on:
                     lora, aslots = s[0], s[1]
@@ -898,20 +886,21 @@ class ServeEngine:
                                    s[1] if s else None,
                                    lora=lora, adapter_slots=aslots)
         else:
-            def verify_fn(params, cache, cur, proposals, active, *s):
+            def serve_verify(params, cache, cur, proposals, active, *s):
                 return verify_core(params, cache, cur, proposals,
                                    active, None, s[0] if s else None,
                                    s[1] if s else None)
 
         self._draft_prefill_fn = jax.jit(
-            draft_prefill_fn, donate_argnums=(1,),
+            serve_draft_prefill, donate_argnums=(1,),
             out_shardings=self._draft_shardings)
         prop_out = ((self._draft_shardings, rep, rep) if temp > 0
                     else (self._draft_shardings, rep))
-        self._propose_fn = jax.jit(propose_fn, donate_argnums=(1,),
-                                   out_shardings=prop_out)
+        self._propose_fn = jax.jit(
+            serve_draft_propose, donate_argnums=(1,),
+            out_shardings=prop_out)
         self._verify_fn = jax.jit(
-            verify_fn, donate_argnums=(1,),
+            serve_verify, donate_argnums=(1,),
             out_shardings=(self._cache_shardings, rep, rep))
 
     def _maybe_key(self):
@@ -966,10 +955,26 @@ class ServeEngine:
         return d
 
     # -- telemetry helpers ----------------------------------------------
+    def programs(self) -> list:
+        """Every jitted program of this engine.  ``fn.__name__`` is the
+        program's one stable name: the module on the profiler's ``XLA
+        Modules`` line is ``jit_<name>`` and ``recompiles_total``
+        carries ``program=<name>``."""
+        fns = [self._decode_fn, self._prefill_fn]
+        if self.paged:
+            fns += [self._copy_fn, self._page_out_fn, self._page_in_fn,
+                    self._set_len_fn]
+        if self.spec_k:
+            fns += [self._verify_fn, self._propose_fn,
+                    self._draft_prefill_fn]
+        if self.lora:
+            fns.append(self._adapter_upload_fn)
+        return fns
+
     def _span(self, name: str, **args):
-        if self.telemetry is None:
-            return contextlib.nullcontext()
-        return self.telemetry.span(name, cat="serve", **args)
+        """A serving span: a profiler annotation always, a trace.json
+        event with telemetry on (docs/observability.md)."""
+        return tracing.span(self._tracer, name, cat="serve", **args)
 
     @property
     def _tracer(self):
@@ -1545,45 +1550,52 @@ class ServeEngine:
             self._finish(slot, reason)
         return True
 
-    def _admit(self) -> None:
-        while self.scheduler.has_free():
-            if self._pending:
-                req = self._pending[0]
-            else:
-                req = self._pop_request()
-                if req is None:
-                    return
-                self._pending.append(req)
-            try:
-                ok = self.stage.call(
-                    "admit", lambda r=req: self._admit_one(r),
-                    path=f"rid={req.rid}")
-                if not ok:
-                    # page-pool backpressure: the head request stays
-                    # parked until eviction/release frees pages —
-                    # admission order is preserved, the pool (not the
-                    # slot count) is the binding constraint now
-                    return
-                self._pending.popleft()
-            except BaseException as e:
-                self._pending.popleft()
-                self._fail_request(req, e)
-                if not isinstance(e, Exception):
-                    # KeyboardInterrupt / SystemExit are not a
-                    # per-request failure: the cache may have been
-                    # donated into the interrupted call, so poison and
-                    # propagate instead of serving on
-                    self._poison(e)
-                    raise
-                # one bad request must not take the pool down: record
-                # its error and keep serving (Orca-style isolation) —
-                # unless the cache was donated into the failing call, in
-                # which case the engine is broken and must poison
-                logger.error("serve: admission of rid=%d failed: %r",
-                             req.rid, e)
-                if self._cache_broken():
-                    self._poison(e)
-                    raise
+    def _admit(self) -> int:
+        """``serve/admit``: fill free slots from the queue (pop, prefix
+        lookup, page allocation, prefill).  Returns admissions made."""
+        with self._span("serve/admit") as sp:
+            admitted = 0
+            while self.scheduler.has_free():
+                if self._pending:
+                    req = self._pending[0]
+                else:
+                    req = self._pop_request()
+                    if req is None:
+                        break
+                    self._pending.append(req)
+                try:
+                    ok = self.stage.call(
+                        "admit", lambda r=req: self._admit_one(r),
+                        path=f"rid={req.rid}")
+                    if not ok:
+                        # page-pool backpressure: the head request stays
+                        # parked until eviction/release frees pages —
+                        # admission order is preserved, the pool (not the
+                        # slot count) is the binding constraint now
+                        break
+                    self._pending.popleft()
+                    admitted += 1
+                except BaseException as e:
+                    self._pending.popleft()
+                    self._fail_request(req, e)
+                    if not isinstance(e, Exception):
+                        # KeyboardInterrupt / SystemExit are not a
+                        # per-request failure: the cache may have been
+                        # donated into the interrupted call, so poison and
+                        # propagate instead of serving on
+                        self._poison(e)
+                        raise
+                    # one bad request must not take the pool down: record
+                    # its error and keep serving (Orca-style isolation) —
+                    # unless the cache was donated into the failing call, in
+                    # which case the engine is broken and must poison
+                    logger.error("serve: admission of rid=%d failed: %r",
+                                 req.rid, e)
+                    if self._cache_broken():
+                        self._poison(e)
+                        raise
+            sp.note(admitted=admitted)
+            return admitted
 
     def _cache_broken(self) -> bool:
         """True when a failing call consumed a donated KV cache —
@@ -1701,44 +1713,67 @@ class ServeEngine:
         return 1
 
     # -- the decode tick --------------------------------------------------
-    def _decode_tick(self) -> int:
-        # mid-prefill slots ride masked: they have no last token to
-        # feed and their KV is a partial prefix (chunked prefill)
-        active_map = {s: r for s, r in self.scheduler.active.items()
-                      if not r.prefilling}
-        if self.paged:
-            # page-boundary appends allocate BEFORE the tick; a dry
-            # pool (even after prefix-cache eviction) finishes the
-            # request with the pool-exhaustion-aware kv_capacity reason
-            # instead of letting the program write into the void
-            for slot, req in list(active_map.items()):
-                idx = req.kv_len // self.page_len
-                if idx >= len(req.pages):
-                    pg = self._alloc_pages(1)
-                    if pg is None:
-                        self._finish(slot, "kv_capacity")
-                        del active_map[slot]
-                        continue
-                    req.pages.append(pg[0])
-                    self._table[slot, idx] = pg[0]
-        if not active_map:
-            return 0
-        tokens = np.zeros((self.slots,), np.int32)
-        active = np.zeros((self.slots,), bool)
-        for slot, req in active_map.items():
-            tokens[slot] = req.last_token
-            active[slot] = True
-        with self._span("serve/decode_step", active=len(active_map)):
-            tr = self._tracer
-            if tr is not None:
-                # per-tick decode attribution: each active request's
-                # flow steps through this tick's span (host appends
-                # only — the in-span sync below is the existing pull)
-                for req in active_map.values():
-                    if req.ctx is not None:
-                        tr.flow_step("serve/request", req.ctx,
-                                     cat="serve", rid=req.rid,
-                                     tick=self._ticks)
+    # Each phase of a tick is one method that opens its span at its
+    # top, so the span and the Python frame coincide: a profiler
+    # session shows the same phase under either name.  Counts ride the
+    # spans as args at the boundary where they are known; nothing here
+    # is per request per tick with telemetry off.
+    def _decode_prepare(self, rows: int = 1):
+        """``serve/decode_prep``: the slots that decode this tick, their
+        page-boundary allocations for ``rows`` more KV rows, and the
+        tick's host operands.  Returns (active_map, tokens, active);
+        an empty map means nothing to run."""
+        with self._span("serve/decode_prep") as sp:
+            # mid-prefill slots ride masked: they have no last token to
+            # feed and their KV is a partial prefix (chunked prefill)
+            active_map = {s: r for s, r in self.scheduler.active.items()
+                          if not r.prefilling}
+            if self.paged:
+                # page-boundary appends allocate BEFORE the tick (a
+                # speculative block: all its pages up front); a dry
+                # pool (even after prefix-cache eviction) finishes the
+                # request with the pool-exhaustion-aware kv_capacity
+                # reason instead of letting the program write into the
+                # void
+                for slot, req in list(active_map.items()):
+                    need = (req.kv_len // self.page_len + 1 if rows == 1
+                            else -(-min(req.kv_len + rows,
+                                        self.max_seq_len)
+                                   // self.page_len))
+                    extra = need - len(req.pages)
+                    if extra > 0:
+                        pg = self._alloc_pages(extra)
+                        if pg is None:
+                            self._finish(slot, "kv_capacity")
+                            del active_map[slot]
+                            continue
+                        for p in pg:
+                            self._table[slot, len(req.pages)] = p
+                            req.pages.append(p)
+            tokens = np.zeros((self.slots,), np.int32)
+            active = np.zeros((self.slots,), bool)
+            for slot, req in active_map.items():
+                tokens[slot] = req.last_token
+                active[slot] = True
+            sp.note(active=len(active_map))
+            return active_map, tokens, active
+
+    def _flow_step_tick(self, active_map) -> None:
+        """Per-tick decode attribution: each active request's flow
+        steps through the enclosing span (host appends only; telemetry
+        on only — the one per-request-per-tick cost)."""
+        tr = self._tracer
+        if tr is None:
+            return
+        for req in active_map.values():
+            if req.ctx is not None:
+                tr.flow_step("serve/request", req.ctx, cat="serve",
+                             rid=req.rid, tick=self._ticks)
+
+    def _decode_dispatch(self, tokens, active):
+        """``serve/decode_dispatch``: the decode program's call, until
+        it returns (enqueue; the device runs on)."""
+        with self._span("serve/decode_dispatch"):
             with self._pallas_scope():
                 if self.paged:
                     self.cache, next_tok = self._decode_fn(
@@ -1751,65 +1786,53 @@ class ServeEngine:
                     self.cache, next_tok = self._decode_fn(
                         self.params, self.cache, tokens, active,
                         *self._maybe_key())
-            # the per-token latency point: the pull IS the device sync,
-            # inside the span (transfer-real, JL006-clean)
-            next_host = np.asarray(jax.block_until_ready(next_tok))
-        now = time.perf_counter()
-        produced = 0
-        for slot, req in active_map.items():
-            tok = int(next_host[slot])
-            req.kv_len += 1
-            req.tokens.append(tok)
-            req.token_times.append(now - req.last_t)
-            self._count_token(now - req.last_t)
-            self._tpot_lat.append(now - req.last_t)
-            req.last_t = now
-            req.last_token = tok
-            produced += 1
-            reason = self.scheduler.finish_reason(req, tok,
-                                                  self.max_seq_len)
-            if reason is not None:
-                self._finish(slot, reason)
-        return produced
+            return next_tok
 
-    def _spec_tick(self) -> int:
-        """One SPECULATIVE serving tick (serving.speculate_k > 0): the
-        draft proposes k tokens per active slot (k+1 chained draft
-        passes in one compiled program), the target scores all k+1
-        positions per slot in ONE widened verify pass, and each
-        request advances by its accepted prefix plus the bonus token —
-        1 to k+1 tokens for one target pass.  Accepted-length variance
-        across slots is absorbed by the same masked machinery as
-        admission/eviction; rejection rollback masks lengths back
-        (unpaged) or frees the speculated pages (paged)."""
-        W = self.spec_k + 1
-        active_map = {s: r for s, r in self.scheduler.active.items()
-                      if not r.prefilling}
-        if self.paged:
-            # allocate the whole speculative block's pages up front: a
-            # pool too dry to hold W more rows (even after prefix-leaf
-            # eviction) finishes the request with the same pool-aware
-            # kv_capacity reason as the one-token appends
-            for slot, req in list(active_map.items()):
-                need = -(-min(req.kv_len + W, self.max_seq_len)
-                         // self.page_len)
-                extra = need - len(req.pages)
-                if extra > 0:
-                    pg = self._alloc_pages(extra)
-                    if pg is None:
-                        self._finish(slot, "kv_capacity")
-                        del active_map[slot]
-                        continue
-                    for p in pg:
-                        self._table[slot, len(req.pages)] = p
-                        req.pages.append(p)
+    def _pull_tokens(self, *arrays):
+        """``serve/token_pull``: the host waiting for the device.  The
+        per-token latency point: the pull IS the device sync
+        (transfer-real, JL006-clean)."""
+        with self._span("serve/token_pull"):
+            return [np.asarray(jax.block_until_ready(a)) for a in arrays]
+
+    def _emit_tokens(self, active_map, next_host) -> int:
+        """``serve/emit``: per-request bookkeeping of one decoded token
+        each, finish reasons."""
+        with self._span("serve/emit") as sp:
+            now = time.perf_counter()
+            produced = 0
+            for slot, req in active_map.items():
+                tok = int(next_host[slot])
+                req.kv_len += 1
+                req.tokens.append(tok)
+                req.token_times.append(now - req.last_t)
+                self._count_token(now - req.last_t)
+                self._tpot_lat.append(now - req.last_t)
+                req.last_t = now
+                req.last_token = tok
+                produced += 1
+                reason = self.scheduler.finish_reason(req, tok,
+                                                      self.max_seq_len)
+                if reason is not None:
+                    self._finish(slot, reason)
+            sp.note(produced=produced)
+            return produced
+
+    def _decode_tick(self) -> int:
+        active_map, tokens, active = self._decode_prepare()
         if not active_map:
             return 0
-        tokens = np.zeros((self.slots,), np.int32)
-        active = np.zeros((self.slots,), bool)
-        for slot, req in active_map.items():
-            tokens[slot] = req.last_token
-            active[slot] = True
+        with self._span("serve/decode_step", active=len(active_map)):
+            self._flow_step_tick(active_map)
+            next_tok = self._decode_dispatch(tokens, active)
+            # the pull stays inside the decode_step span
+            (next_host,) = self._pull_tokens(next_tok)
+        return self._emit_tokens(active_map, next_host)
+
+    def _draft_propose(self, active_map, tokens, active):
+        """``serve/draft_propose``: k+1 chained draft passes in one
+        compiled program.  Returns (proposals, the verify program's
+        sampling tail)."""
         with self._span("serve/draft_propose", active=len(active_map),
                         k=self.spec_k):
             with self._pallas_scope():
@@ -1823,17 +1846,14 @@ class ServeEngine:
                 self._draft_cache, proposals = out
                 extra = ()
             # drain the draft INSIDE its span so the window times real
-            # draft compute (the verify pull below syncs the rest)
+            # draft compute (the verify pull syncs the rest)
             jax.block_until_ready(proposals)
-        with self._span("serve/verify_step", active=len(active_map),
-                        k=self.spec_k):
-            tr = self._tracer
-            if tr is not None:
-                for req in active_map.values():
-                    if req.ctx is not None:
-                        tr.flow_step("serve/request", req.ctx,
-                                     cat="serve", rid=req.rid,
-                                     tick=self._ticks)
+            return proposals, extra
+
+    def _verify_dispatch(self, tokens, proposals, active, extra):
+        """``serve/verify_dispatch``: the widened verify program's
+        call, until it returns."""
+        with self._span("serve/verify_dispatch"):
             with self._pallas_scope():
                 if self.paged:
                     self.cache, out_tok, accepted = self._verify_fn(
@@ -1846,77 +1866,106 @@ class ServeEngine:
                     self.cache, out_tok, accepted = self._verify_fn(
                         self.params, self.cache, tokens, proposals,
                         active, *extra)
-            # the per-block latency point: the pull IS the device
-            # sync, inside the span (transfer-real, JL006-clean)
-            out_host = np.asarray(jax.block_until_ready(out_tok))
-            acc_host = np.asarray(accepted)
-        now = time.perf_counter()
-        produced = 0
-        for slot, req in active_map.items():
-            m = int(acc_host[slot])
-            emit = [int(t) for t in out_host[slot, :m + 1]]
-            finished = False
-            first_of_block = True
-            used = 0
-            for tok in emit:
-                # the block lands at one wall moment: the first token
-                # carries the pass latency, the rest arrive "free" —
-                # the burst semantics the latency histograms should see
-                req.kv_len += 1
-                req.tokens.append(tok)
-                lat = (now - req.last_t) if first_of_block else 0.0
-                first_of_block = False
-                req.token_times.append(lat)
-                self._count_token(lat)
-                self._tpot_lat.append(lat)
-                produced += 1
-                used += 1
-                reason = self.scheduler.finish_reason(
-                    req, tok, self.max_seq_len)
-                if reason is not None:
-                    # EOS (or budget/capacity) INSIDE the accepted
-                    # block: the tail of the block is discarded, the
-                    # slot frees this tick — _finish releases every
-                    # page incl. the speculative pre-allocation
-                    self._finish(slot, reason)
-                    finished = True
-                    break
-            # accounting counts tokens the pass actually DELIVERED
-            # (used - 1 accepted drafts + the first/bonus token), not
-            # what verify hypothetically accepted: an EOS/budget/
-            # capacity truncation inside the block must not let the
-            # mean-accepted-length scalars drift from
-            # serve_tokens_total (they share the 1/MAL denominator)
-            req.spec_accepted.append(used - 1)
-            self._spec_passes += 1
-            self._spec_proposed_n += self.spec_k
-            self._spec_accepted_n += used - 1
-            if self.telemetry is not None:
-                self._spec_proposed.inc(self.spec_k)
-                self._spec_accepted_ctr.inc(used - 1)
-                self._spec_len_hist.observe(used)
-            if finished:
-                continue
-            req.last_t = now
-            req.last_token = emit[-1]
-            if self.paged:
-                # rollback: keep the pages covering the verified rows,
-                # free the ones only rejected speculation touched
-                keep = -(-req.kv_len // self.page_len)
-                while len(req.pages) > keep:
-                    pg = req.pages.pop()
-                    self._table[slot, len(req.pages)] = 0
-                    self.pool.deref(pg)
-        # draft rollback: one replicated lengths row masks every live
-        # slot's draft KV back to its verified length (rejected draft
-        # rows become dead tail the kernels never attend)
-        dlen = np.zeros((self.slots,), np.int32)
-        for slot, req in self.scheduler.active.items():
-            dlen[slot] = req.kv_len
-        self._draft_cache = dict(self._draft_cache)
-        self._draft_cache["lengths"] = jax.device_put(
-            jnp.asarray(dlen), self._draft_shardings["lengths"])
-        return produced
+            return out_tok, accepted
+
+    def _emit_spec_blocks(self, active_map, out_host, acc_host) -> int:
+        """``serve/emit`` of a speculative tick: each request advances
+        by its accepted prefix plus the bonus token; rejected
+        speculation is rolled back (pages freed, draft lengths
+        masked)."""
+        with self._span("serve/emit") as sp:
+            now = time.perf_counter()
+            produced = 0
+            for slot, req in active_map.items():
+                m = int(acc_host[slot])
+                emit = [int(t) for t in out_host[slot, :m + 1]]
+                finished = False
+                first_of_block = True
+                used = 0
+                for tok in emit:
+                    # the block lands at one wall moment: the first token
+                    # carries the pass latency, the rest arrive "free" —
+                    # the burst semantics the latency histograms should see
+                    req.kv_len += 1
+                    req.tokens.append(tok)
+                    lat = (now - req.last_t) if first_of_block else 0.0
+                    first_of_block = False
+                    req.token_times.append(lat)
+                    self._count_token(lat)
+                    self._tpot_lat.append(lat)
+                    produced += 1
+                    used += 1
+                    reason = self.scheduler.finish_reason(
+                        req, tok, self.max_seq_len)
+                    if reason is not None:
+                        # EOS (or budget/capacity) INSIDE the accepted
+                        # block: the tail of the block is discarded, the
+                        # slot frees this tick — _finish releases every
+                        # page incl. the speculative pre-allocation
+                        self._finish(slot, reason)
+                        finished = True
+                        break
+                # accounting counts tokens the pass actually DELIVERED
+                # (used - 1 accepted drafts + the first/bonus token), not
+                # what verify hypothetically accepted: an EOS/budget/
+                # capacity truncation inside the block must not let the
+                # mean-accepted-length scalars drift from
+                # serve_tokens_total (they share the 1/MAL denominator)
+                req.spec_accepted.append(used - 1)
+                self._spec_passes += 1
+                self._spec_proposed_n += self.spec_k
+                self._spec_accepted_n += used - 1
+                if self.telemetry is not None:
+                    self._spec_proposed.inc(self.spec_k)
+                    self._spec_accepted_ctr.inc(used - 1)
+                    self._spec_len_hist.observe(used)
+                if finished:
+                    continue
+                req.last_t = now
+                req.last_token = emit[-1]
+                if self.paged:
+                    # rollback: keep the pages covering the verified rows,
+                    # free the ones only rejected speculation touched
+                    keep = -(-req.kv_len // self.page_len)
+                    while len(req.pages) > keep:
+                        pg = req.pages.pop()
+                        self._table[slot, len(req.pages)] = 0
+                        self.pool.deref(pg)
+            # draft rollback: one replicated lengths row masks every live
+            # slot's draft KV back to its verified length (rejected draft
+            # rows become dead tail the kernels never attend)
+            dlen = np.zeros((self.slots,), np.int32)
+            for slot, req in self.scheduler.active.items():
+                dlen[slot] = req.kv_len
+            self._draft_cache = dict(self._draft_cache)
+            self._draft_cache["lengths"] = jax.device_put(
+                jnp.asarray(dlen), self._draft_shardings["lengths"])
+            sp.note(produced=produced)
+            return produced
+
+    def _spec_tick(self) -> int:
+        """One SPECULATIVE serving tick (serving.speculate_k > 0): the
+        draft proposes k tokens per active slot (k+1 chained draft
+        passes in one compiled program), the target scores all k+1
+        positions per slot in ONE widened verify pass, and each
+        request advances by its accepted prefix plus the bonus token —
+        1 to k+1 tokens for one target pass.  Accepted-length variance
+        across slots is absorbed by the same masked machinery as
+        admission/eviction; rejection rollback masks lengths back
+        (unpaged) or frees the speculated pages (paged)."""
+        active_map, tokens, active = self._decode_prepare(
+            rows=self.spec_k + 1)
+        if not active_map:
+            return 0
+        proposals, extra = self._draft_propose(active_map, tokens, active)
+        with self._span("serve/verify_step", active=len(active_map),
+                        k=self.spec_k):
+            self._flow_step_tick(active_map)
+            out_tok, accepted = self._verify_dispatch(tokens, proposals,
+                                                      active, extra)
+            # the per-block latency point, inside the span
+            out_host, acc_host = self._pull_tokens(out_tok, accepted)
+        return self._emit_spec_blocks(active_map, out_host, acc_host)
 
     def step(self) -> int:
         """One serving tick: admit into free slots, then one masked
@@ -1924,29 +1973,37 @@ class ServeEngine:
         block — over the whole pool.  Returns tokens produced."""
         if self._closed:
             raise RuntimeError("ServeEngine is closed")
-        if self.kv_tier is not None:
-            # park BEFORE admission so pages freed by parking are
-            # immediately allocatable this very tick
-            self.kv_tier.park_tick(self._ticks)
-        self._admit()
-        try:
-            n = 0
-            if self.prefill_chunk_len and any(
-                    r.prefilling
-                    for r in self.scheduler.active.values()):
-                # chunked-prefill co-scheduling: ONE chunk rides this
-                # tick next to the decode pass, and the stage point
-                # charges one injected delay unit per CHUNK
-                # (docs/stages.md) — the bounded-stall guarantee the
-                # disagg bench proves
-                n += self.stage.call("prefill_chunk",
-                                     self._prefill_chunk_tick)
-            n += self.stage.call(
-                "step",
-                self._spec_tick if self.spec_k else self._decode_tick)
-        except BaseException as e:
-            self._poison(e)
-            raise
+        tick_args = {"pages_free": self.pool.free_count} \
+            if self.paged else {}
+        with self._span("serve/tick", tick=self._ticks,
+                        active=len(self.scheduler.active),
+                        queued=self.queue.qsize() + len(self._pending),
+                        **tick_args) as sp:
+            if self.kv_tier is not None:
+                # park BEFORE admission so pages freed by parking are
+                # immediately allocatable this very tick
+                self.kv_tier.park_tick(self._ticks)
+            admitted = self._admit()
+            try:
+                n = 0
+                if self.prefill_chunk_len and any(
+                        r.prefilling
+                        for r in self.scheduler.active.values()):
+                    # chunked-prefill co-scheduling: ONE chunk rides
+                    # this tick next to the decode pass, and the stage
+                    # point charges one injected delay unit per CHUNK
+                    # (docs/stages.md) — the bounded-stall guarantee
+                    # the disagg bench proves
+                    n += self.stage.call("prefill_chunk",
+                                         self._prefill_chunk_tick)
+                n += self.stage.call(
+                    "step",
+                    self._spec_tick if self.spec_k
+                    else self._decode_tick)
+            except BaseException as e:
+                self._poison(e)
+                raise
+            sp.note(produced=n, admitted=admitted)
         if self.telemetry is not None:
             self._active_gauge.set(len(self.scheduler.active))
             if self.paged:

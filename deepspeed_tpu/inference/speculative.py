@@ -40,6 +40,7 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("sample")
 def select_next_token(logits: jnp.ndarray, temperature: float = 0.0,
                       rng=None) -> jnp.ndarray:
     """The one next-token rule every serving program shares (the four

@@ -165,10 +165,12 @@ class BertModel(TrainModule):
             rng = jax.random.PRNGKey(0)
         tt = (token_type_ids if token_type_ids is not None
               else jnp.zeros_like(input_ids))
-        x = (params["word_embeddings"][input_ids]
-             + params["position_embeddings"][:T][None]
-             + params["token_type_embeddings"][tt])
-        x = _layer_norm(x, params["emb_ln_scale"], params["emb_ln_bias"])
+        with jax.named_scope("embed"):
+            x = (params["word_embeddings"][input_ids]
+                 + params["position_embeddings"][:T][None]
+                 + params["token_type_embeddings"][tt])
+            x = _layer_norm(x, params["emb_ln_scale"],
+                            params["emb_ln_bias"])
         x = _dropout(x, cfg.hidden_dropout_prob if train else 0.0,
                      jax.random.fold_in(rng, 997))
 
@@ -219,13 +221,14 @@ class BertModel(TrainModule):
                           batch.get("attention_mask"), rng, train,
                           pld_theta=(pld.reshape(-1)[0]
                                      if pld is not None else None))
-        # MLM head
-        h = seq @ params["mlm_transform_w"].astype(seq.dtype) \
-            + params["mlm_transform_b"].astype(seq.dtype)
-        h = jax.nn.gelu(h, approximate=False)
-        h = _layer_norm(h, params["mlm_ln_scale"], params["mlm_ln_bias"])
-        mlm_logits = h @ params["word_embeddings"].astype(h.dtype).T \
-            + params["mlm_bias"].astype(h.dtype)
+        with jax.named_scope("mlm_head"):
+            h = seq @ params["mlm_transform_w"].astype(seq.dtype) \
+                + params["mlm_transform_b"].astype(seq.dtype)
+            h = jax.nn.gelu(h, approximate=False)
+            h = _layer_norm(h, params["mlm_ln_scale"],
+                            params["mlm_ln_bias"])
+            mlm_logits = h @ params["word_embeddings"].astype(h.dtype).T \
+                + params["mlm_bias"].astype(h.dtype)
         # NSP head on pooled [CLS]
         pooled = jnp.tanh(
             seq[:, 0] @ params["pooler_w"].astype(seq.dtype)

@@ -156,7 +156,8 @@ class GPT2Model(TrainModule):
         if T > cfg.n_positions:
             raise ValueError(
                 f"sequence length {T} exceeds n_positions={cfg.n_positions}")
-        x = params["wte"][tokens] + params["wpe"][:T][None]
+        with jax.named_scope("embed"):
+            x = params["wte"][tokens] + params["wpe"][:T][None]
         x = _dropout(x, cfg.embd_dropout if train else 0.0,
                      jax.random.fold_in(rng, 997))
 
@@ -199,8 +200,7 @@ class GPT2Model(TrainModule):
                 bp = jax.tree.map(lambda a, i=i: a[i], block_params)
                 x, _ = body_fn(x, (bp, jnp.asarray(i)))
 
-        x = _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"])
-        logits = x @ params["wte"].astype(x.dtype).T
+        logits = _lm_head(params, x)
         return logits
 
     def loss_fn(self, params, batch, rng, train: bool = True):
@@ -339,6 +339,7 @@ def _layer_fetcher(block_specs):
     return fetch
 
 
+@jax.named_scope("layer")
 def gpt2_block_forward(cfg: GPT2Config, bp, x, rng, train: bool):
     """One pre-LN transformer block over unstacked per-layer params — the
     single source of the block math, shared by the scan-over-layers model,
@@ -402,6 +403,7 @@ def _lora_bind(bp, lora_layer, adapter_slots, scale):
     return bp
 
 
+@jax.named_scope("mlp")
 def gpt2_ffn(bp, h):
     """fc → gelu → proj over already-normalized input (dense FFN body,
     shared with the MoE flavor's dense blocks)."""
@@ -456,6 +458,7 @@ def gpt2_attn_project(bp, x, attn, drop: float, rng):
     return x + _dropout(y, drop, rng)
 
 
+@jax.named_scope("attn")
 def gpt2_attn_sublayer(cfg: GPT2Config, bp, x, rng, train: bool):
     """ln1 → attention → residual (the block minus its FFN sublayer)."""
     B, T, D = x.shape
@@ -563,18 +566,20 @@ def _decode_attn_impl(cfg: GPT2Config) -> str:
         "time axis the decode cache does not have)")
 
 
+@jax.named_scope("layer")
 def gpt2_block_prefill(cfg: GPT2Config, bp, x):
     """One block at inference (train=False — every dropout is a no-op),
     additionally returning the per-head K/V for the serving cache."""
-    q, k, v = gpt2_qkv_heads(cfg, bp, x)
-    if cfg.attn_impl == "flash":
-        from ..parallel.attention import sharded_flash_attention
-        attn = sharded_flash_attention(q, k, v, causal=True)
-    elif cfg.attn_impl == "dense":
-        attn = causal_attention(q, k, v)
-    else:
-        _decode_attn_impl(cfg)  # raises with the real story
-    x = gpt2_attn_project(bp, x, attn, 0.0, None)
+    with jax.named_scope("attn"):
+        q, k, v = gpt2_qkv_heads(cfg, bp, x)
+        if cfg.attn_impl == "flash":
+            from ..parallel.attention import sharded_flash_attention
+            attn = sharded_flash_attention(q, k, v, causal=True)
+        elif cfg.attn_impl == "dense":
+            attn = causal_attention(q, k, v)
+        else:
+            _decode_attn_impl(cfg)  # raises with the real story
+        x = gpt2_attn_project(bp, x, attn, 0.0, None)
     h = _layer_norm(x, bp["ln2_scale"], bp["ln2_bias"])
     return x + gpt2_ffn(bp, h), (k, v)
 
@@ -594,18 +599,20 @@ def _cache_write(cache, new, pos, active):
     return cache.at[s_idx, :, pos].set(blended)
 
 
+@jax.named_scope("layer")
 def gpt2_block_decode(cfg: GPT2Config, bp, x, k_cache, v_cache,
                       positions, att_len, active, impl: str):
     """One block for a single decode tick: x [S, 1, D] (one new token
     per slot); writes the token's K/V at ``positions`` (masked by
     ``active``) then attends over ``att_len`` live keys per slot."""
-    q, k, v = gpt2_qkv_heads(cfg, bp, x)                # [S, H, 1, Dh]
-    k_cache = _cache_write(k_cache, k[:, :, 0], positions, active)
-    v_cache = _cache_write(v_cache, v[:, :, 0], positions, active)
-    from ..ops.pallas.decode_attention import decode_attention
-    attn = decode_attention(q[:, :, 0], k_cache, v_cache, att_len,
-                            impl=impl)                  # [S, H, Dh]
-    x = gpt2_attn_project(bp, x, attn[:, :, None, :], 0.0, None)
+    with jax.named_scope("attn"):
+        q, k, v = gpt2_qkv_heads(cfg, bp, x)                # [S, H, 1, Dh]
+        k_cache = _cache_write(k_cache, k[:, :, 0], positions, active)
+        v_cache = _cache_write(v_cache, v[:, :, 0], positions, active)
+        from ..ops.pallas.decode_attention import decode_attention
+        attn = decode_attention(q[:, :, 0], k_cache, v_cache, att_len,
+                                impl=impl)                  # [S, H, Dh]
+        x = gpt2_attn_project(bp, x, attn[:, :, None, :], 0.0, None)
     h = _layer_norm(x, bp["ln2_scale"], bp["ln2_bias"])
     return x + gpt2_ffn(bp, h), k_cache, v_cache
 
@@ -621,7 +628,8 @@ def gpt2_prefill(cfg: GPT2Config, params, tokens):
     if T > cfg.n_positions:
         raise ValueError(
             f"sequence length {T} exceeds n_positions={cfg.n_positions}")
-    x = params["wte"][tokens] + params["wpe"][:T][None]
+    with jax.named_scope("embed"):
+        x = params["wte"][tokens] + params["wpe"][:T][None]
     block_params = params["blocks"]
     if cfg.scan_layers:
         def body(x, bp):
@@ -635,8 +643,7 @@ def gpt2_prefill(cfg: GPT2Config, params, tokens):
             ks_l.append(kk)
             vs_l.append(vv)
         ks, vs = jnp.stack(ks_l), jnp.stack(vs_l)
-    x = _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"])
-    logits = x @ params["wte"].astype(x.dtype).T
+    logits = _lm_head(params, x)
     return logits, ks, vs
 
 
@@ -658,8 +665,9 @@ def gpt2_decode_step(cfg: GPT2Config, params, tokens, k_cache, v_cache,
     T = k_cache.shape[3]
     lengths = lengths.astype(jnp.int32)
     positions = jnp.clip(lengths, 0, min(T, cfg.n_positions) - 1)
-    x = (params["wte"][tokens][:, None, :]
-         + params["wpe"][positions][:, None, :])
+    with jax.named_scope("embed"):
+        x = (params["wte"][tokens][:, None, :]
+             + params["wpe"][positions][:, None, :])
     # live keys this tick INCLUDE the token being decoded; free slots
     # attend nothing (exact-zero attention rows)
     att_len = jnp.where(active, lengths + 1, 0).astype(jnp.int32)
@@ -682,8 +690,7 @@ def gpt2_decode_step(cfg: GPT2Config, params, tokens, k_cache, v_cache,
             kc_l.append(kc)
             vc_l.append(vc)
         k_cache, v_cache = jnp.stack(kc_l), jnp.stack(vc_l)
-    x = _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"])
-    logits = (x @ params["wte"].astype(x.dtype).T)[:, 0]
+    logits = _lm_head(params, x)[:, 0]
     new_lengths = lengths + active.astype(jnp.int32)
     return logits, k_cache, v_cache, new_lengths
 
@@ -717,22 +724,24 @@ def _verify_rows(lengths, active, W: int, cap: int):
     return positions, row_valid, row_lens
 
 
+@jax.named_scope("layer")
 def gpt2_block_verify(cfg: GPT2Config, bp, x, k_cache, v_cache,
                       positions, row_valid, row_lens, impl: str):
     """One block of the verify pass: x [S, W, D] (W new tokens per
     slot); writes all W K/V rows (masked per row) then runs the
     multi-query decode attention."""
-    q, k, v = gpt2_qkv_heads(cfg, bp, x)                # [S, H, W, Dh]
-    W = x.shape[1]
-    for i in range(W):                                  # static, W <= 9
-        k_cache = _cache_write(k_cache, k[:, :, i], positions[:, i],
-                               row_valid[:, i])
-        v_cache = _cache_write(v_cache, v[:, :, i], positions[:, i],
-                               row_valid[:, i])
-    from ..ops.pallas.decode_attention import decode_attention_multi
-    attn = decode_attention_multi(q, k_cache, v_cache, row_lens,
-                                  impl=impl)            # [S, H, W, Dh]
-    x = gpt2_attn_project(bp, x, attn, 0.0, None)
+    with jax.named_scope("attn"):
+        q, k, v = gpt2_qkv_heads(cfg, bp, x)                # [S, H, W, Dh]
+        W = x.shape[1]
+        for i in range(W):                                  # static, W <= 9
+            k_cache = _cache_write(k_cache, k[:, :, i], positions[:, i],
+                                   row_valid[:, i])
+            v_cache = _cache_write(v_cache, v[:, :, i], positions[:, i],
+                                   row_valid[:, i])
+        from ..ops.pallas.decode_attention import decode_attention_multi
+        attn = decode_attention_multi(q, k_cache, v_cache, row_lens,
+                                      impl=impl)            # [S, H, W, Dh]
+        x = gpt2_attn_project(bp, x, attn, 0.0, None)
     h = _layer_norm(x, bp["ln2_scale"], bp["ln2_bias"])
     return x + gpt2_ffn(bp, h), k_cache, v_cache
 
@@ -761,7 +770,8 @@ def gpt2_verify_step(cfg: GPT2Config, params, tokens, k_cache, v_cache,
     cap = min(T, cfg.n_positions)
     positions, row_valid, row_lens = _verify_rows(lengths, active, W,
                                                   cap)
-    x = params["wte"][tokens] + params["wpe"][positions]    # [S, W, D]
+    with jax.named_scope("embed"):
+        x = params["wte"][tokens] + params["wpe"][positions]    # [S, W, D]
     block_params = params["blocks"]
     if cfg.scan_layers:
         def body(x, xs):
@@ -781,11 +791,11 @@ def gpt2_verify_step(cfg: GPT2Config, params, tokens, k_cache, v_cache,
             kc_l.append(kc)
             vc_l.append(vc)
         k_cache, v_cache = jnp.stack(kc_l), jnp.stack(vc_l)
-    x = _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"])
-    logits = x @ params["wte"].astype(x.dtype).T            # [S, W, V]
+    logits = _lm_head(params, x)                # [S, W, V]
     return logits, k_cache, v_cache
 
 
+@jax.named_scope("layer")
 def gpt2_block_verify_paged(cfg: GPT2Config, bp, x, k_pool, v_pool,
                             page_table, positions, row_valid, row_lens,
                             impl: str, k_scale=None, v_scale=None):
@@ -793,25 +803,26 @@ def gpt2_block_verify_paged(cfg: GPT2Config, bp, x, k_pool, v_pool,
     (invalid rows to the scratch page) then the paged multi-query
     attention — quantizing each row on write and running the fused-
     dequant multi arm when the pool is int8."""
-    q, k, v = gpt2_qkv_heads(cfg, bp, x)                # [S, H, W, Dh]
-    W = x.shape[1]
-    page_len = k_pool.shape[2]
-    s_idx = jnp.arange(page_table.shape[0])
-    for i in range(W):                                  # static, W <= 9
-        pos = positions[:, i]
-        page_ids = jnp.where(row_valid[:, i],
-                             page_table[s_idx, pos // page_len], 0)
-        offs = pos % page_len
-        k_pool, k_scale = _paged_write(k_pool, k_scale, k[:, :, i],
-                                       page_ids, offs, row_valid[:, i])
-        v_pool, v_scale = _paged_write(v_pool, v_scale, v[:, :, i],
-                                       page_ids, offs, row_valid[:, i])
-    from ..ops.pallas.decode_attention import decode_attention_paged_multi
-    attn = decode_attention_paged_multi(q, k_pool, v_pool, page_table,
-                                        row_lens, impl=impl,
-                                        k_scale=k_scale,
-                                        v_scale=v_scale)
-    x = gpt2_attn_project(bp, x, attn, 0.0, None)
+    with jax.named_scope("attn"):
+        q, k, v = gpt2_qkv_heads(cfg, bp, x)                # [S, H, W, Dh]
+        W = x.shape[1]
+        page_len = k_pool.shape[2]
+        s_idx = jnp.arange(page_table.shape[0])
+        for i in range(W):                                  # static, W <= 9
+            pos = positions[:, i]
+            page_ids = jnp.where(row_valid[:, i],
+                                 page_table[s_idx, pos // page_len], 0)
+            offs = pos % page_len
+            k_pool, k_scale = _paged_write(k_pool, k_scale, k[:, :, i],
+                                           page_ids, offs, row_valid[:, i])
+            v_pool, v_scale = _paged_write(v_pool, v_scale, v[:, :, i],
+                                           page_ids, offs, row_valid[:, i])
+        from ..ops.pallas.decode_attention import decode_attention_paged_multi
+        attn = decode_attention_paged_multi(q, k_pool, v_pool, page_table,
+                                            row_lens, impl=impl,
+                                            k_scale=k_scale,
+                                            v_scale=v_scale)
+        x = gpt2_attn_project(bp, x, attn, 0.0, None)
     h = _layer_norm(x, bp["ln2_scale"], bp["ln2_bias"])
     return (x + gpt2_ffn(bp, h), k_pool, v_pool, k_scale, v_scale)
 
@@ -837,7 +848,8 @@ def gpt2_verify_step_paged(cfg: GPT2Config, params, tokens, k_pool,
     cap = min(page_table.shape[1] * page_len, cfg.n_positions)
     positions, row_valid, row_lens = _verify_rows(lengths, active, W,
                                                   cap)
-    x = params["wte"][tokens] + params["wpe"][positions]
+    with jax.named_scope("embed"):
+        x = params["wte"][tokens] + params["wpe"][positions]
     block_params = params["blocks"]
     if cfg.scan_layers:
         def body(x, xs):
@@ -873,8 +885,7 @@ def gpt2_verify_step_paged(cfg: GPT2Config, params, tokens, k_pool,
         k_pool, v_pool = jnp.stack(kc_l), jnp.stack(vc_l)
         if quant:
             k_scale, v_scale = jnp.stack(ks_l), jnp.stack(vs_l)
-    x = _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"])
-    logits = x @ params["wte"].astype(x.dtype).T
+    logits = _lm_head(params, x)
     if quant:
         return logits, k_pool, v_pool, k_scale, v_scale
     return logits, k_pool, v_pool
@@ -929,6 +940,7 @@ def _paged_write(pool, scales, new, page_ids, offs, active):
                                     active)
 
 
+@jax.named_scope("layer")
 def gpt2_block_decode_paged(cfg: GPT2Config, bp, x, k_pool, v_pool,
                             page_table, positions, att_len, active,
                             impl: str, k_scale=None, v_scale=None):
@@ -938,21 +950,22 @@ def gpt2_block_decode_paged(cfg: GPT2Config, bp, x, k_pool, v_pool,
     ``att_len`` live keys per slot through the page table.  With the
     int8 pool (``k_scale``/``v_scale`` [P, H, page_len]) the write
     quantizes per row and the attention runs the fused-dequant arm."""
-    q, k, v = gpt2_qkv_heads(cfg, bp, x)                # [S, H, 1, Dh]
-    page_len = k_pool.shape[2]
-    s_idx = jnp.arange(page_table.shape[0])
-    page_ids = jnp.where(active,
-                         page_table[s_idx, positions // page_len], 0)
-    offs = positions % page_len
-    k_pool, k_scale = _paged_write(k_pool, k_scale, k[:, :, 0],
-                                   page_ids, offs, active)
-    v_pool, v_scale = _paged_write(v_pool, v_scale, v[:, :, 0],
-                                   page_ids, offs, active)
-    from ..ops.pallas.decode_attention import decode_attention_paged
-    attn = decode_attention_paged(q[:, :, 0], k_pool, v_pool,
-                                  page_table, att_len, impl=impl,
-                                  k_scale=k_scale, v_scale=v_scale)
-    x = gpt2_attn_project(bp, x, attn[:, :, None, :], 0.0, None)
+    with jax.named_scope("attn"):
+        q, k, v = gpt2_qkv_heads(cfg, bp, x)                # [S, H, 1, Dh]
+        page_len = k_pool.shape[2]
+        s_idx = jnp.arange(page_table.shape[0])
+        page_ids = jnp.where(active,
+                             page_table[s_idx, positions // page_len], 0)
+        offs = positions % page_len
+        k_pool, k_scale = _paged_write(k_pool, k_scale, k[:, :, 0],
+                                       page_ids, offs, active)
+        v_pool, v_scale = _paged_write(v_pool, v_scale, v[:, :, 0],
+                                       page_ids, offs, active)
+        from ..ops.pallas.decode_attention import decode_attention_paged
+        attn = decode_attention_paged(q[:, :, 0], k_pool, v_pool,
+                                      page_table, att_len, impl=impl,
+                                      k_scale=k_scale, v_scale=v_scale)
+        x = gpt2_attn_project(bp, x, attn[:, :, None, :], 0.0, None)
     h = _layer_norm(x, bp["ln2_scale"], bp["ln2_bias"])
     return (x + gpt2_ffn(bp, h), k_pool, v_pool, k_scale, v_scale)
 
@@ -994,8 +1007,9 @@ def gpt2_decode_step_paged(cfg: GPT2Config, params, tokens, k_pool,
     cap = page_table.shape[1] * page_len
     lengths = lengths.astype(jnp.int32)
     positions = jnp.clip(lengths, 0, min(cap, cfg.n_positions) - 1)
-    x = (params["wte"][tokens][:, None, :]
-         + params["wpe"][positions][:, None, :])
+    with jax.named_scope("embed"):
+        x = (params["wte"][tokens][:, None, :]
+             + params["wpe"][positions][:, None, :])
     att_len = jnp.where(active, lengths + 1, 0).astype(jnp.int32)
     block_params = params["blocks"]
     if cfg.scan_layers:
@@ -1032,14 +1046,14 @@ def gpt2_decode_step_paged(cfg: GPT2Config, params, tokens, k_pool,
         k_pool, v_pool = jnp.stack(kc_l), jnp.stack(vc_l)
         if quant:
             k_scale, v_scale = jnp.stack(ks_l), jnp.stack(vs_l)
-    x = _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"])
-    logits = (x @ params["wte"].astype(x.dtype).T)[:, 0]
+    logits = _lm_head(params, x)[:, 0]
     new_lengths = lengths + active.astype(jnp.int32)
     if quant:
         return logits, k_pool, v_pool, k_scale, v_scale, new_lengths
     return logits, k_pool, v_pool, new_lengths
 
 
+@jax.named_scope("layer")
 def gpt2_block_prefill_paged(cfg: GPT2Config, bp, x, k_pool, v_pool,
                              page_row, prefix_len, delta_len,
                              k_scale=None, v_scale=None):
@@ -1063,55 +1077,56 @@ def gpt2_block_prefill_paged(cfg: GPT2Config, bp, x, k_pool, v_pool,
       absolute position ``<= prefix_len+i`` — the cached prefix plus
       the causal delta.
     """
-    q, k, v = gpt2_qkv_heads(cfg, bp, x)                # [1, H, Tq, Dh]
-    Tq = x.shape[1]
-    page_len = k_pool.shape[2]
-    cap = page_row.shape[0] * page_len
-    abs_pos = prefix_len + jnp.arange(Tq, dtype=jnp.int32)
-    valid = jnp.arange(Tq) < delta_len
-    # masked rows route to the scratch page: a clipped dead position
-    # must never collide with a live row's (page, off) target
-    abs_clip = jnp.clip(abs_pos, 0, cap - 1)
-    page_ids = jnp.where(valid, page_row[abs_clip // page_len], 0)
-    offs = abs_clip % page_len
-    kn = k[0].transpose(1, 0, 2)                        # [Tq, H, Dh]
-    vn = v[0].transpose(1, 0, 2)
-    k_pool, k_scale = _paged_write(k_pool, k_scale, kn, page_ids, offs,
-                                   valid)
-    v_pool, v_scale = _paged_write(v_pool, v_scale, vn, page_ids, offs,
-                                   valid)
+    with jax.named_scope("attn"):
+        q, k, v = gpt2_qkv_heads(cfg, bp, x)                # [1, H, Tq, Dh]
+        Tq = x.shape[1]
+        page_len = k_pool.shape[2]
+        cap = page_row.shape[0] * page_len
+        abs_pos = prefix_len + jnp.arange(Tq, dtype=jnp.int32)
+        valid = jnp.arange(Tq) < delta_len
+        # masked rows route to the scratch page: a clipped dead position
+        # must never collide with a live row's (page, off) target
+        abs_clip = jnp.clip(abs_pos, 0, cap - 1)
+        page_ids = jnp.where(valid, page_row[abs_clip // page_len], 0)
+        offs = abs_clip % page_len
+        kn = k[0].transpose(1, 0, 2)                        # [Tq, H, Dh]
+        vn = v[0].transpose(1, 0, 2)
+        k_pool, k_scale = _paged_write(k_pool, k_scale, kn, page_ids, offs,
+                                       valid)
+        v_pool, v_scale = _paged_write(v_pool, v_scale, vn, page_ids, offs,
+                                       valid)
 
-    def _self_arm(_):
-        # the pre-page prefill attention, op for op
-        if cfg.attn_impl == "flash":
-            from ..parallel.attention import sharded_flash_attention
-            return sharded_flash_attention(q, k, v, causal=True)
-        return causal_attention(q, k, v)
+        def _self_arm(_):
+            # the pre-page prefill attention, op for op
+            if cfg.attn_impl == "flash":
+                from ..parallel.attention import sharded_flash_attention
+                return sharded_flash_attention(q, k, v, causal=True)
+            return causal_attention(q, k, v)
 
-    def _gather_arm(_):
-        from ..ops.pallas.decode_attention import (_default_scale,
-                                                   dequantize_paged,
-                                                   paged_gather)
-        if k_scale is not None:
-            kg = dequantize_paged(k_pool, k_scale, page_row[None])[0]
-            vg = dequantize_paged(v_pool, v_scale, page_row[None])[0]
-        else:
-            kg = paged_gather(k_pool, page_row[None])[0]  # [H, T', Dh]
-            vg = paged_gather(v_pool, page_row[None])[0]
-        scale = _default_scale(cfg.d_head)
-        s = jnp.einsum("htd,hsd->hts", q[0], kg.astype(q.dtype),
-                       preferred_element_type=jnp.float32) * scale
-        key_pos = jnp.arange(kg.shape[1], dtype=jnp.int32)
-        ok = key_pos[None, :] <= abs_pos[:, None]       # [Tq, T']
-        neg = jnp.asarray(jnp.finfo(jnp.float32).min, jnp.float32)
-        s = jnp.where(ok[None], s, neg)
-        probs = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-        return jnp.einsum("hts,hsd->htd", probs,
-                          vg.astype(q.dtype))[None]
+        def _gather_arm(_):
+            from ..ops.pallas.decode_attention import (_default_scale,
+                                                       dequantize_paged,
+                                                       paged_gather)
+            if k_scale is not None:
+                kg = dequantize_paged(k_pool, k_scale, page_row[None])[0]
+                vg = dequantize_paged(v_pool, v_scale, page_row[None])[0]
+            else:
+                kg = paged_gather(k_pool, page_row[None])[0]  # [H, T', Dh]
+                vg = paged_gather(v_pool, page_row[None])[0]
+            scale = _default_scale(cfg.d_head)
+            s = jnp.einsum("htd,hsd->hts", q[0], kg.astype(q.dtype),
+                           preferred_element_type=jnp.float32) * scale
+            key_pos = jnp.arange(kg.shape[1], dtype=jnp.int32)
+            ok = key_pos[None, :] <= abs_pos[:, None]       # [Tq, T']
+            neg = jnp.asarray(jnp.finfo(jnp.float32).min, jnp.float32)
+            s = jnp.where(ok[None], s, neg)
+            probs = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+            return jnp.einsum("hts,hsd->htd", probs,
+                              vg.astype(q.dtype))[None]
 
-    attn = jax.lax.cond(prefix_len == 0, _self_arm, _gather_arm,
-                        operand=None)
-    x = gpt2_attn_project(bp, x, attn, 0.0, None)
+        attn = jax.lax.cond(prefix_len == 0, _self_arm, _gather_arm,
+                            operand=None)
+        x = gpt2_attn_project(bp, x, attn, 0.0, None)
     h = _layer_norm(x, bp["ln2_scale"], bp["ln2_bias"])
     return (x + gpt2_ffn(bp, h), k_pool, v_pool, k_scale, v_scale)
 
@@ -1153,7 +1168,8 @@ def gpt2_prefill_paged(cfg: GPT2Config, params, tokens, delta_len,
     delta_len = jnp.asarray(delta_len, jnp.int32)
     pos = jnp.clip(prefix_len + jnp.arange(Tq, dtype=jnp.int32), 0,
                    cfg.n_positions - 1)
-    x = params["wte"][tokens] + params["wpe"][pos][None]
+    with jax.named_scope("embed"):
+        x = params["wte"][tokens] + params["wpe"][pos][None]
     if lora is not None:
         # one tenant per prefill: a length-1 slot table so the batched
         # per-row gather (`_lora_delta`) is the SAME einsum as decode
@@ -1194,11 +1210,17 @@ def gpt2_prefill_paged(cfg: GPT2Config, params, tokens, delta_len,
         k_pool, v_pool = jnp.stack(kc_l), jnp.stack(vc_l)
         if quant:
             k_scale, v_scale = jnp.stack(ks_l), jnp.stack(vs_l)
-    x = _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"])
-    logits = x @ params["wte"].astype(x.dtype).T
+    logits = _lm_head(params, x)
     if quant:
         return logits, k_pool, v_pool, k_scale, v_scale
     return logits, k_pool, v_pool
+
+
+@jax.named_scope("lm_head")
+def _lm_head(params, x):
+    """Final LayerNorm → logits over the tied embedding, [..., vocab]."""
+    x = _layer_norm(x, params["ln_f_scale"], params["ln_f_bias"])
+    return x @ params["wte"].astype(x.dtype).T
 
 
 def _layer_norm(x, scale, bias, eps: float = 1e-5):

@@ -102,6 +102,10 @@ def build_kernel_luts(layout: np.ndarray
 # ---------------------------------------------------------------------------
 
 
+# stable Mosaic custom-call names (see flash_attention.FLASH_FWD_KERNEL)
+SPARSE_FWD_KERNEL = "ds_sparse_fwd"
+
+
 def _fwd_kernel(cols_ref, nvalid_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_scr, l_scr, acc_scr, *, sm_scale, heads, lut_heads,
                 block, width):
@@ -188,6 +192,7 @@ def _sparse_fwd(q, k, v, cols, nvalid, *, sm_scale, heads, block,
             jax.ShapeDtypeStruct((bh, nb, 8, block), jnp.float32),
         ],
         interpret=interpret,
+        name=SPARSE_FWD_KERNEL,
     )(cols, nvalid, q, k, v)
     return out, lse[:, :, 0, :].reshape(bh, t)
 
@@ -195,6 +200,9 @@ def _sparse_fwd(q, k, v, cols, nvalid, *, sm_scale, heads, block,
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
+
+
+SPARSE_BWD_DQ_KERNEL = "ds_sparse_bwd_dq"
 
 
 def _bwd_dq_kernel(cols_ref, nvalid_ref, q_ref, k_ref, v_ref, do_ref,
@@ -230,6 +238,9 @@ def _bwd_dq_kernel(cols_ref, nvalid_ref, q_ref, k_ref, v_ref, do_ref,
     @pl.when(w == width - 1)
     def _finalize():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+
+
+SPARSE_BWD_DKV_KERNEL = "ds_sparse_bwd_dkv"
 
 
 def _bwd_dkv_kernel(rows_ref, nvalid_ref, q_ref, k_ref, v_ref, do_ref,
@@ -319,6 +330,7 @@ def _sparse_bwd(q, k, v, out, lse, do, cols, nvalid, rows_t, nvalid_t,
         ),
         out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
         interpret=interpret,
+        name=SPARSE_BWD_DQ_KERNEL,
     )(cols, nvalid, q, k, v, do, lsep, deltap)
 
     # dK/dV: walk the transposed LUT — q/do/lse/delta blocks come from the
@@ -356,6 +368,7 @@ def _sparse_bwd(q, k, v, out, lse, do, cols, nvalid, rows_t, nvalid_t,
         out_shape=[jax.ShapeDtypeStruct((bh, t, d), k.dtype),
                    jax.ShapeDtypeStruct((bh, t, d), v.dtype)],
         interpret=interpret,
+        name=SPARSE_BWD_DKV_KERNEL,
     )(rows_t, nvalid_t, q, k, v, do, lsep, deltap)
     return dq, dk, dv
 

@@ -111,6 +111,14 @@ def _default_scale(d: int) -> float:
     return float(np.float32(1.0) / np.sqrt(np.float32(d)))
 
 
+# Stable names of the Mosaic custom calls (``pl.pallas_call(name=...)``):
+# the HLO instruction, and so the device-trace row, is ``<name>.<n>``.
+# Every kernel of the repo shares the ``ds_`` prefix, and the
+# benchmark's per-kernel shares (benchmark/metrics/*_share.*.json)
+# match on these strings — moving a call keeps its constant.
+DECODE_ATTN_KERNEL = "ds_decode_attn"
+
+
 def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref,
                    m_scr, l_scr, acc_scr,
                    *, sm_scale: float, block_k: int):
@@ -191,6 +199,7 @@ def _decode_pallas(q, k, v, lengths, *, sm_scale, block_k, interpret):
             pltpu.VMEM((8, Dh), jnp.float32),
         ],
         interpret=interpret,
+        name=DECODE_ATTN_KERNEL,
     )(qf, kf, vf, len_op)
     return out[:, 0, :].reshape(S, H, Dh)
 
@@ -305,6 +314,11 @@ def _scale_tile(scales: jnp.ndarray) -> jnp.ndarray:
     return jnp.broadcast_to(lanes[:, :, None, :], (Pp, Hh, 8, 128))
 
 
+PAGED_DECODE_ATTN_KERNEL = "ds_paged_decode_attn"
+#: the fused-dequant arm is another kernel body (two more operands)
+PAGED_DECODE_ATTN_INT8_KERNEL = PAGED_DECODE_ATTN_KERNEL + "_int8"
+
+
 def _decode_paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
                          sm_scale: float, page_len: int, heads: int):
     # fused-dequant arm (int8 pages): two extra scale-tile refs ride
@@ -414,6 +428,8 @@ def _decode_paged_pallas(q, k_pages, v_pages, page_table, lengths, *,
         out_shape=jax.ShapeDtypeStruct((S * H, 8, Dh),
                                        jnp.float32 if quant else q.dtype),
         interpret=interpret,
+        name=(PAGED_DECODE_ATTN_INT8_KERNEL if quant
+              else PAGED_DECODE_ATTN_KERNEL),
     )(pt_flat, lengths.astype(jnp.int32), *operands)
     return out[:, 0, :].reshape(S, H, Dh).astype(q.dtype)
 
@@ -537,6 +553,9 @@ def _pad_queries(q: jnp.ndarray, wp: int) -> jnp.ndarray:
     return qf
 
 
+DECODE_ATTN_MULTI_KERNEL = "ds_decode_attn_multi"
+
+
 def _decode_multi_kernel(q_ref, len_ref, k_ref, v_ref, o_ref,
                          m_scr, l_scr, acc_scr,
                          *, sm_scale: float, block_k: int):
@@ -617,6 +636,7 @@ def _decode_multi_pallas(q, k, v, lengths, *, sm_scale, block_k,
             pltpu.VMEM((wp, Dh), jnp.float32),
         ],
         interpret=interpret,
+        name=DECODE_ATTN_MULTI_KERNEL,
     )(qf, len_op, kf, vf)
     return out[:, :W, :].reshape(S, H, W, Dh)
 
@@ -661,6 +681,10 @@ def decode_attention_multi(q: jnp.ndarray, k: jnp.ndarray,
     return _decode_multi_pallas(q, k, v, lengths.astype(jnp.int32),
                                 sm_scale=sm_scale, block_k=block_k,
                                 interpret=interpret)
+
+
+PAGED_DECODE_ATTN_MULTI_KERNEL = "ds_paged_decode_attn_multi"
+PAGED_DECODE_ATTN_MULTI_INT8_KERNEL = PAGED_DECODE_ATTN_MULTI_KERNEL + "_int8"
 
 
 def _decode_paged_multi_kernel(pt_ref, q_ref, len_ref, k_ref, v_ref,
@@ -769,6 +793,8 @@ def _decode_paged_multi_pallas(q, k_pages, v_pages, page_table, lengths,
         out_shape=jax.ShapeDtypeStruct((S * H, wp, Dh),
                                        jnp.float32 if quant else q.dtype),
         interpret=interpret,
+        name=(PAGED_DECODE_ATTN_MULTI_INT8_KERNEL if quant
+              else PAGED_DECODE_ATTN_MULTI_KERNEL),
     )(pt_flat, *operands)
     return out[:, :W, :].reshape(S, H, W, Dh).astype(q.dtype)
 

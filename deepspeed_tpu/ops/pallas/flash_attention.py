@@ -172,6 +172,13 @@ def _masked_scores(q, k, iq, ik, *, sm_scale, causal, block_q, block_k,
 # ---------------------------------------------------------------------------
 
 
+# Stable names of the Mosaic custom calls (``pl.pallas_call(name=...)``):
+# the HLO instruction, and so the device-trace row, is ``<name>.<n>``
+# whatever JAX construct (checkpoint, scan, shard_map, cond) wraps the
+# call.  The benchmark's per-kernel shares match on these strings.
+FLASH_FWD_KERNEL = "ds_flash_fwd"
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, seed_ref, bh_ref, kmask_ref,
                 o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 *, sm_scale: float, causal: bool, block_q: int,
@@ -351,6 +358,7 @@ def _fwd(q, k, v, seed, bh_base, kmask, *, sm_scale, causal, block_q,
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        name=FLASH_FWD_KERNEL,
     )(qp, kp, vp, _seed_arr(seed), _seed_arr(bh_base), kmask_op)
     return out[:, :t], lse[:, :, 0, :].reshape(bh, tq_p)[:, :t]
 
@@ -358,6 +366,9 @@ def _fwd(q, k, v, seed, bh_base, kmask, *, sm_scale, causal, block_q,
 # ---------------------------------------------------------------------------
 # backward
 # ---------------------------------------------------------------------------
+
+
+FLASH_BWD_DQ_KERNEL = "ds_flash_bwd_dq"
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -409,6 +420,9 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     @pl.when(ik == nk - 1)
     def _finalize():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+
+
+FLASH_BWD_DKV_KERNEL = "ds_flash_bwd_dkv"
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -526,6 +540,7 @@ def _bwd(q, k, v, out, lse, do, seed, bh_base, kmask, *, sm_scale,
         out_shape=jax.ShapeDtypeStruct((bh, tq_p, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name=FLASH_BWD_DQ_KERNEL,
     )(qp, kp, vp, dop, lsep, deltap, _seed_arr(seed), _seed_arr(bh_base),
       kmask_op)
 
@@ -565,6 +580,7 @@ def _bwd(q, k, v, out, lse, do, seed, bh_base, kmask, *, sm_scale,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
+        name=FLASH_BWD_DKV_KERNEL,
     )(qp, kp, vp, dop, lsep, deltap, _seed_arr(seed), _seed_arr(bh_base),
       kmask_op)
     return dq[:, :t], dk[:, :tk], dv[:, :tk]

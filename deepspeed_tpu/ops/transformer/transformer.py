@@ -177,6 +177,7 @@ class DeepSpeedTransformerLayer:
         rows = jnp.broadcast_to(m[:, :, 0, :], (B, H, T))
         return rows.reshape(B * H, T).astype(jnp.float32)
 
+    @jax.named_scope("attn")
     def _attention(self, params, h, attention_mask, rng, train):
         cfg = self.config
         B, T, D = h.shape
@@ -233,6 +234,7 @@ class DeepSpeedTransformerLayer:
         return ctx @ params["attn_ow"].astype(h.dtype) \
             + params["attn_ob"].astype(h.dtype)
 
+    @jax.named_scope("mlp")
     def _ffn(self, params, h):
         def inner(h):
             x = h @ params["inter_w"].astype(h.dtype) \
@@ -245,6 +247,7 @@ class DeepSpeedTransformerLayer:
         return x @ params["output_w"].astype(h.dtype) \
             + params["output_b"].astype(h.dtype)
 
+    @jax.named_scope("layer")
     def __call__(self, params, hidden_states, attention_mask=None,
                  rng=None, train: bool = True):
         cfg = self.config

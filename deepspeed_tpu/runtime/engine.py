@@ -42,6 +42,7 @@ from ..ops.adam import (FusedAdamState, adam_direction, adam_moments,
                         fused_adam)
 from ..ops.lamb import fused_lamb
 from ..parallel.mesh import DATA_AXIS, build_mesh, mesh_axis_size
+from ..telemetry import tracing
 from ..utils.logging import log_dist, logger
 from . import precision
 from .engine_stages import (finish_close, pop_stage_errors,
@@ -51,9 +52,17 @@ from .module import TrainModule
 from .prefetch import DevicePlacedBatch, DevicePrefetcher
 from .precision import LossScaleState
 from .utils import clip_by_global_norm, global_norm
-from .zero import ZeroShardingPlan, constrain_grads
+from .zero import ZeroShardingPlan, cast_for_compute, constrain_grads
 
 MEMORY_OPT_ALLREDUCE_SIZE = 500_000_000  # kept for parity (engine.py:41)
+
+
+def _named(name: str, fn):
+    """``fn`` under a program's stable name.  ``jax.jit`` names the
+    compiled module ``jit_<fn.__name__>``; a ``shard_map`` wrapper would
+    otherwise make every such step ``jit_sm``."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
 
 
 class TrainState(NamedTuple):
@@ -481,8 +490,8 @@ class DeepSpeedEngine:
             # leaf, while a program output lands in host memory at the
             # link's rate.
             pack_piece = jax.jit(
-                lambda l, rec, dp: _pack_leaf(
-                    l.astype(jnp.float32), rec, dp, jnp),
+                _named("offload_pack_piece", lambda l, rec, dp: _pack_leaf(
+                    l.astype(jnp.float32), rec, dp, jnp)),
                 static_argnums=(1, 2), out_shardings=piece_host)
             pieces = []
             for i, rec in enumerate(self._flat_layout):
@@ -576,9 +585,11 @@ class DeepSpeedEngine:
                     master_dev, **opt_kwargs)
                 del master_dev  # host blocks pulled; free the device fp32
                 self._sharded_gather = jax.jit(
-                    lambda t: t, out_shardings=self._compute_shardings)
+                    _named("offload_gather_params", lambda t: t),
+                    out_shardings=self._compute_shardings)
                 self._reshard_to_master = jax.jit(
-                    lambda t: t, out_shardings=master_shardings)
+                    _named("offload_reshard_to_master", lambda t: t),
+                    out_shardings=master_shardings)
                 self._compute_params = self._sharded_gather(
                     self._host_opt.compute_params())
             elif self._offload_disk:
@@ -800,19 +811,8 @@ class DeepSpeedEngine:
                 process_index=jax.process_index())
             # per-program retrace counters (track_program skips drivers
             # without a jit cache, e.g. the chunked offload python loops)
-            for name, fn in (
-                    ("train_step", getattr(self, "_train_step", None)),
-                    ("eval_step", getattr(self, "_eval_step", None)),
-                    ("grad_step", getattr(self, "_grad_step", None)),
-                    ("offload_eval_step",
-                     getattr(self, "_offload_eval_step", None))):
-                if fn is not None:
-                    self.telemetry.track_program(name, fn)
-            if self._onebit_steps is not None:
-                self.telemetry.track_program(
-                    "onebit_warm", self._onebit_steps[0])
-                self.telemetry.track_program(
-                    "onebit_frozen", self._onebit_steps[1])
+            for fn in self.step_programs():
+                self.telemetry.track_program(fn.__name__, fn)
             if self.telemetry.tracer is not None:
                 # offload D2H pulls emit transfer spans (module-level
                 # hook: the last telemetry-enabled engine wins)
@@ -1015,7 +1015,7 @@ class DeepSpeedEngine:
             con = lambda g: g  # noqa: E731
 
         def micro_loss(p, mb, rng):
-            pp = precision.cast_to_compute(p, compute_dtype) if cast else p
+            pp = cast_for_compute(p, compute_dtype) if cast else p
             loss = loss_fn(pp, mb, rng, train=True)
             return precision.scale_loss(loss.astype(jnp.float32), scaler)
 
@@ -1035,8 +1035,9 @@ class DeepSpeedEngine:
             gsum, i = carry
             rng = jax.random.fold_in(step_rng, i)
             scaled_loss, g = grad_fn(params, mb, rng)
-            gsum = jax.tree.map(
-                lambda a, b: a + b.astype(jnp.float32), gsum, con(g))
+            with jax.named_scope("grad_reduce"):
+                gsum = jax.tree.map(
+                    lambda a, b: a + b.astype(jnp.float32), gsum, con(g))
             return (gsum, i + 1), scaled_loss
 
         gsum0 = con(jax.tree.map(
@@ -1044,7 +1045,9 @@ class DeepSpeedEngine:
         (gsum, _), scaled_losses = jax.lax.scan(
             acc_body, (gsum0, jnp.asarray(0, jnp.int32)), batch)
         inv = (1.0 / (scaler.loss_scale * grad_acc)).astype(jnp.float32)
-        return con(jax.tree.map(lambda g: g * inv, gsum)), scaled_losses
+        with jax.named_scope("grad_reduce"):
+            grads = con(jax.tree.map(lambda g: g * inv, gsum))
+        return grads, scaled_losses
 
     # ------------------------------------------------------------------
     # partitioning correctness sweep (the reference's pg_correctness_test,
@@ -1079,11 +1082,11 @@ class DeepSpeedEngine:
         state = self.state
 
         def grads_of(constrain):
-            def f(master, batch_in, scaler, rng):
+            def pg_check_grads(master, batch_in, scaler, rng):
                 g, _ = self._scan_scaled_grads(
                     master, batch_in, scaler, rng, constrain=constrain)
                 return g
-            return jax.jit(f, static_argnums=())
+            return jax.jit(pg_check_grads, static_argnums=())
 
         rng = jax.random.fold_in(state.rng, state.global_steps)
         g_plan = jax.device_get(grads_of(True)(
@@ -1116,14 +1119,27 @@ class DeepSpeedEngine:
                  f"max_rel={max_rel:.3e}", ranks=[0])
         return {"max_abs_diff": max_abs, "max_rel_diff": max_rel}
 
+    def step_programs(self) -> list:
+        """The engine's step programs (the chunked offload tier hands a
+        plain Python driver: no jit cache, not tracked).  ``fn.__name__``
+        is the program's one stable name — ``jit_<name>`` on the
+        profiler's ``XLA Modules`` line, ``program=<name>`` on
+        ``recompiles_total`` — and no two builders share one."""
+        fns = [getattr(self, a, None) for a in (
+            "_train_step", "_eval_step", "_grad_step",
+            "_offload_eval_step")]
+        if self._onebit_steps is not None:
+            fns += list(self._onebit_steps[:2])
+        return [fn for fn in fns if fn is not None]
+
     def _tel_span(self, name: str, cat: str = "runtime", **args):
-        """Telemetry span context — a nullcontext when telemetry is off,
-        so call sites stay unconditional.  Host-side stamps only; never
-        a device sync."""
+        """A span: a profiler annotation always (so a ``profiler``
+        window shows it on the device trace's clock), a trace.json event
+        with telemetry on.  Host-side stamps only; never a device
+        sync."""
         tel = getattr(self, "telemetry", None)
-        if tel is None:
-            return contextlib.nullcontext()
-        return tel.span(name, cat=cat, **args)
+        return tracing.span(tel.tracer if tel is not None else None,
+                            name, cat, **args)
 
     def _profiler_window_tick(self):
         """Open/close the xplane capture window around train_batch calls:
@@ -1239,13 +1255,12 @@ class DeepSpeedEngine:
             """batch leaves: [grad_acc, micro_global, ...]"""
             scaler = state.scaler
             step_rng = jax.random.fold_in(state.rng, state.global_steps)
-            grads, scaled_losses = self._scan_scaled_grads(
-                state.master_params, batch, scaler, step_rng)
-
-            finite = precision.grads_finite(grads)
-            grad_norm = global_norm(grads)
-            if clip > 0:
-                grads, _ = clip_by_global_norm(grads, clip, norm=grad_norm)
+            # scopes at the layer map's boundaries (PERF.md section 3):
+            # fwd_bwd (with grad_reduce inside it, where the ZeRO
+            # placement is stated) and optimizer
+            with jax.named_scope("fwd_bwd"):
+                grads, scaled_losses = self._scan_scaled_grads(
+                    state.master_params, batch, scaler, step_rng)
 
             def do_update(operand):
                 master, opt_state = operand
@@ -1256,9 +1271,15 @@ class DeepSpeedEngine:
             def skip_update(operand):
                 return operand
 
-            new_master, new_opt = jax.lax.cond(
-                finite, do_update, skip_update,
-                (state.master_params, state.opt_state))
+            with jax.named_scope("optimizer"):
+                finite = precision.grads_finite(grads)
+                grad_norm = global_norm(grads)
+                if clip > 0:
+                    grads, _ = clip_by_global_norm(grads, clip,
+                                                   norm=grad_norm)
+                new_master, new_opt = jax.lax.cond(
+                    finite, do_update, skip_update,
+                    (state.master_params, state.opt_state))
 
             mean_loss = (jnp.mean(scaled_losses) / scaler.loss_scale)
             return self._step_epilogue(state, new_master, new_opt, finite,
@@ -1376,7 +1397,8 @@ class DeepSpeedEngine:
             out_specs=(state_specs, P()),
             axis_names={DATA_AXIS},
             check_vma=False)
-        return jax.jit(sm, donate_argnums=(0,),
+        return jax.jit(_named(f"train_step_onebit_{phase}", sm),
+                       donate_argnums=(0,),
                        out_shardings=self._step_out_shardings())
 
     # ------------------------------------------------------------------
@@ -1476,7 +1498,8 @@ class DeepSpeedEngine:
             out_specs=(state_specs, P()),
             axis_names={DATA_AXIS},
             check_vma=False)
-        return jax.jit(sm, donate_argnums=(0,),
+        return jax.jit(_named("train_step_sparse_grad", sm),
+                       donate_argnums=(0,),
                        out_shardings=self._step_out_shardings())
 
     def _init_onebit_opt_state(self, master, master_shardings=None):
@@ -1548,7 +1571,8 @@ class DeepSpeedEngine:
         grad_acc = self._scan_grad_acc
         clip = self.gradient_clipping
 
-        def grad_step(compute_params, batch, loss_scale, step_rng):
+        def train_step_offload_grad(compute_params, batch, loss_scale,
+                                    step_rng):
             def micro_loss(params, mb, rng):
                 loss = module.loss_fn(params, mb, rng, train=True)
                 return loss.astype(jnp.float32) * loss_scale
@@ -1578,15 +1602,15 @@ class DeepSpeedEngine:
             mean_loss = jnp.mean(scaled_losses) / loss_scale
             return grads, mean_loss, finite, grad_norm
 
-        return jax.jit(grad_step, donate_argnums=(1,))
+        return jax.jit(train_step_offload_grad, donate_argnums=(1,))
 
     def _build_offload_eval_step(self):
         module = self.module
 
-        def eval_step(compute_params, batch, rng):
+        def eval_step_offload(compute_params, batch, rng):
             return module.loss_fn(compute_params, batch, rng, train=False)
 
-        return jax.jit(eval_step)
+        return jax.jit(eval_step_offload)
 
     # ------------------------------------------------------------------
     # ZeRO-Offload, XLA tier: one compiled step; fp32 master + moments live
@@ -1608,7 +1632,8 @@ class DeepSpeedEngine:
             # dp is captured ONCE here — it is fixed per engine.
             dp = self.dp_world_size
             zero_piece = jax.jit(
-                lambda w: jnp.zeros((dp, w), jnp.float32),
+                _named("offload_zero_piece",
+                       lambda w: jnp.zeros((dp, w), jnp.float32)),
                 static_argnums=0,
                 out_shardings=self._piece_host_sharding)
             self._zero_piece_jit = zero_piece
@@ -1749,7 +1774,7 @@ class DeepSpeedEngine:
             host_scalar = host_scalar.with_memory_kind("pinned_host")
         lr_at = self._lr_at_fn()
 
-        def train_step(state: TrainState, batch):
+        def train_step_offload_xla(state: TrainState, batch):
             scaler = state.scaler
             step_rng = jax.random.fold_in(state.rng, state.global_steps)
             params = self._xla_offload_cast_up(state.master_params)
@@ -1823,7 +1848,7 @@ class DeepSpeedEngine:
             master_params=host_tuple,
             opt_state=FusedAdamState(count=dev, mu=host_tuple,
                                      nu=host_tuple))
-        return jax.jit(train_step, donate_argnums=(0,),
+        return jax.jit(train_step_offload_xla, donate_argnums=(0,),
                        out_shardings=(state_shardings, dev))
 
     def _host_adam_pieces(self, gpieces, masters, opt, finite_f,
@@ -1899,7 +1924,7 @@ class DeepSpeedEngine:
                     jnp.asarray(step_lr, jnp.float32), cscale)
 
         stats_jit = jax.jit(
-            stats_fn,
+            _named("offload_split_stats", stats_fn),
             out_shardings=(dev, dev) + (host_scalar,) * 5)
 
         def piece_fn(master, mu, nu, g, finite_f, c1, c2, lr, cs):
@@ -1917,7 +1942,7 @@ class DeepSpeedEngine:
         # the grad piece (3) is donated in both variants: it is dead
         # after this program either way
         piece_jit = jax.jit(
-            piece_fn,
+            _named("offload_split_piece", piece_fn),
             donate_argnums=((0, 1, 2, 3) if donate else (3,)),
             out_shardings=(piece_host,) * 3)
 
@@ -1934,7 +1959,8 @@ class DeepSpeedEngine:
         # like the fused path's state_shardings — without this the split
         # tail's scalars ride default placement and their avals diverge
         # from the fused state on a multi-device mesh
-        tail_jit = jax.jit(tail_fn, out_shardings=dev)
+        tail_jit = jax.jit(_named("offload_split_tail", tail_fn),
+                           out_shardings=dev)
 
         def update_split(state: TrainState, gpieces, finites, sumsqs,
                          mean_loss):
@@ -2002,11 +2028,11 @@ class DeepSpeedEngine:
     def _build_xla_offload_eval_step(self):
         module = self.module
 
-        def eval_step(state: TrainState, batch, rng):
+        def eval_step_offload_xla(state: TrainState, batch, rng):
             params = self._xla_offload_cast_up(state.master_params)
             return module.loss_fn(params, batch, rng, train=False)
 
-        return jax.jit(eval_step)
+        return jax.jit(eval_step_offload_xla)
 
     # ------------------------------------------------------------------
     # Chunked-gradient capacity mode (zero_optimization.offload_grad_chunks
@@ -2110,7 +2136,8 @@ class DeepSpeedEngine:
                     out = out + (mean_loss,)
                 return out
 
-            return jax.jit(grad_fn)
+            return jax.jit(_named("train_step_offload_chunk_grads",
+                                  grad_fn))
 
         grad_fns = [make_grad_fn(g, first=(k == 0))
                     for k, g in enumerate(groups)]
@@ -2162,7 +2189,8 @@ class DeepSpeedEngine:
                 donate=not delayed)
         else:
             update_jit = jax.jit(
-                update_fn, donate_argnums=(() if delayed else (0,)),
+                _named("train_step_offload_chunk_update", update_fn),
+                donate_argnums=(() if delayed else (0,)),
                 out_shardings=(state_shardings, dev))
         self._xla_dpu_update = update_jit if delayed else None
 
@@ -2971,7 +2999,8 @@ class DeepSpeedEngine:
             # telemetry sync reuses the same synced wall-clock window
             prev_t = getattr(self, "_last_report", None)
             prev_step = getattr(self, "_last_report_step", 0)
-            self._report(self.last_metrics)
+            with self._tel_span("train/sync", cat="train", where="report"):
+                self._report(self.last_metrics)
             self._flush_tensorboard()
             if self.telemetry is not None:
                 self._telemetry_sync(prev_t, prev_step)
@@ -3240,7 +3269,7 @@ class DeepSpeedEngine:
         def span(name, cat="runtime", **args):
             eng = eng_ref()
             if eng is None:
-                return contextlib.nullcontext()
+                return tracing.span(None, name, cat, **args)
             return eng._tel_span(name, cat=cat, **args)
 
         pf = DevicePrefetcher(
@@ -3626,7 +3655,10 @@ class DeepSpeedEngine:
     def last_metrics(self) -> Optional[StepMetrics]:
         if self._last_metrics is None and \
                 getattr(self, "_last_packed", None) is not None:
-            vec = np.asarray(self._last_packed)
+            with self._tel_span("train/sync", cat="train",
+                                where="last_metrics"):
+                # the host waiting for the device: a span of its own
+                vec = np.asarray(self._last_packed)
             self._last_metrics = StepMetrics(
                 loss=vec[0], grad_norm=vec[1], loss_scale=vec[2],
                 overflow=bool(vec[3] > 0.5), lr=vec[4])

@@ -36,6 +36,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..parallel.mesh import DATA_AXIS
+from . import precision
 
 
 def _leaf_shape(leaf) -> tuple:
@@ -250,13 +251,29 @@ class ZeroShardingPlan:
                             is_leaf=lambda x: isinstance(x, P))
 
 
+# The two places where a ZeRO placement turns into a collective inside
+# the jitted step each sit under a ``jax.named_scope``; the scope lands
+# in the ``op_name`` of the operations the partitioner derives from them
+# (xprof's op profile groups by it; docs/observability.md).
+
+def cast_for_compute(master, compute_dtype):
+    """The step's read of the master copy (data-sharded from stage 1): the
+    cast to compute dtype, which is what XLA all-gathers for the forward
+    — scope ``zero_gather``."""
+    with jax.named_scope("zero_gather"):
+        return precision.cast_to_compute(master, compute_dtype)
+
+
 def constrain_grads(grads, plan: ZeroShardingPlan):
-    """Apply the stage>=2 reduce-scatter constraint inside the jitted step."""
+    """Apply the stage>=2 reduce-scatter constraint inside the jitted
+    step — scope ``zero_scatter``."""
     if plan.stage < 2 or plan.dp <= 1:
         return grads
     specs = plan.grad_specs(grads)
     spec_leaves = jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, P))
     grad_leaves, treedef = jax.tree.flatten(grads)
-    out = [jax.lax.with_sharding_constraint(g, NamedSharding(plan.mesh, s))
-           for g, s in zip(grad_leaves, spec_leaves)]
+    with jax.named_scope("zero_scatter"):
+        out = [jax.lax.with_sharding_constraint(
+                   g, NamedSharding(plan.mesh, s))
+               for g, s in zip(grad_leaves, spec_leaves)]
     return jax.tree.unflatten(treedef, out)

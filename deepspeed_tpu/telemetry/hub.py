@@ -27,6 +27,7 @@ from .compile_monitor import CompileMonitor
 from .exporters import JsonlExporter, SummaryWriterBridge, write_prometheus
 from .memory import MemorySampler
 from .registry import MetricsRegistry
+from . import tracing
 from .tracing import TraceRecorder
 
 EVENTS_FILE = "events.jsonl"
@@ -117,11 +118,9 @@ class TelemetryHub:
         return self.compile_monitor.track(name, fn)
 
     def span(self, name: str, cat: str = "runtime", **args):
-        """Context manager; a no-op context when tracing is disabled."""
-        if self.tracer is None:
-            import contextlib
-            return contextlib.nullcontext()
-        return self.tracer.span(name, cat, **args)
+        """Context manager: a profiler annotation, and a ``trace.json``
+        event unless tracing is disabled."""
+        return tracing.span(self.tracer, name, cat, **args)
 
     # -- at the engine's existing sync points ---------------------------
     def on_sync(self, step: int, *, interval_s: Optional[float] = None,

@@ -1,4 +1,4 @@
-"""Span tracing → Chrome/Perfetto trace-event JSON.
+"""Span tracing → Chrome/Perfetto trace-event JSON + profiler annotations.
 
 Spans are HOST-side intervals: ``span()`` stamps ``time.perf_counter``
 at enter/exit and appends one complete ("ph": "X") event — no device
@@ -10,6 +10,16 @@ per-step dispatch spans are read against (the same discipline as
 ``engine._report``; see docs/observability.md).  Unlike the
 ``wall_clock_breakdown`` timers, tracing never adds a
 ``block_until_ready`` to the step path.
+
+Every span is ALSO a ``jax.profiler.TraceAnnotation`` for its life:
+whenever an xplane session runs (the engine's ``profiler`` block, the
+benchmark's ``--trace 1``, an operator's ``start_trace``) the span is an
+event on ``/host:CPU``, on the thread that did the work and on the
+device planes' clock; with no session the annotation is a flag check.
+The module-level :func:`span` is the ONE door the engines' span helpers
+go through: with a recorder it records and annotates, with ``None``
+(telemetry off) it only annotates — so a traced run holds the program's
+spans whether or not telemetry is on.
 
 The exported file loads in ``chrome://tracing`` / Perfetto and in
 ``json.loads`` — every event carries ``ph``/``ts``/``name`` (the
@@ -30,12 +40,13 @@ open at shutdown so an aborted run's arrows don't dangle.
 """
 from __future__ import annotations
 
-import contextlib
 import itertools
 import json
 import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 _ids = itertools.count(1)
 
@@ -104,30 +115,63 @@ class AsyncSpan:
 
 
 class SpanHandle:
-    """An open span; ``end()`` closes it (idempotent).  Used where a
-    ``with`` block cannot bracket the interval — e.g. a span opened at
-    dispatch and closed at the next periodic sync."""
+    """An open span; ``end()`` closes it (idempotent).  A context
+    manager, and also usable where a ``with`` block cannot bracket the
+    interval — e.g. a span opened at dispatch and closed at the next
+    periodic sync.
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_start", "_done")
+    The span is a profiler annotation from construction to ``end()``
+    (it takes its args when it opens) and, when ``tracer`` is a
+    :class:`TraceRecorder`, one complete event in ``trace.json``.
+    ``tracer=None`` is the telemetry-off form: annotation only."""
 
-    def __init__(self, tracer: "TraceRecorder", name: str, cat: str,
-                 args: Optional[Dict[str, Any]]):
+    __slots__ = ("_tracer", "name", "cat", "args", "_start", "_done",
+                 "_annotation")
+
+    def __init__(self, tracer: Optional["TraceRecorder"], name: str,
+                 cat: str, args: Optional[Dict[str, Any]]):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.args = args
-        self._start = tracer._now_us()
         self._done = False
+        self._start = tracer._now_us() if tracer is not None else 0.0
+        self._annotation = TraceAnnotation(name, **(args or {}))
+
+    def note(self, **args):
+        """Args known only once the work is done (counts at the span's
+        far boundary).  They reach the recorder's event; the annotation
+        took its args when it opened."""
+        if self._tracer is not None:
+            self.args = {**(self.args or {}), **args}
 
     def end(self, **extra_args):
         if self._done:
             return
         self._done = True
+        self._annotation.__exit__(None, None, None)
+        tracer = self._tracer
+        if tracer is None:
+            return
         args = dict(self.args or {})
         args.update(extra_args)
-        self._tracer._emit_complete(self.name, self.cat, self._start,
-                                    self._tracer._now_us() - self._start,
-                                    args or None)
+        tracer._emit_complete(self.name, self.cat, self._start,
+                              tracer._now_us() - self._start,
+                              args or None)
+
+    def __enter__(self) -> "SpanHandle":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end()
+
+
+def span(tracer: Optional["TraceRecorder"], name: str,
+         cat: str = "runtime", **args) -> SpanHandle:
+    """The one span door of both engines: a context manager that is a
+    profiler annotation always and a ``trace.json`` event when
+    ``tracer`` is a recorder (``None`` = telemetry off)."""
+    return SpanHandle(tracer, name, cat, args or None)
 
 
 class TraceRecorder:
@@ -143,6 +187,9 @@ class TraceRecorder:
         self._events: List[dict] = []
         self._dropped = 0
         self._origin = time.perf_counter()
+        #: the unix time of ts 0, exported so trace.json can be laid
+        #: beside an xplane (whose clock is the unix epoch's)
+        self._origin_unix_ns = time.time_ns()
         self.pid = pid
         self.process_name = process_name
         self.max_events = max_events
@@ -185,13 +232,8 @@ class TraceRecorder:
             ev["args"] = args
         self._append(ev)
 
-    @contextlib.contextmanager
-    def span(self, name: str, cat: str = "runtime", **args):
-        handle = SpanHandle(self, name, cat, args or None)
-        try:
-            yield handle
-        finally:
-            handle.end()
+    def span(self, name: str, cat: str = "runtime", **args) -> SpanHandle:
+        return SpanHandle(self, name, cat, args or None)
 
     def begin(self, name: str, cat: str = "runtime", **args) -> SpanHandle:
         return SpanHandle(self, name, cat, args or None)
@@ -297,9 +339,10 @@ class TraceRecorder:
                  "tid": 0, "ts": 0,
                  "args": {"name": self.process_name}}]
         payload = {"traceEvents": meta + events,
-                   "displayTimeUnit": "ms"}
+                   "displayTimeUnit": "ms",
+                   "otherData": {"origin_unix_ns": self._origin_unix_ns}}
         if dropped:
-            payload["otherData"] = {"dropped_events": dropped}
+            payload["otherData"]["dropped_events"] = dropped
         with open(path, "w") as f:
             json.dump(payload, f)
         return path

@@ -11,7 +11,7 @@
   lora-off token for token, int8-base + fp16-adapter composition,
   dp2×tp2 == single device,
 * the zero-recompile contract over waves mixing >= 8 tenants
-  (`recompiles_total{program=decode_step}` == 0, one cache entry),
+  (`recompiles_total{program=serve_decode}` == 0, one cache entry),
 * park-on-adapter-dry admission ordering,
 * cross-tenant prefix-cache isolation — tenant A never hits tenant
   B's pages; the no-lora namespace stays the pre-change digest chain,
@@ -377,7 +377,7 @@ def test_lora_dp2_tp2_matches_single_device():
 def test_zero_recompiles_across_eight_tenant_waves(tmp_path):
     """Waves mixing >= 8 distinct tenants (cold faults, hits, and
     evictions included) never grow the compiled-program caches:
-    ``recompiles_total{program=decode_step}`` stays 0."""
+    ``recompiles_total{program=serve_decode}`` stays 0."""
     model, params = _model_params()
     eng = ServeEngine(model, _lora_cfg(
         hbm_slots=3, telemetry_path=tmp_path), params=params)
@@ -392,8 +392,8 @@ def test_zero_recompiles_across_eight_tenant_waves(tmp_path):
     assert eng._prefill_fn._cache_size() == 1
     reg = eng.telemetry.registry
     assert reg.counter("recompiles_total").value(
-        program="decode_step") == 0
-    assert reg.counter("recompiles_total").value(program="prefill") == 0
+        program="serve_decode") == 0
+    assert reg.counter("recompiles_total").value(program="serve_prefill") == 0
     assert eng.adapters.evictions > 0     # the waves churned the pool
     eng.close()
 

@@ -76,21 +76,32 @@ def _sds(shape, dtype=BF16):
 # flash attention, forward and backward, at the train smoke's shape
 # ---------------------------------------------------------------------------
 
-def test_flash_forward_compiles(one_chip):
-    qkv = [_sds((16, 12, SEQ, DH))] * 3
-    _compile(lambda q, k, v: flash_attention(q, k, v, causal=True,
-                                             interpret=False),
-             one_chip, *qkv)
+def _flash_fwd(one_chip, rows=16):
+    qkv = [_sds((rows, 12, SEQ, DH))] * 3
+    return _compile(lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                                    interpret=False),
+                    one_chip, *qkv)
 
 
-def test_flash_backward_compiles(one_chip):
-    qkv = [_sds((16, 12, SEQ, DH))] * 3
+def _flash_bwd(one_chip, rows=16, wrap=lambda f: f):
+    """``wrap=jax.checkpoint`` differentiates as the models do; the
+    default is the bare ``jax.grad``."""
+    qkv = [_sds((rows, 12, SEQ, DH))] * 3
 
     def loss(q, k, v):
         return flash_attention(q, k, v, causal=True,
                                interpret=False).astype(jnp.float32).sum()
 
-    _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, *qkv)
+    return _compile(jax.grad(wrap(loss), argnums=(0, 1, 2)), one_chip,
+                    *qkv)
+
+
+def test_flash_forward_compiles(one_chip):
+    _flash_fwd(one_chip)
+
+
+def test_flash_backward_compiles(one_chip):
+    _flash_bwd(one_chip)
 
 
 def _flash_grad_on_four_chips(topo, attend, *, dp=1, sp=1, tp=1):
@@ -155,18 +166,19 @@ def _paged_shapes(heads, page_len, quant):
 
 def _slot(heads, one_chip):
     cache = _sds((SLOTS, heads, SEQ, DH))
-    _compile(lambda q, k, v, n: decode_attention(q, k, v, n,
-                                                 interpret=False),
-             one_chip, _sds((SLOTS, heads, DH)), cache, cache,
-             _sds((SLOTS,), jnp.int32))
+    return _compile(
+        lambda q, k, v, n: decode_attention(q, k, v, n, interpret=False),
+        one_chip, _sds((SLOTS, heads, DH)), cache, cache,
+        _sds((SLOTS,), jnp.int32))
 
 
 def _multi(heads, one_chip, w=5):
     cache = _sds((SLOTS, heads, SEQ, DH))
-    _compile(lambda q, k, v, n: decode_attention_multi(q, k, v, n,
-                                                       interpret=False),
-             one_chip, _sds((SLOTS, heads, w, DH)), cache, cache,
-             _sds((SLOTS, w), jnp.int32))
+    return _compile(
+        lambda q, k, v, n: decode_attention_multi(q, k, v, n,
+                                                  interpret=False),
+        one_chip, _sds((SLOTS, heads, w, DH)), cache, cache,
+        _sds((SLOTS, w), jnp.int32))
 
 
 def _paged(heads, one_chip, page_len, quant=False):
@@ -176,8 +188,9 @@ def _paged(heads, one_chip, page_len, quant=False):
         kw = dict(zip(("k_scale", "v_scale"), scales))
         return decode_attention_paged(q, k, v, t, n, interpret=False, **kw)
 
-    _compile(fn, one_chip, _sds((SLOTS, heads, DH)), pool, pool, table,
-             _sds((SLOTS,), jnp.int32), *([scale, scale] if quant else []))
+    return _compile(
+        fn, one_chip, _sds((SLOTS, heads, DH)), pool, pool, table,
+        _sds((SLOTS,), jnp.int32), *([scale, scale] if quant else []))
 
 
 def _paged_multi(heads, one_chip, page_len, quant=False, w=5):
@@ -188,8 +201,9 @@ def _paged_multi(heads, one_chip, page_len, quant=False, w=5):
         return decode_attention_paged_multi(q, k, v, t, n, interpret=False,
                                             **kw)
 
-    _compile(fn, one_chip, _sds((SLOTS, heads, w, DH)), pool, pool, table,
-             _sds((SLOTS, w), jnp.int32), *([scale, scale] if quant else []))
+    return _compile(
+        fn, one_chip, _sds((SLOTS, heads, w, DH)), pool, pool, table,
+        _sds((SLOTS, w), jnp.int32), *([scale, scale] if quant else []))
 
 
 ARMS = {
@@ -229,20 +243,161 @@ def _serve_shapes():
     return model, params, pool
 
 
-@pytest.mark.parametrize("bucket", [128, 1024])
-def test_prefill_paged_compiles(bucket, one_chip):
+def _gpt2_prefill_program(one_chip, bucket=128):
     model, params, pool = _serve_shapes()
     i32 = _sds((), jnp.int32)
     with interpret_scope(False):
-        _compile(model.prefill_paged, one_chip, params,
-                 _sds((1, bucket), jnp.int32), i32, i32,
-                 _sds((MAX_PAGES,), jnp.int32), pool, pool)
+        return _compile(model.prefill_paged, one_chip, params,
+                        _sds((1, bucket), jnp.int32), i32, i32,
+                        _sds((MAX_PAGES,), jnp.int32), pool, pool)
+
+
+def _gpt2_decode_program(one_chip):
+    model, params, pool = _serve_shapes()
+    with interpret_scope(False):
+        return _compile(
+            lambda *a: model.decode_step_paged(*a, impl="pallas"),
+            one_chip, params, _sds((SLOTS,), jnp.int32), pool, pool,
+            _sds((SLOTS, MAX_PAGES), jnp.int32),
+            _sds((SLOTS,), jnp.int32), _sds((SLOTS,), jnp.bool_))
+
+
+@pytest.mark.parametrize("bucket", [128, 1024])
+def test_prefill_paged_compiles(bucket, one_chip):
+    _gpt2_prefill_program(one_chip, bucket)
 
 
 def test_decode_step_paged_compiles(one_chip):
-    model, params, pool = _serve_shapes()
+    _gpt2_decode_program(one_chip)
+
+
+# ---------------------------------------------------------------------------
+# kernel names: every Mosaic call is ``ds_<kernel>.<n>`` in the compiled
+# program, whatever JAX construct wraps it — the device trace's row names
+# and the benchmark's per-kernel shares (benchmark/metrics/*_share.*.json)
+# rest on it
+# ---------------------------------------------------------------------------
+
+def _kernel_names(compiled):
+    """Instruction names of the program's Mosaic custom calls."""
+    import re
+    return re.findall(
+        r'^\s*(?:ROOT )?%?(\S+) = [^\n]*custom_call_target="' + KERNEL + '"',
+        compiled.as_text(), flags=re.M)
+
+
+def _sparse(one_chip, grad):
+    import numpy as np
+    from deepspeed_tpu.ops.pallas.block_sparse_attention import \
+        block_sparse_attention
+    block, nb, heads = 128, 4, 4
+    layout = np.tril(np.ones((heads, nb, nb), np.int32))
+
+    def fwd(q, k, v):
+        return block_sparse_attention(q, k, v, layout, block,
+                                      interpret=False)
+
+    def loss(q, k, v):
+        return fwd(q, k, v).astype(jnp.float32).sum()
+
+    qkv = [_sds((2, heads, block * nb, DH))] * 3
+    return _compile(
+        jax.grad(jax.checkpoint(loss), argnums=(0, 1, 2)) if grad else fwd,
+        one_chip, *qkv)
+
+
+def _flash_bwd_remat(one_chip):
+    """Differentiated as the models do it, under ``jax.checkpoint``: a
+    transformation names what it traces DIRECTLY after itself
+    (``jvp(ds_flash_fwd)``), and any closed jaxpr between the two
+    (remat, a layer scan, shard_map, cond) keeps the kernel's own name
+    innermost."""
+    return _flash_bwd(one_chip, 4, wrap=jax.checkpoint)
+
+
+def _arm(arm):
+    return lambda one_chip: ARMS[arm](25, one_chip)
+
+
+KERNEL_CASES = {
+    # constant name -> (its module under ops/pallas, program builder)
+    "DECODE_ATTN_KERNEL": ("decode_attention", _arm("slot")),
+    "PAGED_DECODE_ATTN_KERNEL": ("decode_attention", _arm("paged16")),
+    "PAGED_DECODE_ATTN_INT8_KERNEL":
+        ("decode_attention", _arm("int8_paged16")),
+    "DECODE_ATTN_MULTI_KERNEL": ("decode_attention", _arm("multi")),
+    "PAGED_DECODE_ATTN_MULTI_KERNEL":
+        ("decode_attention", _arm("paged_multi16")),
+    "PAGED_DECODE_ATTN_MULTI_INT8_KERNEL":
+        ("decode_attention", _arm("int8_paged_multi64")),
+    "FLASH_FWD_KERNEL": ("flash_attention", lambda c: _flash_fwd(c, 4)),
+    "FLASH_BWD_DQ_KERNEL": ("flash_attention", _flash_bwd_remat),
+    "FLASH_BWD_DKV_KERNEL": ("flash_attention", _flash_bwd_remat),
+    "SPARSE_FWD_KERNEL":
+        ("block_sparse_attention", lambda c: _sparse(c, grad=False)),
+    "SPARSE_BWD_DQ_KERNEL":
+        ("block_sparse_attention", lambda c: _sparse(c, grad=True)),
+    "SPARSE_BWD_DKV_KERNEL":
+        ("block_sparse_attention", lambda c: _sparse(c, grad=True)),
+}
+
+
+@pytest.mark.parametrize("constant", sorted(KERNEL_CASES))
+def test_kernel_carries_its_name(constant, one_chip):
+    """The compiled program holds a Mosaic call whose instruction name
+    starts with the kernel's module-level constant, and the constant
+    carries the common ``ds_`` prefix."""
+    import importlib
+    module, build = KERNEL_CASES[constant]
+    name = getattr(importlib.import_module(
+        "deepspeed_tpu.ops.pallas." + module), constant)
+    assert name.startswith("ds_")
+    names = _kernel_names(build(one_chip))
+    assert names, "no Mosaic call found in the program text"
+    # exact name up to the compiler's ``.<n>`` suffix: the int8 and
+    # multi arms extend the base names, so a prefix test would let the
+    # wrong body pass
+    assert any(n.split(".")[0] == name for n in names), names
+
+
+def test_bare_grad_wraps_the_kernel_name(one_chip):
+    """The known limit, pinned: with no closed jaxpr between ``jax.grad``
+    and the kernel, the instruction is named after the transformation
+    and the benchmark's ``unnamed_kernel_share.*`` counts it.  Every
+    model path has a layer scan, remat or shard_map in between."""
+    names = _kernel_names(_flash_bwd(one_chip, 4))
+    assert sorted(n.split(".")[0] for n in names) == [
+        "jvp_ds_flash_fwd_", "transpose_jvp_ds_flash_bwd_dkv__",
+        "transpose_jvp_ds_flash_bwd_dq__"], names
+
+
+def _gpt2_train_program(one_chip):
+    """Forward + backward of GPT-2 124M's widths through the flash
+    kernel, two layers deep under remat and the layer scan (the scan
+    body compiles once, so depth adds nothing to check)."""
+    import dataclasses
+    model = GPT2Model(dataclasses.replace(GPT2_124M, n_layer=2))
+    params = jax.tree.map(lambda s: _sds(s.shape),
+                          jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+
+    def step(params, tokens, rng):
+        return jax.value_and_grad(model.loss_fn)(params, tokens, rng)
+
     with interpret_scope(False):
-        _compile(lambda *a: model.decode_step_paged(*a, impl="pallas"),
-                 one_chip, params, _sds((SLOTS,), jnp.int32), pool, pool,
-                 _sds((SLOTS, MAX_PAGES), jnp.int32),
-                 _sds((SLOTS,), jnp.int32), _sds((SLOTS,), jnp.bool_))
+        return _compile(step, one_chip, params,
+                        _sds((4, SEQ + 1), jnp.int32),
+                        _sds((2,), jnp.uint32))
+
+
+@pytest.mark.parametrize("program", ["serve_decode", "serve_prefill",
+                                     "train"])
+def test_gpt2_programs_hold_no_unnamed_kernel(program, one_chip):
+    """Inside scan, remat, cond and custom_vjp alike, no Mosaic call of
+    the GPT-2 programs is named after a JAX construct (``closed_call``,
+    ``checkpoint``, ``branch_1_fun``, ...): each starts with ``ds_``."""
+    build = {"serve_decode": _gpt2_decode_program,
+             "serve_prefill": _gpt2_prefill_program,
+             "train": _gpt2_train_program}[program]
+    names = _kernel_names(build(one_chip))
+    assert names
+    assert all(n.startswith("ds_") for n in names), names

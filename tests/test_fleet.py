@@ -1082,7 +1082,7 @@ def test_e2e_single_replica_fleet_matches_bare_engine(tmp_path):
         with open(prom) as f:
             for line in f:
                 if line.startswith("recompiles_total") \
-                        and "decode_step" in line:
+                        and "serve_decode" in line:
                     assert float(line.rsplit(None, 1)[1]) == 0.0
 
 
@@ -1235,7 +1235,8 @@ def test_e2e_disagg_stream_parity_and_custody_ledger(tmp_path):
             with open(prom) as f:
                 for line in f:
                     if line.startswith("recompiles_total") and (
-                            "prefill" in line or "decode_step" in line):
+                            "serve_prefill" in line
+                            or "serve_decode" in line):
                         assert float(line.rsplit(None, 1)[1]) == 0.0, \
                             line
 

@@ -255,7 +255,7 @@ def test_serve_mixed_load_zero_recompiles(tmp_path):
     """THE acceptance bar: one compiled decode program survives an
     arbitrary request mix — varying prompt lengths, generation lengths,
     admissions and evictions interleaved — with zero recompiles,
-    asserted via recompiles_total{program=decode_step}."""
+    asserted via recompiles_total{program=serve_decode}."""
     eng = ServeEngine(GPT2Model(TINY), _serve_cfg(
         slots=3, telemetry_path=tmp_path))
     rng = np.random.default_rng(7)
@@ -269,8 +269,8 @@ def test_serve_mixed_load_zero_recompiles(tmp_path):
     assert all(r.error is None for r in reqs)
     eng.telemetry.compile_monitor.sample()
     reg = eng.telemetry.registry
-    assert reg.counter("recompiles_total").value(program="decode_step") == 0
-    assert reg.counter("recompiles_total").value(program="prefill") == 0
+    assert reg.counter("recompiles_total").value(program="serve_decode") == 0
+    assert reg.counter("recompiles_total").value(program="serve_prefill") == 0
     assert eng._decode_fn._cache_size() == 1
     assert reg.counter("serve_requests_total").value() == len(reqs)
     eng.close()

@@ -274,7 +274,7 @@ def test_paged_zero_recompiles_mixed_page_count_waves(tmp_path):
     assert all(r.error is None for r in reqs)
     eng.telemetry.compile_monitor.sample()
     reg = eng.telemetry.registry
-    for prog in ("decode_step", "prefill", "copy_page"):
+    for prog in ("serve_decode", "serve_prefill", "serve_copy_page"):
         assert reg.counter("recompiles_total").value(program=prog) == 0
     assert eng._decode_fn._cache_size() == 1
     assert eng._prefill_fn._cache_size() == 1
@@ -370,7 +370,7 @@ def test_chunked_prefill_zero_recompiles_mixed_lengths(tmp_path):
     assert all(r.error is None for r in reqs)
     eng.telemetry.compile_monitor.sample()
     reg = eng.telemetry.registry
-    for prog in ("decode_step", "prefill", "copy_page"):
+    for prog in ("serve_decode", "serve_prefill", "serve_copy_page"):
         assert reg.counter("recompiles_total").value(program=prog) == 0
     assert eng._prefill_fn._cache_size() == 1
     assert eng._decode_fn._cache_size() == 1

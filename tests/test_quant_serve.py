@@ -338,7 +338,8 @@ def test_quant_weights_unpaged_engine():
 def test_quant_zero_recompiles_mixed_waves(tmp_path):
     """Acceptance bar: the quantized programs compile ONCE across
     waves of mixed page counts / lengths — recompiles_total == 0 and
-    jit cache size 1 for decode_step, prefill and copy_page."""
+    jit cache size 1 for serve_decode, serve_prefill and
+    serve_copy_page."""
     eng = ServeEngine(GPT2Model(TINY), _serve_cfg(
         slots=3, page_len=8, telemetry_path=tmp_path,
         quantization={"weights": "int8", "kv": "int8"}))
@@ -354,7 +355,7 @@ def test_quant_zero_recompiles_mixed_waves(tmp_path):
     assert all(r.error is None for r in reqs)
     eng.telemetry.compile_monitor.sample()
     reg = eng.telemetry.registry
-    for prog in ("decode_step", "prefill", "copy_page"):
+    for prog in ("serve_decode", "serve_prefill", "serve_copy_page"):
         assert reg.counter("recompiles_total").value(program=prog) == 0
     assert eng._decode_fn._cache_size() == 1
     assert eng._prefill_fn._cache_size() == 1

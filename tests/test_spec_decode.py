@@ -423,9 +423,9 @@ def test_spec_zero_recompiles_and_telemetry(tmp_path):
     assert out == _ref_stream("unpaged")
     reg = eng.telemetry.registry
     assert reg.counter("recompiles_total").value(
-        program="verify_step") == 0
+        program="serve_verify") == 0
     assert reg.counter("recompiles_total").value(
-        program="draft_propose") == 0
+        program="serve_draft_propose") == 0
     assert eng._verify_fn._cache_size() == 1
     assert eng._propose_fn._cache_size() == 1
     proposed = reg.counter("serve_spec_proposed_total").value()

@@ -257,6 +257,17 @@ class ServeEngine:
             #: hold the scratch page (a valid index, masked data)
             self._table = np.zeros((self.slots, self.max_pages),
                                    np.int32)
+            #: pages a grid step of the decode kernel attends (the fp
+            #: single-query Pallas arm takes a block of several; every
+            #: other arm steps page by page)
+            self._pages_per_block = 1
+            if (self.decode_impl == "pallas" and not self.quant_kv
+                    and not self.spec_k):
+                from ..ops.pallas.decode_attention import \
+                    paged_pages_per_block
+                self._pages_per_block = paged_pages_per_block(
+                    mcfg.n_head, self.page_len, mcfg.d_head,
+                    jnp.dtype(kv_dtype).itemsize, self.max_pages)
         else:
             self.pool = None
             self.prefix = None
@@ -1752,10 +1763,23 @@ class ServeEngine:
                             req.pages.append(p)
             tokens = np.zeros((self.slots,), np.int32)
             active = np.zeros((self.slots,), bool)
+            live_pages = live_blocks = 0
             for slot, req in active_map.items():
                 tokens[slot] = req.last_token
                 active[slot] = True
-            sp.note(active=len(active_map))
+                if self.paged:
+                    live_pages += len(req.pages)
+                    live_blocks += -(-len(req.pages)
+                                     // self._pages_per_block)
+            notes = {"active": len(active_map)}
+            if self.paged:
+                # the reach of the kernel's grid: blocks of pages that
+                # hold live keys / blocks it steps through, a head group
+                grid = self.slots * -(-self.max_pages
+                                      // self._pages_per_block)
+                notes.update(live_pages=live_pages,
+                             page_blocks=f"{live_blocks}/{grid}")
+            sp.note(**notes)
             return active_map, tokens, active
 
     def _flow_step_tick(self, active_map) -> None:

@@ -7,8 +7,8 @@ every tick and therefore must be TRACED — no static length may leak
 into the program or the one-compiled-decode-program contract
 (docs/serving.md, jaxlint JL005) is gone.
 
-Layout: the cache is slot-major ``[S, H, T, Dh]`` and the kernel runs a
-``(S·H, k_blocks)`` grid — each grid row streams one (slot, head)'s key
+Layout: the slot cache is slot-major ``[S, H, T, Dh]`` and its kernel
+runs a ``(S·H, k_blocks)`` grid — each grid row streams one (slot, head)'s key
 blocks through VMEM with the same online-softmax accumulator as the
 training kernel.  The single query travels as an 8-row sublane
 broadcast (TPU block shapes need (8, 128k) tiles — the lse trick from
@@ -22,13 +22,41 @@ enforces, here with traced lengths.
 Compute for blocks entirely beyond a slot's length is skipped
 (``pl.when``), but their HBM->VMEM streaming is not: block index maps
 are grid-index functions and cannot read traced lengths, so a short
-slot still pays full-cache bandwidth.  The PAGED kernel below
-(:func:`decode_attention_paged`) closes exactly that gap with the
+slot still pays full-cache bandwidth.  The PAGED kernels below
+(:func:`decode_attention_paged`) close that gap with the
 scalar-prefetch grid of PagedAttention (PAPERS.md): the per-slot page
 table rides as a ``PrefetchScalarGridSpec`` operand, block index maps
-read it to gather the slot's pages per k-block, and a slot streams
-only the pages it owns — the KV layout becomes ``[P, H, page_len, Dh]``
-(a flat pool) instead of one ``max_seq_len`` stride per slot.
+read it to gather the slot's pages, and a slot streams only the pages
+it owns — the KV layout becomes ``[P, H, page_len, Dh]`` (a flat pool)
+instead of one ``max_seq_len`` stride per slot.
+
+The fp single-query paged arm (``ds_paged_decode_attn``, the one a
+serving engine decodes with) takes a grid step for ALL HEADS of a
+BLOCK OF PAGES: grid ``(S, max_pages / ppb)``, 256 steps a layer at
+GPT-2 XL's serving shapes where a step for each (slot, head, page) was
+51,200.  Each of the block's ``ppb`` pages is an operand of its own,
+its block index the page's table entry: the pipeline fetches a block's
+pages while the block before computes (the next slot's first block at
+a slot's end) and fetches nothing while the index repeats, as it does
+over dead table entries, which all name the scratch page.  A live step
+packs its pages into one ``[rows, 128]`` buffer a pool (two 64-wide
+keys side by side in the lanes) and attends every head in two matmuls:
+the queries ride the sublane rows (25 heads -> 32), the scores of all
+(query row, buffer row) pairs come out of one product, a precomputed
+position table keeps each head's own live keys, and the masked
+probabilities are already the block-diagonal operand of the value
+matmul.  fp32 scores, softmax and accumulation, the same validity
+floor, exact zeros for a slot of length 0; a block wholly beyond the
+slot's length runs nothing.  ``ppb`` follows from the pool's shape and
+``PAGED_KV_VMEM_BUDGET`` (:func:`paged_pages_per_block`), never from
+configuration.  Two things the chip's compiler decided (PERF.md,
+PR 27): Mosaic copies no window of an HBM array whose last dimension
+is under 128, so the pages cannot be gathered by hand-written DMA
+while a head is 64 wide; and the pool reaches the kernel as
+``[P, page_len, H, Dh]`` because that is the layout XLA gives it for
+the cache write just before — any other shape costs copies of the
+whole pool, every layer of every tick.  The int8 and multi-query paged
+arms keep the grid this one left: a step for each (slot, head, page).
 
 ``impl='dense'`` is the interpretable reference fallback on both
 entry points: the same masking semantics in plain jnp (the paged arm
@@ -318,17 +346,222 @@ PAGED_DECODE_ATTN_KERNEL = "ds_paged_decode_attn"
 #: the fused-dequant arm is another kernel body (two more operands)
 PAGED_DECODE_ATTN_INT8_KERNEL = PAGED_DECODE_ATTN_KERNEL + "_int8"
 
+#: VMEM the fp paged kernel spends on the K and V pages of a block: the
+#: page blocks in flight (two pools, double-buffered by the pipeline)
+#: and the two buffers they are packed into.  Three eighths of the
+#: 16 MiB a Mosaic kernel may use by default on a v5e; the scores and
+#: probabilities of a block, as wide as those buffers are long, take
+#: about a third as much again.
+PAGED_KV_VMEM_BUDGET = 6 * 1024 * 1024
+_LANES = 128
 
-def _decode_paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
-                         sm_scale: float, page_len: int, heads: int):
-    # fused-dequant arm (int8 pages): two extra scale-tile refs ride
-    # between the pool blocks and the output.  The python-level branch
-    # keeps the fp arm's trace byte-identical to the pre-quant kernel.
-    quant = len(rest) > 4
-    if quant:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        o_ref, m_scr, l_scr, acc_scr = rest
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _paged_block_layout(heads, page_len, head_dim, itemsize):
+    """How a page sits in the kernel's packed buffer: ``fold`` keys side
+    by side in a row's lanes (two 64-wide keys fill the 128 lanes the
+    MXU contracts over), the heads of one key group on ``head_rows``
+    sublane rows (a whole number of VMEM tiles, so every store is
+    aligned).  Returns (fold, head_rows, rows a page, lanes a row)."""
+    fold = _LANES // head_dim if head_dim < _LANES else 1
+    if page_len % fold:
+        fold = 1
+    head_rows = _round_up(heads, 8 * 4 // itemsize)
+    return fold, head_rows, page_len // fold * head_rows, fold * head_dim
+
+
+def paged_page_vmem_bytes(heads: int, page_len: int, head_dim: int,
+                          itemsize: int) -> int:
+    """VMEM one page of a block costs the fp paged kernel: its K and V
+    blocks in flight, double-buffered, and its rows of the two packed
+    buffers (lanes padded to 128 in both)."""
+    _, head_rows, rows, width = _paged_block_layout(
+        heads, page_len, head_dim, itemsize)
+    in_flight = page_len * head_rows * _round_up(head_dim, _LANES)
+    packed = rows * _round_up(width, _LANES)
+    return (4 * in_flight + 2 * packed) * itemsize
+
+
+def paged_pages_per_block(heads: int, page_len: int, head_dim: int,
+                          itemsize: int, max_pages: int) -> int:
+    """Pages one grid step of the fp paged kernel attends: the largest
+    power of two that fits ``PAGED_KV_VMEM_BUDGET``, at most
+    ``max_pages``.  A function of the pool's shape alone."""
+    page_bytes = paged_page_vmem_bytes(heads, page_len, head_dim, itemsize)
+    fit = max(1, min(PAGED_KV_VMEM_BUDGET // page_bytes, max_pages))
+    return 1 << (fit.bit_length() - 1)
+
+
+@functools.lru_cache(maxsize=None)
+def _paged_block_positions(heads, page_len, ppb, fold, head_rows):
+    """``[fold*head_rows, ppb*rows]`` int32: where in its block (0 ..
+    ppb*page_len-1) the key sits whose score a (query row, buffer row)
+    pair is.  Query row ``a*head_rows + h`` carries head ``h``'s query
+    in lane group ``a`` and zeros elsewhere; buffer row ``(i, r, h')``
+    holds head ``h'``'s keys ``r*fold .. r*fold+fold-1`` of page ``i``,
+    one a lane group.  So the pair is key ``r*fold + a`` of page ``i`` where
+    ``h' == h``; every other pair, the padding heads and the padding
+    rows get a position no length reaches."""
+    import numpy as np
+    groups = page_len // fold
+    pos = np.full((fold, head_rows, ppb, groups, head_rows), 2 ** 30,
+                  np.int32)
+    h = np.arange(heads)
+    for a in range(fold):
+        for i in range(ppb):
+            for r in range(groups):
+                pos[a, h, i, r, h] = i * page_len + r * fold + a
+    return pos.reshape(fold * head_rows, -1)
+
+
+def _decode_paged_kernel(pt_ref, len_ref, q_ref, pos_ref, *refs,
+                         sm_scale: float, ppb: int):
+    """One grid step = one slot, ALL heads, ``ppb`` pages, each page an
+    operand of its own whose block index the page table gives: the
+    pipeline fetches a block's pages while the block before computes,
+    and fetches nothing while the index repeats (dead table entries all
+    name the scratch page).  A block wholly beyond the slot's length
+    runs nothing."""
+    k_refs, v_refs = refs[:ppb], refs[ppb:2 * ppb]
+    o_ref, k_buf, v_buf, m_scr, l_scr, acc_scr = refs[2 * ppb:]
+    s, j = pl.program_id(0), pl.program_id(1)
+    nb = pl.num_programs(1)
+    _, hp, head_dim = o_ref.shape   # the heads, padded to whole tiles
+    fold = q_ref.shape[1] // hp
+    _, page_len, heads, _ = k_refs[0].shape
+    groups = page_len // fold
+    length = len_ref[s]
+
+    @pl.when((s == 0) & (j == 0))
+    def _clear():
+        # no page is ever packed into the rows between the heads of two
+        # key groups: whatever VMEM held there would reach the value
+        # matmul times 0
+        v_buf[:] = jnp.zeros_like(v_buf)
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(j * ppb * page_len < length)
+    def _live():
+        # a page arrives as [page_len, H, Dh]; ``fold`` keys of a head
+        # go side by side into one row of the packed buffer
+        for refs_, buf in ((k_refs, k_buf), (v_refs, v_buf)):
+            for i, ref in enumerate(refs_):
+                for r in range(groups):
+                    at = (i * groups + r) * hp
+                    buf[at:at + heads, :] = jnp.concatenate(
+                        [ref[0, r * fold + a] for a in range(fold)], axis=1)
+
+        # Every head in two matmuls.  Scores of all (query row, buffer
+        # row) pairs, of which pos_ref keeps a head's own live keys;
+        # the masked probabilities are then already the block-diagonal
+        # operand of the value matmul.  The MXU's spare rows cost
+        # nothing; 2*H single-row matmuls would.
+        sc = jax.lax.dot_general(
+            q_ref[0], k_buf[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale
+        sc = jnp.where(pos_ref[...] < length - j * ppb * page_len,
+                       sc, NEG_INF)                     # [fold*hp, C]
+
+        def per_head(x, op):
+            """Combine a head's ``fold`` query rows; back on every row."""
+            parts = [x[a * hp:(a + 1) * hp] for a in range(fold)]
+            return jnp.concatenate(
+                [functools.reduce(op, parts)] * fold, axis=0)
+
+        m_prev = m_scr[:, 0:1]
+        m_new = jnp.maximum(m_prev, per_head(
+            jnp.max(sc, axis=1, keepdims=True), jnp.maximum))
+        # key j*bk of every head is live (the pl.when guard), so m_new
+        # is a real score and the masked keys' exp underflows to 0
+        p = jnp.exp(sc - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[:] = jnp.broadcast_to(
+            alpha * l_scr[:, 0:1] + per_head(
+                jnp.sum(p, axis=1, keepdims=True), jnp.add),
+            l_scr.shape)
+        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+            p.astype(v_buf.dtype), v_buf[...], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+
+    @pl.when(j == nb - 1)
+    def _finalize():
+        # query row a*hp + h gathered its keys' values in lane group a
+        acc = functools.reduce(jnp.add, [
+            acc_scr[a * hp:(a + 1) * hp, a * head_dim:(a + 1) * head_dim]
+            for a in range(fold)])
+        l = l_scr[0:hp, 0:1]
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        # length 0 -> no block ran -> l == 0 -> exact zeros (free slots)
+        o_ref[0] = jnp.where(l == 0.0, 0.0, acc / l_safe).astype(o_ref.dtype)
+
+
+def _decode_paged_pallas(q, k_pages, v_pages, page_table, lengths, *,
+                         sm_scale, interpret):
+    P, H, page_len, Dh = k_pages.shape
+    S, max_pages = page_table.shape
+    itemsize = k_pages.dtype.itemsize
+    fold, hp, rows, width = _paged_block_layout(H, page_len, Dh, itemsize)
+    ppb = paged_pages_per_block(H, page_len, Dh, itemsize, max_pages)
+    nb = -(-max_pages // ppb)
+    # dead table entries name the scratch page 0, and so does the padding
+    pt_flat = jnp.pad(page_table,
+                      ((0, 0), (0, nb * ppb - max_pages))).reshape(-1)
+    # the heads ride the sublane rows (25 -> 32), once for each of the
+    # ``fold`` keys a buffer row holds, the query in that key's lanes
+    qf = jnp.pad(q, ((0, 0), (0, hp - H), (0, 0)))
+    qf = jnp.einsum("shd,ab->sahbd", qf, jnp.eye(fold, dtype=q.dtype))
+    qf = qf.reshape(S, fold * hp, width)
+    pos = jnp.asarray(_paged_block_positions(H, page_len, ppb, fold, hp))
+
+    def page_spec(i):
+        return pl.BlockSpec(
+            (1, page_len, H, Dh),
+            lambda s, j, pt, ln: (pt[(s * nb + j) * ppb + i], 0, 0, 0))
+
+    pages = [page_spec(i) for i in range(ppb)]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(S, nb),
+        in_specs=[pl.BlockSpec((1, fold * hp, width),
+                               lambda s, j, *_: (s, 0, 0)),
+                  pl.BlockSpec(pos.shape, lambda s, j, *_: (0, 0)),
+                  *pages, *pages],
+        out_specs=pl.BlockSpec((1, hp, Dh), lambda s, j, *_: (s, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((ppb * rows, width), k_pages.dtype),
+            pltpu.VMEM((ppb * rows, width), v_pages.dtype),
+            pltpu.VMEM((fold * hp, 128), jnp.float32),
+            pltpu.VMEM((fold * hp, 128), jnp.float32),
+            pltpu.VMEM((fold * hp, width), jnp.float32),
+        ],
+    )
+    # [P, page_len, H, Dh]: the layout XLA gives the pool for the cache
+    # write just before, so the kernel's operand costs no copy of it
+    kt, vt = (x.transpose(0, 2, 1, 3) for x in (k_pages, v_pages))
+    out = pl.pallas_call(
+        functools.partial(_decode_paged_kernel, sm_scale=sm_scale, ppb=ppb),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, hp, Dh), q.dtype),
+        interpret=interpret,
+        name=PAGED_DECODE_ATTN_KERNEL,
+    )(pt_flat, lengths, qf, pos, *[kt] * ppb, *[vt] * ppb)
+    return out[:, :H]
+
+
+def _decode_paged_int8_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref,
+                              ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr,
+                              *, sm_scale: float, page_len: int, heads: int):
+    """The fused-dequant arm keeps the grid the fp arm left behind: one
+    step for each (slot, head, page), the query an 8-row broadcast."""
     jk = pl.program_id(1)
     nk = pl.num_programs(1)
     slot = pl.program_id(0) // heads
@@ -345,21 +578,17 @@ def _decode_paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
     @pl.when(jk * page_len < length)
     def _compute():
         q = q_ref[0]                                    # [8, d] broadcast
-        k = k_ref[0, 0]                                 # [page_len, d]
-        v = v_ref[0, 0]                                 # [page_len, d]
-        if quant:
-            # dequant folds into the score/prob columns: the scale is
-            # per KEY ROW, so q·(k8*sk) == (q·k8)*sk and p·(v8*sv) ==
-            # (p*sv)·v8 — the int8 page never materializes in fp
-            k = k.astype(jnp.float32)
-            v = v.astype(jnp.float32)
-            ks_row = ks_ref[0, 0][0:1, :page_len]       # [1, page_len]
-            vs_row = vs_ref[0, 0][0:1, :page_len]
+        # dequant folds into the score/prob columns: the scale is per
+        # KEY ROW, so q·(k8*sk) == (q·k8)*sk and p·(v8*sv) == (p*sv)·v8
+        # — the int8 page never materializes in fp
+        k = k_ref[0, 0].astype(jnp.float32)             # [page_len, d]
+        v = v_ref[0, 0].astype(jnp.float32)
+        ks_row = ks_ref[0, 0][0:1, :page_len]           # [1, page_len]
+        vs_row = vs_ref[0, 0][0:1, :page_len]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
-        if quant:
-            s = s * ks_row
+        s = s * ks_row
         k_ids = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) \
             + jk * page_len
         s = jnp.where(k_ids < length, s, NEG_INF)
@@ -370,9 +599,8 @@ def _decode_paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
         l_scr[:] = jnp.broadcast_to(
             alpha * l_scr[:, 0:1] + jnp.sum(p, axis=1, keepdims=True),
             l_scr.shape)
-        pv = (p * vs_row) if quant else p
         acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            pv.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            (p * vs_row).astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
 
@@ -384,36 +612,29 @@ def _decode_paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, *rest,
                              acc_scr[:] / l_safe).astype(o_ref.dtype)
 
 
-def _decode_paged_pallas(q, k_pages, v_pages, page_table, lengths, *,
-                         sm_scale, interpret, k_scale=None, v_scale=None):
+def _decode_paged_int8_pallas(q, k_pages, v_pages, page_table, lengths, *,
+                              sm_scale, interpret, k_scale, v_scale):
     P, H, page_len, Dh = k_pages.shape
     S, max_pages = page_table.shape
-    quant = k_scale is not None
     qf = jnp.broadcast_to(q.reshape(S * H, 1, Dh), (S * H, 8, Dh))
-    pt_flat = page_table.astype(jnp.int32).reshape(-1)
+    pt_flat = page_table.reshape(-1)
 
     def page_block(g, j, pt, ln, H=H, M=max_pages):
-        # THE paged move: the block for grid cell (g, j) is whatever
-        # page the slot's table names — a short slot streams only the
-        # pages it owns (plus scratch no-ops)
+        # the block for grid cell (g, j) is whatever page the slot's
+        # table names; the scale rows ride the SAME indirection as the
+        # int8 blocks they dequantize, as lane-packed (8, 128) tiles
         return (pt[(g // H) * M + j], g % H, 0, 0)
 
-    in_specs = [
-        pl.BlockSpec((1, 8, Dh), lambda g, j, pt, ln: (g, 0, 0)),
-        pl.BlockSpec((1, 1, page_len, Dh), page_block),
-        pl.BlockSpec((1, 1, page_len, Dh), page_block),
-    ]
-    operands = [qf, k_pages, v_pages]
-    if quant:
-        # the scale rows ride the SAME page-table indirection as the
-        # int8 blocks they dequantize, as lane-packed (8, 128) tiles
-        in_specs += [pl.BlockSpec((1, 1, 8, 128), page_block),
-                     pl.BlockSpec((1, 1, 8, 128), page_block)]
-        operands += [_scale_tile(k_scale), _scale_tile(v_scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(S * H, max_pages),
-        in_specs=in_specs,
+        in_specs=[
+            pl.BlockSpec((1, 8, Dh), lambda g, j, pt, ln: (g, 0, 0)),
+            pl.BlockSpec((1, 1, page_len, Dh), page_block),
+            pl.BlockSpec((1, 1, page_len, Dh), page_block),
+            pl.BlockSpec((1, 1, 8, 128), page_block),
+            pl.BlockSpec((1, 1, 8, 128), page_block),
+        ],
         out_specs=pl.BlockSpec((1, 8, Dh), lambda g, j, pt, ln: (g, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((8, 128), jnp.float32),
@@ -422,15 +643,14 @@ def _decode_paged_pallas(q, k_pages, v_pages, page_table, lengths, *,
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_decode_paged_kernel, sm_scale=sm_scale,
+        functools.partial(_decode_paged_int8_kernel, sm_scale=sm_scale,
                           page_len=page_len, heads=H),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S * H, 8, Dh),
-                                       jnp.float32 if quant else q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S * H, 8, Dh), jnp.float32),
         interpret=interpret,
-        name=(PAGED_DECODE_ATTN_INT8_KERNEL if quant
-              else PAGED_DECODE_ATTN_KERNEL),
-    )(pt_flat, lengths.astype(jnp.int32), *operands)
+        name=PAGED_DECODE_ATTN_INT8_KERNEL,
+    )(pt_flat, lengths, qf, k_pages, v_pages,
+      _scale_tile(k_scale), _scale_tile(v_scale))
     return out[:, 0, :].reshape(S, H, Dh).astype(q.dtype)
 
 
@@ -503,11 +723,14 @@ def decode_attention_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
             "or 'dense'")
     if interpret is None:
         interpret = _use_interpret()
-    return _decode_paged_pallas(q, k_pages, v_pages,
-                                page_table.astype(jnp.int32),
-                                lengths.astype(jnp.int32),
-                                sm_scale=sm_scale, interpret=interpret,
-                                k_scale=k_scale, v_scale=v_scale)
+    page_table = page_table.astype(jnp.int32)
+    lengths = lengths.astype(jnp.int32)
+    if k_scale is not None:
+        return _decode_paged_int8_pallas(
+            q, k_pages, v_pages, page_table, lengths, sm_scale=sm_scale,
+            interpret=interpret, k_scale=k_scale, v_scale=v_scale)
+    return _decode_paged_pallas(q, k_pages, v_pages, page_table, lengths,
+                                sm_scale=sm_scale, interpret=interpret)
 
 
 # ---------------------------------------------------------------------------

@@ -19,8 +19,10 @@ from jax.sharding import SingleDeviceSharding
 
 from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
 from deepspeed_tpu.ops.pallas.decode_attention import (
-    decode_attention, decode_attention_multi, decode_attention_paged,
-    decode_attention_paged_multi)
+    PAGED_DECODE_ATTN_KERNEL, PAGED_KV_VMEM_BUDGET, decode_attention,
+    decode_attention_multi, decode_attention_paged,
+    decode_attention_paged_multi, paged_page_vmem_bytes,
+    paged_pages_per_block)
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 from deepspeed_tpu.ops.pallas.runtime import interpret_scope
 
@@ -223,6 +225,27 @@ ARMS = {
 @pytest.mark.parametrize("arm", sorted(ARMS))
 def test_decode_arm_compiles(arm, heads, one_chip):
     ARMS[arm](heads, one_chip)
+
+
+#: what a Mosaic kernel may use of a v5e's VMEM by default
+V5E_SCOPED_VMEM = 16 * 1024 * 1024
+
+
+@pytest.mark.parametrize("heads", [12, 25], ids=["gpt2_124m", "gpt2_xl"])
+@pytest.mark.parametrize("page_len", [16, 64, 128])
+def test_paged_block_kernel_fits_and_keeps_its_name(page_len, heads,
+                                                    one_chip):
+    """The fp paged arm's block of pages: chosen from the shapes, its
+    pages within the module's VMEM budget (and the budget well within
+    what the chip allows: the compile is the proof), one Mosaic call,
+    and still the trace row ``paged_decode_share.*`` reads."""
+    ppb = paged_pages_per_block(heads, page_len, DH, 2, SEQ // page_len)
+    assert ppb == {(12, 16): 16, (12, 64): 4, (12, 128): 2,
+                   (25, 16): 8, (25, 64): 2, (25, 128): 1}[heads, page_len]
+    assert (ppb * paged_page_vmem_bytes(heads, page_len, DH, 2)
+            <= PAGED_KV_VMEM_BUDGET <= V5E_SCOPED_VMEM // 2)
+    names = _kernel_names(_paged(heads, one_chip, page_len))
+    assert [n.split(".")[0] for n in names] == [PAGED_DECODE_ATTN_KERNEL]
 
 
 # ---------------------------------------------------------------------------

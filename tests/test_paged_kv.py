@@ -2,8 +2,10 @@
 
 * kernel parity matrix at page-boundary-covering lengths — fp32
   BITWISE dense-paged vs the pre-page dense reference (the ``jnp.take``
-  anchor) and pallas-paged vs the pre-page pallas kernel; pallas vs
-  dense at the established kernel tolerance,
+  anchor); pallas-paged vs dense and vs the pre-page pallas kernel at
+  the established kernel tolerance, and at the edges of the kernel's
+  blocks of pages (every head count, fp32 and bf16, a shuffled table,
+  poisoned dead pages and tails),
 * token-stream identity of the paged engine vs the pre-page engine,
 * the zero-recompile contract across mixed page-count request waves,
 * prefix cache: shared-template reuse, copy-on-write of the last
@@ -32,7 +34,8 @@ from deepspeed_tpu.inference.scheduler import (PagePool, PrefixCache,
 from deepspeed_tpu.models.gpt2 import (GPT2Config, GPT2Model,
                                        gpt2_prefill, gpt2_prefill_paged)
 from deepspeed_tpu.ops.pallas.decode_attention import (
-    decode_attention, decode_attention_paged, paged_gather)
+    decode_attention, decode_attention_paged, paged_gather,
+    paged_pages_per_block)
 from deepspeed_tpu.parallel import build_mesh
 from deepspeed_tpu.runtime.stages import reset_fault_injection
 
@@ -78,9 +81,13 @@ PAGE_BOUNDARY_LENGTHS = [0, 7, 16, 2 * 16 + 5]
 def test_paged_kernel_parity_matrix():
     """fp32 parity at page-boundary lengths: dense-paged is BITWISE
     against the pre-page dense reference on the gathered layout (the
-    jnp.take anchor), pallas-paged is BITWISE against the pre-page
-    pallas kernel at the same block size, and pallas-vs-dense holds the
-    established kernel tolerance."""
+    jnp.take anchor); pallas-paged holds the established kernel
+    tolerance against dense AND against the pre-page pallas kernel at
+    the paged kernel's block size.  (The paged kernel was bitwise
+    against the pre-page one while both took a (slot, head) a grid
+    step; since it takes all heads of a block of pages in two matmuls
+    it sums the same products in another order, and the honest pin is
+    the tolerance.)"""
     S, H, page_len, max_pages, Dh = 4, 3, 16, 3, 32
     kp, vp, pt = _pool_and_table(S, H, page_len, max_pages, Dh)
     q = jnp.asarray(np.random.RandomState(1).randn(S, H, Dh), jnp.float32)
@@ -91,19 +98,90 @@ def test_paged_kernel_parity_matrix():
     # the pre-page reference arms over the SAME values, gathered dense
     kg, vg = paged_gather(kp, pt), paged_gather(vp, pt)
     ref_d = decode_attention(q, kg, vg, lengths, impl="dense")
+    ppb = paged_pages_per_block(H, page_len, Dh, 4, max_pages)
     ref_p = decode_attention(q, kg, vg, lengths, impl="pallas",
-                             interpret=True, block_k=page_len)
+                             interpret=True, block_k=ppb * page_len)
     np.testing.assert_array_equal(np.asarray(out_d), np.asarray(ref_d))
-    np.testing.assert_array_equal(np.asarray(out_p), np.asarray(ref_p))
+    np.testing.assert_allclose(out_p, ref_p, atol=2e-6, rtol=2e-6)
     np.testing.assert_allclose(out_p, out_d, atol=2e-6, rtol=2e-6)
     # free slot (length 0) outputs exact zeros on both paged arms
     assert (np.asarray(out_d[0]) == 0).all()
     assert (np.asarray(out_p[0]) == 0).all()
 
 
+# What a block of several pages can get wrong: its edges.  Lengths are
+# given in units the block defines, so every head count and dtype meets
+# its own boundaries.
+BLOCK_LENGTHS = {
+    "free": lambda page_len, bk, full: 0,
+    "one": lambda page_len, bk, full: 1,
+    "page": lambda page_len, bk, full: page_len,
+    "block_less_1": lambda page_len, bk, full: bk - 1,
+    "block": lambda page_len, bk, full: bk,
+    "block_plus_1": lambda page_len, bk, full: bk + 1,
+    "full": lambda page_len, bk, full: full,
+}
+POISON = 1e4
+
+_paged_both = jax.jit(
+    lambda q, k, v, t, n, kc, vc, tc: (
+        decode_attention_paged(q, k, v, t, n, impl="pallas", interpret=True),
+        decode_attention_paged(q, kc, vc, tc, n, impl="dense")))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["fp32", "bf16"])
+@pytest.mark.parametrize("H", [3, 12, 25])
+@pytest.mark.parametrize("which", list(BLOCK_LENGTHS))
+def test_paged_kernel_block_boundaries(which, H, dtype):
+    """The block-of-pages kernel against ``impl='dense'`` at the edges
+    of its blocks: H = 25 is not a sublane multiple, the page table is
+    shuffled and not contiguous, every page the slot does not own, the
+    scratch page and the tail of the last live page are poisoned, and
+    the table's dead entries name poisoned pages.  The neighbouring
+    slot always has two blocks and a ragged tail."""
+    page_len, Dh, S = 16, 64, 3
+    itemsize = jnp.dtype(dtype).itemsize
+    ppb = paged_pages_per_block(H, page_len, Dh, itemsize, 1 << 20)
+    max_pages = 2 * ppb + 1          # three blocks, the last one padded
+    bk, full = ppb * page_len, max_pages * page_len
+    lens = [BLOCK_LENGTHS[which](page_len, bk, full), bk + page_len + 3, 0]
+    rng = np.random.RandomState(H)
+    P = 1 + S * max_pages
+    k = rng.randn(P, H, page_len, Dh).astype(np.float32)
+    v = rng.randn(P, H, page_len, Dh).astype(np.float32)
+    table = rng.permutation(np.arange(1, P)).reshape(S, max_pages) \
+        .astype(np.int32)
+    k_bad, v_bad, t_bad = k.copy(), v.copy(), table.copy()
+    for s, n in enumerate(lens):
+        live = -(-n // page_len)
+        for pool in (k_bad, v_bad):
+            pool[table[s, live:]] = POISON           # pages beyond the end
+            if n % page_len:                         # tail of the last page
+                pool[table[s, live - 1], :, n % page_len:] = POISON
+        # dead entries: the scratch page and a poisoned page, in turn
+        if live < max_pages:
+            t_bad[s, live:] = (0, table[s, -1])[s % 2]
+    k_bad[0] = v_bad[0] = POISON
+    q = jnp.asarray(rng.randn(S, H, Dh), dtype)
+    as_dtype = lambda x: jnp.asarray(x, dtype)
+    out_p, out_d = _paged_both(
+        q, as_dtype(k_bad), as_dtype(v_bad), jnp.asarray(t_bad),
+        jnp.asarray(lens, jnp.int32), as_dtype(k), as_dtype(v),
+        jnp.asarray(table))
+    tol = 2e-6 if dtype == jnp.float32 else 1.6e-2
+    np.testing.assert_allclose(np.asarray(out_p, np.float32),
+                               np.asarray(out_d, np.float32),
+                               atol=tol, rtol=tol)
+    for s, n in enumerate(lens):
+        if n == 0:
+            assert (np.asarray(out_p[s], np.float32) == 0).all()
+
+
 def test_paged_kernel_masks_dead_pages():
-    """Garbage in pages beyond a slot's live length — and in the dead
-    table entries pointing at the scratch page — must never leak."""
+    """Garbage in pages beyond a slot's live length, in the dead table
+    entries pointing at the scratch page, and in the tail of the last
+    live page must never leak."""
     S, H, page_len, max_pages, Dh = 2, 2, 8, 3, 16
     kp, vp, pt = _pool_and_table(S, H, page_len, max_pages, Dh, seed=2)
     q = jnp.asarray(np.random.RandomState(3).randn(S, H, Dh), jnp.float32)
@@ -113,6 +191,9 @@ def test_paged_kernel_masks_dead_pages():
     poisoned_pt[:, 1:] = 0                    # dead entries -> scratch
     kp_bad = kp.at[ptn[0, 1]].set(1e4).at[0].set(-1e4)
     vp_bad = vp.at[ptn[0, 1]].set(1e4).at[0].set(-1e4)
+    # slot 0 owns 5 rows of its first page: rows 5.. are a dead tail
+    kp_bad = kp_bad.at[ptn[0, 0], :, 5:].set(1e4)
+    vp_bad = vp_bad.at[ptn[0, 0], :, 5:].set(-1e4)
     for impl in ("dense", "pallas"):
         clean = decode_attention_paged(q, kp, vp, pt, lengths, impl=impl)
         dirty = decode_attention_paged(q, kp_bad, vp_bad,
@@ -120,6 +201,71 @@ def test_paged_kernel_masks_dead_pages():
                                        lengths, impl=impl)
         np.testing.assert_array_equal(np.asarray(clean),
                                       np.asarray(dirty))
+
+
+XL_SERVING = dict(S=32, H=25, page_len=16, max_pages=64, Dh=64, P=833)
+
+
+def _paged_call_grid(S, H, page_len, max_pages, Dh, P):
+    """Grid of the Mosaic call that ``impl='pallas'`` traces."""
+    sds = jax.ShapeDtypeStruct
+    pool = sds((P, H, page_len, Dh), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(lambda *a: decode_attention_paged(
+        *a, impl="pallas", interpret=True))(
+            sds((S, H, Dh), jnp.bfloat16), pool, pool,
+            sds((S, max_pages), jnp.int32), sds((S,), jnp.int32))
+    calls = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    return tuple(calls[0].params["grid_mapping"].grid)
+
+
+def test_paged_kernel_grid_at_xl_serving_shapes():
+    """The mechanism, counted: at the benchmark's serving shapes a
+    layer's call has at most S * max_pages / ppb grid steps (the parent
+    took S * H * max_pages = 51,200), and the pages a step covers
+    follow the pool's shape, never a configuration key, an environment
+    variable or a model's name."""
+    import importlib
+    import inspect
+    module = importlib.import_module(
+        "deepspeed_tpu.ops.pallas.decode_attention")
+    x = XL_SERVING
+    ppb = paged_pages_per_block(x["H"], x["page_len"], x["Dh"], 2,
+                                x["max_pages"])
+    assert ppb == 8
+    grid = _paged_call_grid(**x)
+    assert int(np.prod(grid)) <= x["S"] * x["max_pages"] // ppb == 256
+    # fewer pages a step as a page grows, from the shapes alone
+    assert [paged_pages_per_block(25, pl_, 64, 2, 1024 // pl_)
+            for pl_ in (16, 64, 128)] == [8, 2, 1]
+    assert _paged_call_grid(**{**x, "page_len": 64, "max_pages": 16,
+                               "P": 209}) == (32, 8)
+    assert list(inspect.signature(paged_pages_per_block).parameters) == [
+        "heads", "page_len", "head_dim", "itemsize", "max_pages"]
+    source = inspect.getsource(module)
+    assert "environ" not in source and "getenv" not in source
+
+
+def test_decode_prep_span_counts_live_blocks(tmp_path):
+    """``serve/decode_prep`` notes the kernel's reach (telemetry on):
+    the pages the active slots own and, of the blocks the fp paged
+    kernel's grid steps through a head group, how many hold live keys."""
+    eng = ServeEngine(GPT2Model(TINY_FLASH), {
+        "serving": {"slots": 3, "max_seq_len": 64, "prefill_len": 32,
+                    "page_len": 8},
+        "telemetry": {"enabled": True, "output_path": str(tmp_path)}})
+    ppb = eng._pages_per_block
+    assert ppb == paged_pages_per_block(4, 8, 8, 4, 8) > 1
+    eng.submit(list(_tokens(20)), max_new_tokens=3)
+    eng.run_until_idle()
+    eng.close()
+    with open(tmp_path / "trace.json") as f:
+        events = json.load(f)["traceEvents"]
+    prep = [e["args"] for e in events if e["name"] == "serve/decode_prep"
+            and e["args"].get("active")]
+    # 20 prompt + 1-2 decoded rows: three pages of 8, one block
+    assert prep[-1]["live_pages"] == 3
+    assert prep[-1]["page_blocks"] == f"1/{3 * -(-8 // ppb)}"
 
 
 def test_paged_kernel_single_compile_across_tables():

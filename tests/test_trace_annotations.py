@@ -176,6 +176,14 @@ def test_trace_json_and_xplane_hold_the_same_spans(session):
     tick = [e for e in doc["traceEvents"] if e["name"] == "serve/tick"][-1]
     assert {"tick", "active", "queued", "pages_free", "produced",
             "admitted"} <= set(tick["args"])
+    # the decode kernel's reach rides the prepare span: 2 requests, a
+    # 5-token prompt and five ticks each, own two pages of 8 each; the
+    # toy model decodes dense, so a block is a page, of 4 slots x 8
+    prep = [e for e in doc["traceEvents"]
+            if e["name"] == "serve/decode_prep"][-1]["args"]
+    assert prep["active"] == 2
+    assert prep["live_pages"] == 4
+    assert prep["page_blocks"] == "4/32"
 
 
 def _count_spans(monkeypatch, fn):

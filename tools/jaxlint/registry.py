@@ -299,8 +299,10 @@ class ProjectRegistry:
         """Files whose text mentions ``name`` as a whole word."""
         pat = re.compile(r"(?<![A-Za-z0-9_])%s(?![A-Za-z0-9_])"
                          % re.escape(name))
+        # the substring test is the cheap necessary condition: without
+        # it 93 names x 250 files of regex are 4 of the pre-flight's 7 s
         return [rp for rp, src in sorted(self.sources.items())
-                if pat.search(src)]
+                if name in src and pat.search(src)]
 
     # -- construction ----------------------------------------------------
     @classmethod

@@ -126,20 +126,84 @@ class _ServeConfigView:
         self.stages = DeepSpeedStagesConfig(pd)
 
 
+def _auto_decode_impl(mcfg) -> str:
+    """``serving.decode_impl: auto``: the decode arm that matches the
+    model's own attention (the protocol's ``config.attn_impl``)."""
+    impl = getattr(mcfg, "attn_impl", None)
+    if impl == "flash":
+        return "pallas"
+    if impl == "dense":
+        return "dense"
+    raise NotImplementedError(
+        f"attn_impl={impl!r} has no serving decode path; serve with "
+        "'flash' or 'dense' (sequence-parallel attention shards the time "
+        "axis the decode cache does not have)")
+
+
+def _refuse_unsupported(model, serving) -> None:
+    """A model that lacks a serving arm says so in
+    ``serving_unsupported``; a configuration that asks for one is
+    refused here, not deep in a trace."""
+    lacks = getattr(model, "serving_unsupported", ())
+    quant = serving.quantization
+    arms = {    # arm: (the configuration asks for it, as the message says it)
+        "slot_cache": (serving.page_len == 0,
+                       "serving.page_len: 0 (set page_len > 0)"),
+        "speculate_k": (serving.speculate_k > 0, "serving.speculate_k > 0"),
+        "quantization": ("int8" in (quant["weights"], quant["kv"]),
+                         "serving.quantization int8"),
+        "lora": (int(serving.lora["rank"]) > 0, "serving.lora.rank > 0"),
+    }
+    bad = [how for arm, (asked, how) in arms.items()
+           if asked and arm in lacks]
+    if bad:
+        raise ValueError(
+            f"{type(model).__name__} cannot be served with "
+            + "; ".join(bad) + ": its serving steps have no such arm")
+
+
 def _percentile(sorted_vals: List[float], q: float) -> Optional[float]:
     from ..telemetry.cli import _percentile as p
     return p(sorted_vals, q)
 
 
 class ServeEngine:
-    """Continuous-batching greedy decode over a GPT-2-family model.
+    """Continuous-batching greedy decode over a decoder-only model.
 
-    ``model`` must expose the serving protocol (``GPT2Model`` and its
-    flavors do): ``prefill(params, tokens) -> (logits, k, v)`` and
-    ``decode_step(params, tokens, k, v, lengths, active, impl=...)``.
-    Any decoder exposing that pair serves unchanged; encoder scoring
-    (BERT) maps onto a prefill-only protocol adapter — noted as the
-    follow-up in docs/serving.md.
+    **The serving protocol** (what the engine, and the benchmark's probe,
+    read of ``model``; ``GPT2Model`` and ``OlmoeModel`` are its two
+    implementations):
+
+    * ``model.config`` with ``n_layer``, ``n_head``, ``d_head`` (the KV
+      pool's shape), ``n_positions`` (the longest sequence) and
+      ``attn_impl`` (``'flash'`` | ``'dense'``: which decode arm
+      ``serving.decode_impl: auto`` takes); ``d_model`` with LoRA;
+    * ``model.init(rng)`` -> a params dict whose ``"wte"`` leaf has the
+      dtype the KV cache is kept in; ``model.param_partition_specs(
+      params)`` -> specs or None (replicated);
+    * paged (``serving.page_len > 0``): ``prefill_paged(params, tokens
+      [1, Tq], delta_len, prefix_len, page_row, k_pool, v_pool)`` ->
+      ``(logits [1, Tq, V], k_pool, v_pool)`` and ``decode_step_paged(
+      params, tokens [S], k_pool, v_pool, page_table, lengths, active,
+      impl=)`` -> ``(logits [S, V], k_pool, v_pool, new_lengths)``, the
+      pools ``[L, pages, H, page_len, Dh]``, donated and returned, every
+      other operand traced (``models/gpt2.py`` has the full contracts);
+    * slot cache (``page_len: 0``): ``prefill(params, tokens)`` ->
+      ``(logits, k, v)`` and ``decode_step(params, tokens, k, v, lengths,
+      active, impl=)``;
+    * optional arms, each asked for by configuration: ``verify_step`` /
+      ``verify_step_paged`` (``speculate_k``), the ``k_scale`` /
+      ``v_scale`` operands and an int8 weight tree (``quantization``),
+      the ``lora`` / ``adapter_slots`` operands (``lora``).
+
+    A model names the arms it lacks in ``serving_unsupported`` (of
+    ``'slot_cache'``, ``'speculate_k'``, ``'quantization'``, ``'lora'``)
+    and the engine refuses such a configuration here, at construction.
+    A model that names counters in ``serving_aux`` (OLMoE:
+    ``moe_experts_hit``, ``moe_rows``, ``moe_load_imbalance``) takes
+    ``aux=True`` in its paged steps and returns one more output, a dict
+    of those scalars for the call; the engine keeps them per call in
+    ``aux_log``.
     """
 
     def __init__(self, model, config=None, mesh=None, params=None,
@@ -169,9 +233,9 @@ class ServeEngine:
         self.slots = cfg.serving.slots
         self.eos_id_default = (None if cfg.serving.eos_id < 0
                                else cfg.serving.eos_id)
+        _refuse_unsupported(model, cfg.serving)
         if cfg.serving.decode_impl == "auto":
-            from ..models.gpt2 import _decode_attn_impl
-            self.decode_impl = _decode_attn_impl(mcfg)
+            self.decode_impl = _auto_decode_impl(mcfg)
         else:
             self.decode_impl = cfg.serving.decode_impl
         #: draft-verify speculation (0 = off — the parity reference arm)
@@ -218,6 +282,13 @@ class ServeEngine:
         kv_dtype = wte.dtype if wte is not None else jnp.float32
         self.page_len = cfg.serving.page_len
         self.paged = self.page_len > 0
+        #: per-call counters of a ``serving_aux`` model (class docstring):
+        #: (host time, 'prefill' | 'decode', {name: value}) per program
+        #: call, newest last; bounded
+        self._aux_keys = tuple(getattr(model, "serving_aux", ()))
+        self._aux = self.paged and bool(self._aux_keys)
+        self.aux_log: deque = deque(maxlen=65536)
+        self._aux_pending = ()
         #: chunked prefill (Sarathi-Serve, PAPERS.md; docs/serving.md
         #: "disaggregated fleet"): > 0 = prompts with a longer uncached
         #: delta admit immediately and prefill one chunk per step(),
@@ -409,6 +480,17 @@ class ServeEngine:
             # leaves both signatures and traces byte-identical
             lora_on = self.lora
             lora_scale = self.lora_scale
+            # a model with per-call counters returns them as one more
+            # output of both programs; without, nothing changes
+            aux_kw = {"aux": True} if self._aux else {}
+
+            aux_keys = self._aux_keys
+
+            def pack_aux(counters):
+                """One float32 vector for the host to fetch, in the
+                order the model names them (counts exact to 2**24)."""
+                return jnp.stack([counters[k].astype(jnp.float32)
+                                  for k in aux_keys])
 
             def split_lora(extra):
                 """(lora kwargs, rng tail) of a program's *extra."""
@@ -425,7 +507,7 @@ class ServeEngine:
                 lkw, rng = split_lora(extra)
                 out = self.model.prefill_paged(
                     params, tokens, delta_len, prefix_len, page_row,
-                    cache["k"], cache["v"], **lkw,
+                    cache["k"], cache["v"], **lkw, **aux_kw,
                     **cache_scales(cache))
                 logits, kp, vp = out[0], out[1], out[2]
                 total = jnp.reshape(prefix_len + delta_len,
@@ -439,6 +521,8 @@ class ServeEngine:
                 newc = {"k": kp, "v": vp, "lengths": lengths}
                 if quant_kv:
                     newc["k_scale"], newc["v_scale"] = out[3], out[4]
+                if aux_kw:
+                    return newc, first_tok, pack_aux(out[-1])
                 return newc, first_tok
 
             def serve_decode(params, cache, tokens, active, page_table,
@@ -447,14 +531,17 @@ class ServeEngine:
                 out = self.model.decode_step_paged(
                     params, tokens, cache["k"], cache["v"], page_table,
                     cache["lengths"], active, impl=self.decode_impl,
-                    **lkw, **cache_scales(cache))
+                    **lkw, **aux_kw, **cache_scales(cache))
+                stats = ()
+                if aux_kw:
+                    out, stats = out[:-1], (pack_aux(out[-1]),)
                 logits, k, v, new_len = out[0], out[1], out[2], out[-1]
                 next_tok = select_next_token(logits, temp,
                                              rng[0] if rng else None)
                 newc = {"k": k, "v": v, "lengths": new_len}
                 if quant_kv:
                     newc["k_scale"], newc["v_scale"] = out[3], out[4]
-                return newc, next_tok
+                return (newc, next_tok) + stats
 
             # copy-on-write: duplicate one page (src/dst traced — zero
             # recompiles no matter which pages diverge).  Every pool-
@@ -552,12 +639,11 @@ class ServeEngine:
                                              rng[0] if rng else None)
                 return ({"k": k, "v": v, "lengths": new_len}, next_tok)
 
+        outs = (self._cache_shardings, rep) + ((rep,) if self._aux else ())
         self._prefill_fn = jax.jit(
-            serve_prefill, donate_argnums=(1,),
-            out_shardings=(self._cache_shardings, rep))
+            serve_prefill, donate_argnums=(1,), out_shardings=outs)
         self._decode_fn = jax.jit(
-            serve_decode, donate_argnums=(1,),
-            out_shardings=(self._cache_shardings, rep))
+            serve_decode, donate_argnums=(1,), out_shardings=outs)
         if self.spec_k:
             self._build_spec_plane(cfg, mcfg, kv_dtype, draft_params,
                                    seed, rep)
@@ -675,6 +761,15 @@ class ServeEngine:
                 self._prefix_misses = reg.counter(
                     "serve_prefix_misses_total",
                     "admissions that found no cached prefix")
+            if self._aux:
+                self._moe_hit_gauge = reg.gauge(
+                    "serve_moe_experts_hit",
+                    "experts with at least one token in the last decode "
+                    "tick, summed over layers (of num_experts x layers)")
+                self._moe_imbalance_gauge = reg.gauge(
+                    "serve_moe_load_imbalance",
+                    "tokens of the busiest expert over the mean tokens "
+                    "an expert, largest over layers, last decode tick")
             if self.spec_k:
                 self._spec_proposed = reg.counter(
                     "serve_spec_proposed_total",
@@ -763,8 +858,14 @@ class ServeEngine:
         target or not: at draft scale a full stride is a rounding
         error next to the target pool, and it keeps the rollback a
         pure lengths mask."""
-        from ..models.gpt2 import GPT2Config, GPT2Model, _decode_attn_impl
+        from ..models.gpt2 import GPT2Config, GPT2Model
         from ..config import constants as C
+        if not isinstance(self.model, GPT2Model):
+            from .quantize import NotGPT2ParamsError
+            raise NotGPT2ParamsError(
+                "serving.speculate_k: the draft plane pairs a GPT-2 draft "
+                "with a GPT2Model target (shared vocabulary and verify "
+                f"steps); the target is {type(self.model).__name__}")
         d = cfg.serving.draft
         draft_cfg = GPT2Config(
             vocab_size=mcfg.vocab_size, n_positions=mcfg.n_positions,
@@ -776,7 +877,7 @@ class ServeEngine:
         self.draft_config = draft_cfg
         self.draft_model = GPT2Model(draft_cfg)
         self._draft_impl = ("dense" if self.decode_impl == "dense"
-                            else _decode_attn_impl(draft_cfg))
+                            else _auto_decode_impl(draft_cfg))
         if draft_params is None:
             draft_params = self.draft_model.init(
                 jax.random.PRNGKey(seed + 1))
@@ -1458,14 +1559,16 @@ class ServeEngine:
                                   rid=req.rid)
                 self._charge_prefill_delay(len(delta))
                 with self._pallas_scope():
-                    self.cache, first = self._prefill_fn(
-                        self.params, self.cache, tokens,
-                        np.int32(len(delta)), np.int32(shared_len),
-                        row_np, np.int32(self.scheduler.free[0]),
-                        *((self._lora_pools, np.int32(aslot))
-                          if self.lora else ()),
-                        *self._maybe_key())
+                    self.cache, first, *self._aux_pending = \
+                        self._prefill_fn(
+                            self.params, self.cache, tokens,
+                            np.int32(len(delta)), np.int32(shared_len),
+                            row_np, np.int32(self.scheduler.free[0]),
+                            *((self._lora_pools, np.int32(aslot))
+                              if self.lora else ()),
+                            *self._maybe_key())
                 first = int(np.asarray(jax.block_until_ready(first)))
+                self._note_aux("prefill")
             if self.spec_k:
                 # the draft mirrors the FULL prompt (it has no prefix
                 # cache — draft prefill is cheap by construction)
@@ -1684,7 +1787,7 @@ class ServeEngine:
                 tr.flow_start("serve/request", req.ctx, cat="serve",
                               rid=req.rid)
             with self._pallas_scope():
-                self.cache, first = self._prefill_fn(
+                self.cache, first, *self._aux_pending = self._prefill_fn(
                     self.params, self.cache, tokens,
                     np.int32(len(chunk)),
                     np.int32(req.shared_len + pos),
@@ -1693,6 +1796,7 @@ class ServeEngine:
                       if self.lora else ()),
                     *self._maybe_key())
             first = int(np.asarray(jax.block_until_ready(first)))
+            self._note_aux("prefill")
         req.chunk_pos = pos + len(chunk)
         req.kv_len = req.shared_len + req.chunk_pos
         if not final:
@@ -1800,17 +1904,36 @@ class ServeEngine:
         with self._span("serve/decode_dispatch"):
             with self._pallas_scope():
                 if self.paged:
-                    self.cache, next_tok = self._decode_fn(
-                        self.params, self.cache, tokens, active,
-                        self._table,
-                        *((self._lora_pools, self._adapter_table)
-                          if self.lora else ()),
-                        *self._maybe_key())
+                    self.cache, next_tok, *self._aux_pending = \
+                        self._decode_fn(
+                            self.params, self.cache, tokens, active,
+                            self._table,
+                            *((self._lora_pools, self._adapter_table)
+                              if self.lora else ()),
+                            *self._maybe_key())
+                    for a in self._aux_pending:
+                        # on its way while the host waits for the tokens
+                        a.copy_to_host_async()
                 else:
                     self.cache, next_tok = self._decode_fn(
                         self.params, self.cache, tokens, active,
                         *self._maybe_key())
             return next_tok
+
+    def _note_aux(self, kind: str) -> None:
+        """Log the counters of the paged call just synced, the third
+        output of a ``serving_aux`` model's programs (``aux_log``); with
+        telemetry on, the expert layer's two go to their gauges."""
+        if not self._aux_pending:
+            return
+        vals = dict(zip(self._aux_keys,
+                        np.asarray(self._aux_pending[0]).tolist()))
+        self._aux_pending = ()
+        self.aux_log.append((time.perf_counter(), kind, vals))
+        if self.telemetry is not None and kind == "decode" \
+                and "moe_experts_hit" in vals:
+            self._moe_hit_gauge.set(vals["moe_experts_hit"])
+            self._moe_imbalance_gauge.set(vals["moe_load_imbalance"])
 
     def _pull_tokens(self, *arrays):
         """``serve/token_pull``: the host waiting for the device.  The
@@ -1851,6 +1974,7 @@ class ServeEngine:
             next_tok = self._decode_dispatch(tokens, active)
             # the pull stays inside the decode_step span
             (next_host,) = self._pull_tokens(next_tok)
+            self._note_aux("decode")
         return self._emit_tokens(active_map, next_host)
 
     def _draft_propose(self, active_map, tokens, active):

@@ -85,6 +85,11 @@ def dequantize_rows(q: jnp.ndarray, scale: jnp.ndarray) -> jnp.ndarray:
     return q.astype(jnp.float32) * scale[..., None]
 
 
+class NotGPT2ParamsError(TypeError):
+    """A GPT-2-only serving arm (int8 weights, the speculative draft) was
+    handed a model or a parameter tree of another family."""
+
+
 def quantize_gpt2_params(params: Dict[str, Any]) -> Dict[str, Any]:
     """One-shot post-load quantization of a GPT-2 param tree: each
     block matmul weight becomes int8 with an ``<name>_scale`` fp32
@@ -93,7 +98,14 @@ def quantize_gpt2_params(params: Dict[str, Any]) -> Dict[str, Any]:
     never mutated; non-covered leaves pass through unchanged.  Works
     on any GPT-2-family tree whose ``blocks`` stack layers on axis 0
     (the target and the speculative draft alike)."""
-    blocks = dict(params["blocks"])
+    blocks = params.get("blocks") if isinstance(params, dict) else None
+    missing = [k for k in QUANT_WEIGHT_KEYS
+               if not isinstance(blocks, dict) or k not in blocks]
+    if missing:
+        raise NotGPT2ParamsError(
+            "int8 weight quantization covers GPT-2's block matmuls "
+            f"{QUANT_WEIGHT_KEYS}; this tree has no blocks/{missing[0]}")
+    blocks = dict(blocks)
     for name in QUANT_WEIGHT_KEYS:
         q, scale = quantize_channels(blocks[name])
         blocks[name] = q

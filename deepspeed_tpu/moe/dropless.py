@@ -1,0 +1,199 @@
+"""Dropless top-k expert layer: no capacity, no dropped token, no padding
+to a capacity (OLMoE, arXiv:2409.02060; MegaBlocks' formulation).
+
+The third dispatch beside ``moe/layer.py``'s capacity paths (``einsum`` |
+``scatter``), which stay as they are for ``models/gpt2_moe.py``.  One
+function, :func:`dropless_moe`, serves a prefill (a thousand tokens, ~128
+rows an expert: compute-bound) and a decode tick (64 tokens, ~8 rows an
+expert: bound by reading the hit experts' weights):
+
+1. route: ``softmax(x @ router_w)`` over ALL experts in float32, top-k,
+   weights not renormalised unless asked;
+2. sort the ``N * k`` assignments by expert and lay each expert's rows out
+   from a row-tile boundary (``tm`` rows, 16 at decode, up to 128 at
+   prefill), so that a tile belongs to one expert.  At most
+   ``N*k // tm + E`` tiles whatever the routing: a static shape;
+3. two grouped matmuls over the tiles, each a Pallas kernel whose weight
+   block is the tile's expert (scalar prefetch): ``ds_moe_gate_up``
+   (``silu(x @ gate) * (x @ up)``) and ``ds_moe_down``.  An expert's
+   matrices are one contiguous block, fetched once while its tiles follow
+   each other and not at all for an expert no token chose; tiles past the
+   last live one run nothing and fetch nothing new;
+4. combine: each token's k rows gathered back, weighted, summed in float32.
+
+The stacked weights of ALL layers reach the kernels whole
+(``[L*E, d, f]``) with the layer's offset added to the tile's expert, so a
+layer scan slices (copies) no expert matrix.
+"""
+from __future__ import annotations
+
+from typing import NamedTuple, Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..ops.pallas.runtime import use_interpret
+
+# Stable names of the Mosaic custom calls (docs/observability.md "Kernel
+# naming"): trace rows are ``ds_moe_gate_up.<n>`` / ``ds_moe_down.<n>``.
+MOE_GATE_UP_KERNEL = "ds_moe_gate_up"
+MOE_DOWN_KERNEL = "ds_moe_down"
+
+#: both of an expert's up-projections in flight, double-buffered, are
+#: 16 MiB at OLMoE's widths (2 x 2 x 2048 x 1024 bf16): over Mosaic's
+#: default of 16 MiB a kernel, well inside a v5e core's 128 MiB
+MOE_VMEM_LIMIT = 48 * 1024 * 1024
+MAX_ROW_TILE = 128
+
+
+class MoEStats(NamedTuple):
+    """What one call routed (int32 scalars, for the serving counters)."""
+    experts_hit: jnp.ndarray      # experts with at least one row
+    max_rows: jnp.ndarray         # rows of the busiest expert
+    rows: jnp.ndarray             # live assignments (valid tokens x k)
+
+
+def route_topk(x, router_w, top_k: int, renormalize: bool = False):
+    """x [N, d], router_w [d, E] -> (weights [N, k] float32, experts
+    [N, k] int32): softmax over all E in float32, then the k largest."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    weights, experts = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    if renormalize:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights, experts.astype(jnp.int32)
+
+
+def row_tile(assignments: int, n_experts: int) -> int:
+    """Rows a tile: the power of two at or above the mean rows an expert,
+    from 16 (one bf16 tile of sublanes) to ``MAX_ROW_TILE``."""
+    tm = 16
+    while tm < min(assignments // n_experts, MAX_ROW_TILE):
+        tm *= 2
+    return tm
+
+
+def _gate_up_kernel(te_ref, live_ref, x_ref, wg_ref, wu_ref, h_ref):
+    live = pl.program_id(0) < live_ref[0]
+
+    @pl.when(live)
+    def _():
+        x = x_ref[...]
+        g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+        h_ref[...] = (g * jax.nn.sigmoid(g) * u).astype(h_ref.dtype)
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        h_ref[...] = jnp.zeros_like(h_ref)
+
+
+def _down_kernel(te_ref, live_ref, h_ref, wd_ref, y_ref):
+    live = pl.program_id(0) < live_ref[0]
+
+    @pl.when(live)
+    def _():
+        y_ref[...] = jnp.dot(h_ref[...], wd_ref[0],
+                             preferred_element_type=jnp.float32
+                             ).astype(y_ref.dtype)
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+
+def _grouped(kernel, name, rows, weights, tile_expert, n_live, tm, width,
+             interpret):
+    """One grouped matmul: ``rows`` [T*tm, d_in] against the tile's
+    expert of each of ``weights`` ([X, d_in, width])."""
+    tiles = rows.shape[0] // tm
+    d_in = rows.shape[1]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(tiles,),
+        in_specs=[pl.BlockSpec((tm, d_in), lambda t, te, nl: (t, 0))]
+        + [pl.BlockSpec((1,) + w.shape[1:], lambda t, te, nl: (te[t], 0, 0))
+           for w in weights],
+        out_specs=pl.BlockSpec((tm, width), lambda t, te, nl: (t, 0)),
+    )
+    return pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((tiles * tm, width), rows.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=MOE_VMEM_LIMIT),
+        interpret=interpret, name=name,
+    )(tile_expert, n_live, rows, *weights)
+
+
+def dropless_moe(x, router_w, gate_w, up_w, down_w, top_k: int, *,
+                 expert_offset=0, valid=None, renormalize: bool = False,
+                 interpret: Optional[bool] = None):
+    """x [N, d] -> (y [N, d], :class:`MoEStats`).
+
+    ``router_w`` [d, E] is this layer's; ``gate_w`` / ``up_w``
+    [X, d, f] and ``down_w`` [X, f, d] hold this layer's E experts from
+    row ``expert_offset`` (a traced scalar: ``layer * E`` into the
+    stacked weights of every layer, 0 for one layer's own).  ``valid``
+    [N] bool leaves padding rows out: they reach no expert, count in no
+    statistic and get zeros."""
+    n, d = x.shape
+    e = router_w.shape[-1]
+    width = gate_w.shape[-1]
+    if interpret is None:
+        interpret = use_interpret()
+    with jax.named_scope("moe"):
+        weights, experts = route_topk(x, router_w, top_k, renormalize)
+        flat = experts.reshape(-1)                          # [A]
+        if valid is not None:
+            flat = jnp.where(jnp.repeat(valid, top_k), flat, e)
+        a = n * top_k
+        tm = row_tile(a, e)
+        tiles = a // tm + e
+        i32 = jnp.int32
+
+        # each expert's rows from a tile boundary, experts in order
+        counts = jnp.zeros((e + 1,), i32).at[flat].add(1)[:e]
+        per = (counts + tm - 1) // tm
+        tile_end = jnp.cumsum(per)
+        n_live = tile_end[-1]
+        t = jnp.arange(tiles, dtype=i32)
+        # a tile past the live ones repeats the last live expert: the
+        # pipeline fetches nothing for a block index that does not move
+        tile_expert = jnp.searchsorted(
+            tile_end, jnp.minimum(t, jnp.maximum(n_live - 1, 0)),
+            side="right").astype(i32)
+        tile_expert = jnp.minimum(tile_expert, e - 1)
+        row_start = (tile_end - per) * tm                   # [E]
+        sorted_start = jnp.cumsum(counts) - counts          # [E]
+
+        order = jnp.argsort(flat, stable=True).astype(i32)  # [A]
+        r = jnp.arange(tiles * tm, dtype=i32)
+        e_r = tile_expert[r // tm]
+        rank = r - row_start[e_r]
+        live_row = (r // tm < n_live) & (rank < counts[e_r])
+        src = jnp.where(live_row, sorted_start[e_r] + rank, a)
+        token = jnp.concatenate([order // top_k, jnp.full((1,), n, i32)])
+        x_rows = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])[token[src]]
+
+        te = tile_expert + jnp.asarray(expert_offset, i32)
+        live = jnp.reshape(n_live, (1,)).astype(i32)
+        h = _grouped(_gate_up_kernel, MOE_GATE_UP_KERNEL, x_rows,
+                     (gate_w, up_w), te, live, tm, width, interpret)
+        y_rows = _grouped(_down_kernel, MOE_DOWN_KERNEL, h, (down_w,),
+                          te, live, tm, d, interpret)
+
+        # each assignment's row, then the weighted sum of a token's k
+        place = jnp.zeros((a,), i32).at[order].set(jnp.arange(a, dtype=i32))
+        e_a = jnp.minimum(flat, e - 1)
+        row = row_start[e_a] + place - sorted_start[e_a]
+        row = jnp.where(flat < e, row, 0)
+        picked = y_rows[row].reshape(n, top_k, d).astype(jnp.float32)
+        keep = (flat < e).reshape(n, top_k, 1)
+        y = jnp.sum(jnp.where(keep, picked * weights[..., None], 0.0), axis=1)
+        stats = MoEStats(experts_hit=jnp.sum(counts > 0).astype(i32),
+                         max_rows=jnp.max(counts).astype(i32),
+                         rows=jnp.sum(counts).astype(i32))
+        return y.astype(x.dtype), stats

@@ -54,7 +54,11 @@ class MoEConfig:
 
     def __post_init__(self):
         if self.top_k not in (1, 2):
-            raise ValueError(f"top_k must be 1 or 2, got {self.top_k}")
+            # of the capacity dispatch only: moe/dropless.py routes any k
+            raise ValueError(
+                f"top_k must be 1 or 2 on the capacity paths (einsum | "
+                f"scatter), got {self.top_k}; dropless top-k is "
+                "moe/dropless.py")
         if self.dispatch_impl not in ("einsum", "scatter"):
             raise ValueError(
                 f"dispatch_impl must be 'einsum' or 'scatter', got "

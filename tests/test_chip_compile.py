@@ -424,3 +424,103 @@ def test_gpt2_programs_hold_no_unnamed_kernel(program, one_chip):
     names = _kernel_names(build(one_chip))
     assert names
     assert all(n.startswith("ds_") for n in names), names
+
+
+# ---------------------------------------------------------------------------
+# OLMoE at its published widths (benchmark/configs/olmoe-1b-7b.json): the
+# expert layer's two kernels, the paged decode kernel at head size 128, and
+# both serve programs, two layers deep (the scan body compiles once)
+# ---------------------------------------------------------------------------
+
+OLMOE_SLOTS, OLMOE_HEADS, OLMOE_DH, OLMOE_MAX_PAGES = 64, 16, 128, 128
+
+
+def _olmoe_shapes(pages=1 + 4 * OLMOE_MAX_PAGES):
+    from deepspeed_tpu.models.olmoe import OlmoeConfig, OlmoeModel
+    cfg = OlmoeConfig(num_hidden_layers=2, param_dtype="bfloat16")
+    model = OlmoeModel(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    pool = _sds((2, pages, OLMOE_HEADS, PAGE_LEN, OLMOE_DH))
+    return model, params, pool
+
+
+def _olmoe_decode_program(one_chip):
+    model, params, pool = _olmoe_shapes()
+    s = OLMOE_SLOTS
+    with interpret_scope(False):
+        return _compile(
+            lambda *a: model.decode_step_paged(*a, impl="pallas", aux=True),
+            one_chip, params, _sds((s,), jnp.int32), pool, pool,
+            _sds((s, OLMOE_MAX_PAGES), jnp.int32), _sds((s,), jnp.int32),
+            _sds((s,), jnp.bool_))
+
+
+def _olmoe_prefill_program(one_chip):
+    model, params, pool = _olmoe_shapes()
+    i32 = _sds((), jnp.int32)
+    with interpret_scope(False):
+        return _compile(
+            lambda *a: model.prefill_paged(*a, aux=True), one_chip, params,
+            _sds((1, 1024), jnp.int32), i32, i32,
+            _sds((OLMOE_MAX_PAGES,), jnp.int32), pool, pool)
+
+
+def test_paged_decode_kernel_at_head_128_keeps_its_name(one_chip):
+    """``fold`` 1: a 128-wide key fills the lanes alone.  16 heads of a
+    page of 16 are one bf16 tile a head; the block is as many pages as
+    the module's VMEM budget holds."""
+    ppb = paged_pages_per_block(OLMOE_HEADS, PAGE_LEN, OLMOE_DH, 2,
+                                OLMOE_MAX_PAGES)
+    assert ppb == 16
+    assert (ppb * paged_page_vmem_bytes(OLMOE_HEADS, PAGE_LEN, OLMOE_DH, 2)
+            <= PAGED_KV_VMEM_BUDGET)
+    pool = _sds((1 + 4 * OLMOE_MAX_PAGES, OLMOE_HEADS, PAGE_LEN, OLMOE_DH))
+    compiled = _compile(
+        lambda q, k, v, t, n: decode_attention_paged(q, k, v, t, n,
+                                                     interpret=False),
+        one_chip, _sds((OLMOE_SLOTS, OLMOE_HEADS, OLMOE_DH)), pool, pool,
+        _sds((OLMOE_SLOTS, OLMOE_MAX_PAGES), jnp.int32),
+        _sds((OLMOE_SLOTS,), jnp.int32))
+    names = _kernel_names(compiled)
+    assert [n.split(".")[0] for n in names] == [PAGED_DECODE_ATTN_KERNEL]
+
+
+@pytest.mark.parametrize("tokens", [64, 1024], ids=["decode_tick",
+                                                    "prefill_bucket"])
+def test_moe_kernels_carry_their_names(tokens, one_chip):
+    """64 experts of 2048 x 1024, top-8: rows of 16 at a decode tick, of
+    128 at a prefill; an expert's matrices are one block each (16 MiB in
+    flight: the kernels raise Mosaic's VMEM limit, and the compile is the
+    proof that the chip allows it)."""
+    from deepspeed_tpu.moe import dropless
+    assert dropless.MOE_GATE_UP_KERNEL == "ds_moe_gate_up"
+    assert dropless.MOE_DOWN_KERNEL == "ds_moe_down"
+    d, f, e = 2048, 1024, 64
+    compiled = _compile(
+        lambda x, r, g, u, w: dropless.dropless_moe(
+            x, r, g, u, w, 8, expert_offset=jnp.int32(e),
+            interpret=False)[0],
+        one_chip, _sds((tokens, d)), _sds((d, e)), _sds((2 * e, d, f)),
+        _sds((2 * e, d, f)), _sds((2 * e, f, d)))
+    names = sorted(n.split(".")[0] for n in _kernel_names(compiled))
+    assert names == [dropless.MOE_DOWN_KERNEL, dropless.MOE_GATE_UP_KERNEL]
+
+
+@pytest.mark.parametrize("program", ["serve_decode", "serve_prefill"])
+def test_olmoe_programs_hold_their_named_kernels(program, one_chip):
+    """Every Mosaic call of OLMoE's two serve programs starts ``ds_``
+    (what ``unnamed_kernel_share.saturated`` reads as 0), and the pools
+    pass through the layer scan without a copy of either: the program's
+    temporaries are far smaller than one pool."""
+    build, attn = {"serve_decode": (_olmoe_decode_program,
+                                    PAGED_DECODE_ATTN_KERNEL),
+                   "serve_prefill": (_olmoe_prefill_program,
+                                     "ds_flash_fwd")}[program]
+    compiled = build(one_chip)
+    names = {n.split(".")[0] for n in _kernel_names(compiled)}
+    assert names == {"ds_moe_gate_up", "ds_moe_down", attn}, names
+    pool_bytes = 2 * (1 + 4 * OLMOE_MAX_PAGES) * OLMOE_HEADS * PAGE_LEN \
+        * OLMOE_DH * 2
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    limit = pool_bytes // 4 if program == "serve_decode" else None
+    assert limit is None or temp < limit, (temp, pool_bytes)

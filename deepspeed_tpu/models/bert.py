@@ -22,7 +22,8 @@ from ..ops.transformer import (DeepSpeedTransformerConfig,
                                DeepSpeedTransformerLayer)
 from ..ops.transformer.transformer import _dropout, _layer_norm
 from ..parallel.mesh import MODEL_AXIS
-from ..runtime.module import TrainModule
+from ..runtime.module import TrainModule, mark_subtrees
+from ..runtime.zero import gather_layer
 
 
 @dataclasses.dataclass(frozen=True)
@@ -142,6 +143,13 @@ class BertModel(TrainModule):
             "nsp_w": P(), "nsp_b": P(),
         }
 
+    def stacked_param_spec(self, params):
+        """The encoder leaves are stacked ``[L, ...]`` and ``encode``
+        scans over L."""
+        if not self.config.scan_layers:
+            return None
+        return mark_subtrees(params, {"layers"})
+
     # ---------------- forward ----------------
     def encode(self, params, input_ids, token_type_ids=None,
                attention_mask=None, rng=None, train: bool = True,
@@ -203,11 +211,20 @@ class BertModel(TrainModule):
                 y = layer(lp, h, add_mask, lrng, train)
             return y, None
 
-        body_fn = jax.checkpoint(body) if cfg.remat == "block" else body
+        remat = jax.checkpoint if cfg.remat == "block" else (lambda f: f)
         if cfg.scan_layers:
+            # under ZeRO the layer is gathered here, inside the remat'd
+            # body (runtime/zero.py::gather_layer)
+            layer_specs = self.param_partition_specs(params)["layers"]
+
+            def body_gather(carry, xs):
+                lp, i = xs
+                return body(carry, (gather_layer(lp, layer_specs), i))
+
             x, _ = jax.lax.scan(
-                body_fn, x, (params["layers"], jnp.arange(L)))
+                remat(body_gather), x, (params["layers"], jnp.arange(L)))
         else:
+            body_fn = remat(body)
             for i in range(L):
                 lp = jax.tree.map(lambda a: a[i], params["layers"])
                 x, _ = body_fn(x, (lp, jnp.asarray(i, jnp.int32)))

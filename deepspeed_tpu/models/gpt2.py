@@ -28,7 +28,8 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import causal_attention
 from ..parallel.mesh import MODEL_AXIS
-from ..runtime.module import TrainModule
+from ..runtime.module import TrainModule, mark_subtrees
+from ..runtime.zero import gather_layer
 
 
 @dataclasses.dataclass(frozen=True)
@@ -169,9 +170,7 @@ class GPT2Model(TrainModule):
             lrng = jax.random.fold_in(rng, i)
             return self._block(bp, x, lrng, train), None
 
-        body_fn = body
-        if cfg.remat == "block":
-            body_fn = jax.checkpoint(body)
+        remat = jax.checkpoint if cfg.remat == "block" else (lambda f: f)
 
         if cfg.scan_layers and cfg.stream_scan:
             # Param-streaming form: block params stay a scan CONSTANT
@@ -189,13 +188,23 @@ class GPT2Model(TrainModule):
             def body_stream(carry, i):
                 return body(carry, (fetch(block_params, i), i))
 
-            if cfg.remat == "block":
-                body_stream = jax.checkpoint(body_stream)
-            x, _ = jax.lax.scan(body_stream, x, jnp.arange(cfg.n_layer))
+            x, _ = jax.lax.scan(remat(body_stream), x,
+                                jnp.arange(cfg.n_layer))
         elif cfg.scan_layers:
+            # the layer's gather sits INSIDE the remat'd body: under ZeRO
+            # the backward gathers the layer again instead of keeping 48
+            # gathered layers alive
+            block_specs = self.param_partition_specs(params)["blocks"]
+
+            def body_gather(carry, xs):
+                bp, i = xs
+                return body(carry, (gather_layer(bp, block_specs), i))
+
             layer_idx = jnp.arange(cfg.n_layer)
-            x, _ = jax.lax.scan(body_fn, x, (block_params, layer_idx))
+            x, _ = jax.lax.scan(remat(body_gather), x,
+                                (block_params, layer_idx))
         else:
+            body_fn = remat(body)
             for i in range(cfg.n_layer):
                 bp = jax.tree.map(lambda a, i=i: a[i], block_params)
                 x, _ = body_fn(x, (bp, jnp.asarray(i)))
@@ -275,18 +284,22 @@ class GPT2Model(TrainModule):
                                       adapter_slots=adapter_slots,
                                       lora_scale=lora_scale)
 
-    # ---------------- param-streaming declaration ----------------
+    # ---------------- stacked-leaf declarations ----------------
+    def stacked_param_spec(self, params):
+        """The block leaves are stacked ``[L, ...]`` and ``apply`` scans
+        over L; embeddings/final LN are not."""
+        if not self.config.scan_layers:
+            return None
+        return mark_subtrees(params, {"blocks"})
+
     def streaming_param_spec(self, params):
         """The stacked block leaves stream (one layer per scan tick);
         embeddings/final LN stay device-resident.  Requires the scan form
         with explicit per-layer fetch (``stream_scan``) so the engine's
         host placement actually bounds device bytes."""
-        if not (self.config.scan_layers and self.config.stream_scan):
+        if not self.config.stream_scan:
             return None
-        return {
-            k: jax.tree.map(lambda _: k == "blocks", v)
-            for k, v in params.items()
-        }
+        return self.stacked_param_spec(params)
 
 
 _DEVICE_MEMORY_KIND: Optional[str] = None

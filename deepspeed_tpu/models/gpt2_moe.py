@@ -26,7 +26,8 @@ from jax.sharding import PartitionSpec as P
 
 from ..moe.layer import MoEConfig, init_moe_params, moe_ffn, moe_param_specs
 from ..parallel.mesh import MODEL_AXIS
-from ..runtime.module import TrainModule
+from ..runtime.module import TrainModule, mark_subtrees
+from ..runtime.zero import gather_layer
 from .gpt2 import (GPT2Config, _dropout, _layer_norm, gpt2_attn_sublayer,
                    gpt2_ffn)
 
@@ -288,10 +289,16 @@ class GPT2MoEModel(TrainModule):
 
             attn_g = regroup(params["attn"], freq)
             dense_g = regroup(params["dense_ffn"], freq - 1)
+            specs = self.param_partition_specs(params)
 
             def group_body(carry, xs):
                 x, aux = carry
                 ag, dg, mg, g = xs
+                # under ZeRO the group's rows are gathered here, inside
+                # the remat'd body (runtime/zero.py::gather_layer)
+                ag = gather_layer(ag, specs["attn"], keep_leading=True)
+                dg = gather_layer(dg, specs["dense_ffn"], keep_leading=True)
+                mg = gather_layer(mg, specs["moe"])
                 for j in range(freq - 1):
                     apj = jax.tree.map(lambda a, j=j: a[j], ag)
                     dpj = jax.tree.map(lambda a, j=j: a[j], dg)
@@ -333,18 +340,22 @@ class GPT2MoEModel(TrainModule):
         logits = x @ params["wte"].astype(x.dtype).T
         return logits, aux_total
 
+    def stacked_param_spec(self, params):
+        """The attn/dense-FFN/MoE leaves are stacked over layers and the
+        group scan takes ``freq`` (``freq - 1``, one) rows of them a
+        tick; embeddings/final LN are not."""
+        if not self.config.scan_groups:
+            return None
+        return mark_subtrees(params, {"attn", "dense_ffn", "moe"})
+
     def streaming_param_spec(self, params):
         """The stacked attn/dense-FFN/MoE leaves stream (one group per
         scan tick); embeddings/final LN stay device-resident.  Requires
         the group-scan form with explicit per-group fetch
         (``stream_scan``)."""
-        if not (self.config.scan_groups and self.config.stream_scan):
+        if not self.config.stream_scan:
             return None
-        stacked = {"attn", "dense_ffn", "moe"}
-        return {
-            k: jax.tree.map(lambda _: k in stacked, v)
-            for k, v in params.items()
-        }
+        return self.stacked_param_spec(params)
 
     def loss_fn(self, params, batch, rng, train: bool = True):
         tokens = batch["input_ids"] if isinstance(batch, dict) else batch

@@ -346,10 +346,12 @@ class DeepSpeedEngine:
             stage=config.zero_optimization_stage, mesh=self.mesh,
             base_param_specs=model.param_partition_specs(master),
             offload=config.zero_config.cpu_offload,
-            params=master)
+            params=master,
+            stacked=model.stacked_param_spec(master))
         # sanitized in the plan: indivisible dims fall back to replication
         # (e.g. 4 experts declared over an 8-way data axis)
         base_specs = self.zero_plan.base_param_specs
+        zero_placement = self.zero_plan.placement_summary(master)
 
         scaler, self.loss_scale_config = precision.from_fp16_config(config.fp16)
         # 1-bit Adam engages a dedicated shard_map step (local grads feed
@@ -929,6 +931,32 @@ class DeepSpeedEngine:
             f"dtype={self.compute_dtype.__name__} "
             f"micro_bs={self.micro_batch_size} "
             f"grad_acc={self.gradient_accumulation_steps}", ranks=[0])
+        self._report_zero_placement(*zero_placement)
+
+    def _report_zero_placement(self, counts: dict, replicated: list):
+        """Which axis ZeRO cut, once: the ``zero_sharded_leaves`` gauge
+        and, where sharding is on, one line naming the leaves it had to
+        leave whole."""
+        if self.telemetry is not None:
+            gauge = self.telemetry.registry.gauge(
+                "zero_sharded_leaves",
+                "parameter leaves by where ZeRO's sharded placement puts "
+                "the data axis: on a layer axis the model scans over "
+                "(scanned: a whole stack is gathered per scan iteration), "
+                "on another axis (other), or nowhere (replicated)")
+            for axis, n in counts.items():
+                gauge.set(n, axis=axis)
+        if self.zero_plan.stage >= 1 and self.dp_world_size > 1 and (
+                replicated or counts["scanned"]):
+            shown = ", ".join(replicated[:8]) + (
+                f", ... ({len(replicated)} in all)"
+                if len(replicated) > 8 else "")
+            log_dist(
+                f"ZeRO placement over dp={self.dp_world_size}: "
+                f"{counts['other']} leaves sharded off any scanned axis, "
+                f"{counts['scanned']} on a scanned layer axis, "
+                f"{counts['replicated']} left replicated (no dim the "
+                f"data axis divides): {shown or 'none'}", ranks=[0])
 
     # ------------------------------------------------------------------
     # configuration
@@ -1014,8 +1042,12 @@ class DeepSpeedEngine:
         else:
             con = lambda g: g  # noqa: E731
 
+        # constrain=False callers run inside a manual region (1-bit, CSR)
+        # or want no placement stated at all (the pg check's reference)
+        pin = plan if constrain else None
+
         def micro_loss(p, mb, rng):
-            pp = cast_for_compute(p, compute_dtype) if cast else p
+            pp = cast_for_compute(p, compute_dtype, pin) if cast else p
             loss = loss_fn(pp, mb, rng, train=True)
             return precision.scale_loss(loss.astype(jnp.float32), scaler)
 
@@ -1555,9 +1587,11 @@ class DeepSpeedEngine:
         module = self.module
         compute_dtype = self.compute_dtype
 
+        plan = self.zero_plan
+
         def eval_step(state: TrainState, batch, rng):
-            params = precision.cast_to_compute(
-                state.master_params, compute_dtype)
+            params = cast_for_compute(state.master_params, compute_dtype,
+                                      plan)
             return module.loss_fn(params, batch, rng, train=False)
 
         return jax.jit(eval_step)

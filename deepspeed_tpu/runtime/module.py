@@ -18,6 +18,16 @@ from __future__ import annotations
 
 from typing import Any, Callable, Optional
 
+import jax
+
+
+def mark_subtrees(params: dict, keys) -> dict:
+    """A pytree of bools aligned with ``params``: True for every leaf
+    under one of the top-level ``keys`` — the form ``stacked_param_spec``
+    takes for a model that keeps its layer stacks under a few keys."""
+    return {k: jax.tree.map(lambda _: k in keys, v)
+            for k, v in params.items()}
+
 
 class TrainModule:
     """Duck-typed protocol; subclass or just match the surface."""
@@ -31,10 +41,23 @@ class TrainModule:
     def param_partition_specs(self, params) -> Optional[Any]:
         return None
 
+    def stacked_param_spec(self, params) -> Optional[Any]:
+        """Optional: a pytree of bools aligned with ``params`` marking the
+        leaves that are stacked over a leading layer axis which the
+        forward pass SCANS over (True = dim 0 is the scanned axis and the
+        model consumes one slice of it per scan tick).  The one
+        declaration of that fact: ZeRO never shards a marked leaf's dim 0
+        (a slice along a partitioned dimension makes the partitioner
+        replicate the whole stack inside the loop; runtime/zero.py), and
+        ``streaming_param_spec`` is derived from it.  Return None when
+        nothing is scanned (unrolled layers, or a model without a layer
+        stack): every leaf then keeps the first-divisible-dim rule."""
+        return None
+
     def streaming_param_spec(self, params) -> Optional[Any]:
-        """Optional: a pytree of bools aligned with ``params`` marking
-        stacked-over-layers leaves the model consumes one layer per scan
-        tick (True = streamable).  With
+        """Optional: the ``stacked_param_spec`` marks, returned only when
+        the model also FETCHES each scan tick's slice itself (True =
+        streamable).  With
         ``zero_optimization.param_streaming`` the engine keeps those
         leaves' compute copies in HOST memory, so device-resident
         parameter bytes ~ one layer — ZeRO-Infinity-style parameter

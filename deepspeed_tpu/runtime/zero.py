@@ -23,6 +23,17 @@ a compiled graph*:
   offload — optimizer state placed in host memory (``pinned_host`` memory
             kind); see runtime/offload.py.
 
+Which dim: the first unassigned one the data-axis size divides — but
+never the layer axis a model scans over.  A scan body takes one slice of
+its stacked ``[L, ...]`` leaves per iteration, and a slice along a
+partitioned dimension is something the partitioner can only do by
+replicating the operand first: with the shard on the layer axis every
+iteration all-gathered the WHOLE stack to use one layer of it (GPT-2 XL at
+dp=4: 283 GB gathered a step where 3.1 GB holds every parameter once;
+PERF.md, PR 31).  So a leaf the model declares as stacked
+(``TrainModule.stacked_param_spec``) is sharded from dim 1 on — a feature
+axis — and an iteration gathers only its own layer (``gather_layer``).
+
 Leaves whose dims don't divide the data-axis size stay replicated — the
 analogue of the reference's alignment padding (stage2.py:218-278), chosen
 instead of padding because XLA requires static per-shard shapes.
@@ -43,12 +54,22 @@ def _leaf_shape(leaf) -> tuple:
     return tuple(getattr(leaf, "shape", ()))
 
 
+def _uses_axis(entry, axis_name: str) -> bool:
+    """Whether one PartitionSpec entry (None, a name, a tuple of names)
+    shards its dim over ``axis_name``."""
+    return (axis_name in entry if isinstance(entry, tuple)
+            else entry == axis_name)
+
+
 def shard_spec_for_leaf(shape: tuple,
                         axis_size: int,
                         axis_name: str = DATA_AXIS,
-                        base_spec: Optional[P] = None) -> P:
+                        base_spec: Optional[P] = None,
+                        first_dim: int = 0) -> P:
     """Extend ``base_spec`` (e.g. a tensor-parallel spec) by sharding the
-    first unassigned dim divisible by ``axis_size`` over ``axis_name``."""
+    first unassigned dim divisible by ``axis_size`` over ``axis_name``,
+    looking from ``first_dim`` on (1 for a leaf stacked over a scanned
+    layer axis: dim 0 is never cut)."""
     base = list(base_spec) if base_spec is not None else []
     base += [None] * (len(shape) - len(base))
     if axis_size <= 1:
@@ -56,13 +77,11 @@ def shard_spec_for_leaf(shape: tuple,
     # A base spec may already consume the axis (expert-parallel weights
     # shard their expert dim over ``data``); a mesh axis can appear at most
     # once in a PartitionSpec, so ZeRO then has nothing to add.
-    def _uses_axis(entry) -> bool:
-        return (axis_name in entry if isinstance(entry, tuple)
-                else entry == axis_name)
-    if any(_uses_axis(e) for e in base if e is not None):
+    if any(_uses_axis(e, axis_name) for e in base):
         return P(*base)
     for i, d in enumerate(shape):
-        if base[i] is None and d % axis_size == 0 and d > 0:
+        if i >= first_dim and base[i] is None and d % axis_size == 0 \
+                and d > 0:
             base[i] = axis_name
             return P(*base)
     return P(*base)  # too small / indivisible: replicate (no padding on TPU)
@@ -115,13 +134,19 @@ class ZeroShardingPlan:
     def __init__(self, stage: int, mesh: Mesh,
                  base_param_specs: Optional[Any] = None,
                  offload: bool = False,
-                 params: Optional[Any] = None):
+                 params: Optional[Any] = None,
+                 stacked: Optional[Any] = None):
         if not 0 <= stage <= 3:
             raise ValueError(f"ZeRO stage must be 0..3, got {stage}")
         self.stage = stage
         self.mesh = mesh
         self.offload = offload
         self.dp = mesh.shape.get(DATA_AXIS, 1)
+        # the model's ``stacked_param_spec``: per leaf, whether dim 0 is
+        # a layer axis the forward scans over (never sharded, see the
+        # module docstring).  None: no leaf is.
+        self.scanned = (None if stacked is None
+                        else [bool(b) for b in jax.tree.leaves(stacked)])
         # base specs carry tensor/expert-parallel placement decided by the
         # model; ZeRO composes the 'data' axis on top.  Sanitized ONCE here
         # (indivisible dims → replicated); ``params`` supplies leaf shapes.
@@ -159,15 +184,25 @@ class ZeroShardingPlan:
                 f"  specs tree: "
                 f"{jax.tree.structure(self.base_param_specs)}\n"
                 f"  placed tree: {treedef}")
+        if self.scanned is not None and len(self.scanned) != len(leaves):
+            raise ValueError(
+                "stacked_param_spec leaf count does not match the tree "
+                f"being placed: {len(self.scanned)} marks vs "
+                f"{len(leaves)} leaves")
         specs = []
         for i, leaf in enumerate(leaves):
             base = None if base_leaves is None else base_leaves[i]
             if sharded:
                 specs.append(shard_spec_for_leaf(
-                    _leaf_shape(leaf), self.dp, DATA_AXIS, base))
+                    _leaf_shape(leaf), self.dp, DATA_AXIS, base,
+                    first_dim=int(self.is_scanned(i))))
             else:
                 specs.append(base if base is not None else P())
         return jax.tree.unflatten(treedef, specs)
+
+    def is_scanned(self, i: int) -> bool:
+        """Whether dim 0 of the i-th leaf is a scanned layer axis."""
+        return self.scanned is not None and self.scanned[i]
 
     def _sharding(self, spec: P) -> NamedSharding:
         return NamedSharding(self.mesh, spec)
@@ -232,6 +267,33 @@ class ZeroShardingPlan:
 
         return recurse(opt_state)
 
+    def placement_summary(self, params):
+        """Where the sharded placement (master, and with it moments and
+        stage>=2 gradients) puts the ``data`` axis, leaf by leaf:
+        ``({"scanned": n, "other": n, "replicated": n}, names)``.
+        ``scanned``: on dim 0 of a leaf the model scans over — the
+        placement that gathers a whole stack per scan iteration; only a
+        model's own base spec can still put it there.  ``replicated``:
+        no dim left that ``data`` divides; ``names`` are those leaves'
+        key paths with their shapes."""
+        specs = jax.tree.leaves(self._specs(params, sharded=True),
+                                is_leaf=lambda x: isinstance(x, P))
+        counts = {"scanned": 0, "other": 0, "replicated": 0}
+        names = []
+        flat = jax.tree_util.tree_flatten_with_path(params)[0]
+        for i, ((path, leaf), spec) in enumerate(zip(flat, specs)):
+            dims = [d for d, e in enumerate(spec)
+                    if _uses_axis(e, DATA_AXIS)]
+            if not dims:
+                counts["replicated"] += 1
+                names.append(f"{jax.tree_util.keystr(path)}"
+                             f"{list(_leaf_shape(leaf))}")
+            elif dims[0] == 0 and self.is_scanned(i):
+                counts["scanned"] += 1
+            else:
+                counts["other"] += 1
+        return counts, names
+
     def master_shardings(self, params):
         """Master params stay in device HBM even when offloading: they feed
         the forward cast every micro-step.  Offload targets the optimizer
@@ -256,12 +318,63 @@ class ZeroShardingPlan:
 # in the ``op_name`` of the operations the partitioner derives from them
 # (xprof's op profile groups by it; docs/observability.md).
 
-def cast_for_compute(master, compute_dtype):
+def cast_for_compute(master, compute_dtype, plan=None):
     """The step's read of the master copy (data-sharded from stage 1): the
     cast to compute dtype, which is what XLA all-gathers for the forward
-    — scope ``zero_gather``."""
+    — scope ``zero_gather``.
+
+    With ``plan``, the compute copy of a leaf the model scans over is
+    pinned to the master's own placement: the cast is local to a shard
+    (2 bytes a value leave the chip, not 4), the stack never exists
+    gathered, and each scan iteration gathers its own layer
+    (``gather_layer``).  Every other leaf is the partitioner's, as
+    before."""
     with jax.named_scope("zero_gather"):
-        return precision.cast_to_compute(master, compute_dtype)
+        out = precision.cast_to_compute(master, compute_dtype)
+        if plan is None or plan.dp <= 1 or plan.stage < 1 \
+                or plan.scanned is None:
+            return out
+        specs = jax.tree.leaves(plan.master_param_specs(master),
+                                is_leaf=lambda x: isinstance(x, P))
+        leaves, treedef = jax.tree.flatten(out)
+        leaves = [
+            jax.lax.with_sharding_constraint(
+                x, NamedSharding(plan.mesh, spec))
+            if plan.is_scanned(i) else x
+            for i, (x, spec) in enumerate(zip(leaves, specs))]
+        return jax.tree.unflatten(treedef, leaves)
+
+
+def gather_layer(layer, stack_specs, keep_leading: bool = False):
+    """The scan body's read of ONE slice of the model's stacked leaves:
+    pin it to the model's own (tensor-parallel) placement, replicated over
+    ``data``, so that the partitioner all-gathers this layer — and not the
+    stack, and not the activations for a tensor-parallel execution over
+    the batch's own axis.  Its transpose is the backward's: the layer's
+    gradient leaves the iteration reduced, into a stack sharded on a
+    feature axis.  Scope ``zero_gather``.
+
+    ``layer``: the slice the scan handed the body; ``stack_specs``: the
+    model's ``param_partition_specs`` of the STACKED leaves, same
+    structure (dim 0, the scanned axis, is dropped here; kept as an
+    unsharded dim with ``keep_leading`` for a body that takes several
+    rows a tick).  Called by every model that declares
+    ``stacked_param_spec``; a no-op where there is nothing to gather: no
+    ambient mesh (eager use, serving), a ``data`` axis of one, or a
+    manual (``shard_map``) region, whose operands are already local."""
+    am = jax.sharding.get_abstract_mesh()
+    if am.shape.get(DATA_AXIS, 1) <= 1 or am.manual_axes:
+        return layer
+
+    def one(x, spec):
+        entries = tuple(spec)[1:]
+        if keep_leading:
+            entries = (None,) + entries
+        spec = sanitize_base_spec(P(*entries), _leaf_shape(x), am)
+        return jax.lax.with_sharding_constraint(x, spec)
+
+    with jax.named_scope("zero_gather"):
+        return jax.tree.map(one, layer, stack_specs)
 
 
 def constrain_grads(grads, plan: ZeroShardingPlan):

@@ -1,0 +1,142 @@
+"""Read the collectives out of a compiled program's text.
+
+``compiled.as_text()`` is the program after the SPMD partitioner: what a
+placement rule (runtime/zero.py) really costs is the collectives found
+there, their result shapes, and how often the loop around them runs.
+Bytes and counts only — no time is read from a program's text.
+"""
+from __future__ import annotations
+
+import math
+import re
+from typing import Dict, List, NamedTuple
+
+COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
+               "collective-permute")
+
+_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
+             "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
+             "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8}
+
+_HEADER = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s+\(.*\)\s+->\s+.*\{\s*$")
+_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s+=\s+(.*?)\s+([\w\-]+)\(")
+_ARRAY = re.compile(r"\b([a-z]+[0-9]*(?:e[0-9]m[0-9](?:fn)?)?)\[([0-9,]*)\]")
+_CALLEE = re.compile(
+    r"\b(?:calls|to_apply|body|condition|true_computation|"
+    r"false_computation)=%?([\w.\-]+)")
+_BRANCHES = re.compile(r"\bbranch_computations=\{([^}]*)\}")
+_TRIP = re.compile(r'"known_trip_count":\{"n":"(\d+)"\}')
+_COND = re.compile(r"\bcondition=%?([\w.\-]+)")
+_CHANNEL = re.compile(r"\bchannel_id=(\d+)")
+_CONST = re.compile(r"\bs32\[\][^ ]* constant\((\d+)\)")
+
+
+class Collective(NamedTuple):
+    op: str            # one of COLLECTIVES (a ``-start`` counts as its op)
+    shapes: tuple      # result arrays: ((dtype, dims), ...)
+    bytes: int         # of the results, one execution
+    times: int         # executions a call of the program (loop trip counts)
+    in_loop: bool      # inside some ``while`` body
+
+
+def _arrays(type_text: str):
+    return [(dt, tuple(int(d) for d in dims.split(",") if d))
+            for dt, dims in _ARRAY.findall(type_text) if dt in _ITEMSIZE]
+
+
+def _nbytes(arrays) -> int:
+    return sum(_ITEMSIZE[dt] * math.prod(dims) for dt, dims in arrays)
+
+
+def collectives(hlo_text: str) -> List[Collective]:
+    """Every collective the program runs, with the product of the known
+    trip counts of the loops around it (a loop whose count the compiler
+    does not state counts once, and is still a loop)."""
+    comps: Dict[str, list] = {}
+    entry = current = None
+    for line in hlo_text.splitlines():
+        head = _HEADER.match(line)
+        if head:
+            current = head.group(2)
+            comps[current] = []
+            if head.group(1):
+                entry = current
+        elif current is not None and line.startswith("}"):
+            current = None
+        elif current is not None:
+            comps[current].append(line)
+    if entry is None:
+        raise ValueError("no ENTRY computation in the program text")
+
+    def trip_count(while_line: str) -> int:
+        """The count the compiler states, else (the TPU's text states
+        none) the one bound a counted loop's condition compares with."""
+        stated = _TRIP.search(while_line)
+        if stated:
+            return int(stated.group(1))
+        cond = _COND.search(while_line)
+        bounds = {int(n) for line in comps.get(cond.group(1), ())
+                  for n in _CONST.findall(line)} if cond else set()
+        return bounds.pop() if len(bounds) == 1 else 1
+
+    found: List[Collective] = []
+    # the TPU's compiler splits an asynchronous collective into fusions
+    # (start, steps, done) that each repeat the instruction under its one
+    # channel: counted once
+    channels = set()
+
+    def walk(name: str, times: int, in_loop: bool, seen: tuple):
+        if name not in comps or name in seen:
+            return
+        for line in comps[name]:
+            m = _INSTR.match(line)
+            if not m:
+                continue
+            type_text, op = m.groups()
+            base = op[:-len("-start")] if op.endswith("-start") else op
+            channel = _CHANNEL.search(line)
+            if base in COLLECTIVES and not (
+                    channel and (base, channel.group(1)) in channels):
+                if channel:
+                    channels.add((base, channel.group(1)))
+                arrays = _arrays(type_text)
+                if op.endswith("-start"):
+                    # (operands..., results...): the results are the
+                    # second half
+                    arrays = arrays[len(arrays) // 2:]
+                found.append(Collective(base, tuple(arrays),
+                                        _nbytes(arrays), times, in_loop))
+            callees = _CALLEE.findall(line)
+            for group in _BRANCHES.findall(line):
+                callees += [c.strip().lstrip("%") for c in group.split(",")]
+            if not callees:
+                continue
+            loop = op == "while"
+            n = trip_count(line) if loop else 1
+            for callee in callees:
+                walk(callee, times * n, in_loop or loop, seen + (name,))
+
+    walk(entry, 1, False, ())
+    return found
+
+
+def collective_report(hlo_text: str) -> str:
+    """A few lines for a log: bytes a call by operation, and the largest
+    results inside loops."""
+    found = collectives(hlo_text)
+    lines = []
+    for op in COLLECTIVES:
+        mine = [c for c in found if c.op == op]
+        if mine:
+            lines.append(
+                f"{op}: {len(mine)} instructions, "
+                f"{sum(c.times for c in mine)} executions, "
+                f"{sum(c.bytes * c.times for c in mine) / 1e9:.3f} GB a call")
+    looped = sorted((c for c in found if c.in_loop),
+                    key=lambda c: -c.bytes)[:12]
+    for c in looped:
+        shapes = ", ".join(f"{dt}[{','.join(map(str, dims))}]"
+                           for dt, dims in c.shapes)
+        lines.append(f"  in a loop x{c.times}: {c.op} {shapes} "
+                     f"({c.bytes / 1e6:.2f} MB)")
+    return "\n".join(lines)

@@ -104,3 +104,75 @@ def test_spec_leaf_count_mismatch_raises_at_query(mesh):
                   "b": np.zeros((4,), np.float32)}
     with pytest.raises(ValueError, match="leaf count"):
         plan.master_param_specs(two_leaves)
+
+
+# ---------------------------------------------------------------------------
+# leaves stacked over a scanned layer axis: dim 0 is never cut
+# (TrainModule.stacked_param_spec -> ZeroShardingPlan(stacked=...))
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("shape,base,want", [
+    # GPT-2 XL's fc_w at dp 4: off the layer axis, onto the first feature
+    # axis the data axis divides
+    ((48, 1600, 6400), None, P(None, "data", None)),
+    ((48, 1600), None, P(None, "data")),
+    # no other divisible dim: replicated, never the layer axis
+    ((48, 3), None, P(None, None)),
+    ((48,), None, P(None)),
+    # the model's own tensor-parallel dim is kept, ZeRO takes the next
+    ((48, 1600, 6400), P(None, None, "model"), P(None, "data", "model")),
+    ((48, 6400, 1600), P(None, "model", None), P(None, "model", "data")),
+    # a base spec that already uses the data axis (expert parallel) stands
+    ((48, 8, 64), P(None, "data"), P(None, "data", None)),
+])
+def test_shard_spec_skips_the_scanned_axis(shape, base, want):
+    assert shard_spec_for_leaf(shape, 4, base_spec=base,
+                               first_dim=1) == want
+
+
+def test_plan_reads_the_models_stacked_marks(mesh):
+    """One rule for master, gradients and moments; an unmarked leaf, and a
+    plan given no marks at all, keep the first-divisible-dim rule."""
+    from deepspeed_tpu.runtime.zero import ZeroShardingPlan
+
+    params = {"blocks": {"w": np.zeros((48, 1600, 64), np.float32),
+                         "b": np.zeros((48, 3), np.float32)},
+              "wte": np.zeros((48, 1600), np.float32)}
+    marks = {"blocks": {"w": True, "b": True}, "wte": False}
+    plan = ZeroShardingPlan(stage=2, mesh=mesh, params=params, stacked=marks)
+    want = {"blocks": {"w": P(None, "data", None), "b": P(None, None)},
+            "wte": P("data", None)}
+    assert plan.master_param_specs(params) == want
+    assert plan.grad_specs(params) == want
+    moments = {"mu": params, "nu": params, "count": np.zeros((), np.int32)}
+    got = plan.opt_state_specs(moments, params)
+    assert got["mu"] == want and got["nu"] == want and got["count"] == P()
+    # stage 3 stores the compute copy under the same rule; stage 2 not at all
+    assert ZeroShardingPlan(stage=3, mesh=mesh, params=params, stacked=marks
+                            ).compute_param_specs(params) == want
+    assert plan.compute_param_specs(params)["blocks"]["w"] == P()
+    counts, names = plan.placement_summary(params)
+    assert counts == {"scanned": 0, "other": 2, "replicated": 1}
+    assert names == ["['blocks']['b'][48, 3]"]
+
+    old = ZeroShardingPlan(stage=2, mesh=mesh, params=params)
+    assert old.master_param_specs(params)["blocks"]["w"] == P(
+        "data", None, None)
+    with pytest.raises(ValueError, match="stacked_param_spec"):
+        ZeroShardingPlan(stage=2, mesh=mesh, params=params,
+                         stacked={"blocks": {"w": True}}
+                         ).master_param_specs(params)
+
+
+def test_placement_summary_names_a_scanned_axis_the_model_itself_cut(mesh):
+    """Only a base spec can still put the data axis on a scanned dim 0;
+    the summary counts it so that ``zero_sharded_leaves{axis="scanned"}``
+    shows it."""
+    from deepspeed_tpu.runtime.zero import ZeroShardingPlan
+
+    params = {"w": np.zeros((8, 16), np.float32)}
+    plan = ZeroShardingPlan(stage=2, mesh=mesh, params=params,
+                            base_param_specs={"w": P("data", None)},
+                            stacked={"w": True})
+    assert plan.placement_summary(params) == (
+        {"scanned": 1, "other": 0, "replicated": 0}, [])

@@ -27,7 +27,7 @@ PAPERS.md): KV storage becomes a flat pool of fixed-size pages and each
 slot gets a host-owned int32 page table passed as a TRACED operand, so
 a short request holds ``ceil(len/page_len)`` pages instead of a full
 ``max_seq_len`` stride — the pool, not the slot count, caps how many
-users fit a chip (bench_serve.py --paged proves the multiple).  The
+users fit a chip (tests/test_paged_kv.py counts the multiple).  The
 scheduler grows a refcounted page allocator (free-list alloc on
 admission/append, free on eviction; ``kv_capacity`` finishes become
 pool-exhaustion-aware and admission backpressures when even prefix-
@@ -43,8 +43,8 @@ Speculative decoding (``serving.speculate_k > 0`` — Leviathan et al.
 proposes k tokens per tick in one compiled propose program, and the
 target scores all k+1 positions per slot in ONE widened
 ``verify_step`` program — the pass that used to buy one token now buys
-``accepted + 1`` of them, so wall-clock per token scales with
-1/mean-accepted-length (bench_serve.py --spec proves it on CPU).
+``accepted + 1`` of them: fewer target passes than tokens
+(tests/test_spec_decode.py holds the count; no cell times it yet).
 Greedy acceptance emits exactly the non-speculative stream (the parity
 bar); ``serving.temperature > 0`` switches to rejection-sampling
 acceptance that recovers the target distribution
@@ -62,7 +62,7 @@ serving matmuls — the fp master never reaches the device, params HBM
 ``kv='int8'`` stores the paged pool as int8 rows + per-row fp32 scale
 sidecars, quantized on write inside the compiled programs and
 dequantized fused in the decode kernels — ~2x more pages in the same
-KV bytes (``bench_serve.py --quant`` proves the admitted-concurrency
+KV bytes (tests/test_quant_serve.py holds the admitted-requests
 multiple), composing multiplicatively with paging and making the
 speculative draft plane nearly free.  Default off = every program
 bitwise-unchanged.
@@ -1411,8 +1411,8 @@ class ServeEngine:
         compute.  ``stage.check`` already charged one unit at the admit
         boundary; charge the remaining ``ceil(computed/page_len) - 1``
         here, inside the prefill span — so a prefix-hit delta pays for
-        its delta pages only and the bench's tracer-timestamp proof
-        reads compute ∝ 1 template + K deltas (bench_serve.py)."""
+        its delta pages only (tests/test_paged_kv.py,
+        ``test_prefix_hit_prefill_pays_delta_chunks_only``)."""
         if self.stage.degraded:
             return
         d = injected_delay(self.stage.name)

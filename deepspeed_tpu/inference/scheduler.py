@@ -8,7 +8,7 @@ into free slots (a prefill writes their K/V rows in place) and EVICTED
 the moment they finish (EOS / max_new_tokens / KV capacity), so a new
 request starts decoding on the very next tick — no waiting for the
 batch to drain, which is the whole continuous-batching win
-(bench_serve.py measures it).
+(tests/test_inference.py counts it: the same streams in fewer ticks).
 
 Eviction is pure host bookkeeping: the slot's ``lengths`` entry is
 overwritten by the next admission and the decode program masks the

@@ -185,10 +185,10 @@ def _scatter_moe(cfg: MoEConfig, mp: Dict[str, Any], x: jnp.ndarray,
     the one-hot einsum's O(S·C·E·d) = O(S²·cf·k·d) MXU work per group.
     The einsum formulation's dispatch cost is independent of E (capacity
     shrinks as 1/E) but quadratic in tokens-per-group — at long S the
-    dispatch einsum rivals the expert FFN itself (see bench_moe.py), which
-    is when this path wins.  Slots are unique by construction (disjoint
-    per-expert ranges; second choices queue behind all first choices), so
-    scatter-add never actually collides."""
+    dispatch einsum rivals the expert FFN itself, which is when this path
+    should win (by these counts; no cell measures it).  Slots are unique
+    by construction (disjoint per-expert ranges; second choices queue
+    behind all first choices), so scatter-add never actually collides."""
     G, S, d = x.shape
     E, C = cfg.n_experts, capacity
     dt = x.dtype

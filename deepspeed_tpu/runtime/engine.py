@@ -3097,7 +3097,7 @@ class DeepSpeedEngine:
                 scalars["ckpt_save_s"] = ca["save_s"] / ca["saves"]
                 if ca["overlap_s"] > 0:
                     # per WRITTEN save (coalesced submissions never
-                    # wrote) — the same denominator bench.py uses
+                    # wrote)
                     scalars["ckpt_async_overlap_s"] = (
                         ca["overlap_s"] / max(ca.get("writes", 0), 1))
                 ca.update(save_s=0.0, overlap_s=0.0, saves=0, writes=0)

@@ -579,9 +579,9 @@ class HostOffloadOptimizer:
         (just slow) link: that case logs a loud warning and proceeds —
         each subsequent bulk pull is chunked + watchdogged, so a link
         that later degrades into a stall still fails cleanly.  Set
-        DS_OFFLOAD_SLOW_LINK=error to restore the hard failure
-        (bench.py does: a slow link there should fail the run, not eat
-        the measurement window).
+        DS_OFFLOAD_SLOW_LINK=error to restore the hard failure (a
+        measurement should: a slow link there fails the run instead of
+        eating the window).
 
         Knobs: DS_OFFLOAD_MIN_MBPS (default 8; 0 disables),
         DS_OFFLOAD_PROBE_TIMEOUT seconds (default 60),

@@ -18,8 +18,8 @@ docs/_pages/features.md:115 there; this stack reaches past it):
     python examples/capacity_train.py --cpu --steps 5      # smoke
     python examples/capacity_train.py --layers 96          # on TPU
 
-``bench_capacity.py`` measures the resulting peak trainable params per
-chip (plain vs offload vs chunked vs streamed).
+Peak trainable params per chip (plain vs offload vs chunked vs streamed)
+has no measurement on the current chip: ROADMAP.md S8 / W7.
 """
 import argparse
 import os

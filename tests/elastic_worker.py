@@ -1,5 +1,5 @@
-"""Worker script for the elastic-training e2e tests (and a template for
-``bench.py --elastic-smoke``): trains a tiny linear model through the
+"""Worker script for the elastic-training e2e tests: trains a tiny
+linear model through the
 engine's OWN data-iterator chain (DeepSpeedDataLoader → RepeatingLoader
 → DevicePrefetcher), records per-step losses and every PRODUCED batch's
 sample indices, checkpoints every step, and optionally hard-kills

@@ -512,27 +512,3 @@ def test_summarize_disk_row(tmp_path, capsys):
     assert rep["disk_read_s"] == pytest.approx(0.02)
     out = capsys.readouterr().out
     assert "disk tier" in out
-
-
-# ---------------------------------------------------------------------
-# bench CPU smoke (tier-1): the --offload-tier legs
-# ---------------------------------------------------------------------
-def _load_bench():
-    import importlib.util
-    path = os.path.join(os.path.dirname(__file__), "..", "bench.py")
-    spec = importlib.util.spec_from_file_location("bench_for_test", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_bench_offload_tier_smoke():
-    """Both bench legs on CPU: bitwise-equal loss across tiers, the
-    disk leg measures overlap > 0 under its injected latency, and the
-    capacity accounting (total on disk > resident peak) is recorded."""
-    bench = _load_bench()
-    disk = bench.bench_offload_tier(jax, "disk", steps=2)
-    host = bench.bench_offload_tier(jax, "host", steps=2)
-    assert disk["loss"] == host["loss"]
-    assert disk["disk_overlap_ratio"] > 0, disk
-    assert 0 < disk["peak_resident_bytes"] < disk["total_state_bytes"]

@@ -1,6 +1,7 @@
-"""Guard the driver-facing artifacts: bench.py must refuse to stand a CPU
-run in for a chip, __graft_entry__.entry() must jit, dryrun_multichip must
-run on a small virtual mesh."""
+"""Guard the driver-facing artifacts: __graft_entry__.entry() must jit,
+dryrun_multichip must run on a small virtual mesh.  (That a CPU run is
+never stood in for a chip is tests/test_chip_smoke.py's and
+tests/test_benchmark_cells.py's to hold.)"""
 import os
 import subprocess
 import sys
@@ -18,16 +19,6 @@ def _run(args, timeout, extra_env=None):
         env.update(extra_env)
     return subprocess.run(args, cwd=REPO, env=env, timeout=timeout,
                           capture_output=True, text=True)
-
-
-@pytest.mark.slow
-def test_bench_refuses_to_measure_the_cpu():
-    """No TPU: bench.py runs nothing, prints no result and exits
-    non-zero — never a small CPU run under a benchmark's name."""
-    proc = _run([sys.executable, "bench.py"], timeout=300)
-    assert proc.returncode != 0, proc.stdout
-    assert not [l for l in proc.stdout.splitlines() if l.startswith("{")]
-    assert "measures a TPU" in proc.stderr
 
 
 def test_graft_entry_fn_jits():

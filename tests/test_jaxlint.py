@@ -397,7 +397,6 @@ def test_contracts_bad_project_catches_every_violation_class():
         ("JL102", "'fixture_orphan_total' is emitted here but consumed"),
         ("JL102", "sync scalar 'fixture_dead_s' is emitted here but"),
         ("JL102", "'fixture_ghost_s' is read here but no engine"),
-        ("JL102", "pins 'fixture_missing_speedup' but no committed"),
         ("JL102", "documented metric 'fixture_phantom_total' does not"),
         ("JL103", "`loader`:`vanished` does not exist in code"),
         ("JL103", "('writer', 'flush') is live here but missing"),
@@ -418,7 +417,7 @@ def test_contract_findings_are_suppressible_inline(tmp_path):
     import shutil
     proj = tmp_path / "proj"
     shutil.copytree(os.path.join(CONTRACTS, "bad"), proj)
-    tel = proj / "pkg" / "telemetry.py"
+    tel = proj / "deepspeed_tpu" / "telemetry.py"
     src = tel.read_text()
     src = src.replace(
         '        self.ticks = reg.counter("fixture_orphan_total")',
@@ -429,6 +428,45 @@ def test_contract_findings_are_suppressible_inline(tmp_path):
     assert not [f for f in findings
                 if "fixture_orphan_total" in f.message], \
         "\n".join(f.render() for f in findings)
+
+
+def _good_project(tmp_path):
+    import shutil
+    proj = tmp_path / "proj"
+    shutil.copytree(os.path.join(CONTRACTS, "good"), proj)
+    return proj
+
+
+def test_benchmark_dir_is_not_package_code(tmp_path):
+    """Emissions are collected from ``deepspeed_tpu/`` alone: a
+    benchmark that counts something of its own (no HELP, no consumer)
+    defines no contract and gives no finding."""
+    proj = _good_project(tmp_path)
+    (proj / "benchmark").mkdir()
+    (proj / "benchmark" / "x.py").write_text(
+        "def run(reg, scalars):\n"
+        "    reg.counter('bench_only_total').inc()\n"
+        "    scalars['bench_only_s'] = 0.0\n")
+    findings = lint_paths([str(proj)])
+    assert findings == [], "\n".join(f.render() for f in findings)
+
+
+def test_gitignored_copy_of_the_tree_is_not_the_project(tmp_path):
+    """A whole copy of the project unpacked under a directory the
+    root's ``.gitignore`` names (the verify skill's ``bench_trace/``)
+    neither doubles the emissions nor joins the consumer corpus."""
+    import shutil
+    from tools.jaxlint.registry import ProjectRegistry
+    proj = _good_project(tmp_path)
+    before = ProjectRegistry.build(str(proj)).dump()
+    shutil.copytree(os.path.join(CONTRACTS, "good"), proj / "bench_trace")
+    (proj / ".gitignore").write_text("# run outputs\nbench_trace/\n*.log\n")
+    reg = ProjectRegistry.build(str(proj))
+    assert reg.dump() == before
+    assert not [rp for rp in reg.sources if rp.startswith("bench_trace")]
+    from tools.jaxlint.contracts import run_project_rules
+    findings = run_project_rules(reg)
+    assert findings == [], "\n".join(f.render() for f in findings)
 
 
 def test_registry_dump_matches_golden():
@@ -503,7 +541,7 @@ def test_github_format_paths_are_root_relative_regardless_of_cwd(tmp_path):
         runs.append(sorted(l for l in proc.stdout.splitlines()
                            if l.startswith("::error")))
     assert runs[0] == runs[1]
-    assert any("file=pkg/worker.py" in l for l in runs[0]), runs[0]
+    assert any("file=deepspeed_tpu/worker.py" in l for l in runs[0]), runs[0]
 
 
 def test_inference_telemetry_tools_clean_under_full_v2_rules():

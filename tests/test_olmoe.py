@@ -1,10 +1,9 @@
 """OLMoE: the block's pieces against hand-written cases, the dropless
 expert layer against every-expert-masked, the model against the benchmark's
 plain float32 reference, the paged serving path through ``ServeEngine``
-against the reference's full forward, the refusals, and both new benchmark
-cells rehearsed.  CPU, tiny widths, seeded weights."""
+against the reference's full forward, and the refusals.  CPU, tiny widths,
+seeded weights.  (Its cell's rehearsal: tests/test_benchmark_cells.py.)"""
 import dataclasses
-import json
 import os
 import subprocess
 import sys
@@ -442,22 +441,6 @@ print("OK", len(set(sys.modules) - before))
                          env={**os.environ, "JAX_PLATFORMS": "cpu",
                               "XLA_FLAGS": ""})     # one device
     assert out.returncode == 0 and "OK" in out.stdout, out.stderr[-2000:]
-
-
-@pytest.mark.parametrize("cell", ["olmoe-1b-7b.serve-longform-saturated",
-                                  "bert-large.train-seq128"])
-def test_new_cells_rehearse(cell):
-    out = subprocess.run(
-        [sys.executable, "benchmark/run.py", "--rehearse", "--workload",
-         cell], cwd=ROOT, capture_output=True, text=True, timeout=600,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert out.returncode == 0, out.stdout[-1500:] + out.stderr[-1500:]
-    line = json.loads(out.stdout.strip().splitlines()[-1])
-    assert line["rehearsal"] and all(line["checks"].values())
-    assert line["failed"] == 0
-    if cell.startswith("olmoe"):
-        assert line["counts"]["moe_experts_hit_pct"] \
-            == line["counts"]["tick_ms"] > 0
 
 
 def test_paged_decode_kernel_at_head_128_equals_the_dense_arm():

@@ -12,8 +12,8 @@
   partial page, leaf-LRU eviction, pool accounting,
 * pool-exhaustion backpressure + the pool-aware ``kv_capacity`` finish,
 * the batched-``device_put`` satellite, deque free lists, config
-  validation, telemetry flow, flight-recorder depth fields, benchgate
-  direction pin, and the ``bench_serve.py --paged`` smoke.
+  validation, telemetry flow, flight-recorder depth fields, and
+  admitted requests at one KV-byte budget against the slot layout.
 """
 import json
 import os
@@ -964,7 +964,7 @@ def test_serve_stage_depth_snapshots_include_free_pages():
 
 
 # ---------------------------------------------------------------------------
-# injected prefill device time ∝ computed pages (the bench's cost model)
+# injected prefill device time ∝ computed pages
 # ---------------------------------------------------------------------------
 
 
@@ -987,45 +987,75 @@ def test_prefix_hit_prefill_pays_delta_chunks_only(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# benchgate: explicit direction pin for the new headline
+# one KV-byte budget: pages admit what fixed strides cannot
 # ---------------------------------------------------------------------------
 
 
-def test_benchgate_paged_ratio_is_higher_better():
-    from tools.benchgate import compare, is_lower_better
-    assert not is_lower_better("serve_paged_admitted_ratio")
-    fresh = {"metric": "serve_paged_admitted_ratio", "value": 1.2}
-    base = {"metric": "serve_paged_admitted_ratio", "value": 4.0}
-    assert compare(fresh, base)["regressed"]
-    assert not compare(base, fresh)["regressed"]
+def _drain_peak_active(eng, work):
+    """Submit ``(prompt, budget)`` pairs at once and step to idle;
+    returns the requests and the most that were ever admitted
+    together."""
+    reqs = [eng.submit(p, max_new_tokens=n) for p, n in work]
+    peak = 0
+    while eng.scheduler.active or eng._pending or eng.queue.qsize():
+        eng.step()
+        peak = max(peak, len(eng.scheduler.active))
+    assert all(r.error is None for r in reqs), [r.error for r in reqs]
+    return reqs, peak
 
 
-# ---------------------------------------------------------------------------
-# bench smoke: >= 2x admitted slots at fixed KV bytes, prefix ∝ deltas
-# ---------------------------------------------------------------------------
+def test_paged_admits_twice_the_slot_arm_at_one_kv_byte_budget():
+    """The capacity claim as counts.  The budget is what two slots of
+    fixed ``max_seq_len`` strides cost (``KVCacheSpec.bytes``); the
+    paged engine gets the pages those bytes buy
+    (``PagedKVCacheSpec.page_bytes``) plus the scratch page, which
+    holds no request.  Under a mix of one long request in four it
+    admits at least twice as many together, and never diverges: a
+    stream the pool cut short (the ``kv_capacity`` finish) is a prefix
+    of the slot arm's.  Then K template sharers: K-1 prefix hits, and
+    well under the no-prefix run's prefill tokens."""
+    model = GPT2Model(TINY)
+    params = model.init(jax.random.PRNGKey(0))
+    page_len, max_seq, budget_slots = 8, 32, 2
+    work = [(list(_tokens(16 if i % 4 == 3 else 4, seed=300 + i)),
+             16 if i % 4 == 3 else 4) for i in range(8)]
 
+    slot_eng = ServeEngine(model, _serve_cfg(slots=budget_slots,
+                                             max_seq=max_seq),
+                           params=params)
+    budget = slot_eng.cache_spec.bytes
+    shape = dict(layers=TINY.n_layer, heads=TINY.n_head,
+                 head_dim=TINY.d_head, dtype=slot_eng.cache_spec.dtype)
+    assert budget == KVCacheSpec(slots=budget_slots, max_len=max_seq,
+                                 **shape).bytes
+    page_bytes = PagedKVCacheSpec(slots=1, pages=1, page_len=page_len,
+                                  max_pages=1, **shape).page_bytes
+    slot_reqs, slot_peak = _drain_peak_active(slot_eng, work)
+    slot_eng.close()
 
-def test_bench_serve_paged_smoke(tmp_path):
-    import importlib.util
-    path = os.path.join(os.path.dirname(__file__), "..",
-                        "bench_serve.py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_serve_for_paged_test", path)
-    bench_serve = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench_serve)
-    rec = bench_serve.run_paged_ab(
-        kv_budget_slots=2, max_seq_len=32, page_len=8, n_requests=8,
-        template_len=16, prefix_k=3, tick_delay_s=0.02,
-        out_dir=str(tmp_path))
-    assert rec["metric"] == "serve_paged_admitted_ratio"
-    # the CPU-provable acceptance bar: >= 2x admitted concurrency at a
-    # fixed KV-byte budget under the short/long mix
-    assert rec["value"] >= 2.0
-    assert rec["paged"]["max_concurrent"] >= \
-        2 * rec["legacy"]["max_concurrent"]
-    # prefix caching: total prefill ∝ 1 template + K deltas
-    assert rec["prefix"]["prefill_ratio"] < 0.75
-    assert rec["prefix"]["on"]["prefix_hits"] == 2
-    art = json.load(open(os.path.join(str(tmp_path),
-                                      "BENCH_serve_paged.json")))
-    assert art["value"] == rec["value"]
+    paged_eng = ServeEngine(model, _serve_cfg(
+        slots=4 * budget_slots, max_seq=max_seq, page_len=page_len,
+        pages=budget // page_bytes + 1), params=params)
+    assert paged_eng.cache_spec.bytes - page_bytes <= budget
+    paged_reqs, paged_peak = _drain_peak_active(paged_eng, work)
+    paged_eng.close()
+    assert slot_peak == budget_slots
+    assert paged_peak >= 2 * slot_peak, (paged_peak, slot_peak)
+    for rs, rp in zip(slot_reqs, paged_reqs):
+        assert rp.tokens == rs.tokens[:len(rp.tokens)]
+
+    template = list(_tokens(16, seed=340))
+    sharers = [(template + list(_tokens(4, seed=341 + i)), 2)
+               for i in range(3)]
+    computed = {}
+    for prefix_cache in (True, False):
+        eng = ServeEngine(model, _serve_cfg(
+            page_len=page_len, prefix_cache=prefix_cache), params=params)
+        reqs, _ = _drain_peak_active(eng, sharers)
+        computed[prefix_cache] = (sum(r.computed_len for r in reqs),
+                                  [r.tokens for r in reqs])
+        if prefix_cache:
+            assert eng.prefix.hits == 2
+        eng.close()
+    assert computed[True][1] == computed[False][1]
+    assert computed[True][0] < 0.75 * computed[False][0]

@@ -9,8 +9,8 @@ same capacity point by partitioning fp16 params to CPU/NVMe (reference:
 deepspeed/runtime/zero/stage2.py fp16 partition machinery; generalized
 by the ZeRO-Infinity paper).  On the CPU test mesh memory kinds degrade
 to one space — these tests pin down numerics, composition, and the
-config contract; the capacity claim itself is bench_capacity.py's job
-on hardware.
+config contract; the capacity claim itself needs hardware and has no
+run on the current chip (ROADMAP.md S8).
 """
 import jax
 import jax.numpy as jnp
@@ -300,7 +300,7 @@ def test_stream_mask_marks_blocks_only():
 
 def test_streaming_composes_with_split_update():
     """param_streaming x offload_split_update x grad chunks: the deepest
-    capacity stack the 1.5B/bench_capacity chain can select.  Trajectory
+    capacity stack the 1.5B single-chip chain can select.  Trajectory
     must match the fused-update streaming engine."""
     mesh = build_mesh(dp=1, devices=jax.devices()[:1])
     es = DeepSpeedEngine(GPT2Model(_model_cfg(True)),

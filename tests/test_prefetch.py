@@ -21,8 +21,6 @@ these tests pin:
     for all numpy leaves, and the multi-process arm raises the
     descriptive ValueError on mismatched jax.Array shardings.
 """
-import importlib.util
-import os
 import sys
 import threading
 import time
@@ -572,33 +570,48 @@ def test_summarize_prefetch_row(tmp_path, capsys):
 
 
 # ---------------------------------------------------------------------
-# bench CPU smoke (tier-1): the A/B leg with an injected slow collate
+# the engine's own loader: a slow collate moves off the step path
 # ---------------------------------------------------------------------
-def _load_bench():
-    path = os.path.join(os.path.dirname(__file__), "..", "bench.py")
-    spec = importlib.util.spec_from_file_location("bench_for_test", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+@pytest.mark.parametrize("prefetch_on", [True, False])
+def test_engine_moves_a_slow_collate_off_the_step_path(prefetch_on):
+    """A collate that sleeps 50 ms goes through the engine's own loader
+    (``collate_fn=``).  With ``data_prefetch`` on, every batch is
+    collated on the worker and the step's exposed input stall
+    (``stats()``: ``wait_s`` per consumed batch) stays under the
+    collate, with batches already resident when asked; with the config
+    off there is no prefetcher and the step's own thread pays every
+    collate."""
+    delay_s, steps = 0.05, 3
+    collated_on = []
 
+    def slow_collate(samples):
+        collated_on.append(threading.current_thread())
+        time.sleep(delay_s)
+        xs, ys = zip(*samples)
+        return np.stack(xs), np.stack(ys)
 
-def test_bench_prefetch_smoke(monkeypatch):
-    """The --prefetch A/B legs on CPU with a 50ms injected collate: the
-    off leg pays it inline every step, the on leg's exposed input stall
-    (prefetch_wait) is strictly smaller — the worker hid the step's
-    compute window worth of it."""
-    bench = _load_bench()
-    monkeypatch.setenv("BENCH_PREFETCH_COLLATE_S", "0.05")
-    on = bench.bench_prefetch(jax, prefetch_on=True, steps=2)
-    off = bench.bench_prefetch(jax, prefetch_on=False, steps=2)
-    assert on["prefetch"] == "on" and off["prefetch"] == "off"
-    assert "prefetch_wait_s" in on and "prefetch_wait_s" not in off
-    # off pays the collate on the hot path every step
-    assert off["step_s"] >= 0.05, off
-    # on: the worker hid the collate — the step's exposed input stall is
-    # a fraction of the injected delay, and batches were already
-    # resident when asked.  (No raw step_s comparison: wall-clock A/B
-    # on a loaded CI container is noise; the wait/hit numbers are the
-    # same evidence without the flake.)
-    assert on["prefetch_wait_s"] < 0.05, on
-    assert on["hit_ratio"] > 0.0, on
+    cfg = base_config(micro_bs=2, grad_acc=1)
+    cfg["data_prefetch"] = {"enabled": prefetch_on, "depth": 2}
+    dscfg = DeepSpeedConfig(cfg, world_size=1)
+    eng = DeepSpeedEngine(
+        SimpleModel(hidden_dim=HIDDEN), dscfg,
+        mesh=build_mesh(dp=1, devices=jax.devices()[:1]), seed=3,
+        training_data=_dataset(dscfg.train_batch_size * 8),
+        collate_fn=slow_collate)
+    assert eng._prefetch_enabled == prefetch_on
+    _train(eng, 1)                       # compile, fill the pipeline
+    pf = eng._train_prefetcher
+    s0 = pf.stats() if prefetch_on else None
+    mark = len(collated_on)
+    assert all(np.isfinite(_train(eng, steps)))
+    here = threading.current_thread()
+    if prefetch_on:
+        s1 = pf.stats()
+        assert s1["consumed"] - s0["consumed"] == steps
+        assert s1["hits"] > s0["hits"]
+        assert (s1["wait_s"] - s0["wait_s"]) / steps < delay_s
+        assert here not in collated_on
+    else:
+        assert pf is None
+        assert collated_on[mark:] == [here] * steps
+    eng.close()

@@ -3,9 +3,8 @@ differentials, widened-verify parity against sequential decode, the
 acceptance math (greedy + rejection sampling), the engine-level parity
 bar (speculative stream == non-speculative stream, greedy, at every k,
 both KV layouts, dp1 and dp2×tp2, zero recompiles), accepted-length-
-variance scheduler semantics, telemetry flow, and the bench smoke.
+variance scheduler semantics, and telemetry flow.
 """
-import json
 import os
 
 import numpy as np
@@ -391,6 +390,8 @@ def test_spec_stream_parity_full_accept(arm):
     # pass emitted its whole surviving block
     assert all(m >= 0 for r in reqs for m in r.spec_accepted)
     assert eng._spec_accepted_n > eng._spec_passes  # blocks, not 1/tick
+    # which is the whole point: fewer target passes than tokens
+    assert eng._spec_passes < decode_tokens
     eng.close()
 
 
@@ -650,30 +651,3 @@ def test_draft_heads_must_divide_tp():
             "speculate_k": 2,
             "draft": {"d_model": 30, "n_layer": 1, "n_head": 3}}},
             params=_target_params(), mesh=mesh)
-
-
-def test_benchgate_pins_spec_metric_lower_better():
-    from tools.benchgate import is_lower_better
-    assert is_lower_better("serve_spec_wall_per_token_ratio") is True
-
-
-# ---------------------------------------------------------------------------
-# bench smoke
-# ---------------------------------------------------------------------------
-
-
-def test_bench_spec_smoke(tmp_path):
-    """CPU A/B: spec wall/token beats non-spec under injected per-pass
-    delay and the artifact carries the 1/MAL expectation."""
-    import bench_serve
-    rec = bench_serve.run_spec_ab(k=2, slots=3, n_requests=3,
-                                  prompt_len=6, gen_tokens=7,
-                                  pass_delay_s=0.05,
-                                  out_dir=str(tmp_path))
-    assert rec["metric"] == "serve_spec_wall_per_token_ratio"
-    assert rec["value"] < 0.8, rec
-    assert rec["expected_ratio_1_over_mal"] == pytest.approx(
-        1.0 / rec["spec"]["mean_accepted_len"])
-    assert os.path.exists(tmp_path / "BENCH_serve_spec.json")
-    with open(tmp_path / "BENCH_serve_spec.json") as f:
-        assert json.load(f)["value"] == rec["value"]

@@ -8,9 +8,7 @@ same zero-findings discipline as the code.
 JL102 — metric contracts: every registry metric needs HELP text and a
 consumer (summarize/diagnose row, docs mention, or test reference);
 every sync scalar needs a consumer; a ``scalars.get`` read needs an
-emitter; every benchgate ``METRIC_DIRECTIONS`` pin needs a committed
-``BENCH_*.json`` headline; every docs metric-naming bullet needs an
-emission.
+emitter; every docs metric-naming bullet needs an emission.
 
 JL103 — fault-point registry: the docs/stages.md stage/point contract
 table and drain-order fence must match the code-side registries (both
@@ -54,8 +52,8 @@ class ProjectRule:
 class MetricContracts(ProjectRule):
     id = "JL102"
     summary = ("metric contract: emissions need HELP text and a "
-               "consumer; benchgate pins and docs bullets need a "
-               "real metric behind them")
+               "consumer; docs bullets need a real metric behind "
+               "them")
 
     def _unconsumed(self, reg, name: str, sites) -> bool:
         emitting = {p for p, _l in sites}
@@ -91,13 +89,6 @@ class MetricContracts(ProjectRule):
                     reg, path, line,
                     f"sync scalar '{name}' is read here but no "
                     "engine ever emits it")
-        for name, (path, line) in sorted(reg.bench_directions.items()):
-            if name not in reg.bench_artifacts:
-                yield self.finding(
-                    reg, path, line,
-                    f"benchgate METRIC_DIRECTIONS pins '{name}' but "
-                    "no committed BENCH_*.json artifact carries that "
-                    "headline metric")
         known = set(reg.metrics) | set(reg.scalars)
         for name, path, line in reg.docs_metrics:
             if name not in known:

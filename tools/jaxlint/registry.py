@@ -24,8 +24,6 @@ rules (JL008–JL010, ``rules.py``) reconcile:
 - Config keys: every ``NAME = "literal"`` / ``NAME_DEFAULT`` pair in
   ``constants.py`` files and which uppercase constants each OTHER file
   references.
-- benchgate's ``METRIC_DIRECTIONS`` pins + ``LOWER_BETTER_HINTS`` and
-  the committed ``BENCH_*.json`` headline artifacts.
 - The docs tables: docs/stages.md's stage/point contract table and
   drain-order fence, docs/observability.md's metric-naming bullets.
 
@@ -35,8 +33,6 @@ from __future__ import annotations
 
 import ast
 import dataclasses
-import glob
-import json
 import os
 import re
 from typing import Dict, List, Optional, Set, Tuple
@@ -48,9 +44,9 @@ from .core import _SKIP_DIRS
 _REGISTRY_SKIP = _SKIP_DIRS | {"jaxlint_fixtures"}
 
 #: emissions (metrics, scalars, fault points, stages) are collected
-#: from package code only — tests and tools CONSUME metric names,
-#: they do not define the contract
-_NON_PACKAGE_TOPDIRS = {"tests", "tools", "docs"}
+#: from package code only — tests, tools and the benchmark CONSUME
+#: metric names, they do not define the contract
+_PACKAGE_TOPDIRS = {"deepspeed_tpu"}
 
 _FUNC_DEFS = (ast.FunctionDef, ast.AsyncFunctionDef)
 
@@ -98,7 +94,21 @@ def _const_str(node) -> Optional[str]:
 
 def _is_package_path(relpath: str) -> bool:
     top = relpath.replace(os.sep, "/").split("/", 1)[0]
-    return top not in _NON_PACKAGE_TOPDIRS
+    return top in _PACKAGE_TOPDIRS
+
+
+def _gitignored_dirs(root: str) -> Set[str]:
+    """The plain directory entries (``name/``, no glob) of the root's
+    ``.gitignore``, as root-relative paths: run outputs and unpacked
+    copies of the tree are not the project."""
+    try:
+        with open(os.path.join(root, ".gitignore"), encoding="utf-8") as f:
+            lines = [ln.strip() for ln in f]
+    except OSError:
+        return set()
+    return {ln.strip("/") for ln in lines
+            if ln.endswith("/") and not ln.startswith(("#", "!"))
+            and not any(c in ln for c in "*?[")}
 
 
 # ---------------------------------------------------------------------------
@@ -257,10 +267,6 @@ class ProjectRegistry:
         dataclasses.field(default_factory=dict)
     config_defaults: Dict[str, Site] = dataclasses.field(default_factory=dict)
     upper_refs: Dict[str, Set[str]] = dataclasses.field(default_factory=dict)
-    # bench plane
-    bench_directions: Dict[str, Site] = dataclasses.field(default_factory=dict)
-    bench_hints: Tuple[str, ...] = ()
-    bench_artifacts: Dict[str, str] = dataclasses.field(default_factory=dict)
     # docs plane
     docs_stage_rows: List[Tuple[str, str, str, int]] = \
         dataclasses.field(default_factory=list)
@@ -309,15 +315,18 @@ class ProjectRegistry:
     def build(cls, root: str) -> "ProjectRegistry":
         reg = cls(root=os.path.abspath(root))
         reg._scan_py_files()
-        reg._scan_bench_artifacts()
         reg._scan_docs()
         return reg
 
     def _iter_files(self, suffix: str) -> List[str]:
         out = []
+        ignored = _gitignored_dirs(self.root)
         for dirpath, dirs, names in os.walk(self.root):
-            dirs[:] = sorted(d for d in dirs if d not in _REGISTRY_SKIP
-                             and not d.startswith("."))
+            below = os.path.relpath(dirpath, self.root).replace(os.sep, "/")
+            dirs[:] = sorted(
+                d for d in dirs if d not in _REGISTRY_SKIP
+                and not d.startswith(".")
+                and not {d, f"{below}/{d}"} & ignored)
             for n in sorted(names):
                 if n.endswith(suffix):
                     out.append(os.path.relpath(os.path.join(dirpath, n),
@@ -349,8 +358,6 @@ class ProjectRegistry:
         is_constants = os.path.basename(rp) == "constants.py"
         if is_constants:
             self._scan_constants(rp, tree)
-        if rp.replace(os.sep, "/").endswith("tools/benchgate/__init__.py"):
-            self._scan_benchgate(rp, tree)
         stage_vars = self._stage_assignments(tree) if in_pkg else {}
         for node in ast.walk(tree):
             if isinstance(node, ast.Assign) and in_pkg:
@@ -522,25 +529,6 @@ class ProjectRegistry:
                 if v is not None:
                     self.config_keys[name] = (v, rp, stmt.lineno)
 
-    def _scan_benchgate(self, rp: str, tree):
-        for stmt in tree.body:
-            if not (isinstance(stmt, ast.Assign) and len(stmt.targets) == 1
-                    and isinstance(stmt.targets[0], ast.Name)):
-                continue
-            tname = stmt.targets[0].id
-            if tname == "METRIC_DIRECTIONS" and isinstance(stmt.value,
-                                                           ast.Dict):
-                for k in stmt.value.keys:
-                    name = _const_str(k) if k is not None else None
-                    if name is not None:
-                        self.bench_directions[name] = (rp, k.lineno)
-            elif tname == "LOWER_BETTER_HINTS" and isinstance(
-                    stmt.value, (ast.Tuple, ast.List)):
-                self.bench_hints = tuple(
-                    e.value for e in stmt.value.elts
-                    if isinstance(e, ast.Constant)
-                    and isinstance(e.value, str))
-
     # -- fault-point fixpoint --------------------------------------------
     def _resolve_fault_points(self, trees: Dict[str, ast.AST]):
         plane = _FaultPlane()
@@ -589,18 +577,6 @@ class ProjectRegistry:
         self.fault_points.sort(key=lambda t: (t[2], t[3]))
 
     # -- non-python artifacts --------------------------------------------
-    def _scan_bench_artifacts(self):
-        for path in sorted(glob.glob(os.path.join(self.root,
-                                                  "BENCH_*.json"))):
-            try:
-                with open(path, encoding="utf-8") as f:
-                    doc = json.load(f)
-            except (OSError, ValueError):
-                continue
-            if isinstance(doc, dict) and "metric" in doc and "value" in doc:
-                self.bench_artifacts[str(doc["metric"])] = \
-                    os.path.relpath(path, self.root)
-
     def _read_doc(self, relpath: str) -> Optional[List[str]]:
         path = os.path.join(self.root, relpath)
         if not os.path.isfile(path):
@@ -711,10 +687,6 @@ class ProjectRegistry:
                             sorted(self.config_keys.items())},
             "config_defaults": {k: list(v) for k, v in
                                 sorted(self.config_defaults.items())},
-            "bench_directions": {k: list(v) for k, v in
-                                 sorted(self.bench_directions.items())},
-            "bench_hints": list(self.bench_hints),
-            "bench_artifacts": dict(sorted(self.bench_artifacts.items())),
             "docs_stage_rows": [list(r) for r in self.docs_stage_rows],
             "docs_drain": [list(r) for r in self.docs_drain],
             "docs_metrics": [list(r) for r in self.docs_metrics],

@@ -3,14 +3,9 @@
 ``workload``  — declarative open-loop workload specs (arrival process
                 x length distributions x template mix x session gaps)
                 compiled to deterministic schedules.
-``harness``   — the ONE replay loop: a schedule against a bare
-                ``ServeEngine`` or a ``FleetRouter`` fleet, with the
-                CPU-provable injected-device-time idiom built in.
-``scenarios`` — the bench legs as workload configs over that harness
-                (serve / paged / spec / quant / fleet / goodput), each
-                writing its committed ``BENCH_*.json`` headline.
-
-Run one: ``python -m tools.loadgen <scenario>``.
+``convert``   — public Azure / Mooncake trace rows to the replayable
+                ``load_trace`` JSONL shape
+                (``python -m tools.loadgen convert <src> <dst>``).
 """
 from .workload import (ArrivalSpec, LengthSpec, Workload, WorkloadItem,
                        load_trace, schedule_fingerprint)

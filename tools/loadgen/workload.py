@@ -4,7 +4,7 @@ One declarative spec — arrival process x prompt/output length
 distributions x template/prefix mix x session idle gaps — compiled by
 :meth:`Workload.build` into a flat arrival schedule of
 :class:`WorkloadItem` (``at_s`` offset, prompt token ids, generation
-budget).  The schedule is what the harness replays OPEN-LOOP: arrivals
+budget).  The schedule is meant to be replayed OPEN-LOOP: arrivals
 fire on the clock regardless of completions, which is what makes
 queueing (and therefore goodput) measurable at all.
 
@@ -36,7 +36,7 @@ class WorkloadItem:
     max_new_tokens: int
     session: int = 0
     #: tenant LoRA adapter id (0 = base model) — forwarded to
-    #: ``submit(adapter_id=...)`` by the harness
+    #: ``submit(adapter_id=...)`` by whoever replays the schedule
     tenant: int = 0
 
 
@@ -80,7 +80,7 @@ class ArrivalSpec:
         else:
             raise ValueError(f"unknown arrival kind {self.kind!r} "
                              f"(one of {ARRIVAL_KINDS})")
-        # first request arrives at t0 (like every bench leg so far);
+        # first request arrives at t0;
         # the remaining gaps carry the process's shape
         offs = np.cumsum(gaps) - gaps[0]
         return [float(t) for t in offs]

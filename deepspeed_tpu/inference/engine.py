@@ -300,6 +300,10 @@ class ServeEngine:
                 "serving.quantization.kv='int8' requires a paged cache "
                 "(serving.page_len > 0); the slot layout keeps the "
                 "master dtype")
+        #: the body of the fp single-query Pallas arm that the pool's
+        #: shape chooses ('direct': the fetched page is the matmul
+        #: operand; 'packed'); None where another arm decodes
+        self.paged_decode_arm = None
         if self.paged:
             self.max_pages = -(-self.max_seq_len // self.page_len)
             pages = cfg.serving.pages
@@ -334,11 +338,13 @@ class ServeEngine:
             self._pages_per_block = 1
             if (self.decode_impl == "pallas" and not self.quant_kv
                     and not self.spec_k):
-                from ..ops.pallas.decode_attention import \
-                    paged_pages_per_block
+                from ..ops.pallas.decode_attention import (
+                    paged_decode_arm, paged_pages_per_block)
+                shape = (mcfg.n_head, self.page_len, mcfg.d_head,
+                         jnp.dtype(kv_dtype).itemsize)
                 self._pages_per_block = paged_pages_per_block(
-                    mcfg.n_head, self.page_len, mcfg.d_head,
-                    jnp.dtype(kv_dtype).itemsize, self.max_pages)
+                    *shape, self.max_pages)
+                self.paged_decode_arm = paged_decode_arm(*shape)
         else:
             self.pool = None
             self.prefix = None
@@ -761,6 +767,15 @@ class ServeEngine:
                 self._prefix_misses = reg.counter(
                     "serve_prefix_misses_total",
                     "admissions that found no cached prefix")
+                if self.paged_decode_arm is not None:
+                    arm_gauge = reg.gauge(
+                        "paged_decode_arm",
+                        "which body of ds_paged_decode_attn the pool's "
+                        "shape chose, 1 on the engaged arm: direct (a "
+                        "page at rest is the matmul operand) or packed")
+                    for arm in ("direct", "packed"):
+                        arm_gauge.set(int(arm == self.paged_decode_arm),
+                                      arm=arm)
             if self._aux:
                 self._moe_hit_gauge = reg.gauge(
                     "serve_moe_experts_hit",

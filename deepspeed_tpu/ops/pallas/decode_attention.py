@@ -58,6 +58,20 @@ the cache write just before — any other shape costs copies of the
 whole pool, every layer of every tick.  The int8 and multi-query paged
 arms keep the grid this one left: a step for each (slot, head, page).
 
+That body, the PACKED one, re-lays every page it has fetched.  Where a
+page at rest, ``[page_len, H, Dh]``, already is rows ``(r, h)`` of the
+buffer it would be copied into (``Dh`` a multiple of the 128 lanes, the
+heads a whole number of sublane tiles: 16 heads of 128),
+:func:`paged_decode_arm` chooses the DIRECT body from the pool's shape
+alone: the pools stay in HBM, and a live step copies the live pages of
+the next live block by the prefetched page table into the other half of
+a double buffer, then runs the same two matmuls on its own pages where
+they landed.  No page operands, no repack, and a dead table entry is
+neither fetched nor waited for.  On the chip the pipeline's fetch of 32
+page operands a step was 91 % of the packed body's time at that shape,
+the repack 4 % (PERF.md, PR 33): the hand-written copies, which head 64
+forbids, are the gain.
+
 ``impl='dense'`` is the interpretable reference fallback on both
 entry points: the same masking semantics in plain jnp (the paged arm
 gathers with ``jnp.take``), the differential-test oracle and the
@@ -373,15 +387,29 @@ def _paged_block_layout(heads, page_len, head_dim, itemsize):
     return fold, head_rows, page_len // fold * head_rows, fold * head_dim
 
 
+def paged_decode_arm(heads: int, page_len: int, head_dim: int,
+                     itemsize: int) -> str:
+    """Which body of the fp paged kernel a pool of this shape runs.
+    ``'direct'`` where a page at rest, ``[page_len, H, Dh]``, already is
+    the rows of the packed buffer (no fold, no padded head rows, whole
+    lanes): the fetched page is the matmul operand.  ``'packed'``
+    everywhere else.  A function of the pool's shape alone."""
+    fold, head_rows, _, _ = _paged_block_layout(
+        heads, page_len, head_dim, itemsize)
+    direct = fold == 1 and head_rows == heads and head_dim % _LANES == 0
+    return "direct" if direct else "packed"
+
+
 def paged_page_vmem_bytes(heads: int, page_len: int, head_dim: int,
                           itemsize: int) -> int:
     """VMEM one page of a block costs the fp paged kernel: its K and V
-    blocks in flight, double-buffered, and its rows of the two packed
-    buffers (lanes padded to 128 in both)."""
+    blocks in flight, double-buffered, and (packed arm only) its rows of
+    the two packed buffers (lanes padded to 128 in both)."""
     _, head_rows, rows, width = _paged_block_layout(
         heads, page_len, head_dim, itemsize)
     in_flight = page_len * head_rows * _round_up(head_dim, _LANES)
-    packed = rows * _round_up(width, _LANES)
+    direct = paged_decode_arm(heads, page_len, head_dim, itemsize) == "direct"
+    packed = 0 if direct else rows * _round_up(width, _LANES)
     return (4 * in_flight + 2 * packed) * itemsize
 
 
@@ -557,6 +585,162 @@ def _decode_paged_pallas(q, k_pages, v_pages, page_table, lengths, *,
     return out[:, :H]
 
 
+def _decode_paged_direct_kernel(pt_ref, len_ref, q_ref, pos_ref,
+                                k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
+                                state_ref, m_scr, l_scr, acc_scr,
+                                *, sm_scale: float):
+    """The direct arm: the same grid step (one slot, all heads, ``ppb``
+    pages), for pools whose page at rest, ``[page_len, H, Dh]``, already
+    is rows ``(r, h)`` of the buffer the matmuls read.  The pools stay in
+    HBM; a live step copies the LIVE pages of the next live block (the
+    next slot's first at a slot's end) by the page table into the other
+    half of a double buffer, then waits for its own pages and attends
+    them where they landed.  A dead step copies and waits for nothing.
+    ``state_ref`` passes from step to step which half the next live step
+    reads, and whether any step has started a copy yet."""
+    s, j = pl.program_id(0), pl.program_id(1)
+    slots, nb = pl.num_programs(0), pl.num_programs(1)
+    _, ppb, page_len, heads, head_dim = k_buf.shape
+    bk = ppb * page_len
+    length = len_ref[s]
+
+    def for_live_pages(slot, blk, fn):
+        """``fn(i)`` for each page of the block that holds a live key."""
+        left = len_ref[slot] - blk * bk
+        jax.lax.fori_loop(0, jnp.minimum(ppb, (left + page_len - 1)
+                                         // page_len),
+                          lambda i, _: fn(i), None)
+
+    pools = ((k_hbm, k_buf), (v_hbm, v_buf))
+
+    def fetch(slot, blk, half):
+        def start(i):
+            page = pt_ref[(slot * nb + blk) * ppb + i]
+            for which, (pool, buf) in enumerate(pools):
+                pltpu.make_async_copy(pool.at[page], buf.at[half, i],
+                                      sems.at[which, half]).start()
+        for_live_pages(slot, blk, start)
+
+    def wait(which, half):
+        # a wait takes one page's bytes off the semaphore: whose, is the
+        # same to it
+        pool, buf = pools[which]
+        for_live_pages(s, j, lambda i: pltpu.make_async_copy(
+            pool.at[0], buf.at[half, i], sems.at[which, half]).wait())
+
+    @pl.when((s == 0) & (j == 0))
+    def _clear():
+        # the rows of a partly live block's dead pages are never copied
+        # into: whatever VMEM held there would reach the value matmul
+        # times 0
+        v_buf[...] = jnp.zeros_like(v_buf)
+        state_ref[0] = 0
+        state_ref[1] = 0
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(j * bk < length)
+    def _live():
+        half = state_ref[0]
+        state_ref[0] = 1 - half
+
+        @pl.when(state_ref[1] == 0)
+        def _first():
+            # the grid's first live step: nobody fetched ahead for it
+            fetch(s, j, half)
+            state_ref[1] = 1
+
+        more = (j + 1 < nb) & ((j + 1) * bk < length)
+        # the slot's next block, or the first block of the next slot
+        # that holds a key (free slots ride along in the batch)
+        next_s = jax.lax.while_loop(
+            lambda t: (t < slots) & (len_ref[jnp.minimum(t, slots - 1)] == 0),
+            lambda t: t + 1, jnp.where(more, s, s + 1))
+
+        @pl.when(next_s < slots)
+        def _ahead():
+            fetch(next_s, jnp.where(more, j + 1, 0), 1 - half)
+
+        rows = ppb * page_len * heads
+        wait(0, half)
+        sc = jax.lax.dot_general(
+            q_ref[0], k_buf[half].reshape(rows, head_dim),
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale
+        # pos_ref keeps a head's own keys; a dead page's rows hold
+        # whatever an earlier block left there
+        sc = jnp.where(pos_ref[...] < length - j * bk, sc, NEG_INF)
+        m_prev = m_scr[:, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        # key j*bk of every head is live (the pl.when guard), so m_new
+        # is a real score and the masked keys' exp underflows to 0
+        p = jnp.exp(sc - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[:] = jnp.broadcast_to(
+            alpha * l_scr[:, 0:1] + jnp.sum(p, axis=1, keepdims=True),
+            l_scr.shape)
+        wait(1, half)
+        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+            p.astype(v_buf.dtype), v_buf[half].reshape(rows, head_dim),
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+
+    @pl.when(j == nb - 1)
+    def _finalize():
+        l = l_scr[:, 0:1]
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        # length 0 -> no block ran -> l == 0 -> exact zeros (free slots)
+        o_ref[0] = jnp.where(l == 0.0, 0.0,
+                             acc_scr[:] / l_safe).astype(o_ref.dtype)
+
+
+def _decode_paged_direct_pallas(q, k_pages, v_pages, page_table, lengths, *,
+                                sm_scale, interpret):
+    P, H, page_len, Dh = k_pages.shape
+    S, max_pages = page_table.shape
+    ppb = paged_pages_per_block(H, page_len, Dh, k_pages.dtype.itemsize,
+                                max_pages)
+    nb = -(-max_pages // ppb)
+    pt_flat = jnp.pad(page_table,
+                      ((0, 0), (0, nb * ppb - max_pages))).reshape(-1)
+    pos = jnp.asarray(_paged_block_positions(H, page_len, ppb, 1, H))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(S, nb),
+        in_specs=[pl.BlockSpec((1, H, Dh), lambda s, j, *_: (s, 0, 0)),
+                  pl.BlockSpec(pos.shape, lambda s, j, *_: (0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, H, Dh), lambda s, j, *_: (s, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, ppb, page_len, H, Dh), k_pages.dtype),
+            pltpu.VMEM((2, ppb, page_len, H, Dh), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((2,), jnp.int32),
+            pltpu.VMEM((H, 128), jnp.float32),
+            pltpu.VMEM((H, 128), jnp.float32),
+            pltpu.VMEM((H, Dh), jnp.float32),
+        ],
+    )
+    # [P, page_len, H, Dh], as in the packed arm: no copy of the pool
+    kt, vt = (x.transpose(0, 2, 1, 3) for x in (k_pages, v_pages))
+    return pl.pallas_call(
+        functools.partial(_decode_paged_direct_kernel, sm_scale=sm_scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, H, Dh), q.dtype),
+        # the double buffer and its parity pass from one step to the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name=PAGED_DECODE_ATTN_KERNEL,
+    )(pt_flat, lengths, q, pos, kt, vt)
+
+
 def _decode_paged_int8_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref,
                               ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr,
                               *, sm_scale: float, page_len: int, heads: int):
@@ -729,8 +913,11 @@ def decode_attention_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
         return _decode_paged_int8_pallas(
             q, k_pages, v_pages, page_table, lengths, sm_scale=sm_scale,
             interpret=interpret, k_scale=k_scale, v_scale=v_scale)
-    return _decode_paged_pallas(q, k_pages, v_pages, page_table, lengths,
-                                sm_scale=sm_scale, interpret=interpret)
+    arm = {"direct": _decode_paged_direct_pallas,
+           "packed": _decode_paged_pallas}[
+               paged_decode_arm(H, page_len, Dh, k_pages.dtype.itemsize)]
+    return arm(q, k_pages, v_pages, page_table, lengths, sm_scale=sm_scale,
+               interpret=interpret)
 
 
 # ---------------------------------------------------------------------------

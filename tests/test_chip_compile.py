@@ -21,7 +21,7 @@ from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
 from deepspeed_tpu.ops.pallas.decode_attention import (
     PAGED_DECODE_ATTN_KERNEL, PAGED_KV_VMEM_BUDGET, decode_attention,
     decode_attention_multi, decode_attention_paged,
-    decode_attention_paged_multi, paged_page_vmem_bytes,
+    decode_attention_paged_multi, paged_decode_arm, paged_page_vmem_bytes,
     paged_pages_per_block)
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 from deepspeed_tpu.ops.pallas.runtime import interpret_scope
@@ -466,23 +466,32 @@ def _olmoe_prefill_program(one_chip):
 
 
 def test_paged_decode_kernel_at_head_128_keeps_its_name(one_chip):
-    """``fold`` 1: a 128-wide key fills the lanes alone.  16 heads of a
-    page of 16 are one bf16 tile a head; the block is as many pages as
-    the module's VMEM budget holds."""
-    ppb = paged_pages_per_block(OLMOE_HEADS, PAGE_LEN, OLMOE_DH, 2,
-                                OLMOE_MAX_PAGES)
+    """The direct arm at the cell's own shapes (benchmark/configs/
+    olmoe-1b-7b.json: 64 slots, 128 table entries, 12 layers' pages in
+    one row of 12 x 3,457, a page as it rests ``[16, 16, 128]``): chosen
+    from the pool's shape, its double buffer inside the module's VMEM
+    budget, the pools left in HBM (no temporary of any size that a copy
+    of a pool would be), and still the one trace row
+    ``paged_decode_share.*`` reads."""
+    shape = (OLMOE_HEADS, PAGE_LEN, OLMOE_DH, 2)
+    assert paged_decode_arm(*shape) == "direct"
+    ppb = paged_pages_per_block(*shape, OLMOE_MAX_PAGES)
     assert ppb == 16
-    assert (ppb * paged_page_vmem_bytes(OLMOE_HEADS, PAGE_LEN, OLMOE_DH, 2)
-            <= PAGED_KV_VMEM_BUDGET)
-    pool = _sds((1 + 4 * OLMOE_MAX_PAGES, OLMOE_HEADS, PAGE_LEN, OLMOE_DH))
+    # K and V, two halves each, and nothing packed
+    assert paged_page_vmem_bytes(*shape) == 4 * PAGE_LEN * OLMOE_HEADS \
+        * OLMOE_DH * 2
+    assert ppb * paged_page_vmem_bytes(*shape) <= PAGED_KV_VMEM_BUDGET
+    flat = _sds((12 * 3457, PAGE_LEN, OLMOE_HEADS, OLMOE_DH))
     compiled = _compile(
-        lambda q, k, v, t, n: decode_attention_paged(q, k, v, t, n,
-                                                     interpret=False),
-        one_chip, _sds((OLMOE_SLOTS, OLMOE_HEADS, OLMOE_DH)), pool, pool,
+        lambda q, k, v, t, n: decode_attention_paged(
+            q, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), t, n,
+            interpret=False),
+        one_chip, _sds((OLMOE_SLOTS, OLMOE_HEADS, OLMOE_DH)), flat, flat,
         _sds((OLMOE_SLOTS, OLMOE_MAX_PAGES), jnp.int32),
         _sds((OLMOE_SLOTS,), jnp.int32))
     names = _kernel_names(compiled)
     assert [n.split(".")[0] for n in names] == [PAGED_DECODE_ATTN_KERNEL]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
 @pytest.mark.parametrize("tokens", [64, 1024], ids=["decode_tick",

@@ -51,10 +51,10 @@ def _params(cfg=TINY, seed=0, scale=8.0):
         lambda a: a * scale if a.ndim > 1 and a.shape[-1] != 1 else a, params)
 
 
-def _reference(params, tokens):
+def _reference(params, tokens, keys=SOURCE_KEYS):
     with jax.default_matmul_precision("highest"):
         return olmoe_reference.olmoe_logits(params, jnp.asarray(tokens),
-                                            SOURCE_KEYS)
+                                            keys)
 
 
 # -- the block's pieces ---------------------------------------------------
@@ -257,11 +257,11 @@ def test_init_draws_in_param_dtype_and_counts_its_parameters():
 SERVING = {"slots": 4, "page_len": 8, "max_seq_len": 96, "prefill_len": 32}
 
 
-def _slack(params, req):
+def _slack(params, req, keys=SOURCE_KEYS):
     """How far below the reference's top logit each emitted token sits
     (teacher-forced on the engine's own tokens): logits, not tokens."""
     seq = list(req.prompt) + list(req.tokens)
-    ref = np.asarray(_reference(params, [seq[:-1]]))[0]
+    ref = np.asarray(_reference(params, [seq[:-1]], keys))[0]
     rows = ref[len(req.prompt) - 1:]
     at = np.arange(len(req.tokens))
     return float((rows.max(axis=1) - rows[at, req.tokens]).max())
@@ -292,6 +292,33 @@ def test_engine_streams_sit_on_the_reference_logits(extra):
         kinds = [k for _, k, _ in eng.aux_log]
         assert kinds.count("decode") == eng._ticks or extra
         assert kinds.count("prefill") >= 4
+    finally:
+        eng.close()
+
+
+def test_engine_streams_at_head_128_run_the_direct_arm():
+    """16 heads of 128, as published: the engine's pool chooses the
+    direct arm of the paged decode kernel, and the streams still sit on
+    the reference's logits across page edges (prompts of 3 and 19, 14
+    tokens out: pages of 8 fill and open under the hand-written
+    copies)."""
+    cfg = dataclasses.replace(
+        TINY, hidden_size=2048, num_attention_heads=16,
+        num_key_value_heads=16, num_hidden_layers=1, num_experts=2,
+        intermediate_size=8, attn_impl="flash")
+    keys = {**SOURCE_KEYS, "num_attention_heads": 16, "num_experts": 2}
+    params = _params(cfg, scale=2.0)         # 2,048 wide: logits of size 1
+    eng = ServeEngine(OlmoeModel(cfg), {"serving": SERVING}, params=params)
+    rng = np.random.default_rng(7)
+    reqs = [eng.submit([int(t) for t in rng.integers(0, 256, (n,))],
+                       max_new_tokens=14) for n in (3, 19)]
+    eng.run_until_idle()
+    try:
+        assert eng.paged_decode_arm == "direct"
+        for r in reqs:
+            assert r.finish_reason == "length" and len(r.tokens) == 14
+            assert _slack(params, r, keys) <= 1e-4
+        assert eng._decode_fn._cache_size() == 1
     finally:
         eng.close()
 
@@ -364,6 +391,38 @@ def test_engine_counters_reach_the_registry():
         assert eng._moe_hit_gauge.value() == last["moe_experts_hit"]
         assert eng._moe_imbalance_gauge.value() \
             == pytest.approx(last["moe_load_imbalance"])
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("heads,head_dim,extra,arm", [
+    (16, 128, {}, "direct"), (4, 16, {}, "packed"),
+    (16, 128, {"decode_impl": "dense"}, None)],
+    ids=["head_128", "head_16", "dense_arm"])
+def test_engine_says_which_decode_arm_its_pool_chose(heads, head_dim, extra,
+                                                     arm):
+    """``paged_decode_arm{arm=}`` is set once at construction from the
+    pool's shape, 1 on the arm ``ds_paged_decode_attn`` runs: 16 heads of
+    128 (the published widths) rest as the matmul operand; where another
+    arm decodes the gauge does not exist."""
+    cfg = dataclasses.replace(
+        TINY, hidden_size=heads * head_dim, num_attention_heads=heads,
+        num_key_value_heads=heads, num_hidden_layers=1, num_experts=2,
+        intermediate_size=8, attn_impl="flash")
+    shapes = jax.eval_shape(OlmoeModel(cfg).init, jax.random.PRNGKey(0))
+    eng = ServeEngine(OlmoeModel(cfg), {
+        "serving": {**SERVING, "page_len": 16, **extra},
+        "telemetry": {"enabled": True, "output_path": os.path.join(
+            os.environ.get("TMPDIR", "/tmp"), f"olmoe_arm_{heads}")}},
+        params=jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype), shapes))
+    try:
+        assert eng.paged_decode_arm == arm
+        names = {m.name for m in eng.telemetry.registry.metrics()}
+        assert ("paged_decode_arm" in names) == (arm is not None)
+        if arm is not None:
+            gauge = eng.telemetry.registry.gauge("paged_decode_arm")
+            assert {a: gauge.value(arm=a) for a in ("direct", "packed")} \
+                == {a: int(a == arm) for a in ("direct", "packed")}
     finally:
         eng.close()
 

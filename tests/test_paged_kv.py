@@ -34,8 +34,8 @@ from deepspeed_tpu.inference.scheduler import (PagePool, PrefixCache,
 from deepspeed_tpu.models.gpt2 import (GPT2Config, GPT2Model,
                                        gpt2_prefill, gpt2_prefill_paged)
 from deepspeed_tpu.ops.pallas.decode_attention import (
-    decode_attention, decode_attention_paged, paged_gather,
-    paged_pages_per_block)
+    decode_attention, decode_attention_paged, paged_decode_arm,
+    paged_gather, paged_pages_per_block)
 from deepspeed_tpu.parallel import build_mesh
 from deepspeed_tpu.runtime.stages import reset_fault_injection
 
@@ -203,11 +203,70 @@ def test_paged_kernel_masks_dead_pages():
                                       np.asarray(dirty))
 
 
+# The direct arm (head 128, 16 heads: a page at rest is the matmul
+# operand) copies a slot's live pages by hand, one block ahead, and
+# nothing else: each case is a batch of lengths, in units of its page
+# and block.  The neighbour is ragged, slots between are free.
+DIRECT_BATCHES = {
+    "free": lambda pl_, bk, full: [0, bk + pl_ + 3, 0, 5],
+    "one": lambda pl_, bk, full: [1, bk + pl_ + 3, 0, 5],
+    "page": lambda pl_, bk, full: [pl_, bk + pl_ + 3, 0, 5],
+    "page_plus_1": lambda pl_, bk, full: [pl_ + 1, bk + pl_ + 3, 0, 5],
+    "block": lambda pl_, bk, full: [bk, bk + pl_ + 3, 0, 5],
+    "block_plus_1": lambda pl_, bk, full: [bk + 1, bk + pl_ + 3, 0, 5],
+    "full": lambda pl_, bk, full: [full, bk + pl_ + 3, 0, 5],
+    "ragged_first_free": lambda pl_, bk, full: [0, 2 * pl_ + 1, 0, full],
+    "ragged_last_free": lambda pl_, bk, full: [2 * bk, 0, bk - 1, 0],
+    "all_free": lambda pl_, bk, full: [0, 0, 0, 0],
+}
+
+
+@pytest.mark.parametrize("which", list(DIRECT_BATCHES))
+def test_paged_kernel_direct_arm(which):
+    """OLMoE's pool shape against ``impl='dense'`` in interpret mode.
+    The scratch page and every page behind a dead table entry are NaN,
+    in both pools: a dead key that reaches either matmul, masked or
+    not, fails the case (0 x NaN).  The tail of a last live page holds
+    a large finite value, as an evicted request leaves it."""
+    H, page_len, Dh, S = 16, 16, 128, 4
+    assert paged_decode_arm(H, page_len, Dh, 2) == "direct"
+    ppb = paged_pages_per_block(H, page_len, Dh, 2, 1 << 20)
+    max_pages = 2 * ppb + 1          # three blocks, the last one padded
+    bk, full = ppb * page_len, max_pages * page_len
+    lens = DIRECT_BATCHES[which](page_len, bk, full)
+    rng = np.random.RandomState(len(which))
+    P = 1 + S * max_pages
+    k = rng.randn(P, H, page_len, Dh).astype(np.float32)
+    v = rng.randn(P, H, page_len, Dh).astype(np.float32)
+    table = rng.permutation(np.arange(1, P)).reshape(S, max_pages) \
+        .astype(np.int32)
+    k_bad, v_bad, t_bad = k.copy(), v.copy(), table.copy()
+    for s, n in enumerate(lens):
+        live = -(-n // page_len)
+        for pool in (k_bad, v_bad):
+            pool[table[s, live:]] = np.nan
+            if n % page_len:
+                pool[table[s, live - 1], :, n % page_len:] = POISON
+        if live < max_pages:
+            t_bad[s, live:] = (0, table[s, -1])[s % 2]
+    k_bad[0] = v_bad[0] = np.nan
+    bf16 = lambda x: jnp.asarray(x, jnp.bfloat16)
+    out_p, out_d = _paged_both(
+        bf16(rng.randn(S, H, Dh)), bf16(k_bad), bf16(v_bad),
+        jnp.asarray(t_bad), jnp.asarray(lens, jnp.int32), bf16(k), bf16(v),
+        jnp.asarray(table))
+    out_p = np.asarray(out_p, np.float32)
+    np.testing.assert_allclose(out_p, np.asarray(out_d, np.float32),
+                               atol=1.6e-2, rtol=1.6e-2)
+    for s, n in enumerate(lens):
+        assert n or (out_p[s] == 0).all()
+
+
 XL_SERVING = dict(S=32, H=25, page_len=16, max_pages=64, Dh=64, P=833)
 
 
-def _paged_call_grid(S, H, page_len, max_pages, Dh, P):
-    """Grid of the Mosaic call that ``impl='pallas'`` traces."""
+def _paged_call(S, H, page_len, max_pages, Dh, P):
+    """The Mosaic call that ``impl='pallas'`` traces."""
     sds = jax.ShapeDtypeStruct
     pool = sds((P, H, page_len, Dh), jnp.bfloat16)
     jaxpr = jax.make_jaxpr(lambda *a: decode_attention_paged(
@@ -216,7 +275,15 @@ def _paged_call_grid(S, H, page_len, max_pages, Dh, P):
             sds((S, max_pages), jnp.int32), sds((S,), jnp.int32))
     calls = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
     assert len(calls) == 1
-    return tuple(calls[0].params["grid_mapping"].grid)
+    return calls[0]
+
+
+def _paged_call_grid(**shapes):
+    return tuple(_paged_call(**shapes).params["grid_mapping"].grid)
+
+
+def _paged_call_operands(**shapes):
+    return len(_paged_call(**shapes).invars)
 
 
 def test_paged_kernel_grid_at_xl_serving_shapes():
@@ -244,6 +311,41 @@ def test_paged_kernel_grid_at_xl_serving_shapes():
         "heads", "page_len", "head_dim", "itemsize", "max_pages"]
     source = inspect.getsource(module)
     assert "environ" not in source and "getenv" not in source
+
+
+OLMOE_SERVING = dict(S=64, H=16, page_len=16, max_pages=128, Dh=128,
+                     P=3457)
+
+
+def test_paged_arm_follows_the_pool_shape_alone():
+    """Which body ``ds_paged_decode_attn`` runs is read off (heads,
+    page_len, head_dim, itemsize): GPT-2 XL's pool keeps the packed arm,
+    8 pages a block and the grid 32 x 8 (its program is the parent's);
+    OLMoE's takes the direct one, whose pools stay in HBM (six operands
+    whatever the block, where the packed arm has one a page)."""
+    import inspect
+    assert list(inspect.signature(paged_decode_arm).parameters) == [
+        "heads", "page_len", "head_dim", "itemsize"]
+    x, o = XL_SERVING, OLMOE_SERVING
+    assert paged_decode_arm(x["H"], x["page_len"], x["Dh"], 2) == "packed"
+    assert paged_pages_per_block(x["H"], x["page_len"], x["Dh"], 2,
+                                 x["max_pages"]) == 8
+    assert _paged_call_grid(**x) == (32, 8)
+    assert _paged_call_operands(**x) == 4 + 2 * 8
+    assert paged_decode_arm(o["H"], o["page_len"], o["Dh"], 2) == "direct"
+    ppb = paged_pages_per_block(o["H"], o["page_len"], o["Dh"], 2,
+                                o["max_pages"])
+    assert _paged_call_grid(**o) == (64, o["max_pages"] // ppb)
+    assert _paged_call_operands(**o) == 6
+    # whole lanes, whole sublane tiles of heads, nothing folded
+    assert {shape: paged_decode_arm(*shape) for shape in [
+        (32, 16, 128, 2), (16, 16, 128, 4), (16, 16, 256, 2),
+        (8, 16, 128, 2), (25, 16, 128, 2), (16, 16, 64, 2),
+        (12, 16, 64, 2)]} == {
+        (32, 16, 128, 2): "direct", (16, 16, 128, 4): "direct",
+        (16, 16, 256, 2): "direct", (8, 16, 128, 2): "packed",
+        (25, 16, 128, 2): "packed", (16, 16, 64, 2): "packed",
+        (12, 16, 64, 2): "packed"}
 
 
 def test_decode_prep_span_counts_live_blocks(tmp_path):

@@ -144,8 +144,12 @@ def _refuse_unsupported(model, serving) -> None:
     """A model that lacks a serving arm says so in
     ``serving_unsupported``; a configuration that asks for one is
     refused here, not deep in a trace."""
-    lacks = getattr(model, "serving_unsupported", ())
+    lacks = set(getattr(model, "serving_unsupported", ()))
+    stateful = hasattr(model, "serving_state")
+    if stateful:
+        lacks |= set(_STATE_UNSUPPORTED)
     quant = serving.quantization
+    parks = serving.kv_tier[C.SERVING_KV_TIER_IDLE_PARK_TICKS] > 0
     arms = {    # arm: (the configuration asks for it, as the message says it)
         "slot_cache": (serving.page_len == 0,
                        "serving.page_len: 0 (set page_len > 0)"),
@@ -153,13 +157,29 @@ def _refuse_unsupported(model, serving) -> None:
         "quantization": ("int8" in (quant["weights"], quant["kv"]),
                          "serving.quantization int8"),
         "lora": (int(serving.lora["rank"]) > 0, "serving.lora.rank > 0"),
+        "prefix_cache": (bool(serving.prefix_cache) and serving.page_len > 0,
+                         "serving.prefix_cache: true (set it false)"),
+        "prefill_chunk_len": (serving.prefill_chunk_len > 0,
+                              "serving.prefill_chunk_len > 0"),
+        "kv_tier": (parks, "serving.kv_tier.idle_park_ticks > 0"),
     }
     bad = [how for arm, (asked, how) in arms.items()
            if asked and arm in lacks]
     if bad:
         raise ValueError(
             f"{type(model).__name__} cannot be served with "
-            + "; ".join(bad) + ": its serving steps have no such arm")
+            + "; ".join(bad) + ": its serving steps have no such arm"
+            + (" (it keeps request state by slot, 'serving_state': a page "
+               "of keys without the state at its boundary is no prefix, "
+               "and a chunk, a parked session or a drafted token needs a "
+               "snapshot of it)" if stateful else ""))
+
+
+#: what the engine cannot do yet for a model that keeps request state by
+#: slot (``serving_state``), whatever the model's own steps have: each
+#: needs the state saved at a boundary, which nothing does
+_STATE_UNSUPPORTED = ("prefix_cache", "prefill_chunk_len", "kv_tier",
+                      "slot_cache", "speculate_k")
 
 
 def _percentile(sorted_vals: List[float], q: float) -> Optional[float]:
@@ -172,10 +192,13 @@ class ServeEngine:
 
     **The serving protocol** (what the engine, and the benchmark's probe,
     read of ``model``; ``GPT2Model`` and ``OlmoeModel`` are its two
-    implementations):
+    implementations; ``NemotronHModel`` is a third, with request state):
 
     * ``model.config`` with ``n_layer``, ``n_head``, ``d_head`` (the KV
-      pool's shape), ``n_positions`` (the longest sequence) and
+      pool's shape; ``n_layer`` counts the layers that keep keys, and a
+      config with ``n_kv_head`` has that many key heads in the pool,
+      under ``n_head`` query heads), ``n_positions`` (the longest
+      sequence) and
       ``attn_impl`` (``'flash'`` | ``'dense'``: which decode arm
       ``serving.decode_impl: auto`` takes); ``d_model`` with LoRA;
     * ``model.init(rng)`` -> a params dict whose ``"wte"`` leaf has the
@@ -196,9 +219,28 @@ class ServeEngine:
       ``v_scale`` operands and an int8 weight tree (``quantization``),
       the ``lora`` / ``adapter_slots`` operands (``lora``).
 
+    * request state by slot (optional): a model with
+      ``serving_state(slots)`` -> ``{name: ShapeDtypeStruct}``, each
+      leaf ``[..., slots, ...]`` with the slot on axis 1 and of a fixed
+      size (``NemotronHModel``: the Mamba-2 recurrent state and conv
+      window of each mixer layer).  The engine allocates it once, beside
+      the pools, under ``cache["state"]``; both paged steps take it as
+      ``state=`` and return it after the pools (``prefill_paged(...,
+      state=, slot=)`` -> ``(logits, k_pool, v_pool, state)``,
+      ``decode_step_paged(..., state=)`` -> ``(logits, k_pool, v_pool,
+      state, new_lengths)``), donated with the rest of the cache.  A
+      prefill OVERWRITES the state of the slot it is told (the slot the
+      request is admitted to); a decode tick leaves an inactive slot's
+      state alone; no program clears state.  It is never paged, shared
+      or migrated: ``prefix_cache``, ``prefill_chunk_len``, ``kv_tier``,
+      the slot cache, ``speculate_k``, ``export_pages`` and
+      ``adopt_request`` are refused for such a model.
+
     A model names the arms it lacks in ``serving_unsupported`` (of
-    ``'slot_cache'``, ``'speculate_k'``, ``'quantization'``, ``'lora'``)
-    and the engine refuses such a configuration here, at construction.
+    ``'slot_cache'``, ``'speculate_k'``, ``'quantization'``, ``'lora'``,
+    ``'prefix_cache'``, ``'prefill_chunk_len'``, ``'kv_tier'``) and the
+    engine refuses such a configuration here, at construction, with
+    ``ValueError``.
     A model that names counters in ``serving_aux`` (OLMoE:
     ``moe_experts_hit``, ``moe_rows``, ``moe_load_imbalance``) takes
     ``aux=True`` in its paged steps and returns one more output, a dict
@@ -289,6 +331,11 @@ class ServeEngine:
         self._aux = self.paged and bool(self._aux_keys)
         self.aux_log: deque = deque(maxlen=65536)
         self._aux_pending = ()
+        #: request state by slot (class docstring): name -> shape and
+        #: dtype, {} for a model that keeps none
+        self._state_spec = (dict(model.serving_state(self.slots))
+                            if self.paged and hasattr(model, "serving_state")
+                            else {})
         #: chunked prefill (Sarathi-Serve, PAPERS.md; docs/serving.md
         #: "disaggregated fleet"): > 0 = prompts with a longer uncached
         #: delta admit immediately and prefill one chunk per step(),
@@ -314,9 +361,11 @@ class ServeEngine:
                 pages = 1 + self.slots * self.max_pages
                 dp = mesh.shape.get(DATA_AXIS, 1)
                 pages += (-pages) % dp
+            # grouped keys: the pool holds the KEY heads
+            kv_heads = getattr(mcfg, "n_kv_head", mcfg.n_head)
             self.cache_spec = PagedKVCacheSpec(
                 layers=mcfg.n_layer, slots=self.slots,
-                heads=mcfg.n_head, pages=pages, page_len=self.page_len,
+                heads=kv_heads, pages=pages, page_len=self.page_len,
                 head_dim=mcfg.d_head, max_pages=self.max_pages,
                 dtype=(jnp.int8 if self.quant_kv else kv_dtype),
                 quant=self.quant_kv)
@@ -325,6 +374,15 @@ class ServeEngine:
                 mesh, quant=self.quant_kv)
             self.cache = shard_cache(init_paged_cache(self.cache_spec),
                                      mesh, self._cache_shardings)
+            if self._state_spec:
+                # request state by slot (class docstring): made where it
+                # lives, replicated like the lengths
+                rep = NamedSharding(mesh, P())
+                self._cache_shardings["state"] = {
+                    k: rep for k in self._state_spec}
+                self.cache["state"] = {
+                    k: jax.device_put(jnp.zeros(v.shape, v.dtype), rep)
+                    for k, v in self._state_spec.items()}
             self.pool = PagePool(pages)
             self.prefix = (PrefixCache(self.page_len, self.pool)
                            if cfg.serving.prefix_cache else None)
@@ -340,11 +398,13 @@ class ServeEngine:
                     and not self.spec_k):
                 from ..ops.pallas.decode_attention import (
                     paged_decode_arm, paged_pages_per_block)
-                shape = (mcfg.n_head, self.page_len, mcfg.d_head,
+                shape = (kv_heads, self.page_len, mcfg.d_head,
                          jnp.dtype(kv_dtype).itemsize)
+                grouped = ({"q_heads": mcfg.n_head}
+                           if kv_heads != mcfg.n_head else {})
                 self._pages_per_block = paged_pages_per_block(
-                    *shape, self.max_pages)
-                self.paged_decode_arm = paged_decode_arm(*shape)
+                    *shape, self.max_pages, **grouped)
+                self.paged_decode_arm = paged_decode_arm(*shape, **grouped)
         else:
             self.pool = None
             self.prefix = None
@@ -491,6 +551,7 @@ class ServeEngine:
             aux_kw = {"aux": True} if self._aux else {}
 
             aux_keys = self._aux_keys
+            stateful = bool(self._state_spec)
 
             def pack_aux(counters):
                 """One float32 vector for the host to fetch, in the
@@ -511,9 +572,11 @@ class ServeEngine:
             def serve_prefill(params, cache, tokens, delta_len,
                               prefix_len, page_row, slot, *extra):
                 lkw, rng = split_lora(extra)
+                skw = ({"state": cache["state"], "slot": slot}
+                       if stateful else {})
                 out = self.model.prefill_paged(
                     params, tokens, delta_len, prefix_len, page_row,
-                    cache["k"], cache["v"], **lkw, **aux_kw,
+                    cache["k"], cache["v"], **lkw, **aux_kw, **skw,
                     **cache_scales(cache))
                 logits, kp, vp = out[0], out[1], out[2]
                 total = jnp.reshape(prefix_len + delta_len,
@@ -527,6 +590,8 @@ class ServeEngine:
                 newc = {"k": kp, "v": vp, "lengths": lengths}
                 if quant_kv:
                     newc["k_scale"], newc["v_scale"] = out[3], out[4]
+                if stateful:
+                    newc["state"] = out[3]
                 if aux_kw:
                     return newc, first_tok, pack_aux(out[-1])
                 return newc, first_tok
@@ -537,7 +602,8 @@ class ServeEngine:
                 out = self.model.decode_step_paged(
                     params, tokens, cache["k"], cache["v"], page_table,
                     cache["lengths"], active, impl=self.decode_impl,
-                    **lkw, **aux_kw, **cache_scales(cache))
+                    **lkw, **aux_kw, **cache_scales(cache),
+                    **({"state": cache["state"]} if stateful else {}))
                 stats = ()
                 if aux_kw:
                     out, stats = out[:-1], (pack_aux(out[-1]),)
@@ -547,6 +613,8 @@ class ServeEngine:
                 newc = {"k": k, "v": v, "lengths": new_len}
                 if quant_kv:
                     newc["k_scale"], newc["v_scale"] = out[3], out[4]
+                if stateful:
+                    newc["state"] = out[3]
                 return (newc, next_tok) + stats
 
             # copy-on-write: duplicate one page (src/dst traced — zero
@@ -704,6 +772,12 @@ class ServeEngine:
         from .quantize import param_nbytes
         self.param_bytes = param_nbytes(self.params)
         self.kv_bytes = self.cache_spec.bytes
+        #: bytes of request state by kind, a stateful model's only
+        self.state_bytes = {
+            k: int(np.prod(v.shape)) * jnp.dtype(v.dtype).itemsize
+            for k, v in self._state_spec.items()}
+        if self.state_bytes:
+            self.state_bytes["kv"] = self.kv_bytes
         if self.spec_k:
             self.param_bytes += param_nbytes(self.draft_params)
             self.kv_bytes += self.draft_cache_spec.bytes
@@ -776,6 +850,14 @@ class ServeEngine:
                     for arm in ("direct", "packed"):
                         arm_gauge.set(int(arm == self.paged_decode_arm),
                                       arm=arm)
+            if self._state_spec:
+                state_gauge = reg.gauge(
+                    "serve_state_bytes",
+                    "device bytes a stateful model's requests hold by "
+                    "kind: each serving_state leaf (ssm, conv) and the "
+                    "page pool (kv)")
+                for kind, nbytes in self.state_bytes.items():
+                    state_gauge.set(nbytes, kind=kind)
             if self._aux:
                 self._moe_hit_gauge = reg.gauge(
                     "serve_moe_experts_hit",
@@ -2217,6 +2299,7 @@ class ServeEngine:
             raise RuntimeError(
                 "export_pages needs a paged engine and a finished "
                 "detach_kv request still holding its pages")
+        self._refuse_migration("export_pages")
         out = []
         for pid in req.pages:
             with self._span("serve/page_out", rid=req.rid, page=pid):
@@ -2227,6 +2310,14 @@ class ServeEngine:
             out.append(b"".join(np.asarray(s).tobytes()
                                 for s in slices))
         return out
+
+    def _refuse_migration(self, what: str) -> None:
+        if self._state_spec:
+            raise NotImplementedError(
+                f"{what}: {type(self.model).__name__} keeps request state "
+                f"by slot ({', '.join(sorted(self._state_spec))}) that "
+                "pages do not carry; migrating a request needs a snapshot "
+                "of it")
 
     def release_detached(self, req: Request) -> None:
         """Drop the pages a ``detach_kv`` finish kept alive — the
@@ -2284,6 +2375,7 @@ class ServeEngine:
         retries, the same backpressure contract as admission."""
         if not self.paged:
             raise RuntimeError("KV adoption requires the paged layout")
+        self._refuse_migration("adopt_request")
         prompt = [int(t) for t in np.asarray(prompt).reshape(-1)]
         need = -(-len(prompt) // self.page_len)
         if need != len(page_payloads):

@@ -122,7 +122,11 @@ def validate_cache_mesh(mesh: Mesh, spec: KVCacheSpec) -> None:
 class PagedKVCacheSpec:
     """The flat page pool: ``pages`` fixed-size pages of ``page_len``
     tokens each (page 0 reserved as the scratch page), referenced by
-    per-slot page tables the host owns.
+    per-slot page tables the host owns.  ``heads`` counts the KEY heads:
+    under grouped keys (a model config with ``n_kv_head``) fewer than the
+    query heads that read them.  What a request keeps beside its pages
+    (a ``serving_state`` model's recurrent state, by slot) is not in
+    this spec: ``ServeEngine`` allocates it under ``cache["state"]``.
 
     ``quant`` (serving.quantization.kv='int8', docs/serving.md): the
     pool stores int8 rows (``dtype`` must be int8) plus a fp32 scale

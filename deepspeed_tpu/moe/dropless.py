@@ -24,6 +24,32 @@ expert: bound by reading the hit experts' weights):
 The stacked weights of ALL layers reach the kernels whole
 (``[L*E, d, f]``) with the layer's offset added to the tile's expert, so a
 layer scan slices (copies) no expert matrix.
+
+Three things a caller may hand in instead of OLMoE's defaults (NVIDIA
+Nemotron-3 Super's latent experts, ``models/nemotron_h.py``):
+
+* ``routing=(weights, experts)``: the (token, expert) assignments of a
+  router of the caller's own (:func:`route_sigmoid_topk`: sigmoid scores,
+  a selection bias, renormalised and scaled); :func:`route_topk` stays
+  OLMoE's;
+* ``experts_held=(first, count)``: this chip's share of the layer's
+  experts.  The router still ranges over ALL experts and picks ``top_k``
+  of them; assignments to an expert outside ``[first, first + count)``
+  are left out of the rows, of the statistics and of the sum, and are
+  counted (``HeldMoEStats.rows_elsewhere``).  What comes back is this
+  share's PART of the layer's result; adding the parts of all shares
+  gives the whole layer (``tests/test_nemotron_h.py`` holds that).  No
+  code stands in for the chips that hold the others or for the exchange
+  with them.  The weights handed in are the held experts' only.  The
+  static tile count ``N*k // tm + count`` is sound whatever share of the
+  assignments lands here (every one of them may), the row tile is sized
+  for the share that does on average, and the dead tiles are skipped;
+* ``act="relu2"``: a two-matrix expert ``relu(x @ up)**2 @ down`` (no
+  gate), kernel ``ds_moe_up_relu2`` beside ``ds_moe_down``.  The
+  benchmark's ``moe_*`` metric files say "three matrices" and "two
+  Mosaic kernels (ds_moe_gate_up, ds_moe_down)": their readers match
+  ``^ds_moe_``, so for a relu2 layer read "two matrices" and
+  ``ds_moe_up_relu2``.
 """
 from __future__ import annotations
 
@@ -40,6 +66,7 @@ from ..ops.pallas.runtime import use_interpret
 # naming"): trace rows are ``ds_moe_gate_up.<n>`` / ``ds_moe_down.<n>``.
 MOE_GATE_UP_KERNEL = "ds_moe_gate_up"
 MOE_DOWN_KERNEL = "ds_moe_down"
+MOE_UP_RELU2_KERNEL = "ds_moe_up_relu2"
 
 #: both of an expert's up-projections in flight, double-buffered, are
 #: 16 MiB at OLMoE's widths (2 x 2 x 2048 x 1024 bf16): over Mosaic's
@@ -55,6 +82,15 @@ class MoEStats(NamedTuple):
     rows: jnp.ndarray             # live assignments (valid tokens x k)
 
 
+class HeldMoEStats(NamedTuple):
+    """:class:`MoEStats` of a share of the experts (``experts_held``):
+    the first three count the held experts' rows only."""
+    experts_hit: jnp.ndarray
+    max_rows: jnp.ndarray
+    rows: jnp.ndarray
+    rows_elsewhere: jnp.ndarray   # live assignments to experts not held
+
+
 def route_topk(x, router_w, top_k: int, renormalize: bool = False):
     """x [N, d], router_w [d, E] -> (weights [N, k] float32, experts
     [N, k] int32): softmax over all E in float32, then the k largest."""
@@ -64,6 +100,24 @@ def route_topk(x, router_w, top_k: int, renormalize: bool = False):
     if renormalize:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     return weights, experts.astype(jnp.int32)
+
+
+def route_sigmoid_topk(x, router_w, select_bias, top_k: int,
+                       scale: float = 1.0, renormalize: bool = True):
+    """x [N, d], router_w [d, E], select_bias [E] -> (weights [N, k]
+    float32, experts [N, k] int32).  Scores ``sigmoid(x @ router_w)`` in
+    float32; the k experts with the largest ``score + select_bias`` are
+    chosen (the bias steers the choice only); their weights are their
+    own scores, renormalised to sum 1 if asked, times ``scale``."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, experts = jax.lax.top_k(scores + select_bias.astype(jnp.float32),
+                               top_k)
+    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    if renormalize:
+        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+    return weights * scale, experts.astype(jnp.int32)
 
 
 def row_tile(assignments: int, n_experts: int) -> int:
@@ -84,6 +138,20 @@ def _gate_up_kernel(te_ref, live_ref, x_ref, wg_ref, wu_ref, h_ref):
         g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
         u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
         h_ref[...] = (g * jax.nn.sigmoid(g) * u).astype(h_ref.dtype)
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        h_ref[...] = jnp.zeros_like(h_ref)
+
+
+def _up_relu2_kernel(te_ref, live_ref, x_ref, wu_ref, h_ref):
+    live = pl.program_id(0) < live_ref[0]
+
+    @pl.when(live)
+    def _():
+        u = jnp.maximum(jnp.dot(x_ref[...], wu_ref[0],
+                                preferred_element_type=jnp.float32), 0.0)
+        h_ref[...] = (u * u).astype(h_ref.dtype)
 
     @pl.when(jnp.logical_not(live))
     def _():
@@ -130,7 +198,8 @@ def _grouped(kernel, name, rows, weights, tile_expert, n_live, tm, width,
 
 def dropless_moe(x, router_w, gate_w, up_w, down_w, top_k: int, *,
                  expert_offset=0, valid=None, renormalize: bool = False,
-                 interpret: Optional[bool] = None):
+                 interpret: Optional[bool] = None, routing=None,
+                 experts_held=None, act: str = "swiglu"):
     """x [N, d] -> (y [N, d], :class:`MoEStats`).
 
     ``router_w`` [d, E] is this layer's; ``gate_w`` / ``up_w``
@@ -138,19 +207,41 @@ def dropless_moe(x, router_w, gate_w, up_w, down_w, top_k: int, *,
     row ``expert_offset`` (a traced scalar: ``layer * E`` into the
     stacked weights of every layer, 0 for one layer's own).  ``valid``
     [N] bool leaves padding rows out: they reach no expert, count in no
-    statistic and get zeros."""
+    statistic and get zeros.
+
+    ``routing``, ``experts_held`` and ``act`` are the module docstring's
+    three: with ``routing`` the router is the caller's (of ``router_w``
+    only the shape is read: over how many experts it ranged); with
+    ``experts_held`` the weights hold those experts only and the
+    statistics are :class:`HeldMoEStats`; with ``act='relu2'`` ``gate_w``
+    is None."""
     n, d = x.shape
     e = router_w.shape[-1]
-    width = gate_w.shape[-1]
+    width = up_w.shape[-1]
+    if act not in ("swiglu", "relu2"):
+        raise ValueError(f"act {act!r}: 'swiglu' or 'relu2'")
     if interpret is None:
         interpret = use_interpret()
     with jax.named_scope("moe"):
-        weights, experts = route_topk(x, router_w, top_k, renormalize)
+        if routing is None:
+            weights, experts = route_topk(x, router_w, top_k, renormalize)
+        else:
+            weights, experts = routing
         flat = experts.reshape(-1)                          # [A]
+        a = n * top_k
+        share = a
+        if experts_held is not None:
+            first, count = experts_held
+            # the share of the assignments that lands here on average
+            share, e = a * count // e, count
+            flat = flat - first
+            elsewhere = (flat < 0) | (flat >= e)
+            flat = jnp.where(elsewhere, e, flat)
+            if valid is not None:
+                elsewhere = elsewhere & jnp.repeat(valid, top_k)
         if valid is not None:
             flat = jnp.where(jnp.repeat(valid, top_k), flat, e)
-        a = n * top_k
-        tm = row_tile(a, e)
+        tm = row_tile(share, e)
         tiles = a // tm + e
         i32 = jnp.int32
 
@@ -180,8 +271,12 @@ def dropless_moe(x, router_w, gate_w, up_w, down_w, top_k: int, *,
 
         te = tile_expert + jnp.asarray(expert_offset, i32)
         live = jnp.reshape(n_live, (1,)).astype(i32)
-        h = _grouped(_gate_up_kernel, MOE_GATE_UP_KERNEL, x_rows,
-                     (gate_w, up_w), te, live, tm, width, interpret)
+        if act == "swiglu":
+            h = _grouped(_gate_up_kernel, MOE_GATE_UP_KERNEL, x_rows,
+                         (gate_w, up_w), te, live, tm, width, interpret)
+        else:
+            h = _grouped(_up_relu2_kernel, MOE_UP_RELU2_KERNEL, x_rows,
+                         (up_w,), te, live, tm, width, interpret)
         y_rows = _grouped(_down_kernel, MOE_DOWN_KERNEL, h, (down_w,),
                           te, live, tm, d, interpret)
 
@@ -196,4 +291,7 @@ def dropless_moe(x, router_w, gate_w, up_w, down_w, top_k: int, *,
         stats = MoEStats(experts_hit=jnp.sum(counts > 0).astype(i32),
                          max_rows=jnp.max(counts).astype(i32),
                          rows=jnp.sum(counts).astype(i32))
+        if experts_held is not None:
+            stats = HeldMoEStats(*stats, rows_elsewhere=jnp.sum(
+                elsewhere).astype(i32))
         return y.astype(x.dtype), stats

@@ -388,12 +388,16 @@ def _paged_block_layout(heads, page_len, head_dim, itemsize):
 
 
 def paged_decode_arm(heads: int, page_len: int, head_dim: int,
-                     itemsize: int) -> str:
+                     itemsize: int, q_heads: Optional[int] = None) -> str:
     """Which body of the fp paged kernel a pool of this shape runs.
     ``'direct'`` where a page at rest, ``[page_len, H, Dh]``, already is
     the rows of the packed buffer (no fold, no padded head rows, whole
     lanes): the fetched page is the matmul operand.  ``'packed'``
-    everywhere else.  A function of the pool's shape alone."""
+    everywhere else.  A function of the pool's shape alone.  Grouped
+    keys (``q_heads`` query heads on ``heads`` key heads) have the
+    direct body only: their page at rest is ``[H, page_len, Dh]``."""
+    if q_heads not in (None, heads):
+        return "direct"
     fold, head_rows, _, _ = _paged_block_layout(
         heads, page_len, head_dim, itemsize)
     direct = fold == 1 and head_rows == heads and head_dim % _LANES == 0
@@ -414,11 +418,18 @@ def paged_page_vmem_bytes(heads: int, page_len: int, head_dim: int,
 
 
 def paged_pages_per_block(heads: int, page_len: int, head_dim: int,
-                          itemsize: int, max_pages: int) -> int:
+                          itemsize: int, max_pages: int,
+                          q_heads: Optional[int] = None) -> int:
     """Pages one grid step of the fp paged kernel attends: the largest
     power of two that fits ``PAGED_KV_VMEM_BUDGET``, at most
-    ``max_pages``.  A function of the pool's shape alone."""
-    page_bytes = paged_page_vmem_bytes(heads, page_len, head_dim, itemsize)
+    ``max_pages``.  A function of the pool's shape alone.  With grouped
+    keys a page in flight is its own bytes (K and V, double-buffered):
+    ``[H, page_len, Dh]`` pads nothing."""
+    if q_heads not in (None, heads):
+        page_bytes = 4 * heads * page_len * head_dim * itemsize
+    else:
+        page_bytes = paged_page_vmem_bytes(heads, page_len, head_dim,
+                                           itemsize)
     fit = max(1, min(PAGED_KV_VMEM_BUDGET // page_bytes, max_pages))
     return 1 << (fit.bit_length() - 1)
 
@@ -588,7 +599,8 @@ def _decode_paged_pallas(q, k_pages, v_pages, page_table, lengths, *,
 def _decode_paged_direct_kernel(pt_ref, len_ref, q_ref, pos_ref,
                                 k_hbm, v_hbm, o_ref, k_buf, v_buf, sems,
                                 state_ref, m_scr, l_scr, acc_scr,
-                                *, sm_scale: float):
+                                *, sm_scale: float,
+                                page_len: Optional[int] = None):
     """The direct arm: the same grid step (one slot, all heads, ``ppb``
     pages), for pools whose page at rest, ``[page_len, H, Dh]``, already
     is rows ``(r, h)`` of the buffer the matmuls read.  The pools stay in
@@ -600,7 +612,9 @@ def _decode_paged_direct_kernel(pt_ref, len_ref, q_ref, pos_ref,
     reads, and whether any step has started a copy yet."""
     s, j = pl.program_id(0), pl.program_id(1)
     slots, nb = pl.num_programs(0), pl.num_programs(1)
-    _, ppb, page_len, heads, head_dim = k_buf.shape
+    ppb, head_dim = k_buf.shape[1], k_buf.shape[-1]
+    if page_len is None:
+        page_len = k_buf.shape[2]       # a page [page_len, H, Dh]
     bk = ppb * page_len
     length = len_ref[s]
 
@@ -665,7 +679,7 @@ def _decode_paged_direct_kernel(pt_ref, len_ref, q_ref, pos_ref,
         def _ahead():
             fetch(next_s, jnp.where(more, j + 1, 0), 1 - half)
 
-        rows = ppb * page_len * heads
+        rows = k_buf.shape[1] * k_buf.shape[2] * k_buf.shape[3]
         wait(0, half)
         sc = jax.lax.dot_general(
             q_ref[0], k_buf[half].reshape(rows, head_dim),
@@ -739,6 +753,67 @@ def _decode_paged_direct_pallas(q, k_pages, v_pages, page_table, lengths, *,
         interpret=interpret,
         name=PAGED_DECODE_ATTN_KERNEL,
     )(pt_flat, lengths, q, pos, kt, vt)
+
+
+@functools.lru_cache(maxsize=None)
+def _grouped_block_positions(q_heads, kv_heads, page_len, ppb):
+    """:func:`_paged_block_positions` for grouped keys: ``[q_heads,
+    ppb*kv_heads*page_len]``.  Buffer row ``(i, g, r)`` holds key ``r``
+    of page ``i`` of key head ``g``; query head ``h`` reads key head
+    ``h // (q_heads // kv_heads)`` and no other."""
+    import numpy as np
+    pos = np.full((q_heads, ppb, kv_heads, page_len), 2 ** 30, np.int32)
+    own = np.arange(q_heads) // (q_heads // kv_heads)
+    at = np.arange(ppb)[:, None] * page_len + np.arange(page_len)[None, :]
+    pos[np.arange(q_heads), :, own, :] = at[None]
+    return pos.reshape(q_heads, -1)
+
+
+def _decode_paged_grouped_pallas(q, k_pages, v_pages, page_table, lengths, *,
+                                 sm_scale, interpret):
+    """The direct body for ``Hq`` query heads on ``H`` key heads.  A page
+    at rest is ``[H, page_len, Dh]`` (the pool as the engine holds it:
+    no transpose), so a block's pages are rows ``(page, key head, key)``
+    of the matmul operand and the ``Hq / H`` query heads of a key head
+    are the rows that read it; the position table keeps every query head
+    to its own key head's rows."""
+    P, H, page_len, Dh = k_pages.shape
+    S, max_pages = page_table.shape
+    Hq = q.shape[1]
+    ppb = paged_pages_per_block(H, page_len, Dh, k_pages.dtype.itemsize,
+                                max_pages, q_heads=Hq)
+    nb = -(-max_pages // ppb)
+    pt_flat = jnp.pad(page_table,
+                      ((0, 0), (0, nb * ppb - max_pages))).reshape(-1)
+    pos = jnp.asarray(_grouped_block_positions(Hq, H, page_len, ppb))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(S, nb),
+        in_specs=[pl.BlockSpec((1, Hq, Dh), lambda s, j, *_: (s, 0, 0)),
+                  pl.BlockSpec(pos.shape, lambda s, j, *_: (0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, Hq, Dh), lambda s, j, *_: (s, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, ppb, H, page_len, Dh), k_pages.dtype),
+            pltpu.VMEM((2, ppb, H, page_len, Dh), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((2,), jnp.int32),
+            pltpu.VMEM((Hq, 128), jnp.float32),
+            pltpu.VMEM((Hq, 128), jnp.float32),
+            pltpu.VMEM((Hq, Dh), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_decode_paged_direct_kernel, sm_scale=sm_scale,
+                          page_len=page_len),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, Hq, Dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name=PAGED_DECODE_ATTN_KERNEL,
+    )(pt_flat, lengths, q, pos, k_pages, v_pages)
 
 
 def _decode_paged_int8_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref,
@@ -863,7 +938,10 @@ def decode_attention_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
                            ) -> jnp.ndarray:
     """Single-query attention over a PAGED KV pool (docs/serving.md).
 
-    q: [S, H, Dh] — one new query token per slot.
+    q: [S, H, Dh] — one new query token per slot; ``[S, Hq, Dh]``
+        with ``Hq`` a multiple of the pool's ``H`` for grouped keys
+        (query head ``h`` reads key head ``h // (Hq // H)``; fp pool
+        only).
     k_pages, v_pages: [P, H, page_len, Dh] — the flat page pool; a
         slot's position ``p`` lives at row ``p % page_len`` of page
         ``page_table[s, p // page_len]``.
@@ -888,10 +966,28 @@ def decode_attention_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
     assert q.ndim == 3 and k_pages.ndim == 4, (q.shape, k_pages.shape)
     P, H, page_len, Dh = k_pages.shape
     S, max_pages = page_table.shape
-    assert q.shape == (S, H, Dh), (q.shape, k_pages.shape)
+    Hq = q.shape[1]
+    assert q.shape == (S, Hq, Dh) and Hq % H == 0, (q.shape, k_pages.shape)
     _check_quant_args(k_pages, k_scale, v_scale, "decode_attention_paged")
     if sm_scale is None:
         sm_scale = _default_scale(Dh)
+    if Hq != H:
+        if k_scale is not None:
+            raise NotImplementedError(
+                "decode_attention_paged: grouped keys have no int8 arm")
+        if impl == "dense":
+            kg, vg = (jnp.repeat(paged_gather(x, page_table), Hq // H,
+                                 axis=1) for x in (k_pages, v_pages))
+            return decode_attention_reference(q, kg, vg, lengths,
+                                              sm_scale=sm_scale)
+        if impl != "pallas":
+            raise ValueError(
+                f"decode_attention_paged impl={impl!r}: expected 'pallas' "
+                "or 'dense'")
+        return _decode_paged_grouped_pallas(
+            q, k_pages, v_pages, page_table.astype(jnp.int32),
+            lengths.astype(jnp.int32), sm_scale=sm_scale,
+            interpret=_use_interpret() if interpret is None else interpret)
     if impl == "dense":
         if k_scale is not None:
             kg = dequantize_paged(k_pages, k_scale, page_table)

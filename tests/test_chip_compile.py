@@ -533,3 +533,138 @@ def test_olmoe_programs_hold_their_named_kernels(program, one_chip):
     temp = compiled.memory_analysis().temp_size_in_bytes
     limit = pool_bytes // 4 if program == "serve_decode" else None
     assert limit is None or temp < limit, (temp, pool_bytes)
+
+
+# ---------------------------------------------------------------------------
+# Nemotron-3 Super at its published widths and the cell's own shapes
+# (benchmark/configs/nemotron-3-super-120b-a12b.json: MEMEM*EMEME, 128 of
+# 512 experts held, 192 slots of recurrent state, 24,577 pages of 2 key
+# heads): the Mamba-2 decode update, the relu2 expert kernels, the paged
+# decode kernel at 32 query heads on 2 key heads, and both serve programs
+# ---------------------------------------------------------------------------
+
+NEMO_SLOTS, NEMO_PAGES, NEMO_MAX_PAGES = 192, 24577, 320
+
+
+def _nemotron():
+    from deepspeed_tpu.models.nemotron_h import (NemotronHConfig,
+                                                 NemotronHModel)
+    model = NemotronHModel(NemotronHConfig(
+        vocab_size=32768, experts_held=(0, 128), param_dtype="bfloat16"))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    pool = _sds((1, NEMO_PAGES, 2, PAGE_LEN, 128))
+    return model, params, pool, model.serving_state(NEMO_SLOTS)
+
+
+def _nemotron_program(program, one_chip):
+    """The model's paged step as the engine calls it: pools and state
+    donated."""
+    model, params, pool, state = _nemotron()
+    i32, s = _sds((), jnp.int32), NEMO_SLOTS
+    if program == "serve_decode":
+        def fn(p, t, k, v, tab, ln, act, st):
+            return model.decode_step_paged(p, t, k, v, tab, ln, act,
+                                           state=st, impl="pallas", aux=True)
+        shapes = (params, _sds((s,), jnp.int32), pool, pool,
+                  _sds((s, NEMO_MAX_PAGES), jnp.int32), _sds((s,), jnp.int32),
+                  _sds((s,), jnp.bool_), state)
+        donate = (2, 3, 7)
+    else:
+        def fn(p, t, n, row, k, v, st, slot):
+            return model.prefill_paged(p, t, n, jnp.int32(0), row, k, v,
+                                       state=st, slot=slot, aux=True)
+        shapes = (params, _sds((1, 1024), jnp.int32), i32,
+                  _sds((NEMO_MAX_PAGES,), jnp.int32), pool, pool, state, i32)
+        donate = (4, 5, 6)
+    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), shapes)
+    with interpret_scope(False):
+        return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+
+
+@pytest.mark.parametrize("program", ["serve_decode", "serve_prefill"])
+def test_nemotron_programs_hold_their_kernels_and_no_copy_of_the_state(
+        program, one_chip):
+    """Every Mosaic call of both serve programs starts ``ds_``; the 4 GB
+    of recurrent state (and the pools) pass through aliased to the
+    outputs, and the program's temporaries are far smaller than the
+    state: no copy of it, in a decode tick (``ds_ssm_decode`` rewrites it
+    where it lies) or in a prefill (one slot's rows are written)."""
+    from deepspeed_tpu.moe import dropless
+    from deepspeed_tpu.ops.pallas.ssm import SSM_DECODE_KERNEL
+    compiled = _nemotron_program(program, one_chip)
+    names = {n.split(".")[0] for n in _kernel_names(compiled)}
+    experts = {dropless.MOE_UP_RELU2_KERNEL, dropless.MOE_DOWN_KERNEL}
+    assert names == experts | (
+        {SSM_DECODE_KERNEL, PAGED_DECODE_ATTN_KERNEL}
+        if program == "serve_decode" else {"ds_flash_fwd"}), names
+    mem = compiled.memory_analysis()
+    state_bytes = 5 * NEMO_SLOTS * 128 * 64 * 128 * 4
+    pools = 2 * NEMO_PAGES * 2 * PAGE_LEN * 128 * 2
+    assert mem.alias_size_in_bytes >= state_bytes + pools
+    assert mem.temp_size_in_bytes < state_bytes // 8, mem.temp_size_in_bytes
+    # everything the chip must hold at once fits its 16.91e9 bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.0e9
+
+
+def test_ssm_decode_kernel_keeps_its_name_and_the_state_in_place(one_chip):
+    from deepspeed_tpu.ops.pallas.ssm import SSM_DECODE_KERNEL, ssm_decode
+    assert SSM_DECODE_KERNEL == "ds_ssm_decode"
+    s, h, p, n, g = NEMO_SLOTS, 128, 64, 128, 8
+    f32 = jnp.float32
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (_sds((5 * s, h, p, n), f32), _sds((s, h), f32),
+         _sds((s, h, p), f32), _sds((s, g, n), f32), _sds((s, g, n), f32),
+         _sds((s,), jnp.bool_), _sds((), jnp.int32)))
+    compiled = jax.jit(
+        lambda st, d, x, b, c, act, base: ssm_decode(
+            st, d, x, b, c, act, base=base, interpret=False),
+        donate_argnums=(0,)).lower(*args).compile()
+    names = _kernel_names(compiled)
+    assert [n.split(".")[0] for n in names] == [SSM_DECODE_KERNEL]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 5 * s * h * p * n * 4
+    assert mem.temp_size_in_bytes < 4 << 20
+
+
+def test_paged_decode_kernel_at_32_on_2_heads_keeps_its_name(one_chip):
+    """Grouped keys at the cell's shapes: the direct body, a page at rest
+    ``[2, 16, 128]`` the operand, blocks of 128 pages inside the module's
+    VMEM budget, the pools left in HBM."""
+    shape = (2, PAGE_LEN, 128, 2)
+    assert paged_decode_arm(*shape, q_heads=32) == "direct"
+    ppb = paged_pages_per_block(*shape, NEMO_MAX_PAGES, q_heads=32)
+    assert ppb == 128
+    assert ppb * 4 * 2 * PAGE_LEN * 128 * 2 <= PAGED_KV_VMEM_BUDGET
+    pool = _sds((NEMO_PAGES, 2, PAGE_LEN, 128))
+    compiled = _compile(
+        lambda q, k, v, t, n: decode_attention_paged(q, k, v, t, n,
+                                                     interpret=False),
+        one_chip, _sds((NEMO_SLOTS, 32, 128)), pool, pool,
+        _sds((NEMO_SLOTS, NEMO_MAX_PAGES), jnp.int32),
+        _sds((NEMO_SLOTS,), jnp.int32))
+    names = _kernel_names(compiled)
+    assert [n.split(".")[0] for n in names] == [PAGED_DECODE_ATTN_KERNEL]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("tokens", [NEMO_SLOTS, 1024],
+                         ids=["decode_tick", "prefill_bucket"])
+def test_moe_relu2_kernels_carry_their_names(tokens, one_chip):
+    """128 held experts of 1024 x 2688 (two matrices, no gate), top-22 of
+    512 handed in: rows of 16 at a decode tick and of 64 at a prefill,
+    the tile count sound if every assignment lands on the held."""
+    from deepspeed_tpu.moe import dropless
+    assert dropless.MOE_UP_RELU2_KERNEL == "ds_moe_up_relu2"
+    lat, f, held, k = 1024, 2688, 128, 22
+    compiled = _compile(
+        lambda x, r, w, e, u, d: dropless.dropless_moe(
+            x, r, None, u, d, k, expert_offset=jnp.int32(held),
+            routing=(w, e), experts_held=(0, held), act="relu2",
+            interpret=False)[0],
+        one_chip, _sds((tokens, lat)), _sds((4096, 512)),
+        _sds((tokens, k), jnp.float32), _sds((tokens, k), jnp.int32),
+        _sds((2 * held, lat, f)), _sds((2 * held, f, lat)))
+    names = sorted(n.split(".")[0] for n in _kernel_names(compiled))
+    assert names == [dropless.MOE_DOWN_KERNEL, dropless.MOE_UP_RELU2_KERNEL]

@@ -307,8 +307,9 @@ def test_paged_kernel_grid_at_xl_serving_shapes():
             for pl_ in (16, 64, 128)] == [8, 2, 1]
     assert _paged_call_grid(**{**x, "page_len": 64, "max_pages": 16,
                                "P": 209}) == (32, 8)
+    # shapes only: q_heads (PR 34) is the query heads over grouped keys
     assert list(inspect.signature(paged_pages_per_block).parameters) == [
-        "heads", "page_len", "head_dim", "itemsize", "max_pages"]
+        "heads", "page_len", "head_dim", "itemsize", "max_pages", "q_heads"]
     source = inspect.getsource(module)
     assert "environ" not in source and "getenv" not in source
 
@@ -324,8 +325,9 @@ def test_paged_arm_follows_the_pool_shape_alone():
     OLMoE's takes the direct one, whose pools stay in HBM (six operands
     whatever the block, where the packed arm has one a page)."""
     import inspect
+    # shapes only (q_heads, PR 34: the query heads over grouped keys)
     assert list(inspect.signature(paged_decode_arm).parameters) == [
-        "heads", "page_len", "head_dim", "itemsize"]
+        "heads", "page_len", "head_dim", "itemsize", "q_heads"]
     x, o = XL_SERVING, OLMOE_SERVING
     assert paged_decode_arm(x["H"], x["page_len"], x["Dh"], 2) == "packed"
     assert paged_pages_per_block(x["H"], x["page_len"], x["Dh"], 2,

@@ -67,6 +67,13 @@ multiple), composing multiplicatively with paging and making the
 speculative draft plane nearly free.  Default off = every program
 bitwise-unchanged.
 
+A tick sent ahead (docs/serving.md "A tick"): while every slot is taken
+the engine sends decode tick n+1, fed tick n's output tokens as they lie
+on the device, BEFORE it pulls and books tick n's, so the device has a
+program queued while the host counts; with a free slot the order is the
+old one (``ServeEngine._run_ahead`` is the rule, ``ahead_stats`` the
+count).  Both orders run the one compiled ``serve_decode``.
+
 Fault plane: the request queue is a stages.py :class:`Channel` and all
 serving work runs under one :class:`Stage` record ("serve", points
 ``admit``/``step``), so poison/drain semantics, graceful degradation
@@ -82,7 +89,7 @@ import contextlib
 import json
 import time
 from collections import deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -104,6 +111,18 @@ from .kv_cache import (KVCacheSpec, PagedKVCacheSpec, cache_shardings,
                        validate_cache_mesh, validate_paged_cache_mesh)
 from .scheduler import PagePool, PrefixCache, Request, SlotScheduler
 from .speculative import select_next_token, speculative_accept
+
+
+#: in a tick's host ``tokens`` operand: this slot's input is the output of
+#: the tick in flight and lies on the device (``serve_feed_tokens``)
+_FROM_DEVICE = -1
+
+
+class _Tick(NamedTuple):
+    """A decode tick between its dispatch and its retirement."""
+    active_map: Dict[int, Request]  #: slot -> the request whose row it runs
+    next_tok: Any                   #: [slots] int32 on the device
+    aux: tuple                      #: a ``serving_aux`` model's counters, or ()
 
 
 class _ServeConfigView:
@@ -330,7 +349,6 @@ class ServeEngine:
         self._aux_keys = tuple(getattr(model, "serving_aux", ()))
         self._aux = self.paged and bool(self._aux_keys)
         self.aux_log: deque = deque(maxlen=65536)
-        self._aux_pending = ()
         #: request state by slot (class docstring): name -> shape and
         #: dtype, {} for a model that keeps none
         self._state_spec = (dict(model.serving_state(self.slots))
@@ -718,6 +736,17 @@ class ServeEngine:
             serve_prefill, donate_argnums=(1,), out_shardings=outs)
         self._decode_fn = jax.jit(
             serve_decode, donate_argnums=(1,), out_shardings=outs)
+
+        # a tick's tokens reach serve_decode as ONE kind of operand, a
+        # replicated device array, whether the host built them
+        # (device_put) or the tick in flight did (its next_tok, with the
+        # slots only the host knows merged in here): a host array and a
+        # committed one key two executables of the same program
+        def serve_feed_tokens(prev, tokens):
+            return jnp.where(tokens == _FROM_DEVICE, prev, tokens)
+
+        self._rep = rep
+        self._feed_fn = jax.jit(serve_feed_tokens, out_shardings=rep)
         if self.spec_k:
             self._build_spec_plane(cfg, mcfg, kv_dtype, draft_params,
                                    seed, rep)
@@ -762,6 +791,26 @@ class ServeEngine:
                 fsync=disk_fsync_enabled(kvt[C.SERVING_KV_TIER_FSYNC]),
                 max_failures=cfg.stages.max_stage_failures)
         wire_serve_stage_plane(self)
+
+        # -- the tick sent ahead (_run_ahead is the rule) ------------------
+        #: the decode tick on the device's queue that the host has not
+        #: pulled yet, sent ahead of the retirement of the one before it
+        self._inflight: Optional[_Tick] = None
+        #: decode ticks by the order they were sent in, and the rows run
+        #: for a request that had ended (serve_ticks_total{arm=},
+        #: serve_ahead_wasted_rows_total with telemetry on)
+        self.ahead_stats = {"ahead": 0, "sync": 0, "wasted_rows": 0}
+        #: speculation, the KV tier's park_tick and the slot cache keep
+        #: the synchronous order (a chunked prefill in flight too: that
+        #: one is state, read in _run_ahead)
+        self._ahead_ok = (self.paged and not self.spec_k
+                          and self.kv_tier is None)
+        if self._ahead_ok:
+            # warm-ups never fill the slots, so compile the feed where
+            # nothing is being timed
+            zeros = np.zeros((self.slots,), np.int32)
+            with self._pallas_scope():
+                self._feed_fn(jax.device_put(zeros, rep), zeros)
 
         # -- memory planes (docs/serving.md "quantized serving"): the
         # device bytes the params and KV cache claim, from the param
@@ -817,6 +866,14 @@ class ServeEngine:
                 "level scheduling number)")
             self._active_gauge = reg.gauge(
                 "serve_active_slots", "slots decoding this tick")
+            self._ticks_ctr = reg.counter(
+                "serve_ticks_total",
+                "decode ticks sent, by arm: ahead (before the tick in "
+                "flight was retired: every slot was taken) or sync")
+            self._wasted_rows_ctr = reg.counter(
+                "serve_ahead_wasted_rows_total",
+                "rows a tick sent ahead ran for a request that the tick "
+                "before it had ended (an eos, found at retirement)")
             self._param_bytes_gauge = reg.gauge(
                 "serve_param_bytes",
                 "device bytes of the serving params (target + draft; "
@@ -1169,7 +1226,7 @@ class ServeEngine:
         program's one stable name: the module on the profiler's ``XLA
         Modules`` line is ``jit_<name>`` and ``recompiles_total``
         carries ``program=<name>``."""
-        fns = [self._decode_fn, self._prefill_fn]
+        fns = [self._decode_fn, self._prefill_fn, self._feed_fn]
         if self.paged:
             fns += [self._copy_fn, self._page_out_fn, self._page_in_fn,
                     self._set_len_fn]
@@ -1277,8 +1334,11 @@ class ServeEngine:
         if self.telemetry is None:
             return None
         try:
+            # a dump mutates nothing: it says that a tick is in flight
+            # and leaves retiring it to the engine
             extra = {"active_slots": len(self.scheduler.active),
-                     "queued": self.queue.qsize()}
+                     "queued": self.queue.qsize(),
+                     "tick_in_flight": self._inflight is not None}
             if self.paged:
                 extra["free_pages"] = self.pool.free_count
                 extra["pending"] = len(self._pending)
@@ -1656,16 +1716,17 @@ class ServeEngine:
                                   rid=req.rid)
                 self._charge_prefill_delay(len(delta))
                 with self._pallas_scope():
-                    self.cache, first, *self._aux_pending = \
-                        self._prefill_fn(
-                            self.params, self.cache, tokens,
-                            np.int32(len(delta)), np.int32(shared_len),
-                            row_np, np.int32(self.scheduler.free[0]),
-                            *((self._lora_pools, np.int32(aslot))
-                              if self.lora else ()),
-                            *self._maybe_key())
+                    self.cache, first, *aux = self._prefill_fn(
+                        self.params, self.cache, tokens,
+                        np.int32(len(delta)), np.int32(shared_len),
+                        row_np, np.int32(self.scheduler.free[0]),
+                        *((self._lora_pools, np.int32(aslot))
+                          if self.lora else ()),
+                        *self._maybe_key())
+                # behind the tick in flight, if one is: the wait is for
+                # both, with the device busy throughout
                 first = int(np.asarray(jax.block_until_ready(first)))
-                self._note_aux("prefill")
+                self._note_aux("prefill", aux)
             if self.spec_k:
                 # the draft mirrors the FULL prompt (it has no prefix
                 # cache — draft prefill is cheap by construction)
@@ -1884,7 +1945,7 @@ class ServeEngine:
                 tr.flow_start("serve/request", req.ctx, cat="serve",
                               rid=req.rid)
             with self._pallas_scope():
-                self.cache, first, *self._aux_pending = self._prefill_fn(
+                self.cache, first, *aux = self._prefill_fn(
                     self.params, self.cache, tokens,
                     np.int32(len(chunk)),
                     np.int32(req.shared_len + pos),
@@ -1893,7 +1954,7 @@ class ServeEngine:
                       if self.lora else ()),
                     *self._maybe_key())
             first = int(np.asarray(jax.block_until_ready(first)))
-            self._note_aux("prefill")
+            self._note_aux("prefill", aux)
         req.chunk_pos = pos + len(chunk)
         req.kv_len = req.shared_len + req.chunk_pos
         if not final:
@@ -1934,39 +1995,60 @@ class ServeEngine:
         """``serve/decode_prep``: the slots that decode this tick, their
         page-boundary allocations for ``rows`` more KV rows, and the
         tick's host operands.  Returns (active_map, tokens, active);
-        an empty map means nothing to run."""
+        an empty map means nothing to run.
+
+        With a tick in flight this one is sent ahead of its retirement,
+        and everything it needs is arithmetic on counts the host holds:
+        a request with a row in flight stands one token on, is left out
+        if that token ends it by count (``length``, ``kv_capacity``),
+        and takes its input from the device (``_FROM_DEVICE``); an
+        ``eos`` is found at retirement, one wasted row late."""
         with self._span("serve/decode_prep") as sp:
+            flying = (self._inflight.active_map
+                      if self._inflight is not None else {})
             # mid-prefill slots ride masked: they have no last token to
             # feed and their KV is a partial prefix (chunked prefill)
             active_map = {s: r for s, r in self.scheduler.active.items()
                           if not r.prefilling}
-            if self.paged:
+            tokens = np.zeros((self.slots,), np.int32)
+            for slot, req in list(active_map.items()):
+                on = int(flying.get(slot) is req)
+                if on and (len(req.tokens) + 1 >= req.max_new_tokens
+                           or req.kv_len + 1 >= self.max_seq_len):
+                    del active_map[slot]
+                    continue
+                tokens[slot] = _FROM_DEVICE if on else req.last_token
+                if not self.paged:
+                    continue
                 # page-boundary appends allocate BEFORE the tick (a
                 # speculative block: all its pages up front); a dry
                 # pool (even after prefix-cache eviction) finishes the
                 # request with the pool-exhaustion-aware kv_capacity
                 # reason instead of letting the program write into the
                 # void
-                for slot, req in list(active_map.items()):
-                    need = (req.kv_len // self.page_len + 1 if rows == 1
-                            else -(-min(req.kv_len + rows,
-                                        self.max_seq_len)
-                                   // self.page_len))
-                    extra = need - len(req.pages)
-                    if extra > 0:
-                        pg = self._alloc_pages(extra)
-                        if pg is None:
+                kv_len = req.kv_len + on
+                need = (kv_len // self.page_len + 1 if rows == 1
+                        else -(-min(kv_len + rows, self.max_seq_len)
+                               // self.page_len))
+                extra = need - len(req.pages)
+                if extra > 0:
+                    pg = self._alloc_pages(extra)
+                    if pg is None:
+                        # with a row in flight its token is booked
+                        # first: it sits this tick out, and the next
+                        # prepare decides as this one would have, after
+                        # the retirement has freed what it frees
+                        if not on:
                             self._finish(slot, "kv_capacity")
-                            del active_map[slot]
-                            continue
-                        for p in pg:
-                            self._table[slot, len(req.pages)] = p
-                            req.pages.append(p)
-            tokens = np.zeros((self.slots,), np.int32)
+                        del active_map[slot]
+                        tokens[slot] = 0
+                        continue
+                    for p in pg:
+                        self._table[slot, len(req.pages)] = p
+                        req.pages.append(p)
             active = np.zeros((self.slots,), bool)
             live_pages = live_blocks = 0
             for slot, req in active_map.items():
-                tokens[slot] = req.last_token
                 active[slot] = True
                 if self.paged:
                     live_pages += len(req.pages)
@@ -1997,35 +2079,42 @@ class ServeEngine:
 
     def _decode_dispatch(self, tokens, active):
         """``serve/decode_dispatch``: the decode program's call, until
-        it returns (enqueue; the device runs on)."""
+        it returns (enqueue; the device runs on).  Returns (next_tok,
+        the call's counters), both on the device."""
         with self._span("serve/decode_dispatch"):
             with self._pallas_scope():
+                if self._inflight is not None:
+                    tokens = self._feed_fn(self._inflight.next_tok, tokens)
+                else:
+                    tokens = jax.device_put(tokens, self._rep)
+                aux = ()
                 if self.paged:
-                    self.cache, next_tok, *self._aux_pending = \
-                        self._decode_fn(
-                            self.params, self.cache, tokens, active,
-                            self._table,
-                            *((self._lora_pools, self._adapter_table)
-                              if self.lora else ()),
-                            *self._maybe_key())
-                    for a in self._aux_pending:
+                    # the tables are copied: the host writes them again
+                    # (a finish, an admission) while this call may not
+                    # have read them yet
+                    self.cache, next_tok, *aux = self._decode_fn(
+                        self.params, self.cache, tokens, active,
+                        self._table.copy(),
+                        *((self._lora_pools, self._adapter_table.copy())
+                          if self.lora else ()),
+                        *self._maybe_key())
+                    for a in aux:
                         # on its way while the host waits for the tokens
                         a.copy_to_host_async()
                 else:
                     self.cache, next_tok = self._decode_fn(
                         self.params, self.cache, tokens, active,
                         *self._maybe_key())
-            return next_tok
+            return next_tok, tuple(aux)
 
-    def _note_aux(self, kind: str) -> None:
-        """Log the counters of the paged call just synced, the third
-        output of a ``serving_aux`` model's programs (``aux_log``); with
-        telemetry on, the expert layer's two go to their gauges."""
-        if not self._aux_pending:
+    def _note_aux(self, kind: str, aux) -> None:
+        """Log the counters of a paged call just synced, the third
+        output of a ``serving_aux`` model's programs (``aux_log``: one
+        entry a call, stamped here, at its retirement); with telemetry
+        on, the expert layer's two go to their gauges."""
+        if not aux:
             return
-        vals = dict(zip(self._aux_keys,
-                        np.asarray(self._aux_pending[0]).tolist()))
-        self._aux_pending = ()
+        vals = dict(zip(self._aux_keys, np.asarray(aux[0]).tolist()))
         self.aux_log.append((time.perf_counter(), kind, vals))
         if self.telemetry is not None and kind == "decode" \
                 and "moe_experts_hit" in vals:
@@ -2041,11 +2130,16 @@ class ServeEngine:
 
     def _emit_tokens(self, active_map, next_host) -> int:
         """``serve/emit``: per-request bookkeeping of one decoded token
-        each, finish reasons."""
+        each, finish reasons.  A request that the tick before ended
+        after this one was sent (an ``eos``) ran a wasted row: counted,
+        its output dropped."""
         with self._span("serve/emit") as sp:
             now = time.perf_counter()
-            produced = 0
+            produced = wasted = 0
             for slot, req in active_map.items():
+                if req.finish_reason is not None:
+                    wasted += 1
+                    continue
                 tok = int(next_host[slot])
                 req.kv_len += 1
                 req.tokens.append(tok)
@@ -2059,20 +2153,72 @@ class ServeEngine:
                                                       self.max_seq_len)
                 if reason is not None:
                     self._finish(slot, reason)
+            if wasted:
+                self.ahead_stats["wasted_rows"] += wasted
+                if self.telemetry is not None:
+                    self._wasted_rows_ctr.inc(wasted)
             sp.note(produced=produced)
             return produced
 
-    def _decode_tick(self) -> int:
-        active_map, tokens, active = self._decode_prepare()
+    def _run_ahead(self) -> bool:
+        """Whether the next decode tick is sent before the one in flight
+        is retired.  THE rule, in this one place, from state the engine
+        holds and no option: only while every slot is taken.  Then no
+        arrival could be seated before a finish, so nobody waits longer
+        for a first token because a tick was sent early, and throughput
+        is what a loaded engine's operator pays for; with a free slot an
+        arrival during this tick is admitted before the next, as it
+        always was.  Speculation, the KV tier and the slot cache
+        (``_ahead_ok``) and a chunked prefill in flight stay
+        synchronous."""
+        return (self._ahead_ok
+                and len(self.scheduler.active) == self.slots
+                and not (self.prefill_chunk_len and any(
+                    r.prefilling for r in self.scheduler.active.values())))
+
+    def _send_tick(self, active_map, tokens, active) -> Optional[_Tick]:
+        """One prepared decode tick onto the device's queue (behind the
+        tick in flight, if one is); None where nothing is to run."""
         if not active_map:
+            return None
+        arm = "sync" if self._inflight is None else "ahead"
+        self.ahead_stats[arm] += 1
+        if self.telemetry is not None:
+            self._ticks_ctr.inc(arm=arm)
+        self._flow_step_tick(active_map)
+        return _Tick(active_map, *self._decode_dispatch(tokens, active))
+
+    def _settle(self) -> int:
+        """Retire the tick in flight, if one is: whatever reads the
+        cache or a request's tokens from outside a tick calls this
+        first.  Returns tokens produced."""
+        tick, self._inflight = self._inflight, None
+        if tick is None:
             return 0
-        with self._span("serve/decode_step", active=len(active_map)):
-            self._flow_step_tick(active_map)
-            next_tok = self._decode_dispatch(tokens, active)
+        (next_host,) = self._pull_tokens(tick.next_tok)
+        self._note_aux("decode", tick.aux)
+        return self._emit_tokens(tick.active_map, next_host)
+
+    def _decode_tick(self) -> int:
+        """Retire one decode tick: the one sent ahead by the step
+        before, or (the synchronous arm) one sent here from the host's
+        tokens.  While ``_run_ahead`` holds, the NEXT tick goes onto the
+        device's queue before the host turns to this one's tokens."""
+        tick = self._inflight
+        if tick is None:
+            operands = self._decode_prepare()
+            if not operands[0]:
+                return 0
+        with self._span("serve/decode_step", active=len(
+                operands[0] if tick is None else tick.active_map)):
+            if tick is None:
+                tick = self._inflight = self._send_tick(*operands)
+            self._inflight = (self._send_tick(*self._decode_prepare())
+                              if self._run_ahead() else None)
             # the pull stays inside the decode_step span
-            (next_host,) = self._pull_tokens(next_tok)
-            self._note_aux("decode")
-        return self._emit_tokens(active_map, next_host)
+            (next_host,) = self._pull_tokens(tick.next_tok)
+            self._note_aux("decode", tick.aux)
+        return self._emit_tokens(tick.active_map, next_host)
 
     def _draft_propose(self, active_map, tokens, active):
         """``serve/draft_propose``: k+1 chained draft passes in one
@@ -2215,7 +2361,9 @@ class ServeEngine:
     def step(self) -> int:
         """One serving tick: admit into free slots, then one masked
         decode — or, speculating, one draft-propose + widened-verify
-        block — over the whole pool.  Returns tokens produced."""
+        block — over the whole pool.  Returns tokens produced (of the
+        tick retired here; while every slot is taken the next one is
+        already on the device's queue: ``_decode_tick``)."""
         if self._closed:
             raise RuntimeError("ServeEngine is closed")
         tick_args = {"pages_free": self.pool.free_count} \
@@ -2265,7 +2413,9 @@ class ServeEngine:
         for _ in range(max_ticks):
             if not self.scheduler.active and not self._pending \
                     and self.queue.qsize() == 0:
-                return total
+                # a tick still in flight holds only rows of requests
+                # that have ended
+                return total + self._settle()
             total += self.step()
         raise RuntimeError(
             f"serve loop still busy after max_ticks={max_ticks} "
@@ -2300,6 +2450,7 @@ class ServeEngine:
                 "export_pages needs a paged engine and a finished "
                 "detach_kv request still holding its pages")
         self._refuse_migration("export_pages")
+        self._settle()
         out = []
         for pid in req.pages:
             with self._span("serve/page_out", rid=req.rid, page=pid):
@@ -2409,6 +2560,8 @@ class ServeEngine:
                     self.pool.deref(p)
                 return None
             aslot = got
+        # there is room: from here on the cache is written
+        self._settle()
         self._rid += 1
         now = time.perf_counter()
         req = Request(rid=self._rid, prompt=prompt,
@@ -2496,6 +2649,8 @@ class ServeEngine:
         the flight recorder dumps the pool's last moments."""
         self.queue.poison(err)
         self.stage.record_event("poison", error=repr(err))
+        # the tick in flight goes with the cache: its requests fail below
+        self._inflight = None
         for slot in list(self.scheduler.active):
             req = self.scheduler.release(slot, "error")
             if self.paged:
@@ -2513,6 +2668,8 @@ class ServeEngine:
 
     def _close_queue(self):
         err = RuntimeError("ServeEngine closed")
+        # the tokens the device has already made are booked first
+        self._settle()
         # mark closed and capture the backlog under ONE lock hold: a
         # submit() racing close() either sees put() return False
         # (raises to its caller) or its item lands in `items` here and

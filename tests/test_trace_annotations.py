@@ -34,6 +34,11 @@ HIDDEN = 16
 TICK_TREE = [(1, "serve/admit"), (1, "serve/decode_prep"),
              (1, "serve/decode_step"), (2, "serve/decode_dispatch"),
              (2, "serve/token_pull"), (1, "serve/emit")]
+#: the same spans while every slot is taken: the NEXT tick's prep and
+#: dispatch go before the pull of the one in flight, inside its span
+AHEAD_TREE = [(1, "serve/admit"), (1, "serve/decode_step"),
+              (2, "serve/decode_prep"), (2, "serve/decode_dispatch"),
+              (2, "serve/token_pull"), (1, "serve/emit")]
 
 
 def _serve_engine(tmp, telemetry: bool) -> ServeEngine:
@@ -213,10 +218,12 @@ def test_annotations_per_tick_do_not_grow_with_live_slots(session,
     admitting = _count_spans(monkeypatch, eng.step)
     assert len(eng.scheduler.active) == SLOTS
     full = _count_spans(monkeypatch, eng.step)
-    assert few == full == ["serve/tick"] + [n for _, n in TICK_TREE]
+    assert few == ["serve/tick"] + [n for _, n in TICK_TREE]
+    assert full == ["serve/tick"] + [n for _, n in AHEAD_TREE]
     assert len(full) <= 8
-    # one more for each admission (its prefill)
-    assert len(admitting) == len(full) + (SLOTS - 2)
+    # one more for each admission (its prefill), and the step that fills
+    # the slots sends two ticks: its own, and the first one ahead
+    assert len(admitting) == len(full) + (SLOTS - 2) + 2
 
 
 def test_annotations_per_train_step(session, monkeypatch):

@@ -72,7 +72,12 @@ the engine sends decode tick n+1, fed tick n's output tokens as they lie
 on the device, BEFORE it pulls and books tick n's, so the device has a
 program queued while the host counts; with a free slot the order is the
 old one (``ServeEngine._run_ahead`` is the rule, ``ahead_stats`` the
-count).  Both orders run the one compiled ``serve_decode``.
+count).  An admission that fills the last free slot of such an engine
+sends the next tick BEHIND its prefill, the request's first token taken
+from the prefill's output on the device, before the host waits for that
+token (``_send_behind_prefill``): the device goes from the prefill to the
+tick without the host between them.  All orders run the one compiled
+``serve_decode``.
 
 Fault plane: the request queue is a stages.py :class:`Channel` and all
 serving work runs under one :class:`Stage` record ("serve", points
@@ -116,6 +121,9 @@ from .speculative import select_next_token, speculative_accept
 #: in a tick's host ``tokens`` operand: this slot's input is the output of
 #: the tick in flight and lies on the device (``serve_feed_tokens``)
 _FROM_DEVICE = -1
+#: this slot's input is the first token of the prefill just sent, which
+#: the host has not seen yet (``_send_behind_prefill``)
+_FROM_PREFILL = -2
 
 
 class _Tick(NamedTuple):
@@ -211,12 +219,16 @@ class ServeEngine:
 
     **The serving protocol** (what the engine, and the benchmark's probe,
     read of ``model``; ``GPT2Model`` and ``OlmoeModel`` are its two
-    implementations; ``NemotronHModel`` is a third, with request state):
+    implementations; ``NemotronHModel`` is a third, with request state,
+    and ``MimoV2Model`` a fourth, whose state is a second kind of key
+    cache):
 
     * ``model.config`` with ``n_layer``, ``n_head``, ``d_head`` (the KV
-      pool's shape; ``n_layer`` counts the layers that keep keys, and a
-      config with ``n_kv_head`` has that many key heads in the pool,
-      under ``n_head`` query heads), ``n_positions`` (the longest
+      pool's shape; ``n_layer`` counts the layers that keep every key,
+      and a config with ``n_kv_head`` has that many key heads in the
+      pool, under ``n_head`` query heads; ``d_head`` is the keys' width
+      at rest, and a config with ``d_head_v`` has values that wide
+      beside them), ``n_positions`` (the longest
       sequence) and
       ``attn_impl`` (``'flash'`` | ``'dense'``: which decode arm
       ``serving.decode_impl: auto`` takes); ``d_model`` with LoRA;
@@ -242,8 +254,10 @@ class ServeEngine:
       ``serving_state(slots)`` -> ``{name: ShapeDtypeStruct}``, each
       leaf ``[..., slots, ...]`` with the slot on axis 1 and of a fixed
       size (``NemotronHModel``: the Mamba-2 recurrent state and conv
-      window of each mixer layer).  The engine allocates it once, beside
-      the pools, under ``cache["state"]``; both paged steps take it as
+      window of each mixer layer; ``MimoV2Model``: each sliding-window
+      layer's ring of its last keys and values).  The engine allocates
+      it once, beside the pools, under ``cache["state"]``; both paged
+      steps take it as
       ``state=`` and return it after the pools (``prefill_paged(...,
       state=, slot=)`` -> ``(logits, k_pool, v_pool, state)``,
       ``decode_step_paged(..., state=)`` -> ``(logits, k_pool, v_pool,
@@ -349,6 +363,13 @@ class ServeEngine:
         self._aux_keys = tuple(getattr(model, "serving_aux", ()))
         self._aux = self.paged and bool(self._aux_keys)
         self.aux_log: deque = deque(maxlen=65536)
+        #: what the one prefill bucket costs: every prefill runs the whole
+        #: ``[1, prefill_len]`` program, so a prompt (or delta, or chunk)
+        #: of n tokens pays for ``prefill_len - n`` more; summed, beside
+        #: the tokens that were wanted (counter
+        #: ``serve_prefill_pad_tokens_total``)
+        self.prefill_pad_tokens = 0
+        self.prefill_tokens = 0
         #: request state by slot (class docstring): name -> shape and
         #: dtype, {} for a model that keeps none
         self._state_spec = (dict(model.serving_state(self.slots))
@@ -381,12 +402,14 @@ class ServeEngine:
                 pages += (-pages) % dp
             # grouped keys: the pool holds the KEY heads
             kv_heads = getattr(mcfg, "n_kv_head", mcfg.n_head)
+            # two widths: the values' where they are not the keys'
+            v_dim = getattr(mcfg, "d_head_v", None)
             self.cache_spec = PagedKVCacheSpec(
                 layers=mcfg.n_layer, slots=self.slots,
                 heads=kv_heads, pages=pages, page_len=self.page_len,
                 head_dim=mcfg.d_head, max_pages=self.max_pages,
                 dtype=(jnp.int8 if self.quant_kv else kv_dtype),
-                quant=self.quant_kv)
+                quant=self.quant_kv, v_head_dim=v_dim)
             validate_paged_cache_mesh(mesh, self.cache_spec)
             self._cache_shardings = paged_cache_shardings(
                 mesh, quant=self.quant_kv)
@@ -421,7 +444,7 @@ class ServeEngine:
                 grouped = ({"q_heads": mcfg.n_head}
                            if kv_heads != mcfg.n_head else {})
                 self._pages_per_block = paged_pages_per_block(
-                    *shape, self.max_pages, **grouped)
+                    *shape, self.max_pages, v_head_dim=v_dim, **grouped)
                 self.paged_decode_arm = paged_decode_arm(*shape, **grouped)
         else:
             self.pool = None
@@ -742,8 +765,10 @@ class ServeEngine:
         # (device_put) or the tick in flight did (its next_tok, with the
         # slots only the host knows merged in here): a host array and a
         # committed one key two executables of the same program
-        def serve_feed_tokens(prev, tokens):
-            return jnp.where(tokens == _FROM_DEVICE, prev, tokens)
+        def serve_feed_tokens(prev, first, tokens):
+            return jnp.where(tokens == _FROM_DEVICE, prev,
+                             jnp.where(tokens == _FROM_PREFILL, first,
+                                       tokens))
 
         self._rep = rep
         self._feed_fn = jax.jit(serve_feed_tokens, out_shardings=rep)
@@ -796,10 +821,18 @@ class ServeEngine:
         #: the decode tick on the device's queue that the host has not
         #: pulled yet, sent ahead of the retirement of the one before it
         self._inflight: Optional[_Tick] = None
+        #: the tick sent behind the prefill of the admission that filled
+        #: the slots, until ``_decode_tick`` of the same step takes it
+        #: for the one in flight (``_send_behind_prefill``)
+        self._behind: Optional[_Tick] = None
+        #: that prefill's first token on the device while that tick is
+        #: being sent; a zero stands in on every other tick
+        self._first_dev = self._no_first = None
         #: decode ticks by the order they were sent in, and the rows run
         #: for a request that had ended (serve_ticks_total{arm=},
         #: serve_ahead_wasted_rows_total with telemetry on)
-        self.ahead_stats = {"ahead": 0, "sync": 0, "wasted_rows": 0}
+        self.ahead_stats = {"ahead": 0, "behind": 0, "sync": 0,
+                            "wasted_rows": 0}
         #: speculation, the KV tier's park_tick and the slot cache keep
         #: the synchronous order (a chunked prefill in flight too: that
         #: one is state, read in _run_ahead)
@@ -809,8 +842,11 @@ class ServeEngine:
             # warm-ups never fill the slots, so compile the feed where
             # nothing is being timed
             zeros = np.zeros((self.slots,), np.int32)
+            self._first_dev = self._no_first = jax.device_put(
+                np.int32(0), rep)
             with self._pallas_scope():
-                self._feed_fn(jax.device_put(zeros, rep), zeros)
+                self._feed_fn(jax.device_put(zeros, rep), self._no_first,
+                              zeros)
 
         # -- memory planes (docs/serving.md "quantized serving"): the
         # device bytes the params and KV cache claim, from the param
@@ -869,7 +905,8 @@ class ServeEngine:
             self._ticks_ctr = reg.counter(
                 "serve_ticks_total",
                 "decode ticks sent, by arm: ahead (before the tick in "
-                "flight was retired: every slot was taken) or sync")
+                "flight was retired: every slot was taken), behind (ahead "
+                "too, behind the prefill that took the last slot) or sync")
             self._wasted_rows_ctr = reg.counter(
                 "serve_ahead_wasted_rows_total",
                 "rows a tick sent ahead ran for a request that the tick "
@@ -884,6 +921,19 @@ class ServeEngine:
                 "device bytes of the KV cache from its spec (both "
                 "layouts; incl. quant scale sidecars + draft cache)")
             self._kv_bytes_gauge.set(self.kv_bytes)
+            self._prefill_pad_ctr = reg.counter(
+                "serve_prefill_pad_tokens_total",
+                "tokens of the one prefill bucket that were padding: "
+                "prefill_len less the prompt (delta, chunk) a call")
+            layers = getattr(self.model, "serving_cache_layers", None)
+            if layers is not None:
+                layer_gauge = reg.gauge(
+                    "serve_cache_layers",
+                    "layers by the kind of cache they keep: full (every "
+                    "key, in the page pool) or window (the last keys, by "
+                    "slot)")
+                for kind, n in layers().items():
+                    layer_gauge.set(n, kind=kind)
             if self.paged:
                 self._pages_total_gauge = reg.gauge(
                     "serve_pages_total",
@@ -911,8 +961,8 @@ class ServeEngine:
                 state_gauge = reg.gauge(
                     "serve_state_bytes",
                     "device bytes a stateful model's requests hold by "
-                    "kind: each serving_state leaf (ssm, conv) and the "
-                    "page pool (kv)")
+                    "kind: each serving_state leaf (ssm, conv; window_k, "
+                    "window_v) and the page pool (kv)")
                 for kind, nbytes in self.state_bytes.items():
                     state_gauge.set(nbytes, kind=kind)
             if self._aux:
@@ -1597,6 +1647,15 @@ class ServeEngine:
                     np.int32(self.scheduler.free[0]
                              if slot is None else slot))
 
+    def _note_prefill_pad(self, wanted: int) -> None:
+        """A call of the prefill program on ``wanted`` tokens of its
+        ``prefill_len`` bucket."""
+        pad = self.prefill_len - wanted
+        self.prefill_tokens += wanted
+        self.prefill_pad_tokens += pad
+        if self.telemetry is not None:
+            self._prefill_pad_ctr.inc(pad)
+
     def _admit_one_paged(self, req: Request) -> bool:
         total_pages = -(-len(req.prompt) // self.page_len)
         # tenant namespace: adapter A's KV pages must never be matched
@@ -1648,6 +1707,7 @@ class ServeEngine:
                 return False
             aslot = got
         held = list(spages) + tpages + fresh
+        seated = None
         try:
             # queue wait ends HERE, before any device work: the COW
             # copy below (and its first-use compile) is compute and
@@ -1715,6 +1775,7 @@ class ServeEngine:
                     tr.flow_start("serve/request", req.ctx, cat="serve",
                                   rid=req.rid)
                 self._charge_prefill_delay(len(delta))
+                self._note_prefill_pad(len(delta))
                 with self._pallas_scope():
                     self.cache, first, *aux = self._prefill_fn(
                         self.params, self.cache, tokens,
@@ -1723,6 +1784,9 @@ class ServeEngine:
                         *((self._lora_pools, np.int32(aslot))
                           if self.lora else ()),
                         *self._maybe_key())
+                # where this admission fills the slots the next tick
+                # goes behind the prefill now, before the host waits
+                seated = self._send_behind_prefill(req, row, aslot, first)
                 # behind the tick in flight, if one is: the wait is for
                 # both, with the device busy throughout
                 first = int(np.asarray(jax.block_until_ready(first)))
@@ -1732,6 +1796,13 @@ class ServeEngine:
                 # cache — draft prefill is cheap by construction)
                 self._draft_prefill(req)
         except BaseException:
+            if seated is not None:
+                # the tick sent behind runs its row for nobody
+                held += req.pages[len(row):]
+                self.scheduler.release(seated, "error")
+                self._table[seated, :] = 0
+                if self.lora:
+                    self._adapter_table[seated] = 0
             # roll back every page this admission still holds a ref on
             for p in held:
                 self.pool.deref(p)
@@ -1740,7 +1811,10 @@ class ServeEngine:
             raise
         now = time.perf_counter()
         req.prefill_s = now - req.admit_t
-        slot = self.scheduler.admit(req, now=now)
+        if seated is None:
+            slot = self._seat(req, row, aslot, now)
+        else:
+            slot, req.last_t = seated, now
         if self.prefix is not None:
             # stats count SUCCESSFUL admissions only — neither a
             # parked request re-matching every tick nor a failed
@@ -1752,19 +1826,12 @@ class ServeEngine:
             if self.telemetry is not None:
                 (self._prefix_hits if shared_len
                  else self._prefix_misses).inc()
-        req.pages = row
         req.shared_len = shared_len
         req.computed_len = len(delta)
-        self._table[slot, :] = 0
-        self._table[slot, :len(row)] = row
-        if self.lora:
-            req.adapter_slot = aslot
-            self._adapter_table[slot] = aslot
         if self.prefix is not None:
             # register the freshly computed pages for future sharers
             # (full pages of prompt[:-1] + the partial tail)
             self.prefix.insert(req.prompt, row, ns)
-        req.kv_len = len(req.prompt)
         req.tokens.append(first)
         req.token_times.append(now - req.submit_t)
         req.last_token = first
@@ -1776,6 +1843,50 @@ class ServeEngine:
         if reason is not None:
             self._finish(slot, reason)
         return True
+
+    def _seat(self, req: Request, row: List[int], aslot: int,
+              now: float) -> int:
+        """The host's record of a prefilled request in its slot, as far
+        as a decode tick reads it.  Returns the slot."""
+        slot = self.scheduler.admit(req, now=now)
+        req.pages = row
+        req.kv_len = len(req.prompt)
+        self._table[slot, :] = 0
+        self._table[slot, :len(row)] = row
+        if self.lora:
+            req.adapter_slot = aslot
+            self._adapter_table[slot] = aslot
+        return slot
+
+    def _send_behind_prefill(self, req: Request, row: List[int],
+                             aslot: int, first) -> Optional[int]:
+        """Between a prefill's dispatch and the wait for its token: if
+        it fills the last free slot of an engine with a tick in flight,
+        seat the request and send the next tick behind the prefill,
+        its first input the prefill's output where it lies
+        (``_FROM_PREFILL``), so the device goes from one to the other
+        while the host waits and books.  Returns the slot, or None
+        where the old order holds: a free slot left, no tick in flight
+        or one sent behind a prefill already in this step (whose request
+        ended at its first token), a request that its first token may
+        end by count, or a pool so low that the tick could find it dry.
+        An ``eos`` as first token is found after the wait, one wasted
+        row late, as a tick sent ahead finds one."""
+        if not (self._inflight is not None and self._behind is None
+                and self._run_ahead(seats=1)
+                and req.max_new_tokens > 1
+                and len(req.prompt) + 1 < self.max_seq_len
+                and self.pool.free_count >= self.slots):
+            return None
+        slot = self._seat(req, list(row), aslot, time.perf_counter())
+        req.last_token = _FROM_PREFILL
+        self._first_dev = first
+        try:
+            self._behind = self._send_tick(*self._decode_prepare(),
+                                           arm="behind")
+        finally:
+            self._first_dev = self._no_first
+        return slot
 
     def _admit_one_slot(self, req: Request) -> bool:
         tokens = np.zeros((1, self.prefill_len), np.int32)
@@ -1796,6 +1907,7 @@ class ServeEngine:
                 # the request rides emits a flow step
                 tr.flow_start("serve/request", req.ctx, cat="serve",
                               rid=req.rid)
+            self._note_prefill_pad(len(req.prompt))
             with self._pallas_scope():
                 self.cache, first = self._prefill_fn(
                     self.params, self.cache, tokens, length,
@@ -1944,6 +2056,7 @@ class ServeEngine:
             if final and tr is not None and req.ctx is not None:
                 tr.flow_start("serve/request", req.ctx, cat="serve",
                               rid=req.rid)
+            self._note_prefill_pad(len(chunk))
             with self._pallas_scope():
                 self.cache, first, *aux = self._prefill_fn(
                     self.params, self.cache, tokens,
@@ -2084,7 +2197,8 @@ class ServeEngine:
         with self._span("serve/decode_dispatch"):
             with self._pallas_scope():
                 if self._inflight is not None:
-                    tokens = self._feed_fn(self._inflight.next_tok, tokens)
+                    tokens = self._feed_fn(self._inflight.next_tok,
+                                           self._first_dev, tokens)
                 else:
                     tokens = jax.device_put(tokens, self._rep)
                 aux = ()
@@ -2160,9 +2274,10 @@ class ServeEngine:
             sp.note(produced=produced)
             return produced
 
-    def _run_ahead(self) -> bool:
+    def _run_ahead(self, seats: int = 0) -> bool:
         """Whether the next decode tick is sent before the one in flight
-        is retired.  THE rule, in this one place, from state the engine
+        is retired (``seats``: requests about to be seated, counted as
+        if they were).  THE rule, in this one place, from state the engine
         holds and no option: only while every slot is taken.  Then no
         arrival could be seated before a finish, so nobody waits longer
         for a first token because a tick was sent early, and throughput
@@ -2172,16 +2287,17 @@ class ServeEngine:
         (``_ahead_ok``) and a chunked prefill in flight stay
         synchronous."""
         return (self._ahead_ok
-                and len(self.scheduler.active) == self.slots
+                and len(self.scheduler.active) + seats == self.slots
                 and not (self.prefill_chunk_len and any(
                     r.prefilling for r in self.scheduler.active.values())))
 
-    def _send_tick(self, active_map, tokens, active) -> Optional[_Tick]:
+    def _send_tick(self, active_map, tokens, active,
+                   arm: Optional[str] = None) -> Optional[_Tick]:
         """One prepared decode tick onto the device's queue (behind the
         tick in flight, if one is); None where nothing is to run."""
         if not active_map:
             return None
-        arm = "sync" if self._inflight is None else "ahead"
+        arm = arm or ("sync" if self._inflight is None else "ahead")
         self.ahead_stats[arm] += 1
         if self.telemetry is not None:
             self._ticks_ctr.inc(arm=arm)
@@ -2213,8 +2329,10 @@ class ServeEngine:
                 operands[0] if tick is None else tick.active_map)):
             if tick is None:
                 tick = self._inflight = self._send_tick(*operands)
-            self._inflight = (self._send_tick(*self._decode_prepare())
-                              if self._run_ahead() else None)
+            behind, self._behind = self._behind, None
+            self._inflight = behind or (
+                self._send_tick(*self._decode_prepare())
+                if self._run_ahead() else None)
             # the pull stays inside the decode_step span
             (next_host,) = self._pull_tokens(tick.next_tok)
             self._note_aux("decode", tick.aux)
@@ -2650,7 +2768,7 @@ class ServeEngine:
         self.queue.poison(err)
         self.stage.record_event("poison", error=repr(err))
         # the tick in flight goes with the cache: its requests fail below
-        self._inflight = None
+        self._inflight = self._behind = None
         for slot in list(self.scheduler.active):
             req = self.scheduler.release(slot, "error")
             if self.paged:

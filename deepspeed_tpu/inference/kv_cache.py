@@ -124,7 +124,11 @@ class PagedKVCacheSpec:
     tokens each (page 0 reserved as the scratch page), referenced by
     per-slot page tables the host owns.  ``heads`` counts the KEY heads:
     under grouped keys (a model config with ``n_kv_head``) fewer than the
-    query heads that read them.  What a request keeps beside its pages
+    query heads that read them.  ``head_dim`` is the KEYS' width at
+    rest; ``v_head_dim`` the values' where it differs (a model config
+    with ``d_head_v``; None: the same), so the two pools are
+    ``[L, pages, H, page_len, head_dim]`` and ``[..., v_head_dim]``.
+    What a request keeps beside its pages
     (a ``serving_state`` model's recurrent state, by slot) is not in
     this spec: ``ServeEngine`` allocates it under ``cache["state"]``.
 
@@ -146,16 +150,16 @@ class PagedKVCacheSpec:
     dtype: Any = jnp.float32
     #: int8 rows + per-(page, head, row) fp32 scale sidecar
     quant: bool = False
+    #: the values' width where it is not the keys'
+    v_head_dim: Optional[int] = None
+
+    @property
+    def value_dim(self) -> int:
+        return self.head_dim if self.v_head_dim is None else self.v_head_dim
 
     @property
     def bytes(self) -> int:
-        per = jnp.dtype(self.dtype).itemsize
-        n = (2 * self.layers * self.pages * self.heads * self.page_len
-             * self.head_dim * per)
-        if self.quant:
-            n += (2 * self.layers * self.pages * self.heads
-                  * self.page_len * 4)
-        return n
+        return self.pages * self.page_bytes
 
     @property
     def page_bytes(self) -> int:
@@ -163,8 +167,8 @@ class PagedKVCacheSpec:
         quant scale sidecar rows) — the allocation quantum the bench's
         fixed-byte budget divides by."""
         per = jnp.dtype(self.dtype).itemsize
-        n = 2 * self.layers * self.heads * self.page_len \
-            * self.head_dim * per
+        n = self.layers * self.heads * self.page_len \
+            * (self.head_dim + self.value_dim) * per
         if self.quant:
             n += 2 * self.layers * self.heads * self.page_len * 4
         return n
@@ -176,11 +180,10 @@ def init_paged_cache(spec: PagedKVCacheSpec) -> Dict[str, jnp.ndarray]:
     Quantized pools get all-zero scale sidecars: dequant of a never-
     written row is 0 * scale = exact zero, the same dead-data story as
     the fp pool."""
-    shape = (spec.layers, spec.pages, spec.heads, spec.page_len,
-             spec.head_dim)
+    shape = (spec.layers, spec.pages, spec.heads, spec.page_len)
     cache = {
-        "k": jnp.zeros(shape, spec.dtype),
-        "v": jnp.zeros(shape, spec.dtype),
+        "k": jnp.zeros(shape + (spec.head_dim,), spec.dtype),
+        "v": jnp.zeros(shape + (spec.value_dim,), spec.dtype),
         "lengths": jnp.zeros((spec.slots,), jnp.int32),
     }
     if spec.quant:

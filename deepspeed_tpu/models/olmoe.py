@@ -203,17 +203,20 @@ def rms_norm(x, weight, eps: float):
     return y.astype(x.dtype) * weight.astype(x.dtype)
 
 
-def rope(x, positions, theta: float):
+def rope(x, positions, theta: float, rotary_dim: Optional[int] = None):
     """Rotate-half RoPE.  x [B, H, T, Dh], positions [B, T] (absolute).
-    Pair i is (x[i], x[i + Dh/2]), angle ``pos * theta**(-2i/Dh)``."""
-    half = x.shape[-1] // 2
+    Pair i is (x[i], x[i + R/2]), angle ``pos * theta**(-2i/R)``, over
+    the first ``R = rotary_dim`` dims (None: the whole head); the others
+    pass untouched."""
+    rot = x.shape[-1] if rotary_dim is None else rotary_dim
+    half = rot // 2
     inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     ang = positions.astype(jnp.float32)[:, None, :, None] * inv_freq
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., :half], xf[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           axis=-1).astype(x.dtype)
+    x1, x2 = xf[..., :half], xf[..., half:rot]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
+                            xf[..., rot:]], axis=-1).astype(x.dtype)
 
 
 def qkv_heads(cfg: OlmoeConfig, bp, h, positions):

@@ -53,6 +53,7 @@ Nemotron-3 Super's latent experts, ``models/nemotron_h.py``):
 """
 from __future__ import annotations
 
+import math
 from typing import NamedTuple, Optional
 
 import jax
@@ -73,6 +74,15 @@ MOE_UP_RELU2_KERNEL = "ds_moe_up_relu2"
 #: default of 16 MiB a kernel, well inside a v5e core's 128 MiB
 MOE_VMEM_LIMIT = 48 * 1024 * 1024
 MAX_ROW_TILE = 128
+
+
+def _vmem_limit(weights) -> int:
+    """``MOE_VMEM_LIMIT``, or where an expert's blocks in flight need
+    more (both up-projections at d 4096, f 2048 are 64 MiB
+    double-buffered), those and 16 MiB for the rows, the result and the
+    body's temporaries."""
+    blocks = sum(math.prod(w.shape[1:]) * w.dtype.itemsize for w in weights)
+    return max(MOE_VMEM_LIMIT, 2 * blocks + 16 * 1024 * 1024)
 
 
 class MoEStats(NamedTuple):
@@ -191,7 +201,7 @@ def _grouped(kernel, name, rows, weights, tile_expert, n_live, tm, width,
         out_shape=jax.ShapeDtypeStruct((tiles * tm, width), rows.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=MOE_VMEM_LIMIT),
+            vmem_limit_bytes=_vmem_limit(weights)),
         interpret=interpret, name=name,
     )(tile_expert, n_live, rows, *weights)
 

@@ -8,16 +8,23 @@ into the program or the one-compiled-decode-program contract
 (docs/serving.md, jaxlint JL005) is gone.
 
 Layout: the slot cache is slot-major ``[S, H, T, Dh]`` and its kernel
-runs a ``(S·H, k_blocks)`` grid — each grid row streams one (slot, head)'s key
-blocks through VMEM with the same online-softmax accumulator as the
-training kernel.  The single query travels as an 8-row sublane
-broadcast (TPU block shapes need (8, 128k) tiles — the lse trick from
-the training kernel); the per-slot length travels the same way as a
-broadcast int32 tile, indexed per grid row.  Keys at or beyond a slot's
-live length are hard-masked with the validity floor, and a slot with
-length 0 (a free slot riding along in the static batch) outputs exact
-zeros — the mis-masking discipline the training kernel's kv_length arm
-enforces, here with traced lengths.
+(:func:`decode_attention_slots`) runs a ``(S, k_blocks)`` grid: a grid
+step streams a block of cache rows of ALL of a slot's key heads through
+VMEM, the block ``[H, bk, Dh]`` being rows ``(head, row)`` of one matmul
+operand and the query heads the rows that read it, with the same
+online-softmax accumulator as the training kernel; a precomputed position
+table keeps every query head to its own key head's live rows (the
+grouped paged body's trick), and the per-slot lengths ride as a
+prefetched scalar.  Keys at or beyond a slot's live length are
+hard-masked with the validity floor, and a slot with length 0 (a free
+slot riding along in the static batch) outputs exact zeros — the
+mis-masking discipline the training kernel's kv_length arm enforces,
+here with traced lengths.  The same body, named
+``ds_window_decode_attn``, serves a sliding-window layer's ring of its
+last keys by slot (:func:`window_decode_attention`): fewer key heads
+than query heads, values narrower than keys, a traced base into the
+slots of every layer, and a learned sink a head that joins the softmax's
+denominator and no value.
 
 Compute for blocks entirely beyond a slot's length is skipped
 (``pl.when``), but their HBM->VMEM streaming is not: block index maps
@@ -159,14 +166,53 @@ def _default_scale(d: int) -> float:
 # benchmark's per-kernel shares (benchmark/metrics/*_share.*.json)
 # match on these strings — moving a call keeps its constant.
 DECODE_ATTN_KERNEL = "ds_decode_attn"
+#: the same body over a ring of the last ``window`` keys a slot (a
+#: sliding-window layer's request state), with the sink in the softmax
+WINDOW_DECODE_ATTN_KERNEL = "ds_window_decode_attn"
 
 
-def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref,
-                   m_scr, l_scr, acc_scr,
-                   *, sm_scale: float, block_k: int):
+def slot_decode_reference(q, k, v, lengths, sink=None, sm_scale=None):
+    """Dense jnp reference of :func:`decode_attention_slots`: q
+    [S, Hq, Dk] against k [S, Hkv, T, Dk], v [S, Hkv, T, Dv] (query head
+    ``h`` reads key head ``h // (Hq // Hkv)``), rows ``>= lengths[s]``
+    masked, and with ``sink`` [Hq] one more softmax column a head that
+    takes weight and gives no value:
+    ``p_j = exp(s_j - m) / (sum_j exp(s_j - m) + exp(sink - m))``."""
+    S, Hkv, T, Dk = k.shape
+    Hq = q.shape[1]
+    scale = _default_scale(Dk) if sm_scale is None else sm_scale
+    if Hq != Hkv:
+        k, v = (jnp.repeat(t, Hq // Hkv, axis=1) for t in (k, v))
+    s = jnp.einsum("shd,shtd->sht", q, k,
+                   preferred_element_type=jnp.float32) * scale
+    valid = (jnp.arange(T, dtype=jnp.int32)[None, None, :]
+             < lengths.astype(jnp.int32)[:, None, None])
+    s = jnp.where(valid, s, jnp.finfo(jnp.float32).min)
+    if sink is not None:
+        col = jnp.broadcast_to(sink.astype(jnp.float32)[None, :, None],
+                               (S, Hq, 1))
+        s = jnp.concatenate([s, col], axis=-1)
+    probs = jax.nn.softmax(s, axis=-1)[..., :T]
+    probs = jnp.where(lengths[:, None, None] > 0, probs, 0.0)
+    return jnp.einsum("sht,shtd->shd", probs.astype(q.dtype), v)
+
+
+def _slot_decode_kernel(len_ref, base_ref, q_ref, pos_ref, k_ref, v_ref,
+                        *rest, sm_scale: float, block_k: int,
+                        with_sink: bool):
+    """One grid step = one slot, ALL heads, ``block_k`` cache rows of
+    every key head: the block ``[Hkv, bk, Dk]`` is rows ``(key head,
+    row)`` of the matmul operand, the query heads are the rows that read
+    it, and ``pos_ref`` keeps every query head to its own key head's
+    live rows (the grouped paged body's trick).  A sink joins the
+    denominator at the end."""
+    if with_sink:
+        sink_ref, o_ref, m_scr, l_scr, acc_scr = rest
+    else:
+        o_ref, m_scr, l_scr, acc_scr = rest
     jk = pl.program_id(1)
     nk = pl.num_programs(1)
-    length = len_ref[0][0, 0]                           # this row's slot
+    length = len_ref[pl.program_id(0)]
 
     @pl.when(jk == 0)
     def _init():
@@ -177,19 +223,17 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref,
     # whole k block at or beyond the live length: nothing to do
     @pl.when(jk * block_k < length)
     def _compute():
-        q = q_ref[0]                                    # [8, d] broadcast
-        k = k_ref[0]                                    # [bk, d]
-        v = v_ref[0]                                    # [bk, d]
+        hkv, bk, dk = k_ref.shape[1:]
+        k = k_ref[0].reshape(hkv * bk, dk)
+        v = v_ref[0].reshape(hkv * bk, v_ref.shape[-1])
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale   # [8, bk]
-        k_ids = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) \
-            + jk * block_k
-        s = jnp.where(k_ids < length, s, NEG_INF)
+            q_ref[0], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale  # [Hq, Hkv*bk]
+        s = jnp.where(pos_ref[...] < length - jk * block_k, s, NEG_INF)
         m_prev = m_scr[:, 0:1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        # at least one key of this block is live (the pl.when guard), so
-        # m_new is a real score and the masked keys' exp underflows to 0
+        # row jk*bk of every head is live (the pl.when guard), so m_new
+        # is a real score and the masked keys' exp underflows to 0
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_scr[:] = jnp.broadcast_to(
@@ -202,48 +246,113 @@ def _decode_kernel(q_ref, k_ref, v_ref, len_ref, o_ref,
 
     @pl.when(jk == nk - 1)
     def _finalize():
-        l = l_scr[:, 0:1]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        # length 0 → no block ran → l == 0 → exact zeros (free slots)
-        o_ref[0] = jnp.where(l == 0.0, 0.0,
-                             acc_scr[:] / l_safe).astype(o_ref.dtype)
+        l, acc = l_scr[:, 0:1], acc_scr[:]
+        if with_sink:
+            # one more column of the softmax, in no value
+            m = m_scr[:, 0:1]
+            b = sink_ref[:, 0:1]
+            m_all = jnp.maximum(m, b)
+            keep = jnp.exp(m - m_all)
+            acc = acc * keep
+            l_all = l * keep + jnp.exp(b - m_all)
+        else:
+            l_all = jnp.where(l == 0.0, 1.0, l)
+        # length 0 -> no block ran -> l == 0 -> exact zeros (free slots)
+        o_ref[0] = jnp.where(l == 0.0, 0.0, acc / l_all).astype(o_ref.dtype)
 
 
-def _decode_pallas(q, k, v, lengths, *, sm_scale, block_k, interpret):
-    S, H, T, Dh = k.shape
+def _slot_decode_pallas(q, k, v, lengths, sink, base, *, sm_scale, block_k,
+                        interpret, name):
+    X, Hkv, T, Dk = k.shape
+    Dv = v.shape[-1]
+    S, Hq, _ = q.shape
     block_k = min(block_k, max(T, 8))
-    kf = _pad_seq(k.reshape(S * H, T, Dh), block_k, 1)
-    vf = _pad_seq(v.reshape(S * H, T, Dh), block_k, 1)
-    nk = kf.shape[1] // block_k
-    # single query as an 8-row sublane broadcast (TPU tile rule)
-    qf = jnp.broadcast_to(q.reshape(S * H, 1, Dh), (S * H, 8, Dh))
-    # per-slot lengths as a broadcast (8, 128) int32 tile per slot —
-    # the same sublane-broadcast trick as the training kernel's key
-    # mask (_kmask_args); index map picks row g's slot with a static
-    # division (grid-index arithmetic only)
-    len_op = jnp.broadcast_to(
-        lengths.astype(jnp.int32).reshape(S, 1, 1), (S, 8, 128))
-    out = pl.pallas_call(
-        functools.partial(_decode_kernel, sm_scale=sm_scale,
-                          block_k=block_k),
-        grid=(S * H, nk),
-        in_specs=[
-            pl.BlockSpec((1, 8, Dh), lambda g, j: (g, 0, 0)),
-            pl.BlockSpec((1, block_k, Dh), lambda g, j: (g, j, 0)),
-            pl.BlockSpec((1, block_k, Dh), lambda g, j: (g, j, 0)),
-            pl.BlockSpec((1, 8, 128), lambda g, j: (g // H, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 8, Dh), lambda g, j: (g, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((S * H, 8, Dh), q.dtype),
+    kf, vf = _pad_seq(k, block_k, 2), _pad_seq(v, block_k, 2)
+    nk = kf.shape[2] // block_k
+    # the heads ride the sublane rows, a whole number of tiles
+    hp = _round_up(Hq, 8 * 4 // q.dtype.itemsize)
+    qf = jnp.pad(q, ((0, 0), (0, hp - Hq), (0, 0)))
+    import numpy as np
+    pos = np.full((hp, Hkv * block_k), 2 ** 30, np.int32)
+    pos[:Hq] = _grouped_block_positions(Hq, Hkv, block_k, 1)
+    operands = [qf, jnp.asarray(pos), kf, vf]
+    in_specs = [
+        pl.BlockSpec((1, hp, Dk), lambda s, j, *_: (s, 0, 0)),
+        pl.BlockSpec(pos.shape, lambda s, j, *_: (0, 0)),
+        pl.BlockSpec((1, Hkv, block_k, Dk),
+                     lambda s, j, ln, b: (b[0] + s, 0, j, 0)),
+        pl.BlockSpec((1, Hkv, block_k, Dv),
+                     lambda s, j, ln, b: (b[0] + s, 0, j, 0)),
+    ]
+    if sink is not None:
+        tile = jnp.pad(sink.astype(jnp.float32), (0, hp - Hq))
+        operands.append(jnp.broadcast_to(tile[:, None], (hp, 128)))
+        in_specs.append(pl.BlockSpec((hp, 128), lambda s, j, *_: (0, 0)))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(S, nk),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, hp, Dv), lambda s, j, *_: (s, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((8, 128), jnp.float32),
-            pltpu.VMEM((8, 128), jnp.float32),
-            pltpu.VMEM((8, Dh), jnp.float32),
+            pltpu.VMEM((hp, 128), jnp.float32),
+            pltpu.VMEM((hp, 128), jnp.float32),
+            pltpu.VMEM((hp, Dv), jnp.float32),
         ],
+    )
+    out = pl.pallas_call(
+        functools.partial(_slot_decode_kernel, sm_scale=sm_scale,
+                          block_k=block_k, with_sink=sink is not None),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, hp, Dv), q.dtype),
         interpret=interpret,
-        name=DECODE_ATTN_KERNEL,
-    )(qf, kf, vf, len_op)
-    return out[:, 0, :].reshape(S, H, Dh)
+        name=name,
+    )(lengths, jnp.reshape(base, (1,)).astype(jnp.int32), *operands)
+    return out[:, :Hq]
+
+
+def decode_attention_slots(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                           lengths: jnp.ndarray, *,
+                           sink: Optional[jnp.ndarray] = None, base=0,
+                           sm_scale: Optional[float] = None,
+                           block_k: int = 256, impl: str = "pallas",
+                           interpret: Optional[bool] = None,
+                           name: str = DECODE_ATTN_KERNEL) -> jnp.ndarray:
+    """Single-query attention over keys kept BY SLOT (not
+    differentiable): the slot cache of :func:`decode_attention`, and a
+    sliding-window layer's ring of its last ``T`` keys.
+
+    q: [S, Hq, Dk], one new query a slot.
+    k: [X, Hkv, T, Dk], v: [X, Hkv, T, Dv]: slot ``s`` reads
+        ``k[base + s]`` (``base`` traced: a layer's offset into the
+        slots of every layer, so no layer is sliced out); ``Hq`` a
+        multiple of ``Hkv``, query head ``h`` on key head
+        ``h // (Hq // Hkv)``; ``Dv`` need not be ``Dk``.
+    lengths: [S] int32, TRACED: rows ``0 .. lengths[s] - 1`` are live
+        (a ring that has wrapped: all ``T``; softmax does not care in
+        which order the keys lie).  0 = free slot -> exact zeros.
+    sink: [Hq] or None: a learned logit a head that joins the softmax's
+        denominator and no value.
+    """
+    X, Hkv, T, Dk = k.shape
+    S, Hq, _ = q.shape
+    assert q.shape == (S, Hq, Dk) and Hq % Hkv == 0, (q.shape, k.shape)
+    assert v.shape[:3] == k.shape[:3], (k.shape, v.shape)
+    if sm_scale is None:
+        sm_scale = _default_scale(Dk)
+    lengths = lengths.astype(jnp.int32)
+    if impl == "dense":
+        at = jnp.asarray(base, jnp.int32) + jnp.arange(S, dtype=jnp.int32)
+        return slot_decode_reference(q, k[at], v[at], lengths, sink=sink,
+                                     sm_scale=sm_scale)
+    if impl != "pallas":
+        raise ValueError(
+            f"decode attention impl={impl!r}: expected 'pallas' or "
+            "'dense'")
+    if interpret is None:
+        interpret = _use_interpret()
+    return _slot_decode_pallas(
+        q, k, v, lengths, sink, jnp.asarray(base, jnp.int32),
+        sm_scale=sm_scale, block_k=block_k, interpret=interpret, name=name)
 
 
 def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
@@ -263,9 +372,9 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         position this query's K/V was just written to.  0 = free slot →
         exact-zero output.
 
-    ``impl``: 'pallas' (the kernel; interpret mode off-TPU) or 'dense'
-    (the jnp reference — the serving engine's CPU fallback and the
-    test oracle).
+    ``impl``: 'pallas' (:func:`decode_attention_slots`' kernel;
+    interpret mode off-TPU) or 'dense' (the jnp reference — the serving
+    engine's CPU fallback and the test oracle).
     """
     assert q.ndim == 3 and k.ndim == 4, (q.shape, k.shape)
     S, H, T, Dh = k.shape
@@ -275,15 +384,25 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if impl == "dense":
         return decode_attention_reference(q, k, v, lengths,
                                           sm_scale=sm_scale)
-    if impl != "pallas":
-        raise ValueError(
-            f"decode_attention impl={impl!r}: expected 'pallas' or "
-            "'dense'")
-    if interpret is None:
-        interpret = _use_interpret()
-    return _decode_pallas(q, k, v, lengths.astype(jnp.int32),
-                          sm_scale=sm_scale, block_k=block_k,
-                          interpret=interpret)
+    return decode_attention_slots(q, k, v, lengths, sm_scale=sm_scale,
+                                  block_k=block_k, impl=impl,
+                                  interpret=interpret)
+
+
+def window_decode_attention(q, k_ring, v_ring, lengths, sink, *, base=0,
+                            sm_scale: Optional[float] = None,
+                            impl: str = "pallas",
+                            interpret: Optional[bool] = None):
+    """Single-query attention of a sliding-window layer over the ring of
+    each slot's last ``T`` keys (``k_ring`` [X, Hkv, T, Dk], ``v_ring``
+    [X, Hkv, T, Dv]; position ``p`` at row ``p % T``), the new key
+    already written.  ``lengths`` [S] counts the keys so far, itself
+    included: ``min(lengths, T)`` rows are live.  ``sink`` [Hq]."""
+    T = k_ring.shape[2]
+    return decode_attention_slots(
+        q, k_ring, v_ring, jnp.minimum(lengths.astype(jnp.int32), T),
+        sink=sink, base=base, sm_scale=sm_scale, block_k=T, impl=impl,
+        interpret=interpret, name=WINDOW_DECODE_ATTN_KERNEL)
 
 
 # ---------------------------------------------------------------------------
@@ -419,14 +538,17 @@ def paged_page_vmem_bytes(heads: int, page_len: int, head_dim: int,
 
 def paged_pages_per_block(heads: int, page_len: int, head_dim: int,
                           itemsize: int, max_pages: int,
-                          q_heads: Optional[int] = None) -> int:
+                          q_heads: Optional[int] = None,
+                          v_head_dim: Optional[int] = None) -> int:
     """Pages one grid step of the fp paged kernel attends: the largest
     power of two that fits ``PAGED_KV_VMEM_BUDGET``, at most
     ``max_pages``.  A function of the pool's shape alone.  With grouped
     keys a page in flight is its own bytes (K and V, double-buffered):
-    ``[H, page_len, Dh]`` pads nothing."""
+    ``[H, page_len, Dh]`` pads nothing; there the values may be
+    ``v_head_dim`` wide where the keys are ``head_dim``."""
     if q_heads not in (None, heads):
-        page_bytes = 4 * heads * page_len * head_dim * itemsize
+        page_bytes = 2 * heads * page_len * itemsize * (
+            head_dim + (v_head_dim or head_dim))
     else:
         page_bytes = paged_page_vmem_bytes(heads, page_len, head_dim,
                                            itemsize)
@@ -612,7 +734,7 @@ def _decode_paged_direct_kernel(pt_ref, len_ref, q_ref, pos_ref,
     reads, and whether any step has started a copy yet."""
     s, j = pl.program_id(0), pl.program_id(1)
     slots, nb = pl.num_programs(0), pl.num_programs(1)
-    ppb, head_dim = k_buf.shape[1], k_buf.shape[-1]
+    ppb = k_buf.shape[1]
     if page_len is None:
         page_len = k_buf.shape[2]       # a page [page_len, H, Dh]
     bk = ppb * page_len
@@ -682,7 +804,7 @@ def _decode_paged_direct_kernel(pt_ref, len_ref, q_ref, pos_ref,
         rows = k_buf.shape[1] * k_buf.shape[2] * k_buf.shape[3]
         wait(0, half)
         sc = jax.lax.dot_general(
-            q_ref[0], k_buf[half].reshape(rows, head_dim),
+            q_ref[0], k_buf[half].reshape(rows, k_buf.shape[-1]),
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
         # pos_ref keeps a head's own keys; a dead page's rows hold
@@ -699,7 +821,7 @@ def _decode_paged_direct_kernel(pt_ref, len_ref, q_ref, pos_ref,
             l_scr.shape)
         wait(1, half)
         acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
-            p.astype(v_buf.dtype), v_buf[half].reshape(rows, head_dim),
+            p.astype(v_buf.dtype), v_buf[half].reshape(rows, v_buf.shape[-1]),
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
@@ -776,12 +898,15 @@ def _decode_paged_grouped_pallas(q, k_pages, v_pages, page_table, lengths, *,
     no transpose), so a block's pages are rows ``(page, key head, key)``
     of the matmul operand and the ``Hq / H`` query heads of a key head
     are the rows that read it; the position table keeps every query head
-    to its own key head's rows."""
+    to its own key head's rows.  The values may be narrower than the
+    keys (``v_pages [P, H, page_len, Dv]``): each pool has its own
+    buffer, and the output is ``Dv`` wide."""
     P, H, page_len, Dh = k_pages.shape
+    Dv = v_pages.shape[-1]
     S, max_pages = page_table.shape
     Hq = q.shape[1]
     ppb = paged_pages_per_block(H, page_len, Dh, k_pages.dtype.itemsize,
-                                max_pages, q_heads=Hq)
+                                max_pages, q_heads=Hq, v_head_dim=Dv)
     nb = -(-max_pages // ppb)
     pt_flat = jnp.pad(page_table,
                       ((0, 0), (0, nb * ppb - max_pages))).reshape(-1)
@@ -793,22 +918,22 @@ def _decode_paged_grouped_pallas(q, k_pages, v_pages, page_table, lengths, *,
                   pl.BlockSpec(pos.shape, lambda s, j, *_: (0, 0)),
                   pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec((1, Hq, Dh), lambda s, j, *_: (s, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hq, Dv), lambda s, j, *_: (s, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, ppb, H, page_len, Dh), k_pages.dtype),
-            pltpu.VMEM((2, ppb, H, page_len, Dh), v_pages.dtype),
+            pltpu.VMEM((2, ppb, H, page_len, Dv), v_pages.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((2,), jnp.int32),
             pltpu.VMEM((Hq, 128), jnp.float32),
             pltpu.VMEM((Hq, 128), jnp.float32),
-            pltpu.VMEM((Hq, Dh), jnp.float32),
+            pltpu.VMEM((Hq, Dv), jnp.float32),
         ],
     )
     return pl.pallas_call(
         functools.partial(_decode_paged_direct_kernel, sm_scale=sm_scale,
                           page_len=page_len),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, Hq, Dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, Hq, Dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
@@ -944,7 +1069,11 @@ def decode_attention_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
         only).
     k_pages, v_pages: [P, H, page_len, Dh] — the flat page pool; a
         slot's position ``p`` lives at row ``p % page_len`` of page
-        ``page_table[s, p // page_len]``.
+        ``page_table[s, p // page_len]``.  Under grouped keys the
+        values may be narrower than the keys (``v_pages [..., Dv]``,
+        output ``[S, Hq, Dv]``); ``sm_scale`` then defaults to the
+        keys' stored width, so a caller whose keys are padded at rest
+        passes its own.
     page_table: [S, max_pages] int32, TRACED — dead entries must hold a
         valid page id (the engine fills them with the scratch page 0);
         their data is masked, their streaming is a no-op read.
@@ -968,6 +1097,11 @@ def decode_attention_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
     S, max_pages = page_table.shape
     Hq = q.shape[1]
     assert q.shape == (S, Hq, Dh) and Hq % H == 0, (q.shape, k_pages.shape)
+    if v_pages.shape != k_pages.shape and (
+            Hq == H or v_pages.shape[:3] != k_pages.shape[:3]):
+        raise NotImplementedError(
+            f"decode_attention_paged: keys {k_pages.shape} over values "
+            f"{v_pages.shape}: two widths only under grouped keys")
     _check_quant_args(k_pages, k_scale, v_scale, "decode_attention_paged")
     if sm_scale is None:
         sm_scale = _default_scale(Dh)

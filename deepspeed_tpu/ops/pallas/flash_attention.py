@@ -146,13 +146,15 @@ def _grid_bh(bh_ref, period: int, stride: int):
 
 
 def _masked_scores(q, k, iq, ik, *, sm_scale, causal, block_q, block_k,
-                   seq_len, kmask=None):
+                   seq_len, kmask=None, window=None):
     """Scaled q·kᵀ for one (q-block, k-block) tile with padding + causal
     masking — the single source of the mask math shared by the forward
     and both backward kernels (they must stay bit-identical or forward
     and backward silently disagree).  ``kmask``: optional [1, block_k]
     fp32 additive key mask (0 keep / large-negative drop — the HF
-    convention), applied before the validity floor."""
+    convention), applied before the validity floor.  ``window`` (static,
+    with ``causal``): a query sees its last ``window`` keys, itself
+    included."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * sm_scale      # [bq, bk]
@@ -163,7 +165,10 @@ def _masked_scores(q, k, iq, ik, *, sm_scale, causal, block_q, block_k,
     valid = k_global < seq_len
     if causal:
         q_ids = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        valid = jnp.logical_and(valid, k_global <= q_ids + iq * block_q)
+        q_global = q_ids + iq * block_q
+        valid = jnp.logical_and(valid, k_global <= q_global)
+        if window is not None:
+            valid = jnp.logical_and(valid, k_global > q_global - window)
     return jnp.where(valid, s, NEG_INF)
 
 
@@ -179,19 +184,33 @@ def _masked_scores(q, k, iq, ik, *, sm_scale, causal, block_q, block_k,
 FLASH_FWD_KERNEL = "ds_flash_fwd"
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, seed_ref, bh_ref, kmask_ref,
-                o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, sm_scale: float, causal: bool, block_q: int,
+def _band_first_block(iq, block_q, block_k, window):
+    """First key block a query block of a ``window`` layer reads."""
+    return jnp.maximum(iq * block_q - (window - 1), 0) // block_k
+
+
+def _fwd_kernel(q_ref, k_ref, v_ref, seed_ref, bh_ref, kmask_ref, *rest,
+                sm_scale: float, causal: bool, block_q: int,
                 block_k: int, seq_len: int, dropout_rate: float,
-                bh_period: int, bh_stride: int, use_kmask: bool):
+                bh_period: int, bh_stride: int, use_kmask: bool,
+                window: Optional[int] = None, with_sink: bool = False):
+    if with_sink:
+        sink_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
+    else:
+        o_ref, lse_ref, m_scr, l_scr, acc_scr = rest
     iq, ik = pl.program_id(1), pl.program_id(2)
     nk = pl.num_programs(2)
+    at_first, at_last = ik == 0, ik == nk - 1
+    if window is not None:
+        # the grid's key axis spans the band only: step ``ik`` is key
+        # block ``first + ik`` (see _fwd)
+        ik = ik + _band_first_block(iq, block_q, block_k, window)
     # program_id must be read OUTSIDE pl.when branches: interpret-mode
     # lowering only rewrites it in the top-level kernel body (closures
     # capture the value fine) — same reason iq/ik live up here.
     bh_row = _grid_bh(bh_ref, bh_period, bh_stride)
 
-    @pl.when(ik == 0)
+    @pl.when(at_first)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -206,12 +225,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, seed_ref, bh_ref, kmask_ref,
     def _compute():
         q = q_ref[0]                                   # [bq, d]
         k = k_ref[0]                                   # [bk, d]
-        v = v_ref[0]                                   # [bk, d]
+        v = v_ref[0]                                   # [bk, dv]
         # row 0 of the 8-row sublane-broadcast mask tile (see _kmask_args)
         km = kmask_ref[0][0:1, :] if use_kmask else None
         s = _masked_scores(q, k, iq, ik, sm_scale=sm_scale, causal=causal,
                            block_q=block_q, block_k=block_k,
-                           seq_len=seq_len, kmask=km)
+                           seq_len=seq_len, kmask=km, window=window)
 
         m_prev = m_scr[:, 0:1]                          # [bq, 1]
         m_cur = jnp.max(s, axis=1, keepdims=True)       # [bq, 1]
@@ -235,17 +254,26 @@ def _fwd_kernel(q_ref, k_ref, v_ref, seed_ref, bh_ref, kmask_ref,
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    @pl.when(ik == nk - 1)
+    @pl.when(at_last)
     def _finalize():
-        l = l_scr[:, 0:1]
+        l, acc = l_scr[:, 0:1], acc_scr[:]
+        if with_sink:
+            # the head's sink: one more column of the softmax, in the
+            # denominator and in no value
+            m = m_scr[:, 0:1]
+            b = sink_ref[0][0:1, 0:1]
+            m_all = jnp.maximum(m, b)
+            keep = jnp.exp(m - m_all)
+            acc = acc * keep
+            l = l * keep + jnp.exp(b - m_all)
+            m_scr[:] = jnp.broadcast_to(m_all, m_scr.shape)
         l_safe = jnp.where(l == 0.0, 1.0, l)
         # dead rows (every key masked — all-masked key_mask row, or all
         # keys beyond kv_length) hard-zero instead of renormalizing over
         # masked keys; their lse goes to +DEAD_LSE so backward p
         # underflows to 0 and the gradients are zero too
         dead = m_scr[:, 0:1] <= DEAD_ROW_THRESH         # [bq, 1]
-        o_ref[0] = jnp.where(dead, 0.0,
-                             acc_scr[:] / l_safe).astype(o_ref.dtype)
+        o_ref[0] = jnp.where(dead, 0.0, acc / l_safe).astype(o_ref.dtype)
         # lse output is q-blocked with a sublane-padded layout
         # [bh, nq, 8, block_q]: every store is a whole (8, block_q) tile at
         # lane offset 0.  Mosaic rejects dynamic lane offsets that are not
@@ -298,8 +326,15 @@ def _kmask_args(kmask, bh, tk_p, block_k, k_block_of):
 
 def _fwd(q, k, v, seed, bh_base, kmask, *, sm_scale, causal, block_q,
          block_k, dropout_rate, bh_period, bh_stride, interpret,
-         kv_length=None):
+         kv_length=None, window=None, sink=None, heads=None):
+    """``window`` / ``sink`` / ``heads`` are the serving prefill's
+    (:func:`flash_attention_fwd`); left None, the call is the training
+    forward's, operand for operand.  ``heads = (Hq, Hkv)``: k and v hold
+    ``Hkv`` heads a sequence under q's ``Hq``, and query head ``h`` reads
+    key head ``h // (Hq // Hkv)``: the index map picks it, nothing is
+    repeated.  ``v`` may be narrower than ``k``."""
     bh, t, d = q.shape
+    dv = v.shape[-1]
     tk = k.shape[1]
     # live-KV clamp: keys >= kv_length are hard-masked via the validity
     # floor (the KV-cache decode hazard — a cache tail past the live
@@ -313,7 +348,20 @@ def _fwd(q, k, v, seed, bh_base, kmask, *, sm_scale, causal, block_q,
     tq_p, tk_p = qp.shape[1], kp.shape[1]
     nq, nk = tq_p // block_q, tk_p // block_k
 
-    if causal:
+    if window is not None:
+        assert causal, "a window is a band under the causal diagonal"
+        # the key axis of the grid spans the band's blocks only: blocks
+        # outside it are neither fetched nor stepped over
+        last = [(i * block_q + block_q - 1) // block_k for i in range(nq)]
+        first = [max(i * block_q - (window - 1), 0) // block_k
+                 for i in range(nq)]
+        nk = max(b - a for a, b in zip(first, last)) + 1
+
+        def k_block_of(b, i, j):
+            return jnp.minimum(
+                _band_first_block(i, block_q, block_k, window) + j,
+                (i * block_q + block_q - 1) // block_k)
+    elif causal:
         def k_block_of(b, i, j):
             return jnp.minimum(j, (i * block_q + block_q - 1) // block_k)
     else:
@@ -325,41 +373,58 @@ def _fwd(q, k, v, seed, bh_base, kmask, *, sm_scale, causal, block_q,
         _fwd_kernel, sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k, seq_len=seq_len,
         dropout_rate=dropout_rate, bh_period=bh_period,
-        bh_stride=bh_stride, use_kmask=use_kmask)
+        bh_stride=bh_stride, use_kmask=use_kmask, window=window,
+        with_sink=sink is not None)
     # clamp the K/V block index at the causal diagonal: skipped
     # (fully-masked) grid steps revisit the previous block, and Pallas
     # elides the HBM→VMEM copy for revisited blocks — without this the
     # pipeline streams every K/V block even though pl.when skips the
     # compute (≈2× attention HBM traffic at long T)
-    def kv_im(b, i, j):
-        return (b, k_block_of(b, i, j), 0)
+    if heads is None:
+        def kv_im(b, i, j):
+            return (b, k_block_of(b, i, j), 0)
+    else:
+        hq, hkv = heads
+
+        def kv_im(b, i, j):
+            return ((b // hq) * hkv + (b % hq) // (hq // hkv),
+                    k_block_of(b, i, j), 0)
+    operands = [qp, kp, vp, _seed_arr(seed), _seed_arr(bh_base), kmask_op]
+    in_specs = [
+        pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+        pl.BlockSpec((1, block_k, d), kv_im),
+        pl.BlockSpec((1, block_k, dv), kv_im),
+        _SEED_SPEC,
+        _BH_SPEC,
+        kmask_spec,
+    ]
+    if sink is not None:
+        # a head's sink as one (8, 128) tile, every lane the same
+        hs = sink.shape[0]
+        operands.append(jnp.broadcast_to(
+            sink.astype(jnp.float32)[:, None, None], (hs, 8, 128)))
+        in_specs.append(
+            pl.BlockSpec((1, 8, 128), lambda b, i, j: (b % hs, 0, 0)))
     out, lse = pl.pallas_call(
         kernel,
         grid=(bh, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), kv_im),
-            pl.BlockSpec((1, block_k, d), kv_im),
-            _SEED_SPEC,
-            _BH_SPEC,
-            kmask_spec,
-        ],
+        in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, 1, 8, block_q), lambda b, i, j: (b, i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, tq_p, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, tq_p, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, nq, 8, block_q), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, dv), jnp.float32),
         ],
         interpret=interpret,
         name=FLASH_FWD_KERNEL,
-    )(qp, kp, vp, _seed_arr(seed), _seed_arr(bh_base), kmask_op)
+    )(*operands)
     return out[:, :t], lse[:, :, 0, :].reshape(bh, tq_p)[:, :t]
 
 
@@ -736,3 +801,40 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                  kv_length)
     return out.reshape(b, h, t, d)
 
+
+
+def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
+                        window: Optional[int] = None,
+                        sink: Optional[jnp.ndarray] = None,
+                        sm_scale: Optional[float] = None,
+                        block_q: int = 512, block_k: int = 512,
+                        interpret: Optional[bool] = None) -> jnp.ndarray:
+    """The causal forward kernel for a serving prefill (no backward: a
+    prefill never backpropagates), with what the training call has not:
+
+    * grouped keys: q ``[B, Hq, T, Dk]`` over k ``[B, Hkv, T, Dk]``, v
+      ``[B, Hkv, T, Dv]``; query head ``h`` reads key head
+      ``h // (Hq // Hkv)`` through the block index, nothing is repeated;
+    * ``Dv != Dk``: the output is ``[B, Hq, T, Dv]``;
+    * ``window``: query ``t`` sees keys ``t - window < j <= t``; the
+      grid's key axis spans the band's blocks only;
+    * ``sink`` ``[Hq]``: a head's learned logit, one more column of the
+      softmax that takes weight and gives no value.
+    """
+    b, hq, t, d = q.shape
+    hkv, dv = k.shape[1], v.shape[-1]
+    assert k.shape == (b, hkv, t, d) and v.shape == (b, hkv, t, dv), (
+        q.shape, k.shape, v.shape)
+    assert hq % hkv == 0, (hq, hkv)
+    if sm_scale is None:
+        sm_scale = float(d) ** -0.5
+    if interpret is None:
+        interpret = _use_interpret()
+    zero = jnp.zeros((), jnp.uint32)
+    out, _ = _fwd(q.reshape(b * hq, t, d), k.reshape(b * hkv, t, d),
+                  v.reshape(b * hkv, t, dv), zero, zero, None,
+                  sm_scale=sm_scale, causal=True, block_q=block_q,
+                  block_k=block_k, dropout_rate=0.0, bh_period=b * hq,
+                  bh_stride=0, interpret=interpret, window=window,
+                  sink=sink, heads=None if hq == hkv else (hq, hkv))
+    return out.reshape(b, hq, t, dv)

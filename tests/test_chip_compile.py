@@ -348,6 +348,8 @@ KERNEL_CASES = {
     "PAGED_DECODE_ATTN_KERNEL": ("decode_attention", _arm("paged16")),
     "PAGED_DECODE_ATTN_INT8_KERNEL":
         ("decode_attention", _arm("int8_paged16")),
+    "WINDOW_DECODE_ATTN_KERNEL":
+        ("decode_attention", lambda c: _window_decode(c)),
     "DECODE_ATTN_MULTI_KERNEL": ("decode_attention", _arm("multi")),
     "PAGED_DECODE_ATTN_MULTI_KERNEL":
         ("decode_attention", _arm("paged_multi16")),
@@ -668,3 +670,166 @@ def test_moe_relu2_kernels_carry_their_names(tokens, one_chip):
         _sds((2 * held, lat, f)), _sds((2 * held, f, lat)))
     names = sorted(n.split(".")[0] for n in _kernel_names(compiled))
     assert names == [dropless.MOE_DOWN_KERNEL, dropless.MOE_UP_RELU2_KERNEL]
+
+
+# ---------------------------------------------------------------------------
+# MiMo-V2.5 at its published widths and the cell's own shapes
+# (benchmark/configs/mimo-v2.5.json: layer 0 + one period of five window
+# layers and a full one, 16 of 256 experts held, 192 slots of window rings,
+# 13,825 pages of 4 key heads, keys 192 wide kept 256 wide over values of
+# 128): the window decode kernel, the paged kernel at two widths, and both
+# serve programs
+# ---------------------------------------------------------------------------
+
+MIMO_SLOTS, MIMO_PAGE_LEN, MIMO_PAGES, MIMO_MAX_PAGES = 192, 64, 13825, 128
+
+
+def _window_decode(one_chip, slots=MIMO_SLOTS):
+    from deepspeed_tpu.ops.pallas.decode_attention import \
+        window_decode_attention
+    return _compile(
+        lambda q, k, v, n, b, base: window_decode_attention(
+            q, k, v, n, b, base=base, sm_scale=192 ** -0.5,
+            interpret=False),
+        one_chip, _sds((slots, 64, 256)), _sds((5 * slots, 8, 128, 256)),
+        _sds((5 * slots, 8, 128, 128)), _sds((slots,), jnp.int32),
+        _sds((64,)), _sds((), jnp.int32))
+
+
+def test_window_decode_kernel_reads_the_rings_where_they_lie(one_chip):
+    from deepspeed_tpu.ops.pallas.decode_attention import \
+        WINDOW_DECODE_ATTN_KERNEL
+    assert WINDOW_DECODE_ATTN_KERNEL == "ds_window_decode_attn"
+    compiled = _window_decode(one_chip)
+    names = _kernel_names(compiled)
+    assert [n.split(".")[0] for n in names] == [WINDOW_DECODE_ATTN_KERNEL]
+    # no layer's slots are sliced out of the rings: the base is traced
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 << 20
+
+
+def test_paged_decode_kernel_at_two_widths_keeps_its_name(one_chip):
+    """64 query heads on 4 key heads, keys 256 wide at rest over values
+    of 128: the grouped direct body, 16 pages of 64 a block inside the
+    module's VMEM budget, the pools left in HBM."""
+    shape = (4, MIMO_PAGE_LEN, 256, 2)
+    assert paged_decode_arm(*shape, q_heads=64) == "direct"
+    ppb = paged_pages_per_block(*shape, MIMO_MAX_PAGES, q_heads=64,
+                                v_head_dim=128)
+    assert ppb == 16
+    assert ppb * 2 * 4 * MIMO_PAGE_LEN * (256 + 128) * 2 \
+        <= PAGED_KV_VMEM_BUDGET
+    compiled = _compile(
+        lambda q, k, v, t, n: decode_attention_paged(
+            q, k, v, t, n, sm_scale=192 ** -0.5, interpret=False),
+        one_chip, _sds((MIMO_SLOTS, 64, 256)),
+        _sds((MIMO_PAGES, 4, MIMO_PAGE_LEN, 256)),
+        _sds((MIMO_PAGES, 4, MIMO_PAGE_LEN, 128)),
+        _sds((MIMO_SLOTS, MIMO_MAX_PAGES), jnp.int32),
+        _sds((MIMO_SLOTS,), jnp.int32))
+    names = _kernel_names(compiled)
+    assert [n.split(".")[0] for n in names] == [PAGED_DECODE_ATTN_KERNEL]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def _mimo_program(program, one_chip):
+    """The model's paged step as the engine calls it: pools and window
+    state donated."""
+    from deepspeed_tpu.models.mimo_v2 import MimoV2Config, MimoV2Model
+    model = MimoV2Model(MimoV2Config(
+        vocab_size=19072, num_hidden_layers=7,
+        hybrid_layer_pattern=(0, 1, 1, 1, 1, 1, 0),
+        moe_layer_freq=(0, 1, 1, 1, 1, 1, 1), experts_held=(0, 16),
+        param_dtype="bfloat16"))
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    k_pool = _sds((2, MIMO_PAGES, 4, MIMO_PAGE_LEN, 256))
+    v_pool = _sds((2, MIMO_PAGES, 4, MIMO_PAGE_LEN, 128))
+    state = model.serving_state(MIMO_SLOTS)
+    i32, s = _sds((), jnp.int32), MIMO_SLOTS
+    if program == "serve_decode":
+        def fn(p, t, k, v, tab, ln, act, st):
+            return model.decode_step_paged(p, t, k, v, tab, ln, act,
+                                           state=st, impl="pallas", aux=True)
+        shapes = (params, _sds((s,), jnp.int32), k_pool, v_pool,
+                  _sds((s, MIMO_MAX_PAGES), jnp.int32), _sds((s,), jnp.int32),
+                  _sds((s,), jnp.bool_), state)
+        donate = (2, 3, 7)
+    else:
+        def fn(p, t, n, row, k, v, st, slot):
+            return model.prefill_paged(p, t, n, jnp.int32(0), row, k, v,
+                                       state=st, slot=slot, aux=True)
+        shapes = (params, _sds((1, 4096), jnp.int32), i32,
+                  _sds((MIMO_MAX_PAGES,), jnp.int32), k_pool, v_pool, state,
+                  i32)
+        donate = (4, 5, 6)
+    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), shapes)
+    with interpret_scope(False):
+        return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+
+
+@pytest.mark.parametrize("program", ["serve_decode", "serve_prefill"])
+def test_mimo_programs_hold_their_kernels_and_no_copy_of_a_cache(
+        program, one_chip):
+    """Every Mosaic call of both serve programs starts ``ds_``; the pool
+    (5.4 GB) and the window rings (0.75 GB) pass through aliased to the
+    outputs; a decode tick's temporaries stay under 0.2 GB: no program
+    copies a pool or a state leaf; all the chip must hold at once fits
+    its 16.91e9 bytes."""
+    from deepspeed_tpu.moe import dropless
+    from deepspeed_tpu.ops.pallas.decode_attention import \
+        WINDOW_DECODE_ATTN_KERNEL
+    compiled = _mimo_program(program, one_chip)
+    names = {n.split(".")[0] for n in _kernel_names(compiled)}
+    experts = {dropless.MOE_GATE_UP_KERNEL, dropless.MOE_DOWN_KERNEL}
+    assert names == experts | (
+        {WINDOW_DECODE_ATTN_KERNEL, PAGED_DECODE_ATTN_KERNEL}
+        if program == "serve_decode" else {"ds_flash_fwd"}), names
+    mem = compiled.memory_analysis()
+    pools = 2 * MIMO_PAGES * 4 * MIMO_PAGE_LEN * (256 + 128) * 2
+    rings = 5 * MIMO_SLOTS * 8 * 128 * (256 + 128) * 2
+    assert mem.alias_size_in_bytes >= pools + rings
+    limit = 0.2e9 if program == "serve_decode" else 1.6e9
+    assert mem.temp_size_in_bytes < limit, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.0e9
+
+
+@pytest.mark.parametrize("model", ["gpt2", "bert"])
+def test_training_flash_calls_are_what_they_were(model):
+    """A window, a sink, grouped keys and a second width were added to
+    ``ds_flash_fwd`` for the serving prefill.  A training step's three
+    flash calls take the operands they took before (6, 9 and 9: no sink
+    tile), over the whole causal or bidirectional grid (no band), with
+    blocks as wide as the keys, and their kernels are bound with none of
+    the new switches."""
+    def loss(q, k, v, mask=None):
+        return flash_attention(q, k, v, causal=model == "gpt2",
+                               key_mask=mask, interpret=False
+                               ).astype(jnp.float32).sum()
+
+    shape = (2, 12, SEQ, DH) if model == "gpt2" else (2, 16, 512, DH)
+    qkv = [_sds(shape)] * 3
+    mask = () if model == "gpt2" else (_sds(shape[::2], jnp.bool_),)
+    jaxpr = jax.make_jaxpr(jax.grad(jax.checkpoint(loss), argnums=(0, 1, 2))
+                           )(*qkv, *mask)
+    calls = {}
+
+    def walk(j):
+        for eqn in j.eqns:
+            if eqn.primitive.name == "pallas_call":
+                calls.setdefault(eqn.params["name"], eqn)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jaxpr.jaxpr)
+    assert sorted(calls) == ["ds_flash_bwd_dkv", "ds_flash_bwd_dq",
+                             "ds_flash_fwd"]
+    bh, blocks = shape[0] * shape[1], shape[2] // 512
+    for name, operands in (("ds_flash_fwd", 6), ("ds_flash_bwd_dq", 9),
+                           ("ds_flash_bwd_dkv", 9)):
+        eqn = calls[name]
+        assert len(eqn.invars) == operands, (name, len(eqn.invars))
+        assert eqn.params["grid_mapping"].grid == (bh, blocks, blocks)
+    fwd = calls["ds_flash_fwd"]
+    assert [tuple(v.aval.shape) for v in fwd.outvars][0] == (bh,) + shape[2:]
+    text = str(fwd.params["jaxpr"])
+    assert "window" not in text and "sink" not in text

@@ -307,9 +307,11 @@ def test_paged_kernel_grid_at_xl_serving_shapes():
             for pl_ in (16, 64, 128)] == [8, 2, 1]
     assert _paged_call_grid(**{**x, "page_len": 64, "max_pages": 16,
                                "P": 209}) == (32, 8)
-    # shapes only: q_heads (PR 34) is the query heads over grouped keys
+    # shapes only: q_heads (PR 34) is the query heads over grouped keys,
+    # v_head_dim (PR 36) the values' width where it is not the keys'
     assert list(inspect.signature(paged_pages_per_block).parameters) == [
-        "heads", "page_len", "head_dim", "itemsize", "max_pages", "q_heads"]
+        "heads", "page_len", "head_dim", "itemsize", "max_pages", "q_heads",
+        "v_head_dim"]
     source = inspect.getsource(module)
     assert "environ" not in source and "getenv" not in source
 

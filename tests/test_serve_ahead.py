@@ -107,9 +107,24 @@ def _serve(eng, requests):
 def _length(family):
     # more requests than the full engine has slots, of unequal lengths:
     # every finish is predicted, and each freed slot's admission lands
-    # between two ticks sent ahead (its first token merged on the device)
+    # between two ticks sent ahead: the second of them goes BEHIND the
+    # admission's prefill, its first token merged on the device
     return dict(requests=[(_prompt(i), n, None)
-                          for i, n in enumerate((9, 4, 6, 2, 7))])
+                          for i, n in enumerate((9, 4, 6, 2, 7))],
+                behind=3)
+
+
+def _eos_first(family):
+    # the third request's eos is the token its prefill emits: the tick
+    # sent behind that prefill has run its row by the time the host sees
+    # the token, and the slot it leaves takes the fourth request in the
+    # same step, in the old order (one tick behind a prefill a step)
+    streams, _ = _serve(_engine(family, ROOMY), [(_prompt(2), 2, None)])
+    return dict(requests=[(_prompt(0), 9, None), (_prompt(1), 3, None),
+                          (_prompt(2), 9, streams[0][0][0]),
+                          (_prompt(3), 4, None)],
+                wasted=1, behind=1,
+                reasons=["length", "length", "eos", "length"])
 
 
 def _eos_late(family):
@@ -146,7 +161,7 @@ def _dry_pool(family):
                 reasons=["length", "kv_capacity"])
 
 
-CASES = [(f, c) for c in (_length, _eos_late, _page_boundary)
+CASES = [(f, c) for c in (_length, _eos_late, _page_boundary, _eos_first)
          for f in MODELS] + [("gpt2", _seq_capacity), ("gpt2", _dry_pool)]
 
 
@@ -167,6 +182,10 @@ def test_ahead_and_synchronous_engines_emit_the_same(family, case):
     assert s_stats["ahead"] == 0 and s_stats["sync"] > 0
     assert a_stats["wasted_rows"] == spec.get("wasted", 0)
     assert s_stats["wasted_rows"] == 0
+    # ticks sent behind the prefill that took the last slot
+    if "behind" in spec:
+        assert a_stats["behind"] == spec["behind"]
+    assert s_stats["behind"] == 0
     for eng in (full, roomy):
         assert eng._decode_fn._cache_size() == 1
         assert eng._feed_fn._cache_size() == 1
@@ -289,7 +308,8 @@ def test_the_order_of_dispatch_and_pull_follows_the_slots(tmp_path):
         assert order(("_decode_dispatch", n + 1), ("_pull_tokens", n))
         assert order(("_pull_tokens", n), ("_emit_tokens", n))
     full_stats = {k: eng.ahead_stats[k] - base[k] for k in base}
-    assert full_stats == {"ahead": 4, "sync": 1, "wasted_rows": 0}
+    assert full_stats == {"ahead": 4, "behind": 0, "sync": 1,
+                          "wasted_rows": 0}
 
     # a free slot: 1 request in 2 slots
     eng.submit(_prompt(3), max_new_tokens=6)

@@ -4,10 +4,11 @@ The serving half of the north star: requests stream through a bounded
 queue into a FIXED pool of decode slots, and two compiled programs
 serve every mix —
 
-  ``prefill``      one request's prompt (right-padded to the static
-                   ``serving.prefill_len`` bucket) → its K/V rows
-                   written into the assigned slot + the first greedy
-                   token.
+  ``prefill``      one request's prompt (right-padded to the smallest
+                   rung of the prefill ladder that holds it:
+                   ``prefill_ladder(serving.prefill_len)``) → its K/V
+                   rows written into the assigned slot + the first
+                   greedy token.
   ``decode_step``  ONE masked tick for ALL slots at once: each active
                    slot's last token in, its next greedy token out, its
                    K/V appended in place.  Free/finished slots ride
@@ -94,6 +95,7 @@ import contextlib
 import json
 import time
 from collections import deque
+from concurrent.futures import Future, ThreadPoolExecutor, wait
 from typing import Any, Dict, List, NamedTuple, Optional
 
 import jax
@@ -124,6 +126,22 @@ _FROM_DEVICE = -1
 #: this slot's input is the first token of the prefill just sent, which
 #: the host has not seen yet (``_send_behind_prefill``)
 _FROM_PREFILL = -2
+
+
+def prefill_ladder(prefill_len: int) -> tuple:
+    """The lengths the prefill program is built at, ascending:
+    ``prefill_len`` and, where that is a whole multiple of 256, its half
+    (docs/serving.md "The prefill ladder").  256 tokens is where a bf16
+    matmul stops being bound by reading its weights, so a shorter program
+    would save nothing, and it is a multiple of every unit a prefill
+    program has (page, window, scan chunk, flash block).  Two rungs and
+    not more: each costs the host a second or two of lowering and loading
+    at set-up, which a short set-up cannot hide (``PERF.md`` section 6,
+    PR 37).  Under 512 the ladder is ``(prefill_len,)``."""
+    prefill_len = int(prefill_len)
+    if prefill_len % 512:
+        return (prefill_len,)
+    return (prefill_len // 2, prefill_len)
 
 
 class _Tick(NamedTuple):
@@ -363,13 +381,22 @@ class ServeEngine:
         self._aux_keys = tuple(getattr(model, "serving_aux", ()))
         self._aux = self.paged and bool(self._aux_keys)
         self.aux_log: deque = deque(maxlen=65536)
-        #: what the one prefill bucket costs: every prefill runs the whole
-        #: ``[1, prefill_len]`` program, so a prompt (or delta, or chunk)
-        #: of n tokens pays for ``prefill_len - n`` more; summed, beside
-        #: the tokens that were wanted (counter
+        #: the lengths the prefill program is built at, ascending
+        #: (``prefill_ladder``): a call runs the smallest that holds its
+        #: tokens, the longest is ``prefill_len``
+        self.prefill_buckets = prefill_ladder(self.prefill_len)
+        #: rung -> prefill calls that ran it (counter
+        #: ``serve_prefills_total{bucket=}``)
+        self.prefill_calls = {r: 0 for r in self.prefill_buckets}
+        #: what the rungs cost: a prompt (or delta, or chunk) of n tokens
+        #: in a rung of r pays for ``r - n`` more; summed, beside the
+        #: tokens that were wanted (counter
         #: ``serve_prefill_pad_tokens_total``)
         self.prefill_pad_tokens = 0
         self.prefill_tokens = 0
+        #: the rungs' executables on their way (``_build_prefill_rungs``);
+        #: None for a ladder of one rung, which is the jitted program
+        self._prefill_build: Optional[Future] = None
         #: request state by slot (class docstring): name -> shape and
         #: dtype, {} for a model that keeps none
         self._state_spec = (dict(model.serving_state(self.slots))
@@ -923,8 +950,12 @@ class ServeEngine:
             self._kv_bytes_gauge.set(self.kv_bytes)
             self._prefill_pad_ctr = reg.counter(
                 "serve_prefill_pad_tokens_total",
-                "tokens of the one prefill bucket that were padding: "
-                "prefill_len less the prompt (delta, chunk) a call")
+                "tokens the prefill calls ran that were padding: the "
+                "rung a call took less its prompt (delta, chunk)")
+            self._prefills_ctr = reg.counter(
+                "serve_prefills_total",
+                "prefill calls by the rung of the prefill ladder "
+                "(tokens of the program) they ran")
             layers = getattr(self.model, "serving_cache_layers", None)
             if layers is not None:
                 layer_gauge = reg.gauge(
@@ -1044,6 +1075,14 @@ class ServeEngine:
         self._last_flush_t = time.perf_counter()
         self._last_flush_tokens = 0
         self._tokens_seen = 0
+        if len(self.prefill_buckets) > 1:
+            # beside whatever the caller does between building an engine
+            # and its first prompt (probing, warming a tick): the first
+            # prefill call waits for the rungs, and so does close()
+            pool = ThreadPoolExecutor(
+                1, thread_name_prefix="serve_prefill_rungs")
+            self._prefill_build = pool.submit(self._build_prefill_rungs)
+            pool.shutdown(wait=False)
 
     # -- speculative decoding: the draft plane --------------------------
     def _build_spec_plane(self, cfg, mcfg, kv_dtype, draft_params,
@@ -1636,7 +1675,9 @@ class ServeEngine:
         holds.  The prefill logits are discarded — the tick's first
         pending token is the TARGET's emission.  ``slot`` overrides
         the next-free-slot peek for requests already admitted (chunked
-        prefill's final chunk, KV adoption)."""
+        prefill's final chunk, KV adoption).  Always the whole
+        ``prefill_len``: the draft's program is its own and has no
+        ladder."""
         dtokens = np.zeros((1, self.prefill_len), np.int32)
         dtokens[0, :len(req.prompt)] = req.prompt
         with self._span("serve/draft_prefill", rid=req.rid):
@@ -1647,14 +1688,60 @@ class ServeEngine:
                     np.int32(self.scheduler.free[0]
                              if slot is None else slot))
 
-    def _note_prefill_pad(self, wanted: int) -> None:
-        """A call of the prefill program on ``wanted`` tokens of its
-        ``prefill_len`` bucket."""
-        pad = self.prefill_len - wanted
-        self.prefill_tokens += wanted
-        self.prefill_pad_tokens += pad
+    def _prefill_operand(self, wanted) -> np.ndarray:
+        """A prefill call's ``tokens`` for the ``wanted`` ones (a prompt,
+        a delta, a chunk): right-padded to the smallest rung that holds
+        them, the call and its padding counted."""
+        n = len(wanted)
+        rung = next(r for r in self.prefill_buckets if r >= n)
+        tokens = np.zeros((1, rung), np.int32)
+        tokens[0, :n] = wanted
+        self.prefill_calls[rung] += 1
+        self.prefill_tokens += n
+        self.prefill_pad_tokens += rung - n
         if self.telemetry is not None:
-            self._prefill_pad_ctr.inc(pad)
+            self._prefill_pad_ctr.inc(rung - n)
+            self._prefills_ctr.inc(bucket=str(rung))
+        return tokens
+
+    def _build_prefill_rungs(self) -> Dict[int, Any]:
+        """rung -> ``serve_prefill`` compiled at that length, ahead of
+        time, on the shapes of the operands the admission arms hand it
+        (``_admit_one_paged`` and ``_prefill_chunk_tick``, or
+        ``_admit_one_slot``).  All rungs are executables of the one jitted
+        function, which keeps the name and an empty cache of its own.
+        Runs on a thread of its own from construction on, so that a
+        harness which warms an engine on a prompt or two finds every rung
+        ready, and pays for them beside its own set-up, not after it."""
+        def like(x):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                        sharding=x.sharding)
+
+        i32 = jax.ShapeDtypeStruct((), np.int32)
+        rest = [i32]                            # delta_len | length
+        if self.paged:                          # prefix_len, page_row
+            rest += [i32, jax.ShapeDtypeStruct((self.max_pages,), np.int32)]
+        rest.append(i32)                        # slot
+        if self.paged and self.lora:
+            rest += [jax.tree.map(like, self._lora_pools), i32]
+        if self._rng_base is not None:
+            rest.append(jax.ShapeDtypeStruct(self._rng_base.shape,
+                                             self._rng_base.dtype))
+        held = jax.tree.map(like, (self.params, self.cache))
+        with self._pallas_scope():
+            return {r: self._prefill_fn.lower(
+                *held, jax.ShapeDtypeStruct((1, r), np.int32),
+                *rest).compile() for r in self.prefill_buckets}
+
+    def _run_prefill(self, *operands):
+        """``serve_prefill`` at the length of its tokens (operand 2): the
+        jitted program for a ladder of one rung, as ever; else that rung's
+        executable, once all of them are built, so that no later length
+        compiles anything."""
+        if len(self.prefill_buckets) == 1:
+            return self._prefill_fn(*operands)
+        rungs = self._prefill_build.result()
+        return rungs[operands[2].shape[1]](*operands)
 
     def _admit_one_paged(self, req: Request) -> bool:
         total_pages = -(-len(req.prompt) // self.page_len)
@@ -1763,8 +1850,7 @@ class ServeEngine:
                     req.adapter_slot = aslot
                     self._adapter_table[slot] = aslot
                 return True
-            tokens = np.zeros((1, self.prefill_len), np.int32)
-            tokens[0, :len(delta)] = delta
+            tokens = self._prefill_operand(delta)
             row_np = np.zeros((self.max_pages,), np.int32)
             row_np[:len(row)] = row
             with self._span("serve/prefill", rid=req.rid,
@@ -1775,9 +1861,8 @@ class ServeEngine:
                     tr.flow_start("serve/request", req.ctx, cat="serve",
                                   rid=req.rid)
                 self._charge_prefill_delay(len(delta))
-                self._note_prefill_pad(len(delta))
                 with self._pallas_scope():
-                    self.cache, first, *aux = self._prefill_fn(
+                    self.cache, first, *aux = self._run_prefill(
                         self.params, self.cache, tokens,
                         np.int32(len(delta)), np.int32(shared_len),
                         row_np, np.int32(self.scheduler.free[0]),
@@ -1889,8 +1974,7 @@ class ServeEngine:
         return slot
 
     def _admit_one_slot(self, req: Request) -> bool:
-        tokens = np.zeros((1, self.prefill_len), np.int32)
-        tokens[0, :len(req.prompt)] = req.prompt
+        tokens = self._prefill_operand(req.prompt)
         length = np.int32(len(req.prompt))
         req.admit_t = time.perf_counter()
         if req.queue_span is not None:
@@ -1907,9 +1991,8 @@ class ServeEngine:
                 # the request rides emits a flow step
                 tr.flow_start("serve/request", req.ctx, cat="serve",
                               rid=req.rid)
-            self._note_prefill_pad(len(req.prompt))
             with self._pallas_scope():
-                self.cache, first = self._prefill_fn(
+                self.cache, first = self._run_prefill(
                     self.params, self.cache, tokens, length,
                     np.int32(self.scheduler.free[0]),
                     *self._maybe_key())
@@ -2030,7 +2113,7 @@ class ServeEngine:
         """One chunk of the OLDEST mid-prefill slot (Sarathi-Serve's
         co-scheduling policy, FIFO over prefilling slots): the same
         delta-aware compiled prefill program with ``prefix_len``
-        advanced to the chunk boundary — same prefill_len bucket,
+        advanced to the chunk boundary — the rung that holds a chunk,
         traced prefix/delta lengths and page row, so N chunks cost
         zero recompiles.  Intermediate chunk logits are discarded; the
         FINAL chunk's next-token is the request's first token (TTFT
@@ -2048,17 +2131,15 @@ class ServeEngine:
         pos = req.chunk_pos
         chunk = delta[pos:pos + self.prefill_chunk_len]
         final = pos + len(chunk) >= len(delta)
-        tokens = np.zeros((1, self.prefill_len), np.int32)
-        tokens[0, :len(chunk)] = chunk
+        tokens = self._prefill_operand(chunk)
         with self._span("serve/prefill_chunk", rid=req.rid, pos=pos,
                         chunk=len(chunk)):
             tr = self._tracer
             if final and tr is not None and req.ctx is not None:
                 tr.flow_start("serve/request", req.ctx, cat="serve",
                               rid=req.rid)
-            self._note_prefill_pad(len(chunk))
             with self._pallas_scope():
-                self.cache, first, *aux = self._prefill_fn(
+                self.cache, first, *aux = self._run_prefill(
                     self.params, self.cache, tokens,
                     np.int32(len(chunk)),
                     np.int32(req.shared_len + pos),
@@ -2831,6 +2912,8 @@ class ServeEngine:
         if self._closed:
             return
         self._closed = True
+        if self._prefill_build is not None:
+            wait([self._prefill_build])
         errors = self._graph.close_all()
         if errors:
             raise errors[0][1]

@@ -10,6 +10,7 @@ inside the module-scoped fixture below: the TPU library is loaded by the
 first test that runs, in the one xdist worker that owns this file, and
 never while a module is imported.
 """
+import functools
 import os
 
 import jax
@@ -558,9 +559,10 @@ def _nemotron():
     return model, params, pool, model.serving_state(NEMO_SLOTS)
 
 
-def _nemotron_program(program, one_chip):
+@functools.lru_cache(maxsize=None)
+def _nemotron_program(program, one_chip, bucket=1024):
     """The model's paged step as the engine calls it: pools and state
-    donated."""
+    donated; a prefill at ``bucket`` tokens."""
     model, params, pool, state = _nemotron()
     i32, s = _sds((), jnp.int32), NEMO_SLOTS
     if program == "serve_decode":
@@ -575,7 +577,7 @@ def _nemotron_program(program, one_chip):
         def fn(p, t, n, row, k, v, st, slot):
             return model.prefill_paged(p, t, n, jnp.int32(0), row, k, v,
                                        state=st, slot=slot, aux=True)
-        shapes = (params, _sds((1, 1024), jnp.int32), i32,
+        shapes = (params, _sds((1, bucket), jnp.int32), i32,
                   _sds((NEMO_MAX_PAGES,), jnp.int32), pool, pool, state, i32)
         donate = (4, 5, 6)
     args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
@@ -731,9 +733,10 @@ def test_paged_decode_kernel_at_two_widths_keeps_its_name(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
 
 
-def _mimo_program(program, one_chip):
+@functools.lru_cache(maxsize=None)
+def _mimo_program(program, one_chip, bucket=4096):
     """The model's paged step as the engine calls it: pools and window
-    state donated."""
+    state donated; a prefill at ``bucket`` tokens."""
     from deepspeed_tpu.models.mimo_v2 import MimoV2Config, MimoV2Model
     model = MimoV2Model(MimoV2Config(
         vocab_size=19072, num_hidden_layers=7,
@@ -757,7 +760,7 @@ def _mimo_program(program, one_chip):
         def fn(p, t, n, row, k, v, st, slot):
             return model.prefill_paged(p, t, n, jnp.int32(0), row, k, v,
                                        state=st, slot=slot, aux=True)
-        shapes = (params, _sds((1, 4096), jnp.int32), i32,
+        shapes = (params, _sds((1, bucket), jnp.int32), i32,
                   _sds((MIMO_MAX_PAGES,), jnp.int32), k_pool, v_pool, state,
                   i32)
         donate = (4, 5, 6)
@@ -791,6 +794,32 @@ def test_mimo_programs_hold_their_kernels_and_no_copy_of_a_cache(
     limit = 0.2e9 if program == "serve_decode" else 1.6e9
     assert mem.temp_size_in_bytes < limit, mem.temp_size_in_bytes
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.0e9
+
+
+@pytest.mark.parametrize("family,bucket", [("mimo", 2048),
+                                           ("nemotron", 512)])
+def test_the_lower_rung_of_the_prefill_ladder_compiles_under_the_top_rungs_peak(
+        family, bucket, one_chip):
+    """``ServeEngine`` builds ``serve_prefill`` at half of
+    ``serving.prefill_len`` too (``inference/engine.py::prefill_ladder``:
+    4,096 -> 2,048; 1,024 -> 512).  The shorter rung holds the same
+    kernels, passes the same caches through aliased, and needs fewer
+    temporaries than the top rung: what the chip must hold at once is
+    still set by ``prefill_len``."""
+    from deepspeed_tpu.inference.engine import prefill_ladder
+    build = {"mimo": _mimo_program, "nemotron": _nemotron_program}[family]
+    top = build("serve_prefill", one_chip)
+    top_len = top.in_avals[0][1].shape[1]
+    assert bucket in prefill_ladder(top_len)[:-1]
+    rung = build("serve_prefill", one_chip, bucket)
+    assert {n.split(".")[0] for n in _kernel_names(rung)} \
+        == {n.split(".")[0] for n in _kernel_names(top)}
+    mem, top_mem = rung.memory_analysis(), top.memory_analysis()
+    assert mem.alias_size_in_bytes == top_mem.alias_size_in_bytes
+    assert mem.argument_size_in_bytes <= top_mem.argument_size_in_bytes
+    print(f"{family} serve_prefill temporaries: {mem.temp_size_in_bytes} B "
+          f"at {bucket} tokens, {top_mem.temp_size_in_bytes} B at {top_len}")
+    assert mem.temp_size_in_bytes < top_mem.temp_size_in_bytes
 
 
 @pytest.mark.parametrize("model", ["gpt2", "bert"])

@@ -1,0 +1,319 @@
+"""The prefill ladder (docs/serving.md "The prefill ladder",
+``inference/engine.py::prefill_ladder``): ``serve_prefill`` is built at
+``serving.prefill_len`` and at its half, and a call runs the smaller rung
+where that holds its tokens.  Proved here by equality and by count, never
+by speed: the same prompts through an engine with its ladder and through
+the same engine held to its top rung give the same streams, ``kv_len``,
+pages and (MiMo-V2) window rings; the counters say which rung ran; and
+once the first prefill has returned, no length compiles anything.  CPU,
+tiny widths, seeded weights.
+"""
+import jax
+import numpy as np
+import pytest
+from jax import monitoring
+
+from deepspeed_tpu.inference import ServeEngine
+from deepspeed_tpu.inference.engine import prefill_ladder
+from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
+from deepspeed_tpu.models.mimo_v2 import MimoV2Config, MimoV2Model
+
+TOP = 1024
+LENGTHS = (5, 250, 257, 512, 513, 600, 1024)
+RUNG = {n: 512 if n <= 512 else 1024 for n in LENGTHS}
+NEW = 3
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+@pytest.mark.parametrize("prefill_len,ladder", [
+    (32, (32,)), (128, (128,)), (256, (256,)), (512, (256, 512)),
+    (1024, (512, 1024)), (4096, (2048, 4096)), (8192, (4096, 8192)),
+    # the half is a rung only as a whole multiple of 256
+    (768, (768,)), (1000, (1000,)), (1280, (1280,)), (1536, (768, 1536))])
+def test_the_ladder_of_a_prefill_len(prefill_len, ladder):
+    assert prefill_ladder(prefill_len) == ladder
+    assert ladder[-1] == prefill_len and len(ladder) <= 2
+    assert all(r % 256 == 0 and 2 * r == prefill_len for r in ladder[:-1])
+
+
+def _gpt2():
+    return GPT2Model(GPT2Config(vocab_size=128, n_positions=TOP + 8,
+                                d_model=32, n_layer=2, n_head=4, remat=None,
+                                attn_impl="dense"))
+
+
+def _mimo():
+    return MimoV2Model(MimoV2Config(
+        vocab_size=128, hidden_size=32, intermediate_size=48,
+        moe_intermediate_size=16, num_hidden_layers=3,
+        hybrid_layer_pattern=(0, 1, 0), moe_layer_freq=(0, 1, 1),
+        num_attention_heads=4, num_key_value_heads=2, head_dim=24,
+        v_head_dim=16, swa_num_attention_heads=4, swa_num_key_value_heads=2,
+        swa_head_dim=24, swa_v_head_dim=16, sliding_window=8,
+        n_routed_experts=8, num_experts_per_tok=2, experts_held=(0, 8),
+        max_position_embeddings=TOP + 8, attn_impl="dense"))
+
+
+MODELS = {"gpt2": _gpt2, "mimo_v2": _mimo}
+
+
+def _engine(family, one_rung=False, serving=(), **config):
+    model = MODELS[family]()
+    eng = ServeEngine(model, {
+        "serving": {"slots": 2, "page_len": 16, "max_seq_len": TOP + 8,
+                    "prefill_len": TOP, "prefix_cache": False,
+                    **dict(serving)}, **config},
+        params=model.init(jax.random.PRNGKey(0)))
+    if one_rung:
+        # the engine as it was before the ladder: held to its top rung
+        # from here, in the test; the program has no such option
+        eng.prefill_buckets = (TOP,)
+        eng.prefill_calls = {TOP: 0}
+    return eng
+
+
+def _prompt(n):
+    return [int(t) for t in np.random.default_rng(n).integers(1, 128, n)]
+
+
+def _leaves(eng, req):
+    """What the request holds on the device: its pages of each pool and,
+    for a model with window state, its slot's rings."""
+    out = {k: np.asarray(eng.cache[k])[:, np.asarray(req.pages)]
+           for k in ("k", "v")}
+    for name, leaf in eng.cache.get("state", {}).items():
+        out[name] = np.asarray(leaf)[:, req.slot]
+    return out
+
+
+def _serve_one(eng, n):
+    """One prompt of ``n`` tokens, alone: its stream, ``kv_len`` after its
+    first step (the prefill and a tick) and at the end, and what it holds
+    on the device after that step."""
+    req = eng.submit(_prompt(n), max_new_tokens=NEW)
+    eng.step()
+    kv_after_step = req.kv_len
+    leaves = _leaves(eng, req)
+    eng.run_until_idle()
+    return dict(tokens=list(req.tokens), reason=req.finish_reason,
+                kv_len=(kv_after_step, req.kv_len), leaves=leaves)
+
+
+@pytest.fixture(scope="module", params=sorted(MODELS))
+def served(request):
+    """Every length through the ladder and through the top rung alone."""
+    family = request.param
+    out = {}
+    for one_rung in (False, True):
+        eng = _engine(family, one_rung)
+        try:
+            out[one_rung] = {n: _serve_one(eng, n) for n in LENGTHS}
+            out[one_rung]["calls"] = dict(eng.prefill_calls)
+            out[one_rung]["jit_cache"] = eng._prefill_fn._cache_size()
+            out[one_rung]["programs"] = [f.__name__ for f in eng.programs()]
+        finally:
+            eng.close()
+    return out
+
+
+@pytest.mark.parametrize("n", LENGTHS)
+def test_a_rung_gives_what_the_top_rung_gives(served, n):
+    got, want = served[False][n], served[True][n]
+    assert got["tokens"] == want["tokens"] and len(got["tokens"]) == NEW
+    assert got["reason"] == want["reason"] == "length"
+    # the prompt's keys and the first tick's, one more a token after
+    assert got["kv_len"] == want["kv_len"] == (n + 1, n + NEW - 1)
+    assert sorted(got["leaves"]) == sorted(want["leaves"])
+    for name, leaf in got["leaves"].items():
+        # float32 on the CPU: the same sums in programs of two lengths
+        np.testing.assert_allclose(leaf, want["leaves"][name], atol=2e-5,
+                                   err_msg=name)
+
+
+def test_the_rungs_are_one_program_under_one_name(served):
+    assert served[False]["calls"] == {512: 4, 1024: 3}
+    assert served[True]["calls"] == {TOP: len(LENGTHS)}
+    assert served[False]["programs"] == served[True]["programs"]
+    assert served[False]["programs"].count("serve_prefill") == 1
+    # the rungs are executables built ahead from the one jitted function,
+    # whose own cache stays empty; held to one rung it is called as ever
+    assert (served[False]["jit_cache"], served[True]["jit_cache"]) == (0, 1)
+
+
+@pytest.mark.parametrize("prefill_len", [32, 128, 256])
+def test_under_512_an_engine_builds_one_prefill_program(prefill_len):
+    model = _gpt2()
+    eng = ServeEngine(model, {"serving": {
+        "slots": 2, "page_len": 16, "max_seq_len": TOP,
+        "prefill_len": prefill_len}})
+    try:
+        assert eng.prefill_buckets == (prefill_len,)
+        for n in (3, prefill_len):
+            eng.submit(_prompt(n), max_new_tokens=2)
+        eng.run_until_idle()
+        assert eng.prefill_calls == {prefill_len: 2}
+        assert eng.prefill_pad_tokens == prefill_len - 3
+        assert eng._prefill_fn._cache_size() == 1
+        assert eng._prefill_build is None
+    finally:
+        eng.close()
+
+
+@pytest.fixture(scope="module")
+def counted(tmp_path_factory):
+    """A ladder engine with telemetry on: its construction and first
+    prefill (5 tokens), then every other length, with the backend compiles
+    each step caused (``jax.monitoring``, what the benchmark's windows
+    count)."""
+    compiles = []
+
+    def listen(event, duration, **kw):
+        if event == COMPILE_EVENT:
+            compiles.append(event)
+
+    monitoring.register_event_duration_secs_listener(listen)
+    eng = _engine("gpt2", telemetry={
+        "enabled": True,
+        "output_path": str(tmp_path_factory.mktemp("ladder_tel"))})
+    rows = {}
+    try:
+        reg = eng.telemetry.registry
+        before = 0
+        for n in LENGTHS:
+            eng.submit(_prompt(n), max_new_tokens=NEW)
+            eng.run_until_idle()
+            eng.telemetry.compile_monitor.sample()
+            rows[n] = dict(
+                compiles=len(compiles) - before,
+                recompiles=reg.counter("recompiles_total", "").value(
+                    program="serve_prefill"),
+                rungs_ready=sorted(eng._prefill_build.result()))
+            before = len(compiles)
+        rows["calls"] = dict(eng.prefill_calls)
+        rows["tokens"] = (eng.prefill_tokens, eng.prefill_pad_tokens)
+        rows["pad_counter"] = reg.counter(
+            "serve_prefill_pad_tokens_total", "").value()
+        rows["by_bucket"] = {
+            r: reg.counter("serve_prefills_total", "").value(bucket=str(r))
+            for r in eng.prefill_buckets}
+        rows["buckets"] = eng.prefill_buckets
+    finally:
+        eng.close()
+        monitoring.unregister_event_duration_listener(listen)
+    return rows
+
+
+def test_the_first_prefill_finds_every_rung_ready(counted):
+    first = counted[LENGTHS[0]]
+    assert first["rungs_ready"] == [512, 1024]
+    # from construction to the first request's end: both rungs and the
+    # decode tick, at least
+    assert first["compiles"] >= 3
+
+
+@pytest.mark.parametrize("n", LENGTHS[1:])
+def test_after_the_first_prefill_no_length_compiles(counted, n):
+    assert counted[n]["compiles"] == 0
+    assert counted[n]["recompiles"] == counted[LENGTHS[0]]["recompiles"] == 0
+
+
+def test_the_counters_say_which_rung_ran(counted):
+    assert counted["buckets"] == (512, 1024)
+    assert counted["calls"] == {512: 4, 1024: 3}
+    assert counted["by_bucket"] == {512: 4.0, 1024: 3.0}
+    wanted = sum(LENGTHS)
+    pad = sum(RUNG[n] - n for n in LENGTHS)
+    assert counted["tokens"] == (wanted, pad)
+    assert counted["pad_counter"] == pad
+    # against the one bucket: 7 x 1,024 less the tokens wanted
+    assert pad < len(LENGTHS) * TOP - wanted
+
+
+@pytest.mark.parametrize("family", sorted(MODELS))
+def test_a_full_engines_admission_still_goes_behind_its_prefill(family):
+    """More requests than slots, prompts of both rungs: each admission
+    that takes the last slot sends the next tick behind its prefill,
+    whichever rung that prefill ran (``_send_behind_prefill`` takes the
+    token where the program left it), and the streams are the top
+    rung's."""
+    requests = [(_prompt(n), new) for n, new in
+                ((300, 9), (20, 4), (700, 6), (512, 2), (513, 7))]
+    out = {}
+    for one_rung in (False, True):
+        eng = _engine(family, one_rung)
+        try:
+            reqs = [eng.submit(p, max_new_tokens=new) for p, new in requests]
+            eng.run_until_idle()
+            assert eng._inflight is None and not eng.pool.refs
+            out[one_rung] = (
+                [(list(r.tokens), r.finish_reason, r.kv_len) for r in reqs],
+                dict(eng.ahead_stats), dict(eng.prefill_calls))
+        finally:
+            eng.close()
+    assert out[False][0] == out[True][0]
+    assert out[False][1] == out[True][1] and out[False][1]["behind"] >= 2
+    assert out[False][2] == {512: 3, 1024: 2}
+
+
+ARMS = {
+    # the slot cache: serve_prefill takes (length, slot) and no page row
+    "slot_cache": dict(serving={"page_len": 0}),
+    # a prefix hit: the second prompt's delta is its last 40 tokens
+    "prefix_delta": dict(serving={"prefix_cache": True}),
+    # tenant adapters: two more operands ahead of the slot's tail
+    "lora": dict(serving={"lora": {"rank": 2, "alpha": 4.0,
+                                   "max_adapters": 4,
+                                   "hbm_adapter_slots": 2,
+                                   "targets": ["qkv_w"]}}),
+    # sampling: a key as the last operand
+    "sampling": dict(serving={"temperature": 0.7}),
+}
+
+
+@pytest.mark.parametrize("arm", sorted(ARMS))
+def test_every_admission_arm_takes_the_ladder(arm):
+    """The rungs are built on the operands each arm hands
+    ``serve_prefill`` (``_build_prefill_rungs``): a mismatch would fail
+    the first prefill.  Same streams as the top rung alone, but under
+    sampling, where a tie in float32 may fall the other way."""
+    shared = _prompt(600)
+    requests = [(_prompt(30), 0), (shared, 1), (shared[:560] + _prompt(40), 1),
+                (_prompt(400), 2)]
+    out = {}
+    for one_rung in (False, True):
+        eng = _engine("gpt2", one_rung, **ARMS[arm])
+        try:
+            reqs = [eng.submit(p, max_new_tokens=NEW,
+                               **({"adapter_id": t} if arm == "lora" else {}))
+                    for p, t in requests]
+            eng.run_until_idle()
+            assert all(r.finish_reason == "length" and r.error is None
+                       for r in reqs)
+            out[one_rung] = ([list(r.tokens) for r in reqs],
+                             dict(eng.prefill_calls))
+        finally:
+            eng.close()
+    if arm != "sampling":
+        assert out[False][0] == out[True][0]
+    delta = arm == "prefix_delta"
+    assert out[False][1] == {512: 3 if delta else 2,
+                             1024: 1 if delta else 2}
+    assert out[True][1] == {TOP: 4}
+
+
+def test_a_chunk_runs_the_rung_that_holds_it():
+    """Chunked prefill takes the ladder as is: a chunk of 64 tokens runs
+    the lower rung, not the whole ``prefill_len``; same stream."""
+    out = {}
+    for one_rung in (False, True):
+        eng = _engine("gpt2", one_rung, serving={"prefill_chunk_len": 64})
+        try:
+            req = eng.submit(_prompt(200), max_new_tokens=NEW)
+            eng.run_until_idle()
+            out[one_rung] = (list(req.tokens), req.kv_len,
+                             dict(eng.prefill_calls))
+        finally:
+            eng.close()
+    assert out[False][:2] == out[True][:2]
+    assert out[False][2] == {512: 4, 1024: 0}
+    assert out[True][2] == {TOP: 4}

@@ -111,6 +111,7 @@ from ..parallel.mesh import DATA_AXIS, MODEL_AXIS, build_mesh
 from ..runtime.engine_stages import wire_serve_stage_plane
 from ..runtime.stages import Channel, Stage, injected_delay
 from ..telemetry import tracing
+from ..telemetry.device_queue import DeviceQueueBook, Phase
 from ..utils.logging import logger
 from .kv_cache import (KVCacheSpec, PagedKVCacheSpec, cache_shardings,
                        init_cache, init_paged_cache,
@@ -149,6 +150,7 @@ class _Tick(NamedTuple):
     active_map: Dict[int, Request]  #: slot -> the request whose row it runs
     next_tok: Any                   #: [slots] int32 on the device
     aux: tuple                      #: a ``serving_aux`` model's counters, or ()
+    rec: dict                       #: its record in the device's queue book
 
 
 class _ServeConfigView:
@@ -232,6 +234,36 @@ def _percentile(sorted_vals: List[float], q: float) -> Optional[float]:
     return p(sorted_vals, q)
 
 
+class _SetupPhase:
+    """An open phase of an engine's set-up (``ServeEngine._setup``)."""
+
+    __slots__ = ("_eng", "_label", "_t0", "_span")
+
+    def __init__(self, eng: "ServeEngine", phase: str, args: dict):
+        self._eng = eng
+        self._label = ":".join([phase] + [str(v) for v in args.values()])
+        self._t0 = time.perf_counter()
+        self._span = tracing.span(eng._tracer, "serve/setup_" + phase,
+                                  cat="serve", **args)
+
+    def end(self) -> None:
+        self._span.end()
+        eng, dt = self._eng, time.perf_counter() - self._t0
+        eng.setup_log.append((self._label, self._t0, dt))
+        if eng.telemetry is not None:
+            eng._setup_gauge.set(dt, phase=self._label)
+
+    def __enter__(self) -> "_SetupPhase":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.end()
+
+
+#: what ``ServeEngine._first_call`` hands out after a program's first call
+_NO_PHASE = contextlib.nullcontext()
+
+
 class ServeEngine:
     """Continuous-batching greedy decode over a decoder-only model.
 
@@ -296,13 +328,81 @@ class ServeEngine:
     ``moe_experts_hit``, ``moe_rows``, ``moe_load_imbalance``) takes
     ``aux=True`` in its paged steps and returns one more output, a dict
     of those scalars for the call; the engine keeps them per call in
-    ``aux_log``.
+    ``aux_log``, the one log of every program the host sent and waited
+    for (``_file``).
     """
 
     def __init__(self, model, config=None, mesh=None, params=None,
                  seed: int = 0, draft_params=None):
-        self.model = model
         cfg = _ServeConfigView(config)
+        # -- telemetry first: construction is spans and gauges too --------
+        self.telemetry = None
+        #: the engine's own set-up by phase, (phase, started, seconds)
+        #: each, from any thread (gauge ``serve_setup_seconds{phase=}``):
+        #: ``params``, ``cache``, ``draft``, ``feed``, then for each rung
+        #: of the prefill ladder ``lower:<rung>`` and ``compile:<rung>``
+        #: (its executable's load or compile) on the ladder's thread,
+        #: ``rungs_wait`` (the first prefill call waiting for that
+        #: thread) and ``first_call:<program>[:<rung>]`` (a program's
+        #: first call until it returns: trace, compile or load, enqueue)
+        self.setup_log: List[tuple] = []
+        self._called: set = set()
+        mirror = {}
+        if cfg.telemetry.enabled:
+            import os
+            from ..telemetry.hub import TelemetryHub
+            out = cfg.telemetry.output_path or os.path.join(
+                os.getcwd(), "telemetry")
+            self.telemetry = TelemetryHub(
+                out, trace=cfg.telemetry.trace,
+                compile_events=cfg.telemetry.compile_events,
+                memory=cfg.telemetry.memory,
+                storm_threshold=cfg.telemetry.recompile_storm_threshold)
+            reg = self.telemetry.registry
+            self._setup_gauge = reg.gauge(
+                "serve_setup_seconds",
+                "the engine's own set-up by phase: params, cache, feed, "
+                "lower:<rung> and compile:<rung> of the prefill ladder, "
+                "rungs_wait, first_call:<program>[:<rung>]")
+            device_ctr = reg.counter(
+                "serve_device_seconds_total",
+                "the device's time by program and by rung (prefill) or "
+                "arm (decode), from the host's waits: ready - max(sent, "
+                "the ready before); waits that returned at once left out")
+            dry_ctr = reg.counter(
+                "serve_queue_dry_seconds_total",
+                "seconds the device had nothing queued (a lower bound "
+                "on its idleness), by the engine span open at the middle "
+                "of the interval, outside_step for the caller's time")
+            # the counters alone are captured, not the engine
+            mirror = {
+                "on_device": lambda prog, bucket, s: device_ctr.inc(
+                    s, program=prog, bucket=bucket),
+                "on_dry": lambda phase, s: dry_ctr.inc(s, phase=phase)}
+        #: every program the host sends and waits for, in device order
+        #: (telemetry/device_queue.py): ``device_seconds`` and
+        #: ``queue_dry_seconds`` below are its sums, ``aux_log`` its
+        #: records
+        # the clock is looked up at each read, so that a test can hand this
+        # module a clock of its own
+        self.book = DeviceQueueBook(clock=lambda: time.perf_counter(),
+                                    **mirror)
+        #: (program, rung or arm) -> seconds of the device
+        #: (``serve_device_seconds_total{program=,bucket=}``)
+        self.device_seconds = self.book.device_seconds
+        #: phase -> seconds the device's queue was dry
+        #: (``serve_queue_dry_seconds_total{phase=}``)
+        self.queue_dry_seconds = self.book.dry_seconds
+        try:
+            self._construct(model, cfg, mesh, params, seed, draft_params)
+        except BaseException:
+            # a refused configuration leaves no hub open behind it
+            if self.telemetry is not None:
+                self.telemetry.close()
+            raise
+
+    def _construct(self, model, cfg, mesh, params, seed, draft_params):
+        self.model = model
         self.serving_config = cfg.serving
         mcfg = model.config
         if mesh is None:
@@ -351,6 +451,7 @@ class ServeEngine:
         self._spec_passes = 0
 
         # -- params + cache, sharded over the mesh -----------------------
+        phase = self._setup("params")
         if params is None:
             params = model.init(jax.random.PRNGKey(seed))
         pspecs = model.param_partition_specs(params)
@@ -371,13 +472,19 @@ class ServeEngine:
             is_leaf=lambda s: isinstance(s, P))
         self.params = jax.tree.map(jax.device_put, params,
                                    self._param_shardings)
+        phase.end()
         wte = params["wte"] if isinstance(params, dict) else None
         kv_dtype = wte.dtype if wte is not None else jnp.float32
         self.page_len = cfg.serving.page_len
         self.paged = self.page_len > 0
-        #: per-call counters of a ``serving_aux`` model (class docstring):
-        #: (host time, 'prefill' | 'decode', {name: value}) per program
-        #: call, newest last; bounded
+        #: the one log of the programs the host sent and waited for, of
+        #: every model: (host time at retirement, 'prefill' | 'decode' |
+        #: 'propose' | 'verify', vals) per call, newest last; bounded.
+        #: ``vals`` is the call's record in the device's queue book
+        #: (``program``, ``bucket``, ``sent_t``, ``ready_t``, ``at_once``,
+        #: ``ahead``, ``run_s``, ``dry_s``, ``dry_phase``:
+        #: telemetry/device_queue.py) and, for a ``serving_aux`` model
+        #: (class docstring), the call's counters by name beside them
         self._aux_keys = tuple(getattr(model, "serving_aux", ()))
         self._aux = self.paged and bool(self._aux_keys)
         self.aux_log: deque = deque(maxlen=65536)
@@ -417,6 +524,7 @@ class ServeEngine:
         #: shape chooses ('direct': the fetched page is the matmul
         #: operand; 'packed'); None where another arm decodes
         self.paged_decode_arm = None
+        phase = self._setup("cache")
         if self.paged:
             self.max_pages = -(-self.max_seq_len // self.page_len)
             pages = cfg.serving.pages
@@ -484,6 +592,7 @@ class ServeEngine:
             self._cache_shardings = cache_shardings(mesh)
             self.cache = shard_cache(init_cache(self.cache_spec), mesh,
                                      self._cache_shardings)
+        phase.end()
 
         # -- multi-tenant LoRA adapter plane (serving.lora, docs/
         # serving.md "multi-tenant serving"; S-LoRA / Punica,
@@ -800,8 +909,9 @@ class ServeEngine:
         self._rep = rep
         self._feed_fn = jax.jit(serve_feed_tokens, out_shardings=rep)
         if self.spec_k:
-            self._build_spec_plane(cfg, mcfg, kv_dtype, draft_params,
-                                   seed, rep)
+            with self._setup("draft"):
+                self._build_spec_plane(cfg, mcfg, kv_dtype, draft_params,
+                                       seed, rep)
 
         # -- fault plane: queue as a Channel, work under one Stage -------
         self.queue = Channel(capacity=cfg.serving.queue_capacity)
@@ -871,7 +981,7 @@ class ServeEngine:
             zeros = np.zeros((self.slots,), np.int32)
             self._first_dev = self._no_first = jax.device_put(
                 np.int32(0), rep)
-            with self._pallas_scope():
+            with self._setup("feed"), self._pallas_scope():
                 self._feed_fn(jax.device_put(zeros, rep), self._no_first,
                               zeros)
 
@@ -894,20 +1004,13 @@ class ServeEngine:
             self.param_bytes += param_nbytes(self.draft_params)
             self.kv_bytes += self.draft_cache_spec.bytes
 
-        # -- telemetry ---------------------------------------------------
-        self.telemetry = None
-        if cfg.telemetry.enabled:
-            import os
-            from ..telemetry.hub import TelemetryHub
-            out = cfg.telemetry.output_path or os.path.join(
-                os.getcwd(), "telemetry")
-            self.telemetry = TelemetryHub(
-                out, trace=cfg.telemetry.trace,
-                compile_events=cfg.telemetry.compile_events,
-                memory=cfg.telemetry.memory,
-                storm_threshold=cfg.telemetry.recompile_storm_threshold)
+        # -- telemetry: the hub was made first, the metrics of what has
+        # been built since are made here ----------------------------------
+        if self.telemetry is not None:
             for fn in self.programs():
                 self.telemetry.track_program(fn.__name__, fn)
+            book = self.book
+            self.telemetry.watch_stalls(lambda: book.phase)
             reg = self.telemetry.registry
             self._tokens_total = reg.counter(
                 "serve_tokens_total", "generated tokens")
@@ -1328,8 +1431,76 @@ class ServeEngine:
 
     def _span(self, name: str, **args):
         """A serving span: a profiler annotation always, a trace.json
-        event with telemetry on (docs/observability.md)."""
-        return tracing.span(self._tracer, name, cat="serve", **args)
+        event with telemetry on (docs/observability.md), and a phase of
+        the host's time in the device's queue book (the name less its
+        ``serve/``).  The serving loop's thread only."""
+        return Phase(self.book, name[6:],
+                     tracing.span(self._tracer, name, cat="serve", **args))
+
+    def _setup(self, phase: str, **args) -> "_SetupPhase":
+        """A phase of the engine's own set-up, from any thread: a
+        ``serve/setup_<phase>`` span (its args: ``rung=``, ``program=``)
+        whose seconds go to ``setup_log`` and the
+        ``serve_setup_seconds{phase=}`` gauge.  ``with``, or ``.end()``."""
+        return _SetupPhase(self, phase, args)
+
+    def _first_call(self, program: str, bucket: str = ""):
+        """Around a program's call: its first (trace, compile or the
+        cached executable's load, enqueue) is set-up and stamped as such;
+        every later one passes through."""
+        key = (program, bucket)
+        if key in self._called:
+            return _NO_PHASE
+        self._called.add(key)
+        args = {"bucket": bucket} if bucket else {}
+        return self._setup("first_call", program=program, **args)
+
+    def _ready_now(self, arr) -> bool:
+        """Whether a wait for ``arr`` would return at once."""
+        return arr.is_ready()
+
+    @contextlib.contextmanager
+    def _wait(self, rec: dict, out):
+        """Around the host's wait (``jax.block_until_ready``) for ``out``,
+        the output of the program ``rec`` records: the stamp that the wait
+        returned.  The wait itself stays in the caller's frame, which is
+        what the benchmark's ``breakdown.idle_gaps`` names a gap by.
+        Whatever was sent before ``rec`` and not waited for yet is waited
+        for first (``_await_ahead``); a record already stamped keeps its
+        stamp."""
+        if "ready_t" in rec:
+            yield
+            return
+        self._await_ahead(rec)
+        at_once = self._ready_now(out)
+        try:
+            yield
+        finally:
+            self.book.ready(rec, at_once)
+
+    def _await_ahead(self, rec: dict) -> None:
+        """Wait (``block_until_ready`` only: no pull, no retirement) for
+        every program sent before ``rec``'s whose end the host has not
+        seen, oldest first: a decode tick in flight, retired where it
+        always was.  The device runs them in that order, so the wait for
+        ``rec`` implied these."""
+        for head, out in self.book.ahead_of(rec):
+            with self._wait(head, out):
+                jax.block_until_ready(out)
+
+    def _file(self, kind: str, rec: dict, aux=()) -> None:
+        """File a retired program's record in ``aux_log`` (one entry a
+        call, stamped here, at its retirement), with the counters of a
+        ``serving_aux`` model's call, its third output, beside the
+        queue's; with telemetry on, the expert layer's two go to their
+        gauges."""
+        if aux:
+            rec.update(zip(self._aux_keys, np.asarray(aux[0]).tolist()))
+        self.aux_log.append((time.perf_counter(), kind, rec))
+        if self.telemetry is not None and kind == "decode" \
+                and "moe_experts_hit" in rec:
+            self._moe_hit_gauge.set(rec["moe_experts_hit"])
+            self._moe_imbalance_gauge.set(rec["moe_load_imbalance"])
 
     @property
     def _tracer(self):
@@ -1403,7 +1574,11 @@ class ServeEngine:
                              if req.admit_t else None),
             "ttft_s": (float(req.token_times[0])
                        if req.token_times else None),
+            # the prefill program's own interval, and the rest of
+            # admission -> first token (Request.prefill_wait_s)
             "prefill_s": req.prefill_s if req.prefill_s else None,
+            "prefill_wait_s": (req.prefill_wait_s if req.prefill_s
+                               else None),
             "decode_tokens": len(decode),
             "decode_s_sum": sum(decode),
             # bounded: a million-token request must not write a
@@ -1656,8 +1831,9 @@ class ServeEngine:
         stage's ``DS_STAGE_DELAY_S`` unit is ONE PAGE of prefill
         compute.  ``stage.check`` already charged one unit at the admit
         boundary; charge the remaining ``ceil(computed/page_len) - 1``
-        here, inside the prefill span — so a prefix-hit delta pays for
-        its delta pages only (tests/test_paged_kv.py,
+        here, inside the prefill's own interval (``_await_first``) — so
+        a prefix-hit delta pays for its delta pages only
+        (tests/test_paged_kv.py,
         ``test_prefix_hit_prefill_pays_delta_chunks_only``)."""
         if self.stage.degraded:
             return
@@ -1728,20 +1904,55 @@ class ServeEngine:
             rest.append(jax.ShapeDtypeStruct(self._rng_base.shape,
                                              self._rng_base.dtype))
         held = jax.tree.map(like, (self.params, self.cache))
+        rungs = {}
         with self._pallas_scope():
-            return {r: self._prefill_fn.lower(
-                *held, jax.ShapeDtypeStruct((1, r), np.int32),
-                *rest).compile() for r in self.prefill_buckets}
+            for r in self.prefill_buckets:
+                with self._setup("lower", rung=r):
+                    lowered = self._prefill_fn.lower(
+                        *held, jax.ShapeDtypeStruct((1, r), np.int32),
+                        *rest)
+                with self._setup("compile", rung=r):
+                    rungs[r] = lowered.compile()
+        return rungs
 
     def _run_prefill(self, *operands):
         """``serve_prefill`` at the length of its tokens (operand 2): the
         jitted program for a ladder of one rung, as ever; else that rung's
         executable, once all of them are built, so that no later length
-        compiles anything."""
-        if len(self.prefill_buckets) == 1:
-            return self._prefill_fn(*operands)
-        rungs = self._prefill_build.result()
-        return rungs[operands[2].shape[1]](*operands)
+        compiles anything.  Takes the cache it is handed for the one it
+        returns; gives (first token on the device, the call's counters,
+        its record in the device's queue book)."""
+        rung = operands[2].shape[1]
+        fn = self._prefill_fn
+        if len(self.prefill_buckets) > 1:
+            if not self._prefill_build.done():
+                with self._setup("rungs_wait"):
+                    self._prefill_build.result()
+            fn = self._prefill_build.result()[rung]
+        with self._first_call("serve_prefill", str(rung)):
+            self.cache, first, *aux = fn(*operands)
+        return first, aux, self.book.sent("serve_prefill", str(rung), first)
+
+    def _await_first(self, req: Request, rec: dict, first,
+                     delay_tokens: int = 0) -> int:
+        """A prefill's first token, the wait for it cut in two:
+        ``serve/prefill_wait`` for what was on the device's queue ahead of
+        the prefill (the decode tick in flight, on a full engine) and
+        ``serve/prefill_run`` for the prefill itself, whose interval
+        (``run_s`` of its record: from the later of its send and the end
+        of that first wait) is added to ``req.prefill_s``.  The injected
+        device time of ``delay_tokens`` (``_charge_prefill_delay``) is the
+        prefill's own and falls in the second."""
+        with self._span("serve/prefill_wait"):
+            self._await_ahead(rec)
+        with self._span("serve/prefill_run"):
+            if delay_tokens:
+                self._charge_prefill_delay(delay_tokens)
+            with self._wait(rec, first):
+                jax.block_until_ready(first)
+            first = int(np.asarray(first))
+        req.prefill_s += rec["run_s"]
+        return first
 
     def _admit_one_paged(self, req: Request) -> bool:
         total_pages = -(-len(req.prompt) // self.page_len)
@@ -1854,15 +2065,13 @@ class ServeEngine:
             row_np = np.zeros((self.max_pages,), np.int32)
             row_np[:len(row)] = row
             with self._span("serve/prefill", rid=req.rid,
-                            prompt_len=len(req.prompt),
-                            computed=len(delta), shared=shared_len):
+                            prompt_len=len(req.prompt)):
                 tr = self._tracer
                 if tr is not None and req.ctx is not None:
                     tr.flow_start("serve/request", req.ctx, cat="serve",
                                   rid=req.rid)
-                self._charge_prefill_delay(len(delta))
                 with self._pallas_scope():
-                    self.cache, first, *aux = self._run_prefill(
+                    first, aux, rec = self._run_prefill(
                         self.params, self.cache, tokens,
                         np.int32(len(delta)), np.int32(shared_len),
                         row_np, np.int32(self.scheduler.free[0]),
@@ -1872,10 +2081,10 @@ class ServeEngine:
                 # where this admission fills the slots the next tick
                 # goes behind the prefill now, before the host waits
                 seated = self._send_behind_prefill(req, row, aslot, first)
-                # behind the tick in flight, if one is: the wait is for
-                # both, with the device busy throughout
-                first = int(np.asarray(jax.block_until_ready(first)))
-                self._note_aux("prefill", aux)
+                # behind the tick in flight, if one is: first the wait
+                # for that tick, then the prefill's own
+                first = self._await_first(req, rec, first, len(delta))
+                self._file("prefill", rec, aux)
             if self.spec_k:
                 # the draft mirrors the FULL prompt (it has no prefix
                 # cache — draft prefill is cheap by construction)
@@ -1895,7 +2104,7 @@ class ServeEngine:
                 self.adapters.release(req.adapter_id)
             raise
         now = time.perf_counter()
-        req.prefill_s = now - req.admit_t
+        req.prefill_wait_s = now - req.admit_t - req.prefill_s
         if seated is None:
             slot = self._seat(req, row, aslot, now)
         else:
@@ -1992,15 +2201,16 @@ class ServeEngine:
                 tr.flow_start("serve/request", req.ctx, cat="serve",
                               rid=req.rid)
             with self._pallas_scope():
-                self.cache, first = self._run_prefill(
+                first, aux, rec = self._run_prefill(
                     self.params, self.cache, tokens, length,
                     np.int32(self.scheduler.free[0]),
                     *self._maybe_key())
-            first = int(np.asarray(jax.block_until_ready(first)))
+            first = self._await_first(req, rec, first)
+            self._file("prefill", rec, aux)
         if self.spec_k:
             self._draft_prefill(req)
         now = time.perf_counter()
-        req.prefill_s = now - req.admit_t
+        req.prefill_wait_s = now - req.admit_t - req.prefill_s
         slot = self.scheduler.admit(req, now=now)
         req.kv_len = len(req.prompt)
         req.tokens.append(first)
@@ -2139,7 +2349,7 @@ class ServeEngine:
                 tr.flow_start("serve/request", req.ctx, cat="serve",
                               rid=req.rid)
             with self._pallas_scope():
-                self.cache, first, *aux = self._run_prefill(
+                first, aux, rec = self._run_prefill(
                     self.params, self.cache, tokens,
                     np.int32(len(chunk)),
                     np.int32(req.shared_len + pos),
@@ -2147,15 +2357,15 @@ class ServeEngine:
                     *((self._lora_pools, np.int32(req.adapter_slot))
                       if self.lora else ()),
                     *self._maybe_key())
-            first = int(np.asarray(jax.block_until_ready(first)))
-            self._note_aux("prefill", aux)
+            first = self._await_first(req, rec, first)
+            self._file("prefill", rec, aux)
         req.chunk_pos = pos + len(chunk)
         req.kv_len = req.shared_len + req.chunk_pos
         if not final:
             return 0
         now = time.perf_counter()
         req.prefilling = False
-        req.prefill_s = now - req.admit_t
+        req.prefill_wait_s = now - req.admit_t - req.prefill_s
         req.kv_len = len(req.prompt)
         if self.prefix is not None:
             # the pages are fully written now — register them for
@@ -2283,45 +2493,36 @@ class ServeEngine:
                 else:
                     tokens = jax.device_put(tokens, self._rep)
                 aux = ()
-                if self.paged:
-                    # the tables are copied: the host writes them again
-                    # (a finish, an admission) while this call may not
-                    # have read them yet
-                    self.cache, next_tok, *aux = self._decode_fn(
-                        self.params, self.cache, tokens, active,
-                        self._table.copy(),
-                        *((self._lora_pools, self._adapter_table.copy())
-                          if self.lora else ()),
-                        *self._maybe_key())
-                    for a in aux:
-                        # on its way while the host waits for the tokens
-                        a.copy_to_host_async()
-                else:
-                    self.cache, next_tok = self._decode_fn(
-                        self.params, self.cache, tokens, active,
-                        *self._maybe_key())
+                with self._first_call("serve_decode"):
+                    if self.paged:
+                        # the tables are copied: the host writes them
+                        # again (a finish, an admission) while this call
+                        # may not have read them yet
+                        self.cache, next_tok, *aux = self._decode_fn(
+                            self.params, self.cache, tokens, active,
+                            self._table.copy(),
+                            *((self._lora_pools,
+                               self._adapter_table.copy())
+                              if self.lora else ()),
+                            *self._maybe_key())
+                    else:
+                        self.cache, next_tok = self._decode_fn(
+                            self.params, self.cache, tokens, active,
+                            *self._maybe_key())
+                for a in aux:
+                    # on its way while the host waits for the tokens
+                    a.copy_to_host_async()
             return next_tok, tuple(aux)
 
-    def _note_aux(self, kind: str, aux) -> None:
-        """Log the counters of a paged call just synced, the third
-        output of a ``serving_aux`` model's programs (``aux_log``: one
-        entry a call, stamped here, at its retirement); with telemetry
-        on, the expert layer's two go to their gauges."""
-        if not aux:
-            return
-        vals = dict(zip(self._aux_keys, np.asarray(aux[0]).tolist()))
-        self.aux_log.append((time.perf_counter(), kind, vals))
-        if self.telemetry is not None and kind == "decode" \
-                and "moe_experts_hit" in vals:
-            self._moe_hit_gauge.set(vals["moe_experts_hit"])
-            self._moe_imbalance_gauge.set(vals["moe_load_imbalance"])
-
-    def _pull_tokens(self, *arrays):
-        """``serve/token_pull``: the host waiting for the device.  The
-        per-token latency point: the pull IS the device sync
-        (transfer-real, JL006-clean)."""
+    def _pull_tokens(self, rec: dict, *arrays):
+        """``serve/token_pull``: the host waiting for the device, for
+        the outputs of the program ``rec`` records.  The per-token
+        latency point: the pull IS the device sync (transfer-real,
+        JL006-clean)."""
         with self._span("serve/token_pull"):
-            return [np.asarray(jax.block_until_ready(a)) for a in arrays]
+            with self._wait(rec, arrays[0]):
+                ready = [jax.block_until_ready(a) for a in arrays]
+            return [np.asarray(a) for a in ready]
 
     def _emit_tokens(self, active_map, next_host) -> int:
         """``serve/emit``: per-request bookkeeping of one decoded token
@@ -2383,7 +2584,9 @@ class ServeEngine:
         if self.telemetry is not None:
             self._ticks_ctr.inc(arm=arm)
         self._flow_step_tick(active_map)
-        return _Tick(active_map, *self._decode_dispatch(tokens, active))
+        next_tok, aux = self._decode_dispatch(tokens, active)
+        return _Tick(active_map, next_tok, aux,
+                     self.book.sent("serve_decode", arm, next_tok))
 
     def _settle(self) -> int:
         """Retire the tick in flight, if one is: whatever reads the
@@ -2392,8 +2595,8 @@ class ServeEngine:
         tick, self._inflight = self._inflight, None
         if tick is None:
             return 0
-        (next_host,) = self._pull_tokens(tick.next_tok)
-        self._note_aux("decode", tick.aux)
+        (next_host,) = self._pull_tokens(tick.rec, tick.next_tok)
+        self._file("decode", tick.rec, tick.aux)
         return self._emit_tokens(tick.active_map, next_host)
 
     def _decode_tick(self) -> int:
@@ -2415,8 +2618,8 @@ class ServeEngine:
                 self._send_tick(*self._decode_prepare())
                 if self._run_ahead() else None)
             # the pull stays inside the decode_step span
-            (next_host,) = self._pull_tokens(tick.next_tok)
-            self._note_aux("decode", tick.aux)
+            (next_host,) = self._pull_tokens(tick.rec, tick.next_tok)
+            self._file("decode", tick.rec, tick.aux)
         return self._emit_tokens(tick.active_map, next_host)
 
     def _draft_propose(self, active_map, tokens, active):
@@ -2425,7 +2628,8 @@ class ServeEngine:
         sampling tail)."""
         with self._span("serve/draft_propose", active=len(active_map),
                         k=self.spec_k):
-            with self._pallas_scope():
+            with self._pallas_scope(), \
+                    self._first_call("serve_draft_propose"):
                 out = self._propose_fn(self.draft_params,
                                        self._draft_cache, tokens,
                                        active, *self._maybe_key())
@@ -2435,16 +2639,19 @@ class ServeEngine:
             else:
                 self._draft_cache, proposals = out
                 extra = ()
+            rec = self.book.sent("serve_draft_propose", "", proposals)
             # drain the draft INSIDE its span so the window times real
             # draft compute (the verify pull syncs the rest)
-            jax.block_until_ready(proposals)
+            with self._wait(rec, proposals):
+                jax.block_until_ready(proposals)
+            self._file("propose", rec)
             return proposals, extra
 
     def _verify_dispatch(self, tokens, proposals, active, extra):
         """``serve/verify_dispatch``: the widened verify program's
         call, until it returns."""
         with self._span("serve/verify_dispatch"):
-            with self._pallas_scope():
+            with self._pallas_scope(), self._first_call("serve_verify"):
                 if self.paged:
                     self.cache, out_tok, accepted = self._verify_fn(
                         self.params, self.cache, tokens, proposals,
@@ -2553,8 +2760,10 @@ class ServeEngine:
             self._flow_step_tick(active_map)
             out_tok, accepted = self._verify_dispatch(tokens, proposals,
                                                       active, extra)
+            rec = self.book.sent("serve_verify", "", out_tok)
             # the per-block latency point, inside the span
-            out_host, acc_host = self._pull_tokens(out_tok, accepted)
+            out_host, acc_host = self._pull_tokens(rec, out_tok, accepted)
+            self._file("verify", rec)
         return self._emit_spec_blocks(active_map, out_host, acc_host)
 
     def step(self) -> int:
@@ -2850,6 +3059,7 @@ class ServeEngine:
         self.stage.record_event("poison", error=repr(err))
         # the tick in flight goes with the cache: its requests fail below
         self._inflight = self._behind = None
+        self.book.pending.clear()
         for slot in list(self.scheduler.active):
             req = self.scheduler.release(slot, "error")
             if self.paged:

@@ -68,11 +68,16 @@ class Request:
     ctx: Optional[object] = None
     span: Optional[object] = None
     queue_span: Optional[object] = None
-    #: latency attribution stamps: admission time (queue_wait ends) and
-    #: the prefill's wall time — queue/prefill/decode components of the
-    #: per-request completion record
+    #: latency attribution stamps: admission time (queue_wait ends), the
+    #: prefill program's own interval on the device (from the later of
+    #: its send and the end of the host's wait for what was queued ahead
+    #: of it, to its first token ready; summed over the chunks of a
+    #: chunked prefill) and the rest of admission -> first token (host
+    #: work, and the wait behind a decode tick in flight):
+    #: ``queue wait + prefill_wait_s + prefill_s`` is the TTFT
     admit_t: float = 0.0
     prefill_s: float = 0.0
+    prefill_wait_s: float = 0.0
     #: paged mode (engine-internal): the slot's live page ids in table
     #: order, the prompt prefix length served from shared pages, and
     #: how many prompt tokens the prefill actually computed (the delta)

@@ -19,6 +19,7 @@ import json
 import math
 import os
 import re
+import threading
 import time
 from typing import Dict, Optional
 
@@ -44,6 +45,8 @@ class JsonlExporter:
         # in summarize.  Point output_path at a per-run directory to
         # keep history.
         self._fh = open(path, "w")
+        # the engine's thread and the hub's stall watcher both write
+        self._lock = threading.Lock()
         self._closed = False
         self._degraded = False
 
@@ -60,8 +63,10 @@ class JsonlExporter:
             return
         rec = {"kind": kind, "ts": time.time() if ts is None else ts}
         rec.update(data)
+        line = json.dumps(rec) + "\n"
         try:
-            self._fh.write(json.dumps(rec) + "\n")
+            with self._lock:
+                self._fh.write(line)
         except (OSError, ValueError) as e:  # ValueError: closed file obj
             self._disable(e)
 

@@ -15,13 +15,20 @@ sampler + exporters together and exposes exactly two cadences:
                      engine was already paying for.
 
 ``close()`` is idempotent and exports the Chrome trace.
+
+:class:`StallWatch` (``TelemetryHub.watch_stalls``) is the one thread
+telemetry starts: it only sleeps, and says when and why the whole
+process stood still.
 """
 from __future__ import annotations
 
+import gc
 import json
 import os
+import resource
+import threading
 import time
-from typing import Optional
+from typing import Callable, List, NamedTuple, Optional
 
 from .compile_monitor import CompileMonitor
 from .exporters import JsonlExporter, SummaryWriterBridge, write_prometheus
@@ -66,6 +73,100 @@ def write_flight_record(directory: str, stages, step: int, reason: str,
     return path
 
 
+class Beat(NamedTuple):
+    """What :class:`StallWatch` reads each time it wakes."""
+    t: float                #: time.perf_counter
+    cpu: float              #: time.process_time
+    collections: List[int]  #: gc collections so far, by generation
+    switches: int           #: involuntary context switches so far
+    faults: int             #: major page faults so far
+    compiles: float         #: jax_compiles_total
+
+
+class StallWatch:
+    """A freeze of the process, seen and sorted by cause.
+
+    A thread that sleeps ``BEAT_S`` at a time; when it wakes more than
+    ``STALL_S`` after its last beat, every thread of the process stood
+    still that long (a thread that waits for the device releases the
+    interpreter lock, and this one keeps its beat).  Each such silence is
+    one ``process_stall`` event in ``events.jsonl``, one ``process_stall``
+    span in ``trace.json`` and one count of
+    ``process_stalls_total{cause=}``.  The event carries the wall
+    seconds, the CPU seconds the process burnt in them
+    (``time.process_time``), the collections of Python's collector by
+    generation, the involuntary context switches and major page faults
+    (``resource.getrusage``), the XLA compiles that ended in the interval
+    and the engine span open when the beat came back (``phase_fn``).
+
+    ``cause``, the first that holds: ``compile`` (a compile ended in the
+    interval), ``gc`` (the oldest generation was collected), ``paging``
+    (a major fault), ``busy`` (the process burnt CPU for at least half
+    the silence: native code holding the interpreter lock) or
+    ``descheduled`` (it did not: the host ran something else)."""
+    BEAT_S = 0.02
+    STALL_S = 0.1
+
+    def __init__(self, hub: "TelemetryHub",
+                 phase_fn: Optional[Callable[[], str]] = None):
+        self._hub = hub
+        self._phase_fn = phase_fn
+        self._counter = hub.registry.counter(
+            "process_stalls_total",
+            "silences of the 20 ms watcher thread over 100 ms (the whole "
+            "process stood still), by cause: compile, gc, paging, busy "
+            "(CPU burnt under the interpreter lock) or descheduled")
+        self._stop = threading.Event()
+        # here, not at import: the runtime's engines import this module
+        from ..runtime.stages import spawn
+        self._thread = spawn(self._run, "telemetry_stall_watch")
+
+    def _sample(self) -> Beat:
+        usage = resource.getrusage(resource.RUSAGE_SELF)
+        compiles = (self._hub.compile_monitor.compiles.value()
+                    if self._hub.compile_monitor is not None else 0.0)
+        return Beat(time.perf_counter(), time.process_time(),
+                    [g["collections"] for g in gc.get_stats()],
+                    usage.ru_nivcsw, usage.ru_majflt, compiles)
+
+    def _run(self) -> None:
+        last = self._sample()
+        while not self._stop.wait(self.BEAT_S):
+            now = self._sample()
+            if now.t - last.t > self.STALL_S:
+                self._record(last, now)
+            last = now
+
+    def _record(self, last: Beat, now: Beat) -> None:
+        wall, cpu = now.t - last.t, now.cpu - last.cpu
+        collections = [b - a for a, b in
+                       zip(last.collections, now.collections)]
+        data = {
+            "wall_s": wall, "cpu_s": cpu, "gc_collections": collections,
+            "involuntary_switches": now.switches - last.switches,
+            "major_faults": now.faults - last.faults,
+            "compiles": int(now.compiles - last.compiles),
+            "phase": self._phase_fn() if self._phase_fn else None}
+        if data["compiles"]:
+            cause = "compile"
+        elif collections[-1]:
+            cause = "gc"
+        elif data["major_faults"]:
+            cause = "paging"
+        else:
+            cause = "busy" if cpu >= wall / 2 else "descheduled"
+        data["cause"] = cause
+        self._counter.inc(cause=cause)
+        self._hub.jsonl.write_event("process_stall", data)
+        if self._hub.tracer is not None:
+            self._hub.tracer.complete("process_stall", last.t, wall,
+                                      cat="runtime", **data)
+
+    def stop(self) -> None:
+        self._stop.set()
+        self._thread.join(timeout=5.0)
+
+
 class TelemetryHub:
     def __init__(self, output_path: str, *,
                  trace: bool = True,
@@ -100,7 +201,14 @@ class TelemetryHub:
             "synced per-step wall time (interval average at each "
             "steps_per_print materialization)")
         self._interval_span = None
+        self._stall_watch: Optional[StallWatch] = None
         self._closed = False
+
+    def watch_stalls(self, phase_fn: Optional[Callable[[], str]] = None):
+        """Start the watcher thread (:class:`StallWatch`), once;
+        ``close()`` stops it."""
+        if self._stall_watch is None and not self._closed:
+            self._stall_watch = StallWatch(self, phase_fn)
 
     # -- per-step (host-only, no syncs) ---------------------------------
     def record_step(self, step: int, dispatch_s: float,
@@ -158,12 +266,6 @@ class TelemetryHub:
             stats = self.memory_sampler.sample()
             self.jsonl.write_event("memory", {"step": int(step),
                                               "stats": stats})
-            if self.tracer is not None:
-                for dev in stats.get("devices", [])[:8]:
-                    if dev.get("bytes_in_use") is not None:
-                        self.tracer.counter(
-                            f"hbm/device{dev.get('id')}",
-                            {"bytes_in_use": dev["bytes_in_use"]})
         if self.compile_monitor is not None:
             self.compile_monitor.sample()
 
@@ -194,6 +296,8 @@ class TelemetryHub:
         if self._closed:
             return
         self._closed = True
+        if self._stall_watch is not None:
+            self._stall_watch.stop()
         if self._interval_span is not None:
             self._interval_span.end()
             self._interval_span = None

@@ -254,13 +254,13 @@ class TraceRecorder:
         instances of the same name run concurrently."""
         return AsyncSpan(self, name, cat, span_id, args or None)
 
-    def instant(self, name: str, cat: str = "runtime", **args):
-        ev = {"name": name, "cat": cat, "ph": "i", "s": "p",
-              "pid": self.pid, "tid": self._tid(),
-              "ts": round(self._now_us(), 3)}
-        if args:
-            ev["args"] = args
-        self._append(ev)
+    def complete(self, name: str, start_t: float, dur_s: float,
+                 cat: str = "runtime", **args):
+        """A span after the fact: one complete event from a
+        ``time.perf_counter`` stamp and a duration (the recorder's clock
+        is that counter), for an interval only known once it is over."""
+        self._emit_complete(name, cat, (start_t - self._origin) * 1e6,
+                            dur_s * 1e6, args or None)
 
     # -- flow events (causal arrows between spans) ----------------------
     @staticmethod
@@ -310,14 +310,6 @@ class TraceRecorder:
         for fid, (name, cat) in pending:
             self._emit_flow("f", name, cat, fid, {"flushed": True})
         return len(pending)
-
-    def counter(self, name: str, values: Dict[str, float],
-                cat: str = "runtime"):
-        """Chrome counter-track event ("ph": "C") — HBM over time renders
-        as a filled graph in the trace viewer."""
-        self._append({"name": name, "cat": cat, "ph": "C", "pid": self.pid,
-                      "tid": 0, "ts": round(self._now_us(), 3),
-                      "args": {k: float(v) for k, v in values.items()}})
 
     # -- introspection / export -----------------------------------------
     def events(self) -> List[dict]:

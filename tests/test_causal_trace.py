@@ -121,7 +121,7 @@ def test_flow_terminators_survive_buffer_cap(tmp_path):
     ctx = TraceContext.new()
     tr.flow_start("link", ctx)
     for i in range(10):
-        tr.instant(f"filler{i}")       # fill the buffer past the cap
+        tr.span(f"filler{i}").end()    # fill the buffer past the cap
     tr.flow_end("link", ctx)           # must not be dropped
     evs = tr.events()
     assert any(e["ph"] == "f" and e["id"] == ctx.trace_id for e in evs)

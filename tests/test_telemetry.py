@@ -12,6 +12,7 @@ Covers the acceptance contract:
 import json
 import os
 import re
+import time
 
 import numpy as np
 import pytest
@@ -77,8 +78,7 @@ def test_trace_recorder_span_and_export(tmp_path):
     with tr.span("outer", cat="test", step=3):
         with tr.span("inner"):
             pass
-    tr.instant("marker")
-    tr.counter("hbm", {"bytes": 123.0})
+    tr.complete("marker", time.perf_counter() - 0.5, 0.25, stalled=True)
     h = tr.begin("lazy")
     h.end(steps=5)
     h.end()  # idempotent
@@ -86,7 +86,7 @@ def test_trace_recorder_span_and_export(tmp_path):
     doc = json.loads(open(path).read())
     evs = doc["traceEvents"]
     names = {e["name"] for e in evs}
-    assert {"outer", "inner", "marker", "hbm", "lazy"} <= names
+    assert {"outer", "inner", "marker", "lazy"} <= names
     for e in evs:
         assert "ph" in e and "ts" in e and "name" in e
     lazy = next(e for e in evs if e["name"] == "lazy")
@@ -95,12 +95,17 @@ def test_trace_recorder_span_and_export(tmp_path):
     inner = next(e for e in evs if e["name"] == "inner")
     assert outer["ts"] <= inner["ts"]
     assert outer["dur"] >= inner["dur"]
+    # a span filed after the fact lies on the recorder's clock
+    marker = next(e for e in evs if e["name"] == "marker")
+    assert marker["ph"] == "X" and marker["args"] == {"stalled": True}
+    assert marker["dur"] == pytest.approx(0.25e6)
+    assert marker["ts"] + marker["dur"] < outer["ts"]
 
 
 def test_trace_recorder_bounds_events():
     tr = TraceRecorder(max_events=10)
     for i in range(25):
-        tr.instant(f"e{i}")
+        tr.span(f"e{i}").end()
     assert len(tr.events()) == 10
     assert tr.dropped == 15
 

@@ -221,9 +221,13 @@ def test_annotations_per_tick_do_not_grow_with_live_slots(session,
     assert few == ["serve/tick"] + [n for _, n in TICK_TREE]
     assert full == ["serve/tick"] + [n for _, n in AHEAD_TREE]
     assert len(full) <= 8
-    # one more for each admission (its prefill), and the step that fills
-    # the slots sends two ticks: its own, and the first one ahead
-    assert len(admitting) == len(full) + (SLOTS - 2) + 2
+    # three more for each admission (its prefill, cut into the wait for
+    # what was queued ahead of it and its own run), and the step that
+    # fills the slots sends two ticks: its own, and the first one ahead
+    assert len(admitting) == len(full) + 3 * (SLOTS - 2) + 2
+    assert admitting.count("serve/prefill") == SLOTS - 2 \
+        == admitting.count("serve/prefill_wait") \
+        == admitting.count("serve/prefill_run")
 
 
 def test_annotations_per_train_step(session, monkeypatch):

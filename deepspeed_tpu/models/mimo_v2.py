@@ -54,12 +54,27 @@ anyway; said once here, every DMA and matmul of the kernels is then
 lane-aligned.  PERF.md (section 7) has what it costs and the layouts
 that would not pad.
 
-Parameter tree: ``wte``, ``lm_head`` [d, V], ``norm_f``; the layers
-stacked by kind in the order they occur: ``full`` and ``window``
-(``ln1``, ``q_w``, ``k_w``, ``v_w``, ``o_w``; ``window`` also ``sink``
-[Hq]), ``dense`` (``ln2``, ``gate_w``, ``up_w``, ``down_w``), ``moe``
-(``ln2``, ``router_w`` [d, E], ``router_bias`` [E], ``gate_w`` / ``up_w``
-[held, d, f], ``down_w`` [held, f, d]); every matrix input-major.
+Parameter tree: ``wte``, ``lm_head`` [d, V], ``norm_f``; the layers by
+kind in the order they occur: ``full`` and ``window`` (``ln1``, ``q_w``,
+``k_w``, ``v_w``, ``o_w``; ``window`` also ``sink`` [Hq]), ``dense``
+(``ln2``, ``gate_w``, ``up_w``, ``down_w``), ``moe`` (``ln2``,
+``router_w`` [d, E], ``router_bias`` [E], ``gate_w`` / ``up_w`` [held, d,
+f], ``down_w`` [held, f, d]); every matrix input-major.
+
+ONE rule for the layout: what a layer reads by its own index is a leaf
+of its own.  ``params[kind][name]`` is a TUPLE of one array a layer
+(``params["window"]["q_w"][i]`` is a leaf, vectors and norm weights
+included: no size decides), because the layers are walked in Python and
+never scanned, so nothing needs a ``[layers, ...]`` axis, and a static
+slice of one is a copy: XLA wrote every ``q_w`` of a decode tick to HBM
+transposed, and passed every window layer's ``k_w`` and ``v_w`` through
+fast memory, before the matmul read it (0.83 GB a tick at MiMo-V2.5's
+widths, 2 of its 21.5 ms; PERF.md section 6, PR 39).  ``_at`` is the one
+place that picks a layer.  The experts alone stay stacked, ``moe``'s
+``gate_w`` / ``up_w`` / ``down_w`` as ``[layers, held, ...]`` arrays:
+they reach their kernels whole, every layer's held experts flat
+(``_stacked_experts``, a reshape of the leading axes), and the kernel
+finds a layer's by ``expert_offset``.
 """
 from __future__ import annotations
 
@@ -279,7 +294,10 @@ class MimoV2Model:
         give scores near 0) such a sink takes about half the softmax's
         weight, so leaving it out shows; around 0 it would take a
         hundredth and hide in bfloat16's rounding.  Drawn a layer at a
-        time in ``param_dtype``."""
+        time in ``param_dtype``, each layer from its own key of the
+        kind's: a kind's per-layer leaves, stacked, are what one draw over
+        the ``[layers]`` axis gives (the module docstring has the
+        layout)."""
         cfg = self.config
         d, dt = cfg.hidden_size, jnp.dtype(cfg.param_dtype)
         std = cfg.initializer_range
@@ -309,26 +327,39 @@ class MimoV2Model:
                     "up_w": norm(k[1], (d, cfg.intermediate_size)),
                     "down_w": norm(k[2], (cfg.intermediate_size, d))}
 
-        def moe(key):
+        def moe(key):                   # of the layer's four keys, the first
+            return {"router_w": norm(jax.random.split(key, 4)[0], (d, e)),
+                    "router_bias": jnp.zeros((e,), dt)}
+
+        def experts(key):               # the other three
             k = jax.random.split(key, 4)
-            return {"router_w": norm(k[0], (d, e)),
-                    "router_bias": jnp.zeros((e,), dt),
-                    "gate_w": norm(k[1], (held, d, f)),
+            return {"gate_w": norm(k[1], (held, d, f)),
                     "up_w": norm(k[2], (held, d, f)),
                     "down_w": norm(k[3], (held, f, d))}
 
         out = {"wte": norm(keys[0], (cfg.vocab_size, d)),
                "lm_head": norm(keys[1], (d, cfg.vocab_size)),
                "norm_f": jnp.ones((d,), dt)}
-        for name, layer, ln, key in (
-                ("full", attn(cfg.kv_heads("full")), "ln1", keys[2]),
-                ("window", attn(cfg.kv_heads("window")), "ln1", keys[3]),
-                ("dense", dense, "ln2", keys[4]),
-                ("moe", moe, "ln2", keys[5])):
+        for name, layer, ln, key, whole in (
+                ("full", attn(cfg.kv_heads("full")), "ln1", keys[2], None),
+                ("window", attn(cfg.kv_heads("window")), "ln1", keys[3],
+                 None),
+                ("dense", dense, "ln2", keys[4], None),
+                ("moe", moe, "ln2", keys[5], experts)):
             n = cfg.count(name)
             if n:
-                out[name] = jax.lax.map(layer, jax.random.split(key, n))
-                out[name][ln] = jnp.ones((n, d), dt)
+                of = jax.random.split(key, n)
+                # one XLA computation a layer, as the body of a scan over
+                # the keys is: the constants of a draw fold the same way
+                # called eagerly or inside a caller's jit (apart they
+                # round apart, an ulp in a quarter of the numbers)
+                draw = jax.jit(layer)
+                drawn = [{**draw(of[i]), ln: jnp.ones((d,), dt)}
+                         for i in range(n)]
+                out[name] = {leaf: tuple(one[leaf] for one in drawn)
+                             for leaf in drawn[0]}
+                if whole:
+                    out[name].update(jax.lax.map(whole, of))
         out.get("full", {}).pop("sink", None)
         return out
 
@@ -365,14 +396,15 @@ def _refuse(unbuilt: dict) -> None:
 
 # -- the layer's parts ----------------------------------------------------
 
-def _at(stacked, i: int):
-    """Layer ``i`` of a kind's stacked leaves, but for the experts, which
-    reach their kernels whole (``_stacked_experts``)."""
-    big = ("gate_w", "up_w", "down_w") if "router_w" in stacked else ()
-    return {k: v[i] for k, v in stacked.items() if k not in big}
+def _at(kind, i: int):
+    """Layer ``i``'s own leaves of a kind (module docstring: each a leaf
+    of the tree, nothing is sliced); not the experts, which reach their
+    kernels whole (``_stacked_experts``)."""
+    return {k: v[i] for k, v in kind.items() if isinstance(v, tuple)}
 
 
 def _stacked_experts(params):
+    """Every expert layer's held experts flat: ``[layers * held, ...]``."""
     moe = params["moe"]
     return {k: moe[k].reshape((-1,) + moe[k].shape[2:])
             for k in ("gate_w", "up_w", "down_w")}

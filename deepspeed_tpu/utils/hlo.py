@@ -1,9 +1,13 @@
-"""Read the collectives out of a compiled program's text.
+"""Read the collectives, and the weights a program moves before it uses
+them, out of a compiled program's text.
 
 ``compiled.as_text()`` is the program after the SPMD partitioner: what a
 placement rule (runtime/zero.py) really costs is the collectives found
-there, their result shapes, and how often the loop around them runs.
-Bytes and counts only — no time is read from a program's text.
+there, their result shapes, and how often the loop around them runs; what
+a parameter tree's layout costs (models/mimo_v2.py) is the fusions and
+copies that write a parameter again before a matmul reads it
+(``parameter_rewrites``).  Bytes and counts only — no time is read from a
+program's text.
 """
 from __future__ import annotations
 
@@ -20,7 +24,11 @@ _ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
 
 _HEADER = re.compile(r"^(ENTRY\s+)?%?([\w.\-]+)\s+\(.*\)\s+->\s+.*\{\s*$")
 _INSTR = re.compile(r"^\s*(?:ROOT\s+)?%?[\w.\-]+\s+=\s+(.*?)\s+([\w\-]+)\(")
-_ARRAY = re.compile(r"\b([a-z]+[0-9]*(?:e[0-9]m[0-9](?:fn)?)?)\[([0-9,]*)\]")
+_NAMED = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s+=\s+(.*?)\s+([\w\-]+)\(([^)]*)\)")
+_OPERAND = re.compile(r"[\w.\-]+")
+_ARRAY = re.compile(r"\b([a-z]+[0-9]*(?:e[0-9]m[0-9](?:fn)?)?)\[([0-9,]*)\]"
+                    r"(\{[^}]*\})?")
 _CALLEE = re.compile(
     r"\b(?:calls|to_apply|body|condition|true_computation|"
     r"false_computation)=%?([\w.\-]+)")
@@ -39,19 +47,23 @@ class Collective(NamedTuple):
     in_loop: bool      # inside some ``while`` body
 
 
+def _laid(type_text: str):
+    """((dtype, dims), layout text or '') of each array of a type."""
+    return [((dt, tuple(int(d) for d in dims.split(",") if d)), layout)
+            for dt, dims, layout in _ARRAY.findall(type_text)
+            if dt in _ITEMSIZE]
+
+
 def _arrays(type_text: str):
-    return [(dt, tuple(int(d) for d in dims.split(",") if d))
-            for dt, dims in _ARRAY.findall(type_text) if dt in _ITEMSIZE]
+    return [array for array, _ in _laid(type_text)]
 
 
 def _nbytes(arrays) -> int:
     return sum(_ITEMSIZE[dt] * math.prod(dims) for dt, dims in arrays)
 
 
-def collectives(hlo_text: str) -> List[Collective]:
-    """Every collective the program runs, with the product of the known
-    trip counts of the loops around it (a loop whose count the compiler
-    does not state counts once, and is still a loop)."""
+def _computations(hlo_text: str):
+    """(the lines of each computation by name, the entry's name)."""
     comps: Dict[str, list] = {}
     entry = current = None
     for line in hlo_text.splitlines():
@@ -67,6 +79,14 @@ def collectives(hlo_text: str) -> List[Collective]:
             comps[current].append(line)
     if entry is None:
         raise ValueError("no ENTRY computation in the program text")
+    return comps, entry
+
+
+def collectives(hlo_text: str) -> List[Collective]:
+    """Every collective the program runs, with the product of the known
+    trip counts of the loops around it (a loop whose count the compiler
+    does not state counts once, and is still a loop)."""
+    comps, entry = _computations(hlo_text)
 
     def trip_count(while_line: str) -> int:
         """The count the compiler states, else (the TPU's text states
@@ -140,3 +160,45 @@ def collective_report(hlo_text: str) -> str:
         lines.append(f"  in a loop x{c.times}: {c.op} {shapes} "
                      f"({c.bytes / 1e6:.2f} MB)")
     return "\n".join(lines)
+
+
+class Rewrite(NamedTuple):
+    instruction: str   # the fusion or copy, by its name in the entry
+    op: str            # 'fusion' | 'copy'
+    parameter: int     # the entry parameter it reads, by number
+    bytes: int         # of its results
+    hbm_bytes: int     # of those it leaves in HBM: the rest are in the
+    #                    compiler's fast memory (``S(1)`` in the layout)
+
+
+def parameter_rewrites(hlo_text: str, parameters: int,
+                       share: float = 0.125) -> List[Rewrite]:
+    """Every fusion or copy of the entry computation that reads one of
+    the first ``parameters`` entry parameters (the leaves of a jitted
+    function's first argument, its weights) and writes between ``share``
+    of that parameter's bytes and all of them: the weight, or one layer
+    of a stacked one, moved and not used.  A matmul fused with its
+    weight writes activations and is not listed while those are under
+    the share or more than it read (a decode tick's are; a prefill's
+    are as large as a matrix and do get listed); neither is an
+    asynchronous ``copy-start`` or ``slice-start``, the compiler's own
+    prefetch of an operand.  What a listed line costs a call: its bytes
+    read once more, its ``hbm_bytes`` written and read again."""
+    comps, entry = _computations(hlo_text)
+    lines = [m.groups() for m in map(_NAMED.match, comps[entry]) if m]
+    held = {}
+    for name, type_text, op, operands in lines:
+        if op == "parameter" and int(operands) < parameters:
+            held[name] = (int(operands), _nbytes(_arrays(type_text)))
+    found = []
+    for name, type_text, op, operands in lines:
+        if op not in ("fusion", "copy"):
+            continue
+        results = _laid(type_text)
+        wrote = _nbytes(a for a, _ in results)
+        in_hbm = _nbytes(a for a, layout in results if "S(1)" not in layout)
+        for operand in _OPERAND.findall(operands):
+            number, size = held.get(operand, (None, 0))
+            if number is not None and share * size <= wrote <= size:
+                found.append(Rewrite(name, op, number, wrote, in_hbm))
+    return found

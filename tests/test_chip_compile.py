@@ -796,6 +796,39 @@ def test_mimo_programs_hold_their_kernels_and_no_copy_of_a_cache(
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.0e9
 
 
+def test_mimo_decode_tick_reads_each_layers_matrices_where_they_lie(
+        one_chip):
+    """``MimoV2Model``'s layers are walked in Python and each reads
+    leaves of its own (``params["window"]["q_w"][i]``).  Stacked
+    ``[layers, d, n]`` and sliced by a static index, every ``q_w``
+    (100.7 MB) was written to HBM transposed by a
+    ``slice_bitcast_fusion`` and every window layer's ``k_w`` / ``v_w``
+    to fast memory, before a ``copy`` brought it to the matmul: 0.83 GB
+    a tick moved once more than the model needs, 0.112 GB of
+    temporaries (PR 39).  Now no fusion of the entry computation writes a weight again;
+    what is left is one ``copy`` a ``q_w`` from the parameter into fast
+    memory in the layout the dot takes, the matrix's one read; and the
+    tick's temporaries are 0.015 GB.  A leaf stacked again trips this."""
+    from deepspeed_tpu.utils.hlo import parameter_rewrites
+    compiled = _mimo_program("serve_decode", one_chip)
+    weights = len(jax.tree.leaves(compiled.in_avals[0][0]))
+    assert weights == 3 + 2 * 5 + 5 * 6 + 4 + 6 * 3 + 3
+    moved = [r for r in parameter_rewrites(compiled.as_text(), weights)
+             if r.bytes >= 1 << 20]
+    assert [r for r in moved if r.op != "copy" or r.hbm_bytes] == [], moved
+    assert len({r.parameter for r in moved}) == len(moved) <= 7, moved
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.03e9
+
+
+def test_mimo_prefill_rung_holds_half_the_temporaries_of_stacked_leaves(
+        one_chip):
+    """The 2,048 rung of the same walk: with stacked leaves one fusion
+    wrote all five window layers' ``q_w`` transposed at once (0.966 GB
+    of temporaries); with a leaf a layer 0.492 GB."""
+    compiled = _mimo_program("serve_prefill", one_chip, 2048)
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
 @pytest.mark.parametrize("family,bucket", [("mimo", 2048),
                                            ("nemotron", 512)])
 def test_the_lower_rung_of_the_prefill_ladder_compiles_under_the_top_rungs_peak(

@@ -325,3 +325,47 @@ def test_collectives_reads_the_tpu_compilers_text():
     assert "in a loop x48: all-reduce f32[8], f32[2,8]" in report
     with pytest.raises(ValueError, match="ENTRY"):
         collectives("%lonely (a: f32[]) -> f32[] {\n}\n")
+
+
+# the forms of a decode tick compiled for a v5e (tests/test_chip_compile.py
+# reads the real one): a stacked weight cut by a fusion that writes one
+# layer to HBM and one to fast memory, a per-layer weight brought to fast
+# memory by one copy, a weight fused with its matmul, the compiler's own
+# prefetch, and a cache that is no weight
+_REWRITES = """\
+HloModule jit_fn, is_scheduled=true
+
+%fused_slice (p: bf16[2,64,32]) -> bf16[32,64] {
+  %p = bf16[2,64,32]{2,1,0} parameter(0)
+  ROOT %s = bf16[32,64]{0,1:T(8,128)(2,1)} bitcast(%p)
+}
+
+ENTRY %main (w: bf16[2,64,32], q: bf16[64,32], o: bf16[64,32], kv: bf16[64,32]) -> bf16[4,32] {
+  %p__stacked__.1 = bf16[2,64,32]{2,1,0:T(8,128)(2,1)} parameter(0), sharding={replicated}
+  %p__q_w___0_.1 = bf16[64,32]{1,0:T(8,128)(2,1)} parameter(1), sharding={replicated}
+  %p__o_w___0_.1 = bf16[64,32]{1,0:T(8,128)(2,1)} parameter(2)
+  %kv.1 = bf16[64,32]{1,0:T(8,128)(2,1)} parameter(3)
+  %slice_bitcast_fusion = bf16[32,64]{0,1:T(8,128)(2,1)} fusion(%p__stacked__.1), kind=kLoop, calls=%fused_slice
+  %slice_bitcast_fusion.remat = (bf16[32,64]{0,1:T(8,128)(2,1)}, bf16[32,64]{0,1:T(8,128)(2,1)S(1)}) fusion(%p__stacked__.1), kind=kLoop, calls=%fused_slice
+  %copy.160 = bf16[64,32]{0,1:T(8,128)(2,1)S(1)} copy(%p__q_w___0_.1), sharding={replicated}
+  %copy.161 = bf16[64,32]{0,1:T(8,128)(2,1)} copy(%kv.1)
+  %copy-start.2 = (bf16[64,32]{1,0:T(8,128)(2,1)S(1)}, bf16[64,32]{1,0:T(8,128)(2,1)}, u32[]{:S(2)}) copy-start(%p__o_w___0_.1)
+  ROOT %fusion.7 = bf16[4,32]{1,0:T(8,128)(2,1)S(1)} fusion(%copy.160, %p__o_w___0_.1), kind=kOutput, calls=%fused_slice
+}
+"""
+
+
+def test_parameter_rewrites_lists_the_weights_a_program_moves():
+    from deepspeed_tpu.utils.hlo import Rewrite, parameter_rewrites
+    assert parameter_rewrites(_REWRITES, 3) == [
+        Rewrite("slice_bitcast_fusion", "fusion", 0, 4096, 4096),
+        Rewrite("slice_bitcast_fusion.remat", "fusion", 0, 8192, 4096),
+        Rewrite("copy.160", "copy", 1, 4096, 0),
+    ]
+    # the matmul fused with ``o_w`` writes 256 B of activations: under an
+    # eighth of the weight; a share of 0 lists it too
+    assert [r.instruction for r in parameter_rewrites(_REWRITES, 3, 0.0)][-1] \
+        == "fusion.7"
+    # the cache is a weight only if the caller counts it among them
+    assert [r.instruction for r in parameter_rewrites(_REWRITES, 4)
+            if r.parameter == 3] == ["copy.161"]

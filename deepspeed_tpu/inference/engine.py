@@ -270,15 +270,19 @@ class ServeEngine:
     **The serving protocol** (what the engine, and the benchmark's probe,
     read of ``model``; ``GPT2Model`` and ``OlmoeModel`` are its two
     implementations; ``NemotronHModel`` is a third, with request state,
-    and ``MimoV2Model`` a fourth, whose state is a second kind of key
-    cache):
+    ``MimoV2Model`` a fourth, whose state is a second kind of key
+    cache, and ``AxK1Model`` a fifth, whose pool is one array of latent
+    rows):
 
     * ``model.config`` with ``n_layer``, ``n_head``, ``d_head`` (the KV
       pool's shape; ``n_layer`` counts the layers that keep every key,
       and a config with ``n_kv_head`` has that many key heads in the
       pool, under ``n_head`` query heads; ``d_head`` is the keys' width
       at rest, and a config with ``d_head_v`` has values that wide
-      beside them), ``n_positions`` (the longest
+      beside them; a config that also declares ``values_in_keys`` keeps
+      ONE pool whose rows' first ``d_head_v`` lanes are the values:
+      no ``"v"`` array exists, and its paged steps are handed None for
+      ``v_pool`` and hand None back), ``n_positions`` (the longest
       sequence) and
       ``attn_impl`` (``'flash'`` | ``'dense'``: which decode arm
       ``serving.decode_impl: auto`` takes); ``d_model`` with LoRA;
@@ -539,15 +543,19 @@ class ServeEngine:
             kv_heads = getattr(mcfg, "n_kv_head", mcfg.n_head)
             # two widths: the values' where they are not the keys'
             v_dim = getattr(mcfg, "d_head_v", None)
+            # one pool: the values are the first d_head_v lanes of the
+            # rows (latent attention), and the cache has no "v"
+            one_pool = bool(getattr(mcfg, "values_in_keys", False))
             self.cache_spec = PagedKVCacheSpec(
                 layers=mcfg.n_layer, slots=self.slots,
                 heads=kv_heads, pages=pages, page_len=self.page_len,
                 head_dim=mcfg.d_head, max_pages=self.max_pages,
                 dtype=(jnp.int8 if self.quant_kv else kv_dtype),
-                quant=self.quant_kv, v_head_dim=v_dim)
+                quant=self.quant_kv, v_head_dim=v_dim,
+                values_in_keys=one_pool)
             validate_paged_cache_mesh(mesh, self.cache_spec)
             self._cache_shardings = paged_cache_shardings(
-                mesh, quant=self.quant_kv)
+                mesh, quant=self.quant_kv, values_in_keys=one_pool)
             self.cache = shard_cache(init_paged_cache(self.cache_spec),
                                      mesh, self._cache_shardings)
             if self._state_spec:
@@ -570,7 +578,13 @@ class ServeEngine:
             #: single-query Pallas arm takes a block of several; every
             #: other arm steps page by page)
             self._pages_per_block = 1
-            if (self.decode_impl == "pallas" and not self.quant_kv
+            if self.decode_impl == "pallas" and one_pool:
+                from ..ops.pallas.decode_attention import (
+                    latent_pages_per_block)
+                self._pages_per_block = latent_pages_per_block(
+                    self.page_len, mcfg.d_head,
+                    jnp.dtype(kv_dtype).itemsize, self.max_pages)
+            elif (self.decode_impl == "pallas" and not self.quant_kv
                     and not self.spec_k):
                 from ..ops.pallas.decode_attention import (
                     paged_decode_arm, paged_pages_per_block)
@@ -736,6 +750,14 @@ class ServeEngine:
                 return jnp.stack([counters[k].astype(jnp.float32)
                                   for k in aux_keys])
 
+            def pools(k, v, lengths):
+                """The cache a paged step hands back; a one-pool model
+                (``PagedKVCacheSpec.values_in_keys``) is handed None for
+                the values and hands None back."""
+                if one_pool:
+                    return {"k": k, "lengths": lengths}
+                return {"k": k, "v": v, "lengths": lengths}
+
             def split_lora(extra):
                 """(lora kwargs, rng tail) of a program's *extra."""
                 if not lora_on:
@@ -753,7 +775,7 @@ class ServeEngine:
                        if stateful else {})
                 out = self.model.prefill_paged(
                     params, tokens, delta_len, prefix_len, page_row,
-                    cache["k"], cache["v"], **lkw, **aux_kw, **skw,
+                    cache["k"], cache.get("v"), **lkw, **aux_kw, **skw,
                     **cache_scales(cache))
                 logits, kp, vp = out[0], out[1], out[2]
                 total = jnp.reshape(prefix_len + delta_len,
@@ -764,7 +786,7 @@ class ServeEngine:
                     logits, delta_len - 1, axis=1, keepdims=False)[0]
                 first_tok = select_next_token(last, temp,
                                               rng[0] if rng else None)
-                newc = {"k": kp, "v": vp, "lengths": lengths}
+                newc = pools(kp, vp, lengths)
                 if quant_kv:
                     newc["k_scale"], newc["v_scale"] = out[3], out[4]
                 if stateful:
@@ -777,7 +799,7 @@ class ServeEngine:
                              *extra):
                 lkw, rng = split_lora(extra)
                 out = self.model.decode_step_paged(
-                    params, tokens, cache["k"], cache["v"], page_table,
+                    params, tokens, cache["k"], cache.get("v"), page_table,
                     cache["lengths"], active, impl=self.decode_impl,
                     **lkw, **aux_kw, **cache_scales(cache),
                     **({"state": cache["state"]} if stateful else {}))
@@ -787,7 +809,7 @@ class ServeEngine:
                 logits, k, v, new_len = out[0], out[1], out[2], out[-1]
                 next_tok = select_next_token(logits, temp,
                                              rng[0] if rng else None)
-                newc = {"k": k, "v": v, "lengths": new_len}
+                newc = pools(k, v, new_len)
                 if quant_kv:
                     newc["k_scale"], newc["v_scale"] = out[3], out[4]
                 if stateful:
@@ -799,11 +821,11 @@ class ServeEngine:
             # shaped leaf is copied — on the quantized cache that
             # includes the scale sidecars, or the COW'd page would
             # dequantize with the wrong scales.
+            pool_names = self.cache_spec.pool_names
+
             def serve_copy_page(cache, src, dst):
                 out = dict(cache)
-                for key in ("k", "v", "k_scale", "v_scale"):
-                    if key not in cache:
-                        continue
+                for key in pool_names:
                     a = cache[key]
                     pg = jax.lax.dynamic_slice_in_dim(a, src, 1, axis=1)
                     out[key] = jax.lax.dynamic_update_slice_in_dim(
@@ -823,9 +845,7 @@ class ServeEngine:
             # src/dst, so any page migrates on one compiled pair.
             def serve_page_out(cache, page):
                 out = []
-                for key in ("k", "v", "k_scale", "v_scale"):
-                    if key not in cache:
-                        continue
+                for key in pool_names:
                     out.append(jax.lax.dynamic_slice_in_dim(
                         cache[key], page, 1, axis=1))
                 return tuple(out)
@@ -833,9 +853,7 @@ class ServeEngine:
             def serve_page_in(cache, page, *leaves):
                 out = dict(cache)
                 i = 0
-                for key in ("k", "v", "k_scale", "v_scale"):
-                    if key not in cache:
-                        continue
+                for key in pool_names:
                     out[key] = jax.lax.dynamic_update_slice_in_dim(
                         cache[key], leaves[i], page, axis=1)
                     i += 1
@@ -1000,6 +1018,8 @@ class ServeEngine:
             for k, v in self._state_spec.items()}
         if self.state_bytes:
             self.state_bytes["kv"] = self.kv_bytes
+        elif self.paged and self.cache_spec.values_in_keys:
+            self.state_bytes["latent"] = self.kv_bytes
         if self.spec_k:
             self.param_bytes += param_nbytes(self.draft_params)
             self.kv_bytes += self.draft_cache_spec.bytes
@@ -1064,8 +1084,8 @@ class ServeEngine:
                 layer_gauge = reg.gauge(
                     "serve_cache_layers",
                     "layers by the kind of cache they keep: full (every "
-                    "key, in the page pool) or window (the last keys, by "
-                    "slot)")
+                    "key, in the page pool), window (the last keys, by "
+                    "slot) or latent (one row a token in the page pool)")
                 for kind, n in layers().items():
                     layer_gauge.set(n, kind=kind)
             if self.paged:
@@ -1091,12 +1111,13 @@ class ServeEngine:
                     for arm in ("direct", "packed"):
                         arm_gauge.set(int(arm == self.paged_decode_arm),
                                       arm=arm)
-            if self._state_spec:
+            if self.state_bytes:
                 state_gauge = reg.gauge(
                     "serve_state_bytes",
                     "device bytes a stateful model's requests hold by "
                     "kind: each serving_state leaf (ssm, conv; window_k, "
-                    "window_v) and the page pool (kv)")
+                    "window_v) and the page pool (kv; latent where it is "
+                    "one pool of latent rows and nothing else is kept)")
                 for kind, nbytes in self.state_bytes.items():
                     state_gauge.set(nbytes, kind=kind)
             if self._aux:
@@ -2835,8 +2856,7 @@ class ServeEngine:
     def _page_leaves(self) -> List[str]:
         """Pool-shaped cache leaves in the fixed wire order (mirrors
         _copy_fn: scales ride along on the quantized pool)."""
-        return [k for k in ("k", "v", "k_scale", "v_scale")
-                if k in self.cache]
+        return list(self.cache_spec.pool_names)
 
     def page_leaf_nbytes(self) -> List[int]:
         """Per-leaf byte lengths inside ONE exported page payload —

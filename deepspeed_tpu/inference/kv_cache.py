@@ -14,8 +14,10 @@ slot:
     k, v     [L, P, H, page_len, Dh]   layer-major, page-pooled
     lengths  [S] int32                 per-slot LIVE length
 
-A slot's KV rows live wherever its int32 page table (a TRACED operand
-of the decode program, never part of any compiled shape) points; page 0
+(a model whose values are lanes of its key rows keeps the ``k`` array
+alone: ``PagedKVCacheSpec.values_in_keys``).  A slot's KV rows live
+wherever its int32 page table (a TRACED operand of the decode program,
+never part of any compiled shape) points; page 0
 is the reserved scratch page masked writes of inactive slots land on,
 so scatter conflicts can only happen between no-op writes.  Short
 requests hold ceil(len/page_len) pages instead of a full ``max_seq_len``
@@ -128,6 +130,12 @@ class PagedKVCacheSpec:
     rest; ``v_head_dim`` the values' where it differs (a model config
     with ``d_head_v``; None: the same), so the two pools are
     ``[L, pages, H, page_len, head_dim]`` and ``[..., v_head_dim]``.
+    ``values_in_keys`` (a model config that declares it, beside
+    ``d_head_v``): a layer caches ONE row a token and its values are the
+    first ``v_head_dim`` lanes of that row (latent attention:
+    ``[c_kv ; k_rope]`` read by every head), so the pool is the ``"k"``
+    array alone and no ``"v"`` array exists; every count of bytes below
+    follows.
     What a request keeps beside its pages
     (a ``serving_state`` model's recurrent state, by slot) is not in
     this spec: ``ServeEngine`` allocates it under ``cache["state"]``.
@@ -152,10 +160,32 @@ class PagedKVCacheSpec:
     quant: bool = False
     #: the values' width where it is not the keys'
     v_head_dim: Optional[int] = None
+    #: one pool: the values are the rows' first ``v_head_dim`` lanes
+    values_in_keys: bool = False
+
+    def __post_init__(self):
+        if self.values_in_keys and (self.quant or not self.v_head_dim
+                                    or self.v_head_dim > self.head_dim):
+            raise ValueError(
+                "values_in_keys: the values are the first v_head_dim "
+                f"(got {self.v_head_dim}) of the rows' {self.head_dim} "
+                "lanes, and such a pool has no int8 arm")
 
     @property
     def value_dim(self) -> int:
         return self.head_dim if self.v_head_dim is None else self.v_head_dim
+
+    @property
+    def pool_names(self):
+        """The pool-shaped leaves of the cache, in the fixed order every
+        page copy, export and import walks them."""
+        names = ("k",) if self.values_in_keys else ("k", "v")
+        return names + (("k_scale", "v_scale") if self.quant else ())
+
+    @property
+    def row_width(self) -> int:
+        """Lanes a token keeps a head a layer, over every pool."""
+        return self.head_dim + (0 if self.values_in_keys else self.value_dim)
 
     @property
     def bytes(self) -> int:
@@ -167,8 +197,7 @@ class PagedKVCacheSpec:
         quant scale sidecar rows) — the allocation quantum the bench's
         fixed-byte budget divides by."""
         per = jnp.dtype(self.dtype).itemsize
-        n = self.layers * self.heads * self.page_len \
-            * (self.head_dim + self.value_dim) * per
+        n = self.layers * self.heads * self.page_len * self.row_width * per
         if self.quant:
             n += 2 * self.layers * self.heads * self.page_len * 4
         return n
@@ -183,9 +212,10 @@ def init_paged_cache(spec: PagedKVCacheSpec) -> Dict[str, jnp.ndarray]:
     shape = (spec.layers, spec.pages, spec.heads, spec.page_len)
     cache = {
         "k": jnp.zeros(shape + (spec.head_dim,), spec.dtype),
-        "v": jnp.zeros(shape + (spec.value_dim,), spec.dtype),
         "lengths": jnp.zeros((spec.slots,), jnp.int32),
     }
+    if not spec.values_in_keys:
+        cache["v"] = jnp.zeros(shape + (spec.value_dim,), spec.dtype)
     if spec.quant:
         sshape = (spec.layers, spec.pages, spec.heads, spec.page_len)
         cache["k_scale"] = jnp.zeros(sshape, jnp.float32)
@@ -193,13 +223,17 @@ def init_paged_cache(spec: PagedKVCacheSpec) -> Dict[str, jnp.ndarray]:
     return cache
 
 
-def paged_partition_specs(quant: bool = False) -> Dict[str, P]:
+def paged_partition_specs(quant: bool = False,
+                          values_in_keys: bool = False) -> Dict[str, P]:
     """Pool pages on ``data``, heads on ``model`` — the page pool is
     the DP-sharded storage dimension the way slots were.  The quant
     scale sidecars shard exactly like their pools (minus the row dim's
-    trailing head_dim)."""
+    trailing head_dim).  A one-pool spec (``values_in_keys``) has no
+    ``"v"``."""
     kv = P(None, DATA_AXIS, MODEL_AXIS, None, None)
     specs = {"k": kv, "v": kv, "lengths": P()}
+    if values_in_keys:
+        del specs["v"]
     if quant:
         sc = P(None, DATA_AXIS, MODEL_AXIS, None)
         specs["k_scale"] = sc
@@ -207,10 +241,11 @@ def paged_partition_specs(quant: bool = False) -> Dict[str, P]:
     return specs
 
 
-def paged_cache_shardings(mesh: Mesh,
-                          quant: bool = False) -> Dict[str, NamedSharding]:
-    return {name: NamedSharding(mesh, spec)
-            for name, spec in paged_partition_specs(quant).items()}
+def paged_cache_shardings(mesh: Mesh, quant: bool = False,
+                          values_in_keys: bool = False
+                          ) -> Dict[str, NamedSharding]:
+    return {name: NamedSharding(mesh, spec) for name, spec in
+            paged_partition_specs(quant, values_in_keys).items()}
 
 
 def validate_paged_cache_mesh(mesh: Mesh,

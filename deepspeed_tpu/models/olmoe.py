@@ -203,14 +203,18 @@ def rms_norm(x, weight, eps: float):
     return y.astype(x.dtype) * weight.astype(x.dtype)
 
 
-def rope(x, positions, theta: float, rotary_dim: Optional[int] = None):
+def rope(x, positions, theta: float, rotary_dim: Optional[int] = None,
+         inv_freq=None):
     """Rotate-half RoPE.  x [B, H, T, Dh], positions [B, T] (absolute).
     Pair i is (x[i], x[i + R/2]), angle ``pos * theta**(-2i/R)``, over
     the first ``R = rotary_dim`` dims (None: the whole head); the others
-    pass untouched."""
+    pass untouched.  ``inv_freq`` [R/2] float32: a frequency a pair of
+    the caller's own (a scaled RoPE: ``models/axk1.py::yarn_inv_freq``)
+    in place of ``theta``'s, which is then not read."""
     rot = x.shape[-1] if rotary_dim is None else rotary_dim
     half = rot // 2
-    inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    if inv_freq is None:
+        inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
     ang = positions.astype(jnp.float32)[:, None, :, None] * inv_freq
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     xf = x.astype(jnp.float32)

@@ -18,7 +18,12 @@ expert: bound by reading the hit experts' weights):
    (``silu(x @ gate) * (x @ up)``) and ``ds_moe_down``.  An expert's
    matrices are one contiguous block, fetched once while its tiles follow
    each other and not at all for an expert no token chose; tiles past the
-   last live one run nothing and fetch nothing new;
+   last live one run nothing and fetch nothing new.  Where whole matrices
+   in flight would not fit ``MOE_WEIGHT_VMEM_BUDGET`` (both up-projections
+   at d 7,168, f 2,048) a kernel walks them in blocks of its OUTPUT width
+   (:func:`weight_blocks`): every tile for the first block of columns,
+   then every tile for the next, so a block is still fetched once an
+   expert and the rows once a block;
 4. combine: each token's k rows gathered back, weighted, summed in float32.
 
 The stacked weights of ALL layers reach the kernels whole
@@ -53,6 +58,7 @@ Nemotron-3 Super's latent experts, ``models/nemotron_h.py``):
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import NamedTuple, Optional
 
@@ -76,13 +82,39 @@ MOE_VMEM_LIMIT = 48 * 1024 * 1024
 MAX_ROW_TILE = 128
 
 
-def _vmem_limit(weights) -> int:
+#: what an expert's weight blocks in flight (double-buffered) may take of
+#: a v5e core's 128 MiB.  Both up-projections at d 4096, f 2048 are 64 MiB
+#: and stay whole; at d 7168, f 2048 they would be 112 MiB and are walked
+#: in two blocks of 1024 columns (56 MiB); the down-projection there is
+#: 56 MiB and stays whole
+MOE_WEIGHT_VMEM_BUDGET = 80 * 1024 * 1024
+_LANES = 128
+
+
+def _in_flight(weights) -> int:
+    """Bytes of one expert's matrices, double-buffered."""
+    return 2 * sum(math.prod(w.shape[1:]) * w.dtype.itemsize
+                   for w in weights)
+
+
+def weight_blocks(weights, width: int) -> int:
+    """Blocks of the output width a grouped matmul walks an expert's
+    matrices in: 1 (whole) where they fit ``MOE_WEIGHT_VMEM_BUDGET``
+    double-buffered, else the smallest power of two that does, each block
+    whole lanes wide.  A function of the shapes alone."""
+    nb = 1
+    while (_in_flight(weights) // nb > MOE_WEIGHT_VMEM_BUDGET
+           and width % (2 * nb * _LANES) == 0):
+        nb *= 2
+    return nb
+
+
+def _vmem_limit(weights, nb: int = 1) -> int:
     """``MOE_VMEM_LIMIT``, or where an expert's blocks in flight need
     more (both up-projections at d 4096, f 2048 are 64 MiB
     double-buffered), those and 16 MiB for the rows, the result and the
     body's temporaries."""
-    blocks = sum(math.prod(w.shape[1:]) * w.dtype.itemsize for w in weights)
-    return max(MOE_VMEM_LIMIT, 2 * blocks + 16 * 1024 * 1024)
+    return max(MOE_VMEM_LIMIT, _in_flight(weights) // nb + 16 * 1024 * 1024)
 
 
 class MoEStats(NamedTuple):
@@ -139,8 +171,9 @@ def row_tile(assignments: int, n_experts: int) -> int:
     return tm
 
 
-def _gate_up_kernel(te_ref, live_ref, x_ref, wg_ref, wu_ref, h_ref):
-    live = pl.program_id(0) < live_ref[0]
+def _gate_up_kernel(te_ref, live_ref, x_ref, wg_ref, wu_ref, h_ref, *,
+                    axis=0):
+    live = pl.program_id(axis) < live_ref[0]
 
     @pl.when(live)
     def _():
@@ -154,8 +187,8 @@ def _gate_up_kernel(te_ref, live_ref, x_ref, wg_ref, wu_ref, h_ref):
         h_ref[...] = jnp.zeros_like(h_ref)
 
 
-def _up_relu2_kernel(te_ref, live_ref, x_ref, wu_ref, h_ref):
-    live = pl.program_id(0) < live_ref[0]
+def _up_relu2_kernel(te_ref, live_ref, x_ref, wu_ref, h_ref, *, axis=0):
+    live = pl.program_id(axis) < live_ref[0]
 
     @pl.when(live)
     def _():
@@ -168,8 +201,8 @@ def _up_relu2_kernel(te_ref, live_ref, x_ref, wu_ref, h_ref):
         h_ref[...] = jnp.zeros_like(h_ref)
 
 
-def _down_kernel(te_ref, live_ref, h_ref, wd_ref, y_ref):
-    live = pl.program_id(0) < live_ref[0]
+def _down_kernel(te_ref, live_ref, h_ref, wd_ref, y_ref, *, axis=0):
+    live = pl.program_id(axis) < live_ref[0]
 
     @pl.when(live)
     def _():
@@ -185,23 +218,35 @@ def _down_kernel(te_ref, live_ref, h_ref, wd_ref, y_ref):
 def _grouped(kernel, name, rows, weights, tile_expert, n_live, tm, width,
              interpret):
     """One grouped matmul: ``rows`` [T*tm, d_in] against the tile's
-    expert of each of ``weights`` ([X, d_in, width])."""
+    expert of each of ``weights`` ([X, d_in, width]), whole or in
+    :func:`weight_blocks` blocks of ``width`` (the tiles inside a block:
+    the module docstring has the order and why)."""
     tiles = rows.shape[0] // tm
     d_in = rows.shape[1]
+    nb = weight_blocks(weights, width)
+    if nb == 1:
+        grid, semantics = (tiles,), ("arbitrary",)
+        row_map = out_map = lambda t, te, nl: (t, 0)
+        w_map = lambda t, te, nl: (te[t], 0, 0)
+    else:
+        grid, semantics = (nb, tiles), ("arbitrary", "arbitrary")
+        kernel = functools.partial(kernel, axis=1)
+        row_map = lambda j, t, te, nl: (t, 0)
+        w_map = lambda j, t, te, nl: (te[t], 0, j)
+        out_map = lambda j, t, te, nl: (t, j)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(tiles,),
-        in_specs=[pl.BlockSpec((tm, d_in), lambda t, te, nl: (t, 0))]
-        + [pl.BlockSpec((1,) + w.shape[1:], lambda t, te, nl: (te[t], 0, 0))
-           for w in weights],
-        out_specs=pl.BlockSpec((tm, width), lambda t, te, nl: (t, 0)),
+        grid=grid,
+        in_specs=[pl.BlockSpec((tm, d_in), row_map)]
+        + [pl.BlockSpec((1, d_in, width // nb), w_map) for _ in weights],
+        out_specs=pl.BlockSpec((tm, width // nb), out_map),
     )
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((tiles * tm, width), rows.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
-            vmem_limit_bytes=_vmem_limit(weights)),
+            dimension_semantics=semantics,
+            vmem_limit_bytes=_vmem_limit(weights, nb)),
         interpret=interpret, name=name,
     )(tile_expert, n_live, rows, *weights)
 

@@ -1151,6 +1151,203 @@ def decode_attention_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
 
 
 # ---------------------------------------------------------------------------
+# latent attention (MLA) in absorbed form: ONE pool of latent rows
+# ---------------------------------------------------------------------------
+
+LATENT_DECODE_ATTN_KERNEL = "ds_latent_decode_attn"
+
+
+def latent_pages_per_block(page_len: int, width: int, itemsize: int,
+                           max_pages: int) -> int:
+    """Pages one grid step of the latent kernel attends: the largest
+    power of two whose double buffer fits ``PAGED_KV_VMEM_BUDGET``, at
+    most ``max_pages``.  A page in flight is its own bytes, once: the
+    rows that score are the rows that are summed."""
+    fit = max(1, min(PAGED_KV_VMEM_BUDGET // (2 * page_len * width
+                                              * itemsize), max_pages))
+    return 1 << (fit.bit_length() - 1)
+
+
+def latent_decode_reference(q, pool, page_table, lengths, value_dim: int,
+                            sm_scale: float):
+    """Dense jnp reference of :func:`latent_decode_attention`: the slot's
+    pages gathered, every head against the same rows, the values their
+    first ``value_dim`` lanes.  A slot of length 0 gives exact zeros."""
+    S, max_pages = page_table.shape
+    rows = pool[page_table].reshape(S, -1, pool.shape[-1])   # [S, cap, W]
+    s = jnp.einsum("shw,stw->sht", q, rows.astype(q.dtype),
+                   preferred_element_type=jnp.float32) * sm_scale
+    lengths = lengths.astype(jnp.int32)
+    live = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, None, :] \
+        < lengths[:, None, None]
+    s = jnp.where(live, s, jnp.finfo(jnp.float32).min)
+    p = jnp.where(lengths[:, None, None] > 0, jax.nn.softmax(s, axis=-1),
+                  0.0).astype(q.dtype)
+    return jnp.einsum("sht,stv->shv", p,
+                      rows[..., :value_dim].astype(q.dtype))
+
+
+def _latent_decode_kernel(pt_ref, len_ref, q_ref, kv_hbm, o_ref, buf, sems,
+                          state_ref, m_scr, l_scr, acc_scr, *,
+                          sm_scale: float, value_dim: int):
+    """One grid step = one slot, ALL heads, ``ppb`` pages of the ONE pool
+    (the direct paged body's double buffer and hand-written page copies:
+    ``_decode_paged_direct_kernel`` says how the halves pass from step to
+    step).  A page ``[page_len, W]`` lands once and is read twice where
+    it lies: all ``W`` lanes of its rows against every head's ``[q_lat ;
+    q_rope]`` for the scores, their first ``value_dim`` lanes under the
+    probabilities for the output.  A key's position is its row's place
+    in the block: no head owns a row, so no position table."""
+    s, j = pl.program_id(0), pl.program_id(1)
+    slots, nb = pl.num_programs(0), pl.num_programs(1)
+    _, ppb, page_len, width = buf.shape
+    bk = ppb * page_len
+    length = len_ref[s]
+
+    def for_live_pages(slot, blk, fn):
+        left = len_ref[slot] - blk * bk
+        jax.lax.fori_loop(0, jnp.minimum(ppb, (left + page_len - 1)
+                                         // page_len),
+                          lambda i, _: fn(i), None)
+
+    def fetch(slot, blk, half):
+        for_live_pages(slot, blk, lambda i: pltpu.make_async_copy(
+            kv_hbm.at[pt_ref[(slot * nb + blk) * ppb + i]],
+            buf.at[half, i], sems.at[half]).start())
+
+    @pl.when((s == 0) & (j == 0))
+    def _clear():
+        # a partly live block's dead pages are never copied into: what
+        # VMEM held there would reach the value matmul times 0
+        buf[...] = jnp.zeros_like(buf)
+        state_ref[0] = 0
+        state_ref[1] = 0
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(j * bk < length)
+    def _live():
+        half = state_ref[0]
+        state_ref[0] = 1 - half
+
+        @pl.when(state_ref[1] == 0)
+        def _first():
+            fetch(s, j, half)
+            state_ref[1] = 1
+
+        more = (j + 1 < nb) & ((j + 1) * bk < length)
+        next_s = jax.lax.while_loop(
+            lambda t: (t < slots) & (len_ref[jnp.minimum(t, slots - 1)] == 0),
+            lambda t: t + 1, jnp.where(more, s, s + 1))
+
+        @pl.when(next_s < slots)
+        def _ahead():
+            fetch(next_s, jnp.where(more, j + 1, 0), 1 - half)
+
+        # a wait takes one page's bytes off the semaphore: whose, is the
+        # same to it
+        for_live_pages(s, j, lambda i: pltpu.make_async_copy(
+            kv_hbm.at[0], buf.at[half, i], sems.at[half]).wait())
+        rows = buf[half].reshape(bk, width)
+        sc = jax.lax.dot_general(
+            q_ref[0], rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale
+        at = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        sc = jnp.where(at < length - j * bk, sc, NEG_INF)
+        m_prev = m_scr[:, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
+        # key j*bk is live (the pl.when guard), so m_new is a real score
+        # and the masked keys' exp underflows to 0
+        p = jnp.exp(sc - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[:] = jnp.broadcast_to(
+            alpha * l_scr[:, 0:1] + jnp.sum(p, axis=1, keepdims=True),
+            l_scr.shape)
+        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+            p.astype(rows.dtype), rows[:, :value_dim],
+            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+
+    @pl.when(j == nb - 1)
+    def _finalize():
+        l = l_scr[:, 0:1]
+        l_safe = jnp.where(l == 0.0, 1.0, l)
+        # length 0 -> no block ran -> l == 0 -> exact zeros (free slots)
+        o_ref[0] = jnp.where(l == 0.0, 0.0,
+                             acc_scr[:] / l_safe).astype(o_ref.dtype)
+
+
+def latent_decode_attention(q: jnp.ndarray, pool: jnp.ndarray,
+                            page_table: jnp.ndarray, lengths: jnp.ndarray,
+                            value_dim: int, *, sm_scale: float,
+                            impl: str = "pallas",
+                            interpret: Optional[bool] = None) -> jnp.ndarray:
+    """Single-query latent attention (MLA, absorbed form) over ONE paged
+    pool, the kernel ``ds_latent_decode_attn``.
+
+    q: [S, H, W]: a head's ``[q_lat ; q_rope]``, zeros in the lanes the
+        rows pad.
+    pool: [P, page_len, W]: one row a token, ``[c_kv ; k_rope]`` after
+        the norm and the rotation, shared by every head; a slot's
+        position ``p`` is row ``p % page_len`` of page ``page_table[s,
+        p // page_len]``.  The values are the rows' first ``value_dim``
+        lanes.
+    page_table [S, max_pages], lengths [S]: traced, as
+        :func:`decode_attention_paged` takes them.  ``sm_scale`` is the
+        caller's: the stored width says nothing of the head it came from.
+
+    Returns ``[S, H, value_dim]``; a slot of length 0 gives exact zeros.
+    ``impl='dense'`` is :func:`latent_decode_reference`."""
+    assert q.ndim == 3 and pool.ndim == 3, (q.shape, pool.shape)
+    P, page_len, W = pool.shape
+    S, max_pages = page_table.shape
+    H = q.shape[1]
+    assert q.shape == (S, H, W) and value_dim <= W, (q.shape, pool.shape)
+    if impl == "dense":
+        return latent_decode_reference(q, pool, page_table, lengths,
+                                       value_dim, sm_scale)
+    if impl != "pallas":
+        raise ValueError(f"latent_decode_attention impl={impl!r}: expected "
+                         "'pallas' or 'dense'")
+    if interpret is None:
+        interpret = _use_interpret()
+    ppb = latent_pages_per_block(page_len, W, pool.dtype.itemsize, max_pages)
+    nb = -(-max_pages // ppb)
+    pt_flat = jnp.pad(page_table.astype(jnp.int32),
+                      ((0, 0), (0, nb * ppb - max_pages))).reshape(-1)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(S, nb),
+        in_specs=[pl.BlockSpec((1, H, W), lambda s, j, *_: (s, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, H, value_dim), lambda s, j, *_: (s, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, ppb, page_len, W), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((2,), jnp.int32),
+            pltpu.VMEM((H, 128), jnp.float32),
+            pltpu.VMEM((H, 128), jnp.float32),
+            pltpu.VMEM((H, value_dim), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_latent_decode_kernel, sm_scale=sm_scale,
+                          value_dim=value_dim),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, H, value_dim), q.dtype),
+        # the double buffer and its parity pass from one step to the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name=LATENT_DECODE_ATTN_KERNEL,
+    )(pt_flat, lengths.astype(jnp.int32), q, pool)
+
+
+# ---------------------------------------------------------------------------
 # multi-query decode attention: the speculative verify arm
 # ---------------------------------------------------------------------------
 

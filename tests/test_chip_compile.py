@@ -10,6 +10,7 @@ inside the module-scoped fixture below: the TPU library is loaded by the
 first test that runs, in the one xdist worker that owns this file, and
 never while a module is imported.
 """
+import dataclasses
 import functools
 import os
 
@@ -853,6 +854,167 @@ def test_the_lower_rung_of_the_prefill_ladder_compiles_under_the_top_rungs_peak(
     print(f"{family} serve_prefill temporaries: {mem.temp_size_in_bytes} B "
           f"at {bucket} tokens, {top_mem.temp_size_in_bytes} B at {top_len}")
     assert mem.temp_size_in_bytes < top_mem.temp_size_in_bytes
+
+
+# ---------------------------------------------------------------------------
+# A.X-K1 at its cell's sizes (benchmark/configs/a.x-k1.json: 6 layers of
+# latent attention, 12 of 192 experts held at hidden 7,168, 192 slots, ONE
+# pool of 12,289 pages of 64 rows 640 wide): the latent decode kernel, the
+# expert kernels walked in blocks, and both serve programs
+# ---------------------------------------------------------------------------
+
+AXK1_SLOTS, AXK1_PAGE_LEN, AXK1_PAGES, AXK1_MAX_PAGES = 192, 64, 12289, 192
+
+
+def _axk1_model():
+    import json
+    from deepspeed_tpu.models.axk1 import AxK1Config, AxK1Model
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "a.x-k1.json")) as f:
+        file = json.load(f)
+    serving = file["serving"]
+    assert (serving["slots"], serving["page_len"], serving["pages"],
+            -(-serving["max_seq_len"] // serving["page_len"])) == (
+        AXK1_SLOTS, AXK1_PAGE_LEN, AXK1_PAGES, AXK1_MAX_PAGES)
+    fields = {f.name for f in dataclasses.fields(AxK1Config)}
+    keys = {k: v for k, v in file.items() if k in fields}
+    keys["n_routed_experts"] = file["published"]["n_routed_experts"]
+    return AxK1Model(AxK1Config(**keys, param_dtype=file["dtype"])), file
+
+
+def test_latent_decode_kernel_reads_the_one_pool_where_it_lies(one_chip):
+    """64 heads' [q_lat ; q_rope] against rows 640 wide, values their
+    first 512 lanes: 32 pages of 64 a block inside the module's VMEM
+    budget, the pool left in HBM, no layer sliced out of it."""
+    from deepspeed_tpu.ops.pallas.decode_attention import (
+        LATENT_DECODE_ATTN_KERNEL, latent_decode_attention,
+        latent_pages_per_block)
+    assert LATENT_DECODE_ATTN_KERNEL == "ds_latent_decode_attn"
+    ppb = latent_pages_per_block(AXK1_PAGE_LEN, 640, 2, AXK1_MAX_PAGES)
+    assert ppb == 32
+    assert 2 * ppb * AXK1_PAGE_LEN * 640 * 2 <= PAGED_KV_VMEM_BUDGET
+    compiled = _compile(
+        lambda q, pool, t, n: latent_decode_attention(
+            q, pool, t, n, 512, sm_scale=0.13, interpret=False),
+        one_chip, _sds((AXK1_SLOTS, 64, 640)),
+        _sds((6 * AXK1_PAGES, AXK1_PAGE_LEN, 640)),
+        _sds((AXK1_SLOTS, AXK1_MAX_PAGES), jnp.int32),
+        _sds((AXK1_SLOTS,), jnp.int32))
+    names = _kernel_names(compiled)
+    assert [n.split(".")[0] for n in names] == [LATENT_DECODE_ATTN_KERNEL]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("tokens", [AXK1_SLOTS, 2048],
+                         ids=["decode_tick", "prefill_rung"])
+def test_moe_kernels_walk_an_expert_in_blocks_at_hidden_7168(tokens,
+                                                             one_chip):
+    """12 held of 192 experts of 7,168 x 2,048, top-8: both
+    up-projections whole would be 112 MiB in flight of a core's 128; the
+    kernel walks them in two blocks of 1,024 columns (72 MiB with the
+    rows), the down-projection whole (72 MiB).  The compile is the proof
+    that the chip allows both."""
+    from deepspeed_tpu.moe import dropless
+    d, f, held = 7168, 2048, 12
+    weights = [_sds((5 * held, d, f))] * 2
+    assert dropless.weight_blocks(weights, f) == 2
+    assert dropless._vmem_limit(weights, 2) == 72 << 20
+    compiled = _compile(
+        lambda x, r, g, u, w: dropless.dropless_moe(
+            x, r, g, u, w, 8, expert_offset=jnp.int32(held),
+            experts_held=(0, held), interpret=False)[0],
+        one_chip, _sds((tokens, d)), _sds((d, 192)), *weights,
+        _sds((5 * held, f, d)))
+    names = sorted(n.split(".")[0] for n in _kernel_names(compiled))
+    assert names == [dropless.MOE_DOWN_KERNEL, dropless.MOE_GATE_UP_KERNEL]
+
+
+@functools.lru_cache(maxsize=None)
+def _axk1_program(program, one_chip, bucket=4096):
+    """The model's paged step as the engine calls it: the one pool
+    donated, None where a second would be; a prefill at ``bucket`` tokens
+    with its prefix length TRACED, so both forms of its attention (from
+    nothing; its context read back from the pages) are in the program."""
+    model, _ = _axk1_model()
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    pool = _sds((6, AXK1_PAGES, 1, AXK1_PAGE_LEN, 640))
+    i32, s = _sds((), jnp.int32), AXK1_SLOTS
+    if program == "serve_decode":
+        def fn(p, t, k, tab, ln, act):
+            return model.decode_step_paged(p, t, k, None, tab, ln, act,
+                                           impl="pallas", aux=True)
+        shapes = (params, _sds((s,), jnp.int32), pool,
+                  _sds((s, AXK1_MAX_PAGES), jnp.int32),
+                  _sds((s,), jnp.int32), _sds((s,), jnp.bool_))
+        donate = (2,)
+    else:
+        def fn(p, t, n, pre, row, k):
+            return model.prefill_paged(p, t, n, pre, row, k, None, aux=True)
+        shapes = (params, _sds((1, bucket), jnp.int32), i32, i32,
+                  _sds((AXK1_MAX_PAGES,), jnp.int32), pool)
+        donate = (5,)
+    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), shapes)
+    with interpret_scope(False):
+        return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+
+
+@pytest.mark.parametrize("program", ["serve_decode", "serve_prefill"])
+def test_axk1_programs_hold_their_kernels_and_one_pool(program, one_chip):
+    """Every Mosaic call of both serve programs starts ``ds_``; the ONE
+    pool (6.04 GB) passes through aliased to the output and nothing of
+    its size is a temporary; the arguments are the weights and that pool
+    and no second array of latents; the compiler's own counts are the
+    ones the configuration's ``reduced_why`` states; all the chip must
+    hold at once fits its 16.91e9 bytes."""
+    from deepspeed_tpu.moe import dropless
+    from deepspeed_tpu.ops.pallas.decode_attention import \
+        LATENT_DECODE_ATTN_KERNEL
+    compiled = _axk1_program(program, one_chip)
+    names = {n.split(".")[0] for n in _kernel_names(compiled)}
+    experts = {dropless.MOE_GATE_UP_KERNEL, dropless.MOE_DOWN_KERNEL}
+    assert names == experts | ({LATENT_DECODE_ATTN_KERNEL}
+                               if program == "serve_decode"
+                               else {"ds_flash_fwd"}), names
+    mem = compiled.memory_analysis()
+    pool = 6 * AXK1_PAGES * AXK1_PAGE_LEN * 640 * 2
+    assert mem.alias_size_in_bytes >= pool
+    weights = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves(compiled.in_avals[0][0]))
+    assert abs(mem.argument_size_in_bytes - weights - pool) < 1 << 20
+    limit = 0.06e9 if program == "serve_decode" else 1.2e9
+    assert mem.temp_size_in_bytes < limit, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.6e9
+    _, file = _axk1_model()
+    said = {"serve_decode": "temporaries %.3f GB (decode)",
+            "serve_prefill": "%.3f GB (prefill"}[program]
+    assert "arguments %.3f GB" % (mem.argument_size_in_bytes / 1e9) \
+        in file["reduced_why"]
+    assert said % (mem.temp_size_in_bytes / 1e9) in file["reduced_why"]
+
+
+def test_axk1_decode_tick_reads_each_layers_matrices_where_they_lie(
+        one_chip):
+    """A leaf a layer (``models/mimo_v2.py``'s rule): no fusion of the
+    tick's entry computation writes a weight again, the absorbed
+    matrices ``k_b_w`` / ``v_b_w`` [heads, ., .] included, and what is
+    copied is a matrix's one read into the layout its dot takes."""
+    from deepspeed_tpu.utils.hlo import parameter_rewrites
+    compiled = _axk1_program("serve_decode", one_chip)
+    weights = len(jax.tree.leaves(compiled.in_avals[0][0]))
+    assert weights == 3 + 6 * 9 + 4 + 5 * 5 + 3
+    moved = [r for r in parameter_rewrites(compiled.as_text(), weights)
+             if r.bytes >= 1 << 20]
+    assert [r for r in moved if r.op != "copy" or r.hbm_bytes] == [], moved
+
+
+def test_axk1_lower_prefill_rung_compiles_under_the_top_rungs_peak(one_chip):
+    from deepspeed_tpu.inference.engine import prefill_ladder
+    assert prefill_ladder(4096) == (2048, 4096)
+    top = _axk1_program("serve_prefill", one_chip).memory_analysis()
+    rung = _axk1_program("serve_prefill", one_chip, 2048).memory_analysis()
+    assert rung.alias_size_in_bytes == top.alias_size_in_bytes
+    assert rung.temp_size_in_bytes < top.temp_size_in_bytes
 
 
 @pytest.mark.parametrize("model", ["gpt2", "bert"])

@@ -20,7 +20,8 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops.transformer import (DeepSpeedTransformerConfig,
                                DeepSpeedTransformerLayer)
-from ..ops.transformer.transformer import _dropout, _layer_norm
+from ..ops.dropout import dropout
+from ..ops.transformer.transformer import _layer_norm
 from ..parallel.mesh import MODEL_AXIS
 from ..runtime.module import TrainModule, mark_subtrees
 from ..runtime.zero import gather_layer
@@ -179,8 +180,8 @@ class BertModel(TrainModule):
                  + params["token_type_embeddings"][tt])
             x = _layer_norm(x, params["emb_ln_scale"],
                             params["emb_ln_bias"])
-        x = _dropout(x, cfg.hidden_dropout_prob if train else 0.0,
-                     jax.random.fold_in(rng, 997))
+        x = dropout(x, cfg.hidden_dropout_prob if train else 0.0,
+                    jax.random.fold_in(rng, 997))
 
         # HF-style additive mask [B, 1, 1, T]
         add_mask = None

@@ -21,7 +21,8 @@ from ..ops.transformer import (DeepSpeedTransformerConfig,
                                DeepSpeedTransformerLayer)
 from ..parallel.mesh import MODEL_AXIS
 from ..pipe.module import LayerSpec, TiedLayerSpec, PipelineModule
-from .bert import BertConfig, _dropout, _layer_norm
+from ..ops.dropout import dropout
+from .bert import BertConfig, _layer_norm
 
 
 def _layer_cfg(cfg: BertConfig) -> DeepSpeedTransformerConfig:
@@ -78,8 +79,8 @@ class BertEmbeddingPipe:
         x = (onehot @ wte + params["wpe"][:T][None]
              + params["tte"][0][None, None])
         x = _layer_norm(x, params["ln_scale"], params["ln_bias"])
-        return _dropout(x, self.cfg.hidden_dropout_prob if train else 0.0,
-                        rng)
+        return dropout(x, self.cfg.hidden_dropout_prob if train else 0.0,
+                       rng)
 
 
 class BertLayerPipe:

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import causal_attention
+from ..ops.dropout import dropout
 from ..parallel.mesh import MODEL_AXIS
 from ..runtime.module import TrainModule, mark_subtrees
 from ..runtime.zero import gather_layer
@@ -159,8 +160,8 @@ class GPT2Model(TrainModule):
                 f"sequence length {T} exceeds n_positions={cfg.n_positions}")
         with jax.named_scope("embed"):
             x = params["wte"][tokens] + params["wpe"][:T][None]
-        x = _dropout(x, cfg.embd_dropout if train else 0.0,
-                     jax.random.fold_in(rng, 997))
+        x = dropout(x, cfg.embd_dropout if train else 0.0,
+                    jax.random.fold_in(rng, 997))
 
         block_params = params["blocks"]
 
@@ -363,7 +364,7 @@ def gpt2_block_forward(cfg: GPT2Config, bp, x, rng, train: bool):
     x = gpt2_attn_sublayer(cfg, bp, x, r_attn, train)
     h = _layer_norm(x, bp["ln2_scale"], bp["ln2_bias"])
     h = gpt2_ffn(bp, h)
-    return x + _dropout(h, drop, r3)
+    return x + dropout(h, drop, r3)
 
 
 def _wscale(y, bp, name: str):
@@ -468,7 +469,7 @@ def gpt2_attn_project(bp, x, attn, drop: float, rng):
     d = _lora_delta(attn, bp, "out_w")
     if d is not None:
         y = y + d
-    return x + _dropout(y, drop, rng)
+    return x + dropout(y, drop, rng)
 
 
 @jax.named_scope("attn")
@@ -1244,10 +1245,3 @@ def _layer_norm(x, scale, bias, eps: float = 1e-5):
     y = (x32 - mu) * jax.lax.rsqrt(var + eps)
     return (y * scale.astype(jnp.float32) +
             bias.astype(jnp.float32)).astype(dt)
-
-
-def _dropout(x, rate: float, rng):
-    if rate <= 0.0:
-        return x
-    keep = jax.random.bernoulli(rng, 1.0 - rate, x.shape)
-    return jnp.where(keep, x / (1.0 - rate), 0.0).astype(x.dtype)

@@ -25,10 +25,11 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..moe.layer import MoEConfig, init_moe_params, moe_ffn, moe_param_specs
+from ..ops.dropout import dropout
 from ..parallel.mesh import MODEL_AXIS
 from ..runtime.module import TrainModule, mark_subtrees
 from ..runtime.zero import gather_layer
-from .gpt2 import (GPT2Config, _dropout, _layer_norm, gpt2_attn_sublayer,
+from .gpt2 import (GPT2Config, _layer_norm, gpt2_attn_sublayer,
                    gpt2_ffn)
 
 
@@ -216,8 +217,8 @@ class GPT2MoEModel(TrainModule):
             raise ValueError(
                 f"sequence length {T} exceeds n_positions={cfg.n_positions}")
         x = params["wte"][tokens] + params["wpe"][:T][None]
-        x = _dropout(x, cfg.embd_dropout if train else 0.0,
-                     jax.random.fold_in(rng, 997))
+        x = dropout(x, cfg.embd_dropout if train else 0.0,
+                    jax.random.fold_in(rng, 997))
 
         mcfg = cfg.moe_cfg()
         drop = cfg.dropout if train else 0.0
@@ -227,14 +228,14 @@ class GPT2MoEModel(TrainModule):
             x = gpt2_attn_sublayer(cfg, ap, x, r_attn, train)
             h = _layer_norm(x, ap["ln2_scale"], ap["ln2_bias"])
             y = gpt2_ffn(dp, h)
-            return x + _dropout(y, drop, jax.random.fold_in(r_ffn, 1))
+            return x + dropout(y, drop, jax.random.fold_in(r_ffn, 1))
 
         def moe_block(x, ap, mp, lrng):
             r_attn, r_ffn = jax.random.split(lrng)
             x = gpt2_attn_sublayer(cfg, ap, x, r_attn, train)
             h = _layer_norm(x, ap["ln2_scale"], ap["ln2_bias"])
             y, aux = moe_ffn(mcfg, mp, h, r_ffn, train)
-            return x + _dropout(y, drop, jax.random.fold_in(r_ffn, 1)), aux
+            return x + dropout(y, drop, jax.random.fold_in(r_ffn, 1)), aux
 
         aux0 = jnp.zeros((), jnp.float32)
         if cfg.scan_groups and cfg.stream_scan:

@@ -17,7 +17,8 @@ from jax.sharding import PartitionSpec as P
 
 from ..parallel.mesh import MODEL_AXIS
 from ..pipe.module import LayerSpec, TiedLayerSpec, PipelineModule
-from .gpt2 import GPT2Config, _dropout, _layer_norm, gpt2_block_forward
+from ..ops.dropout import dropout
+from .gpt2 import GPT2Config, _layer_norm, gpt2_block_forward
 
 
 class GPT2EmbeddingPipe:
@@ -52,7 +53,7 @@ class GPT2EmbeddingPipe:
         wte = params["wte"]
         onehot = jax.nn.one_hot(tokens, wte.shape[0], dtype=wte.dtype)
         x = onehot @ wte + params["wpe"][:T][None]
-        return _dropout(x, cfg.embd_dropout if train else 0.0, rng)
+        return dropout(x, cfg.embd_dropout if train else 0.0, rng)
 
 
 class GPT2BlockPipe:

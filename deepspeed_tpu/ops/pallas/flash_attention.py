@@ -42,6 +42,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..dropout import fmix32, keep_threshold
+
 NEG_INF = -1e30
 # additive-mask drop value for boolean key masks: large enough that the
 # dropped probability underflows to 0 after the lse subtraction, finite
@@ -75,18 +77,6 @@ def _pad_seq(x, block, axis):
     return jnp.pad(x, widths)
 
 
-
-def _fmix32(x):
-    """murmur3 finalizer — a cheap, well-mixed u32→u32 bijection (not
-    cryptographic; dropout only needs decorrelation)."""
-    x = x ^ (x >> jnp.uint32(16))
-    x = x * jnp.uint32(0x85EBCA6B)
-    x = x ^ (x >> jnp.uint32(13))
-    x = x * jnp.uint32(0xC2B2AE35)
-    x = x ^ (x >> jnp.uint32(16))
-    return x
-
-
 def dropout_keep_mask(q_ids, k_ids, bh, seed, rate: float):
     """Counter-based keep mask: u32 hash of (bh, q position, k position,
     seed) compared against rate.  Pure jnp on index arrays, so the SAME
@@ -96,13 +86,7 @@ def dropout_keep_mask(q_ids, k_ids, bh, seed, rate: float):
     x = (q_ids.astype(jnp.uint32) * jnp.uint32(0x9E3779B9)
          + k_ids.astype(jnp.uint32))
     x = x ^ (jnp.uint32(bh) * jnp.uint32(0x85EBCA6B))
-    x = _fmix32(x ^ jnp.uint32(seed))
-    # round() (not int() truncation) so the realized drop probability is
-    # unbiased to the nearest 2^-32; rates within 2^-32 of 1.0 still
-    # saturate at 2^32-1 (a keep probability of exactly 0 would need a
-    # 33-bit threshold — irrelevant at practical dropout rates).
-    thresh = jnp.uint32(min(round(rate * 2.0 ** 32), 2 ** 32 - 1))
-    return x >= thresh
+    return fmix32(x ^ jnp.uint32(seed)) >= keep_threshold(rate)
 
 
 def dense_keep_mask(B, H, Tq, Tk, seed, rate: float, bh_ids=None):

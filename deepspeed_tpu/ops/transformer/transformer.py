@@ -31,6 +31,8 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
+from ..dropout import dropout
+
 
 @dataclasses.dataclass
 class DeepSpeedTransformerConfig:
@@ -90,13 +92,6 @@ def _layer_norm(x, scale, bias, eps: float = 1e-12):
     y = (x32 - mu) * jax.lax.rsqrt(var + eps)
     return (y * scale.astype(jnp.float32)
             + bias.astype(jnp.float32)).astype(dt)
-
-
-def _dropout(x, rate: float, rng):
-    if rate <= 0.0 or rng is None:
-        return x
-    keep = jax.random.bernoulli(rng, 1.0 - rate, x.shape)
-    return jnp.where(keep, x / (1.0 - rate), 0.0).astype(x.dtype)
 
 
 class DeepSpeedTransformerLayer:
@@ -220,8 +215,8 @@ class DeepSpeedTransformerLayer:
                     mask = mask[:, None]
                 scores = scores + mask
             probs = jax.nn.softmax(scores, axis=-1)
-            probs = _dropout(probs.astype(q.dtype),
-                             cfg.attn_dropout_ratio if train else 0.0, rng)
+            probs = dropout(probs.astype(q.dtype),
+                            cfg.attn_dropout_ratio if train else 0.0, rng)
             return jnp.einsum("bhqk,bhkd->bhqd", probs, v)
 
         if cfg.attn_dropout_checkpoint:
@@ -267,13 +262,13 @@ class DeepSpeedTransformerLayer:
         if cfg.pre_layer_norm:
             attn_out = self._attention(params, ln1(x), attention_mask,
                                        r_attn, train)
-            x = x + _dropout(attn_out, drop, r1)
+            x = x + dropout(attn_out, drop, r1)
             ffn_out = self._ffn(params, ln2(x))
-            return x + _dropout(ffn_out, drop, r2)
+            return x + dropout(ffn_out, drop, r2)
         # post-LN (classic BERT)
         attn_out = self._attention(params, x, attention_mask, r_attn, train)
-        x = ln1(x + _dropout(attn_out, drop, r1))
+        x = ln1(x + dropout(attn_out, drop, r1))
         ffn_out = self._ffn(params, x)
-        return ln2(x + _dropout(ffn_out, drop, r2))
+        return ln2(x + dropout(ffn_out, drop, r2))
 
     forward = __call__

@@ -40,6 +40,7 @@ from ..config import DeepSpeedConfig
 from ..config import constants as C
 from ..ops.adam import (FusedAdamState, adam_direction, adam_moments,
                         fused_adam)
+from ..ops.dropout import traced_sites
 from ..ops.lamb import fused_lamb
 from ..parallel.mesh import DATA_AXIS, build_mesh, mesh_axis_size
 from ..telemetry import tracing
@@ -1046,12 +1047,28 @@ class DeepSpeedEngine:
         # or want no placement stated at all (the pg check's reference)
         pin = plan if constrain else None
 
-        def micro_loss(p, mb, rng):
+        def forward(p, mb, rng):
             pp = cast_for_compute(p, compute_dtype, pin) if cast else p
-            loss = loss_fn(pp, mb, rng, train=True)
+            return loss_fn(pp, mb, rng, train=True)
+
+        def micro_loss(p, mb, rng):
+            loss = forward(p, mb, rng)
             return precision.scale_loss(loss.astype(jnp.float32), scaler)
 
         grad_fn = jax.value_and_grad(micro_loss)
+        if self.telemetry is not None:
+            # the forward alone, traced once more for its dropout sites
+            # (the gradient's program holds each again, recomputed and
+            # transposed); set while the step is traced
+            micro = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), batch)
+            self.telemetry.registry.gauge(
+                "train_dropout_sites",
+                "dropout sites with a rate above 0 in the traced train "
+                "step, scan bodies times their trip count; each draws "
+                "its mask from the counter hash of ops/dropout.py"
+            ).set(grad_acc * traced_sites(jax.make_jaxpr(forward)(
+                params, micro, step_rng).jaxpr), generator="hash")
 
         if keep_param_dtype and grad_acc == 1:
             mb = jax.tree.map(lambda x: x[0], batch)

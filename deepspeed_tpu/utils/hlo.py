@@ -1,13 +1,16 @@
-"""Read the collectives, and the weights a program moves before it uses
-them, out of a compiled program's text.
+"""Read the collectives, the weights a program moves before it uses them,
+and the fusions that draw random bits an element, out of a compiled
+program's text.
 
 ``compiled.as_text()`` is the program after the SPMD partitioner: what a
 placement rule (runtime/zero.py) really costs is the collectives found
 there, their result shapes, and how often the loop around them runs; what
 a parameter tree's layout costs (models/mimo_v2.py) is the fusions and
 copies that write a parameter again before a matmul reads it
-(``parameter_rewrites``).  Bytes and counts only — no time is read from a
-program's text.
+(``parameter_rewrites``); what a random draw over an activation costs is
+the threefry rounds fused into whatever reads the mask (``rng_fusions``).
+Bytes and counts only — no time is read from a program's text (a fusion's
+``estimated_cycles`` is the compiler's guess, and is reported as that).
 """
 from __future__ import annotations
 
@@ -82,11 +85,11 @@ def _computations(hlo_text: str):
     return comps, entry
 
 
-def collectives(hlo_text: str) -> List[Collective]:
-    """Every collective the program runs, with the product of the known
-    trip counts of the loops around it (a loop whose count the compiler
-    does not state counts once, and is still a loop)."""
-    comps, entry = _computations(hlo_text)
+def _reached(comps: Dict[str, list], entry: str):
+    """(computation, line, result type, op, times, in_loop) of every
+    instruction the entry computation reaches, ``times`` the product of
+    the known trip counts of the loops around it (a loop whose count the
+    compiler does not state counts once, and is still a loop)."""
 
     def trip_count(while_line: str) -> int:
         """The count the compiler states, else (the TPU's text states
@@ -99,12 +102,6 @@ def collectives(hlo_text: str) -> List[Collective]:
                   for n in _CONST.findall(line)} if cond else set()
         return bounds.pop() if len(bounds) == 1 else 1
 
-    found: List[Collective] = []
-    # the TPU's compiler splits an asynchronous collective into fusions
-    # (start, steps, done) that each repeat the instruction under its one
-    # channel: counted once
-    channels = set()
-
     def walk(name: str, times: int, in_loop: bool, seen: tuple):
         if name not in comps or name in seen:
             return
@@ -113,30 +110,42 @@ def collectives(hlo_text: str) -> List[Collective]:
             if not m:
                 continue
             type_text, op = m.groups()
-            base = op[:-len("-start")] if op.endswith("-start") else op
-            channel = _CHANNEL.search(line)
-            if base in COLLECTIVES and not (
-                    channel and (base, channel.group(1)) in channels):
-                if channel:
-                    channels.add((base, channel.group(1)))
-                arrays = _arrays(type_text)
-                if op.endswith("-start"):
-                    # (operands..., results...): the results are the
-                    # second half
-                    arrays = arrays[len(arrays) // 2:]
-                found.append(Collective(base, tuple(arrays),
-                                        _nbytes(arrays), times, in_loop))
+            yield name, line, type_text, op, times, in_loop
             callees = _CALLEE.findall(line)
             for group in _BRANCHES.findall(line):
                 callees += [c.strip().lstrip("%") for c in group.split(",")]
-            if not callees:
-                continue
             loop = op == "while"
             n = trip_count(line) if loop else 1
             for callee in callees:
-                walk(callee, times * n, in_loop or loop, seen + (name,))
+                yield from walk(callee, times * n, in_loop or loop,
+                                seen + (name,))
 
-    walk(entry, 1, False, ())
+    return walk(entry, 1, False, ())
+
+
+def collectives(hlo_text: str) -> List[Collective]:
+    """Every collective the program runs, with the product of the known
+    trip counts of the loops around it."""
+    found: List[Collective] = []
+    # the TPU's compiler splits an asynchronous collective into fusions
+    # (start, steps, done) that each repeat the instruction under its one
+    # channel: counted once
+    channels = set()
+    for _, line, type_text, op, times, in_loop in _reached(
+            *_computations(hlo_text)):
+        base = op[:-len("-start")] if op.endswith("-start") else op
+        channel = _CHANNEL.search(line)
+        if base not in COLLECTIVES or (
+                channel and (base, channel.group(1)) in channels):
+            continue
+        if channel:
+            channels.add((base, channel.group(1)))
+        arrays = _arrays(type_text)
+        if op.endswith("-start"):
+            # (operands..., results...): the results are the second half
+            arrays = arrays[len(arrays) // 2:]
+        found.append(Collective(base, tuple(arrays), _nbytes(arrays),
+                                times, in_loop))
     return found
 
 
@@ -201,4 +210,64 @@ def parameter_rewrites(hlo_text: str, parameters: int,
             number, size = held.get(operand, (None, 0))
             if number is not None and share * size <= wrote <= size:
                 found.append(Rewrite(name, op, number, wrote, in_hbm))
+    return found
+
+
+class RngFusion(NamedTuple):
+    instruction: str   # the fusion, by its name where it is called
+    elements: int      # of the largest u32 array the generator's body fills
+    times: int         # executions a call of the program (loop trip counts)
+    cycles: object     # the compiler's ``estimated_cycles`` of one
+    #                    execution, None where the text states none
+
+
+_CYCLES = re.compile(r'"estimated_cycles":"(\d+)"')
+
+
+def rng_fusions(hlo_text: str, elements: int = 1 << 20,
+                rounds: int = 20) -> List[RngFusion]:
+    """Every fusion that draws random bits an element: its computation
+    (with the fusions nested in it) holds ``rounds`` or more
+    ``shift-right-logical`` and as many ``xor`` on u32 arrays of
+    ``elements`` or more, which is threefry2x32's twenty rotations
+    (``jax.random.bits`` / ``bernoulli`` / ``uniform`` over an activation)
+    and not a counter hash's three shifts a site (``ops/dropout.py``).
+    The key derivations of a step (``fold_in``, ``split``: threefry over a
+    handful of words) are far under ``elements``."""
+    comps, entry = _computations(hlo_text)
+    # (where it is called, its line, times, the computation it calls)
+    fusions = [(name, line, times, _CALLEE.search(line).group(1))
+               for name, line, _, op, times, _ in _reached(comps, entry)
+               if op == "fusion" and _CALLEE.search(line)]
+    fused = {callee for *_, callee in fusions}
+
+    def filled(computation: str) -> dict:
+        """Sizes of the large u32 results of each of the two operations,
+        through nested fusions."""
+        sizes = {"shift-right-logical": [], "xor": []}
+        for line in comps.get(computation, ()):
+            m = _INSTR.match(line)
+            if not m:
+                continue
+            if m.group(2) in sizes:
+                sizes[m.group(2)] += [
+                    math.prod(dims) for dt, dims in _arrays(m.group(1))
+                    if dt == "u32" and math.prod(dims) >= elements]
+            elif m.group(2) == "fusion" and _CALLEE.search(line):
+                for op, inner in filled(
+                        _CALLEE.search(line).group(1)).items():
+                    sizes[op] += inner
+        return sizes
+
+    found = []
+    for name, line, times, callee in fusions:
+        if name in fused:
+            continue    # nested: counted with the fusion that holds it
+        sizes = filled(callee)
+        if min(map(len, sizes.values())) >= rounds:
+            cycles = _CYCLES.search(line)
+            found.append(RngFusion(
+                _NAMED.match(line).group(1),
+                max(sizes["shift-right-logical"]), times,
+                int(cycles.group(1)) if cycles else None))
     return found

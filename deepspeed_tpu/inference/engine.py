@@ -218,15 +218,20 @@ def _refuse_unsupported(model, serving) -> None:
             + "; ".join(bad) + ": its serving steps have no such arm"
             + (" (it keeps request state by slot, 'serving_state': a page "
                "of keys without the state at its boundary is no prefix, "
-               "and a chunk, a parked session or a drafted token needs a "
-               "snapshot of it)" if stateful else ""))
+               "and a parked session or a drafted token needs a snapshot "
+               "of it; chunks of a prompt need none, if the model's "
+               "prefill reads what the chunk before left in the slot)"
+               if stateful else ""))
 
 
 #: what the engine cannot do yet for a model that keeps request state by
 #: slot (``serving_state``), whatever the model's own steps have: each
-#: needs the state saved at a boundary, which nothing does
-_STATE_UNSUPPORTED = ("prefix_cache", "prefill_chunk_len", "kv_tier",
-                      "slot_cache", "speculate_k")
+#: needs the state saved at a boundary, which nothing does.  Not a chunked
+#: prefill: the chunks of ONE request run in order into ONE slot, and the
+#: slot's state after a chunk is what the next one needs; a model whose
+#: prefill takes no prefix says so itself (``serving_unsupported``)
+_STATE_UNSUPPORTED = ("prefix_cache", "kv_tier", "slot_cache",
+                      "speculate_k")
 
 
 def _percentile(sorted_vals: List[float], q: float) -> Optional[float]:
@@ -318,10 +323,16 @@ class ServeEngine:
       state, new_lengths)``), donated with the rest of the cache.  A
       prefill OVERWRITES the state of the slot it is told (the slot the
       request is admitted to); a decode tick leaves an inactive slot's
-      state alone; no program clears state.  It is never paged, shared
-      or migrated: ``prefix_cache``, ``prefill_chunk_len``, ``kv_tier``,
-      the slot cache, ``speculate_k``, ``export_pages`` and
-      ``adopt_request`` are refused for such a model.
+      state alone (a free slot's, and one still prefilling in chunks); no
+      program clears state.  It is never paged, shared or migrated:
+      ``prefix_cache``, ``kv_tier``, the slot cache, ``speculate_k``,
+      ``export_pages`` and ``adopt_request`` are refused for such a
+      model.  Chunked prefill (``prefill_chunk_len``) is the model's to
+      take or refuse: the chunks of a request run in order into its slot
+      (``_prefill_chunk_tick``), each handed ``prefix_len`` (the tokens
+      the chunks before it covered), ``state=`` and ``slot=``, and a
+      model that takes them reads from the slot's state and the
+      request's pages what the chunk before left there.
 
     A model names the arms it lacks in ``serving_unsupported`` (of
     ``'slot_cache'``, ``'speculate_k'``, ``'quantization'``, ``'lora'``,
@@ -343,8 +354,9 @@ class ServeEngine:
         self.telemetry = None
         #: the engine's own set-up by phase, (phase, started, seconds)
         #: each, from any thread (gauge ``serve_setup_seconds{phase=}``):
-        #: ``params``, ``cache``, ``draft``, ``feed``, then for each rung
-        #: of the prefill ladder ``lower:<rung>`` and ``compile:<rung>``
+        #: ``params``, ``cache``, ``copy_page``, ``draft``, ``feed``, then
+        #: for each rung of the prefill ladder ``lower:<rung>`` and
+        #: ``compile:<rung>``
         #: (its executable's load or compile) on the ladder's thread,
         #: ``rungs_wait`` (the first prefill call waiting for that
         #: thread) and ``first_call:<program>[:<rung>]`` (a program's
@@ -505,6 +517,9 @@ class ServeEngine:
         #: ``serve_prefill_pad_tokens_total``)
         self.prefill_pad_tokens = 0
         self.prefill_tokens = 0
+        #: rung -> prefill calls that were one chunk of a longer prompt
+        #: (counter ``serve_prefill_chunks_total{bucket=}``)
+        self.prefill_chunk_calls = {r: 0 for r in self.prefill_buckets}
         #: the rungs' executables on their way (``_build_prefill_rungs``);
         #: None for a ladder of one rung, which is the jitted program
         self._prefill_build: Optional[Future] = None
@@ -519,6 +534,14 @@ class ServeEngine:
         #: co-scheduled with decode ticks (config requires paged)
         self.prefill_chunk_len = (cfg.serving.prefill_chunk_len
                                   if self.paged else 0)
+        #: the longest prompt ``submit`` takes: what one prefill program
+        #: holds; or, where every chunk fits one (and no draft mirrors the
+        #: whole prompt), whatever leaves room for a token: a prompt longer
+        #: than any prefill program is prefilled in chunks
+        self.max_prompt_len = (
+            self.max_seq_len - 1
+            if 0 < self.prefill_chunk_len <= self.prefill_len
+            and not self.spec_k else self.prefill_len)
         if self.quant_kv and not self.paged:
             raise ValueError(
                 "serving.quantization.kv='int8' requires a paged cache "
@@ -835,6 +858,13 @@ class ServeEngine:
             self._copy_fn = jax.jit(
                 serve_copy_page, donate_argnums=(0,),
                 out_shardings=self._cache_shardings)
+            if self.prefix is not None:
+                # only a prefix hit that ends inside a page copies one, and
+                # no warm-up is sure to bring one: compile it where nothing
+                # is being timed (the scratch page onto itself)
+                with self._setup("copy_page"), self._pallas_scope():
+                    self.cache = self._copy_fn(self.cache, np.int32(0),
+                                               np.int32(0))
 
             # KV-page export/import (disaggregated fleet, docs/
             # serving.md): one page's pool rows out to the host / back
@@ -1079,6 +1109,10 @@ class ServeEngine:
                 "serve_prefills_total",
                 "prefill calls by the rung of the prefill ladder "
                 "(tokens of the program) they ran")
+            self._prefill_chunks_ctr = reg.counter(
+                "serve_prefill_chunks_total",
+                "of those, the calls that were one chunk of a prompt "
+                "longer than serving.prefill_chunk_len, by rung")
             layers = getattr(self.model, "serving_cache_layers", None)
             if layers is not None:
                 layer_gauge = reg.gauge(
@@ -1761,11 +1795,14 @@ class ServeEngine:
         prompt = [int(t) for t in np.asarray(prompt).reshape(-1)]
         if not prompt:
             raise ValueError("empty prompt")
-        if len(prompt) > self.prefill_len:
+        if len(prompt) > self.max_prompt_len:
             raise ValueError(
                 f"prompt length {len(prompt)} exceeds the static "
                 f"serving.prefill_len bucket ({self.prefill_len}); "
-                "raise the bucket or truncate the prompt")
+                "raise the bucket or truncate the prompt"
+                if self.max_prompt_len == self.prefill_len else
+                f"prompt length {len(prompt)} leaves no room for a token "
+                f"under serving.max_seq_len ({self.max_seq_len})")
         if max_new_tokens < 1:
             raise ValueError("max_new_tokens must be >= 1")
         if self.paged:
@@ -2379,7 +2416,11 @@ class ServeEngine:
                       if self.lora else ()),
                     *self._maybe_key())
             first = self._await_first(req, rec, first)
+            rec.update(chunk_pos=pos, final_chunk=final)
             self._file("prefill", rec, aux)
+        self.prefill_chunk_calls[tokens.shape[1]] += 1
+        if self.telemetry is not None:
+            self._prefill_chunks_ctr.inc(bucket=str(tokens.shape[1]))
         req.chunk_pos = pos + len(chunk)
         req.kv_len = req.shared_len + req.chunk_pos
         if not final:

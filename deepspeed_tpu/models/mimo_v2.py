@@ -255,12 +255,13 @@ def _layers(cfg: MimoV2Config):
 
 
 class MimoV2Model:
-    #: the engine refuses these for any model with ``serving_state``
-    #: (prefix cache, chunked prefill, KV tiering, migration): a page of
-    #: full-layer keys is no prefix without the window layers' last keys
-    #: at its boundary; the rest are arms these paged steps do not have
+    #: the engine refuses the prefix cache, KV tiering and migration for
+    #: any model with ``serving_state``: a page of full-layer keys is no
+    #: prefix without the window layers' last keys at its boundary; the
+    #: rest are arms these paged steps do not have (chunked prefill: the
+    #: prefill takes no prefix, ``models/cohere2_moe.py``'s does)
     serving_unsupported = ("slot_cache", "speculate_k", "quantization",
-                           "lora")
+                           "lora", "prefill_chunk_len")
     serving_aux = ("moe_experts_hit", "moe_load_imbalance", "moe_rows",
                    "moe_rows_elsewhere", "full_kv_tokens", "window_kv_rows")
 

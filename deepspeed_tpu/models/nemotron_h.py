@@ -198,11 +198,12 @@ class NemotronHConfig:
 
 
 class NemotronHModel:
-    #: the engine refuses these for any model with ``serving_state``
-    #: (prefix cache, chunked prefill, KV tiering, migration); the rest
-    #: are arms these paged steps do not have
+    #: the engine refuses the prefix cache, KV tiering and migration for
+    #: any model with ``serving_state``; the rest are arms these paged
+    #: steps do not have (chunked prefill: the prefill takes no prefix,
+    #: and the scan would have to start from the slot's state)
     serving_unsupported = ("slot_cache", "speculate_k", "quantization",
-                           "lora")
+                           "lora", "prefill_chunk_len")
     serving_aux = ("moe_experts_hit", "moe_load_imbalance", "moe_rows",
                    "moe_rows_elsewhere")
 

@@ -276,13 +276,18 @@ def _slot_decode_pallas(q, k, v, lengths, sink, base, *, sm_scale, block_k,
     pos = np.full((hp, Hkv * block_k), 2 ** 30, np.int32)
     pos[:Hq] = _grouped_block_positions(Hq, Hkv, block_k, 1)
     operands = [qf, jnp.asarray(pos), kf, vf]
+
+    def kv_im(s, j, ln, b):
+        # a block past the live rows repeats the last live one: it is
+        # neither computed nor fetched
+        return (b[0] + s, 0,
+                jnp.minimum(j, jnp.maximum(ln[s] - 1, 0) // block_k), 0)
+
     in_specs = [
         pl.BlockSpec((1, hp, Dk), lambda s, j, *_: (s, 0, 0)),
         pl.BlockSpec(pos.shape, lambda s, j, *_: (0, 0)),
-        pl.BlockSpec((1, Hkv, block_k, Dk),
-                     lambda s, j, ln, b: (b[0] + s, 0, j, 0)),
-        pl.BlockSpec((1, Hkv, block_k, Dv),
-                     lambda s, j, ln, b: (b[0] + s, 0, j, 0)),
+        pl.BlockSpec((1, Hkv, block_k, Dk), kv_im),
+        pl.BlockSpec((1, Hkv, block_k, Dv), kv_im),
     ]
     if sink is not None:
         tile = jnp.pad(sink.astype(jnp.float32), (0, hp - Hq))
@@ -389,6 +394,21 @@ def decode_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                                   interpret=interpret)
 
 
+def window_ring_block(kv_heads: int, rows: int, width: int,
+                      itemsize: int) -> int:
+    """Rows of every key head a grid step of
+    :func:`window_decode_attention` takes: the whole ring where its key
+    and value blocks, double-buffered, fit ``PAGED_KV_VMEM_BUDGET`` (128
+    keys on 8 heads of 256 + 128: 1.5 MiB); else the largest power-of-two
+    share of it that does (4,096 keys on 8 heads of 128 + 128 would be
+    32 MiB: 512 rows, 4 MiB)."""
+    block = rows
+    while (2 * kv_heads * block * width * itemsize > PAGED_KV_VMEM_BUDGET
+           and block % 2 == 0):
+        block //= 2
+    return block
+
+
 def window_decode_attention(q, k_ring, v_ring, lengths, sink, *, base=0,
                             sm_scale: Optional[float] = None,
                             impl: str = "pallas",
@@ -397,12 +417,20 @@ def window_decode_attention(q, k_ring, v_ring, lengths, sink, *, base=0,
     each slot's last ``T`` keys (``k_ring`` [X, Hkv, T, Dk], ``v_ring``
     [X, Hkv, T, Dv]; position ``p`` at row ``p % T``), the new key
     already written.  ``lengths`` [S] counts the keys so far, itself
-    included: ``min(lengths, T)`` rows are live.  ``sink`` [Hq]."""
-    T = k_ring.shape[2]
+    included: ``min(lengths, T)`` rows are live.  ``sink`` [Hq] or None.
+
+    :func:`decode_attention_slots`' body under the window kernel's name:
+    a whole ring a grid step where that fits, else the ring walked in
+    blocks of :func:`window_ring_block` rows with the online softmax, the
+    blocks past a ring's live rows (one that has not wrapped yet) neither
+    computed nor fetched."""
+    X, Hkv, T, Dk = k_ring.shape
     return decode_attention_slots(
         q, k_ring, v_ring, jnp.minimum(lengths.astype(jnp.int32), T),
-        sink=sink, base=base, sm_scale=sm_scale, block_k=T, impl=impl,
-        interpret=interpret, name=WINDOW_DECODE_ATTN_KERNEL)
+        sink=sink, base=base, sm_scale=sm_scale,
+        block_k=window_ring_block(Hkv, T, Dk + v_ring.shape[-1],
+                                  k_ring.dtype.itemsize),
+        impl=impl, interpret=interpret, name=WINDOW_DECODE_ATTN_KERNEL)
 
 
 # ---------------------------------------------------------------------------

@@ -787,12 +787,134 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
 
 
+FLASH_FWD_CTX_KERNEL = "ds_flash_fwd_ctx"
+
+
+def _ctx_first_block(iq, live, *, block_q, block_k, ctx, window):
+    """First key block a query block reads of keys ``[ctx + Tq]``: the
+    later of the first live context block and the band's first."""
+    first = (ctx - live) // block_k
+    if window is not None:
+        first = jnp.maximum(
+            first, jnp.maximum(ctx + iq * block_q - (window - 1), 0)
+            // block_k)
+    return first
+
+
+def _fwd_ctx_kernel(live_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr,
+                    acc_scr, *, sm_scale: float, block_q: int, block_k: int,
+                    ctx: int, seq_len: int, window: Optional[int]):
+    """The serving forward over keys that lie BEFORE the queries: key
+    index ``kk`` of ``[ctx + Tq]``, query row ``t`` at key index ``ctx +
+    t``.  Of the ``ctx`` context keys the LAST ``live_ref[0]`` are live
+    (right-aligned, so that index is position up to a constant and the
+    causal and band masks are :func:`_masked_scores`' shifted by ``ctx``).
+    The grid's key axis starts at the first block the masks let through
+    (:func:`_ctx_first_block`); steps past the causal diagonal run
+    nothing and, their block index clamped, fetch nothing."""
+    iq, j = pl.program_id(1), pl.program_id(2)
+    nk = pl.num_programs(2)
+    live = live_ref[0]
+    ik = _ctx_first_block(iq, live, block_q=block_q, block_k=block_k,
+                          ctx=ctx, window=window) + j
+    q_last = ctx + iq * block_q + block_q - 1
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(ik * block_k <= q_last)
+    def _compute():
+        v = v_ref[0]
+        s = jax.lax.dot_general(
+            q_ref[0], k_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale
+        shape = (block_q, block_k)
+        kk = jax.lax.broadcasted_iota(jnp.int32, shape, 1) + ik * block_k
+        qq = jax.lax.broadcasted_iota(jnp.int32, shape, 0) \
+            + (ctx + iq * block_q)
+        valid = (kk <= qq) & (kk >= ctx - live) & (kk < seq_len)
+        if window is not None:
+            valid &= kk > qq - window
+        s = jnp.where(valid, s, NEG_INF)
+        m_prev = m_scr[:, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[:] = jnp.broadcast_to(
+            alpha * l_scr[:, 0:1] + jnp.sum(p, axis=1, keepdims=True),
+            l_scr.shape)
+        acc_scr[:] = acc_scr[:] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+
+    @pl.when(j == nk - 1)
+    def _finalize():
+        # a query sees its own key at least: l > 0 on every real row
+        l = l_scr[:, 0:1]
+        o_ref[0] = (acc_scr[:] / jnp.where(l == 0.0, 1.0, l)
+                    ).astype(o_ref.dtype)
+
+
+def _fwd_ctx(q, k, v, ctx_live, *, heads, ctx, sm_scale, block_q, block_k,
+             window, interpret):
+    """q [B*Hq, Tq, D] over k [B*Hkv, ctx + Tq, D], v [.., Dv]."""
+    bh, t, d = q.shape
+    dv = v.shape[-1]
+    hq, hkv = heads
+    block_q = min(block_q, max(t, 8))
+    block_k = min(block_k, ctx)
+    assert ctx % block_k == 0, (ctx, block_k)
+    qp, kp, vp = _pad_seq(q, block_q, 1), _pad_seq(k, block_k, 1), \
+        _pad_seq(v, block_k, 1)
+    nq = qp.shape[1] // block_q
+    last = [(ctx + i * block_q + block_q - 1) // block_k for i in range(nq)]
+    band = [max(ctx + i * block_q - (window - 1), 0) // block_k
+            if window is not None else 0 for i in range(nq)]
+    nk = max(b - a for a, b in zip(band, last)) + 1
+    geometry = dict(block_q=block_q, block_k=block_k, ctx=ctx, window=window)
+
+    def kv_im(b, i, j, live):
+        block = jnp.minimum(
+            _ctx_first_block(i, live[0], **geometry) + j,
+            (ctx + i * block_q + block_q - 1) // block_k)
+        return ((b // hq) * hkv + (b % hq) // (hq // hkv), block, 0)
+
+    out = pl.pallas_call(
+        functools.partial(_fwd_ctx_kernel, sm_scale=sm_scale,
+                          seq_len=ctx + t, **geometry),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bh, nq, nk),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), lambda b, i, j, live: (b, i, 0)),
+                pl.BlockSpec((1, block_k, d), kv_im),
+                pl.BlockSpec((1, block_k, dv), kv_im),
+            ],
+            out_specs=pl.BlockSpec((1, block_q, dv),
+                                   lambda b, i, j, live: (b, i, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((block_q, 128), jnp.float32),
+                pltpu.VMEM((block_q, 128), jnp.float32),
+                pltpu.VMEM((block_q, dv), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((bh, qp.shape[1], dv), q.dtype),
+        interpret=interpret,
+        name=FLASH_FWD_CTX_KERNEL,
+    )(jnp.reshape(ctx_live, (1,)).astype(jnp.int32), qp, kp, vp)
+    return out[:, :t]
+
+
 def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                         window: Optional[int] = None,
                         sink: Optional[jnp.ndarray] = None,
                         sm_scale: Optional[float] = None,
                         block_q: int = 512, block_k: int = 512,
-                        interpret: Optional[bool] = None) -> jnp.ndarray:
+                        interpret: Optional[bool] = None,
+                        ctx_live=None) -> jnp.ndarray:
     """The causal forward kernel for a serving prefill (no backward: a
     prefill never backpropagates), with what the training call has not:
 
@@ -803,17 +925,34 @@ def flash_attention_fwd(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     * ``window``: query ``t`` sees keys ``t - window < j <= t``; the
       grid's key axis spans the band's blocks only;
     * ``sink`` ``[Hq]``: a head's learned logit, one more column of the
-      softmax that takes weight and gives no value.
+      softmax that takes weight and gives no value;
+    * context keys (a chunk of a prompt after its first): k ``[B, Hkv,
+      Tc + T, Dk]``, v alike, the queries at key positions ``Tc ..``;
+      ``ctx_live`` (traced) of the ``Tc`` keys ahead are live, the LAST
+      so many (right-aligned: a key's index is its position less a
+      constant), and the causal and band masks are shifted by ``Tc``;
+      blocks wholly outside them are not visited.  ``Tc`` a multiple of
+      ``block_k``; no sink.  ``Tc == 0`` is the call without.
     """
     b, hq, t, d = q.shape
     hkv, dv = k.shape[1], v.shape[-1]
-    assert k.shape == (b, hkv, t, d) and v.shape == (b, hkv, t, dv), (
-        q.shape, k.shape, v.shape)
+    ctx = k.shape[2] - t
+    assert k.shape == (b, hkv, ctx + t, d) and ctx >= 0 \
+        and v.shape == (b, hkv, ctx + t, dv), (q.shape, k.shape, v.shape)
     assert hq % hkv == 0, (hq, hkv)
     if sm_scale is None:
         sm_scale = float(d) ** -0.5
     if interpret is None:
         interpret = _use_interpret()
+    if ctx:
+        assert sink is None and ctx_live is not None
+        out = _fwd_ctx(q.reshape(b * hq, t, d),
+                       k.reshape(b * hkv, ctx + t, d),
+                       v.reshape(b * hkv, ctx + t, dv), ctx_live,
+                       heads=(hq, hkv), ctx=ctx, sm_scale=sm_scale,
+                       block_q=block_q, block_k=block_k, window=window,
+                       interpret=interpret)
+        return out.reshape(b, hq, t, dv)
     zero = jnp.zeros((), jnp.uint32)
     out, _ = _fwd(q.reshape(b * hq, t, d), k.reshape(b * hkv, t, d),
                   v.reshape(b * hkv, t, dv), zero, zero, None,

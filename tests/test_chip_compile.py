@@ -1019,6 +1019,186 @@ def test_axk1_lower_prefill_rung_compiles_under_the_top_rungs_peak(one_chip):
     assert rung.temp_size_in_bytes < top.temp_size_in_bytes
 
 
+# ---------------------------------------------------------------------------
+# Command A+ at its cell's sizes (benchmark/configs/command-a-plus-05-2026
+# .json: one period of three window layers and a full one, 8 of 128 experts
+# held at 4,096 x 4,096, 96 slots of rings of 4,096 keys, 12,289 pages of 8
+# key heads): the ring walked in blocks, the chunk's attention over keys
+# ahead of it, the expert kernels in blocks, and both serve programs
+# ---------------------------------------------------------------------------
+
+CMDA_SLOTS, CMDA_PAGE_LEN, CMDA_MAX_PAGES, CMDA_WINDOW = 96, 64, 320, 4096
+
+
+@functools.lru_cache(maxsize=None)
+def _cmda_model():
+    import json
+    from deepspeed_tpu.models.cohere2_moe import (Cohere2MoeConfig,
+                                                  Cohere2MoeModel)
+    with open(os.path.join(os.path.dirname(__file__), "..", "benchmark",
+                           "configs", "command-a-plus-05-2026.json")) as f:
+        file = json.load(f)
+    fields = {f.name for f in dataclasses.fields(Cohere2MoeConfig)}
+    m = {k: v for k, v in file.items() if k in fields}
+    m["num_experts"] = file["published"]["num_experts"]
+    m["experts_held"] = tuple(file["experts_held"])
+    return Cohere2MoeModel(Cohere2MoeConfig(
+        **m, param_dtype=file["dtype"])), file
+
+
+def test_window_decode_walks_rings_of_4096_keys_where_they_lie(one_chip):
+    """128 query heads on 8 key heads over rings of 4,096 keys: 512 rows
+    of every key head a grid step (4 MiB of keys and values in flight), no
+    layer's slots sliced out of the 3.2 GB of rings."""
+    from deepspeed_tpu.ops.pallas.decode_attention import (
+        WINDOW_DECODE_ATTN_KERNEL, window_decode_attention)
+    s = CMDA_SLOTS
+    compiled = _compile(
+        lambda q, k, v, n, base: window_decode_attention(
+            q, k, v, n, None, base=base, interpret=False),
+        one_chip, _sds((s, 128, 128)), _sds((3 * s, 8, CMDA_WINDOW, 128)),
+        _sds((3 * s, 8, CMDA_WINDOW, 128)), _sds((s,), jnp.int32),
+        _sds((), jnp.int32))
+    names = _kernel_names(compiled)
+    assert [n.split(".")[0] for n in names] == [WINDOW_DECODE_ATTN_KERNEL]
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 20
+
+
+@pytest.mark.parametrize("ctx,window", [(CMDA_WINDOW, CMDA_WINDOW),
+                                        (CMDA_MAX_PAGES * CMDA_PAGE_LEN,
+                                         None)], ids=["ring", "pages"])
+def test_flash_forward_over_context_keys_compiles_at_128_on_8(ctx, window,
+                                                              one_chip):
+    """A chunk of 4,096 queries on 128 heads over ``[context ; chunk]``
+    keys on 8: a window layer's ring of 4,096 ahead, the full layer's
+    20,480 gathered positions; the live count is traced."""
+    from deepspeed_tpu.ops.pallas.flash_attention import (
+        FLASH_FWD_CTX_KERNEL, flash_attention_fwd)
+    compiled = _compile(
+        lambda q, k, v, n: flash_attention_fwd(
+            q, k, v, window=window, ctx_live=n, interpret=False),
+        one_chip, _sds((1, 128, 4096, 128)), _sds((1, 8, ctx + 4096, 128)),
+        _sds((1, 8, ctx + 4096, 128)), _sds((), jnp.int32))
+    assert [n.split(".")[0] for n in _kernel_names(compiled)] \
+        == [FLASH_FWD_CTX_KERNEL]
+
+
+@pytest.mark.parametrize("tokens", [CMDA_SLOTS, 2048],
+                         ids=["decode_tick", "prefill_rung"])
+def test_moe_kernels_walk_an_expert_in_blocks_at_4096_by_4096(tokens,
+                                                              one_chip):
+    """8 held of 128 experts of 4,096 x 4,096, top-8: both up-projections
+    whole would be 128 MiB in flight, the whole VMEM; the kernel walks
+    them in two blocks of 2,048 columns (64 MiB + 16 for the rows), the
+    down-projection whole (64 MiB + 16).  The compile is the proof that
+    the chip allows both."""
+    from deepspeed_tpu.moe import dropless
+    d = f = 4096
+    held = 8
+    weights = [_sds((4 * held, d, f))] * 2
+    assert dropless.weight_blocks(weights, f) == 2
+    assert dropless._vmem_limit(weights, 2) == 80 << 20
+    assert dropless.weight_blocks(weights[:1], d) == 1
+    assert dropless._vmem_limit(weights[:1], 1) == 80 << 20
+    compiled = _compile(
+        lambda x, r, g, u, w: dropless.dropless_moe(
+            x, r, g, u, w, 8, expert_offset=jnp.int32(held),
+            experts_held=(0, held), interpret=False)[0],
+        one_chip, _sds((tokens, d)), _sds((d, 128)), *weights,
+        _sds((4 * held, f, d)))
+    names = sorted(n.split(".")[0] for n in _kernel_names(compiled))
+    assert names == [dropless.MOE_DOWN_KERNEL, dropless.MOE_GATE_UP_KERNEL]
+
+
+@functools.lru_cache(maxsize=None)
+def _cmda_program(program, one_chip, bucket=4096):
+    """The model's paged step as the engine calls it: pool and rings
+    donated; a prefill (or a chunk of one) at ``bucket`` tokens with its
+    prefix length TRACED, so both forms of its attention (from nothing;
+    over the ring and the pages the chunk before left) are in it."""
+    model, file = _cmda_model()
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    pool = _sds((1, file["serving"]["pages"], 8, CMDA_PAGE_LEN, 128))
+    state = model.serving_state(CMDA_SLOTS)
+    i32, s = _sds((), jnp.int32), CMDA_SLOTS
+    if program == "serve_decode":
+        def fn(p, t, k, v, tab, ln, act, st):
+            return model.decode_step_paged(p, t, k, v, tab, ln, act,
+                                           state=st, impl="pallas", aux=True)
+        shapes = (params, _sds((s,), jnp.int32), pool, pool,
+                  _sds((s, CMDA_MAX_PAGES), jnp.int32), _sds((s,), jnp.int32),
+                  _sds((s,), jnp.bool_), state)
+        donate = (2, 3, 7)
+    else:
+        def fn(p, t, n, pre, row, k, v, st, slot):
+            return model.prefill_paged(p, t, n, pre, row, k, v, state=st,
+                                       slot=slot, aux=True)
+        shapes = (params, _sds((1, bucket), jnp.int32), i32, i32,
+                  _sds((CMDA_MAX_PAGES,), jnp.int32), pool, pool, state, i32)
+        donate = (5, 6, 7)
+    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), shapes)
+    with interpret_scope(False):
+        return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+
+
+@pytest.mark.parametrize("program", ["serve_decode", "serve_prefill"])
+def test_command_a_plus_programs_hold_their_kernels_and_no_copy_of_a_cache(
+        program, one_chip):
+    """Every Mosaic call of both serve programs starts ``ds_``; the pool
+    (3.22 GB) and the rings (4.83 GB) pass through aliased to the
+    outputs and no program copies a layer of them (a static slice of a
+    ring leaf did: 0.77 GB a window layer); the compiler's own counts are
+    the ones the configuration's ``reduced_why`` states; all the chip
+    must hold at once fits its 16.91e9 bytes."""
+    from deepspeed_tpu.moe import dropless
+    from deepspeed_tpu.ops.pallas.decode_attention import \
+        WINDOW_DECODE_ATTN_KERNEL
+    compiled = _cmda_program(program, one_chip)
+    _, file = _cmda_model()
+    names = {n.split(".")[0] for n in _kernel_names(compiled)}
+    experts = {dropless.MOE_GATE_UP_KERNEL, dropless.MOE_DOWN_KERNEL}
+    assert names == experts | (
+        {WINDOW_DECODE_ATTN_KERNEL, PAGED_DECODE_ATTN_KERNEL}
+        if program == "serve_decode"
+        else {"ds_flash_fwd", "ds_flash_fwd_ctx"}), names
+    mem = compiled.memory_analysis()
+    pools = 2 * file["serving"]["pages"] * 8 * CMDA_PAGE_LEN * 128 * 2
+    rings = 2 * 3 * CMDA_SLOTS * 8 * CMDA_WINDOW * 128 * 2
+    assert mem.alias_size_in_bytes >= pools + rings
+    limit = 0.2e9 if program == "serve_decode" else 1.2e9
+    assert mem.temp_size_in_bytes < limit, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.6e9
+    said = {"serve_decode": "temporaries %.3f GB (decode)",
+            "serve_prefill": "%.3f GB (a chunk of 4,096"}[program]
+    assert "arguments %.3f GB" % (mem.argument_size_in_bytes / 1e9) \
+        in file["reduced_why"]
+    assert said % (mem.temp_size_in_bytes / 1e9) in file["reduced_why"]
+
+
+def test_command_a_plus_tick_reads_each_layers_matrices_where_they_lie(
+        one_chip):
+    """A leaf a layer (``models/mimo_v2.py``'s rule), the experts alone
+    stacked: no fusion of the tick's entry computation writes a weight
+    again; what is copied is a matrix's one read into the layout its dot
+    takes."""
+    from deepspeed_tpu.utils.hlo import parameter_rewrites
+    compiled = _cmda_program("serve_decode", one_chip)
+    weights = len(jax.tree.leaves(compiled.in_avals[0][0]))
+    assert weights == 2 + 4 * 9 + 3
+    moved = [r for r in parameter_rewrites(compiled.as_text(), weights)
+             if r.bytes >= 1 << 20]
+    assert [r for r in moved if r.op != "copy" or r.hbm_bytes] == [], moved
+
+
+def test_command_a_plus_lower_prefill_rung_compiles_under_the_top_rungs_peak(
+        one_chip):
+    top = _cmda_program("serve_prefill", one_chip).memory_analysis()
+    rung = _cmda_program("serve_prefill", one_chip, 2048).memory_analysis()
+    assert rung.alias_size_in_bytes == top.alias_size_in_bytes
+    assert rung.temp_size_in_bytes < top.temp_size_in_bytes
+
+
 @pytest.mark.parametrize("model", ["gpt2", "bert"])
 def test_training_flash_calls_are_what_they_were(model):
     """A window, a sink, grouped keys and a second width were added to

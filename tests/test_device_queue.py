@@ -435,6 +435,28 @@ def test_setup_log_stamps_construction_the_ladder_and_first_calls(tmp_path):
             "serve/prefill_wait", "serve/prefill_run"} <= set(names)
 
 
+def test_setup_compiles_the_page_copy_a_prefix_hit_can_bring():
+    """With the prefix cache on, construction runs ``serve_copy_page`` once
+    (phase ``copy_page``): the first copy-on-write of a timed window finds
+    it compiled.  Without the cache nothing copies a page and nothing is
+    compiled for it."""
+    model = GPT2Model(GPT2Config(vocab_size=128, n_positions=256,
+                                 d_model=32, n_layer=1, n_head=4,
+                                 remat=None, attn_impl="dense"))
+    for prefix_cache, want in ((True, 1), (False, 0)):
+        eng = ServeEngine(model, {"serving": {
+            "slots": 2, "page_len": 16, "max_seq_len": 256,
+            "prefill_len": 64, "prefix_cache": prefix_cache}})
+        try:
+            phases = [phase for phase, _, _ in eng.setup_log]
+            assert phases.count("copy_page") == want
+            assert eng._copy_fn._cache_size() == want
+            eng._copy_page(0, 0)
+            assert eng._copy_fn._cache_size() == 1
+        finally:
+            eng.close()
+
+
 def test_a_refused_configuration_leaves_no_hub_open(tmp_path):
     with pytest.raises(ValueError, match="exceeds"):
         ServeEngine(_gpt2(), {

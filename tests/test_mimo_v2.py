@@ -313,6 +313,137 @@ def test_a_window_layers_flash_grid_spans_the_band_only(monkeypatch):
     assert seen == [(2, 16, 2), (2, 16, 16)]
 
 
+@pytest.mark.parametrize("tc,live,window,bq,bk", [
+    (64, 64, None, 32, 32), (64, 40, None, 32, 32), (64, 0, None, 32, 32),
+    (64, 64, 64, 32, 32), (64, 64, 48, 16, 32), (64, 23, 64, 32, 16),
+    (128, 100, 20, 64, 64), (32, 32, 200, 64, 32)])
+def test_flash_forward_over_keys_ahead_of_its_queries(tc, live, window, bq,
+                                                      bk):
+    """A chunk's queries at key positions ``tc ..`` over ``[context ;
+    chunk]`` keys of which the last ``live`` context keys are live (traced)
+    against the dense mask: causal and band shifted by ``tc``."""
+    rng = np.random.RandomState(11)
+    B, hq, hkv, T, dk, dv = 1, 8, 2, 72, 24, 16
+    q = jnp.asarray(rng.randn(B, hq, T, dk), jnp.float32)
+    k = jnp.asarray(rng.randn(B, hkv, tc + T, dk), jnp.float32)
+    v = jnp.asarray(rng.randn(B, hkv, tc + T, dv), jnp.float32)
+    got = jax.jit(lambda n: flash_attention_fwd(
+        q, k, v, window=window, block_q=bq, block_k=bk, ctx_live=n))(
+            jnp.int32(live))
+    kr, vr = (jnp.repeat(t, hq // hkv, axis=1) for t in (k, v))
+    s = np.asarray(jnp.einsum("bhqd,bhkd->bhqk", q, kr)) * 24 ** -0.5
+    kk, qq = np.arange(tc + T)[None, :], tc + np.arange(T)[:, None]
+    ok = (kk <= qq) & (kk >= tc - live)
+    if window is not None:
+        ok &= kk > qq - window
+    s = np.where(ok[None, None], s, -np.inf)
+    p = np.exp(s - s.max(-1, keepdims=True))
+    want = np.einsum("bhqk,bhkd->bhqd", p / p.sum(-1, keepdims=True),
+                     np.asarray(vr))
+    np.testing.assert_allclose(got, want, atol=3e-6)
+
+
+def test_flash_forward_without_context_is_the_call_it_was(monkeypatch):
+    """``Tc == 0``: the same kernel, name and grid as before context keys
+    existed (``ds_flash_fwd``), bit for bit the result of the same call
+    with a context that holds no live key taken off."""
+    fa = sys.modules["deepspeed_tpu.ops.pallas.flash_attention"]
+    seen = []
+    real = fa.pl.pallas_call
+
+    def spy(kernel, **kw):
+        seen.append((kw["name"], kw.get("grid")))
+        return real(kernel, **kw)
+
+    monkeypatch.setattr(fa.pl, "pallas_call", spy)
+    rng = np.random.RandomState(12)
+    q, k, v = (jnp.asarray(rng.randn(1, 4, 64, 16), jnp.float32)
+               for _ in range(3))
+    plain = flash_attention_fwd(q, k, v, window=24, block_q=32, block_k=32)
+    assert seen == [("ds_flash_fwd", (4, 2, 2))]
+    dead = jnp.full((1, 4, 32, 16), 7.0, jnp.float32)
+    ahead = flash_attention_fwd(
+        q, jnp.concatenate([dead, k], 2), jnp.concatenate([dead, v], 2),
+        window=24, block_q=32, block_k=32, ctx_live=jnp.int32(0))
+    assert seen[1][0] == "ds_flash_fwd_ctx"
+    np.testing.assert_allclose(ahead, plain, atol=1e-6)
+    with pytest.raises(AssertionError):
+        flash_attention_fwd(q, jnp.concatenate([dead, k], 2),
+                            jnp.concatenate([dead, v], 2))
+
+
+@pytest.mark.parametrize("T,bk,sink,lens", [
+    (32, 8, False, [0, 1, 7, 8, 9, 31, 32, 100]),
+    (32, 16, True, [5, 32, 33, 0]),
+    (64, 16, False, [17, 64, 4097])])
+def test_window_decode_walks_a_ring_in_blocks(T, bk, sink, lens,
+                                              monkeypatch):
+    """The blocked walk (the slot body, ``bk`` rows of every key head a
+    grid step, online softmax, dead blocks skipped) against the dense
+    reference, at rings partly filled, just full and long wrapped; a free
+    slot gives zeros; a layer's base is traced.  The block comes from the
+    shapes: the budget is cut so that these small rings do not fit it."""
+    da = sys.modules["deepspeed_tpu.ops.pallas.decode_attention"]
+    monkeypatch.setattr(da, "PAGED_KV_VMEM_BUDGET",
+                        2 * 2 * bk * (24 + 16) * 4)
+    assert da.window_ring_block(2, T, 24 + 16, 4) == bk
+    rng = np.random.RandomState(13)
+    S, hq, hkv, dk, dv = len(lens), 16, 2, 24, 16
+    q = jnp.asarray(rng.randn(S, hq, dk), jnp.float32)
+    k = jnp.asarray(rng.randn(2 * S, hkv, T, dk), jnp.float32)
+    v = jnp.asarray(rng.randn(2 * S, hkv, T, dv), jnp.float32)
+    b = jnp.asarray(rng.randn(hq), jnp.float32) if sink else None
+    n = jnp.asarray(lens, jnp.int32)
+    got = jax.jit(lambda base: window_decode_attention(
+        q, k, v, n, b, base=base))(jnp.int32(S))
+    want = slot_decode_reference(q, k[S:], v[S:], jnp.minimum(n, T), sink=b)
+    np.testing.assert_allclose(got, want, atol=3e-6)
+    for s, length in enumerate(lens):
+        if length == 0:
+            assert not np.asarray(got[s]).any()
+
+
+def test_window_decode_chooses_its_walk_from_the_shapes(monkeypatch):
+    """MiMo's rings (128 keys on 8 heads of 256 + 128) stay ONE block a
+    slot, the call it was: the slot body, its name, grid ``(slots, 1)``.
+    Command A+'s (4,096 keys on 8 heads of 128 + 128) are walked by the
+    same body in blocks of 512 rows of every key head."""
+    da = sys.modules["deepspeed_tpu.ops.pallas.decode_attention"]
+    assert da.window_ring_block(8, 128, 256 + 128, 2) == 128
+    assert da.window_ring_block(8, 4096, 128 + 128, 2) == 512
+    seen = []
+    real = da.pl.pallas_call
+
+    def spy(kernel, **kw):
+        seen.append((kw["name"], kw["grid_spec"].grid))
+        return real(kernel, **kw)
+
+    monkeypatch.setattr(da.pl, "pallas_call", spy)
+    bf = jnp.bfloat16
+    for hq, t, dk in ((64, 128, 256), (128, 4096, 128)):
+        jax.eval_shape(
+            lambda q, k, v, n: window_decode_attention(q, k, v, n, None),
+            jax.ShapeDtypeStruct((4, hq, dk), bf),
+            jax.ShapeDtypeStruct((4, 8, t, dk), bf),
+            jax.ShapeDtypeStruct((4, 8, t, 128), bf),
+            jax.ShapeDtypeStruct((4,), jnp.int32))
+    assert seen == [("ds_window_decode_attn", (4, 1)),
+                    ("ds_window_decode_attn", (4, 8))]
+
+
+def test_mimos_window_decode_is_bit_for_bit_the_slot_body():
+    """What ``window_decode_attention`` gives at MiMo's shapes is what
+    ``decode_attention_slots`` gives for the whole ring a step."""
+    rng = np.random.RandomState(14)
+    q, k, v = _qkv(rng, 3, 8, 4, 8, 24, 16)
+    b = jnp.asarray(rng.randn(8), jnp.float32)
+    n = jnp.asarray([3, 8, 30], jnp.int32)
+    got = window_decode_attention(q, k, v, n, b)
+    want = decode_attention_slots(q, k, v, jnp.minimum(n, 8), sink=b,
+                                  block_k=8, name="ds_window_decode_attn")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 # -- the model against the reference -------------------------------------
 
 @pytest.mark.parametrize("attn_impl", ["dense", "flash"])

@@ -23,6 +23,7 @@ from ..ops.transformer import (DeepSpeedTransformerConfig,
 from ..ops.dropout import dropout
 from ..ops.transformer.transformer import _layer_norm
 from ..parallel.mesh import MODEL_AXIS
+from ..runtime.activation_checkpointing.block_remat import checkpoint_block
 from ..runtime.module import TrainModule, mark_subtrees
 from ..runtime.zero import gather_layer
 
@@ -212,7 +213,14 @@ class BertModel(TrainModule):
                 y = layer(lp, h, add_mask, lrng, train)
             return y, None
 
-        remat = jax.checkpoint if cfg.remat == "block" else (lambda f: f)
+        # whether a block keeps the flash kernel's results besides its
+        # input follows the engine's memory budget (block_remat.py)
+        remat = checkpoint_block(
+            x, trips=L, heads=cfg.num_attention_heads,
+            ffn_width=cfg.intermediate_size, head_width=cfg.vocab_size,
+            attn_sites=int(cfg.attn_impl == "flash")
+        ) if cfg.remat == "block" else (lambda f: f)
+
         if cfg.scan_layers:
             # under ZeRO the layer is gathered here, inside the remat'd
             # body (runtime/zero.py::gather_layer)

@@ -29,6 +29,7 @@ from jax.sharding import PartitionSpec as P
 from ..ops.attention import causal_attention
 from ..ops.dropout import dropout
 from ..parallel.mesh import MODEL_AXIS
+from ..runtime.activation_checkpointing.block_remat import checkpoint_block
 from ..runtime.module import TrainModule, mark_subtrees
 from ..runtime.zero import gather_layer
 
@@ -171,7 +172,14 @@ class GPT2Model(TrainModule):
             lrng = jax.random.fold_in(rng, i)
             return self._block(bp, x, lrng, train), None
 
-        remat = jax.checkpoint if cfg.remat == "block" else (lambda f: f)
+        # whether a block keeps the flash kernel's results besides its
+        # input follows the engine's memory budget (block_remat.py)
+        remat = checkpoint_block(
+            x, trips=cfg.n_layer, heads=cfg.n_head,
+            ffn_width=block_params["fc_w"].shape[-1],
+            head_width=cfg.vocab_size,
+            attn_sites=int(cfg.attn_impl == "flash")
+        ) if cfg.remat == "block" else (lambda f: f)
 
         if cfg.scan_layers and cfg.stream_scan:
             # Param-streaming form: block params stay a scan CONSTANT

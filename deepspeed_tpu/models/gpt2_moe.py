@@ -27,6 +27,7 @@ from jax.sharding import PartitionSpec as P
 from ..moe.layer import MoEConfig, init_moe_params, moe_ffn, moe_param_specs
 from ..ops.dropout import dropout
 from ..parallel.mesh import MODEL_AXIS
+from ..runtime.activation_checkpointing.block_remat import checkpoint_block
 from ..runtime.module import TrainModule, mark_subtrees
 from ..runtime.zero import gather_layer
 from .gpt2 import (GPT2Config, _layer_norm, gpt2_attn_sublayer,
@@ -237,6 +238,17 @@ class GPT2MoEModel(TrainModule):
             y, aux = moe_ffn(mcfg, mp, h, r_ffn, train)
             return x + dropout(y, drop, jax.random.fold_in(r_ffn, 1)), aux
 
+        # remat='block': ONE choice for every block of whether it keeps
+        # the flash kernel's results besides its input, from the engine's
+        # memory budget (block_remat.py): groups of freq flash calls, the
+        # expert FFN's working set counted as a dense FFN's
+        freq = cfg.moe_layer_freq
+        remat = checkpoint_block(
+            x, trips=-(-cfg.n_layer // freq), heads=cfg.n_head,
+            ffn_width=4 * cfg.d_model, head_width=cfg.vocab_size,
+            attn_sites=freq * int(cfg.attn_impl == "flash"), ffn_sites=freq,
+        ) if cfg.remat == "block" else (lambda f: f)
+
         aux0 = jnp.zeros((), jnp.float32)
         if cfg.scan_groups and cfg.stream_scan:
             # Param-streaming form of the group scan: the stacks stay
@@ -269,10 +281,8 @@ class GPT2MoEModel(TrainModule):
                     jax.random.fold_in(rng, g * freq + freq - 1))
                 return (x, aux + a), None
 
-            if cfg.remat == "block":
-                group_body = jax.checkpoint(group_body)
             (x, aux_total), _ = jax.lax.scan(
-                group_body, (x, aux0), jnp.arange(G))
+                remat(group_body), (x, aux0), jnp.arange(G))
         elif cfg.scan_groups:
             # One compiled group body regardless of depth: the layer loop
             # scans over groups of ``freq`` blocks (freq-1 dense + 1 MoE,
@@ -311,15 +321,11 @@ class GPT2MoEModel(TrainModule):
                     jax.random.fold_in(rng, g * freq + freq - 1))
                 return (x, aux + a), None
 
-            if cfg.remat == "block":
-                group_body = jax.checkpoint(group_body)
             (x, aux_total), _ = jax.lax.scan(
-                group_body, (x, aux0),
+                remat(group_body), (x, aux0),
                 (attn_g, dense_g, params["moe"], jnp.arange(G)))
         else:
-            if cfg.remat == "block":
-                dense_block = jax.checkpoint(dense_block)
-                moe_block = jax.checkpoint(moe_block)
+            dense_block, moe_block = remat(dense_block), remat(moe_block)
             aux_total = aux0
             d_idx = m_idx = 0
             for i in range(cfg.n_layer):

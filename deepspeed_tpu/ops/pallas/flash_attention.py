@@ -39,6 +39,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -166,6 +167,13 @@ def _masked_scores(q, k, iq, ik, *, sm_scale, causal, block_q, block_k,
 # whatever JAX construct (checkpoint, scan, shard_map, cond) wraps the
 # call.  The benchmark's per-kernel shares match on these strings.
 FLASH_FWD_KERNEL = "ds_flash_fwd"
+# ``checkpoint_name``s of the forward's two results where they become the
+# backward's residuals (``_flash_fwd``): a remat policy that saves both
+# (runtime/activation_checkpointing/block_remat.py) keeps the backward
+# from running the forward kernel again.  Under a ``jax.checkpoint`` with
+# no such policy, and outside one, a name is the identity.
+FLASH_OUT = "flash_out"     # [batch * heads, seq, head size]
+FLASH_LSE = "flash_lse"     # [batch * heads, seq], one float32 a row
 
 
 def _band_first_block(iq, block_q, block_k, window):
@@ -661,6 +669,9 @@ def _flash_fwd(q, k, v, seed, bh_base, kmask, sm_scale, causal, block_q,
                     dropout_rate=dropout_rate, bh_period=bh_period,
                     bh_stride=bh_stride, interpret=interpret,
                     kv_length=kv_length)
+    # with both saved the recomputed forward's kernel has no consumer left
+    out = checkpoint_name(out, FLASH_OUT)
+    lse = checkpoint_name(lse, FLASH_LSE)
     return out, (q, k, v, seed, bh_base, kmask, out, lse)
 
 

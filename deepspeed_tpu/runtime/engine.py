@@ -23,6 +23,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import math
 import os
 import statistics
 import threading
@@ -46,13 +47,16 @@ from ..parallel.mesh import DATA_AXIS, build_mesh, mesh_axis_size
 from ..telemetry import tracing
 from ..utils.logging import log_dist, logger
 from . import precision
+from .activation_checkpointing.block_remat import (RematBudget,
+                                                   remat_budget_scope)
 from .engine_stages import (finish_close, pop_stage_errors,
                             stage_degraded, wire_stage_plane)
 from .lr_schedules import get_lr_schedule
 from .module import TrainModule
 from .prefetch import DevicePlacedBatch, DevicePrefetcher
 from .precision import LossScaleState
-from .utils import clip_by_global_norm, global_norm
+from .utils import (clip_by_global_norm, collect_memory_stats, device_bytes,
+                    global_norm)
 from .zero import ZeroShardingPlan, cast_for_compute, constrain_grads
 
 MEMORY_OPT_ALLREDUCE_SIZE = 500_000_000  # kept for parity (engine.py:41)
@@ -1294,6 +1298,55 @@ class DeepSpeedEngine:
         return (jax.tree.map(lambda x: x.sharding, self.state),
                 NamedSharding(self.mesh, P()))
 
+    def _remat_budget(self) -> Optional[RematBudget]:
+        """One device's memory for the fused train step's remat policy:
+        the allocator's limit; what the device holds (the state placed on
+        it, exact from the placed tree, or the allocator's own count
+        where that is larger); and the parameter-sized temporaries of the
+        step, a compute-dtype copy and a gradient tree (in compute dtype
+        and in float32, and the float32 sum beside it when the step
+        accumulates over micro-batches) placed as the ZeRO plan places
+        gradients.  None where the backend states no limit (the CPU):
+        nothing is then saved, as before."""
+        mine = {d.id for d in self.mesh.devices.flat}
+        devices = [d for d in collect_memory_stats()["devices"]
+                   if d["id"] in mine and d["bytes_limit"]]
+        if not devices:
+            return None
+        master = self.state.master_params
+        item = jnp.dtype(self.compute_dtype).itemsize
+        held = sum(
+            math.prod(NamedSharding(self.mesh, spec).shard_shape(x.shape))
+            for x, spec in zip(
+                jax.tree.leaves(master),
+                jax.tree.leaves(self.zero_plan.grad_specs(master),
+                                is_leaf=lambda s: isinstance(s, P))))
+        return RematBudget(
+            bytes_limit=min(d["bytes_limit"] for d in devices),
+            resident_bytes=max(
+                [device_bytes(self.state)]
+                + [d["bytes_in_use"] or 0 for d in devices]),
+            copy_bytes=held * item,
+            grad_bytes=held * (item + (8 if self._scan_grad_acc > 1 else 4)),
+            report=self._report_remat_choice)
+
+    def _report_remat_choice(self, kept: Dict[str, int], line: str):
+        """The remat policy's choice, when a block is traced: one log
+        line a distinct choice (the step's forward is traced again for
+        its dropout sites) and gauge ``train_remat_saved_bytes{name=}``,
+        the bytes a device keeps for each name over the whole stack."""
+        if line != getattr(self, "_remat_line", None):
+            self._remat_line = line
+            log_dist(line, ranks=[0])
+        if self.telemetry is not None:
+            gauge = self.telemetry.registry.gauge(
+                "train_remat_saved_bytes",
+                "bytes one device keeps across the remat boundary of the "
+                "traced train step's blocks, over the whole stack, by "
+                "checkpoint name; 0 = recomputed")
+            for name, nbytes in kept.items():
+                gauge.set(nbytes, name=name)
+
     def _build_train_step(self):
         optimizer = self.optimizer
         clip = self.gradient_clipping
@@ -1306,8 +1359,11 @@ class DeepSpeedEngine:
             step_rng = jax.random.fold_in(state.rng, state.global_steps)
             # scopes at the layer map's boundaries (PERF.md section 3):
             # fwd_bwd (with grad_reduce inside it, where the ZeRO
-            # placement is stated) and optimizer
-            with jax.named_scope("fwd_bwd"):
+            # placement is stated) and optimizer.  The memory budget is
+            # read here, while the step is traced: what a remat'd block
+            # may keep across its boundary (block_remat.py)
+            with jax.named_scope("fwd_bwd"), \
+                    remat_budget_scope(self._remat_budget()):
                 grads, scaled_losses = self._scan_scaled_grads(
                     state.master_params, batch, scaler, step_rng)
 

@@ -7,6 +7,8 @@ which XLA fuses into the step.)
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -31,6 +33,13 @@ def clip_by_global_norm(tree, max_norm: float, norm=None):
 
 def weight_norm(tree) -> jnp.ndarray:
     return global_norm(tree)
+
+
+def device_bytes(tree) -> int:
+    """Bytes one device holds of a placed tree of arrays, from each
+    leaf's own sharding."""
+    return sum(math.prod(x.sharding.shard_shape(x.shape))
+               * x.dtype.itemsize for x in jax.tree.leaves(tree))
 
 
 def see_memory_usage(message: str = "", force: bool = False) -> str:

@@ -1,6 +1,6 @@
 """Read the collectives, the weights a program moves before it uses them,
-and the fusions that draw random bits an element, out of a compiled
-program's text.
+the fusions that draw random bits an element, and how often each Mosaic
+kernel runs, out of a compiled program's text.
 
 ``compiled.as_text()`` is the program after the SPMD partitioner: what a
 placement rule (runtime/zero.py) really costs is the collectives found
@@ -8,7 +8,9 @@ there, their result shapes, and how often the loop around them runs; what
 a parameter tree's layout costs (models/mimo_v2.py) is the fusions and
 copies that write a parameter again before a matmul reads it
 (``parameter_rewrites``); what a random draw over an activation costs is
-the threefry rounds fused into whatever reads the mask (``rng_fusions``).
+the threefry rounds fused into whatever reads the mask (``rng_fusions``);
+what a remat policy saves or recomputes is how often a kernel runs a call
+of the program (``kernel_calls``).
 Bytes and counts only — no time is read from a program's text (a fusion's
 ``estimated_cycles`` is the compiler's guess, and is reported as that).
 """
@@ -270,4 +272,24 @@ def rng_fusions(hlo_text: str, elements: int = 1 << 20,
                 _NAMED.match(line).group(1),
                 max(sizes["shift-right-logical"]), times,
                 int(cycles.group(1)) if cycles else None))
+    return found
+
+
+_MOSAIC = 'custom_call_target="tpu_custom_call"'
+_SUFFIX = re.compile(r"(\.\d+)+$")
+
+
+def kernel_calls(hlo_text: str) -> Dict[str, int]:
+    """Executions a call of the program of every Mosaic custom call of
+    the chip's text (``tpu_custom_call``), by kernel: the
+    instruction's name less its numeric suffix, which is the
+    ``pl.pallas_call(name=...)`` (``ds_flash_fwd.10`` -> ``ds_flash_fwd``);
+    each instruction counts the product of the trip counts of the loops
+    around it.  A layer scan's forward loop holds a kernel once a layer,
+    its backward loop once more where the block's remat runs it again."""
+    found: Dict[str, int] = {}
+    for _, line, _, op, times, _ in _reached(*_computations(hlo_text)):
+        if op == "custom-call" and _MOSAIC in line:
+            kernel = _SUFFIX.sub("", _NAMED.match(line).group(1))
+            found[kernel] = found.get(kernel, 0) + times
     return found

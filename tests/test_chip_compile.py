@@ -1341,3 +1341,102 @@ def test_hashed_dropout_costs_the_ffn_out_matmul_next_to_nothing(bert_stacks,
         ("out", "first"), ("out", "recomputed")]
     assert hashed["out", run] <= 1.1 * none["out", run], (hashed, none)
     assert hashed["out", run] <= 1.6 * hashed["in", run], hashed
+
+
+# ---------------------------------------------------------------------------
+# What remat="block" keeps (PR 46): with the flash kernel's output and
+# log-sum-exp saved across the block's boundary the recomputed forward's
+# kernel has no consumer, and the chip's program runs it once a layer.
+# ---------------------------------------------------------------------------
+def _train_step_text(model, batch, one_chip, saved: bool) -> str:
+    """The loss-and-gradient program of a whole model at bf16 weights, as
+    the chip's compiler leaves it; ``saved``: traced with a budget that is
+    all room (the flash results kept), else with none (the bare
+    checkpoint)."""
+    from deepspeed_tpu.runtime.activation_checkpointing.block_remat import (
+        RematBudget, remat_budget_scope)
+    params = jax.tree.map(lambda s: _sds(s.shape),
+                          jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    budget = RematBudget(bytes_limit=10 ** 12, resident_bytes=0) if saved \
+        else None
+
+    def step(params, batch, rng):
+        return jax.value_and_grad(model.loss_fn)(params, batch, rng)
+
+    with interpret_scope(False), remat_budget_scope(budget):
+        return _compile(step, one_chip, params, batch,
+                        _sds((2,), jnp.uint32)).as_text()
+
+
+def _bert_large_step(one_chip, saved):
+    from deepspeed_tpu.models.bert import BERT_LARGE, BertModel
+    batch = {"input_ids": _sds((BERT_STACK_ROWS, 512), jnp.int32),
+             "masked_lm_labels": _sds((BERT_STACK_ROWS, 512), jnp.int32),
+             "next_sentence_label": _sds((BERT_STACK_ROWS,), jnp.int32)}
+    return _train_step_text(BertModel(BERT_LARGE), batch, one_chip, saved)
+
+
+def test_the_step_runs_the_flash_forward_once_a_layer_when_its_output_is_kept(
+        one_chip):
+    """``utils/hlo.py::kernel_calls`` on the chip's text, loops counted:
+    BERT-large's step holds 24 ``ds_flash_fwd`` calls (48 under the bare
+    checkpoint, the parent's: the test below; GPT-2's, on four chips:
+    the last test), the backward kernels run once a layer, and no fusion
+    draws random bits an element."""
+    from deepspeed_tpu.utils.hlo import kernel_calls
+    text = _bert_large_step(one_chip, saved=True)
+    assert kernel_calls(text) == {
+        "ds_flash_fwd": 24, "ds_flash_bwd_dq": 24, "ds_flash_bwd_dkv": 24}
+    assert rng_fusions(text) == []
+
+
+def test_the_bare_checkpoint_runs_the_flash_forward_twice_a_layer(
+        bert_stacks):
+    """What the parent's step did, and what a step still does where no
+    budget is handed over: the two scanned layers of ``bert_stacks`` run
+    ``ds_flash_fwd`` four times, the backward kernels twice."""
+    from deepspeed_tpu.utils.hlo import kernel_calls
+    assert kernel_calls(bert_stacks[0.1]) == {
+        "ds_flash_fwd": 4, "ds_flash_bwd_dq": 2, "ds_flash_bwd_dkv": 2}
+
+
+def test_saved_flash_results_stay_with_their_rows_on_four_chips(topo):
+    """dp=4, the flash call inside ``sharded_flash_attention``'s manual
+    region: the kept output leaves it as the batch is sharded (a device's
+    stack holds its own 4 of 16 rows x 12 heads, no more), the forward
+    kernel runs once a layer, and the program's collectives are the bare
+    checkpoint's, instruction for instruction."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from deepspeed_tpu.parallel import build_mesh
+    from deepspeed_tpu.runtime.activation_checkpointing.block_remat import (
+        RematBudget, remat_budget_scope)
+    from deepspeed_tpu.utils.hlo import collectives, kernel_calls
+    mesh = build_mesh(dp=4, devices=topo.devices)
+    layers = 2
+    model = GPT2Model(dataclasses.replace(GPT2_124M, n_layer=layers))
+    params = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, BF16,
+                                       sharding=NamedSharding(mesh, P())),
+        jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    tokens = jax.ShapeDtypeStruct((16, SEQ + 1), jnp.int32,
+                                  sharding=NamedSharding(mesh, P("data")))
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32,
+                               sharding=NamedSharding(mesh, P()))
+
+    def text(budget):
+        with jax.set_mesh(mesh), interpret_scope(False), \
+                remat_budget_scope(budget):
+            return jax.jit(jax.value_and_grad(model.loss_fn)).lower(
+                params, tokens, key).compile().as_text()
+
+    bare = text(None)
+    assert kernel_calls(bare)["ds_flash_fwd"] == 2 * layers
+    assert f"bf16[{layers},48,{SEQ},{DH}]" not in bare
+    kept = text(RematBudget(bytes_limit=10 ** 12, resident_bytes=0))
+    assert kernel_calls(kept) == {"ds_flash_fwd": layers,
+                                  "ds_flash_bwd_dq": layers,
+                                  "ds_flash_bwd_dkv": layers}
+    assert f"bf16[{layers},48,{SEQ},{DH}]" in kept      # 4 rows x 12 heads
+    assert f"bf16[{layers},192,{SEQ},{DH}]" not in kept
+    assert sorted((c.op, c.shapes, c.times) for c in collectives(kept)) == \
+        sorted((c.op, c.shapes, c.times) for c in collectives(bare))

@@ -16,10 +16,12 @@ from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..ops.transformer import (DeepSpeedTransformerConfig,
                                DeepSpeedTransformerLayer)
+from ..ops import mlm_head
 from ..ops.dropout import dropout
 from ..ops.transformer.transformer import _layer_norm
 from ..parallel.mesh import MODEL_AXIS
@@ -68,6 +70,13 @@ class BertModel(TrainModule):
     Batches: dict with ``input_ids`` [B, T]; optional ``token_type_ids``,
     ``attention_mask`` (1 keep / 0 pad), ``masked_lm_labels`` [B, T] with
     -100 for unmasked positions, ``next_sentence_label`` [B].
+
+    ``loss_fn`` reads the labelled rows only: the MLM head (transform,
+    tied decoder, float32 softmax, and their gradient) runs over the
+    positions that carry a label, a block of rows at a time, and never
+    holds logits for every position (ops/mlm_head.py).  ``apply`` still
+    returns ``mlm_logits`` ``[B, T, V]`` for callers that want them
+    (fine-tuning, inference).
     """
 
     def __init__(self, config: BertConfig):
@@ -218,6 +227,7 @@ class BertModel(TrainModule):
         remat = checkpoint_block(
             x, trips=L, heads=cfg.num_attention_heads,
             ffn_width=cfg.intermediate_size, head_width=cfg.vocab_size,
+            head_rows=mlm_head.HEAD_BLOCK_ROWS,
             attn_sites=int(cfg.attn_impl == "flash")
         ) if cfg.remat == "block" else (lambda f: f)
 
@@ -239,45 +249,56 @@ class BertModel(TrainModule):
                 x, _ = body_fn(x, (lp, jnp.asarray(i, jnp.int32)))
         return x
 
-    def apply(self, params, batch, rng=None, train: bool = True):
-        """→ (mlm_logits [B, T, V], nsp_logits [B, 2])."""
+    def _encode_batch(self, params, batch, rng, train: bool):
         pld = batch.get("pld_theta")
-        seq = self.encode(params, batch["input_ids"],
-                          batch.get("token_type_ids"),
-                          batch.get("attention_mask"), rng, train,
-                          pld_theta=(pld.reshape(-1)[0]
-                                     if pld is not None else None))
-        with jax.named_scope("mlm_head"):
-            h = seq @ params["mlm_transform_w"].astype(seq.dtype) \
-                + params["mlm_transform_b"].astype(seq.dtype)
-            h = jax.nn.gelu(h, approximate=False)
-            h = _layer_norm(h, params["mlm_ln_scale"],
-                            params["mlm_ln_bias"])
-            mlm_logits = h @ params["word_embeddings"].astype(h.dtype).T \
-                + params["mlm_bias"].astype(h.dtype)
-        # NSP head on pooled [CLS]
+        return self.encode(params, batch["input_ids"],
+                           batch.get("token_type_ids"),
+                           batch.get("attention_mask"), rng, train,
+                           pld_theta=(pld.reshape(-1)[0]
+                                      if pld is not None else None))
+
+    @staticmethod
+    def _nsp_logits(params, seq):
+        """NSP head on pooled [CLS]."""
         pooled = jnp.tanh(
             seq[:, 0] @ params["pooler_w"].astype(seq.dtype)
             + params["pooler_b"].astype(seq.dtype))
-        nsp_logits = pooled @ params["nsp_w"].astype(seq.dtype) \
+        return pooled @ params["nsp_w"].astype(seq.dtype) \
             + params["nsp_b"].astype(seq.dtype)
-        return mlm_logits, nsp_logits
+
+    def apply(self, params, batch, rng=None, train: bool = True):
+        """→ (mlm_logits [B, T, V], nsp_logits [B, 2])."""
+        seq = self._encode_batch(params, batch, rng, train)
+        with jax.named_scope("mlm_head"):
+            h = mlm_head.mlm_transform(seq, params)
+            mlm_logits = h @ params["word_embeddings"].astype(h.dtype).T \
+                + params["mlm_bias"].astype(h.dtype)
+        return mlm_logits, self._nsp_logits(params, seq)
 
     def loss_fn(self, params, batch, rng, train: bool = True):
-        mlm_logits, nsp_logits = self.apply(params, batch, rng, train)
-        mlm_logits = mlm_logits.astype(jnp.float32)
+        seq = self._encode_batch(params, batch, rng, train)
         loss = jnp.asarray(0.0, jnp.float32)
         labels = batch.get("masked_lm_labels")
         if labels is not None:
-            logp = jax.nn.log_softmax(mlm_logits, axis=-1)
-            safe = jnp.maximum(labels, 0)
-            nll = -jnp.take_along_axis(logp, safe[..., None], -1)[..., 0]
-            mask = (labels >= 0).astype(jnp.float32)
-            loss = loss + jnp.sum(nll * mask) / jnp.maximum(
-                jnp.sum(mask), 1.0)
+            with jax.named_scope("mlm_head"):
+                loss = loss + mlm_head.masked_lm_loss(
+                    seq, labels,
+                    {k: params[k] for k in mlm_head.HEAD_LEAVES},
+                    mlm_head.HEAD_BLOCK_ROWS)
         nsl = batch.get("next_sentence_label")
         if nsl is not None:
-            logp = jax.nn.log_softmax(nsp_logits.astype(jnp.float32), -1)
+            logp = jax.nn.log_softmax(
+                self._nsp_logits(params, seq).astype(jnp.float32), -1)
             loss = loss - jnp.mean(
                 jnp.take_along_axis(logp, nsl[:, None], -1))
         return loss
+
+    def labelled_rows(self, batch) -> Optional[int]:
+        """Positions of a host batch that carry a masked-LM label, the
+        rows the head's walk runs (``train_head_rows{kind="labelled"}``);
+        None for a batch that is on the device already (reading it would
+        wait for the device)."""
+        labels = batch.get("masked_lm_labels")
+        if labels is None or isinstance(labels, jax.Array):
+            return None
+        return int(np.sum(np.asarray(labels) >= 0))

@@ -106,7 +106,8 @@ def saved_bytes(carry, *, trips: int, heads: int,
 
 def activation_room(budget: RematBudget, carry, *, trips: int,
                     ffn_width: int, head_width: int, attn_sites: int = 1,
-                    ffn_sites: int = 1) -> int:
+                    ffn_sites: int = 1,
+                    head_rows: Optional[int] = None) -> int:
     """Bytes of one device left for saved names, after what the step
     holds besides them.  Reckoned from shapes, the large ones only:
 
@@ -114,17 +115,23 @@ def activation_room(budget: RematBudget, carry, *, trips: int,
       long;
     * the stack of layer inputs remat keeps (``trips`` carries);
     * the larger of the two phases that never overlap: the head (float32
-      logits ``[rows, head_width]`` and their gradient) and the backward
-      of one body (its working set, taken as twice what its forward
-      writes: eight carry-wide and two FFN-wide values a layer) beside
-      the gradient tree.
+      logits ``[rows, head_width]`` and their gradient; for a head that
+      walks its rows ``head_rows`` at a time, models/mlm_head.py, the
+      logits of one block, their gradient and the decoder's float32
+      gradient ``[head_width, width]``) and the backward of one body (its
+      working set, taken as twice what its forward writes: eight
+      carry-wide and two FFN-wide values a layer) beside the gradient
+      tree.
     """
     b, t, d = carry.shape
     item = np.dtype(carry.dtype).itemsize
     rows = b * t // _axis_split(DATA_AXIS, b)
     carry_bytes = rows * d * item
     ffn_bytes = rows * ffn_width * item // _axis_split(MODEL_AXIS, ffn_width)
-    head = 2 * rows * head_width * 4
+    if head_rows is None:
+        head = 2 * rows * head_width * 4
+    else:
+        head = (2 * min(head_rows, rows) + d) * head_width * 4
     body = 2 * (attn_sites * 8 * carry_bytes + ffn_sites * 2 * ffn_bytes)
     held = (budget.resident_bytes + budget.copy_bytes + trips * carry_bytes
             + max(head, body + budget.grad_bytes))
@@ -133,14 +140,15 @@ def activation_room(budget: RematBudget, carry, *, trips: int,
 
 def checkpoint_block(carry, *, trips: int, heads: int, ffn_width: int,
                      head_width: int, attn_sites: int = 1,
-                     ffn_sites: int = 1):
+                     ffn_sites: int = 1, head_rows: Optional[int] = None):
     """The ``jax.checkpoint`` of a model's layer bodies: a decorator for
     the body (or bodies) run ``trips`` times in all, by a ``lax.scan`` or
     a Python loop, over ``carry`` ``[batch, seq, width]``, saving the
     flash kernel's output and log-sum-exp where the budget in scope has
     room for them.  One trip holds ``attn_sites`` flash calls and
     ``ffn_sites`` dense FFNs of ``ffn_width``; ``head_width`` is the width
-    of the float32 logits the model's head writes for every row.  Bodies
+    of the float32 logits the model's head writes for every row, or for
+    ``head_rows`` of a device's rows at a time.  Bodies
     whose attention is not the flash kernel (``attn_sites=0``) have
     nothing to save."""
     budget = _budget.get()
@@ -150,7 +158,7 @@ def checkpoint_block(carry, *, trips: int, heads: int, ffn_width: int,
                         attn_sites=attn_sites)
     room = activation_room(budget, carry, trips=trips, ffn_width=ffn_width,
                            head_width=head_width, attn_sites=attn_sites,
-                           ffn_sites=ffn_sites)
+                           ffn_sites=ffn_sites, head_rows=head_rows)
     fits = sum(costs.values()) <= room
     if budget.report is not None:
         kept = " + ".join(f"{n} {costs[n] / 1e9:.3f} GB" for n in SAVED)

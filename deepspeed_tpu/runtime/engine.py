@@ -43,6 +43,7 @@ from ..ops.adam import (FusedAdamState, adam_direction, adam_moments,
                         fused_adam)
 from ..ops.dropout import traced_sites
 from ..ops.lamb import fused_lamb
+from ..ops.mlm_head import traced_head_rows
 from ..parallel.mesh import DATA_AXIS, build_mesh, mesh_axis_size
 from ..telemetry import tracing
 from ..utils.logging import log_dist, logger
@@ -1066,13 +1067,19 @@ class DeepSpeedEngine:
             # transposed); set while the step is traced
             micro = jax.tree.map(
                 lambda x: jax.ShapeDtypeStruct(x.shape[1:], x.dtype), batch)
+            traced = jax.make_jaxpr(forward)(params, micro, step_rng).jaxpr
             self.telemetry.registry.gauge(
                 "train_dropout_sites",
                 "dropout sites with a rate above 0 in the traced train "
                 "step, scan bodies times their trip count; each draws "
                 "its mask from the counter hash of ops/dropout.py"
-            ).set(grad_acc * traced_sites(jax.make_jaxpr(forward)(
-                params, micro, step_rng).jaxpr), generator="hash")
+            ).set(grad_acc * traced_sites(traced), generator="hash")
+            head = traced_head_rows(traced)
+            if head is not None:
+                # a head that walks its labelled rows (ops/mlm_head.py)
+                gauge = self._head_rows_gauge()
+                gauge.set(grad_acc * head["all"], kind="all")
+                gauge.set(head["block"], kind="block")
 
         if keep_param_dtype and grad_acc == 1:
             mb = jax.tree.map(lambda x: x[0], batch)
@@ -1329,6 +1336,15 @@ class DeepSpeedEngine:
             copy_bytes=held * item,
             grad_bytes=held * (item + (8 if self._scan_grad_acc > 1 else 4)),
             report=self._report_remat_choice)
+
+    def _head_rows_gauge(self):
+        return self.telemetry.registry.gauge(
+            "train_head_rows",
+            "rows of a masked-LM head that walks its labelled rows in "
+            "blocks (ops/mlm_head.py), one device, one step: all = rows "
+            "the walk orders, block = rows of one block (both set while "
+            "the step is traced), labelled = rows of the last batch the "
+            "host placed that carry a label (mean over the data axis)")
 
     def _report_remat_choice(self, kept: Dict[str, int], line: str):
         """The remat policy's choice, when a block is traced: one log
@@ -3019,6 +3035,12 @@ class DeepSpeedEngine:
                             prefetched=placed is not None):
             sharded = (placed.tree if placed is not None
                        else self._shard_batch(batch))
+            if self.telemetry is not None and placed is None:
+                labelled = getattr(self.module, "labelled_rows",
+                                   lambda batch: None)(batch)
+                if labelled is not None:
+                    self._head_rows_gauge().set(
+                        labelled / self.dp_world_size, kind="labelled")
         if self._pg_check_pending:
             # first-step sweep, before any update mutates the state
             self._pg_check_pending = False

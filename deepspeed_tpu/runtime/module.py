@@ -68,6 +68,15 @@ class TrainModule:
         config error rather than a silent no-op)."""
         return None
 
+    def labelled_rows(self, batch) -> Optional[int]:
+        """Optional: rows of a HOST batch (numpy leaves, as
+        ``train_batch`` receives it) that carry a label, for a model
+        whose head reads the labelled rows alone (models/bert.py).  With
+        telemetry on the engine sets ``train_head_rows{kind="labelled"}``
+        from it; None (a batch already on the device, or a head that
+        reads every row) sets nothing."""
+        return None
+
     def sparse_grad_tokens(self, batch) -> dict:
         """Optional: declare embedding-style params whose gradient rows are
         only the batch's token rows.  Returns {param keystr: token-id

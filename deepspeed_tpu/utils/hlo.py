@@ -1,6 +1,7 @@
 """Read the collectives, the weights a program moves before it uses them,
-the fusions that draw random bits an element, and how often each Mosaic
-kernel runs, out of a compiled program's text.
+the fusions that draw random bits an element, how often each Mosaic
+kernel runs, and which matmuls lie under control flow the device decides,
+out of a compiled program's text.
 
 ``compiled.as_text()`` is the program after the SPMD partitioner: what a
 placement rule (runtime/zero.py) really costs is the collectives found
@@ -10,7 +11,9 @@ copies that write a parameter again before a matmul reads it
 (``parameter_rewrites``); what a random draw over an activation costs is
 the threefry rounds fused into whatever reads the mask (``rng_fusions``);
 what a remat policy saves or recomputes is how often a kernel runs a call
-of the program (``kernel_calls``).
+of the program (``kernel_calls``); whether a skip is a branch the device
+takes or a ``select`` over work done anyway is where its matmuls lie
+(``matmuls``).
 Bytes and counts only — no time is read from a program's text (a fusion's
 ``estimated_cycles`` is the compiler's guess, and is reported as that).
 """
@@ -88,23 +91,28 @@ def _computations(hlo_text: str):
 
 
 def _reached(comps: Dict[str, list], entry: str):
-    """(computation, line, result type, op, times, in_loop) of every
-    instruction the entry computation reaches, ``times`` the product of
-    the known trip counts of the loops around it (a loop whose count the
-    compiler does not state counts once, and is still a loop)."""
+    """(computation, line, result type, op, times, in_loop, at_run_time)
+    of every instruction the entry computation reaches, ``times`` the
+    product of the known trip counts of the loops around it (a loop whose
+    count the compiler does not state counts once, and is still a loop);
+    ``at_run_time``: whether it runs, or how often, is decided on the
+    device: it lies in a branch of a ``conditional`` or in a loop of no
+    stated count."""
 
-    def trip_count(while_line: str) -> int:
+    def trip_count(while_line: str):
         """The count the compiler states, else (the TPU's text states
-        none) the one bound a counted loop's condition compares with."""
+        none) the one bound a counted loop's condition compares with,
+        else None: the count is a value of the run."""
         stated = _TRIP.search(while_line)
         if stated:
             return int(stated.group(1))
         cond = _COND.search(while_line)
         bounds = {int(n) for line in comps.get(cond.group(1), ())
                   for n in _CONST.findall(line)} if cond else set()
-        return bounds.pop() if len(bounds) == 1 else 1
+        return bounds.pop() if len(bounds) == 1 else None
 
-    def walk(name: str, times: int, in_loop: bool, seen: tuple):
+    def walk(name: str, times: int, in_loop: bool, at_run_time: bool,
+             seen: tuple):
         if name not in comps or name in seen:
             return
         for line in comps[name]:
@@ -112,17 +120,19 @@ def _reached(comps: Dict[str, list], entry: str):
             if not m:
                 continue
             type_text, op = m.groups()
-            yield name, line, type_text, op, times, in_loop
+            yield name, line, type_text, op, times, in_loop, at_run_time
             callees = _CALLEE.findall(line)
             for group in _BRANCHES.findall(line):
                 callees += [c.strip().lstrip("%") for c in group.split(",")]
             loop = op == "while"
             n = trip_count(line) if loop else 1
             for callee in callees:
-                yield from walk(callee, times * n, in_loop or loop,
-                                seen + (name,))
+                yield from walk(
+                    callee, times * (n or 1), in_loop or loop,
+                    at_run_time or n is None or op == "conditional",
+                    seen + (name,))
 
-    return walk(entry, 1, False, ())
+    return walk(entry, 1, False, False, ())
 
 
 def collectives(hlo_text: str) -> List[Collective]:
@@ -133,7 +143,7 @@ def collectives(hlo_text: str) -> List[Collective]:
     # (start, steps, done) that each repeat the instruction under its one
     # channel: counted once
     channels = set()
-    for _, line, type_text, op, times, in_loop in _reached(
+    for _, line, type_text, op, times, in_loop, _ in _reached(
             *_computations(hlo_text)):
         base = op[:-len("-start")] if op.endswith("-start") else op
         channel = _CHANNEL.search(line)
@@ -239,7 +249,7 @@ def rng_fusions(hlo_text: str, elements: int = 1 << 20,
     comps, entry = _computations(hlo_text)
     # (where it is called, its line, times, the computation it calls)
     fusions = [(name, line, times, _CALLEE.search(line).group(1))
-               for name, line, _, op, times, _ in _reached(comps, entry)
+               for name, line, _, op, times, _, _ in _reached(comps, entry)
                if op == "fusion" and _CALLEE.search(line)]
     fused = {callee for *_, callee in fusions}
 
@@ -288,8 +298,29 @@ def kernel_calls(hlo_text: str) -> Dict[str, int]:
     around it.  A layer scan's forward loop holds a kernel once a layer,
     its backward loop once more where the block's remat runs it again."""
     found: Dict[str, int] = {}
-    for _, line, _, op, times, _ in _reached(*_computations(hlo_text)):
+    for _, line, _, op, times, _, _ in _reached(*_computations(hlo_text)):
         if op == "custom-call" and _MOSAIC in line:
             kernel = _SUFFIX.sub("", _NAMED.match(line).group(1))
             found[kernel] = found.get(kernel, 0) + times
     return found
+
+
+class Matmul(NamedTuple):
+    instruction: str   # the ``dot`` (the TPU's text: ``convolution``)
+    shapes: tuple      # result arrays: ((dtype, dims), ...)
+    at_run_time: bool  # in a branch of a ``conditional`` or in a loop
+    #                    whose trip count is a value of the run
+
+
+def matmuls(hlo_text: str) -> List[Matmul]:
+    """Every matmul the program holds, fused or not, and whether the
+    device decides at run time if (or how often) it runs: a
+    ``lax.cond`` that stayed a branch and a ``while_loop`` of a traced
+    trip count do; a ``cond`` that became a ``select`` under ``vmap``,
+    and a ``scan``, do not (models/mlm_head.py walks the labelled rows
+    in such a loop)."""
+    return [Matmul(_NAMED.match(line).group(1), tuple(_arrays(type_text)),
+                   at_run_time)
+            for _, line, type_text, op, _, _, at_run_time in _reached(
+                *_computations(hlo_text))
+            if op in ("dot", "convolution")]

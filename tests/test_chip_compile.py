@@ -1376,18 +1376,43 @@ def _bert_large_step(one_chip, saved):
     return _train_step_text(BertModel(BERT_LARGE), batch, one_chip, saved)
 
 
+@pytest.fixture(scope="module")
+def bert_large_step(one_chip):
+    return _bert_large_step(one_chip, saved=True)
+
+
 def test_the_step_runs_the_flash_forward_once_a_layer_when_its_output_is_kept(
-        one_chip):
+        bert_large_step):
     """``utils/hlo.py::kernel_calls`` on the chip's text, loops counted:
     BERT-large's step holds 24 ``ds_flash_fwd`` calls (48 under the bare
     checkpoint, the parent's: the test below; GPT-2's, on four chips:
     the last test), the backward kernels run once a layer, and no fusion
     draws random bits an element."""
     from deepspeed_tpu.utils.hlo import kernel_calls
-    text = _bert_large_step(one_chip, saved=True)
-    assert kernel_calls(text) == {
+    assert kernel_calls(bert_large_step) == {
         "ds_flash_fwd": 24, "ds_flash_bwd_dq": 24, "ds_flash_bwd_dkv": 24}
-    assert rng_fusions(text) == []
+    assert rng_fusions(bert_large_step) == []
+
+
+def test_the_step_holds_the_logits_of_one_block_of_labelled_rows(
+        bert_large_step):
+    """PR 47, on the chip's text: no array of every row's 30,522 logits
+    (the parent's text names ``[rows, 512, 30522]`` in float32 and bf16), and
+    the decoder's three matmuls lie in a ``while`` whose trip count the
+    text does not state: the label count decides how often they run."""
+    from deepspeed_tpu.ops.mlm_head import HEAD_BLOCK_ROWS
+    from deepspeed_tpu.utils.hlo import _arrays, matmuls
+    held = {dims for _, dims in _arrays(bert_large_step)}
+    assert not {(BERT_STACK_ROWS, 512, 30522),
+                (BERT_STACK_ROWS * 512, 30522)} & held
+    decoder = [m for m in matmuls(bert_large_step)
+               if any(30522 in dims for _, dims in m.shapes)]
+    assert sorted(dims for m in decoder for _, dims in m.shapes) == [
+        (HEAD_BLOCK_ROWS, 30522), (30522, 1024)], decoder
+    assert all(m.at_run_time for m in decoder)
+    walked = [m for m in matmuls(bert_large_step) if m.at_run_time]
+    assert (HEAD_BLOCK_ROWS, 1024) in {
+        dims for m in walked for _, dims in m.shapes}       # dlogits @ E
 
 
 def test_the_bare_checkpoint_runs_the_flash_forward_twice_a_layer(

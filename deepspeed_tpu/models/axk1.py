@@ -82,14 +82,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..moe.dropless import dropless_moe, route_sigmoid_topk
-from .mimo_v2 import _at, _dense_ffn, _stacked_experts
-from .mimo_v2 import grouped_causal_attention
-from .nemotron_h import _aux as _held_expert_counters
-from .nemotron_h import _row_index, _rows_view, _write_rows
-from .olmoe import rms_norm, rope
+from .walked import (F32, PagePool, ServedConfig, WalkedModel, at,
+                     causal_self_attention, decode_index, dense_ffn,
+                     draw_layers, held_expert_counters, lm_head, merge_heads,
+                     prefill_index, project_heads, rms_norm, rope,
+                     routed_experts, stacked_experts, swiglu)
 
-F32 = jnp.float32
 _LANES = 128
 #: keys a step of :func:`_paged_context_attention` expands (whole pages)
 _CONTEXT_BLOCK = 256
@@ -101,7 +99,7 @@ def _whole_tiles(width: int) -> int:
 
 
 @dataclasses.dataclass(frozen=True)
-class AxK1Config:
+class AxK1Config(ServedConfig):
     """The source's keys (HF ``config.json``), then the program's own."""
     vocab_size: int = 163840
     hidden_size: int = 7168
@@ -159,22 +157,13 @@ class AxK1Config:
             "rope_scaling mscale != mscale_all_dim (cos and sin scaled)":
                 rs.get("mscale", 1) != rs.get("mscale_all_dim", 1),
         }
-        bad = [k for k, v in unbuilt.items() if v]
-        if bad:
-            raise ValueError("AxK1Config: not built: " + "; ".join(bad))
+        self.check(unbuilt, self.n_routed_experts)
         if self.qk_rope_head_dim % 2:
             raise ValueError("qk_rope_head_dim must be even")
         if not 0 <= self.first_k_dense_replace <= self.num_hidden_layers:
             raise ValueError("first_k_dense_replace: 0 .. num_hidden_layers")
         if self.num_experts_per_tok > self.n_routed_experts:
             raise ValueError("num_experts_per_tok exceeds n_routed_experts")
-        first, count = self.held
-        if first < 0 or count < 1 or first + count > self.n_routed_experts:
-            raise ValueError(f"experts_held {self.experts_held}: not a "
-                             f"range of the {self.n_routed_experts}")
-        if self.attn_impl not in ("flash", "dense"):
-            raise ValueError(f"attn_impl {self.attn_impl!r}: 'flash' or "
-                             "'dense'")
 
     @property
     def held(self) -> Tuple[int, int]:
@@ -200,10 +189,6 @@ class AxK1Config:
         return self.num_hidden_layers
 
     @property
-    def n_head(self) -> int:
-        return self.num_attention_heads
-
-    @property
     def n_kv_head(self) -> int:
         """The pool's rows belong to no head: one a token."""
         return 1
@@ -222,10 +207,6 @@ class AxK1Config:
     def values_in_keys(self) -> bool:
         """ONE pool (``PagedKVCacheSpec.values_in_keys``)."""
         return True
-
-    @property
-    def n_positions(self) -> int:
-        return self.max_position_embeddings
 
 
 def yarn_inv_freq(cfg: AxK1Config) -> Optional[np.ndarray]:
@@ -267,19 +248,11 @@ def softmax_scale(cfg: AxK1Config) -> float:
     return float(np.float32(cfg.qk_head_dim ** -0.5 * m * m))
 
 
-class AxK1Model:
-    #: arms these paged steps do not have (the prefix cache and chunked
-    #: prefill they do: a request keeps pages and nothing else)
-    serving_unsupported = ("slot_cache", "speculate_k", "quantization",
-                           "lora")
-    serving_aux = ("moe_experts_hit", "moe_load_imbalance", "moe_rows",
-                   "moe_rows_elsewhere", "latent_kv_tokens")
-
-    def __init__(self, config: AxK1Config):
-        self.config = config
-
-    def param_partition_specs(self, params):
-        return None                     # one chip: everything replicated
+class AxK1Model(WalkedModel):
+    #: ``serving_unsupported`` is the common one: arms these paged steps
+    #: do not have (the prefix cache and chunked prefill they do: a
+    #: request keeps pages and nothing else)
+    serving_aux = WalkedModel.serving_aux + ("latent_kv_tokens",)
 
     def serving_cache_layers(self) -> Dict[str, int]:
         """Layers by the kind of cache they keep."""
@@ -342,12 +315,7 @@ class AxK1Model:
             if not n:
                 continue
             of = jax.random.split(key, n)
-            draw = jax.jit(layer)       # one computation a layer
-            drawn = [{**draw(of[i]),
-                      **{k: jnp.ones((w,), dt)
-                         for k, w in ones[name].items()}} for i in range(n)]
-            out[name] = {leaf: tuple(one[leaf] for one in drawn)
-                         for leaf in drawn[0]}
+            out[name] = draw_layers(layer, of, ones[name], dt)
             if whole:
                 out[name].update(jax.lax.map(whole, of))
         return out
@@ -365,35 +333,87 @@ class AxK1Model:
         logits, stats = _layers(cfg, params, tokens, positions, None, attend)
         return (logits, _aux(cfg, stats, 0)) if aux else logits
 
-    def prefill_paged(self, params, tokens, delta_len, prefix_len, page_row,
-                      k_pool, v_pool=None, *, aux: bool = False, **unbuilt):
-        _refuse(unbuilt)
-        return axk1_prefill_paged(self.config, params, tokens, delta_len,
-                                  prefix_len, page_row, k_pool, aux=aux)
-
     def decode_step_paged(self, params, tokens, k_pool, v_pool, page_table,
                           lengths, active, *, impl: Optional[str] = None,
                           aux: bool = False, **unbuilt):
-        _refuse(unbuilt)
-        return axk1_decode_step_paged(self.config, params, tokens, k_pool,
-                                      page_table, lengths, active,
-                                      impl=impl, aux=aux)
+        """One decode tick of every slot in the ABSORBED form over the one
+        pool ``k_pool`` ``[L, pages, 1, page_len, latent_width]``;
+        ``gpt2_decode_step_paged``'s contract with None where a second
+        pool would be.  Returns (logits [S, V], pool, None, new_lengths)
+        and, with ``aux``, the tick's counters.  An inactive slot's pages
+        are neither read nor written."""
+        from ..ops.pallas.decode_attention import latent_decode_attention
+        self.refuse(unbuilt)
+        cfg, impl = self.config, self.decode_impl(impl)
+        page_len, width = k_pool.shape[3], k_pool.shape[4]
+        scale = softmax_scale(cfg)
+        lengths, positions, att_len, page_ids, offs = decode_index(
+            page_table, lengths, active, page_len, cfg.n_positions)
+        pool = PagePool((k_pool,), page_ids, offs, active)
 
+        def attend(layer, ap, q_nope, q_rope, c_kv, k_rope):
+            pool.write(layer, _cached_rows(cfg, c_kv[:, 0], k_rope[:, 0]))
+            with jax.named_scope("absorb"):
+                q_lat = jnp.einsum("shn,hnc->shc", q_nope[:, :, 0],
+                                   ap["k_b_w"].astype(q_nope.dtype))
+            # a head's query in the rows' own layout: [q_lat ; q_rope ; 0]
+            o_lat = latent_decode_attention(
+                _cached_rows(cfg, q_lat, q_rope[:, :, 0]),
+                pool.rows[0].reshape(-1, page_len, width),
+                page_table + layer * pool.per_layer, att_len,
+                cfg.kv_lora_rank, sm_scale=scale, impl=impl)
+            with jax.named_scope("absorb"):
+                out = jnp.einsum("shc,hcv->shv", o_lat,
+                                 ap["v_b_w"].astype(o_lat.dtype))
+            return out[:, :, None]
 
-def _refuse(unbuilt: dict) -> None:
-    asked = sorted(k for k, v in unbuilt.items() if v is not None)
-    if asked:
-        raise NotImplementedError(
-            f"AxK1Model's paged steps have no {', '.join(asked)} arm")
+        logits, stats = _layers(cfg, params, tokens[:, None],
+                                positions[:, None], active, attend)
+        out = (logits[:, 0], *pool.arrays(), None,
+               lengths + active.astype(jnp.int32))
+        if aux:
+            out += (_aux(cfg, stats, jnp.sum(att_len) * cfg.n_layer),)
+        return out
+
+    def prefill_paged(self, params, tokens, delta_len, prefix_len, page_row,
+                      k_pool, v_pool=None, *, aux: bool = False, **unbuilt):
+        """Delta-aware prefill of one request in the EXPANDED form, its
+        latent rows written to the one pool ``k_pool``;
+        ``gpt2_prefill_paged``'s contract: tokens [1, Tq] are the prompt
+        less its cached prefix, right-padded to the bucket; ``delta_len``,
+        ``prefix_len`` and ``page_row`` [max_pages] are traced.  Returns
+        (logits [1, Tq, V], pool, None); ``logits[0, delta_len - 1]``
+        scores the first generated token.  Padding rows reach no expert
+        and write no page."""
+        self.refuse(unbuilt)
+        cfg = self.config
+        page_len, width = k_pool.shape[3], k_pool.shape[4]
+        prefix_len = jnp.asarray(prefix_len, jnp.int32)
+        delta_len = jnp.asarray(delta_len, jnp.int32)
+        valid, page_ids, offs, abs_pos, positions = prefill_index(
+            page_row, delta_len, tokens.shape[1], page_len, prefix_len,
+            cfg.n_positions)
+        pool = PagePool((k_pool,), page_ids, offs, valid)
+
+        def attend(layer, ap, q_nope, q_rope, c_kv, k_rope):
+            pool.write(layer, _cached_rows(cfg, c_kv[0], k_rope[0]))
+            return jax.lax.cond(
+                prefix_len == 0,
+                lambda _: _self_attention(cfg, ap, q_nope, q_rope, c_kv,
+                                          k_rope),
+                lambda _: _paged_context_attention(
+                    cfg, ap, q_nope[0], q_rope[0],
+                    pool.rows[0].reshape(-1, page_len, width),
+                    layer * pool.per_layer + page_row, abs_pos,
+                    prefix_len + delta_len)[None],
+                None)
+
+        logits, stats = _layers(cfg, params, tokens, positions, valid, attend)
+        out = (logits, *pool.arrays(), None)
+        return out + (_aux(cfg, stats, 0),) if aux else out
 
 
 # -- the layer's parts ----------------------------------------------------
-
-def _heads(t, n: int):
-    """[B, T, n * w] -> [B, n, T, w]."""
-    B, T, _ = t.shape
-    return t.reshape(B, T, n, -1).transpose(0, 2, 1, 3)
-
 
 def _latents(cfg: AxK1Config, ap, h, positions):
     """h [B, T, d] (normed), positions [B, T] -> q_nope [B, H, T, nope],
@@ -402,7 +422,7 @@ def _latents(cfg: AxK1Config, ap, h, positions):
     eps, inv_freq = cfg.rms_norm_eps, yarn_inv_freq(cfg)
     with jax.named_scope("latent_q"):
         c_q = rms_norm(h @ ap["q_a_w"].astype(h.dtype), ap["q_a_norm"], eps)
-        q = _heads(c_q @ ap["q_b_w"].astype(h.dtype), cfg.n_head)
+        q = project_heads(c_q, ap["q_b_w"], cfg.n_head)
         q_nope = q[..., :cfg.qk_nope_head_dim]
         q_rope = rope(q[..., cfg.qk_nope_head_dim:], positions,
                       cfg.rope_theta, inv_freq=inv_freq)
@@ -442,11 +462,8 @@ def _self_attention(cfg: AxK1Config, ap, q_nope, q_rope, c_kv, k_rope):
                             - cfg.qk_head_dim),)
     q = jnp.pad(jnp.concatenate([q_nope, q_rope], axis=-1), pad)
     k = jnp.pad(jnp.concatenate([k_nope, k_rope], axis=-1), pad)
-    scale = softmax_scale(cfg)
-    if cfg.attn_impl == "flash":
-        from ..ops.pallas.flash_attention import flash_attention_fwd
-        return flash_attention_fwd(q, k, v, sm_scale=scale)
-    return grouped_causal_attention(q, k, v, sm_scale=scale)
+    return causal_self_attention(q, k, v, cfg.attn_impl == "flash",
+                                 sm_scale=softmax_scale(cfg))
 
 
 def _paged_context_attention(cfg: AxK1Config, ap, q_nope, q_rope, pool_pages,
@@ -498,9 +515,8 @@ def _paged_context_attention(cfg: AxK1Config, ap, q_nope, q_rope, pool_pages,
 
 def _shared_expert(ep, x):
     with jax.named_scope("shared_expert"):
-        g = x @ ep["shared_gate_w"].astype(x.dtype)
-        u = x @ ep["shared_up_w"].astype(x.dtype)
-        return (jax.nn.silu(g) * u) @ ep["shared_down_w"].astype(x.dtype)
+        return swiglu(x, ep["shared_gate_w"], ep["shared_up_w"],
+                      ep["shared_down_w"])
 
 
 def _experts(cfg: AxK1Config, ep, stacked, index: int, x, valid):
@@ -508,15 +524,11 @@ def _experts(cfg: AxK1Config, ep, stacked, index: int, x, valid):
     routed sum and the shared expert whole.  ``stacked``: every layer's
     held experts flat."""
     with jax.named_scope("moe"):
-        routing = route_sigmoid_topk(
+        routed, st = routed_experts(
             x, ep["router_w"], jnp.zeros((cfg.n_routed_experts,), F32),
-            cfg.num_experts_per_tok, scale=cfg.routed_scaling_factor,
+            stacked, index, top_k=cfg.num_experts_per_tok, held=cfg.held,
+            valid=valid, act="swiglu", scale=cfg.routed_scaling_factor,
             renormalize=cfg.norm_topk_prob)
-        routed, st = dropless_moe(
-            x, ep["router_w"], stacked["gate_w"], stacked["up_w"],
-            stacked["down_w"], cfg.num_experts_per_tok,
-            expert_offset=index * cfg.held[1], valid=valid,
-            routing=routing, experts_held=cfg.held, act="swiglu")
     if cfg.n_shared_experts:
         routed = routed + _shared_expert(ep, x)
     return routed, st
@@ -527,10 +539,10 @@ def _ffn(cfg: AxK1Config, params, stacked, layer: int, x, valid, stats):
     appended to ``stats``."""
     dense = cfg.first_k_dense_replace
     kind, i = ("dense", layer) if layer < dense else ("moe", layer - dense)
-    fp = _at(params[kind], i)
+    fp = at(params[kind], i)
     h = rms_norm(x, fp["ln2"], cfg.rms_norm_eps)
     if kind == "dense":
-        return x + _dense_ffn(fp, h)
+        return x + dense_ffn(fp, h)
     out, st = _experts(cfg, fp, stacked, i, h, valid)
     stats.append(st)
     return x + out
@@ -540,14 +552,8 @@ def _aux(cfg: AxK1Config, stats, latent_kv_tokens) -> Dict[str, jnp.ndarray]:
     """The call's counters: the expert layers' as ``NemotronHModel``'s
     (of the HELD experts), and ``latent_kv_tokens``: the live rows the
     decode kernel read, summed over layers (0 in a prefill)."""
-    return {**_held_expert_counters(cfg, stats),
+    return {**held_expert_counters(stats, cfg.held[1]),
             "latent_kv_tokens": jnp.asarray(latent_kv_tokens, jnp.int32)}
-
-
-@jax.named_scope("lm_head")
-def _lm_head(cfg: AxK1Config, params, x):
-    x = rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
-    return x @ params["lm_head"].astype(x.dtype)
 
 
 def _layers(cfg: AxK1Config, params, tokens, positions, valid, attend):
@@ -557,115 +563,19 @@ def _layers(cfg: AxK1Config, params, tokens, positions, valid, attend):
     cache keeps); ``valid`` [B * T] bool leaves padding out of the expert
     layers.  Returns (logits, the expert layers' statistics)."""
     B, T = tokens.shape
-    stacked = _stacked_experts(params) if cfg.count("moe") else None
+    stacked = stacked_experts(params) if cfg.count("moe") else None
     stats = []
     with jax.named_scope("embed"):
         x = params["wte"][tokens]
     for layer in range(cfg.num_hidden_layers):
         with jax.named_scope("layer"):
-            ap = _at(params["attn"], layer)
+            ap = at(params["attn"], layer)
             with jax.named_scope("attn"):
                 h = rms_norm(x, ap["ln1"], cfg.rms_norm_eps)
                 out = attend(layer, ap, *_latents(cfg, ap, h, positions))
-                merged = out.transpose(0, 2, 1, 3).reshape(B, T, -1)
-                x = x + merged @ ap["o_w"].astype(x.dtype)
+                x = x + merge_heads(out) @ ap["o_w"].astype(x.dtype)
             x = _ffn(cfg, params, stacked, layer, x.reshape(B * T, -1),
                      valid, stats).reshape(x.shape)
-    return _lm_head(cfg, params, x), stats
-
-
-# -- the paged steps ------------------------------------------------------
-
-def axk1_decode_step_paged(cfg: AxK1Config, params, tokens, pool, page_table,
-                           lengths, active, impl: Optional[str] = None,
-                           aux: bool = False):
-    """One decode tick of every slot in the ABSORBED form over the one
-    pool ``[L, pages, 1, page_len, latent_width]``;
-    ``gpt2_decode_step_paged``'s contract with None where a second pool
-    would be.  Returns (logits [S, V], pool, None, new_lengths) and, with
-    ``aux``, the tick's counters.  An inactive slot's pages are neither
-    read nor written."""
-    from ..ops.pallas.decode_attention import latent_decode_attention
-    if impl is None:
-        impl = "pallas" if cfg.attn_impl == "flash" else "dense"
-    shape = pool.shape
-    pages, page_len, width = shape[1], shape[3], shape[4]
-    S = page_table.shape[0]
-    cap = page_table.shape[1] * page_len
-    scale = softmax_scale(cfg)
-    lengths = lengths.astype(jnp.int32)
-    positions = jnp.clip(lengths, 0, min(cap, cfg.n_positions) - 1)
-    att_len = jnp.where(active, lengths + 1, 0).astype(jnp.int32)
-    page_ids = jnp.where(
-        active, page_table[jnp.arange(S), positions // page_len], 0)
-    offs = positions % page_len
-    rows = [_rows_view(pool)]
-
-    def attend(layer, ap, q_nope, q_rope, c_kv, k_rope):
-        index = _row_index(layer * pages + page_ids, offs, 1, page_len)
-        rows[0] = _write_rows(
-            rows[0], _cached_rows(cfg, c_kv[:, 0], k_rope[:, 0]), index,
-            active)
-        with jax.named_scope("absorb"):
-            q_lat = jnp.einsum("shn,hnc->shc", q_nope[:, :, 0],
-                               ap["k_b_w"].astype(q_nope.dtype))
-        # a head's query in the rows' own layout: [q_lat ; q_rope ; 0]
-        o_lat = latent_decode_attention(
-            _cached_rows(cfg, q_lat, q_rope[:, :, 0]),
-            rows[0].reshape(-1, page_len, width),
-            page_table + layer * pages, att_len, cfg.kv_lora_rank,
-            sm_scale=scale, impl=impl)
-        with jax.named_scope("absorb"):
-            out = jnp.einsum("shc,hcv->shv", o_lat,
-                             ap["v_b_w"].astype(o_lat.dtype))
-        return out[:, :, None]
-
-    logits, stats = _layers(cfg, params, tokens[:, None], positions[:, None],
-                            active, attend)
-    out = (logits[:, 0], rows[0].reshape(shape), None,
-           lengths + active.astype(jnp.int32))
-    if aux:
-        out += (_aux(cfg, stats, jnp.sum(att_len) * cfg.n_layer),)
-    return out
-
-
-def axk1_prefill_paged(cfg: AxK1Config, params, tokens, delta_len,
-                       prefix_len, page_row, pool, aux: bool = False):
-    """Delta-aware prefill of one request in the EXPANDED form, its
-    latent rows written to the pool; ``gpt2_prefill_paged``'s contract:
-    tokens [1, Tq] are the prompt less its cached prefix, right-padded to
-    the bucket; ``delta_len``, ``prefix_len`` and ``page_row``
-    [max_pages] are traced.  Returns (logits [1, Tq, V], pool, None);
-    ``logits[0, delta_len - 1]`` scores the first generated token.
-    Padding rows reach no expert and write no page."""
-    B, Tq = tokens.shape
-    shape = pool.shape
-    pages, page_len, width = shape[1], shape[3], shape[4]
-    cap = page_row.shape[0] * page_len
-    prefix_len = jnp.asarray(prefix_len, jnp.int32)
-    delta_len = jnp.asarray(delta_len, jnp.int32)
-    abs_pos = prefix_len + jnp.arange(Tq, dtype=jnp.int32)
-    valid = jnp.arange(Tq) < delta_len
-    abs_clip = jnp.clip(abs_pos, 0, cap - 1)
-    page_ids = jnp.where(valid, page_row[abs_clip // page_len], 0)
-    offs = abs_clip % page_len
-    positions = jnp.clip(abs_pos, 0, cfg.n_positions - 1)[None]
-    rows = [_rows_view(pool)]
-
-    def attend(layer, ap, q_nope, q_rope, c_kv, k_rope):
-        index = _row_index(layer * pages + page_ids, offs, 1, page_len)
-        rows[0] = _write_rows(rows[0], _cached_rows(cfg, c_kv[0], k_rope[0]),
-                              index, valid)
-        return jax.lax.cond(
-            prefix_len == 0,
-            lambda _: _self_attention(cfg, ap, q_nope, q_rope, c_kv, k_rope),
-            lambda _: _paged_context_attention(
-                cfg, ap, q_nope[0], q_rope[0],
-                rows[0].reshape(-1, page_len, width),
-                layer * pages + page_row, abs_pos,
-                prefix_len + delta_len)[None],
-            None)
-
-    logits, stats = _layers(cfg, params, tokens, positions, valid, attend)
-    out = (logits, rows[0].reshape(shape), None)
-    return out + (_aux(cfg, stats, 0),) if aux else out
+    logits = lm_head(x, params["norm_f"], params["lm_head"],
+                     cfg.rms_norm_eps)
+    return logits, stats

@@ -73,17 +73,17 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..moe.dropless import dropless_moe, route_sigmoid_topk
-from .mimo_v2 import _at, grouped_causal_attention
-from .nemotron_h import _aux as _held_expert_counters
-from .nemotron_h import _row_index, _rows_view, _write_rows
+from .walked import (F32, PagePool, Rings, ServedConfig, WalkedModel, at,
+                     causal_self_attention, decode_index, default_scale,
+                     draw_layers, held_expert_counters, merge_heads,
+                     prefill_index, project_heads, ring_positions,
+                     routed_experts, stacked_experts, swiglu, write_slot_state)
 
-F32 = jnp.float32
 _KINDS = {"full_attention": "full", "sliding_attention": "window"}
 
 
 @dataclasses.dataclass(frozen=True)
-class Cohere2MoeConfig:
+class Cohere2MoeConfig(ServedConfig):
     """The source's keys (HF ``config.json``), then the program's own."""
     vocab_size: int = 262144
     hidden_size: int = 4096
@@ -146,10 +146,7 @@ class Cohere2MoeConfig:
             f"hidden_act {self.hidden_act!r} ungated or not 'silu'":
                 self.hidden_act != "silu" or not self.use_gated_activation,
         }
-        bad = [k for k, v in unbuilt.items() if v]
-        if bad:
-            raise ValueError("Cohere2MoeConfig: not built: "
-                             + "; ".join(bad))
+        self.check(unbuilt, self.num_experts)
         if len(self.layer_types) != self.num_hidden_layers \
                 or set(self.layer_types) - set(_KINDS):
             raise ValueError(
@@ -162,13 +159,6 @@ class Cohere2MoeConfig:
             raise ValueError("head_dim must be even: it is rotated in pairs")
         if self.num_experts_per_tok > self.num_experts:
             raise ValueError("num_experts_per_tok exceeds num_experts")
-        first, count = self.held
-        if first < 0 or count < 1 or first + count > self.num_experts:
-            raise ValueError(f"experts_held {self.experts_held}: not a "
-                             f"range of the {self.num_experts}")
-        if self.attn_impl not in ("flash", "dense"):
-            raise ValueError(f"attn_impl {self.attn_impl!r}: 'flash' or "
-                             "'dense'")
 
     @property
     def held(self) -> Tuple[int, int]:
@@ -189,38 +179,19 @@ class Cohere2MoeConfig:
         return self.count("full")
 
     @property
-    def n_head(self) -> int:
-        return self.num_attention_heads
-
-    @property
-    def n_kv_head(self) -> int:
-        return self.num_key_value_heads
-
-    @property
     def d_head(self) -> int:
         return self.head_dim
 
-    @property
-    def n_positions(self) -> int:
-        return self.max_position_embeddings
 
-
-class Cohere2MoeModel:
-    #: chunked prefill works (module docstring).  The prefix cache, KV
-    #: tiering, the slot cache and speculation are refused by the engine
-    #: for any model with ``serving_state``; int8 and LoRA are arms these
-    #: paged steps do not have
-    serving_unsupported = ("slot_cache", "speculate_k", "quantization",
-                           "lora")
-    serving_aux = ("moe_experts_hit", "moe_load_imbalance", "moe_rows",
-                   "moe_rows_elsewhere", "full_kv_tokens", "window_kv_rows",
-                   "window_wrapped_slots", "flash_q_rows", "flash_live_keys")
-
-    def __init__(self, config: Cohere2MoeConfig):
-        self.config = config
-
-    def param_partition_specs(self, params):
-        return None                     # one chip: everything replicated
+class Cohere2MoeModel(WalkedModel):
+    #: ``serving_unsupported`` is the common one: chunked prefill works
+    #: (module docstring).  The prefix cache, KV tiering, the slot cache
+    #: and speculation are refused by the engine for any model with
+    #: ``serving_state``; int8 and LoRA are arms these paged steps do not
+    #: have
+    serving_aux = WalkedModel.serving_aux + (
+        "full_kv_tokens", "window_kv_rows", "window_wrapped_slots",
+        "flash_q_rows", "flash_live_keys")
 
     def serving_cache_layers(self) -> Dict[str, int]:
         """Layers by the kind of cache they keep."""
@@ -273,14 +244,11 @@ class Cohere2MoeModel:
                "norm_f": jnp.ones((d,), dt),
                "experts": jax.lax.map(experts, jax.random.split(
                    keys[1], cfg.num_hidden_layers))}
-        draw = jax.jit(layer)
         for name, key in (("full", keys[2]), ("window", keys[3])):
             n = cfg.count(name)
             if n:
-                drawn = [{**draw(k), "ln": jnp.ones((d,), dt)}
-                         for k in jax.random.split(key, n)]
-                out[name] = {leaf: tuple(one[leaf] for one in drawn)
-                             for leaf in drawn[0]}
+                out[name] = draw_layers(layer, jax.random.split(key, n),
+                                        {"ln": d}, dt)
         return out
 
     def apply(self, params, tokens, aux: bool = False):
@@ -296,29 +264,135 @@ class Cohere2MoeModel:
                                 None, attend)
         return (logits, _aux(self.config, stats)) if aux else logits
 
-    def prefill_paged(self, params, tokens, delta_len, prefix_len, page_row,
-                      k_pool, v_pool, *, state, slot, aux: bool = False,
-                      **unbuilt):
-        _refuse(unbuilt)
-        return cohere2_prefill_paged(
-            self.config, params, tokens, delta_len, prefix_len, page_row,
-            k_pool, v_pool, state, slot, aux=aux)
-
     def decode_step_paged(self, params, tokens, k_pool, v_pool, page_table,
                           lengths, active, *, state,
                           impl: Optional[str] = None, aux: bool = False,
                           **unbuilt):
-        _refuse(unbuilt)
-        return cohere2_decode_step_paged(
-            self.config, params, tokens, k_pool, v_pool, page_table,
-            lengths, active, state, impl=impl, aux=aux)
+        """One decode tick of every slot; ``gpt2_decode_step_paged``'s
+        contract plus the request state.  Returns (logits [S, V], k_pool,
+        v_pool, state, new_lengths) and, with ``aux``, the tick's
+        counters.  An inactive slot's pages and rings are neither read
+        nor written (a free slot's, and one that is still prefilling in
+        chunks)."""
+        self.refuse(unbuilt)
+        cfg, impl = self.config, self.decode_impl(impl)
+        scale = default_scale(cfg.head_dim)
+        lengths, positions, att_len, page_ids, offs = decode_index(
+            page_table, lengths, active, k_pool.shape[3], cfg.n_positions)
+        pool = PagePool((k_pool, v_pool), page_ids, offs, active)
+        rings = Rings(state["window_k"], state["window_v"], positions,
+                      active)
 
+        def attend(kind, i, q, k, v):
+            q, k, v = q[:, :, 0], k[:, :, 0], v[:, :, 0]
+            if kind == "full":
+                pool.write(i, k, v)
+                out = pool.attend(i, q, page_table, att_len, impl=impl,
+                                  sm_scale=scale)
+            else:
+                rings.write(i, k, v)
+                out = rings.attend(i, q, att_len, None, impl=impl,
+                                   sm_scale=scale)
+            return out[:, :, None]
 
-def _refuse(unbuilt: dict) -> None:
-    asked = sorted(k for k, v in unbuilt.items() if v is not None)
-    if asked:
-        raise NotImplementedError(
-            f"Cohere2MoeModel's paged steps have no {', '.join(asked)} arm")
+        logits, stats = _layers(cfg, params, tokens[:, None],
+                                positions[:, None], active, attend)
+        out = (logits[:, 0], *pool.arrays(),
+               dict(zip(("window_k", "window_v"), rings.arrays())),
+               lengths + active.astype(jnp.int32))
+        if aux:
+            W = rings.length
+            out += (_aux(cfg, stats, jnp.sum(att_len),
+                         jnp.sum(jnp.minimum(att_len, W)),
+                         jnp.sum(att_len > W)),)
+        return out
+
+    def prefill_paged(self, params, tokens, delta_len, prefix_len, page_row,
+                      k_pool, v_pool, *, state, slot, aux: bool = False,
+                      **unbuilt):
+        """Prefill of one request, or of one CHUNK of it, into the pool
+        (the full layers' keys) and into ``slot`` of the request state
+        (the window layers' rings).  tokens [1, Tq] are positions
+        ``prefix_len ..``, right-padded to the bucket; ``delta_len``,
+        ``prefix_len``, ``page_row`` [max_pages] and ``slot`` traced.
+        With ``prefix_len`` 0 nothing is read of either cache.  Else the
+        chunks before this one (the engine runs a request's in order,
+        into this slot) left what its queries need: a window layer reads
+        the slot's ring, a full layer the prefix's pages (module
+        docstring).  Returns (logits [1, Tq, V], k_pool, v_pool, state);
+        ``logits[0, delta_len - 1]`` scores the next token.  Of the
+        slot's rings, row ``r`` takes the last position before
+        ``prefix_len + delta_len`` that is ``r mod W`` if this call
+        computed it, and keeps what it held if not."""
+        self.refuse(unbuilt)
+        cfg, Tq = self.config, tokens.shape[1]
+        wk, wv = state["window_k"], state["window_v"]
+        W = wk.shape[3]
+        cap = page_row.shape[0] * k_pool.shape[3]
+        i32 = jnp.int32
+        delta_len = jnp.asarray(delta_len, i32)
+        prefix_len = jnp.asarray(prefix_len, i32)
+        slot = jnp.asarray(slot, i32)
+        valid, page_ids, offs, _, positions = prefill_index(
+            page_row, delta_len, Tq, k_pool.shape[3], prefix_len,
+            cfg.n_positions)
+        pool = PagePool((k_pool, v_pool), page_ids, offs, valid)
+        # ring row r: the last position before the end that is r mod W
+        ring_pos = ring_positions(prefix_len + delta_len, W)
+        ring_new = (ring_pos >= prefix_len)[None, :, None]
+        ring_src = jnp.clip(ring_pos - prefix_len, 0, Tq - 1)
+        rings = {"window_k": [], "window_v": []}
+
+        def slot_ring(leaf, i):
+            # one slice of the leaf: ``leaf[i]`` first would copy the layer
+            return jax.lax.dynamic_slice(
+                leaf, (i, slot, 0, 0, 0), (1, 1) + leaf.shape[2:])[0, 0]
+
+        def attend(kind, i, q, k, v):
+            if kind == "window":
+                old_k, old_v = slot_ring(wk, i), slot_ring(wv, i)
+                rings["window_k"].append(
+                    jnp.where(ring_new, k[0][:, ring_src], old_k))
+                rings["window_v"].append(
+                    jnp.where(ring_new, v[0][:, ring_src], old_v))
+
+                def context():
+                    with jax.named_scope("chunk_context"):
+                        # into position order: row (prefix_len + kk) % W
+                        shift = -jnp.mod(prefix_len, W)
+                        ctx_k, ctx_v = (jnp.roll(t, shift, axis=1)
+                                        for t in (old_k, old_v))
+                    return _context_attention(
+                        cfg, kind, q, k, v, ctx_k, ctx_v,
+                        jnp.minimum(prefix_len, W))
+            else:
+                pool.write(i, k[0].transpose(1, 0, 2),
+                           v[0].transpose(1, 0, 2))
+
+                def context():
+                    with jax.named_scope("chunk_context"):
+                        ctx_k, ctx_v = (
+                            _prefix_keys(t[i], page_row, prefix_len)
+                            for t in pool.arrays())
+                    return _context_attention(
+                        cfg, kind, q, k, v, ctx_k, ctx_v,
+                        jnp.minimum(prefix_len, cap))
+
+            return jax.lax.cond(prefix_len == 0,
+                                lambda: _self_attention(cfg, kind, q, k, v),
+                                context)
+
+        logits, stats = _layers(cfg, params, tokens, positions, valid, attend)
+        out = (logits, *pool.arrays(), write_slot_state(state, rings, slot))
+        if aux:
+            total = prefix_len + delta_len
+            pairs = (cfg.count("full") * _live_pairs(delta_len, prefix_len)
+                     + cfg.count("window") * _live_pairs(delta_len,
+                                                         prefix_len, W))
+            out += (_aux(cfg, stats, total, jnp.minimum(total, W),
+                         (total > W).astype(i32),
+                         delta_len * cfg.num_hidden_layers, pairs / 1024),)
+        return out
 
 
 # -- the layer's parts ----------------------------------------------------
@@ -350,23 +424,13 @@ def rope_interleaved(x, positions, theta: float):
             ).astype(x.dtype)
 
 
-def _sm_scale(cfg: Cohere2MoeConfig) -> float:
-    from ..ops.pallas.decode_attention import _default_scale
-    return _default_scale(cfg.head_dim)
-
-
 def _qkv(cfg: Cohere2MoeConfig, kind: str, lp, h, positions):
     """h [B, T, d] (normed), positions [B, T] -> q [B, Hq, T, D], k, v
     [B, Hkv, T, D]; a window layer's q and k rotated, a full layer's
     not."""
-    B, T, _ = h.shape
-
-    def heads(name, n):
-        return (h @ lp[name].astype(h.dtype)).reshape(
-            B, T, n, cfg.head_dim).transpose(0, 2, 1, 3)
-
-    q, k, v = (heads("q_w", cfg.n_head), heads("k_w", cfg.n_kv_head),
-               heads("v_w", cfg.n_kv_head))
+    q, k, v = (project_heads(h, lp["q_w"], cfg.n_head),
+               project_heads(h, lp["k_w"], cfg.n_kv_head),
+               project_heads(h, lp["v_w"], cfg.n_kv_head))
     if kind == "window":
         q, k = (rope_interleaved(t, positions, cfg.rope_theta)
                 for t in (q, k))
@@ -375,13 +439,10 @@ def _qkv(cfg: Cohere2MoeConfig, kind: str, lp, h, positions):
 
 def _self_attention(cfg: Cohere2MoeConfig, kind: str, q, k, v):
     """A whole sequence's attention from nothing ahead of it."""
-    window = cfg.sliding_window if kind == "window" else None
-    if cfg.attn_impl == "flash":
-        from ..ops.pallas.flash_attention import flash_attention_fwd
-        return flash_attention_fwd(q, k, v, window=window,
-                                   sm_scale=_sm_scale(cfg))
-    return grouped_causal_attention(q, k, v, window=window,
-                                    sm_scale=_sm_scale(cfg))
+    return causal_self_attention(
+        q, k, v, cfg.attn_impl == "flash",
+        window=cfg.sliding_window if kind == "window" else None,
+        sm_scale=default_scale(cfg.head_dim))
 
 
 def _context_attention(cfg: Cohere2MoeConfig, kind: str, q, k, v, ctx_k,
@@ -390,14 +451,14 @@ def _context_attention(cfg: Cohere2MoeConfig, kind: str, q, k, v, ctx_k,
     [Hkv, Tc, D] of which the LAST ``live`` (traced) are the positions
     just before the chunk, in order."""
     window = cfg.sliding_window if kind == "window" else None
+    scale = default_scale(cfg.head_dim)
     keys = jnp.concatenate([ctx_k[None].astype(k.dtype), k], axis=2)
     values = jnp.concatenate([ctx_v[None].astype(v.dtype), v], axis=2)
     if cfg.attn_impl == "flash":
         from ..ops.pallas.flash_attention import flash_attention_fwd
         return flash_attention_fwd(q, keys, values, window=window,
-                                   sm_scale=_sm_scale(cfg), ctx_live=live)
-    return _dense_context_attention(q, keys, values, live, window,
-                                    _sm_scale(cfg))
+                                   sm_scale=scale, ctx_live=live)
+    return _dense_context_attention(q, keys, values, live, window, scale)
 
 
 def _dense_context_attention(q, k, v, live, window, sm_scale):
@@ -421,9 +482,8 @@ def _dense_context_attention(q, k, v, live, window, sm_scale):
 def _shared_experts(cfg: Cohere2MoeConfig, lp, x):
     """The shared experts averaged: one SwiGLU as wide as all of them,
     times ``1 / num_shared_experts``."""
-    g = x @ lp["shared_gate_w"].astype(x.dtype)
-    u = x @ lp["shared_up_w"].astype(x.dtype)
-    y = (jax.nn.silu(g) * u) @ lp["shared_down_w"].astype(x.dtype)
+    y = swiglu(x, lp["shared_gate_w"], lp["shared_up_w"],
+               lp["shared_down_w"])
     return y * jnp.asarray(1.0 / cfg.num_shared_experts, y.dtype)
 
 
@@ -432,17 +492,12 @@ def _ffn(cfg: Cohere2MoeConfig, params, lp, layer: int, h, valid, stats):
     the routed sum + the shared experts whole.  The experts reach their
     kernels whole, every layer's held experts flat (a reshape of the
     leading axes), and the kernel finds a layer's by ``expert_offset``."""
-    flat = {k: w.reshape((-1,) + w.shape[2:])
-            for k, w in params["experts"].items()}
+    flat = stacked_experts(params, "experts")
     with jax.named_scope("moe"):
-        routing = route_sigmoid_topk(
-            h, lp["router_w"], jnp.zeros((cfg.num_experts,), F32),
-            cfg.num_experts_per_tok, renormalize=cfg.norm_topk_prob)
-        routed, st = dropless_moe(
-            h, lp["router_w"], flat["gate_w"], flat["up_w"], flat["down_w"],
-            cfg.num_experts_per_tok, expert_offset=layer * cfg.held[1],
-            valid=valid, routing=routing, experts_held=cfg.held,
-            act="swiglu")
+        routed, st = routed_experts(
+            h, lp["router_w"], jnp.zeros((cfg.num_experts,), F32), flat,
+            layer, top_k=cfg.num_experts_per_tok, held=cfg.held,
+            valid=valid, act="swiglu", renormalize=cfg.norm_topk_prob)
     stats.append(st)
     return routed + _shared_experts(cfg, lp, h)
 
@@ -461,7 +516,7 @@ def _aux(cfg: Cohere2MoeConfig, stats, full_kv_tokens=0, window_kv_rows=0,
     head, summed over layers (counted in units of 1,024: a float32 on
     the way to the host)."""
     i32 = jnp.int32
-    return {**_held_expert_counters(cfg, stats),
+    return {**held_expert_counters(stats, cfg.held[1]),
             "full_kv_tokens": jnp.asarray(full_kv_tokens, i32),
             "window_kv_rows": jnp.asarray(window_kv_rows, i32),
             "window_wrapped_slots": jnp.asarray(window_wrapped_slots, i32),
@@ -483,13 +538,12 @@ def _layers(cfg: Cohere2MoeConfig, params, tokens, positions, valid,
         x = params["wte"][tokens]
     for layer, kind in enumerate(cfg.kinds):
         with jax.named_scope("layer"):
-            lp = _at(params[kind], seen[kind])
+            lp = at(params[kind], seen[kind])
             h = layer_norm(x, lp["ln"], cfg.layer_norm_eps)
             with jax.named_scope("attn"), jax.named_scope("attn_" + kind):
                 q, k, v = _qkv(cfg, kind, lp, h, positions)
                 attn = attend(kind, seen[kind], q, k, v)
-                merged = attn.transpose(0, 2, 1, 3).reshape(B, T, -1)
-                branch = merged @ lp["o_w"].astype(x.dtype)
+                branch = merge_heads(attn) @ lp["o_w"].astype(x.dtype)
             ffn = _ffn(cfg, params, lp, layer, h.reshape(B * T, -1), valid,
                        stats).reshape(x.shape)
             x = x + branch + ffn
@@ -502,79 +556,7 @@ def _layers(cfg: Cohere2MoeConfig, params, tokens, positions, valid,
     return logits, stats
 
 
-# -- the paged steps ------------------------------------------------------
-
-def cohere2_decode_step_paged(cfg: Cohere2MoeConfig, params, tokens, k_pool,
-                              v_pool, page_table, lengths, active, state,
-                              impl: Optional[str] = None,
-                              aux: bool = False):
-    """One decode tick of every slot; ``gpt2_decode_step_paged``'s
-    contract plus the request state.  Returns (logits [S, V], k_pool,
-    v_pool, state, new_lengths) and, with ``aux``, the tick's counters.
-    An inactive slot's pages and rings are neither read nor written (a
-    free slot's, and one that is still prefilling in chunks)."""
-    from ..ops.pallas.decode_attention import (decode_attention_paged,
-                                               window_decode_attention)
-    if impl is None:
-        impl = "pallas" if cfg.attn_impl == "flash" else "dense"
-    k_shape, v_shape = k_pool.shape, v_pool.shape
-    pages, kv_heads, page_len = k_shape[1], k_shape[2], k_shape[3]
-    wk, wv = state["window_k"], state["window_v"]
-    W = wk.shape[3]
-    S = page_table.shape[0]
-    cap = page_table.shape[1] * page_len
-    scale = _sm_scale(cfg)
-    lengths = lengths.astype(jnp.int32)
-    positions = jnp.clip(lengths, 0, min(cap, cfg.n_positions) - 1)
-    att_len = jnp.where(active, lengths + 1, 0).astype(jnp.int32)
-    page_ids = jnp.where(
-        active, page_table[jnp.arange(S), positions // page_len], 0)
-    pool_index = (page_ids, positions % page_len)
-    ring_index = _row_index(jnp.arange(S, dtype=jnp.int32), positions % W,
-                            kv_heads, W)
-    keep = jnp.repeat(active, kv_heads)
-    rows = {"k": _rows_view(k_pool), "v": _rows_view(v_pool),
-            "wk": _rows_view(wk), "wv": _rows_view(wv)}
-
-    def write(name, new, index):
-        rows[name] = _write_rows(
-            rows[name], new[:, :, 0].reshape(-1, new.shape[-1]), index, keep)
-
-    def attend(kind, i, q, k, v):
-        q = q[:, :, 0]
-        if kind == "full":
-            index = _row_index(i * pages + pool_index[0], pool_index[1],
-                               kv_heads, page_len)
-            write("k", k, index)
-            write("v", v, index)
-            out = decode_attention_paged(
-                q, rows["k"].reshape((-1,) + k_shape[2:]),
-                rows["v"].reshape((-1,) + v_shape[2:]),
-                page_table + i * pages, att_len, sm_scale=scale, impl=impl)
-        else:
-            # position p of a slot at row p % W of its ring
-            index = i * S * kv_heads * W + ring_index
-            write("wk", k, index)
-            write("wv", v, index)
-            out = window_decode_attention(
-                q, rows["wk"].reshape((-1,) + wk.shape[2:]),
-                rows["wv"].reshape((-1,) + wv.shape[2:]), att_len, None,
-                base=i * S, sm_scale=scale, impl=impl)
-        return out[:, :, None]
-
-    logits, stats = _layers(cfg, params, tokens[:, None],
-                            positions[:, None], active, attend)
-    new_state = {"window_k": rows["wk"].reshape(wk.shape),
-                 "window_v": rows["wv"].reshape(wv.shape)}
-    out = (logits[:, 0], rows["k"].reshape(k_shape),
-           rows["v"].reshape(v_shape), new_state,
-           lengths + active.astype(jnp.int32))
-    if aux:
-        out += (_aux(cfg, stats, jnp.sum(att_len),
-                     jnp.sum(jnp.minimum(att_len, W)),
-                     jnp.sum(att_len > W)),)
-    return out
-
+# -- the paged steps' parts ---------------------------------------------
 
 def _live_pairs(delta_len, prefix_len, window=None):
     """(query, key) pairs the masks let through a head for ``delta_len``
@@ -588,110 +570,6 @@ def _live_pairs(delta_len, prefix_len, window=None):
     over = jnp.maximum(p + n - window, 0) - jnp.maximum(p - window, 0)
     under = n - over                # queries whose whole past is in reach
     return under * p + under * (under + 1) / 2 + over * window
-
-
-def cohere2_prefill_paged(cfg: Cohere2MoeConfig, params, tokens, delta_len,
-                          prefix_len, page_row, k_pool, v_pool, state, slot,
-                          aux: bool = False):
-    """Prefill of one request, or of one CHUNK of it, into the pool (the
-    full layers' keys) and into ``slot`` of the request state (the window
-    layers' rings).  tokens [1, Tq] are positions ``prefix_len ..``,
-    right-padded to the bucket; ``delta_len``, ``prefix_len``,
-    ``page_row`` [max_pages] and ``slot`` traced.  With ``prefix_len``
-    0 nothing is read of either cache.  Else the chunks before this one
-    (the engine runs a request's in order, into this slot) left what its
-    queries need: a window layer reads the slot's ring, a full layer the
-    prefix's pages (module docstring).  Returns (logits [1, Tq, V],
-    k_pool, v_pool, state); ``logits[0, delta_len - 1]`` scores the next
-    token.  Of the slot's rings, row ``r`` takes the last position before
-    ``prefix_len + delta_len`` that is ``r mod W`` if this call computed
-    it, and keeps what it held if not."""
-    B, Tq = tokens.shape
-    k_shape, v_shape = k_pool.shape, v_pool.shape
-    pages, kv_heads, page_len = k_shape[1], k_shape[2], k_shape[3]
-    wk, wv = state["window_k"], state["window_v"]
-    W = wk.shape[3]
-    cap = page_row.shape[0] * page_len
-    i32 = jnp.int32
-    delta_len = jnp.asarray(delta_len, i32)
-    prefix_len = jnp.asarray(prefix_len, i32)
-    slot = jnp.asarray(slot, i32)
-    abs_pos = prefix_len + jnp.arange(Tq, dtype=i32)
-    valid = jnp.arange(Tq) < delta_len
-    at = jnp.clip(abs_pos, 0, cap - 1)
-    page_ids = jnp.where(valid, page_row[at // page_len], 0)
-    offs = at % page_len
-    keep = jnp.repeat(valid, kv_heads)
-    positions = jnp.clip(abs_pos, 0, cfg.n_positions - 1)[None]
-    # ring row r takes the last position before the end that is r mod W
-    last = prefix_len + delta_len - 1
-    ring_pos = last - jnp.mod(last - jnp.arange(W, dtype=i32), W)
-    ring_new = (ring_pos >= prefix_len)[None, :, None]
-    ring_src = jnp.clip(ring_pos - prefix_len, 0, Tq - 1)
-    rows = {"k": _rows_view(k_pool), "v": _rows_view(v_pool)}
-    rings = {"window_k": [], "window_v": []}
-
-    def slot_ring(leaf, i):
-        # one slice of the leaf: ``leaf[i]`` first would copy the layer
-        return jax.lax.dynamic_slice(
-            leaf, (i, slot, 0, 0, 0), (1, 1) + leaf.shape[2:])[0, 0]
-
-    def attend(kind, i, q, k, v):
-        if kind == "window":
-            old_k, old_v = slot_ring(wk, i), slot_ring(wv, i)
-            rings["window_k"].append(
-                jnp.where(ring_new, k[0][:, ring_src], old_k))
-            rings["window_v"].append(
-                jnp.where(ring_new, v[0][:, ring_src], old_v))
-
-            def context():
-                with jax.named_scope("chunk_context"):
-                    # into position order: row (prefix_len + kk) % W
-                    shift = -jnp.mod(prefix_len, W)
-                    ctx_k, ctx_v = (jnp.roll(t, shift, axis=1)
-                                    for t in (old_k, old_v))
-                return _context_attention(cfg, kind, q, k, v, ctx_k, ctx_v,
-                                          jnp.minimum(prefix_len, W))
-        else:
-            index = _row_index(i * pages + page_ids, offs, kv_heads,
-                               page_len)
-            for name, new in (("k", k), ("v", v)):
-                rows[name] = _write_rows(
-                    rows[name], new[0].transpose(1, 0, 2).reshape(
-                        -1, new.shape[-1]), index, keep)
-
-            def context():
-                with jax.named_scope("chunk_context"):
-                    ctx_k, ctx_v = (
-                        _prefix_keys(rows[name].reshape(k_shape)[i],
-                                     page_row, prefix_len)
-                        for name in ("k", "v"))
-                return _context_attention(cfg, kind, q, k, v, ctx_k, ctx_v,
-                                          jnp.minimum(prefix_len, cap))
-
-        return jax.lax.cond(prefix_len == 0,
-                            lambda: _self_attention(cfg, kind, q, k, v),
-                            context)
-
-    logits, stats = _layers(cfg, params, tokens, positions, valid, attend)
-    new_state = dict(state)
-    for name, new in rings.items():
-        if new:
-            leaf = state[name]
-            new_state[name] = jax.lax.dynamic_update_slice(
-                leaf, jnp.stack(new)[:, None].astype(leaf.dtype),
-                (0, slot, 0, 0, 0))
-    out = (logits, rows["k"].reshape(k_shape), rows["v"].reshape(v_shape),
-           new_state)
-    if aux:
-        total = prefix_len + delta_len
-        pairs = (cfg.count("full") * _live_pairs(delta_len, prefix_len)
-                 + cfg.count("window") * _live_pairs(delta_len, prefix_len,
-                                                     W))
-        out += (_aux(cfg, stats, total, jnp.minimum(total, W),
-                     (total > W).astype(i32),
-                     delta_len * cfg.num_hidden_layers, pairs / 1024),)
-    return out
 
 
 def _prefix_keys(layer_pool, page_row, prefix_len):

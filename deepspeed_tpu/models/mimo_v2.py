@@ -69,12 +69,12 @@ never scanned, so nothing needs a ``[layers, ...]`` axis, and a static
 slice of one is a copy: XLA wrote every ``q_w`` of a decode tick to HBM
 transposed, and passed every window layer's ``k_w`` and ``v_w`` through
 fast memory, before the matmul read it (0.83 GB a tick at MiMo-V2.5's
-widths, 2 of its 21.5 ms; PERF.md section 6, PR 39).  ``_at`` is the one
-place that picks a layer.  The experts alone stay stacked, ``moe``'s
-``gate_w`` / ``up_w`` / ``down_w`` as ``[layers, held, ...]`` arrays:
-they reach their kernels whole, every layer's held experts flat
-(``_stacked_experts``, a reshape of the leading axes), and the kernel
-finds a layer's by ``expert_offset``.
+widths, 2 of its 21.5 ms; PERF.md section 6, PR 39).  ``walked.at`` is
+the one place that picks a layer.  The experts alone stay stacked,
+``moe``'s ``gate_w`` / ``up_w`` / ``down_w`` as ``[layers, held, ...]``
+arrays: they reach their kernels whole, every layer's held experts flat
+(``walked.stacked_experts``, a reshape of the leading axes), and the
+kernel finds a layer's by ``expert_offset``.
 """
 from __future__ import annotations
 
@@ -85,17 +85,18 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..moe.dropless import dropless_moe, route_sigmoid_topk
-from .nemotron_h import _aux as _held_expert_counters
-from .nemotron_h import _row_index, _rows_view, _write_rows
-from .olmoe import rms_norm, rope
+from .walked import (F32, PagePool, Rings, ServedConfig, WalkedModel, at,
+                     causal_self_attention, decode_index, default_scale,
+                     dense_ffn, draw_layers, held_expert_counters, lm_head,
+                     merge_heads, prefill_index, project_heads, ring_positions,
+                     rms_norm, rope, routed_experts, stacked_experts,
+                     write_slot_state)
 
-F32 = jnp.float32
 _LANES = 128
 
 
 @dataclasses.dataclass(frozen=True)
-class MimoV2Config:
+class MimoV2Config(ServedConfig):
     """The source's keys (HF ``config.json``), then the program's own."""
     vocab_size: int = 152576
     hidden_size: int = 4096
@@ -168,9 +169,7 @@ class MimoV2Config:
             "num_nextn_predict_layers (the multi-token-prediction "
             "layers)": self.num_nextn_predict_layers != 0,
         }
-        bad = [k for k, v in unbuilt.items() if v]
-        if bad:
-            raise ValueError("MimoV2Config: not built: " + "; ".join(bad))
+        self.check(unbuilt, self.n_routed_experts)
         for name in ("hybrid_layer_pattern", "moe_layer_freq"):
             got = getattr(self, name)
             if len(got) != self.num_hidden_layers or set(got) - {0, 1}:
@@ -187,13 +186,6 @@ class MimoV2Config:
                              f"{self.head_dim}: even, at most the head")
         if self.num_experts_per_tok > self.n_routed_experts:
             raise ValueError("num_experts_per_tok exceeds n_routed_experts")
-        first, count = self.held
-        if first < 0 or count < 1 or first + count > self.n_routed_experts:
-            raise ValueError(f"experts_held {self.experts_held}: not a "
-                             f"range of the {self.n_routed_experts}")
-        if self.attn_impl not in ("flash", "dense"):
-            raise ValueError(f"attn_impl {self.attn_impl!r}: 'flash' or "
-                             "'dense'")
 
     @property
     def held(self) -> Tuple[int, int]:
@@ -226,14 +218,6 @@ class MimoV2Config:
         return self.count("full")
 
     @property
-    def n_head(self) -> int:
-        return self.num_attention_heads
-
-    @property
-    def n_kv_head(self) -> int:
-        return self.num_key_value_heads
-
-    @property
     def d_head(self) -> int:
         """The pool's key width."""
         return self.k_width
@@ -243,10 +227,6 @@ class MimoV2Config:
         """The pool's value width."""
         return self.v_head_dim
 
-    @property
-    def n_positions(self) -> int:
-        return self.max_position_embeddings
-
 
 def _layers(cfg: MimoV2Config):
     """(attention kind, FFN kind) of each layer, in order."""
@@ -254,22 +234,16 @@ def _layers(cfg: MimoV2Config):
         yield ("window" if a else "full"), ("moe" if f else "dense")
 
 
-class MimoV2Model:
+class MimoV2Model(WalkedModel):
     #: the engine refuses the prefix cache, KV tiering and migration for
     #: any model with ``serving_state``: a page of full-layer keys is no
     #: prefix without the window layers' last keys at its boundary; the
     #: rest are arms these paged steps do not have (chunked prefill: the
     #: prefill takes no prefix, ``models/cohere2_moe.py``'s does)
-    serving_unsupported = ("slot_cache", "speculate_k", "quantization",
-                           "lora", "prefill_chunk_len")
-    serving_aux = ("moe_experts_hit", "moe_load_imbalance", "moe_rows",
-                   "moe_rows_elsewhere", "full_kv_tokens", "window_kv_rows")
-
-    def __init__(self, config: MimoV2Config):
-        self.config = config
-
-    def param_partition_specs(self, params):
-        return None                     # one chip: everything replicated
+    serving_unsupported = WalkedModel.serving_unsupported + (
+        "prefill_chunk_len",)
+    serving_aux = WalkedModel.serving_aux + ("full_kv_tokens",
+                                             "window_kv_rows")
 
     def serving_cache_layers(self) -> Dict[str, int]:
         """Layers by the kind of cache they keep."""
@@ -350,15 +324,7 @@ class MimoV2Model:
             n = cfg.count(name)
             if n:
                 of = jax.random.split(key, n)
-                # one XLA computation a layer, as the body of a scan over
-                # the keys is: the constants of a draw fold the same way
-                # called eagerly or inside a caller's jit (apart they
-                # round apart, an ulp in a quarter of the numbers)
-                draw = jax.jit(layer)
-                drawn = [{**draw(of[i]), ln: jnp.ones((d,), dt)}
-                         for i in range(n)]
-                out[name] = {leaf: tuple(one[leaf] for one in drawn)
-                             for leaf in drawn[0]}
+                out[name] = draw_layers(layer, of, {ln: d}, dt)
                 if whole:
                     out[name].update(jax.lax.map(whole, of))
         out.get("full", {}).pop("sink", None)
@@ -370,143 +336,148 @@ class MimoV2Model:
         logits, _, stats = _sequence(self.config, params, tokens, None)
         return (logits, _aux(self.config, stats, 0, 0)) if aux else logits
 
-    def prefill_paged(self, params, tokens, delta_len, prefix_len, page_row,
-                      k_pool, v_pool, *, state, slot, aux: bool = False,
-                      **unbuilt):
-        _refuse(unbuilt)
-        return mimo_v2_prefill_paged(
-            self.config, params, tokens, delta_len, page_row, k_pool,
-            v_pool, state, slot, aux=aux)
-
     def decode_step_paged(self, params, tokens, k_pool, v_pool, page_table,
                           lengths, active, *, state,
                           impl: Optional[str] = None, aux: bool = False,
                           **unbuilt):
-        _refuse(unbuilt)
-        return mimo_v2_decode_step_paged(
-            self.config, params, tokens, k_pool, v_pool, page_table,
-            lengths, active, state, impl=impl, aux=aux)
+        """One decode tick of every slot; ``gpt2_decode_step_paged``'s
+        contract plus the request state.  Returns (logits [S, V], k_pool,
+        v_pool, state, new_lengths) and, with ``aux``, the tick's
+        counters.  An inactive slot's pages and rings are neither read
+        nor written."""
+        self.refuse(unbuilt)
+        cfg, impl = self.config, self.decode_impl(impl)
+        S = page_table.shape[0]
+        eps, scale = cfg.layernorm_epsilon, default_scale(cfg.head_dim)
+        lengths, positions, att_len, page_ids, offs = decode_index(
+            page_table, lengths, active, k_pool.shape[3], cfg.n_positions)
+        pool = PagePool((k_pool, v_pool), page_ids, offs, active)
+        rings = Rings(state["window_k"], state["window_v"], positions,
+                      active)
+        stacked = stacked_experts(params) if cfg.count("moe") else None
+        seen = {"full": 0, "window": 0, "dense": 0, "moe": 0}
+        stats = []
+        with jax.named_scope("embed"):
+            x = params["wte"][tokens]                       # [S, d]
+        for kind, ffn in _layers(cfg):
+            with jax.named_scope("layer"):
+                i = seen[kind]
+                ap = at(params[kind], i)
+                with jax.named_scope("attn"), \
+                        jax.named_scope("attn_" + kind):
+                    h = rms_norm(x, ap["ln1"], eps)
+                    q, k, v = (t[:, :, 0] for t in _qkv(
+                        cfg, kind, ap, h[:, None], positions[:, None]))
+                    if kind == "full":
+                        pool.write(i, k, v)
+                        attn = pool.attend(i, q, page_table, att_len,
+                                           impl=impl, sm_scale=scale)
+                    else:
+                        rings.write(i, k, v)
+                        attn = rings.attend(i, q, att_len, ap["sink"],
+                                            impl=impl, sm_scale=scale)
+                    x = x + attn.reshape(S, -1) @ ap["o_w"].astype(x.dtype)
+                x = _ffn(cfg, params, stacked, ffn, seen[ffn], x, active,
+                         stats)
+                seen[kind] += 1
+                seen[ffn] += 1
+        logits = lm_head(x, params["norm_f"], params["lm_head"], eps)
+        out = (logits, *pool.arrays(),
+               dict(zip(("window_k", "window_v"), rings.arrays())),
+               lengths + active.astype(jnp.int32))
+        if aux:
+            out += (_aux(cfg, stats, jnp.sum(att_len),
+                         jnp.sum(jnp.minimum(att_len, rings.length))),)
+        return out
 
-
-def _refuse(unbuilt: dict) -> None:
-    asked = sorted(k for k, v in unbuilt.items() if v is not None)
-    if asked:
-        raise NotImplementedError(
-            f"MimoV2Model's paged steps have no {', '.join(asked)} arm")
+    def prefill_paged(self, params, tokens, delta_len, prefix_len, page_row,
+                      k_pool, v_pool, *, state, slot, aux: bool = False,
+                      **unbuilt):
+        """Prefill of one request into the pool (the full layers' keys)
+        and into ``slot`` of the request state (the window layers'
+        rings).  tokens [1, Tq] right-padded to the bucket; ``delta_len``,
+        ``page_row`` [max_pages] and ``slot`` traced.  No cached prefix
+        (``prefix_len`` is not read): the engine refuses the prefix cache
+        for this model.  Returns (logits [1, Tq, V], k_pool, v_pool,
+        state); ``logits[0, delta_len - 1]`` scores the first generated
+        token.  The slot's rings are OVERWRITTEN with the last
+        ``sliding_window`` positions before ``delta_len``, each at its row
+        ``p % W``; with fewer, rows ``delta_len ..`` hold nothing a decode
+        tick reads."""
+        self.refuse(unbuilt)
+        cfg, Tq = self.config, tokens.shape[1]
+        W = state["window_k"].shape[3]
+        delta_len = jnp.asarray(delta_len, jnp.int32)
+        slot = jnp.asarray(slot, jnp.int32)
+        valid, page_ids, offs, _, _ = prefill_index(
+            page_row, delta_len, Tq, k_pool.shape[3])
+        pool = PagePool((k_pool, v_pool), page_ids, offs, valid)
+        # ring row r: the last position before delta_len that is r mod W
+        ring_pos = jnp.clip(ring_positions(delta_len, W), 0, Tq - 1)
+        logits, kept, stats = _sequence(cfg, params, tokens, delta_len)
+        rings, i = {"window_k": [], "window_v": []}, 0
+        for kind, k, v in kept:                 # [Hkv, Tq, Kw], [.., Dv]
+            if kind == "window":
+                rings["window_k"].append(k[:, ring_pos])
+                rings["window_v"].append(v[:, ring_pos])
+                continue
+            pool.write(i, k.transpose(1, 0, 2), v.transpose(1, 0, 2))
+            i += 1
+        out = (logits, *pool.arrays(), write_slot_state(state, rings, slot))
+        if aux:
+            out += (_aux(cfg, stats, delta_len, jnp.minimum(delta_len, W)),)
+        return out
 
 
 # -- the layer's parts ----------------------------------------------------
-
-def _at(kind, i: int):
-    """Layer ``i``'s own leaves of a kind (module docstring: each a leaf
-    of the tree, nothing is sliced); not the experts, which reach their
-    kernels whole (``_stacked_experts``)."""
-    return {k: v[i] for k, v in kind.items() if isinstance(v, tuple)}
-
-
-def _stacked_experts(params):
-    """Every expert layer's held experts flat: ``[layers * held, ...]``."""
-    moe = params["moe"]
-    return {k: moe[k].reshape((-1,) + moe[k].shape[2:])
-            for k in ("gate_w", "up_w", "down_w")}
-
-
-def _sm_scale(cfg: MimoV2Config) -> float:
-    from ..ops.pallas.decode_attention import _default_scale
-    return _default_scale(cfg.head_dim)
-
 
 def _qkv(cfg: MimoV2Config, kind: str, ap, h, positions):
     """h [B, T, d] (normed), positions [B, T] -> q [B, Hq, T, Kw], k
     [B, Hkv, T, Kw] (both rotated, then widened to the key's width at
     rest with zeros: scores do not change), v [B, Hkv, T, Dv] (scaled by
     ``attention_value_scale``: what the cache holds)."""
-    B, T, _ = h.shape
     hkv = cfg.kv_heads(kind)
     theta = cfg.rope_theta if kind == "full" else cfg.swa_rope_theta
 
-    def heads(t, n, width):
-        return t.reshape(B, T, n, width).transpose(0, 2, 1, 3)
-
-    def rotated(t, n):
-        t = rope(heads(t, n, cfg.head_dim), positions, theta,
+    def rotated(name, n):
+        t = rope(project_heads(h, ap[name], n), positions, theta,
                  rotary_dim=cfg.rotary_dim)
         return jnp.pad(t, ((0, 0),) * 3 + ((0, cfg.k_width - cfg.head_dim),))
 
-    q = rotated(h @ ap["q_w"].astype(h.dtype), cfg.n_head)
-    k = rotated(h @ ap["k_w"].astype(h.dtype), hkv)
-    v = heads(h @ ap["v_w"].astype(h.dtype), hkv, cfg.v_head_dim)
+    q, k = rotated("q_w", cfg.n_head), rotated("k_w", hkv)
+    v = project_heads(h, ap["v_w"], hkv)
     return q, k, v * jnp.asarray(cfg.attention_value_scale, v.dtype)
-
-
-def grouped_causal_attention(q, k, v, window=None, sink=None,
-                             sm_scale=None):
-    """The dense (XLA) arm of a whole sequence's attention: q [B, Hq, T,
-    Dk] over k [B, Hkv, T, Dk], v [B, Hkv, T, Dv]; ``window``: the last
-    so many keys, the query's own included; ``sink`` [Hq]: one more
-    softmax column a head that gives no value."""
-    B, Hq, T, _ = q.shape
-    rep = Hq // k.shape[1]
-    k, v = (jnp.repeat(t, rep, axis=1) for t in (k, v))
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                   preferred_element_type=F32) * sm_scale
-    at = jnp.arange(T)
-    ok = at[None, :] <= at[:, None]
-    if window is not None:
-        ok &= at[None, :] > at[:, None] - window
-    s = jnp.where(ok[None, None], s, jnp.finfo(F32).min)
-    if sink is not None:
-        col = jnp.broadcast_to(sink.astype(F32)[None, :, None, None],
-                               (B, Hq, T, 1))
-        s = jnp.concatenate([s, col], axis=-1)
-    p = jax.nn.softmax(s, axis=-1)[..., :T].astype(q.dtype)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
 
 
 def _self_attention(cfg: MimoV2Config, kind: str, ap, q, k, v):
     window = cfg.sliding_window if kind == "window" else None
     sink = ap["sink"] if kind == "window" else None
-    if cfg.attn_impl == "flash":
-        from ..ops.pallas.flash_attention import flash_attention_fwd
-        # a window layer's band is two blocks of 256 a query block
-        block = 256 if kind == "window" else 512
-        return flash_attention_fwd(q, k, v, window=window, sink=sink,
-                                   sm_scale=_sm_scale(cfg), block_q=block,
-                                   block_k=block)
-    return grouped_causal_attention(q, k, v, window=window, sink=sink,
-                                    sm_scale=_sm_scale(cfg))
-
-
-@jax.named_scope("dense_ffn")
-def _dense_ffn(fp, x):
-    g = x @ fp["gate_w"].astype(x.dtype)
-    u = x @ fp["up_w"].astype(x.dtype)
-    return (jax.nn.silu(g) * u) @ fp["down_w"].astype(x.dtype)
+    # a window layer's band is two blocks of 256 a query block
+    block = 256 if kind == "window" else 512
+    return causal_self_attention(
+        q, k, v, cfg.attn_impl == "flash", window=window, sink=sink,
+        sm_scale=default_scale(cfg.head_dim), block_q=block, block_k=block)
 
 
 def _experts(cfg: MimoV2Config, ep, stacked, index: int, x, valid):
     """The expert layer on normed x [N, d]: this share's part of the
     sum.  ``stacked``: every layer's held experts flat."""
     with jax.named_scope("moe"):
-        routing = route_sigmoid_topk(
-            x, ep["router_w"], ep["router_bias"], cfg.num_experts_per_tok,
-            scale=cfg.routed_scaling_factor or 1.0,
+        return routed_experts(
+            x, ep["router_w"], ep["router_bias"], stacked, index,
+            top_k=cfg.num_experts_per_tok, held=cfg.held, valid=valid,
+            act="swiglu", scale=cfg.routed_scaling_factor or 1.0,
             renormalize=cfg.norm_topk_prob)
-        return dropless_moe(
-            x, ep["router_w"], stacked["gate_w"], stacked["up_w"],
-            stacked["down_w"], cfg.num_experts_per_tok,
-            expert_offset=index * cfg.held[1], valid=valid,
-            routing=routing, experts_held=cfg.held, act="swiglu")
 
 
 def _ffn(cfg: MimoV2Config, params, stacked, kind: str, i: int, x, valid,
          stats):
     """x [N, d] -> x + ffn(norm(x)); an expert layer's statistics are
     appended to ``stats``."""
-    fp = _at(params[kind], i)
+    fp = at(params[kind], i)
     h = rms_norm(x, fp["ln2"], cfg.layernorm_epsilon)
     if kind == "dense":
-        return x + _dense_ffn(fp, h)
+        return x + dense_ffn(fp, h)
     out, st = _experts(cfg, fp, stacked, i, h, valid)
     stats.append(st)
     return x + out
@@ -518,15 +489,9 @@ def _aux(cfg: MimoV2Config, stats, full_kv_tokens,
     (of the HELD experts), and what the two kinds of cache held for the
     call's live sequences: ``full_kv_tokens`` keys a full layer,
     ``window_kv_rows`` ring rows a window layer."""
-    return {**_held_expert_counters(cfg, stats),
+    return {**held_expert_counters(stats, cfg.held[1]),
             "full_kv_tokens": jnp.asarray(full_kv_tokens, jnp.int32),
             "window_kv_rows": jnp.asarray(window_kv_rows, jnp.int32)}
-
-
-@jax.named_scope("lm_head")
-def _lm_head(cfg: MimoV2Config, params, x):
-    x = rms_norm(x, params["norm_f"], cfg.layernorm_epsilon)
-    return x @ params["lm_head"].astype(x.dtype)
 
 
 def _sequence(cfg: MimoV2Config, params, tokens, delta_len):
@@ -541,169 +506,23 @@ def _sequence(cfg: MimoV2Config, params, tokens, delta_len):
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
     valid = None if delta_len is None \
         else jnp.tile(jnp.arange(T) < delta_len, B)
-    stacked = _stacked_experts(params) if cfg.count("moe") else None
+    stacked = stacked_experts(params) if cfg.count("moe") else None
     seen = {"full": 0, "window": 0, "dense": 0, "moe": 0}
     kept, stats = [], []
     with jax.named_scope("embed"):
         x = params["wte"][tokens]
     for kind, ffn in _layers(cfg):
         with jax.named_scope("layer"):
-            ap = _at(params[kind], seen[kind])
+            ap = at(params[kind], seen[kind])
             with jax.named_scope("attn"), jax.named_scope("attn_" + kind):
                 h = rms_norm(x, ap["ln1"], eps)
                 q, k, v = _qkv(cfg, kind, ap, h, positions)
                 kept.append((kind, k[0], v[0]))
                 attn = _self_attention(cfg, kind, ap, q, k, v)
-                merged = attn.transpose(0, 2, 1, 3).reshape(B, T, -1)
-                x = x + merged @ ap["o_w"].astype(x.dtype)
+                x = x + merge_heads(attn) @ ap["o_w"].astype(x.dtype)
             x = _ffn(cfg, params, stacked, ffn, seen[ffn],
                      x.reshape(B * T, -1), valid, stats).reshape(x.shape)
             seen[kind] += 1
             seen[ffn] += 1
-    return _lm_head(cfg, params, x), kept, stats
-
-
-# -- the paged steps ------------------------------------------------------
-
-def mimo_v2_decode_step_paged(cfg: MimoV2Config, params, tokens, k_pool,
-                              v_pool, page_table, lengths, active, state,
-                              impl: Optional[str] = None,
-                              aux: bool = False):
-    """One decode tick of every slot; ``gpt2_decode_step_paged``'s
-    contract plus the request state.  Returns (logits [S, V], k_pool,
-    v_pool, state, new_lengths) and, with ``aux``, the tick's counters.
-    An inactive slot's pages and rings are neither read nor written."""
-    from ..ops.pallas.decode_attention import (decode_attention_paged,
-                                               window_decode_attention)
-    if impl is None:
-        impl = "pallas" if cfg.attn_impl == "flash" else "dense"
-    k_shape, v_shape = k_pool.shape, v_pool.shape
-    pages, kv_heads, page_len = k_shape[1], k_shape[2], k_shape[3]
-    wk, wv = state["window_k"], state["window_v"]
-    w_heads, W = wk.shape[2], wk.shape[3]
-    S = page_table.shape[0]
-    cap = page_table.shape[1] * page_len
-    eps, scale = cfg.layernorm_epsilon, _sm_scale(cfg)
-    lengths = lengths.astype(jnp.int32)
-    positions = jnp.clip(lengths, 0, min(cap, cfg.n_positions) - 1)
-    att_len = jnp.where(active, lengths + 1, 0).astype(jnp.int32)
-    page_ids = jnp.where(
-        active, page_table[jnp.arange(S), positions // page_len], 0)
-    offs = positions % page_len
-    slots = jnp.arange(S, dtype=jnp.int32)
-    stacked = _stacked_experts(params) if cfg.count("moe") else None
-    k_rows, v_rows = _rows_view(k_pool), _rows_view(v_pool)
-    wk_rows, wv_rows = _rows_view(wk), _rows_view(wv)
-    seen = {"full": 0, "window": 0, "dense": 0, "moe": 0}
-    stats = []
-    with jax.named_scope("embed"):
-        x = params["wte"][tokens]                           # [S, d]
-    for kind, ffn in _layers(cfg):
-        with jax.named_scope("layer"):
-            i = seen[kind]
-            ap = _at(params[kind], i)
-            with jax.named_scope("attn"), jax.named_scope("attn_" + kind):
-                h = rms_norm(x, ap["ln1"], eps)
-                q, k, v = _qkv(cfg, kind, ap, h[:, None],
-                               positions[:, None])
-                q = q[:, :, 0]
-                if kind == "full":
-                    index = _row_index(i * pages + page_ids, offs,
-                                       kv_heads, page_len)
-                    keep = jnp.repeat(active, kv_heads)
-                    k_rows = _write_rows(
-                        k_rows, k[:, :, 0].reshape(-1, k.shape[-1]), index,
-                        keep)
-                    v_rows = _write_rows(
-                        v_rows, v[:, :, 0].reshape(-1, v.shape[-1]), index,
-                        keep)
-                    attn = decode_attention_paged(
-                        q, k_rows.reshape((-1,) + k_shape[2:]),
-                        v_rows.reshape((-1,) + v_shape[2:]),
-                        page_table + i * pages, att_len, sm_scale=scale,
-                        impl=impl)
-                else:
-                    # position p of a slot at row p % W of its ring
-                    index = _row_index(i * S + slots, positions % W,
-                                       w_heads, W)
-                    keep = jnp.repeat(active, w_heads)
-                    wk_rows = _write_rows(
-                        wk_rows, k[:, :, 0].reshape(-1, k.shape[-1]), index,
-                        keep)
-                    wv_rows = _write_rows(
-                        wv_rows, v[:, :, 0].reshape(-1, v.shape[-1]), index,
-                        keep)
-                    attn = window_decode_attention(
-                        q, wk_rows.reshape((-1,) + wk.shape[2:]),
-                        wv_rows.reshape((-1,) + wv.shape[2:]), att_len,
-                        ap["sink"], base=i * S, sm_scale=scale, impl=impl)
-                x = x + attn.reshape(S, -1) @ ap["o_w"].astype(x.dtype)
-            x = _ffn(cfg, params, stacked, ffn, seen[ffn], x, active, stats)
-            seen[kind] += 1
-            seen[ffn] += 1
-    logits = _lm_head(cfg, params, x)
-    new_state = {"window_k": wk_rows.reshape(wk.shape),
-                 "window_v": wv_rows.reshape(wv.shape)}
-    out = (logits, k_rows.reshape(k_shape), v_rows.reshape(v_shape),
-           new_state, lengths + active.astype(jnp.int32))
-    if aux:
-        out += (_aux(cfg, stats, jnp.sum(att_len),
-                     jnp.sum(jnp.minimum(att_len, W))),)
-    return out
-
-
-def mimo_v2_prefill_paged(cfg: MimoV2Config, params, tokens, delta_len,
-                          page_row, k_pool, v_pool, state, slot,
-                          aux: bool = False):
-    """Prefill of one request into the pool (the full layers' keys) and
-    into ``slot`` of the request state (the window layers' rings).
-    tokens [1, Tq] right-padded to the bucket; ``delta_len``, ``page_row``
-    [max_pages] and ``slot`` traced.  No cached prefix: the engine
-    refuses the prefix cache for this model.  Returns (logits [1, Tq, V],
-    k_pool, v_pool, state); ``logits[0, delta_len - 1]`` scores the first
-    generated token.  The slot's rings are OVERWRITTEN with the last
-    ``sliding_window`` positions before ``delta_len``, each at its row
-    ``p % W``; with fewer, rows ``delta_len ..`` hold nothing a decode
-    tick reads."""
-    B, Tq = tokens.shape
-    k_shape, v_shape = k_pool.shape, v_pool.shape
-    pages, kv_heads, page_len = k_shape[1], k_shape[2], k_shape[3]
-    W = state["window_k"].shape[3]
-    cap = page_row.shape[0] * page_len
-    delta_len = jnp.asarray(delta_len, jnp.int32)
-    slot = jnp.asarray(slot, jnp.int32)
-    pos = jnp.clip(jnp.arange(Tq, dtype=jnp.int32), 0, cap - 1)
-    valid = jnp.arange(Tq) < delta_len
-    page_ids = jnp.where(valid, page_row[pos // page_len], 0)
-    offs = pos % page_len
-    keep_rows = jnp.repeat(valid, kv_heads)
-    # ring row r holds the last position before delta_len that is r mod W
-    r = jnp.arange(W, dtype=jnp.int32)
-    last = delta_len - 1
-    ring_pos = jnp.clip(last - jnp.mod(last - r, W), 0, Tq - 1)
-    logits, kept, stats = _sequence(cfg, params, tokens, delta_len)
-    k_rows, v_rows = _rows_view(k_pool), _rows_view(v_pool)
-    rings_k, rings_v, i = [], [], 0
-    for kind, k, v in kept:                     # [Hkv, Tq, Kw], [.., Dv]
-        if kind == "window":
-            rings_k.append(k[:, ring_pos])
-            rings_v.append(v[:, ring_pos])
-            continue
-        index = _row_index(i * pages + page_ids, offs, kv_heads, page_len)
-        k_rows = _write_rows(k_rows, k.transpose(1, 0, 2).reshape(
-            -1, k.shape[-1]), index, keep_rows)
-        v_rows = _write_rows(v_rows, v.transpose(1, 0, 2).reshape(
-            -1, v.shape[-1]), index, keep_rows)
-        i += 1
-    new_state = dict(state)
-    for name, rings in (("window_k", rings_k), ("window_v", rings_v)):
-        if rings:
-            leaf = state[name]
-            new = jnp.stack(rings)[:, None].astype(leaf.dtype)
-            new_state[name] = jax.lax.dynamic_update_slice(
-                leaf, new, (0, slot, 0, 0, 0))
-    out = (logits, k_rows.reshape(k_shape), v_rows.reshape(v_shape),
-           new_state)
-    if aux:
-        out += (_aux(cfg, stats, delta_len, jnp.minimum(delta_len, W)),)
-    return out
+    logits = lm_head(x, params["norm_f"], params["lm_head"], eps)
+    return logits, kept, stats

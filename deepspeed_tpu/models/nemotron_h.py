@@ -60,16 +60,16 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..moe.dropless import dropless_moe, route_sigmoid_topk
 from ..ops.attention import causal_attention
 from ..ops.pallas.ssm import ssd_chunked, ssm_decode
-from .olmoe import rms_norm
-
-F32 = jnp.float32
+from .walked import (F32, PagePool, ServedConfig, WalkedModel, decode_index,
+                     held_expert_counters, lm_head, merge_heads, prefill_index,
+                     project_heads, rms_norm, routed_experts, stacked_experts,
+                     write_slot_state)
 
 
 @dataclasses.dataclass(frozen=True)
-class NemotronHConfig:
+class NemotronHConfig(ServedConfig):
     """The source's keys (HF ``config.json``), then the program's own."""
     vocab_size: int = 131072
     hidden_size: int = 4096
@@ -131,9 +131,7 @@ class NemotronHConfig:
             "num_nextn_predict_layers (the multi-token-prediction "
             "module)": self.num_nextn_predict_layers != 0,
         }
-        bad = [k for k, v in unbuilt.items() if v]
-        if bad:
-            raise ValueError("NemotronHConfig: not built: " + "; ".join(bad))
+        self.check(unbuilt, self.n_routed_experts)
         pattern = self.hybrid_override_pattern
         if set(pattern) - set("ME*") or not pattern:
             raise ValueError(f"hybrid_override_pattern {pattern!r}: a "
@@ -151,13 +149,6 @@ class NemotronHConfig:
                              "divide into n_groups")
         if self.num_experts_per_tok > self.n_routed_experts:
             raise ValueError("num_experts_per_tok exceeds n_routed_experts")
-        first, count = self.held
-        if first < 0 or count < 1 or first + count > self.n_routed_experts:
-            raise ValueError(f"experts_held {self.experts_held}: not a "
-                             f"range of the {self.n_routed_experts}")
-        if self.attn_impl not in ("flash", "dense"):
-            raise ValueError(f"attn_impl {self.attn_impl!r}: 'flash' or "
-                             "'dense'")
 
     @property
     def held(self) -> Tuple[int, int]:
@@ -181,37 +172,17 @@ class NemotronHConfig:
         return self.count("*")
 
     @property
-    def n_head(self) -> int:
-        return self.num_attention_heads
-
-    @property
-    def n_kv_head(self) -> int:
-        return self.num_key_value_heads
-
-    @property
     def d_head(self) -> int:
         return self.head_dim
 
-    @property
-    def n_positions(self) -> int:
-        return self.max_position_embeddings
 
-
-class NemotronHModel:
+class NemotronHModel(WalkedModel):
     #: the engine refuses the prefix cache, KV tiering and migration for
     #: any model with ``serving_state``; the rest are arms these paged
     #: steps do not have (chunked prefill: the prefill takes no prefix,
     #: and the scan would have to start from the slot's state)
-    serving_unsupported = ("slot_cache", "speculate_k", "quantization",
-                           "lora", "prefill_chunk_len")
-    serving_aux = ("moe_experts_hit", "moe_load_imbalance", "moe_rows",
-                   "moe_rows_elsewhere")
-
-    def __init__(self, config: NemotronHConfig):
-        self.config = config
-
-    def param_partition_specs(self, params):
-        return None                     # one chip: everything replicated
+    serving_unsupported = WalkedModel.serving_unsupported + (
+        "prefill_chunk_len",)
 
     def serving_state(self, slots: int) -> Dict[str, Any]:
         """What a request keeps beside its pages, by slot: name ->
@@ -307,31 +278,103 @@ class NemotronHModel:
         """tokens [B, T] -> logits [B, T, V]: the whole-sequence forward
         (no cache, every position live)."""
         logits, _, stats = _sequence(self.config, params, tokens, None)
-        return (logits, _aux(self.config, stats)) if aux else logits
-
-    def prefill_paged(self, params, tokens, delta_len, prefix_len, page_row,
-                      k_pool, v_pool, *, state, slot, aux: bool = False,
-                      **unbuilt):
-        _refuse(unbuilt)
-        return nemotron_h_prefill_paged(
-            self.config, params, tokens, delta_len, page_row, k_pool,
-            v_pool, state, slot, aux=aux)
+        return (logits, held_expert_counters(
+            stats, self.config.held[1])) if aux else logits
 
     def decode_step_paged(self, params, tokens, k_pool, v_pool, page_table,
                           lengths, active, *, state,
                           impl: Optional[str] = None, aux: bool = False,
                           **unbuilt):
-        _refuse(unbuilt)
-        return nemotron_h_decode_step_paged(
-            self.config, params, tokens, k_pool, v_pool, page_table,
-            lengths, active, state, impl=impl, aux=aux)
+        """One decode tick of every slot; ``gpt2_decode_step_paged``'s
+        contract plus the request state.  Returns (logits [S, V], k_pool,
+        v_pool, state, new_lengths) and, with ``aux``, the tick's
+        counters.  An inactive slot's state is neither read nor
+        written."""
+        self.refuse(unbuilt)
+        cfg, impl = self.config, self.decode_impl(impl)
+        S = page_table.shape[0]
+        eps = cfg.layer_norm_epsilon
+        lengths, _, att_len, page_ids, offs = decode_index(
+            page_table, lengths, active, k_pool.shape[3], cfg.n_positions)
+        pool = PagePool((k_pool, v_pool), page_ids, offs, active)
+        stacked = stacked_experts(params, names=("up_w", "down_w")) \
+            if cfg.count("E") else None
+        ssm, conv = state["ssm"], state["conv"]
+        ssm_flat = ssm.reshape((-1,) + ssm.shape[2:])
+        stats = []
+        with jax.named_scope("embed"):
+            x = params["wte"][tokens]                       # [S, d]
+        for kind, i in _layers(cfg):
+            with jax.named_scope("layer"):
+                if kind == "M":
+                    mp = _at(params["mamba"], i)
+                    with jax.named_scope("ssm"):
+                        h = rms_norm(x, mp["norm"], eps)
+                        z, xbc, dt_raw = _mamba_in(cfg, mp, h)
+                        window = jnp.concatenate(
+                            [conv[i], xbc[:, None].astype(conv.dtype)],
+                            axis=1)
+                        conv = conv.at[i].set(jnp.where(
+                            active[:, None, None], window[:, 1:], conv[i]))
+                        x_s, b, c, dt, a = _ssm_inputs(
+                            cfg, mp, _conv(mp, [window[:, j] for j in range(
+                                cfg.conv_kernel)]), dt_raw)
+                        with jax.named_scope("scan"):
+                            ssm_flat, y = ssm_decode(
+                                ssm_flat, jnp.exp(dt * a),
+                                dt[..., None] * x_s, b, c, active,
+                                base=i * S)
+                        x = x + _mamba_out(cfg, mp, y, x_s, z, x.dtype)
+                elif kind == "*":
+                    ap = _at(params["attn"], i)
+                    with jax.named_scope("attn"):
+                        h = rms_norm(x, ap["norm"], eps)
+                        q, k, v = _qkv(cfg, ap, h[:, None])
+                        pool.write(i, k[:, :, 0], v[:, :, 0])
+                        attn = pool.attend(i, q[:, :, 0], page_table,
+                                           att_len, impl=impl)
+                        x = x + attn.reshape(S, -1) \
+                            @ ap["o_w"].astype(x.dtype)
+                else:
+                    ep = _at(params["moe"], i)
+                    out, st = _experts(cfg, ep, stacked, i,
+                                       rms_norm(x, ep["norm"], eps), active)
+                    stats.append(st)
+                    x = x + out
+        logits = lm_head(x, params["norm_f"], params["lm_head"], eps)
+        new_state = {"ssm": ssm_flat.reshape(ssm.shape), "conv": conv}
+        out = (logits, *pool.arrays(), new_state,
+               lengths + active.astype(jnp.int32))
+        return out + (held_expert_counters(stats, cfg.held[1]),) \
+            if aux else out
 
-
-def _refuse(unbuilt: dict) -> None:
-    asked = sorted(k for k, v in unbuilt.items() if v is not None)
-    if asked:
-        raise NotImplementedError(
-            f"NemotronHModel's paged steps have no {', '.join(asked)} arm")
+    def prefill_paged(self, params, tokens, delta_len, prefix_len, page_row,
+                      k_pool, v_pool, *, state, slot, aux: bool = False,
+                      **unbuilt):
+        """Prefill of one request into the pool and into ``slot`` of the
+        request state.  tokens [1, Tq] right-padded to the bucket;
+        ``delta_len``, ``page_row`` [max_pages] and ``slot`` traced.  No
+        cached prefix (``prefix_len`` is not read): the engine refuses
+        the prefix cache for this model, because a page of keys without
+        the recurrent state at its boundary is no prefix.  Returns (logits
+        [1, Tq, V], k_pool, v_pool, state); ``logits[0, delta_len - 1]``
+        scores the first generated token.  The slot's state is
+        OVERWRITTEN with the state at ``delta_len``: padding takes ``dt =
+        0`` and feeds nothing, the conv window is read at the true
+        end."""
+        self.refuse(unbuilt)
+        cfg = self.config
+        delta_len = jnp.asarray(delta_len, jnp.int32)
+        slot = jnp.asarray(slot, jnp.int32)
+        valid, page_ids, offs, _, _ = prefill_index(
+            page_row, delta_len, tokens.shape[1], k_pool.shape[3])
+        pool = PagePool((k_pool, v_pool), page_ids, offs, valid)
+        logits, kept, stats = _sequence(cfg, params, tokens, delta_len)
+        for i, (k, v) in enumerate(kept.pop("kv")):         # [Hkv, Tq, Dh]
+            pool.write(i, k.transpose(1, 0, 2), v.transpose(1, 0, 2))
+        out = (logits, *pool.arrays(), write_slot_state(state, kept, slot))
+        return out + (held_expert_counters(stats, cfg.held[1]),) \
+            if aux else out
 
 
 # -- the three parts ------------------------------------------------------
@@ -346,7 +389,7 @@ def _layers(cfg: NemotronHConfig):
 
 def _at(stacked, i: int):
     """Layer ``i`` of a kind's stacked leaves, but for the experts, which
-    reach their kernels whole (``_stacked_experts``)."""
+    reach their kernels whole (``walked.stacked_experts``)."""
     return {k: v[i] for k, v in stacked.items()
             if k not in ("up_w", "down_w")}
 
@@ -403,16 +446,13 @@ def _experts(cfg: NemotronHConfig, ep, stacked, index: int, x, valid):
     """The ``E`` part on normed x [N, d]: this share's routed part + the
     shared expert.  ``stacked``: every layer's held experts flat."""
     with jax.named_scope("moe"):
-        routing = route_sigmoid_topk(
-            x, ep["router_w"], ep["router_bias"], cfg.num_experts_per_tok,
-            scale=cfg.routed_scaling_factor, renormalize=cfg.norm_topk_prob)
         with jax.named_scope("latent_down"):
             u = x @ ep["latent_down"].astype(x.dtype)
-        r, stats = dropless_moe(
-            u, ep["router_w"], None, stacked["up_w"], stacked["down_w"],
-            cfg.num_experts_per_tok, expert_offset=index * cfg.held[1],
-            valid=valid, routing=routing, experts_held=cfg.held,
-            act="relu2")
+        r, stats = routed_experts(
+            x, ep["router_w"], ep["router_bias"], stacked, index, rows=u,
+            top_k=cfg.num_experts_per_tok, held=cfg.held, valid=valid,
+            act="relu2", scale=cfg.routed_scaling_factor,
+            renormalize=cfg.norm_topk_prob)
         with jax.named_scope("latent_up"):
             out = r @ ep["latent_up"].astype(x.dtype)
         with jax.named_scope("shared_expert"):
@@ -421,49 +461,14 @@ def _experts(cfg: NemotronHConfig, ep, stacked, index: int, x, valid):
     return out, stats
 
 
-def _stacked_experts(params):
-    moe = params["moe"]
-    return {k: moe[k].reshape((-1,) + moe[k].shape[2:])
-            for k in ("up_w", "down_w")}
-
-
-def _aux(cfg: NemotronHConfig, stats) -> Dict[str, jnp.ndarray]:
-    """The ``E`` layers' HeldMoEStats -> the call's counters: experts hit
-    and rows summed over layers (of the HELD experts), the busiest held
-    expert's rows over the mean rows a held expert (largest over layers),
-    and the live assignments that went to experts held elsewhere."""
-    held = cfg.held[1]
-    zero = jnp.zeros((), jnp.int32)
-    imb = [s.max_rows / (jnp.maximum(s.rows, 1).astype(F32) / held)
-           for s in stats]
-    return {"moe_experts_hit": sum((s.experts_hit for s in stats), zero),
-            "moe_rows": sum((s.rows for s in stats), zero),
-            "moe_load_imbalance": jnp.max(jnp.stack(imb)) if imb
-            else jnp.zeros((), F32),
-            "moe_rows_elsewhere": sum((s.rows_elsewhere for s in stats),
-                                      zero)}
-
-
-@jax.named_scope("lm_head")
-def _lm_head(cfg: NemotronHConfig, params, x):
-    x = rms_norm(x, params["norm_f"], cfg.layer_norm_epsilon)
-    return x @ params["lm_head"].astype(x.dtype)
-
-
 def _qkv(cfg: NemotronHConfig, ap, h):
     """h [B, T, d] (normed) -> q [B, Hq, T, Dh], k, v [B, Hkv, T, Dh].
     Nothing is rotated (module docstring); if the source turns out to
-    rotate, ``models/olmoe.py::rope(t, positions, cfg.rope_theta)`` on q
+    rotate, ``models/walked.py::rope(t, positions, cfg.rope_theta)`` on q
     and k goes here, and its like into the reference's ``_attention``."""
-    B, T, _ = h.shape
-
-    def heads(t, n):
-        return t.reshape(B, T, n, cfg.d_head).transpose(0, 2, 1, 3)
-
-    q = heads(h @ ap["q_w"].astype(h.dtype), cfg.n_head)
-    k = heads(h @ ap["k_w"].astype(h.dtype), cfg.n_kv_head)
-    v = heads(h @ ap["v_w"].astype(h.dtype), cfg.n_kv_head)
-    return q, k, v
+    return (project_heads(h, ap["q_w"], cfg.n_head),
+            project_heads(h, ap["k_w"], cfg.n_kv_head),
+            project_heads(h, ap["v_w"], cfg.n_kv_head))
 
 
 def _self_attention(cfg: NemotronHConfig, q, k, v):
@@ -489,7 +494,8 @@ def _sequence(cfg: NemotronHConfig, params, tokens, delta_len):
         else jnp.arange(T) < delta_len
     end = T if delta_len is None else delta_len
     pad = -T % cfg.chunk_size
-    stacked = _stacked_experts(params) if cfg.count("E") else None
+    stacked = stacked_experts(params, names=("up_w", "down_w")) \
+        if cfg.count("E") else None
     kept = {"kv": [], "ssm": [], "conv": []}
     stats = []
     with jax.named_scope("embed"):
@@ -528,8 +534,7 @@ def _sequence(cfg: NemotronHConfig, params, tokens, delta_len):
                     q, k, v = _qkv(cfg, ap, h)
                     kept["kv"].append((k[0], v[0]))
                     attn = _self_attention(cfg, q, k, v)
-                    merged = attn.transpose(0, 2, 1, 3).reshape(B, T, -1)
-                    x = x + merged @ ap["o_w"].astype(x.dtype)
+                    x = x + merge_heads(attn) @ ap["o_w"].astype(x.dtype)
             else:
                 ep = _at(params["moe"], i)
                 h = rms_norm(x, ep["norm"], eps).reshape(B * T, -1)
@@ -537,152 +542,5 @@ def _sequence(cfg: NemotronHConfig, params, tokens, delta_len):
                 out, st = _experts(cfg, ep, stacked, i, h, valid)
                 stats.append(st)
                 x = x + out.reshape(x.shape)
-    return _lm_head(cfg, params, x), kept, stats
-
-
-# -- the paged steps ------------------------------------------------------
-
-def _rows_view(pool):
-    """[L, P, Hkv, page_len, Dh] as the engine holds it -> every key row
-    of every layer in one column [L*P*Hkv*page_len, Dh].  Same bytes."""
-    return pool.reshape(-1, pool.shape[-1])
-
-
-def _write_rows(rows, new, index, keep):
-    """``rows[index[i]] = new[i]`` where ``keep[i]``; the others write
-    their old value back (their index names the scratch page)."""
-    old = rows[index]
-    return rows.at[index].set(
-        jnp.where(keep[:, None], new.astype(rows.dtype), old))
-
-
-def _row_index(pages_flat, offs, kv_heads: int, page_len: int):
-    """Row of key ``offs[i]`` of page ``pages_flat[i]`` (layer's base
-    added) for each key head -> [n, Hkv] flattened."""
-    g = jnp.arange(kv_heads, dtype=jnp.int32)
-    return ((pages_flat[:, None] * kv_heads + g[None, :]) * page_len
-            + offs[:, None]).reshape(-1)
-
-
-def nemotron_h_decode_step_paged(cfg: NemotronHConfig, params, tokens,
-                                 k_pool, v_pool, page_table, lengths,
-                                 active, state, impl: Optional[str] = None,
-                                 aux: bool = False):
-    """One decode tick of every slot; ``gpt2_decode_step_paged``'s
-    contract plus the request state.  Returns (logits [S, V], k_pool,
-    v_pool, state, new_lengths) and, with ``aux``, the tick's counters.
-    An inactive slot's state is neither read nor written."""
-    from ..ops.pallas.decode_attention import decode_attention_paged
-    if impl is None:
-        impl = "pallas" if cfg.attn_impl == "flash" else "dense"
-    shape = k_pool.shape
-    pages, kv_heads, page_len = shape[1], shape[2], shape[3]
-    S = page_table.shape[0]
-    cap = page_table.shape[1] * page_len
-    eps = cfg.layer_norm_epsilon
-    lengths = lengths.astype(jnp.int32)
-    positions = jnp.clip(lengths, 0, min(cap, cfg.n_positions) - 1)
-    att_len = jnp.where(active, lengths + 1, 0).astype(jnp.int32)
-    page_ids = jnp.where(
-        active, page_table[jnp.arange(S), positions // page_len], 0)
-    offs = positions % page_len
-    keep_rows = jnp.repeat(active, kv_heads)
-    stacked = _stacked_experts(params) if cfg.count("E") else None
-    ssm, conv = state["ssm"], state["conv"]
-    ssm_flat = ssm.reshape((-1,) + ssm.shape[2:])
-    k_rows, v_rows = _rows_view(k_pool), _rows_view(v_pool)
-    stats = []
-    with jax.named_scope("embed"):
-        x = params["wte"][tokens]                           # [S, d]
-    for kind, i in _layers(cfg):
-        with jax.named_scope("layer"):
-            if kind == "M":
-                mp = _at(params["mamba"], i)
-                with jax.named_scope("ssm"):
-                    h = rms_norm(x, mp["norm"], eps)
-                    z, xbc, dt_raw = _mamba_in(cfg, mp, h)
-                    window = jnp.concatenate(
-                        [conv[i], xbc[:, None].astype(conv.dtype)], axis=1)
-                    conv = conv.at[i].set(jnp.where(
-                        active[:, None, None], window[:, 1:], conv[i]))
-                    x_s, b, c, dt, a = _ssm_inputs(
-                        cfg, mp, _conv(mp, [window[:, j] for j in range(
-                            cfg.conv_kernel)]), dt_raw)
-                    with jax.named_scope("scan"):
-                        ssm_flat, y = ssm_decode(
-                            ssm_flat, jnp.exp(dt * a), dt[..., None] * x_s,
-                            b, c, active, base=i * S)
-                    x = x + _mamba_out(cfg, mp, y, x_s, z, x.dtype)
-            elif kind == "*":
-                ap = _at(params["attn"], i)
-                with jax.named_scope("attn"):
-                    h = rms_norm(x, ap["norm"], eps)
-                    q, k, v = _qkv(cfg, ap, h[:, None])
-                    index = _row_index(i * pages + page_ids, offs,
-                                       kv_heads, page_len)
-                    k_rows = _write_rows(
-                        k_rows, k[:, :, 0].reshape(-1, cfg.d_head), index,
-                        keep_rows)
-                    v_rows = _write_rows(
-                        v_rows, v[:, :, 0].reshape(-1, cfg.d_head), index,
-                        keep_rows)
-                    flat = (-1,) + shape[2:]
-                    attn = decode_attention_paged(
-                        q[:, :, 0], k_rows.reshape(flat),
-                        v_rows.reshape(flat), page_table + i * pages,
-                        att_len, impl=impl)
-                    x = x + attn.reshape(S, -1) @ ap["o_w"].astype(x.dtype)
-            else:
-                ep = _at(params["moe"], i)
-                out, st = _experts(cfg, ep, stacked, i,
-                                   rms_norm(x, ep["norm"], eps), active)
-                stats.append(st)
-                x = x + out
-    logits = _lm_head(cfg, params, x)
-    new_state = {"ssm": ssm_flat.reshape(ssm.shape), "conv": conv}
-    out = (logits, k_rows.reshape(shape), v_rows.reshape(shape), new_state,
-           lengths + active.astype(jnp.int32))
-    return out + (_aux(cfg, stats),) if aux else out
-
-
-def nemotron_h_prefill_paged(cfg: NemotronHConfig, params, tokens,
-                             delta_len, page_row, k_pool, v_pool, state,
-                             slot, aux: bool = False):
-    """Prefill of one request into the pool and into ``slot`` of the
-    request state.  tokens [1, Tq] right-padded to the bucket;
-    ``delta_len``, ``page_row`` [max_pages] and ``slot`` traced.  No
-    cached prefix: the engine refuses the prefix cache for this model,
-    because a page of keys without the recurrent state at its boundary
-    is no prefix.  Returns (logits [1, Tq, V], k_pool, v_pool, state);
-    ``logits[0, delta_len - 1]`` scores the first generated token.  The
-    slot's state is OVERWRITTEN with the state at ``delta_len``: padding
-    takes ``dt = 0`` and feeds nothing, the conv window is read at the
-    true end."""
-    B, Tq = tokens.shape
-    shape = k_pool.shape
-    pages, kv_heads, page_len = shape[1], shape[2], shape[3]
-    cap = page_row.shape[0] * page_len
-    delta_len = jnp.asarray(delta_len, jnp.int32)
-    slot = jnp.asarray(slot, jnp.int32)
-    pos = jnp.clip(jnp.arange(Tq, dtype=jnp.int32), 0, cap - 1)
-    valid = jnp.arange(Tq) < delta_len
-    page_ids = jnp.where(valid, page_row[pos // page_len], 0)
-    offs = pos % page_len
-    keep_rows = jnp.repeat(valid, kv_heads)
-    logits, kept, stats = _sequence(cfg, params, tokens, delta_len)
-    k_rows, v_rows = _rows_view(k_pool), _rows_view(v_pool)
-    for i, (k, v) in enumerate(kept["kv"]):                 # [Hkv, Tq, Dh]
-        index = _row_index(i * pages + page_ids, offs, kv_heads, page_len)
-        k_rows = _write_rows(k_rows, k.transpose(1, 0, 2).reshape(
-            -1, cfg.d_head), index, keep_rows)
-        v_rows = _write_rows(v_rows, v.transpose(1, 0, 2).reshape(
-            -1, cfg.d_head), index, keep_rows)
-    new_state = dict(state)
-    if kept["ssm"]:
-        for name in ("ssm", "conv"):
-            leaf = state[name]
-            new = jnp.stack(kept[name])[:, None].astype(leaf.dtype)
-            new_state[name] = jax.lax.dynamic_update_slice(
-                leaf, new, (0, slot) + (0,) * (leaf.ndim - 2))
-    out = (logits, k_rows.reshape(shape), v_rows.reshape(shape), new_state)
-    return out + (_aux(cfg, stats),) if aux else out
+    logits = lm_head(x, params["norm_f"], params["lm_head"], eps)
+    return logits, kept, stats

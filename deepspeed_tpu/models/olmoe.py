@@ -44,10 +44,12 @@ import jax.numpy as jnp
 
 from ..moe.dropless import dropless_moe
 from ..ops.attention import causal_attention
+from .walked import (ServedConfig, WalkedModel, decode_index, default_scale,
+                     lm_head, merge_heads, prefill_index, rms_norm, rope)
 
 
 @dataclasses.dataclass(frozen=True)
-class OlmoeConfig:
+class OlmoeConfig(ServedConfig):
     """The source's keys (HF ``config.json``), then the program's own."""
     vocab_size: int = 50304
     hidden_size: int = 2048
@@ -80,16 +82,11 @@ class OlmoeConfig:
             f"hidden_act {self.hidden_act!r} (only 'silu')":
                 self.hidden_act != "silu",
         }
-        bad = [k for k, v in unbuilt.items() if v]
-        if bad:
-            raise ValueError("OlmoeConfig: not built: " + "; ".join(bad))
+        self.check(unbuilt)
         if self.hidden_size % self.num_attention_heads:
             raise ValueError("hidden_size must divide into the heads")
         if self.num_experts_per_tok > self.num_experts:
             raise ValueError("num_experts_per_tok exceeds num_experts")
-        if self.attn_impl not in ("flash", "dense"):
-            raise ValueError(f"attn_impl {self.attn_impl!r}: 'flash' or "
-                             "'dense'")
 
     # -- what the serving engine reads of any model's config -------------
     @property
@@ -97,16 +94,8 @@ class OlmoeConfig:
         return self.num_hidden_layers
 
     @property
-    def n_head(self) -> int:
-        return self.num_attention_heads
-
-    @property
     def d_head(self) -> int:
         return self.hidden_size // self.num_attention_heads
-
-    @property
-    def n_positions(self) -> int:
-        return self.max_position_embeddings
 
     @property
     def num_params(self) -> int:
@@ -115,20 +104,10 @@ class OlmoeConfig:
         return 2 * self.vocab_size * d + d + self.num_hidden_layers * per_layer
 
 
-class OlmoeModel:
-    #: the serving features this model's paged steps do not have; the
-    #: engine refuses a configuration that asks for one (``ServeEngine``)
-    serving_unsupported = ("slot_cache", "speculate_k", "quantization",
-                           "lora")
-    #: the paged steps also return these per-call counters (``aux=True``):
-    #: the engine keeps them per call (``ServeEngine.aux_log``)
+class OlmoeModel(WalkedModel):
+    #: every expert is held here: no ``moe_rows_elsewhere``
     serving_aux = ("moe_experts_hit", "moe_load_imbalance", "moe_rows")
-
-    def __init__(self, config: OlmoeConfig):
-        self.config = config
-
-    def param_partition_specs(self, params):
-        return None                     # one chip: everything replicated
+    refusal_note = " (int8 KV and LoRA are GPT2Model's)"
 
     def init(self, rng) -> Dict[str, Any]:
         """HF's init: every matrix normal(0, initializer_range), every
@@ -163,65 +142,151 @@ class OlmoeModel:
     def apply(self, params, tokens, aux: bool = False):
         """tokens [B, T] -> logits [B, T, V]: the whole-sequence forward
         (no cache)."""
-        return olmoe_forward(self.config, params, tokens, aux=aux)
+        cfg = self.config
+        B, T = tokens.shape
+        if T > cfg.n_positions:
+            raise ValueError(f"sequence length {T} exceeds "
+                             f"max_position_embeddings={cfg.n_positions}")
+        positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+        small, stacked = _split_blocks(params["blocks"])
+        with jax.named_scope("embed"):
+            x = params["wte"][tokens]
 
-    def prefill_paged(self, params, tokens, delta_len, prefix_len, page_row,
-                      k_pool, v_pool, aux: bool = False, **unbuilt):
-        _refuse(unbuilt)
-        return olmoe_prefill_paged(self.config, params, tokens, delta_len,
-                                   prefix_len, page_row, k_pool, v_pool,
-                                   aux=aux)
+        def body(x, xs):
+            bp, layer = xs
+            with jax.named_scope("layer"):
+                with jax.named_scope("attn"):
+                    h = rms_norm(x, bp["ln1"], cfg.rms_norm_eps)
+                    q, k, v = qkv_heads(cfg, bp, h, positions)
+                    x = _attn_out(bp, x, _self_attention(cfg, q, k, v))
+                return _experts(cfg, bp, stacked, layer, x)
+
+        x, stats = jax.lax.scan(
+            body, x, (small, jnp.arange(cfg.n_layer, dtype=jnp.int32)))
+        logits = lm_head(x, params["norm_f"], params["lm_head"],
+                         cfg.rms_norm_eps)
+        return (logits, _aux(cfg, stats)) if aux else logits
 
     def decode_step_paged(self, params, tokens, k_pool, v_pool, page_table,
                           lengths, active, impl: Optional[str] = None,
                           aux: bool = False, **unbuilt):
-        _refuse(unbuilt)
-        return olmoe_decode_step_paged(self.config, params, tokens, k_pool,
-                                       v_pool, page_table, lengths, active,
-                                       impl=impl, aux=aux)
+        """One decode tick of every slot over the paged pool; the contract
+        of ``gpt2_decode_step_paged`` (masked no-op for inactive slots,
+        every operand traced).  Returns (logits [S, V], k_pool, v_pool,
+        new_lengths) and, with ``aux``, the tick's expert counters."""
+        from ..ops.pallas.decode_attention import decode_attention_paged
+        self.refuse(unbuilt)
+        cfg, impl = self.config, self.decode_impl(impl)
+        shape = k_pool.shape
+        pages, page_len = shape[1], shape[3]
+        lengths, positions, att_len, page_ids, offs = decode_index(
+            page_table, lengths, active, page_len, cfg.n_positions)
+        small, stacked = _split_blocks(params["blocks"])
+        with jax.named_scope("embed"):
+            x = params["wte"][tokens][:, None, :]           # [S, 1, d]
 
+        def body(carry, xs):
+            x, kf, vf = carry
+            bp, layer = xs
+            base = layer * pages
+            with jax.named_scope("layer"):
+                with jax.named_scope("attn"):
+                    h = rms_norm(x, bp["ln1"], cfg.rms_norm_eps)
+                    q, k, v = qkv_heads(cfg, bp, h, positions[:, None])
+                    kf = _write_rows(kf, k[:, :, 0], base + page_ids, offs,
+                                     active)
+                    vf = _write_rows(vf, v[:, :, 0], base + page_ids, offs,
+                                     active)
+                    attn = decode_attention_paged(
+                        q[:, :, 0], _kernel_view(kf), _kernel_view(vf),
+                        page_table + base, att_len, impl=impl)
+                    x = _attn_out(bp, x, attn[:, :, None, :])
+                x, stats = _experts(cfg, bp, stacked, layer, x, valid=active)
+            return (x, kf, vf), stats
 
-def _refuse(unbuilt: dict) -> None:
-    asked = sorted(k for k, v in unbuilt.items() if v is not None)
-    if asked:
-        raise NotImplementedError(
-            f"OlmoeModel's paged steps have no {', '.join(asked)} arm "
-            "(int8 KV and LoRA are GPT2Model's)")
+        (x, kf, vf), stats = jax.lax.scan(
+            body, (x, _flat_pool(k_pool), _flat_pool(v_pool)),
+            (small, jnp.arange(cfg.n_layer, dtype=jnp.int32)))
+        logits = lm_head(x, params["norm_f"], params["lm_head"],
+                         cfg.rms_norm_eps)[:, 0]
+        out = (logits, kf.reshape(shape), vf.reshape(shape),
+               lengths + active.astype(jnp.int32))
+        return out + (_aux(cfg, stats),) if aux else out
 
+    def prefill_paged(self, params, tokens, delta_len, prefix_len, page_row,
+                      k_pool, v_pool, aux: bool = False, **unbuilt):
+        """Delta-aware prefill into the paged pool; the contract of
+        ``gpt2_prefill_paged``: tokens [1, Tq] are the prompt less its
+        cached prefix, right-padded to the bucket; ``delta_len``,
+        ``prefix_len`` and ``page_row`` [max_pages] are traced.  Returns
+        (logits [1, Tq, V], k_pool, v_pool); ``logits[0, delta_len - 1]``
+        scores the first generated token.  Padding rows reach no expert
+        and write no page."""
+        self.refuse(unbuilt)
+        cfg = self.config
+        B, Tq = tokens.shape
+        if Tq > cfg.n_positions:
+            raise ValueError(f"sequence length {Tq} exceeds "
+                             f"max_position_embeddings={cfg.n_positions}")
+        shape = k_pool.shape
+        pages, page_len = shape[1], shape[3]
+        cap = page_row.shape[0] * page_len
+        prefix_len = jnp.asarray(prefix_len, jnp.int32)
+        delta_len = jnp.asarray(delta_len, jnp.int32)
+        valid, page_ids, offs, abs_pos, positions = prefill_index(
+            page_row, delta_len, Tq, page_len, prefix_len, cfg.n_positions)
+        small, stacked = _split_blocks(params["blocks"])
+        with jax.named_scope("embed"):
+            x = params["wte"][tokens]                       # [1, Tq, d]
 
-def decode_attn_impl(cfg: OlmoeConfig) -> str:
-    return "pallas" if cfg.attn_impl == "flash" else "dense"
+        def body(carry, xs):
+            x, kf, vf = carry
+            bp, layer = xs
+            base = layer * pages
+            with jax.named_scope("layer"):
+                with jax.named_scope("attn"):
+                    h = rms_norm(x, bp["ln1"], cfg.rms_norm_eps)
+                    q, k, v = qkv_heads(cfg, bp, h, positions)
+                    kf = _write_rows(kf, k[0].transpose(1, 0, 2),
+                                     base + page_ids, offs, valid)
+                    vf = _write_rows(vf, v[0].transpose(1, 0, 2),
+                                     base + page_ids, offs, valid)
+
+                    def cached_prefix(_):
+                        # dense attention over the slot's pages: the
+                        # cached prefix and the causal delta
+                        rows = base + page_row
+                        kg = kf[rows].reshape(cap, cfg.n_head, cfg.d_head)
+                        vg = vf[rows].reshape(cap, cfg.n_head, cfg.d_head)
+                        s = jnp.einsum("htd,shd->hts", q[0],
+                                       kg.astype(q.dtype),
+                                       preferred_element_type=jnp.float32)
+                        s = s * default_scale(cfg.d_head)
+                        ok = jnp.arange(cap)[None, :] <= abs_pos[:, None]
+                        s = jnp.where(ok[None], s,
+                                      jnp.finfo(jnp.float32).min)
+                        p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+                        return jnp.einsum("hts,shd->htd", p,
+                                          vg.astype(q.dtype))[None]
+
+                    attn = jax.lax.cond(
+                        prefix_len == 0,
+                        lambda _: _self_attention(cfg, q, k, v),
+                        cached_prefix, operand=None)
+                    x = _attn_out(bp, x, attn)
+                x, stats = _experts(cfg, bp, stacked, layer, x, valid=valid)
+            return (x, kf, vf), stats
+
+        (x, kf, vf), stats = jax.lax.scan(
+            body, (x, _flat_pool(k_pool), _flat_pool(v_pool)),
+            (small, jnp.arange(cfg.n_layer, dtype=jnp.int32)))
+        logits = lm_head(x, params["norm_f"], params["lm_head"],
+                         cfg.rms_norm_eps)
+        out = (logits, kf.reshape(shape), vf.reshape(shape))
+        return out + (_aux(cfg, stats),) if aux else out
 
 
 # -- the block's pieces ---------------------------------------------------
-
-def rms_norm(x, weight, eps: float):
-    """HF ``OlmoeRMSNorm``: normalise in float32, back to x's type, then
-    the weight."""
-    xf = x.astype(jnp.float32)
-    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
-    return y.astype(x.dtype) * weight.astype(x.dtype)
-
-
-def rope(x, positions, theta: float, rotary_dim: Optional[int] = None,
-         inv_freq=None):
-    """Rotate-half RoPE.  x [B, H, T, Dh], positions [B, T] (absolute).
-    Pair i is (x[i], x[i + R/2]), angle ``pos * theta**(-2i/R)``, over
-    the first ``R = rotary_dim`` dims (None: the whole head); the others
-    pass untouched.  ``inv_freq`` [R/2] float32: a frequency a pair of
-    the caller's own (a scaled RoPE: ``models/axk1.py::yarn_inv_freq``)
-    in place of ``theta``'s, which is then not read."""
-    rot = x.shape[-1] if rotary_dim is None else rotary_dim
-    half = rot // 2
-    if inv_freq is None:
-        inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = positions.astype(jnp.float32)[:, None, :, None] * inv_freq
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., :half], xf[..., half:rot]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
-                            xf[..., rot:]], axis=-1).astype(x.dtype)
-
 
 def qkv_heads(cfg: OlmoeConfig, bp, h, positions):
     """h [B, T, d] (normed) -> q, k, v [B, H, T, Dh], q and k QK-normed
@@ -241,9 +306,7 @@ def qkv_heads(cfg: OlmoeConfig, bp, h, positions):
 
 def _attn_out(bp, x, attn):
     """attn [B, H, T, Dh] -> residual added."""
-    B, H, T, Dh = attn.shape
-    merged = attn.transpose(0, 2, 1, 3).reshape(B, T, H * Dh)
-    return x + merged @ bp["o_w"].astype(x.dtype)
+    return x + merge_heads(attn) @ bp["o_w"].astype(x.dtype)
 
 
 def _experts(cfg: OlmoeConfig, bp, stacked, layer, x, valid=None):
@@ -278,12 +341,6 @@ def _aux(cfg: OlmoeConfig, stats) -> Dict[str, jnp.ndarray]:
             "moe_load_imbalance": jnp.max(stats.max_rows / mean)}
 
 
-@jax.named_scope("lm_head")
-def _lm_head(cfg: OlmoeConfig, params, x):
-    x = rms_norm(x, params["norm_f"], cfg.rms_norm_eps)
-    return x @ params["lm_head"].astype(x.dtype)
-
-
 def _self_attention(cfg: OlmoeConfig, q, k, v):
     if cfg.attn_impl == "flash":
         from ..parallel.attention import sharded_flash_attention
@@ -291,32 +348,7 @@ def _self_attention(cfg: OlmoeConfig, q, k, v):
     return causal_attention(q, k, v)
 
 
-def olmoe_forward(cfg: OlmoeConfig, params, tokens, aux: bool = False):
-    B, T = tokens.shape
-    if T > cfg.n_positions:
-        raise ValueError(f"sequence length {T} exceeds "
-                         f"max_position_embeddings={cfg.n_positions}")
-    positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
-    small, stacked = _split_blocks(params["blocks"])
-    with jax.named_scope("embed"):
-        x = params["wte"][tokens]
-
-    def body(x, xs):
-        bp, layer = xs
-        with jax.named_scope("layer"):
-            with jax.named_scope("attn"):
-                h = rms_norm(x, bp["ln1"], cfg.rms_norm_eps)
-                q, k, v = qkv_heads(cfg, bp, h, positions)
-                x = _attn_out(bp, x, _self_attention(cfg, q, k, v))
-            return _experts(cfg, bp, stacked, layer, x)
-
-    x, stats = jax.lax.scan(
-        body, x, (small, jnp.arange(cfg.n_layer, dtype=jnp.int32)))
-    logits = _lm_head(cfg, params, x)
-    return (logits, _aux(cfg, stats)) if aux else logits
-
-
-# -- the paged steps ------------------------------------------------------
+# -- the paged steps' pool -----------------------------------------------
 
 def _flat_pool(pool):
     """[L, P, H, page_len, Dh] as the engine holds it -> every layer's
@@ -340,126 +372,3 @@ def _kernel_view(flat):
     [X, H, page_len, Dh]: that file's own transpose to
     [X, page_len, H, Dh] then cancels this one and nothing moves."""
     return flat.transpose(0, 2, 1, 3)
-
-
-def olmoe_decode_step_paged(cfg: OlmoeConfig, params, tokens, k_pool,
-                            v_pool, page_table, lengths, active,
-                            impl: Optional[str] = None, aux: bool = False):
-    """One decode tick of every slot over the paged pool; the contract of
-    ``gpt2_decode_step_paged`` (masked no-op for inactive slots, every
-    operand traced).  Returns (logits [S, V], k_pool, v_pool,
-    new_lengths) and, with ``aux``, the tick's expert counters."""
-    from ..ops.pallas.decode_attention import decode_attention_paged
-    if impl is None:
-        impl = decode_attn_impl(cfg)
-    shape = k_pool.shape
-    pages, page_len = shape[1], shape[3]
-    cap = page_table.shape[1] * page_len
-    lengths = lengths.astype(jnp.int32)
-    positions = jnp.clip(lengths, 0, min(cap, cfg.n_positions) - 1)
-    att_len = jnp.where(active, lengths + 1, 0).astype(jnp.int32)
-    s_idx = jnp.arange(page_table.shape[0])
-    page_ids = jnp.where(active, page_table[s_idx, positions // page_len], 0)
-    offs = positions % page_len
-    small, stacked = _split_blocks(params["blocks"])
-    with jax.named_scope("embed"):
-        x = params["wte"][tokens][:, None, :]               # [S, 1, d]
-
-    def body(carry, xs):
-        x, kf, vf = carry
-        bp, layer = xs
-        base = layer * pages
-        with jax.named_scope("layer"):
-            with jax.named_scope("attn"):
-                h = rms_norm(x, bp["ln1"], cfg.rms_norm_eps)
-                q, k, v = qkv_heads(cfg, bp, h, positions[:, None])
-                kf = _write_rows(kf, k[:, :, 0], base + page_ids, offs,
-                                 active)
-                vf = _write_rows(vf, v[:, :, 0], base + page_ids, offs,
-                                 active)
-                attn = decode_attention_paged(
-                    q[:, :, 0], _kernel_view(kf), _kernel_view(vf),
-                    page_table + base, att_len, impl=impl)
-                x = _attn_out(bp, x, attn[:, :, None, :])
-            x, stats = _experts(cfg, bp, stacked, layer, x, valid=active)
-        return (x, kf, vf), stats
-
-    (x, kf, vf), stats = jax.lax.scan(
-        body, (x, _flat_pool(k_pool), _flat_pool(v_pool)),
-        (small, jnp.arange(cfg.n_layer, dtype=jnp.int32)))
-    logits = _lm_head(cfg, params, x)[:, 0]
-    out = (logits, kf.reshape(shape), vf.reshape(shape),
-           lengths + active.astype(jnp.int32))
-    return out + (_aux(cfg, stats),) if aux else out
-
-
-def olmoe_prefill_paged(cfg: OlmoeConfig, params, tokens, delta_len,
-                        prefix_len, page_row, k_pool, v_pool,
-                        aux: bool = False):
-    """Delta-aware prefill into the paged pool; the contract of
-    ``gpt2_prefill_paged``: tokens [1, Tq] are the prompt less its cached
-    prefix, right-padded to the bucket; ``delta_len``, ``prefix_len`` and
-    ``page_row`` [max_pages] are traced.  Returns (logits [1, Tq, V],
-    k_pool, v_pool); ``logits[0, delta_len - 1]`` scores the first
-    generated token.  Padding rows reach no expert and write no page."""
-    from ..ops.pallas.decode_attention import _default_scale
-    B, Tq = tokens.shape
-    if Tq > cfg.n_positions:
-        raise ValueError(f"sequence length {Tq} exceeds "
-                         f"max_position_embeddings={cfg.n_positions}")
-    shape = k_pool.shape
-    pages, page_len = shape[1], shape[3]
-    cap = page_row.shape[0] * page_len
-    prefix_len = jnp.asarray(prefix_len, jnp.int32)
-    delta_len = jnp.asarray(delta_len, jnp.int32)
-    abs_pos = prefix_len + jnp.arange(Tq, dtype=jnp.int32)
-    valid = jnp.arange(Tq) < delta_len
-    abs_clip = jnp.clip(abs_pos, 0, cap - 1)
-    page_ids = jnp.where(valid, page_row[abs_clip // page_len], 0)
-    offs = abs_clip % page_len
-    positions = jnp.clip(abs_pos, 0, cfg.n_positions - 1)[None]
-    small, stacked = _split_blocks(params["blocks"])
-    with jax.named_scope("embed"):
-        x = params["wte"][tokens]                           # [1, Tq, d]
-
-    def body(carry, xs):
-        x, kf, vf = carry
-        bp, layer = xs
-        base = layer * pages
-        with jax.named_scope("layer"):
-            with jax.named_scope("attn"):
-                h = rms_norm(x, bp["ln1"], cfg.rms_norm_eps)
-                q, k, v = qkv_heads(cfg, bp, h, positions)
-                kf = _write_rows(kf, k[0].transpose(1, 0, 2),
-                                 base + page_ids, offs, valid)
-                vf = _write_rows(vf, v[0].transpose(1, 0, 2),
-                                 base + page_ids, offs, valid)
-
-                def cached_prefix(_):
-                    # dense attention over the slot's pages: the cached
-                    # prefix and the causal delta
-                    rows = base + page_row
-                    kg = kf[rows].reshape(cap, cfg.n_head, cfg.d_head)
-                    vg = vf[rows].reshape(cap, cfg.n_head, cfg.d_head)
-                    s = jnp.einsum("htd,shd->hts", q[0], kg.astype(q.dtype),
-                                   preferred_element_type=jnp.float32)
-                    s = s * _default_scale(cfg.d_head)
-                    ok = jnp.arange(cap)[None, :] <= abs_pos[:, None]
-                    s = jnp.where(ok[None], s, jnp.finfo(jnp.float32).min)
-                    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-                    return jnp.einsum("hts,shd->htd", p,
-                                      vg.astype(q.dtype))[None]
-
-                attn = jax.lax.cond(
-                    prefix_len == 0,
-                    lambda _: _self_attention(cfg, q, k, v), cached_prefix,
-                    operand=None)
-                x = _attn_out(bp, x, attn)
-            x, stats = _experts(cfg, bp, stacked, layer, x, valid=valid)
-        return (x, kf, vf), stats
-
-    (x, kf, vf), stats = jax.lax.scan(
-        body, (x, _flat_pool(k_pool), _flat_pool(v_pool)),
-        (small, jnp.arange(cfg.n_layer, dtype=jnp.int32)))
-    out = (_lm_head(cfg, params, x), kf.reshape(shape), vf.reshape(shape))
-    return out + (_aux(cfg, stats),) if aux else out

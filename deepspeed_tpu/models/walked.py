@@ -1,0 +1,426 @@
+"""What the served families whose layers are WALKED share: ``olmoe.py``
+(the one that scans), ``nemotron_h.py``, ``mimo_v2.py``, ``axk1.py``,
+``cohere2_moe.py``.  A family's file holds what is its own: its config under
+the source's keys, ``init`` and the parameter tree, its projections,
+latents and mixers, its list of layer kinds, and two paged steps that read
+as that list walked over the pieces here.  No family imports another; the
+next one imports this module, ``ops`` and ``moe``.
+
+The functions know no family and take VALUES, never a config (the configs
+keep their sources' key names: ``rms_norm_eps``, ``layernorm_epsilon``,
+``layer_norm_epsilon``, ``layer_norm_eps``): the mathematics two or more
+families call; the index preludes of the two paged steps
+(:func:`decode_index`, :func:`prefill_index`); the cache's bookkeeping as
+two pairs of write and attend (:class:`PagePool`, :class:`Rings`); the
+routed-expert call (:func:`routed_experts`).  ONE rule for what is not
+live: an inactive slot and a padded prompt row name page 0, the engine's
+scratch page, are kept out of every write and attend over length 0.
+:class:`ServedConfig` and :class:`WalkedModel` are what ``ServeEngine``
+reads of every one of them alike.
+"""
+from __future__ import annotations
+
+from typing import Dict, Optional
+
+import jax
+import jax.numpy as jnp
+
+from ..moe.dropless import dropless_moe, route_sigmoid_topk
+
+F32 = jnp.float32
+
+
+class ServedConfig:
+    """What ``ServeEngine`` reads of any model's config under the names
+    every source shares (HF ``config.json``); ``n_layer`` (the pool's
+    depth), ``d_head`` and the rest are the family's."""
+
+    @property
+    def n_head(self) -> int:
+        return self.num_attention_heads
+
+    @property
+    def n_kv_head(self) -> int:
+        return self.num_key_value_heads
+
+    @property
+    def n_positions(self) -> int:
+        return self.max_position_embeddings
+
+    def check(self, unbuilt: Dict[str, bool],
+              experts: Optional[int] = None) -> None:
+        """Refuses what the source asks for and is not built (``unbuilt``:
+        what -> asked), ``experts_held`` no range of ``experts``."""
+        bad = [k for k, v in unbuilt.items() if v]
+        if bad:
+            raise ValueError(f"{type(self).__name__}: not built: "
+                             + "; ".join(bad))
+        if experts is not None:
+            first, count = self.held
+            if first < 0 or count < 1 or first + count > experts:
+                raise ValueError(f"experts_held {self.experts_held}: not a "
+                                 f"range of the {experts}")
+        if self.attn_impl not in ("flash", "dense"):
+            raise ValueError(f"attn_impl {self.attn_impl!r}: 'flash' or "
+                             "'dense'")
+
+
+class WalkedModel:
+    """The part of ``ServeEngine``'s protocol the families spell alike
+    (``docs/serving.md``, "Adding a served family")."""
+    #: the serving features the paged steps do not have; the engine
+    #: refuses a configuration that asks for one (``ServeEngine``)
+    serving_unsupported = ("slot_cache", "speculate_k", "quantization",
+                           "lora")
+    #: the paged steps also return these per-call counters (``aux=True``):
+    #: the engine keeps them per call (``ServeEngine.aux_log``)
+    serving_aux = ("moe_experts_hit", "moe_load_imbalance", "moe_rows",
+                   "moe_rows_elsewhere")
+    refusal_note = ""                   # whose such an arm is, if anyone's
+
+    def __init__(self, config):
+        self.config = config
+
+    def param_partition_specs(self, params):
+        return None                     # one chip: everything replicated
+
+    def decode_impl(self, impl: Optional[str]) -> str:
+        """The decode kernels' arm where the engine names none."""
+        return impl or (
+            "pallas" if self.config.attn_impl == "flash" else "dense")
+
+    def refuse(self, unbuilt: dict) -> None:
+        """The keyword arms a paged step was handed and does not have."""
+        asked = sorted(k for k, v in unbuilt.items() if v is not None)
+        if asked:
+            raise NotImplementedError(
+                f"{type(self).__name__}'s paged steps have no "
+                f"{', '.join(asked)} arm{self.refusal_note}")
+
+
+# -- the leaf helpers -------------------------------------------------------
+
+def rms_norm(x, weight, eps: float):
+    """HF ``OlmoeRMSNorm``: normalise in float32, back to x's type, then
+    the weight."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return y.astype(x.dtype) * weight.astype(x.dtype)
+
+
+def rope(x, positions, theta: float, rotary_dim: Optional[int] = None,
+         inv_freq=None):
+    """Rotate-half RoPE.  x [B, H, T, Dh], positions [B, T] (absolute).
+    Pair i is (x[i], x[i + R/2]), angle ``pos * theta**(-2i/R)``, over
+    the first ``R = rotary_dim`` dims (None: the whole head); the others
+    pass untouched.  ``inv_freq`` [R/2] float32: a frequency a pair of
+    the caller's own (a scaled RoPE: ``models/axk1.py::yarn_inv_freq``)
+    in place of ``theta``'s, which is then not read."""
+    rot = x.shape[-1] if rotary_dim is None else rotary_dim
+    half = rot // 2
+    if inv_freq is None:
+        inv_freq = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = positions.astype(jnp.float32)[:, None, :, None] * inv_freq
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:rot]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
+                            xf[..., rot:]], axis=-1).astype(x.dtype)
+
+
+def project_heads(h, w, n: int):
+    """h [B, T, d] @ w [d, n * width] -> [B, n, T, width]."""
+    B, T, _ = h.shape
+    return (h @ w.astype(h.dtype)).reshape(B, T, n, -1).transpose(0, 2, 1, 3)
+
+
+def merge_heads(t):
+    """[B, n, T, width] -> [B, T, n * width]."""
+    B, _, T, _ = t.shape
+    return t.transpose(0, 2, 1, 3).reshape(B, T, -1)
+
+
+def grouped_causal_attention(q, k, v, window=None, sink=None,
+                             sm_scale=None):
+    """The dense (XLA) arm of a whole sequence's attention: q [B, Hq, T,
+    Dk] over k [B, Hkv, T, Dk], v [B, Hkv, T, Dv]; ``window``: the last
+    so many keys, the query's own included; ``sink`` [Hq]: one more
+    softmax column a head that gives no value."""
+    B, Hq, T, _ = q.shape
+    rep = Hq // k.shape[1]
+    k, v = (jnp.repeat(t, rep, axis=1) for t in (k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   preferred_element_type=F32) * sm_scale
+    at = jnp.arange(T)
+    ok = at[None, :] <= at[:, None]
+    if window is not None:
+        ok &= at[None, :] > at[:, None] - window
+    s = jnp.where(ok[None, None], s, jnp.finfo(F32).min)
+    if sink is not None:
+        col = jnp.broadcast_to(sink.astype(F32)[None, :, None, None],
+                               (B, Hq, T, 1))
+        s = jnp.concatenate([s, col], axis=-1)
+    p = jax.nn.softmax(s, axis=-1)[..., :T].astype(q.dtype)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+
+def default_scale(head_dim: int) -> float:
+    """``head_dim ** -0.5``, as the decode kernels round it."""
+    from ..ops.pallas.decode_attention import _default_scale
+    return _default_scale(head_dim)
+
+
+def causal_self_attention(q, k, v, flash: bool, *, window=None, sink=None,
+                          sm_scale=None, **blocks):
+    """A whole sequence's attention from nothing ahead of it, on grouped
+    keys: the flash forward kernel (``blocks``: its ``block_q`` /
+    ``block_k``) or the dense arm."""
+    if flash:
+        from ..ops.pallas.flash_attention import flash_attention_fwd
+        return flash_attention_fwd(q, k, v, window=window, sink=sink,
+                                   sm_scale=sm_scale, **blocks)
+    return grouped_causal_attention(q, k, v, window=window, sink=sink,
+                                    sm_scale=sm_scale)
+
+
+def swiglu(x, gate_w, up_w, down_w):
+    """``down(silu(gate x) * up x)``, every matrix input-major."""
+    g = x @ gate_w.astype(x.dtype)
+    u = x @ up_w.astype(x.dtype)
+    return (jax.nn.silu(g) * u) @ down_w.astype(x.dtype)
+
+
+@jax.named_scope("dense_ffn")
+def dense_ffn(fp, x):
+    return swiglu(x, fp["gate_w"], fp["up_w"], fp["down_w"])
+
+
+@jax.named_scope("lm_head")
+def lm_head(x, norm_w, head_w, eps: float):
+    """The final RMSNorm, then the untied head [d, V]."""
+    x = rms_norm(x, norm_w, eps)
+    return x @ head_w.astype(x.dtype)
+
+
+def at(kind, i: int):
+    """Layer ``i``'s own leaves of a kind (``models/mimo_v2.py``'s rule:
+    each a leaf of the tree, nothing is sliced); not the experts, which
+    reach their kernels whole (:func:`stacked_experts`)."""
+    return {k: v[i] for k, v in kind.items() if isinstance(v, tuple)}
+
+
+def draw_layers(layer, keys, ones: Dict[str, int], dtype):
+    """A kind's per-layer leaves as :func:`at` reads them, name -> a TUPLE
+    of one array a layer: ``layer(key)`` draws a layer's, ``ones`` (name
+    -> width) are the norm weights beside them.  One XLA computation a
+    layer, as the body of a scan over the keys is: the constants of a draw
+    fold the same way called eagerly or inside a caller's jit (apart they
+    round apart, an ulp in a quarter of the numbers)."""
+    draw = jax.jit(layer)
+    drawn = [{**draw(key), **{name: jnp.ones((width,), dtype)
+                              for name, width in ones.items()}}
+             for key in keys]
+    return {leaf: tuple(one[leaf] for one in drawn) for leaf in drawn[0]}
+
+
+def stacked_experts(params, kind: str = "moe",
+                    names=("gate_w", "up_w", "down_w")):
+    """Every expert layer's held experts flat, ``[layers * held, ...]``:
+    a reshape of the leading axes of ``params[kind]``'s ``[layers, held,
+    ...]`` arrays."""
+    tree = params[kind]
+    return {k: tree[k].reshape((-1,) + tree[k].shape[2:]) for k in names}
+
+
+def held_expert_counters(stats, held: int) -> Dict[str, jnp.ndarray]:
+    """The expert layers' HeldMoEStats -> the call's counters: experts
+    hit and rows summed over layers (of the ``held`` HELD experts), the
+    busiest held expert's rows over the mean rows a held expert (largest
+    over layers), and the live assignments that went to experts held
+    elsewhere."""
+    zero = jnp.zeros((), jnp.int32)
+    imb = [s.max_rows / (jnp.maximum(s.rows, 1).astype(F32) / held)
+           for s in stats]
+    return {"moe_experts_hit": sum((s.experts_hit for s in stats), zero),
+            "moe_rows": sum((s.rows for s in stats), zero),
+            "moe_load_imbalance": jnp.max(jnp.stack(imb)) if imb
+            else jnp.zeros((), F32),
+            "moe_rows_elsewhere": sum((s.rows_elsewhere for s in stats),
+                                      zero)}
+
+
+def routed_experts(x, router_w, router_bias, experts, index: int, *,
+                   top_k: int, held, valid, act: str, scale: float = 1.0,
+                   renormalize: bool = True, rows=None):
+    """The routed part of expert layer ``index`` (among the expert layers)
+    on normed x [N, d]: sigmoid routing (``route_sigmoid_topk``), then the
+    ``held = (first, count)`` experts this chip holds on the rows routed
+    to them, of ``rows`` [N, .] where the experts read another width than
+    the router (a latent; default x).  ``experts``: every layer's held
+    experts flat (:func:`stacked_experts`); the kernel finds this layer's
+    at ``index * count``.  Returns (this share's part of the sum,
+    HeldMoEStats).  The activation, the bias, the scale and what stands
+    beside the sum (latent projections, shared experts) are the family's."""
+    routing = route_sigmoid_topk(x, router_w, router_bias, top_k,
+                                 scale=scale, renormalize=renormalize)
+    return dropless_moe(
+        x if rows is None else rows, router_w, experts.get("gate_w"),
+        experts["up_w"], experts["down_w"], top_k,
+        expert_offset=index * held[1], valid=valid, routing=routing,
+        experts_held=held, act=act)
+
+
+# -- the index preludes -----------------------------------------------------
+
+def decode_index(page_table, lengths, active, page_len: int,
+                 n_positions: int):
+    """A decode tick's bookkeeping for slots [S]: -> (lengths as int32,
+    positions [S] of the new token (clipped into the slot's pages and the
+    model's positions), att_len [S] the keys a slot attends over, the new
+    one included (0 where inactive), page_ids [S] and offs [S]: the page
+    and the row of it the new key goes to (page 0 where inactive))."""
+    cap = page_table.shape[1] * page_len
+    lengths = lengths.astype(jnp.int32)
+    positions = jnp.clip(lengths, 0, min(cap, n_positions) - 1)
+    att_len = jnp.where(active, lengths + 1, 0).astype(jnp.int32)
+    s_idx = jnp.arange(page_table.shape[0])
+    page_ids = jnp.where(active, page_table[s_idx, positions // page_len], 0)
+    return lengths, positions, att_len, page_ids, positions % page_len
+
+
+def prefill_index(page_row, delta_len, Tq: int, page_len: int,
+                  prefix_len=None, n_positions: Optional[int] = None):
+    """A prefill's bookkeeping for the ``Tq`` rows of a bucket, the first
+    ``delta_len`` (traced) the prompt's, at positions ``prefix_len ..``
+    (traced; None: a whole prompt, from 0): -> (valid [Tq], page_ids [Tq]
+    and offs [Tq]: the page of ``page_row`` [max_pages] and the row of it
+    a position's key goes to (page 0 where not valid), abs_pos [Tq]
+    unclipped, positions [1, Tq] clipped into the model's (None without
+    ``prefix_len``))."""
+    cap = page_row.shape[0] * page_len
+    abs_pos = jnp.arange(Tq, dtype=jnp.int32)
+    if prefix_len is not None:
+        abs_pos = prefix_len + abs_pos
+    valid = jnp.arange(Tq) < delta_len
+    abs_clip = jnp.clip(abs_pos, 0, cap - 1)
+    page_ids = jnp.where(valid, page_row[abs_clip // page_len], 0)
+    offs = abs_clip % page_len
+    positions = None if prefix_len is None \
+        else jnp.clip(abs_pos, 0, n_positions - 1)[None]
+    return valid, page_ids, offs, abs_pos, positions
+
+
+# -- the cache's bookkeeping ------------------------------------------------
+
+def rows_view(pool):
+    """[L, P, Hkv, page_len, Dh] as the engine holds it -> every key row
+    of every layer in one column [L*P*Hkv*page_len, Dh].  Same bytes."""
+    return pool.reshape(-1, pool.shape[-1])
+
+
+def write_rows(rows, new, index, keep):
+    """``rows[index[i]] = new[i]`` where ``keep[i]``; the others write
+    their old value back (their index names the scratch page)."""
+    old = rows[index]
+    return rows.at[index].set(
+        jnp.where(keep[:, None], new.astype(rows.dtype), old))
+
+
+def row_index(pages_flat, offs, kv_heads: int, page_len: int):
+    """Row of key ``offs[i]`` of page ``pages_flat[i]`` (layer's base
+    added) for each key head -> [n, Hkv] flattened."""
+    g = jnp.arange(kv_heads, dtype=jnp.int32)
+    return ((pages_flat[:, None] * kv_heads + g[None, :]) * page_len
+            + offs[:, None]).reshape(-1)
+
+
+class _LayerRows:
+    """Arrays ``[layers, X, heads, rows, D]`` (one, or keys and values)
+    held as flat rows while a step walks the layers.  ``units`` [n],
+    ``offs`` [n]: the unit (of X) and the row each of a call's ``n`` new
+    keys goes to; ``keep`` [n]: which are written."""
+
+    def __init__(self, arrays, units, offs, keep):
+        self.shapes = [a.shape for a in arrays]
+        _, self.per_layer, self.heads, self.length, _ = self.shapes[0]
+        self.rows = [rows_view(a) for a in arrays]
+        self.units, self.offs = units, offs
+        self.keep = keep if self.heads == 1 else jnp.repeat(keep, self.heads)
+
+    def write(self, layer: int, *new):
+        """``new``: one [n, heads, D] for each array."""
+        index = row_index(layer * self.per_layer + self.units, self.offs,
+                          self.heads, self.length)
+        self.rows = [write_rows(r, t.reshape(-1, t.shape[-1]), index,
+                                self.keep)
+                     for r, t in zip(self.rows, new)]
+
+    def flat(self):
+        """Every layer's units in one row, ``[layers * X, heads, rows,
+        D]``: what the decode kernels take, with the layer's base."""
+        return [r.reshape((-1,) + s[2:])
+                for r, s in zip(self.rows, self.shapes)]
+
+    def arrays(self):
+        """As they came."""
+        return [r.reshape(s) for r, s in zip(self.rows, self.shapes)]
+
+
+class PagePool(_LayerRows):
+    """The POOL pair: the engine's pool(s) ``[L, pages, Hkv, page_len,
+    D]`` of the layers that keep every key; ``page_ids``, ``offs``,
+    ``keep``: a prelude's.  One pool (A.X-K1's latent rows) is the pair
+    with one row set, and its kernel the family's."""
+
+    def attend(self, layer: int, q, page_table, att_len, *, impl: str,
+               sm_scale: Optional[float] = None):
+        """q [S, Hq, D] over layer ``layer``'s pages of each slot, the
+        tick's keys already written -> [S, Hq, Dv]."""
+        from ..ops.pallas.decode_attention import decode_attention_paged
+        k, v = self.flat()
+        return decode_attention_paged(
+            q, k, v, page_table + layer * self.per_layer, att_len,
+            sm_scale=sm_scale, impl=impl)
+
+
+class Rings(_LayerRows):
+    """The RING pair: the window layers' last ``W`` keys and values BY SLOT,
+    ``[Lw, slots, Hkv, W, D]`` (request state), position ``p`` at ``p % W``."""
+
+    def __init__(self, window_k, window_v, positions, active):
+        slots, W = window_k.shape[1], window_k.shape[3]
+        super().__init__([window_k, window_v],
+                         jnp.arange(slots, dtype=jnp.int32), positions % W,
+                         active)
+
+    def attend(self, layer: int, q, att_len, sink, *, impl: str,
+               sm_scale: Optional[float] = None):
+        """q [S, Hq, D] over window layer ``layer``'s ring of each slot,
+        the tick's keys already written; ``sink`` [Hq] or None -> [S, Hq,
+        Dv]."""
+        from ..ops.pallas.decode_attention import window_decode_attention
+        k, v = self.flat()
+        return window_decode_attention(
+            q, k, v, att_len, sink, base=layer * self.per_layer,
+            sm_scale=sm_scale, impl=impl)
+
+
+def ring_positions(end, W: int):
+    """For each row ``r`` of a ring, the last position before ``end``
+    (traced) that is ``r mod W`` [W]; negative where there is none yet."""
+    last = end - 1
+    return last - jnp.mod(last - jnp.arange(W, dtype=jnp.int32), W)
+
+
+def write_slot_state(state, kept, slot):
+    """What a prefill computed for its request (``kept``: name -> one
+    array a layer; none: the leaf stays) OVERWRITES ``slot`` (traced) of
+    the request state's leaves ``[layers, slots, ...]``."""
+    new_state = dict(state)
+    for name, new in kept.items():
+        if new:
+            leaf = state[name]
+            new_state[name] = jax.lax.dynamic_update_slice(
+                leaf, jnp.stack(new)[:, None].astype(leaf.dtype),
+                (0, slot) + (0,) * (leaf.ndim - 2))
+    return new_state

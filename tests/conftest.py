@@ -30,6 +30,22 @@ os.environ.setdefault("DS_CKPT_FSYNC", "0")
 # tests/test_disk_offload.py::test_fsync_on_by_default pins it.
 os.environ.setdefault("DS_DISK_FSYNC", "0")
 
+# A run compiles a program once: its workers (pytest-xdist's, which
+# inherit this process's environment) and the children tests start share
+# ONE persistent compilation cache, made fresh for the run under the run's
+# temporary directory and removed at its end (``pytest_sessionfinish``),
+# so no run ever reads what another left.  The two thresholds are JAX's
+# own: keep every program, however quick to compile or small.
+# ``tests/test_chip_compile.py`` switches the cache off for its
+# described-device compiles.
+_RUN_OWNS_CACHE = "PYTEST_XDIST_WORKER" not in os.environ
+if _RUN_OWNS_CACHE:
+    import tempfile
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = tempfile.mkdtemp(
+        prefix="ds_tpu_tests_jax_cache_")
+os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
+os.environ["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "-1"
+
 import jax  # noqa: E402
 
 assert len(jax.devices()) == 8, (
@@ -80,6 +96,31 @@ def _tier_guard_track_item(request):
     _current_item = request.node
     yield
     _current_item = None
+
+
+# What every test of a family file builds identically, the parameters of
+# its tiny config, is drawn once a module: a draw is ~2 s, and compiles
+# anew at every call (``init`` jits closures of its own).
+_drawn = {}
+
+
+def drawn_once(model_cls, cfg, seed):
+    """``model_cls(cfg).init(PRNGKey(seed))``, drawn the first time a test
+    of the running module asks (``attn_impl`` draws nothing).  The arrays
+    are shared, the containers the caller's own to change."""
+    import dataclasses
+    key = (model_cls.__name__,
+           repr(dataclasses.replace(cfg, attn_impl="dense")), seed)
+    if key not in _drawn:
+        _drawn[key] = model_cls(cfg).init(jax.random.PRNGKey(seed))
+    return jax.tree.map(lambda a: a, _drawn[key])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _drawn_for_a_module():
+    """Drops what ``drawn_once`` holds at the module's end."""
+    yield
+    _drawn.clear()
 
 
 # Mesh construction goes through __new__ (cached), not __init__.
@@ -139,6 +180,10 @@ def pytest_runtest_logreport(report):
 
 
 def pytest_sessionfinish(session, exitstatus):
+    if _RUN_OWNS_CACHE:
+        import shutil
+        shutil.rmtree(os.environ["JAX_COMPILATION_CACHE_DIR"],
+                      ignore_errors=True)
     if _duration_offenders:
         tr = session.config.pluginmanager.get_plugin("terminalreporter")
         lines = ["tier guard: unmarked tests overran the core-tier budget "

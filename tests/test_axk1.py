@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import drawn_once
 
 from deepspeed_tpu.inference import ServeEngine
 from deepspeed_tpu.inference.kv_cache import (PagedKVCacheSpec,
@@ -26,7 +27,7 @@ from deepspeed_tpu.inference.kv_cache import (PagedKVCacheSpec,
 from deepspeed_tpu.models import axk1
 from deepspeed_tpu.models.axk1 import (AxK1Config, AxK1Model, softmax_scale,
                                        yarn_inv_freq)
-from deepspeed_tpu.models.olmoe import rope
+from deepspeed_tpu.models.walked import rope
 from deepspeed_tpu.moe import dropless
 from deepspeed_tpu.ops.pallas.decode_attention import (
     latent_decode_attention, latent_pages_per_block)
@@ -59,7 +60,7 @@ F32_TOL = 1e-5
 
 
 def _params(cfg=TINY, seed=0):
-    return AxK1Model(cfg).init(jax.random.PRNGKey(seed))
+    return drawn_once(AxK1Model, cfg, seed)
 
 
 def _reference(params, tokens, cfg=TINY, **switches):
@@ -128,7 +129,7 @@ def test_rope_without_frequencies_is_what_it_was_bit_for_bit(rotary_dim):
 def _latents_of(cfg, seed=3, S=3, T=20):
     """A layer's parameters and what ``_latents`` gives for S sequences."""
     params = _params(cfg, seed)
-    ap = axk1._at(params["attn"], 1)
+    ap = axk1.at(params["attn"], 1)
     h = jnp.asarray(np.random.RandomState(seed).randn(S, T, cfg.hidden_size),
                     jnp.float32)
     pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (S, T))
@@ -280,8 +281,8 @@ def test_the_sixteen_shares_and_the_shared_expert_once_make_the_uncut_layer():
     x = jnp.asarray(np.random.RandomState(8).randn(12, 64), jnp.float32)
 
     def layer(c, p):
-        ep = axk1._at(p["moe"], 0)
-        out, st = axk1._experts(c, ep, axk1._stacked_experts(p), 0, x, None)
+        ep = axk1.at(p["moe"], 0)
+        out, st = axk1._experts(c, ep, axk1.stacked_experts(p), 0, x, None)
         return out, axk1._shared_expert(ep, x), st
 
     full, shared, stats = layer(cfg, params)

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import drawn_once
 
 from deepspeed_tpu.inference import ServeEngine
 from deepspeed_tpu.inference.kv_cache import (PagedKVCacheSpec,
@@ -44,7 +45,7 @@ F32_TOL = 5e-5
 
 
 def _params(cfg=TINY, seed=0):
-    return Cohere2MoeModel(cfg).init(jax.random.PRNGKey(seed))
+    return drawn_once(Cohere2MoeModel, cfg, seed)
 
 
 def _reference(params, tokens, cfg=TINY, **switches):
@@ -93,9 +94,9 @@ def test_layer_norm_takes_the_mean_out_and_has_no_bias():
 def test_interleaved_rotation_against_the_closed_form(theta):
     """Pair ``i`` is ``(x[2i], x[2i + 1])`` at ``pos * theta**(-2i/D)``:
     by hand with complex numbers, and against the reference's own (0/1
-    matrices); rotate-half (``models/olmoe.py::rope``) is another
+    matrices); rotate-half (``models/walked.py::rope``) is another
     function."""
-    from deepspeed_tpu.models.olmoe import rope
+    from deepspeed_tpu.models.walked import rope
     rng = np.random.RandomState(0)
     x = rng.randn(1, 3, 7, 16).astype(np.float32)       # [B, H, T, D]
     pos = np.asarray([[0, 1, 2, 5, 9, 100, 4095]], np.int32)
@@ -179,7 +180,7 @@ def test_the_shares_of_the_experts_add_up_to_the_uncut_layer():
         B, T = tokens.shape
         positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
         x = params["wte"][tokens]
-        lp = M._at(params["window"], 0)
+        lp = M.at(params["window"], 0)
         h = M.layer_norm(x, lp["ln"], cfg.layer_norm_eps)
         q, k, v = M._qkv(cfg, "window", lp, h, positions)
         attn = M._self_attention(cfg, "window", q, k, v)

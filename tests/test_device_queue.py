@@ -507,7 +507,11 @@ def test_stall_watch_sorts_a_silence_by_cause(tmp_path, cause, now):
 
 def test_stall_watch_sees_the_interpreter_lock_held(tmp_path):
     """A thread that keeps the interpreter lock for 0.3 s silences the
-    watcher: one ``process_stall``, CPU burnt all through it."""
+    watcher: one ``process_stall``, CPU burnt through it.  How much CPU is
+    the host's to give: alone the spin burns all 0.3 s, under six workers
+    of the suite it read 0.176 s.  So the stall is held to its wall time,
+    its cause and its phase, and its CPU to the share that MAKES the cause
+    ``busy`` (half the wall time; less is ``descheduled``)."""
     hub = TelemetryHub(str(tmp_path), compile_events=False, memory=False)
     hub.watch_stalls(lambda: "decode_prep")
     hub.watch_stalls()                      # once: no second thread
@@ -527,8 +531,10 @@ def test_stall_watch_sees_the_interpreter_lock_held(tmp_path):
         stalls = [json.loads(line) for line in f
                   if '"process_stall"' in line]
     assert len(stalls) == 1
-    assert stalls[0]["wall_s"] > 0.25 and stalls[0]["cause"] == "busy"
-    assert stalls[0]["cpu_s"] > 0.2 and stalls[0]["phase"] == "decode_prep"
+    stall = stalls[0]
+    assert stall["wall_s"] > 0.25 and stall["cause"] == "busy"
+    assert stall["cpu_s"] >= stall["wall_s"] / 2
+    assert stall["phase"] == "decode_prep"
     assert hub.registry.counter("process_stalls_total").value(
         cause="busy") == 1
 

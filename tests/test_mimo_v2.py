@@ -17,13 +17,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import drawn_once
 
 from deepspeed_tpu.inference import ServeEngine
 from deepspeed_tpu.inference.kv_cache import (PagedKVCacheSpec,
                                               init_paged_cache)
-from deepspeed_tpu.models.mimo_v2 import (MimoV2Config, MimoV2Model,
-                                          grouped_causal_attention)
-from deepspeed_tpu.models.olmoe import rope
+from deepspeed_tpu.models.mimo_v2 import MimoV2Config, MimoV2Model
+from deepspeed_tpu.models.walked import grouped_causal_attention, rope
 from deepspeed_tpu.ops.pallas.decode_attention import (
     decode_attention_paged, decode_attention_slots, paged_decode_arm,
     paged_pages_per_block, slot_decode_reference, window_decode_attention)
@@ -51,7 +51,7 @@ F32_TOL = 5e-6
 
 
 def _params(cfg=TINY, seed=0):
-    return MimoV2Model(cfg).init(jax.random.PRNGKey(seed))
+    return drawn_once(MimoV2Model, cfg, seed)
 
 
 def _keys(cfg=TINY):
@@ -155,7 +155,8 @@ def test_partial_rotation_turns_the_first_dims_and_leaves_the_rest(theta):
 
 def test_the_two_kinds_of_layer_rotate_at_their_own_theta():
     cfg = TINY
-    from deepspeed_tpu.models.mimo_v2 import _at, _qkv as model_qkv
+    from deepspeed_tpu.models.mimo_v2 import _qkv as model_qkv
+    from deepspeed_tpu.models.walked import at as _at
     params = _params()
     h = jnp.asarray(np.random.RandomState(3).randn(1, 6, 64), jnp.float32)
     pos = jnp.arange(6, dtype=jnp.int32)[None] + 50
@@ -480,7 +481,9 @@ def test_the_sixteenth_shares_counted_once_make_the_uncut_layer():
     whole = MimoV2Model(cfg)
     params = whole.init(jax.random.PRNGKey(2))
     x = jnp.asarray(np.random.RandomState(8).randn(12, 64), jnp.float32)
-    from deepspeed_tpu.models.mimo_v2 import _at, _experts, _stacked_experts
+    from deepspeed_tpu.models.mimo_v2 import _experts
+    from deepspeed_tpu.models.walked import at as _at
+    from deepspeed_tpu.models.walked import stacked_experts as _stacked_experts
 
     def layer(c, p):
         return _experts(c, _at(p["moe"], 0), _stacked_experts(p), 0, x, None)

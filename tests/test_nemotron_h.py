@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import drawn_once
 
 from deepspeed_tpu.inference import ServeEngine
 from deepspeed_tpu.models.nemotron_h import NemotronHConfig, NemotronHModel
@@ -51,7 +52,7 @@ PAGED_TOL = 5e-6
 
 
 def _params(cfg=TINY, seed=0):
-    return NemotronHModel(cfg).init(jax.random.PRNGKey(seed))
+    return drawn_once(NemotronHModel, cfg, seed)
 
 
 def _keys(cfg=TINY):

@@ -12,12 +12,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import drawn_once
 
 from deepspeed_tpu.inference import ServeEngine
 from deepspeed_tpu.inference.quantize import (NotGPT2ParamsError,
                                               quantize_gpt2_params)
-from deepspeed_tpu.models.olmoe import (OlmoeConfig, OlmoeModel, qkv_heads,
-                                        rms_norm, rope)
+from deepspeed_tpu.models.olmoe import OlmoeConfig, OlmoeModel, qkv_heads
+from deepspeed_tpu.models.walked import rms_norm, rope
 from deepspeed_tpu.moe.dropless import dropless_moe, route_topk, row_tile
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -46,9 +47,9 @@ PAGED_TOL = 3e-4
 def _params(cfg=TINY, seed=0, scale=8.0):
     """Seeded weights; larger than init so that routing is decisive and
     logits are of size 1."""
-    params = OlmoeModel(cfg).init(jax.random.PRNGKey(seed))
     return jax.tree.map(
-        lambda a: a * scale if a.ndim > 1 and a.shape[-1] != 1 else a, params)
+        lambda a: a * scale if a.ndim > 1 and a.shape[-1] != 1 else a,
+        drawn_once(OlmoeModel, cfg, seed))
 
 
 def _reference(params, tokens, keys=SOURCE_KEYS):

@@ -276,8 +276,9 @@ class ServeEngine:
     read of ``model``; ``GPT2Model`` and ``OlmoeModel`` are its two
     implementations; ``NemotronHModel`` is a third, with request state,
     ``MimoV2Model`` a fourth, whose state is a second kind of key
-    cache, and ``AxK1Model`` a fifth, whose pool is one array of latent
-    rows):
+    cache, ``AxK1Model`` a fifth, whose pool is one array of latent
+    rows, and ``GlmDsaModel`` a sixth, with an indexer's keys paged
+    beside them):
 
     * ``model.config`` with ``n_layer``, ``n_head``, ``d_head`` (the KV
       pool's shape; ``n_layer`` counts the layers that keep every key,
@@ -287,7 +288,11 @@ class ServeEngine:
       beside them; a config that also declares ``values_in_keys`` keeps
       ONE pool whose rows' first ``d_head_v`` lanes are the values:
       no ``"v"`` array exists, and its paged steps are handed None for
-      ``v_pool`` and hand None back), ``n_positions`` (the longest
+      ``v_pool`` and hand None back; a config with ``n_index_layer``
+      and ``d_index`` also keeps an indexer key a token on that many
+      layers in a SECOND paged array under the same page ids,
+      ``cache["index_k"]``: its paged steps take it as ``index_pool=``
+      and return it after the pools), ``n_positions`` (the longest
       sequence) and
       ``attn_impl`` (``'flash'`` | ``'dense'``: which decode arm
       ``serving.decode_impl: auto`` takes); ``d_model`` with LoRA;
@@ -569,16 +574,23 @@ class ServeEngine:
             # one pool: the values are the first d_head_v lanes of the
             # rows (latent attention), and the cache has no "v"
             one_pool = bool(getattr(mcfg, "values_in_keys", False))
+            # a second paged array under the same page ids: an indexer
+            # key a token on the layers that score (learned sparse
+            # attention); the model's paged steps take and return it as
+            # ``index_pool`` after the pools
+            index_layers = int(getattr(mcfg, "n_index_layer", 0))
             self.cache_spec = PagedKVCacheSpec(
                 layers=mcfg.n_layer, slots=self.slots,
                 heads=kv_heads, pages=pages, page_len=self.page_len,
                 head_dim=mcfg.d_head, max_pages=self.max_pages,
                 dtype=(jnp.int8 if self.quant_kv else kv_dtype),
                 quant=self.quant_kv, v_head_dim=v_dim,
-                values_in_keys=one_pool)
+                values_in_keys=one_pool, index_layers=index_layers,
+                index_dim=mcfg.d_index if index_layers else 0)
             validate_paged_cache_mesh(mesh, self.cache_spec)
             self._cache_shardings = paged_cache_shardings(
-                mesh, quant=self.quant_kv, values_in_keys=one_pool)
+                mesh, quant=self.quant_kv, values_in_keys=one_pool,
+                indexed=bool(index_layers))
             self.cache = shard_cache(init_paged_cache(self.cache_spec),
                                      mesh, self._cache_shardings)
             if self._state_spec:
@@ -773,13 +785,25 @@ class ServeEngine:
                 return jnp.stack([counters[k].astype(jnp.float32)
                                   for k in aux_keys])
 
-            def pools(k, v, lengths):
-                """The cache a paged step hands back; a one-pool model
+            indexed = bool(self.cache_spec.index_layers)
+
+            def index_kw(cache):
+                """The indexer keys' array for a model that keeps one
+                (``PagedKVCacheSpec.index_layers``); it comes back after
+                the pools."""
+                return {"index_pool": cache["index_k"]} if indexed else {}
+
+            def pools(k, v, lengths, out):
+                """The cache a paged step hands back (``out``: all it
+                returned); a one-pool model
                 (``PagedKVCacheSpec.values_in_keys``) is handed None for
                 the values and hands None back."""
-                if one_pool:
-                    return {"k": k, "lengths": lengths}
-                return {"k": k, "v": v, "lengths": lengths}
+                newc = {"k": k, "lengths": lengths}
+                if not one_pool:
+                    newc["v"] = v
+                if indexed:
+                    newc["index_k"] = out[3]
+                return newc
 
             def split_lora(extra):
                 """(lora kwargs, rng tail) of a program's *extra."""
@@ -799,7 +823,7 @@ class ServeEngine:
                 out = self.model.prefill_paged(
                     params, tokens, delta_len, prefix_len, page_row,
                     cache["k"], cache.get("v"), **lkw, **aux_kw, **skw,
-                    **cache_scales(cache))
+                    **cache_scales(cache), **index_kw(cache))
                 logits, kp, vp = out[0], out[1], out[2]
                 total = jnp.reshape(prefix_len + delta_len,
                                     (1,)).astype(jnp.int32)
@@ -809,7 +833,7 @@ class ServeEngine:
                     logits, delta_len - 1, axis=1, keepdims=False)[0]
                 first_tok = select_next_token(last, temp,
                                               rng[0] if rng else None)
-                newc = pools(kp, vp, lengths)
+                newc = pools(kp, vp, lengths, out)
                 if quant_kv:
                     newc["k_scale"], newc["v_scale"] = out[3], out[4]
                 if stateful:
@@ -825,6 +849,7 @@ class ServeEngine:
                     params, tokens, cache["k"], cache.get("v"), page_table,
                     cache["lengths"], active, impl=self.decode_impl,
                     **lkw, **aux_kw, **cache_scales(cache),
+                    **index_kw(cache),
                     **({"state": cache["state"]} if stateful else {}))
                 stats = ()
                 if aux_kw:
@@ -832,7 +857,7 @@ class ServeEngine:
                 logits, k, v, new_len = out[0], out[1], out[2], out[-1]
                 next_tok = select_next_token(logits, temp,
                                              rng[0] if rng else None)
-                newc = pools(k, v, new_len)
+                newc = pools(k, v, new_len, out)
                 if quant_kv:
                     newc["k_scale"], newc["v_scale"] = out[3], out[4]
                 if stateful:
@@ -1049,7 +1074,11 @@ class ServeEngine:
         if self.state_bytes:
             self.state_bytes["kv"] = self.kv_bytes
         elif self.paged and self.cache_spec.values_in_keys:
-            self.state_bytes["latent"] = self.kv_bytes
+            index_bytes = (self.cache_spec.pages
+                           * self.cache_spec.index_page_bytes)
+            self.state_bytes["latent"] = self.kv_bytes - index_bytes
+            if index_bytes:
+                self.state_bytes["index_k"] = index_bytes
         if self.spec_k:
             self.param_bytes += param_nbytes(self.draft_params)
             self.kv_bytes += self.draft_cache_spec.bytes
@@ -1119,7 +1148,8 @@ class ServeEngine:
                     "serve_cache_layers",
                     "layers by the kind of cache they keep: full (every "
                     "key, in the page pool), window (the last keys, by "
-                    "slot) or latent (one row a token in the page pool)")
+                    "slot), latent (one row a token in the page pool) or "
+                    "index (an indexer key a token beside the rows)")
                 for kind, n in layers().items():
                     layer_gauge.set(n, kind=kind)
             if self.paged:
@@ -1151,7 +1181,8 @@ class ServeEngine:
                     "device bytes a stateful model's requests hold by "
                     "kind: each serving_state leaf (ssm, conv; window_k, "
                     "window_v) and the page pool (kv; latent where it is "
-                    "one pool of latent rows and nothing else is kept)")
+                    "one pool of latent rows, index_k the indexer keys "
+                    "paged beside them)")
                 for kind, nbytes in self.state_bytes.items():
                     state_gauge.set(nbytes, kind=kind)
             if self._aux:

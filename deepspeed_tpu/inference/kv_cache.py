@@ -136,6 +136,12 @@ class PagedKVCacheSpec:
     ``[c_kv ; k_rope]`` read by every head), so the pool is the ``"k"``
     array alone and no ``"v"`` array exists; every count of bytes below
     follows.
+    ``index_layers`` > 0 (a model config with ``n_index_layer`` and
+    ``d_index``: learned sparse attention, ``models/glm_dsa.py``): a
+    SECOND paged array ``"index_k"`` ``[index_layers, pages, 1, page_len,
+    index_dim]`` of one indexer key a token, on the layers that score,
+    under the SAME page ids as the rows: a page is allocated, freed,
+    copied, exported and imported as one, and the page table is one.
     What a request keeps beside its pages
     (a ``serving_state`` model's recurrent state, by slot) is not in
     this spec: ``ServeEngine`` allocates it under ``cache["state"]``.
@@ -162,8 +168,17 @@ class PagedKVCacheSpec:
     v_head_dim: Optional[int] = None
     #: one pool: the values are the rows' first ``v_head_dim`` lanes
     values_in_keys: bool = False
+    #: layers that keep an indexer key a token beside the rows, and its width
+    index_layers: int = 0
+    index_dim: int = 0
 
     def __post_init__(self):
+        if bool(self.index_layers) != bool(self.index_dim) or (
+                self.index_layers and self.quant):
+            raise ValueError(
+                f"index_layers {self.index_layers} and index_dim "
+                f"{self.index_dim} go together, and such a cache has no "
+                "int8 arm")
         if self.values_in_keys and (self.quant or not self.v_head_dim
                                     or self.v_head_dim > self.head_dim):
             raise ValueError(
@@ -180,6 +195,8 @@ class PagedKVCacheSpec:
         """The pool-shaped leaves of the cache, in the fixed order every
         page copy, export and import walks them."""
         names = ("k",) if self.values_in_keys else ("k", "v")
+        if self.index_layers:
+            names += ("index_k",)
         return names + (("k_scale", "v_scale") if self.quant else ())
 
     @property
@@ -200,7 +217,13 @@ class PagedKVCacheSpec:
         n = self.layers * self.heads * self.page_len * self.row_width * per
         if self.quant:
             n += 2 * self.layers * self.heads * self.page_len * 4
-        return n
+        return n + self.index_page_bytes
+
+    @property
+    def index_page_bytes(self) -> int:
+        """Of ``page_bytes``, the indexer keys' part."""
+        return (self.index_layers * self.page_len * self.index_dim
+                * jnp.dtype(self.dtype).itemsize)
 
 
 def init_paged_cache(spec: PagedKVCacheSpec) -> Dict[str, jnp.ndarray]:
@@ -216,6 +239,10 @@ def init_paged_cache(spec: PagedKVCacheSpec) -> Dict[str, jnp.ndarray]:
     }
     if not spec.values_in_keys:
         cache["v"] = jnp.zeros(shape + (spec.value_dim,), spec.dtype)
+    if spec.index_layers:
+        cache["index_k"] = jnp.zeros(
+            (spec.index_layers, spec.pages, 1, spec.page_len,
+             spec.index_dim), spec.dtype)
     if spec.quant:
         sshape = (spec.layers, spec.pages, spec.heads, spec.page_len)
         cache["k_scale"] = jnp.zeros(sshape, jnp.float32)
@@ -224,16 +251,20 @@ def init_paged_cache(spec: PagedKVCacheSpec) -> Dict[str, jnp.ndarray]:
 
 
 def paged_partition_specs(quant: bool = False,
-                          values_in_keys: bool = False) -> Dict[str, P]:
+                          values_in_keys: bool = False,
+                          indexed: bool = False) -> Dict[str, P]:
     """Pool pages on ``data``, heads on ``model`` — the page pool is
     the DP-sharded storage dimension the way slots were.  The quant
     scale sidecars shard exactly like their pools (minus the row dim's
     trailing head_dim).  A one-pool spec (``values_in_keys``) has no
-    ``"v"``."""
+    ``"v"``; an ``indexed`` one (``index_layers``) has ``"index_k"``,
+    whose one head is never split."""
     kv = P(None, DATA_AXIS, MODEL_AXIS, None, None)
     specs = {"k": kv, "v": kv, "lengths": P()}
     if values_in_keys:
         del specs["v"]
+    if indexed:
+        specs["index_k"] = P(None, DATA_AXIS, None, None, None)
     if quant:
         sc = P(None, DATA_AXIS, MODEL_AXIS, None)
         specs["k_scale"] = sc
@@ -242,10 +273,11 @@ def paged_partition_specs(quant: bool = False,
 
 
 def paged_cache_shardings(mesh: Mesh, quant: bool = False,
-                          values_in_keys: bool = False
+                          values_in_keys: bool = False,
+                          indexed: bool = False
                           ) -> Dict[str, NamedSharding]:
     return {name: NamedSharding(mesh, spec) for name, spec in
-            paged_partition_specs(quant, values_in_keys).items()}
+            paged_partition_specs(quant, values_in_keys, indexed).items()}
 
 
 def validate_paged_cache_mesh(mesh: Mesh,

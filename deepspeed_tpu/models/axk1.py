@@ -84,18 +84,11 @@ import numpy as np
 
 from .walked import (F32, PagePool, ServedConfig, WalkedModel, at,
                      causal_self_attention, decode_index, dense_ffn,
-                     draw_layers, held_expert_counters, lm_head, merge_heads,
-                     prefill_index, project_heads, rms_norm, rope,
-                     routed_experts, stacked_experts, swiglu)
-
-_LANES = 128
-#: keys a step of :func:`_paged_context_attention` expands (whole pages)
-_CONTEXT_BLOCK = 256
-
-
-def _whole_tiles(width: int) -> int:
-    """``width``, in whole lane tiles where it is wider than one."""
-    return width if width <= _LANES else -(-width // _LANES) * _LANES
+                     draw_layers, expand_latents, held_expert_counters,
+                     latent_context_attention, latent_projections,
+                     latent_rows, lm_head, merge_heads, prefill_index,
+                     rms_norm, routed_experts, shared_expert,
+                     stacked_experts, whole_tiles)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -176,7 +169,7 @@ class AxK1Config(ServedConfig):
     @property
     def latent_width(self) -> int:
         """A cached row at rest (module docstring)."""
-        return _whole_tiles(self.kv_lora_rank + self.qk_rope_head_dim)
+        return whole_tiles(self.kv_lora_rank + self.qk_rope_head_dim)
 
     def count(self, kind: str) -> int:
         """Layers of an FFN kind ('dense', 'moe')."""
@@ -419,46 +412,25 @@ def _latents(cfg: AxK1Config, ap, h, positions):
     """h [B, T, d] (normed), positions [B, T] -> q_nope [B, H, T, nope],
     q_rope [B, H, T, rot] (rotated), c_kv [B, T, kv_lora_rank] (normed),
     k_rope [B, T, rot] (rotated): what the cache keeps is the last two."""
-    eps, inv_freq = cfg.rms_norm_eps, yarn_inv_freq(cfg)
-    with jax.named_scope("latent_q"):
-        c_q = rms_norm(h @ ap["q_a_w"].astype(h.dtype), ap["q_a_norm"], eps)
-        q = project_heads(c_q, ap["q_b_w"], cfg.n_head)
-        q_nope = q[..., :cfg.qk_nope_head_dim]
-        q_rope = rope(q[..., cfg.qk_nope_head_dim:], positions,
-                      cfg.rope_theta, inv_freq=inv_freq)
-    with jax.named_scope("latent_kv"):
-        kv = h @ ap["kv_a_w"].astype(h.dtype)
-        c_kv = rms_norm(kv[..., :cfg.kv_lora_rank], ap["kv_a_norm"], eps)
-        k_rope = rope(kv[:, None, :, cfg.kv_lora_rank:], positions,
-                      cfg.rope_theta, inv_freq=inv_freq)[:, 0]
-    return q_nope, q_rope, c_kv, k_rope
+    return latent_projections(
+        ap, h, positions, heads=cfg.n_head, nope=cfg.qk_nope_head_dim,
+        kv_rank=cfg.kv_lora_rank, eps=cfg.rms_norm_eps,
+        theta=cfg.rope_theta, inv_freq=yarn_inv_freq(cfg))[1:]
 
 
 def _cached_rows(cfg: AxK1Config, c_kv, k_rope):
-    """[..., kv_lora_rank], [..., rot] -> the rows at rest [...,
-    latent_width]: ``[c_kv ; k_rope ; 0]``."""
-    pad = cfg.latent_width - cfg.kv_lora_rank - cfg.qk_rope_head_dim
-    rows = jnp.concatenate([c_kv, k_rope], axis=-1)
-    return jnp.pad(rows, ((0, 0),) * (rows.ndim - 1) + ((0, pad),))
-
-
-@jax.named_scope("expand")
-def _expand(ap, c_kv, dtype):
-    """c_kv [..., T, kv_lora_rank] -> every head's k_nope [..., H, T,
-    nope] and v [..., H, T, v_head_dim]."""
-    c_kv = c_kv.astype(dtype)
-    return (jnp.einsum("...tc,hnc->...htn", c_kv, ap["k_b_w"].astype(dtype)),
-            jnp.einsum("...tc,hcv->...htv", c_kv, ap["v_b_w"].astype(dtype)))
+    """The rows at rest ``[c_kv ; k_rope ; 0]``, ``latent_width`` wide."""
+    return latent_rows(c_kv, k_rope, cfg.latent_width)
 
 
 def _self_attention(cfg: AxK1Config, ap, q_nope, q_rope, c_kv, k_rope):
     """The expanded form over whole sequences from position 0: q_* [B, H,
     T, .], c_kv [B, T, C], k_rope [B, T, rot] -> [B, H, T, v_head_dim]."""
-    k_nope, v = _expand(ap, c_kv, q_nope.dtype)
+    k_nope, v = expand_latents(ap, c_kv, q_nope.dtype)
     k_rope = jnp.broadcast_to(k_rope[:, None], q_rope.shape)
     # the two widths at rest in whole lane tiles (192 -> 256), zeros in
     # the upper lanes: scores do not change, every matmul is aligned
-    pad = ((0, 0),) * 3 + ((0, _whole_tiles(cfg.qk_head_dim)
+    pad = ((0, 0),) * 3 + ((0, whole_tiles(cfg.qk_head_dim)
                             - cfg.qk_head_dim),)
     q = jnp.pad(jnp.concatenate([q_nope, q_rope], axis=-1), pad)
     k = jnp.pad(jnp.concatenate([k_nope, k_rope], axis=-1), pad)
@@ -469,54 +441,11 @@ def _self_attention(cfg: AxK1Config, ap, q_nope, q_rope, c_kv, k_rope):
 def _paged_context_attention(cfg: AxK1Config, ap, q_nope, q_rope, pool_pages,
                              page_ids, abs_pos, context_len):
     """The expanded form for a prefill whose context begins in the pages
-    (a prefix hit, a chunk): queries q_* [H, Tq, .] at absolute positions
-    ``abs_pos`` [Tq] against the request's latent rows READ BACK from the
-    pool (``pool_pages`` [X, page_len, latent_width]; ``page_ids``
-    [max_pages] the request's pages of this layer, the delta's own rows
-    already written) up to ``context_len`` (traced).  A block of whole
-    pages at a time: its rows expanded to every head's keys and values,
-    float32 scores, an online softmax; nothing the size of the context
-    is ever held.  Returns [H, Tq, v_head_dim]."""
-    H, Tq, _ = q_nope.shape
-    page_len = pool_pages.shape[1]
-    ppb = max(1, _CONTEXT_BLOCK // page_len)
-    bk = ppb * page_len
-    ids = jnp.pad(page_ids, (0, (-page_ids.shape[0]) % ppb))
-    C, rot = cfg.kv_lora_rank, cfg.qk_rope_head_dim
-    scale, dt = softmax_scale(cfg), q_nope.dtype
-    floor = jnp.finfo(F32).min
-
-    def block(j, carry):
-        m, l, acc = carry
-        rows = pool_pages[jax.lax.dynamic_slice_in_dim(
-            ids, j * ppb, ppb)].reshape(bk, -1)
-        k_nope, v = _expand(ap, rows[:, :C], dt)
-        s = (jnp.einsum("htn,hkn->htk", q_nope, k_nope,
-                        preferred_element_type=F32)
-             + jnp.einsum("htr,kr->htk", q_rope,
-                          rows[:, C:C + rot].astype(dt),
-                          preferred_element_type=F32)) * scale
-        at = j * bk + jnp.arange(bk, dtype=jnp.int32)
-        ok = (at[None, :] <= abs_pos[:, None])[None]
-        s = jnp.where(ok, s, floor)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.where(ok, jnp.exp(s - m_new[..., None]), 0.0)
-        alpha = jnp.exp(m - m_new)
-        acc = acc * alpha[..., None] + jnp.einsum(
-            "htk,hkv->htv", p.astype(dt), v, preferred_element_type=F32)
-        return m_new, alpha * l + jnp.sum(p, axis=-1), acc
-
-    _, l, acc = jax.lax.fori_loop(
-        0, (context_len + bk - 1) // bk, block,
-        (jnp.full((H, Tq), floor, F32), jnp.zeros((H, Tq), F32),
-         jnp.zeros((H, Tq, cfg.v_head_dim), F32)))
-    return (acc / jnp.where(l == 0.0, 1.0, l)[..., None]).astype(dt)
-
-
-def _shared_expert(ep, x):
-    with jax.named_scope("shared_expert"):
-        return swiglu(x, ep["shared_gate_w"], ep["shared_up_w"],
-                      ep["shared_down_w"])
+    (``walked.latent_context_attention``, every key under the causal
+    rule)."""
+    return latent_context_attention(
+        ap, q_nope, q_rope, pool_pages, page_ids, abs_pos, context_len,
+        kv_rank=cfg.kv_lora_rank, sm_scale=softmax_scale(cfg))
 
 
 def _experts(cfg: AxK1Config, ep, stacked, index: int, x, valid):
@@ -530,7 +459,7 @@ def _experts(cfg: AxK1Config, ep, stacked, index: int, x, valid):
             valid=valid, act="swiglu", scale=cfg.routed_scaling_factor,
             renormalize=cfg.norm_topk_prob)
     if cfg.n_shared_experts:
-        routed = routed + _shared_expert(ep, x)
+        routed = routed + shared_expert(ep, x)
     return routed, st
 
 

@@ -1,7 +1,8 @@
 """What the served families whose layers are WALKED share: ``olmoe.py``
 (the one that scans), ``nemotron_h.py``, ``mimo_v2.py``, ``axk1.py``,
-``cohere2_moe.py``.  A family's file holds what is its own: its config under
-the source's keys, ``init`` and the parameter tree, its projections,
+``cohere2_moe.py``, ``glm_dsa.py``.  A family's file holds what is its
+own: its config under the source's keys, ``init`` and the parameter tree,
+its projections,
 latents and mixers, its list of layer kinds, and two paged steps that read
 as that list walked over the pieces here.  No family imports another; the
 next one imports this module, ``ops`` and ``moe``.
@@ -12,7 +13,9 @@ keep their sources' key names: ``rms_norm_eps``, ``layernorm_epsilon``,
 families call; the index preludes of the two paged steps
 (:func:`decode_index`, :func:`prefill_index`); the cache's bookkeeping as
 two pairs of write and attend (:class:`PagePool`, :class:`Rings`); the
-routed-expert call (:func:`routed_experts`).  ONE rule for what is not
+routed-expert call (:func:`routed_experts`); latent attention's
+projections, rows at rest and expanded form over a paged context
+(``axk1.py``, ``glm_dsa.py``).  ONE rule for what is not
 live: an inactive slot and a padded prompt row name page 0, the engine's
 scratch page, are kept out of every write and attend over length 0.
 :class:`ServedConfig` and :class:`WalkedModel` are what ``ServeEngine``
@@ -200,6 +203,119 @@ def lm_head(x, norm_w, head_w, eps: float):
     """The final RMSNorm, then the untied head [d, V]."""
     x = rms_norm(x, norm_w, eps)
     return x @ head_w.astype(x.dtype)
+
+
+# -- latent attention (MLA): what its families share --------------------------
+
+_LANES = 128
+#: keys a step of :func:`latent_context_attention` expands (whole pages)
+_CONTEXT_BLOCK = 256
+
+
+def whole_tiles(width: int) -> int:
+    """``width``, in whole lane tiles where it is wider than one."""
+    return width if width <= _LANES else -(-width // _LANES) * _LANES
+
+
+def latent_projections(ap, h, positions, *, heads: int, nope: int,
+                       kv_rank: int, eps: float, theta: float,
+                       inv_freq=None):
+    """h [B, T, d] (normed), positions [B, T] -> c_q [B, T, q_lora_rank]
+    (normed), q_nope [B, H, T, nope], q_rope [B, H, T, rot] (rotated),
+    c_kv [B, T, kv_rank] (normed), k_rope [B, T, rot] (rotated): what the
+    cache keeps is the last two."""
+    with jax.named_scope("latent_q"):
+        c_q = rms_norm(h @ ap["q_a_w"].astype(h.dtype), ap["q_a_norm"], eps)
+        q = project_heads(c_q, ap["q_b_w"], heads)
+        q_nope = q[..., :nope]
+        q_rope = rope(q[..., nope:], positions, theta, inv_freq=inv_freq)
+    with jax.named_scope("latent_kv"):
+        kv = h @ ap["kv_a_w"].astype(h.dtype)
+        c_kv = rms_norm(kv[..., :kv_rank], ap["kv_a_norm"], eps)
+        k_rope = rope(kv[:, None, :, kv_rank:], positions, theta,
+                      inv_freq=inv_freq)[:, 0]
+    return c_q, q_nope, q_rope, c_kv, k_rope
+
+
+def latent_rows(c_kv, k_rope, width: int):
+    """[..., kv_rank], [..., rot] -> the rows at rest [..., width]:
+    ``[c_kv ; k_rope ; 0]``."""
+    rows = jnp.concatenate([c_kv, k_rope], axis=-1)
+    pad = width - rows.shape[-1]
+    return jnp.pad(rows, ((0, 0),) * (rows.ndim - 1) + ((0, pad),))
+
+
+@jax.named_scope("expand")
+def expand_latents(ap, c_kv, dtype):
+    """c_kv [..., T, kv_rank] -> every head's k_nope [..., H, T, nope] and
+    v [..., H, T, v_head_dim]."""
+    c_kv = c_kv.astype(dtype)
+    return (jnp.einsum("...tc,hnc->...htn", c_kv, ap["k_b_w"].astype(dtype)),
+            jnp.einsum("...tc,hcv->...htv", c_kv, ap["v_b_w"].astype(dtype)))
+
+
+def latent_context_attention(ap, q_nope, q_rope, pool_pages, page_ids,
+                             abs_pos, context_len, *, kv_rank: int,
+                             sm_scale: float, allowed=None):
+    """The expanded form for a prefill whose context is in the pages (a
+    prefix hit, a chunk; the delta's own rows already written): queries
+    q_* [H, Tq, .] at absolute positions ``abs_pos`` [Tq] against the
+    request's latent rows READ BACK from the pool (``pool_pages`` [X,
+    page_len, width]; ``page_ids`` [max_pages] the request's pages of this
+    layer) up to ``context_len`` (traced).  ``allowed`` [Tq, max_pages *
+    page_len] bool: the keys a query may see beside the causal rule
+    (learned sparse attention); None: all.  A block of whole pages at a
+    time: its rows expanded to every head's keys and values, float32
+    scores, an online softmax; nothing the size of the context is ever
+    held.  Returns [H, Tq, v_head_dim]."""
+    H, Tq, _ = q_nope.shape
+    rot, dv = q_rope.shape[-1], ap["v_b_w"].shape[-1]
+    page_len = pool_pages.shape[1]
+    ppb = max(1, _CONTEXT_BLOCK // page_len)
+    bk = ppb * page_len
+    ids = jnp.pad(page_ids, (0, (-page_ids.shape[0]) % ppb))
+    if allowed is not None:
+        allowed = jnp.pad(allowed, ((0, 0),
+                                    (0, ids.shape[0] * page_len
+                                     - allowed.shape[1])))
+    C, dt = kv_rank, q_nope.dtype
+    floor = jnp.finfo(F32).min
+
+    def block(j, carry):
+        m, l, acc = carry
+        rows = pool_pages[jax.lax.dynamic_slice_in_dim(
+            ids, j * ppb, ppb)].reshape(bk, -1)
+        k_nope, v = expand_latents(ap, rows[:, :C], dt)
+        s = (jnp.einsum("htn,hkn->htk", q_nope, k_nope,
+                        preferred_element_type=F32)
+             + jnp.einsum("htr,kr->htk", q_rope,
+                          rows[:, C:C + rot].astype(dt),
+                          preferred_element_type=F32)) * sm_scale
+        at = j * bk + jnp.arange(bk, dtype=jnp.int32)
+        ok = at[None, :] <= abs_pos[:, None]
+        if allowed is not None:
+            ok &= jax.lax.dynamic_slice_in_dim(allowed, j * bk, bk, axis=1)
+        ok = ok[None]
+        s = jnp.where(ok, s, floor)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.where(ok, jnp.exp(s - m_new[..., None]), 0.0)
+        alpha = jnp.exp(m - m_new)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "htk,hkv->htv", p.astype(dt), v, preferred_element_type=F32)
+        return m_new, alpha * l + jnp.sum(p, axis=-1), acc
+
+    _, l, acc = jax.lax.fori_loop(
+        0, (context_len + bk - 1) // bk, block,
+        (jnp.full((H, Tq), floor, F32), jnp.zeros((H, Tq), F32),
+         jnp.zeros((H, Tq, dv), F32)))
+    return (acc / jnp.where(l == 0.0, 1.0, l)[..., None]).astype(dt)
+
+
+@jax.named_scope("shared_expert")
+def shared_expert(ep, x):
+    """The SwiGLU expert every token takes (``shared_*_w``)."""
+    return swiglu(x, ep["shared_gate_w"], ep["shared_up_w"],
+                  ep["shared_down_w"])
 
 
 def at(kind, i: int):

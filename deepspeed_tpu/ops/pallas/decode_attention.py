@@ -1313,9 +1313,12 @@ def latent_decode_attention(q: jnp.ndarray, pool: jnp.ndarray,
                             page_table: jnp.ndarray, lengths: jnp.ndarray,
                             value_dim: int, *, sm_scale: float,
                             impl: str = "pallas",
-                            interpret: Optional[bool] = None) -> jnp.ndarray:
+                            interpret: Optional[bool] = None,
+                            name: str = LATENT_DECODE_ATTN_KERNEL
+                            ) -> jnp.ndarray:
     """Single-query latent attention (MLA, absorbed form) over ONE paged
-    pool, the kernel ``ds_latent_decode_attn``.
+    pool, the kernel ``ds_latent_decode_attn`` (or ``name``, for a caller
+    whose pool is not the cache itself and whose time is read apart).
 
     q: [S, H, W]: a head's ``[q_lat ; q_rope]``, zeros in the lanes the
         rows pad.
@@ -1371,8 +1374,195 @@ def latent_decode_attention(q: jnp.ndarray, pool: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-        name=LATENT_DECODE_ATTN_KERNEL,
+        name=name,
     )(pt_flat, lengths.astype(jnp.int32), q, pool)
+
+
+# ---------------------------------------------------------------------------
+# learned sparse attention (DeepSeek sparse attention over latent rows): an
+# indexer scores a slot's whole context, the largest ``k`` scores name the
+# rows the real attention reads
+# ---------------------------------------------------------------------------
+
+INDEX_SCORE_KERNEL = "ds_index_score"
+SPARSE_LATENT_DECODE_ATTN_KERNEL = "ds_sparse_latent_decode_attn"
+#: rows of a "page" of the gathered picks, as the latent kernel copies them
+SPARSE_BLOCK_ROWS = 512
+
+
+def index_score_reference(q, w, pool, page_table, lengths):
+    """Dense jnp reference of :func:`index_score`."""
+    S = page_table.shape[0]
+    keys = pool[page_table].reshape(S, -1, pool.shape[-1])   # [S, cap, D]
+    s = jnp.einsum("sjd,std->sjt", q, keys.astype(q.dtype),
+                   preferred_element_type=jnp.float32)
+    score = jnp.sum(jnp.maximum(s, 0.0)
+                    * w.astype(jnp.float32)[:, :, None], axis=1)
+    live = jnp.arange(keys.shape[1], dtype=jnp.int32)[None, :] \
+        < lengths.astype(jnp.int32)[:, None]
+    return jnp.where(live, score, -jnp.inf)
+
+
+def _index_score_kernel(pt_ref, len_ref, q_ref, w_ref, k_hbm, o_ref, buf,
+                        sems, state_ref):
+    """One grid step = one slot, ALL indexer heads, ``ppb`` pages of the
+    indexer keys (``_latent_decode_kernel``'s double buffer and
+    hand-written page copies).  A page ``[page_len, D]`` lands once:
+    every head's query against its rows, ReLU, the heads' weighted sum,
+    one float32 score a row.  Rows past the slot's length score -inf,
+    whatever the buffer held there."""
+    s, j = pl.program_id(0), pl.program_id(1)
+    slots, nb = pl.num_programs(0), pl.num_programs(1)
+    _, ppb, page_len, width = buf.shape
+    bk = ppb * page_len
+    length = len_ref[s]
+
+    def for_live_pages(slot, blk, fn):
+        left = len_ref[slot] - blk * bk
+        jax.lax.fori_loop(0, jnp.minimum(ppb, (left + page_len - 1)
+                                         // page_len),
+                          lambda i, _: fn(i), None)
+
+    def fetch(slot, blk, half):
+        for_live_pages(slot, blk, lambda i: pltpu.make_async_copy(
+            k_hbm.at[pt_ref[(slot * nb + blk) * ppb + i]],
+            buf.at[half, i], sems.at[half]).start())
+
+    @pl.when((s == 0) & (j == 0))
+    def _clear():
+        state_ref[0] = 0
+        state_ref[1] = 0
+
+    o_ref[0] = jnp.full(o_ref.shape[1:], -jnp.inf, o_ref.dtype)
+
+    @pl.when(j * bk < length)
+    def _live():
+        half = state_ref[0]
+        state_ref[0] = 1 - half
+
+        @pl.when(state_ref[1] == 0)
+        def _first():
+            fetch(s, j, half)
+            state_ref[1] = 1
+
+        more = (j + 1 < nb) & ((j + 1) * bk < length)
+        next_s = jax.lax.while_loop(
+            lambda t: (t < slots) & (len_ref[jnp.minimum(t, slots - 1)] == 0),
+            lambda t: t + 1, jnp.where(more, s, s + 1))
+
+        @pl.when(next_s < slots)
+        def _ahead():
+            fetch(next_s, jnp.where(more, j + 1, 0), 1 - half)
+
+        for_live_pages(s, j, lambda i: pltpu.make_async_copy(
+            k_hbm.at[0], buf.at[half, i], sems.at[half]).wait())
+        rows = buf[half].reshape(bk, width)
+        sc = jax.lax.dot_general(
+            q_ref[0], rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [heads, bk]
+        score = jnp.sum(jnp.maximum(sc, 0.0) * w_ref[0], axis=0,
+                        keepdims=True)                       # [1, bk]
+        at = jax.lax.broadcasted_iota(jnp.int32, score.shape, 1)
+        o_ref[0] = jnp.where(at < length - j * bk, score, -jnp.inf)
+
+
+def index_score(q: jnp.ndarray, w: jnp.ndarray, pool: jnp.ndarray,
+                page_table: jnp.ndarray, lengths: jnp.ndarray, *,
+                impl: str = "pallas",
+                interpret: Optional[bool] = None) -> jnp.ndarray:
+    """The indexer's score of every cached position of every slot, the
+    kernel ``ds_index_score``: ``I[s, t] = sum_j w[s, j] * relu(q[s, j] .
+    key[s, t])`` in float32.
+
+    q: [S, J, D] the slot's indexer queries (rotated); w: [S, J] float32,
+        the heads' weights with every scale folded in.
+    pool: [P, page_len, D]: one indexer key a token (normed, rotated), a
+        slot's position ``p`` at row ``p % page_len`` of page
+        ``page_table[s, p // page_len]``.
+    page_table [S, max_pages], lengths [S]: traced.
+
+    Returns ``[S, max_pages * page_len]`` float32, ``-inf`` at and past a
+    slot's length.  ``impl='dense'`` is :func:`index_score_reference`."""
+    P, page_len, D = pool.shape
+    S, max_pages = page_table.shape
+    J = q.shape[1]
+    assert q.shape == (S, J, D) and w.shape == (S, J), (q.shape, w.shape)
+    if impl == "dense":
+        return index_score_reference(q, w, pool, page_table, lengths)
+    if impl != "pallas":
+        raise ValueError(f"index_score impl={impl!r}: expected 'pallas' or "
+                         "'dense'")
+    if interpret is None:
+        interpret = _use_interpret()
+    ppb = latent_pages_per_block(page_len, D, pool.dtype.itemsize, max_pages)
+    nb = -(-max_pages // ppb)
+    bk = ppb * page_len
+    pt_flat = jnp.pad(page_table.astype(jnp.int32),
+                      ((0, 0), (0, nb * ppb - max_pages))).reshape(-1)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(S, nb),
+        in_specs=[pl.BlockSpec((1, J, D), lambda s, j, *_: (s, 0, 0)),
+                  pl.BlockSpec((1, J, 1), lambda s, j, *_: (s, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, 1, bk), lambda s, j, *_: (s, 0, j)),
+        scratch_shapes=[
+            pltpu.VMEM((2, ppb, page_len, D), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((2,), jnp.int32),
+        ],
+    )
+    out = pl.pallas_call(
+        _index_score_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, 1, nb * bk), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+        name=INDEX_SCORE_KERNEL,
+    )(pt_flat, lengths.astype(jnp.int32), q,
+      w.astype(jnp.float32)[:, :, None], pool)
+    return out[:, 0, :max_pages * page_len]
+
+
+def sparse_latent_decode_attention(q: jnp.ndarray, rows: jnp.ndarray,
+                                   row_index: jnp.ndarray,
+                                   counts: jnp.ndarray, value_dim: int, *,
+                                   sm_scale: float, impl: str = "pallas",
+                                   interpret: Optional[bool] = None
+                                   ) -> jnp.ndarray:
+    """Single-query latent attention (absorbed form) over the rows an
+    indexer PICKED: XLA's gather, then :func:`latent_decode_attention`
+    under the name ``ds_sparse_latent_decode_attn``.
+
+    q: [S, H, W] as :func:`latent_decode_attention` takes it.
+    rows: [N, W]: every cached row of every layer and page in one column
+        (the pool's own bytes).
+    row_index: [S, K] int32: the picked rows of each slot, already through
+        the page table (``(layer base + page) * page_len + offset``); the
+        first ``counts[s]`` are live, the others any valid row.
+    counts [S]: 0 where the slot is not active.
+
+    The rows are fetched BY INDEX by XLA's gather (scope
+    ``sparse_gather``) into ``[S, K, W]``: Mosaic takes no copy of one
+    row out of the pool (a slice of an HBM array along its rows "must be
+    aligned to tiling (8)", and a bfloat16 tile is 16 rows, two to a
+    32-bit word; compiled for a described v5e, PR 49).  The gathered rows
+    ARE a paged pool, a slot's picks its pages of ``SPARSE_BLOCK_ROWS``
+    rows in order and ``counts`` its length, so the latent kernel reads
+    them as it reads a cache.  Returns ``[S, H, value_dim]``; a slot of
+    count 0 gives exact zeros.  ``impl='dense'`` is the same gather into
+    :func:`latent_decode_reference`."""
+    N, W = rows.shape
+    S, K = row_index.shape
+    page = min(SPARSE_BLOCK_ROWS, K)
+    assert K % page == 0, (K, page)
+    with jax.named_scope("sparse_gather"):
+        picked = rows[row_index].reshape(S * (K // page), page, W)
+    table = jnp.arange(S * (K // page), dtype=jnp.int32).reshape(S, -1)
+    return latent_decode_attention(
+        q, picked, table, counts, value_dim, sm_scale=sm_scale, impl=impl,
+        interpret=interpret, name=SPARSE_LATENT_DECODE_ATTN_KERNEL)
 
 
 # ---------------------------------------------------------------------------

@@ -283,7 +283,7 @@ def test_the_sixteen_shares_and_the_shared_expert_once_make_the_uncut_layer():
     def layer(c, p):
         ep = axk1.at(p["moe"], 0)
         out, st = axk1._experts(c, ep, axk1.stacked_experts(p), 0, x, None)
-        return out, axk1._shared_expert(ep, x), st
+        return out, axk1.shared_expert(ep, x), st
 
     full, shared, stats = layer(cfg, params)
     assert int(stats.rows) == 12 * 3 and float(jnp.abs(shared).max()) > 0

@@ -1020,6 +1020,159 @@ def test_axk1_lower_prefill_rung_compiles_under_the_top_rungs_peak(one_chip):
 
 
 # ---------------------------------------------------------------------------
+# GLM-5.2 at its cell's sizes (benchmark/configs/glm-5.2.json: 7 layers of
+# latent attention of which 2 score, 16 of 256 experts held at hidden 6,144,
+# 32 slots, 6,145 pages of 64 rows 640 wide + 2 layers of indexer keys 128
+# wide under the same page ids): the indexer's kernel over a whole context,
+# the sparse kernel over the picked rows, and both serve programs
+# ---------------------------------------------------------------------------
+
+GLM_SLOTS, GLM_PAGE_LEN, GLM_PAGES, GLM_MAX_PAGES = 32, 64, 7169, 384
+
+
+def _glm_model():
+    import json
+    from deepspeed_tpu.models.glm_dsa import GlmDsaConfig, GlmDsaModel
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs", "glm-5.2.json")) as f:
+        file = json.load(f)
+    serving = file["serving"]
+    assert (serving["slots"], serving["page_len"], serving["pages"],
+            -(-serving["max_seq_len"] // serving["page_len"])) == (
+        GLM_SLOTS, GLM_PAGE_LEN, GLM_PAGES, GLM_MAX_PAGES)
+    fields = {f.name for f in dataclasses.fields(GlmDsaConfig)}
+    keys = {k: v for k, v in file.items() if k in fields}
+    keys["n_routed_experts"] = file["published"]["n_routed_experts"]
+    keys["experts_held"] = tuple(file["experts_held"])
+    return GlmDsaModel(GlmDsaConfig(**keys, param_dtype=file["dtype"])), file
+
+
+def test_index_score_kernel_streams_a_whole_context_of_keys(one_chip):
+    """32 indexer heads against keys 128 wide: 128 pages of 64 a block
+    (8,192 keys) inside the module's VMEM budget, three blocks to the
+    longest context, the keys left in HBM, float32 scores out."""
+    from deepspeed_tpu.ops.pallas.decode_attention import (
+        INDEX_SCORE_KERNEL, index_score, latent_pages_per_block)
+    assert INDEX_SCORE_KERNEL == "ds_index_score"
+    ppb = latent_pages_per_block(GLM_PAGE_LEN, 128, 2, GLM_MAX_PAGES)
+    assert ppb == 128 and GLM_MAX_PAGES % ppb == 0
+    compiled = _compile(
+        lambda q, w, pool, t, n: index_score(q, w, pool, t, n,
+                                             interpret=False),
+        one_chip, _sds((GLM_SLOTS, 32, 128)),
+        _sds((GLM_SLOTS, 32), jnp.float32),
+        _sds((2 * GLM_PAGES, GLM_PAGE_LEN, 128)),
+        _sds((GLM_SLOTS, GLM_MAX_PAGES), jnp.int32),
+        _sds((GLM_SLOTS,), jnp.int32))
+    names = _kernel_names(compiled)
+    assert [n.split(".")[0] for n in names] == [INDEX_SCORE_KERNEL]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def test_sparse_kernel_reads_the_rows_xla_gathered(one_chip):
+    """64 heads' [q_lat ; q_rope] against 2,048 picked rows a slot 640
+    wide, fetched by index by XLA's gather out of the pool where it lies
+    (no copy of the pool) and read by the latent kernel under its own
+    name as four pages of 512 rows a slot."""
+    from deepspeed_tpu.ops.pallas.decode_attention import (
+        SPARSE_BLOCK_ROWS, SPARSE_LATENT_DECODE_ATTN_KERNEL,
+        sparse_latent_decode_attention)
+    assert SPARSE_LATENT_DECODE_ATTN_KERNEL == "ds_sparse_latent_decode_attn"
+    assert 2048 % SPARSE_BLOCK_ROWS == 0
+    compiled = _compile(
+        lambda q, rows, index, n: sparse_latent_decode_attention(
+            q, rows, index, n, 512, sm_scale=0.0625, interpret=False),
+        one_chip, _sds((GLM_SLOTS, 64, 640)),
+        _sds((7 * GLM_PAGES * GLM_PAGE_LEN, 640)),
+        _sds((GLM_SLOTS, 2048), jnp.int32), _sds((GLM_SLOTS,), jnp.int32))
+    names = _kernel_names(compiled)
+    assert [n.split(".")[0] for n in names] \
+        == [SPARSE_LATENT_DECODE_ATTN_KERNEL]
+    # the gathered rows and nothing of the pool's size
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 2 * GLM_SLOTS * 2048 * 640 * 2
+
+
+@functools.lru_cache(maxsize=None)
+def _glm_program(program, one_chip, bucket=2048):
+    """The model's paged step as the engine calls it: both arrays donated,
+    None where a second pool would be; a prefill at ``bucket`` tokens with
+    its prefix length TRACED (a whole prompt and a chunk are one
+    program)."""
+    model, _ = _glm_model()
+    cfg = model.config
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    pool = _sds((7, GLM_PAGES, 1, GLM_PAGE_LEN, cfg.latent_width))
+    keys = _sds((2, GLM_PAGES, 1, GLM_PAGE_LEN, cfg.d_index))
+    i32, s = _sds((), jnp.int32), GLM_SLOTS
+    if program == "serve_decode":
+        def fn(p, t, k, ik, tab, ln, act):
+            return model.decode_step_paged(p, t, k, None, tab, ln, act,
+                                           impl="pallas", aux=True,
+                                           index_pool=ik)
+        shapes = (params, _sds((s,), jnp.int32), pool, keys,
+                  _sds((s, GLM_MAX_PAGES), jnp.int32),
+                  _sds((s,), jnp.int32), _sds((s,), jnp.bool_))
+        donate = (2, 3)
+    else:
+        def fn(p, t, n, pre, row, k, ik):
+            return model.prefill_paged(p, t, n, pre, row, k, None, aux=True,
+                                       index_pool=ik)
+        shapes = (params, _sds((1, bucket), jnp.int32), i32, i32,
+                  _sds((GLM_MAX_PAGES,), jnp.int32), pool, keys)
+        donate = (5, 6)
+    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), shapes)
+    with interpret_scope(False):
+        return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+
+
+@pytest.mark.parametrize("program", ["serve_decode", "serve_prefill"])
+def test_glm_programs_hold_their_kernels_and_both_arrays(program, one_chip):
+    """Every Mosaic call of both serve programs starts ``ds_``; the two
+    paged arrays (4.346 GB) pass through aliased to the outputs and nothing
+    of their size is a temporary; the arguments are the weights and those
+    arrays; the compiler's own counts are the ones the configuration's
+    ``reduced_why`` states; all the chip must hold at once fits its
+    16.91e9 bytes."""
+    from deepspeed_tpu.moe import dropless
+    from deepspeed_tpu.ops.pallas.decode_attention import (
+        INDEX_SCORE_KERNEL, SPARSE_LATENT_DECODE_ATTN_KERNEL)
+    compiled = _glm_program(program, one_chip)
+    names = {n.split(".")[0] for n in _kernel_names(compiled)}
+    experts = {dropless.MOE_GATE_UP_KERNEL, dropless.MOE_DOWN_KERNEL}
+    assert names == experts | ({INDEX_SCORE_KERNEL,
+                                SPARSE_LATENT_DECODE_ATTN_KERNEL}
+                               if program == "serve_decode"
+                               else set()), names
+    mem = compiled.memory_analysis()
+    arrays = GLM_PAGES * GLM_PAGE_LEN * (7 * 640 + 2 * 128) * 2
+    assert mem.alias_size_in_bytes >= arrays
+    weights = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves(compiled.in_avals[0][0]))
+    assert abs(weights - 10.996e9) < 1e6
+    assert abs(mem.argument_size_in_bytes - weights - arrays) < 1 << 20
+    limit = 0.15e9 if program == "serve_decode" else 0.6e9
+    assert mem.temp_size_in_bytes < limit, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.0e9
+    _, file = _glm_model()
+    said = {"serve_decode": "temporaries %.3f GB (decode",
+            "serve_prefill": "%.3f GB (prefill"}[program]
+    assert "arguments %.3f GB" % (mem.argument_size_in_bytes / 1e9) \
+        in file["reduced_why"]
+    assert said % (mem.temp_size_in_bytes / 1e9) in file["reduced_why"]
+
+
+def test_glm_lower_prefill_rung_compiles_under_the_top_rungs_peak(one_chip):
+    from deepspeed_tpu.inference.engine import prefill_ladder
+    assert prefill_ladder(2048) == (1024, 2048)
+    top = _glm_program("serve_prefill", one_chip).memory_analysis()
+    rung = _glm_program("serve_prefill", one_chip, 1024).memory_analysis()
+    assert rung.alias_size_in_bytes == top.alias_size_in_bytes
+    assert rung.temp_size_in_bytes < top.temp_size_in_bytes
+
+
+# ---------------------------------------------------------------------------
 # Command A+ at its cell's sizes (benchmark/configs/command-a-plus-05-2026
 # .json: one period of three window layers and a full one, 8 of 128 experts
 # held at 4,096 x 4,096, 96 slots of rings of 4,096 keys, 12,289 pages of 8

@@ -5,7 +5,10 @@
   (read from the sources, nothing is imported);
 * the two index preludes state once the rule five files relied on: an
   inactive slot and a padded prefill row name page 0, the engine's scratch
-  page, and attend over / count as length 0.
+  page, and attend over / count as length 0;
+* latent attention's expanded form over a paged context, which two
+  families share: every key under the causal rule, or the keys a mask
+  allows beside it.
 """
 import ast
 import os
@@ -18,7 +21,8 @@ from deepspeed_tpu.models import walked
 
 MODELS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "deepspeed_tpu", "models")
-FAMILIES = ("olmoe", "nemotron_h", "mimo_v2", "axk1", "cohere2_moe")
+FAMILIES = ("olmoe", "nemotron_h", "mimo_v2", "axk1", "cohere2_moe",
+            "glm_dsa")
 
 
 def _imported_modules(path):
@@ -108,3 +112,56 @@ def test_a_row_that_is_not_kept_is_written_back_as_it_was():
     want = np.asarray(pool).copy()
     want[1, 2, :, 1] = -1.0             # layer 1, page 2, both heads, row 1
     np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["causal", "allowed"])
+def test_latent_context_attention_reads_the_pages_under_a_mask(masked):
+    """Queries at positions 20..29 over 30 cached rows on pages out of
+    order, two blocks of pages: the blocked online softmax is the dense
+    one; ``allowed`` (learned sparse attention's picks) takes keys out
+    beside the causal rule, and all-true is None."""
+    rng = np.random.default_rng(0)
+    H, nope, rot, C, dv, page_len, Tq = 2, 8, 4, 16, 8, 8, 10
+    ap = {"k_b_w": jnp.asarray(rng.normal(size=(H, nope, C)), jnp.float32),
+          "v_b_w": jnp.asarray(rng.normal(size=(H, C, dv)), jnp.float32)}
+    width = walked.whole_tiles(C + rot)
+    assert (width, walked.whole_tiles(576), walked.whole_tiles(128)) \
+        == (20, 640, 128)
+    c_kv = jnp.asarray(rng.normal(size=(30, C)), jnp.float32)
+    k_rope = jnp.asarray(rng.normal(size=(30, rot)), jnp.float32)
+    rows = walked.latent_rows(c_kv, k_rope, width + 4)   # 4 lanes of zeros
+    assert rows.shape == (30, 24) and not np.asarray(rows[:, 20:]).any()
+    page_ids = np.array([5, 2, 7, 1, 0, 0, 0, 0], np.int32)
+    pool = np.zeros((9, page_len, 24), np.float32)
+    padded = np.concatenate([np.asarray(rows), np.zeros((2, 24))])
+    pool[page_ids[:4]] = padded.reshape(4, page_len, 24)
+    q_nope = jnp.asarray(rng.normal(size=(H, Tq, nope)), jnp.float32)
+    q_rope = jnp.asarray(rng.normal(size=(H, Tq, rot)), jnp.float32)
+    abs_pos = 20 + jnp.arange(Tq, dtype=jnp.int32)
+    allowed = rng.random((Tq, 64)) < 0.6
+    allowed[np.arange(Tq), 20 + np.arange(Tq)] = True     # its own key
+
+    def attend(mask, block):
+        walked._CONTEXT_BLOCK, was = block, walked._CONTEXT_BLOCK
+        try:
+            return np.asarray(walked.latent_context_attention(
+                ap, q_nope, q_rope, jnp.asarray(pool), page_ids, abs_pos,
+                jnp.int32(30), kv_rank=C, sm_scale=0.3,
+                allowed=None if mask is None else jnp.asarray(mask)))
+        finally:
+            walked._CONTEXT_BLOCK = was
+
+    got = attend(allowed if masked else None, 16)
+    k_nope, v = walked.expand_latents(ap, c_kv, jnp.float32)
+    s = (jnp.einsum("htn,hkn->htk", q_nope, k_nope)
+         + jnp.einsum("htr,kr->htk", q_rope, k_rope)) * 0.3
+    ok = np.arange(30)[None, :] <= np.asarray(abs_pos)[:, None]
+    if masked:
+        ok &= allowed[:, :30]
+    p = np.asarray(jnp.exp(s)) * ok[None]
+    want = np.einsum("htk,hkv->htv", p / p.sum(-1, keepdims=True), v)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    if not masked:
+        np.testing.assert_array_equal(
+            attend(np.ones((Tq, 64), bool), 16), got)
+        np.testing.assert_allclose(attend(None, 32), got, atol=1e-5)

@@ -43,13 +43,18 @@ layers (``config.n_index_layer``, ``d_index``;
 else, so chunked prefill works; the steps:
 
 * the decode tick, a layer: the new row (and key) written; on a ``full``
-  layer ``ds_index_score`` scores the slot's whole context and XLA's
-  stable sort picks (exact, ties to the lower position), each position
-  carrying the row of the page table it lies at; every layer fetches its
-  picked rows (XLA's gather, scope ``sparse_gather``) and the latent
-  kernel, as ``ds_sparse_latent_decode_attn``, attends them in the
-  ABSORBED form.  A
-  context of ``index_topk`` rows or fewer takes every row.
+  layer ``ds_index_score`` scores the slot's whole context and the picks
+  become a mask over positions (``_pick_mask``: exact, ties to the lower
+  position, no sort); every layer's latent kernel, as
+  ``ds_sparse_latent_decode_attn``, walks every live row of the slot where
+  it lies and scores the allowed ones (ABSORBED form), a ``shared`` layer
+  under its ``full`` layer's mask.  A context of ``index_topk`` rows or
+  fewer takes every row.  Reading a slot's ~7,700 rows in place costs less
+  than sorting, then fetching 2,048 of them by index (whole ticks at the
+  GLM-5.2 cell's shapes: 12.98 ms against 17.84; my chip run, PR 50), and
+  so it does up to ~20,700 rows a slot, past what 32 slots of that pool
+  hold (``PERF.md`` section 7 has the readings, and the cell that would
+  want the gather back).
 * the prefill (a whole prompt, a chunk, the same program): a ``full``
   layer scores the chunk's queries against the request's cached keys a
   block of pages at a time and turns the ``index_topk``-th largest score
@@ -373,9 +378,10 @@ class GlmDsaModel(WalkedModel):
         index_pool, new_lengths) and, with ``aux``, the tick's counters
         and beside them ``"index_picks"``: each ``full`` layer's picked
         positions ``[full layers, S, K]``, the first ``min(K, context)``
-        of a slot live (what a probe compares; the engine fetches the
-        counters ``serving_aux`` names and nothing else).  An inactive
-        slot's pages are neither read nor written."""
+        of a slot live, in the positions' order (what a probe compares as
+        sets; the engine fetches the counters ``serving_aux`` names and
+        nothing else).  An inactive slot's pages are neither read nor
+        written."""
         from ..ops.pallas.decode_attention import (
             index_score, sparse_latent_decode_attention)
         self.refuse(unbuilt)
@@ -386,23 +392,15 @@ class GlmDsaModel(WalkedModel):
             page_table, lengths, active, page_len, cfg.n_positions)
         pool = PagePool((k_pool,), page_ids, offs, active)
         index = PagePool((index_pool,), page_ids, offs, active)
-        cap = page_table.shape[1] * page_len
-        K = min(cfg.index_topk, cap)
-        counts = jnp.minimum(att_len, K)
-        # every position of a slot, and the row of a layer's pages it lies
-        # at: what a full layer's selection carries along with the scores
-        at_pos = jnp.broadcast_to(jnp.arange(cap, dtype=jnp.int32),
-                                  (page_table.shape[0], cap))
-        at_row = jnp.repeat(page_table, page_len, axis=1) * page_len \
-            + at_pos % page_len
-        picked = []     # a full layer's (rows, positions) [S, K], newest last
+        K = min(cfg.index_topk, page_table.shape[1] * page_len)
+        masks = []      # a full layer's picks [S, cap] bool, newest last
 
         def attend(layer, ap, ip, h, lat):
             c_q, q_nope, q_rope, c_kv, k_rope = lat
             pool.write(layer, latent_rows(c_kv[:, 0], k_rope[:, 0],
                                           cfg.latent_width))
             if ip is not None:
-                full = len(picked)
+                full = len(masks)
                 with jax.named_scope("indexer"):
                     q_i, k_i, w = _index_projections(cfg, ip, h, c_q,
                                                      positions[:, None])
@@ -415,23 +413,15 @@ class GlmDsaModel(WalkedModel):
                             page_table + full * index.per_layer, att_len,
                             impl=impl)
                     with jax.named_scope("index_topk"):
-                        # exact, ties to the lower position (a stable
-                        # sort); the rows ride along: looking 65,536
-                        # picked positions up in the page table afterwards
-                        # costs more than the sort (0.70 ms against 0.21
-                        # more; my chip run, PR 49)
-                        _, rows, where = jax.lax.sort(
-                            (-scores, at_row, at_pos), dimension=1,
-                            is_stable=True, num_keys=1)
-                        picked.append((rows[:, :K], where[:, :K]))
-            row_index = layer * pool.per_layer * page_len + picked[-1][0]
+                        masks.append(_pick_mask(scores, K))
             with jax.named_scope("absorb"):
                 q_lat = jnp.einsum("shn,hnc->shc", q_nope[:, :, 0],
                                    ap["k_b_w"].astype(q_nope.dtype))
             o_lat = sparse_latent_decode_attention(
                 latent_rows(q_lat, q_rope[:, :, 0], cfg.latent_width),
-                pool.rows[0], row_index, counts, cfg.kv_lora_rank,
-                sm_scale=scale, impl=impl)
+                pool.rows[0].reshape(-1, page_len, cfg.latent_width),
+                page_table + layer * pool.per_layer, att_len, masks[-1],
+                cfg.kv_lora_rank, sm_scale=scale, impl=impl)
             with jax.named_scope("absorb"):
                 out = jnp.einsum("shc,hcv->shv", o_lat,
                                  ap["v_b_w"].astype(o_lat.dtype))
@@ -445,8 +435,11 @@ class GlmDsaModel(WalkedModel):
             live = jnp.sum(att_len)
             counters = _aux(cfg, stats, live * cfg.n_layer,
                             live * cfg.count("full"),
-                            jnp.sum(counts) * cfg.n_layer)
-            counters["index_picks"] = jnp.stack([p for _, p in picked])
+                            jnp.sum(jnp.minimum(att_len, K)) * cfg.n_layer)
+            # a mask's positions in order, the picked ones first
+            counters["index_picks"] = jnp.stack([
+                jnp.argsort(~m, axis=1, stable=True)[:, :K]
+                for m in masks]).astype(jnp.int32)
             out += (counters,)
         return out
 
@@ -652,7 +645,9 @@ def _aux(cfg: GlmDsaConfig, stats, latent_kv_tokens=0, scored=0,
     ``latent_kv_tokens``: the LIVE rows of the slots' contexts summed
     over layers; ``index_scored_rows``: the keys the indexer scored,
     summed over the ``full`` layers; ``index_selected_rows``: the rows
-    the attention read, summed over layers (all 0 in a prefill)."""
+    the attention attended (the picked ones, of the ``latent_kv_tokens``
+    the tick's kernel read to attend them), summed over layers (all 0 in
+    a prefill)."""
     return {**held_expert_counters(stats, cfg.held[1]),
             "latent_kv_tokens": jnp.asarray(latent_kv_tokens, jnp.int32),
             "index_scored_rows": jnp.asarray(scored, jnp.int32),

@@ -1197,27 +1197,29 @@ def latent_pages_per_block(page_len: int, width: int, itemsize: int,
 
 
 def latent_decode_reference(q, pool, page_table, lengths, value_dim: int,
-                            sm_scale: float):
+                            sm_scale: float, allowed=None):
     """Dense jnp reference of :func:`latent_decode_attention`: the slot's
     pages gathered, every head against the same rows, the values their
-    first ``value_dim`` lanes.  A slot of length 0 gives exact zeros."""
+    first ``value_dim`` lanes; with ``allowed`` [S, cap] only the positions
+    it names.  A slot with no key to read gives exact zeros."""
     S, max_pages = page_table.shape
     rows = pool[page_table].reshape(S, -1, pool.shape[-1])   # [S, cap, W]
     s = jnp.einsum("shw,stw->sht", q, rows.astype(q.dtype),
                    preferred_element_type=jnp.float32) * sm_scale
-    lengths = lengths.astype(jnp.int32)
-    live = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, None, :] \
-        < lengths[:, None, None]
+    live = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, :] \
+        < lengths.astype(jnp.int32)[:, None]
+    if allowed is not None:
+        live &= allowed
+    live = live[:, None, :]
     s = jnp.where(live, s, jnp.finfo(jnp.float32).min)
-    p = jnp.where(lengths[:, None, None] > 0, jax.nn.softmax(s, axis=-1),
-                  0.0).astype(q.dtype)
+    p = jnp.where(jnp.any(live, axis=-1, keepdims=True),
+                  jax.nn.softmax(s, axis=-1), 0.0).astype(q.dtype)
     return jnp.einsum("sht,stv->shv", p,
                       rows[..., :value_dim].astype(q.dtype))
 
 
-def _latent_decode_kernel(pt_ref, len_ref, q_ref, kv_hbm, o_ref, buf, sems,
-                          state_ref, m_scr, l_scr, acc_scr, *,
-                          sm_scale: float, value_dim: int):
+def _latent_decode_kernel(pt_ref, len_ref, q_ref, *refs, sm_scale: float,
+                          value_dim: int, masked: bool):
     """One grid step = one slot, ALL heads, ``ppb`` pages of the ONE pool
     (the direct paged body's double buffer and hand-written page copies:
     ``_decode_paged_direct_kernel`` says how the halves pass from step to
@@ -1225,7 +1227,12 @@ def _latent_decode_kernel(pt_ref, len_ref, q_ref, kv_hbm, o_ref, buf, sems,
     it lies: all ``W`` lanes of its rows against every head's ``[q_lat ;
     q_rope]`` for the scores, their first ``value_dim`` lanes under the
     probabilities for the output.  A key's position is its row's place
-    in the block: no head owns a row, so no position table."""
+    in the block: no head owns a row, so no position table.  ``masked``:
+    one more operand ahead of the pool, the block's ``[1, bk]`` lanes of
+    the caller's mask over positions (nonzero = this key may score)."""
+    allowed_ref = refs[0] if masked else None
+    kv_hbm, o_ref, buf, sems, state_ref, m_scr, l_scr, acc_scr = \
+        refs[masked:]
     s, j = pl.program_id(0), pl.program_id(1)
     slots, nb = pl.num_programs(0), pl.num_programs(1)
     _, ppb, page_len, width = buf.shape
@@ -1284,13 +1291,24 @@ def _latent_decode_kernel(pt_ref, len_ref, q_ref, kv_hbm, o_ref, buf, sems,
         sc = jax.lax.dot_general(
             q_ref[0], rows, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * sm_scale
-        at = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
-        sc = jnp.where(at < length - j * bk, sc, NEG_INF)
+        if masked:
+            at = jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+            sc = jnp.where((at < length - j * bk) & (allowed_ref[0] != 0),
+                           sc, NEG_INF)
+        else:
+            at = jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+            sc = jnp.where(at < length - j * bk, sc, NEG_INF)
         m_prev = m_scr[:, 0:1]
         m_new = jnp.maximum(m_prev, jnp.max(sc, axis=1, keepdims=True))
-        # key j*bk is live (the pl.when guard), so m_new is a real score
-        # and the masked keys' exp underflows to 0
-        p = jnp.exp(sc - m_new)
+        if masked:
+            # no key of the slot may have scored yet: m_new is then the
+            # floor itself, and against 0 the floored keys' exp is still
+            # 0 (alpha is exp(0) = 1 over sums that are 0)
+            p = jnp.exp(sc - jnp.where(m_new == NEG_INF, 0.0, m_new))
+        else:
+            # key j*bk is live (the pl.when guard), so m_new is a real
+            # score and the masked keys' exp underflows to 0
+            p = jnp.exp(sc - m_new)
         alpha = jnp.exp(m_prev - m_new)
         l_scr[:] = jnp.broadcast_to(
             alpha * l_scr[:, 0:1] + jnp.sum(p, axis=1, keepdims=True),
@@ -1314,7 +1332,8 @@ def latent_decode_attention(q: jnp.ndarray, pool: jnp.ndarray,
                             value_dim: int, *, sm_scale: float,
                             impl: str = "pallas",
                             interpret: Optional[bool] = None,
-                            name: str = LATENT_DECODE_ATTN_KERNEL
+                            name: str = LATENT_DECODE_ATTN_KERNEL,
+                            allowed: Optional[jnp.ndarray] = None
                             ) -> jnp.ndarray:
     """Single-query latent attention (MLA, absorbed form) over ONE paged
     pool, the kernel ``ds_latent_decode_attn`` (or ``name``, for a caller
@@ -1330,9 +1349,16 @@ def latent_decode_attention(q: jnp.ndarray, pool: jnp.ndarray,
     page_table [S, max_pages], lengths [S]: traced, as
         :func:`decode_attention_paged` takes them.  ``sm_scale`` is the
         caller's: the stored width says nothing of the head it came from.
+    allowed: None, or bool ``[S, max_pages * page_len]`` over a slot's
+        POSITIONS: a key scores only where it is set (and under the
+        slot's length); the others have weight 0, a block or a slot with
+        none set included.  The kernel then takes one more operand, a
+        block's lanes of the mask a grid step; without it the call is the
+        one it always was.
 
-    Returns ``[S, H, value_dim]``; a slot of length 0 gives exact zeros.
-    ``impl='dense'`` is :func:`latent_decode_reference`."""
+    Returns ``[S, H, value_dim]``; a slot with no key to read (length 0,
+    nothing allowed) gives exact zeros.  ``impl='dense'`` is
+    :func:`latent_decode_reference`."""
     assert q.ndim == 3 and pool.ndim == 3, (q.shape, pool.shape)
     P, page_len, W = pool.shape
     S, max_pages = page_table.shape
@@ -1340,7 +1366,7 @@ def latent_decode_attention(q: jnp.ndarray, pool: jnp.ndarray,
     assert q.shape == (S, H, W) and value_dim <= W, (q.shape, pool.shape)
     if impl == "dense":
         return latent_decode_reference(q, pool, page_table, lengths,
-                                       value_dim, sm_scale)
+                                       value_dim, sm_scale, allowed)
     if impl != "pallas":
         raise ValueError(f"latent_decode_attention impl={impl!r}: expected "
                          "'pallas' or 'dense'")
@@ -1348,13 +1374,25 @@ def latent_decode_attention(q: jnp.ndarray, pool: jnp.ndarray,
         interpret = _use_interpret()
     ppb = latent_pages_per_block(page_len, W, pool.dtype.itemsize, max_pages)
     nb = -(-max_pages // ppb)
+    bk = ppb * page_len
     pt_flat = jnp.pad(page_table.astype(jnp.int32),
                       ((0, 0), (0, nb * ppb - max_pages))).reshape(-1)
+    mask, mask_spec = [], []
+    if allowed is not None:
+        assert allowed.shape == (S, max_pages * page_len), allowed.shape
+        # a grid step's lanes: block j of slot s is row s * nb + j; a block
+        # past the slot's live rows names the last live one, so nothing is
+        # fetched for it
+        mask = [jnp.pad(allowed.astype(jnp.int32),
+                        ((0, 0), (0, nb * bk - allowed.shape[1])))
+                .reshape(S * nb, 1, bk)]
+        mask_spec = [pl.BlockSpec((1, 1, bk), lambda s, j, pt, ln: (
+            s * nb + jnp.minimum(j, jnp.maximum(ln[s] - 1, 0) // bk), 0, 0))]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(S, nb),
         in_specs=[pl.BlockSpec((1, H, W), lambda s, j, *_: (s, 0, 0)),
-                  pl.BlockSpec(memory_space=pl.ANY)],
+                  *mask_spec, pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((1, H, value_dim), lambda s, j, *_: (s, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, ppb, page_len, W), pool.dtype),
@@ -1367,7 +1405,7 @@ def latent_decode_attention(q: jnp.ndarray, pool: jnp.ndarray,
     )
     return pl.pallas_call(
         functools.partial(_latent_decode_kernel, sm_scale=sm_scale,
-                          value_dim=value_dim),
+                          value_dim=value_dim, masked=bool(mask)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, value_dim), q.dtype),
         # the double buffer and its parity pass from one step to the next
@@ -1375,7 +1413,7 @@ def latent_decode_attention(q: jnp.ndarray, pool: jnp.ndarray,
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
         name=name,
-    )(pt_flat, lengths.astype(jnp.int32), q, pool)
+    )(pt_flat, lengths.astype(jnp.int32), q, *mask, pool)
 
 
 # ---------------------------------------------------------------------------
@@ -1386,8 +1424,6 @@ def latent_decode_attention(q: jnp.ndarray, pool: jnp.ndarray,
 
 INDEX_SCORE_KERNEL = "ds_index_score"
 SPARSE_LATENT_DECODE_ATTN_KERNEL = "ds_sparse_latent_decode_attn"
-#: rows of a "page" of the gathered picks, as the latent kernel copies them
-SPARSE_BLOCK_ROWS = 512
 
 
 def index_score_reference(q, w, pool, page_table, lengths):
@@ -1525,44 +1561,36 @@ def index_score(q: jnp.ndarray, w: jnp.ndarray, pool: jnp.ndarray,
     return out[:, 0, :max_pages * page_len]
 
 
-def sparse_latent_decode_attention(q: jnp.ndarray, rows: jnp.ndarray,
-                                   row_index: jnp.ndarray,
-                                   counts: jnp.ndarray, value_dim: int, *,
-                                   sm_scale: float, impl: str = "pallas",
-                                   interpret: Optional[bool] = None
-                                   ) -> jnp.ndarray:
+def sparse_latent_decode_attention(
+        q: jnp.ndarray, pool: jnp.ndarray, page_table: jnp.ndarray,
+        lengths: jnp.ndarray, allowed: jnp.ndarray, value_dim: int, *,
+        sm_scale: float, impl: str = "pallas",
+        interpret: Optional[bool] = None) -> jnp.ndarray:
     """Single-query latent attention (absorbed form) over the rows an
-    indexer PICKED: XLA's gather, then :func:`latent_decode_attention`
-    under the name ``ds_sparse_latent_decode_attn``.
+    indexer PICKED, read where they lie: :func:`latent_decode_attention`
+    under the name ``ds_sparse_latent_decode_attn`` walks each slot's whole
+    context in the pool (``pool``, ``page_table``, ``lengths`` as it takes
+    them) under the picks as a mask over positions (``allowed`` bool ``[S,
+    max_pages * page_len]``, e.g. ``models/glm_dsa.py::_pick_mask`` of the
+    indexer's scores).  A row that was not picked is fetched and has weight
+    0: the softmax is over the picked rows alone, in float32.  Returns
+    ``[S, H, value_dim]``; a slot with nothing picked gives exact zeros.
 
-    q: [S, H, W] as :func:`latent_decode_attention` takes it.
-    rows: [N, W]: every cached row of every layer and page in one column
-        (the pool's own bytes).
-    row_index: [S, K] int32: the picked rows of each slot, already through
-        the page table (``(layer base + page) * page_len + offset``); the
-        first ``counts[s]`` are live, the others any valid row.
-    counts [S]: 0 where the slot is not active.
-
-    The rows are fetched BY INDEX by XLA's gather (scope
-    ``sparse_gather``) into ``[S, K, W]``: Mosaic takes no copy of one
-    row out of the pool (a slice of an HBM array along its rows "must be
-    aligned to tiling (8)", and a bfloat16 tile is 16 rows, two to a
-    32-bit word; compiled for a described v5e, PR 49).  The gathered rows
-    ARE a paged pool, a slot's picks its pages of ``SPARSE_BLOCK_ROWS``
-    rows in order and ``counts`` its length, so the latent kernel reads
-    them as it reads a cache.  Returns ``[S, H, value_dim]``; a slot of
-    count 0 gives exact zeros.  ``impl='dense'`` is the same gather into
-    :func:`latent_decode_reference`."""
-    N, W = rows.shape
-    S, K = row_index.shape
-    page = min(SPARSE_BLOCK_ROWS, K)
-    assert K % page == 0, (K, page)
-    with jax.named_scope("sparse_gather"):
-        picked = rows[row_index].reshape(S * (K // page), page, W)
-    table = jnp.arange(S * (K // page), dtype=jnp.int32).reshape(S, -1)
+    The cost is the LIVE rows', not the picks'.  Fetching the picked rows
+    by index first is XLA's gather (Mosaic takes no copy of one row out of
+    the pool: a slice of an HBM array along its rows "must be aligned to
+    tiling (8)", and a bfloat16 tile is 16 rows; compiled for a described
+    v5e, PR 49), which moves 65,536 rows of 1,280 B in 1.04 ms, a tenth of
+    the HBM peak, and needs a sort to say which; this kernel reads 32
+    slots' whole contexts of ~7,700 rows in 0.49 ms (my chip runs, PR 49
+    and PR 50).  Up to the 14,300 rows a slot that 32 slots of GLM-5.2's
+    pool can hold, reading all of them is the cheaper (``PERF.md`` section
+    7 has the length past which it is not, and the cell that would need
+    the gather back)."""
     return latent_decode_attention(
-        q, picked, table, counts, value_dim, sm_scale=sm_scale, impl=impl,
-        interpret=interpret, name=SPARSE_LATENT_DECODE_ATTN_KERNEL)
+        q, pool, page_table, lengths, value_dim, sm_scale=sm_scale,
+        impl=impl, interpret=interpret, allowed=allowed,
+        name=SPARSE_LATENT_DECODE_ATTN_KERNEL)
 
 
 # ---------------------------------------------------------------------------

@@ -1,8 +1,9 @@
 """GLM-5.2 (latent attention over the rows a learned indexer picks): the two
-kernels in interpret mode against their dense arms, the exact selection with
-ties, the model against the benchmark's plain float32 reference, prefill in
-chunks then decode through BOTH paged arrays (contexts at, one under and one
-over ``index_topk``), the picked sets against the reference's own, a
+kernels in interpret mode against their dense arms, the latent kernel under
+a mask over positions (and without one, the call it was), the exact selection
+with ties, the model against the benchmark's plain float32 reference, prefill
+in chunks then decode through BOTH paged arrays (contexts at, one under and
+one over ``index_topk``), the picked sets against the reference's own, a
 ``shared`` layer against the ``full`` one whose picks it takes, the indexer
 keys' pages under copy, export, adoption and reuse, the shares of the experts
 with the shared expert counted once against the uncut layer, the two-array
@@ -97,25 +98,101 @@ def test_index_score_kernel_streams_pages_and_masks_past_the_length(
     assert np.abs(want[live]).max() > 1
 
 
-def test_sparse_attention_reads_the_picked_rows_as_the_latent_kernel_reads_a_cache(
-        monkeypatch):
-    monkeypatch.setattr(da, "SPARSE_BLOCK_ROWS", 8)
-    rng = np.random.default_rng(1)
-    S, H, W, K, N = 4, 3, 256, 24, 320
-    rows = jnp.asarray(rng.normal(size=(N, W)), jnp.float32)
+def _masked_case(case, rng, S, cap, K):
+    """(lengths [S], allowed [S, cap]) of a case of the masked kernel; 16
+    positions a block."""
+    lengths = np.asarray([40, 48, 23, 37], np.int32)
+    scores = rng.normal(size=(S, cap)).astype(np.float32)
+    if case == "tie_at_kth":
+        scores = rng.integers(0, 4, (S, cap)).astype(np.float32)
+    if case == "dead_slot":
+        lengths[[0, 2]] = 0
+    if case == "every_row":                     # contexts of K rows or fewer
+        lengths = np.asarray([K, 1, K - 5, 3], np.int32)
+    scores[np.arange(cap)[None] >= lengths[:, None]] = -np.inf
+    allowed = np.array(glm_dsa._pick_mask(jnp.asarray(scores), K))
+    if case == "empty_blocks":
+        allowed[0, :16] = False                 # the slot's FIRST block
+        allowed[1, 16:32] = False               # one in the middle
+        allowed[2, 16:] = False                 # its last live one
+        allowed[3] = False                      # every block: nothing to read
+    return lengths, allowed
+
+
+@pytest.mark.parametrize("case", ["picked", "empty_blocks", "dead_slot",
+                                  "every_row", "tie_at_kth"])
+def test_the_latent_kernel_under_a_mask_reads_the_allowed_rows_alone(
+        monkeypatch, case):
+    """Three blocks of two pages a slot under a mask over positions: the
+    kernel is the dense arm under the same mask, whichever blocks hold no
+    allowed key (a slot's first, so that nothing has scored when the next
+    one runs; all of them: exact zeros); the mask of a context of ``K``
+    rows or fewer allows every row, and the call is the unmasked one; with
+    ties at the ``K``-th score the mask is the stable sort's set, and
+    reading under it is a softmax over that set's rows, fetched by hand."""
+    monkeypatch.setattr(da, "PAGED_KV_VMEM_BUDGET", 2 * 2 * 8 * 256 * 4)
+    rng = np.random.default_rng(3)
+    S, H, W, page_len, max_pages, P, K = 4, 3, 256, 8, 6, 30, 16
+    cap = max_pages * page_len
+    assert da.latent_pages_per_block(page_len, W, 4, max_pages) == 2
+    pool = jnp.asarray(rng.normal(size=(P, page_len, W)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(S, H, W)), jnp.float32)
-    index = jnp.asarray(rng.integers(0, N, (S, K)), jnp.int32)
-    counts = jnp.asarray([0, 5, 24, 9], jnp.int32)
+    table = jnp.asarray(1 + rng.permutation(P - 1)[:S * max_pages]
+                        .reshape(S, max_pages), jnp.int32)
+    lengths, allowed = _masked_case(case, rng, S, cap, K)
     got, want = (np.asarray(da.sparse_latent_decode_attention(
-        q, rows, index, counts, 128, sm_scale=0.1, impl=impl,
-        interpret=True)) for impl in ("pallas", "dense"))
+        q, pool, table, jnp.asarray(lengths), jnp.asarray(allowed), 128,
+        sm_scale=0.1, impl=impl, interpret=True))
+        for impl in ("pallas", "dense"))
     np.testing.assert_allclose(got, want, atol=1e-5)
-    assert not got[0].any() and np.abs(got[1:]).min() > 0
-    # the rows past a slot's count are not read: any row will do there
-    other = index.at[:, 9:].set(0)
-    again = np.asarray(da.sparse_latent_decode_attention(
-        q, rows, other, counts, 128, sm_scale=0.1, interpret=True))
-    np.testing.assert_array_equal(again[[1, 3]], got[[1, 3]])
+    reads = allowed.any(axis=1)
+    assert not got[~reads].any() and np.abs(got[reads]).min() > 0
+    assert (allowed.sum(axis=1) <= np.minimum(lengths, K)).all()
+    if case == "every_row":
+        assert (allowed.sum(axis=1) == lengths).all()
+        plain = np.asarray(da.latent_decode_attention(
+            q, pool, table, jnp.asarray(lengths), 128, sm_scale=0.1,
+            interpret=True))
+        np.testing.assert_allclose(got, plain, atol=1e-6)
+    if case in ("picked", "tie_at_kth"):
+        # the stable sort's picks, their rows fetched through the page table
+        scores = np.where(allowed, 1.0, -np.inf)
+        rows = np.asarray(pool)[np.asarray(table)].reshape(S, cap, W)
+        for slot in range(S):
+            order = np.argsort(-scores[slot], kind="stable")
+            order = order[:min(K, lengths[slot])]
+            assert allowed[slot, order].all()
+            picked = rows[slot, order]                          # [k, W]
+            sc = np.asarray(q)[slot] @ picked.T * 0.1           # [H, k]
+            p = np.exp(sc - sc.max(axis=1, keepdims=True))
+            np.testing.assert_allclose(
+                got[slot], p / p.sum(axis=1, keepdims=True)
+                @ picked[:, :128], atol=1e-5)
+
+
+def test_the_latent_kernel_without_a_mask_is_the_call_it_was():
+    """A.X-K1's path: no mask, and the Mosaic call takes the page table,
+    the lengths, the queries and the pool, as it always did; under a mask,
+    one operand more and nothing else of the call moves."""
+    S, H, W, page_len, max_pages, P = 2, 3, 128, 8, 4, 9
+    args = (jnp.zeros((S, H, W)), jnp.zeros((P, page_len, W)),
+            jnp.zeros((S, max_pages), jnp.int32), jnp.zeros((S,), jnp.int32))
+
+    def call(**kw):
+        jaxpr = jax.make_jaxpr(lambda *a: da.latent_decode_attention(
+            *a, 64, sm_scale=1.0, interpret=False, **kw))(*args)
+        eqn, = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+        return ([v.aval.shape for v in eqn.invars], eqn.params["name"],
+                len(eqn.params["jaxpr"].invars))
+
+    operands, name, refs = call()
+    assert operands == [(S * max_pages,), (S,), (S, H, W),
+                        (P, page_len, W)]
+    assert name == da.LATENT_DECODE_ATTN_KERNEL
+    under, _, more = call(allowed=jnp.ones((S, max_pages * page_len), bool))
+    assert under == operands[:3] + [(S, 1, max_pages * page_len)] \
+        + operands[3:]
+    assert more == refs + 1
 
 
 @pytest.mark.parametrize("pick", [glm_dsa._pick_mask,
@@ -162,7 +239,7 @@ def _paged(model, params, prompt, forced, chunks, impl, page_len=8, slots=3,
     """Prefill ``prompt`` in ``chunks``, then one decode tick a forced
     token, in the middle slot of two arrays of its own.  Returns the logits
     of every prompt position and tick, the ticks' picked positions [ticks,
-    full layers, K], and the arrays."""
+    full layers, K]."""
     cfg = model.config
     spec = PagedKVCacheSpec(
         layers=cfg.n_layer, slots=slots, heads=1, pages=1 + max_pages,
@@ -202,7 +279,11 @@ def _paged(model, params, prompt, forced, chunks, impl, page_len=8, slots=3,
         picked.append(np.asarray(aux["index_picks"][:, slot]))
         assert int(aux["index_scored_rows"]) \
             == cfg.count("full") * int(lengths[slot])
-    return np.concatenate(rows), np.stack(picked), (pool, keys)
+        assert int(aux["index_selected_rows"]) \
+            == cfg.n_layer * min(cfg.index_topk, int(lengths[slot]))
+        assert int(aux["latent_kv_tokens"]) \
+            == cfg.n_layer * int(lengths[slot])
+    return np.concatenate(rows), np.stack(picked)
 
 
 @pytest.mark.parametrize("chunks", [(27,), (16, 11), (8, 8, 11), (15,)],
@@ -212,8 +293,9 @@ def _paged(model, params, prompt, forced, chunks, impl, page_len=8, slots=3,
 def test_paged_steps_and_their_picks_against_the_reference(attn_impl,
                                                            chunks):
     """Prefill (whole; in chunks that read the keys ahead of them from the
-    pages) writes both arrays; the ticks score the cached keys, pick, gather
-    and attend: every logit is the reference's full forward's and every
+    pages) writes both arrays; the ticks score the cached keys, pick and
+    attend every live row under the picks' mask: every logit is the
+    reference's full forward's and every
     picked set the reference's own.  ``under_topk``: the ticks run at
     contexts of 16 (= index_topk), then 17, after a prefill of 15."""
     cfg = dataclasses.replace(TINY, attn_impl=attn_impl)
@@ -221,9 +303,8 @@ def test_paged_steps_and_their_picks_against_the_reference(attn_impl,
     n = sum(chunks)
     prompt, forced = _tokens((n,), 4), _tokens((9,), 5)
     with interpret_scope(True):
-        got, picked, _ = _paged(model, params, prompt, forced, chunks,
-                                "pallas" if attn_impl == "flash"
-                                else "dense")
+        got, picked = _paged(model, params, prompt, forced, chunks,
+                             "pallas" if attn_impl == "flash" else "dense")
     seq = np.concatenate([prompt, forced])[None]
     want, sets = _reference(params, seq, cfg, pick_rows=n + np.arange(9))
     np.testing.assert_allclose(got, want[0], atol=F32_TOL)
@@ -241,6 +322,36 @@ def test_paged_steps_and_their_picks_against_the_reference(attn_impl,
         for switch in ("skip_indexer", "stale_picks"):
             off = _reference(params, seq, cfg, **{switch: True})[0]
             assert np.abs(off[n:] - want[0, n:]).max() > 10 * F32_TOL
+
+
+def test_a_shared_layer_of_the_tick_takes_its_full_layers_mask(monkeypatch):
+    """The tick, traced: layers 1-2 read under the very mask layer
+    0 made and layer 4 under layer 3's; the two masks are two selections."""
+    seen = []
+    real = da.sparse_latent_decode_attention
+
+    def spy(q, pool, table, lengths, allowed, *a, **kw):
+        seen.append(allowed)
+        return real(q, pool, table, lengths, allowed, *a, **kw)
+
+    monkeypatch.setattr(da, "sparse_latent_decode_attention", spy)
+    model, params = GlmDsaModel(TINY), _params()
+    spec = PagedKVCacheSpec(
+        layers=5, slots=2, heads=1, pages=9, page_len=8, head_dim=TINY.d_head,
+        max_pages=4, dtype=jnp.float32, v_head_dim=TINY.d_head_v,
+        values_in_keys=True, index_layers=2, index_dim=TINY.d_index)
+    cache = jax.eval_shape(lambda: init_paged_cache(spec))
+    jax.eval_shape(
+        lambda p, k, ik: model.decode_step_paged(
+            p, jnp.zeros((2,), jnp.int32), k, None,
+            jnp.zeros((2, 4), jnp.int32), jnp.zeros((2,), jnp.int32),
+            jnp.ones((2,), bool), impl="dense", index_pool=ik),
+        params, cache["k"], cache["index_k"])
+    assert list(TINY.indexer_types) \
+        == ["full", "shared", "shared", "full", "shared"]
+    assert len(seen) == 5 and seen[0].shape == (2, 32)
+    assert seen[0] is seen[1] is seen[2] and seen[3] is seen[4]
+    assert seen[0] is not seen[3]
 
 
 def test_a_shared_layer_takes_its_full_layers_picks():

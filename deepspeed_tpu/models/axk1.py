@@ -43,8 +43,9 @@ The two steps take DIFFERENT forms of the one attention:
 * the prefill computes the EXPANDED form (``k_nope`` and ``v`` of every
   head, ``flash_attention_fwd`` at the two widths) and writes the LATENT
   rows to the pages; with a cached prefix (a prefix hit, a chunk) it
-  reads the context's latent rows back from the pages and expands them a
-  block of pages at a time (:func:`_paged_context_attention`);
+  reads the context's latent rows where they lie in the pages and expands
+  them a block of pages at a time inside ``ds_latent_context_attn``
+  (:func:`_paged_context_attention`);
 * the decode tick computes the ABSORBED form over the pool
   (``ds_latent_decode_attn``).
 
@@ -85,9 +86,9 @@ import numpy as np
 from .walked import (F32, PagePool, ServedConfig, WalkedModel, at,
                      causal_self_attention, decode_index, dense_ffn,
                      draw_layers, expand_latents, held_expert_counters,
-                     latent_context_attention, latent_projections,
-                     latent_rows, lm_head, merge_heads, prefill_index,
-                     rms_norm, routed_experts, shared_expert,
+                     latent_context_attention, latent_context_pairs,
+                     latent_projections, latent_rows, lm_head, merge_heads,
+                     prefill_index, rms_norm, routed_experts, shared_expert,
                      stacked_experts, whole_tiles)
 
 
@@ -245,7 +246,8 @@ class AxK1Model(WalkedModel):
     #: ``serving_unsupported`` is the common one: arms these paged steps
     #: do not have (the prefix cache and chunked prefill they do: a
     #: request keeps pages and nothing else)
-    serving_aux = WalkedModel.serving_aux + ("latent_kv_tokens",)
+    serving_aux = WalkedModel.serving_aux + (
+        "latent_kv_tokens", "latent_context_rows", "latent_context_pairs")
 
     def serving_cache_layers(self) -> Dict[str, int]:
         """Layers by the kind of cache they keep."""
@@ -387,6 +389,9 @@ class AxK1Model(WalkedModel):
             page_row, delta_len, tokens.shape[1], page_len, prefix_len,
             cfg.n_positions)
         pool = PagePool((k_pool,), page_ids, offs, valid)
+        context_len = prefix_len + delta_len
+        # a padding row sees no key: whole blocks of them are skipped
+        q_pos = jnp.where(valid, abs_pos, -1)
 
         def attend(layer, ap, q_nope, q_rope, c_kv, k_rope):
             pool.write(layer, _cached_rows(cfg, c_kv[0], k_rope[0]))
@@ -397,13 +402,18 @@ class AxK1Model(WalkedModel):
                 lambda _: _paged_context_attention(
                     cfg, ap, q_nope[0], q_rope[0],
                     pool.rows[0].reshape(-1, page_len, width),
-                    layer * pool.per_layer + page_row, abs_pos,
-                    prefix_len + delta_len)[None],
+                    layer * pool.per_layer + page_row, q_pos,
+                    context_len)[None],
                 None)
 
         logits, stats = _layers(cfg, params, tokens, positions, valid, attend)
         out = (logits, *pool.arrays(), None)
-        return out + (_aux(cfg, stats, 0),) if aux else out
+        if aux:
+            # the kernel runs where the context begins in the pages
+            paged = jnp.where(prefix_len == 0, 0, cfg.n_layer)
+            out += (_aux(cfg, stats, 0, paged * context_len,
+                         paged * latent_context_pairs(q_pos, context_len)),)
+        return out
 
 
 # -- the layer's parts ----------------------------------------------------
@@ -477,12 +487,20 @@ def _ffn(cfg: AxK1Config, params, stacked, layer: int, x, valid, stats):
     return x + out
 
 
-def _aux(cfg: AxK1Config, stats, latent_kv_tokens) -> Dict[str, jnp.ndarray]:
+def _aux(cfg: AxK1Config, stats, latent_kv_tokens, context_rows=0,
+         context_pairs=0) -> Dict[str, jnp.ndarray]:
     """The call's counters: the expert layers' as ``NemotronHModel``'s
-    (of the HELD experts), and ``latent_kv_tokens``: the live rows the
-    decode kernel read, summed over layers (0 in a prefill)."""
+    (of the HELD experts); ``latent_kv_tokens``: the live rows the decode
+    kernel read, summed over layers (0 in a prefill); and of a prefill
+    whose context begins in the pages (0 elsewhere) ``latent_context_rows``:
+    the context's rows ``ds_latent_context_attn`` walked, summed over
+    layers, and ``latent_context_pairs``: the (query, key) pairs the causal
+    rule let through a head (``walked.latent_context_pairs``), summed over
+    layers, float32."""
     return {**held_expert_counters(stats, cfg.held[1]),
-            "latent_kv_tokens": jnp.asarray(latent_kv_tokens, jnp.int32)}
+            "latent_kv_tokens": jnp.asarray(latent_kv_tokens, jnp.int32),
+            "latent_context_rows": jnp.asarray(context_rows, jnp.int32),
+            "latent_context_pairs": jnp.asarray(context_pairs, F32)}
 
 
 def _layers(cfg: AxK1Config, params, tokens, positions, valid, attend):

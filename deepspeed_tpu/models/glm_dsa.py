@@ -60,7 +60,12 @@ else, so chunked prefill works; the steps:
   block of pages at a time and turns the ``index_topk``-th largest score
   of each query into a mask ``[Tq, context]``; every layer attends in the
   EXPANDED form over the pages under that mask
-  (``walked.latent_context_attention``), keys ahead of the chunk too.
+  (``walked.latent_context_attention``), keys ahead of the chunk too: one
+  kernel, ``ds_latent_context_attn``, that reads a block of whole pages
+  where they lie, expands it once for a group of heads and walks the
+  chunk's query blocks with the scores, the softmax's running max and sum
+  and the accumulator in VMEM; blocks past the context or wholly ahead of
+  a query block are skipped.
 
 Parameter tree: ``wte``, ``lm_head`` [d, V], ``norm_f``; ``attn`` (as
 ``axk1.py``'s); ``indexer``, of the ``full`` layers only (``wq_b_w``
@@ -79,10 +84,11 @@ import jax.numpy as jnp
 from .walked import (F32, PagePool, ServedConfig, WalkedModel, at,
                      decode_index, default_scale, dense_ffn, draw_layers,
                      expand_latents, held_expert_counters,
-                     latent_context_attention, latent_projections,
-                     latent_rows, lm_head, merge_heads, prefill_index,
-                     project_heads, rms_norm, rope, routed_experts,
-                     shared_expert, stacked_experts, whole_tiles)
+                     latent_context_attention, latent_context_pairs,
+                     latent_projections, latent_rows, lm_head, merge_heads,
+                     prefill_index, project_heads, rms_norm, rope,
+                     routed_experts, shared_expert, stacked_experts,
+                     whole_tiles)
 
 #: cached keys a step of :func:`_chunk_index_scores` scores (whole pages)
 _SCORE_BLOCK = 512
@@ -254,7 +260,8 @@ class GlmDsaModel(WalkedModel):
     #: they do: a request keeps pages and nothing else, and both arrays
     #: go by the same page ids)
     serving_aux = WalkedModel.serving_aux + (
-        "latent_kv_tokens", "index_scored_rows", "index_selected_rows")
+        "latent_kv_tokens", "index_scored_rows", "index_selected_rows",
+        "latent_context_rows", "latent_context_pairs")
 
     def serving_cache_layers(self) -> Dict[str, int]:
         """Layers by the kind of cache they keep."""
@@ -462,7 +469,10 @@ class GlmDsaModel(WalkedModel):
         pool = PagePool((k_pool,), page_ids, offs, valid)
         index = PagePool((index_pool,), page_ids, offs, valid)
         context_len = prefix_len + delta_len
-        picked = []                     # a full layer's mask, newest last
+        # a padding row sees no key: whole blocks of them are skipped
+        q_pos = jnp.where(valid, abs_pos, -1)
+        picked = []     # a full layer's (mask, pairs it lets through)
+        pairs = []      # a layer's, as its full layer counted them
 
         def attend(layer, ap, ip, h, lat):
             c_q, q_nope, q_rope, c_kv, k_rope = lat
@@ -473,23 +483,35 @@ class GlmDsaModel(WalkedModel):
                     q_i, k_i, w = _index_projections(cfg, ip, h, c_q,
                                                      positions)
                     index.write(full, k_i[0])
-                    picked.append(_chunk_picks(
+                    mask = _chunk_picks(
                         q_i[0], w[0],
                         index.rows[0].reshape(-1, page_len,
                                               cfg.index_head_dim),
                         full * index.per_layer + page_row, abs_pos,
-                        context_len, cfg.index_topk))
+                        context_len, cfg.index_topk)
+                count = None
+                if aux:
+                    # counted before the mask is used: else the old mask
+                    # lives on beside the next until its sum is taken
+                    mask, count = jax.lax.optimization_barrier((
+                        mask, latent_context_pairs(q_pos, context_len,
+                                                   mask)))
+                picked.append((mask, count))
+            pairs.append(picked[-1][1])
             return latent_context_attention(
                 ap, q_nope[0], q_rope[0],
                 pool.rows[0].reshape(-1, page_len, width),
-                layer * pool.per_layer + page_row, abs_pos, context_len,
+                layer * pool.per_layer + page_row, q_pos, context_len,
                 kv_rank=cfg.kv_lora_rank,
                 sm_scale=default_scale(cfg.qk_head_dim),
-                allowed=picked[-1])[None]
+                allowed=picked[-1][0])[None]
 
         logits, stats = _layers(cfg, params, tokens, positions, valid, attend)
         out = (logits, *pool.arrays(), None, *index.arrays())
-        return out + (_aux(cfg, stats),) if aux else out
+        if aux:
+            out += (_aux(cfg, stats, context_rows=context_len * cfg.n_layer,
+                         context_pairs=sum(pairs)),)
+        return out
 
 
 # -- the layer's parts ----------------------------------------------------
@@ -640,18 +662,25 @@ def _ffn(cfg: GlmDsaConfig, params, stacked, layer: int, x, valid, stats):
 
 
 def _aux(cfg: GlmDsaConfig, stats, latent_kv_tokens=0, scored=0,
-         selected=0) -> Dict[str, jnp.ndarray]:
+         selected=0, context_rows=0,
+         context_pairs=0) -> Dict[str, jnp.ndarray]:
     """The call's counters: the expert layers' (of the HELD experts);
     ``latent_kv_tokens``: the LIVE rows of the slots' contexts summed
     over layers; ``index_scored_rows``: the keys the indexer scored,
     summed over the ``full`` layers; ``index_selected_rows``: the rows
     the attention attended (the picked ones, of the ``latent_kv_tokens``
     the tick's kernel read to attend them), summed over layers (all 0 in
-    a prefill)."""
+    a prefill); and of a prefill (0 in a tick) ``latent_context_rows``:
+    the context's rows ``ds_latent_context_attn`` walked, summed over
+    layers, and ``latent_context_pairs``: the (query, key) pairs its masks
+    let through a head (``walked.latent_context_pairs``), summed over
+    layers, float32."""
     return {**held_expert_counters(stats, cfg.held[1]),
             "latent_kv_tokens": jnp.asarray(latent_kv_tokens, jnp.int32),
             "index_scored_rows": jnp.asarray(scored, jnp.int32),
-            "index_selected_rows": jnp.asarray(selected, jnp.int32)}
+            "index_selected_rows": jnp.asarray(selected, jnp.int32),
+            "latent_context_rows": jnp.asarray(context_rows, jnp.int32),
+            "latent_context_pairs": jnp.asarray(context_pairs, F32)}
 
 
 def _layers(cfg: GlmDsaConfig, params, tokens, positions, valid, attend):

@@ -208,8 +208,6 @@ def lm_head(x, norm_w, head_w, eps: float):
 # -- latent attention (MLA): what its families share --------------------------
 
 _LANES = 128
-#: keys a step of :func:`latent_context_attention` expands (whole pages)
-_CONTEXT_BLOCK = 256
 
 
 def whole_tiles(width: int) -> int:
@@ -259,56 +257,53 @@ def latent_context_attention(ap, q_nope, q_rope, pool_pages, page_ids,
                              sm_scale: float, allowed=None):
     """The expanded form for a prefill whose context is in the pages (a
     prefix hit, a chunk; the delta's own rows already written): queries
-    q_* [H, Tq, .] at absolute positions ``abs_pos`` [Tq] against the
-    request's latent rows READ BACK from the pool (``pool_pages`` [X,
-    page_len, width]; ``page_ids`` [max_pages] the request's pages of this
-    layer) up to ``context_len`` (traced).  ``allowed`` [Tq, max_pages *
-    page_len] bool: the keys a query may see beside the causal rule
-    (learned sparse attention); None: all.  A block of whole pages at a
-    time: its rows expanded to every head's keys and values, float32
-    scores, an online softmax; nothing the size of the context is ever
-    held.  Returns [H, Tq, v_head_dim]."""
-    H, Tq, _ = q_nope.shape
-    rot, dv = q_rope.shape[-1], ap["v_b_w"].shape[-1]
-    page_len = pool_pages.shape[1]
-    ppb = max(1, _CONTEXT_BLOCK // page_len)
-    bk = ppb * page_len
-    ids = jnp.pad(page_ids, (0, (-page_ids.shape[0]) % ppb))
-    if allowed is not None:
-        allowed = jnp.pad(allowed, ((0, 0),
-                                    (0, ids.shape[0] * page_len
-                                     - allowed.shape[1])))
-    C, dt = kv_rank, q_nope.dtype
-    floor = jnp.finfo(F32).min
+    q_* [H, Tq, .] at absolute positions ``abs_pos`` [Tq] (below 0: a row
+    that sees nothing) against the request's latent rows READ WHERE THEY
+    LIE in the pool (``pool_pages`` [X, page_len, width]; ``page_ids``
+    [max_pages] the request's pages of this layer) up to ``context_len``
+    (traced).  ``allowed`` [Tq, max_pages * page_len] bool: the keys a
+    query may see beside the causal rule (learned sparse attention); None:
+    all.  One kernel, ``ds_latent_context_attn``
+    (``ops/pallas/context_attention.py``): a block of whole pages at a
+    time expanded to a group of heads' keys and values, float32 scores and
+    an online softmax that stay in VMEM; nothing the size of the context
+    is ever held.  Returns [H, Tq, v_head_dim]; a query with no key to see
+    gives zeros."""
+    from ..ops.pallas.context_attention import \
+        latent_context_attention as attend
+    H, _, nope = q_nope.shape
+    rot, width, dt = q_rope.shape[-1], pool_pages.shape[-1], q_nope.dtype
+    dq = whole_tiles(nope + rot)
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    q = jnp.pad(q, ((0, 0), (0, 0), (0, dq - nope - rot)))
+    # a row at rest [c_kv ; k_rope ; 0] -> a head's key [k_nope ; k_rope ;
+    # 0] in ONE matmul: W_UK over the latent's lanes, the identity over
+    # the rotated ones (exact: each is one product by 1.0; the sum below
+    # adds zeros, inside the pad's own pass).  Built when the
+    # layer's queries are (the barrier), as the positions' column is: the
+    # compiler otherwise lays out every layer's ahead of the first and
+    # holds them all
+    k_b_w, q, abs_pos = jax.lax.optimization_barrier(
+        (ap["k_b_w"], q, abs_pos))
+    passed = jnp.pad(jnp.eye(rot, dq, k=nope, dtype=dt),
+                     ((kv_rank, width - kv_rank - rot), (0, 0)))
+    k_w = passed + jnp.pad(jnp.swapaxes(k_b_w.astype(dt), 1, 2), (
+        (0, 0), (0, width - kv_rank), (0, dq - nope)))
+    return attend(q, k_w, ap["v_b_w"], pool_pages, page_ids, abs_pos,
+                  context_len, sm_scale=sm_scale, allowed=allowed)
 
-    def block(j, carry):
-        m, l, acc = carry
-        rows = pool_pages[jax.lax.dynamic_slice_in_dim(
-            ids, j * ppb, ppb)].reshape(bk, -1)
-        k_nope, v = expand_latents(ap, rows[:, :C], dt)
-        s = (jnp.einsum("htn,hkn->htk", q_nope, k_nope,
-                        preferred_element_type=F32)
-             + jnp.einsum("htr,kr->htk", q_rope,
-                          rows[:, C:C + rot].astype(dt),
-                          preferred_element_type=F32)) * sm_scale
-        at = j * bk + jnp.arange(bk, dtype=jnp.int32)
-        ok = at[None, :] <= abs_pos[:, None]
-        if allowed is not None:
-            ok &= jax.lax.dynamic_slice_in_dim(allowed, j * bk, bk, axis=1)
-        ok = ok[None]
-        s = jnp.where(ok, s, floor)
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-        p = jnp.where(ok, jnp.exp(s - m_new[..., None]), 0.0)
-        alpha = jnp.exp(m - m_new)
-        acc = acc * alpha[..., None] + jnp.einsum(
-            "htk,hkv->htv", p.astype(dt), v, preferred_element_type=F32)
-        return m_new, alpha * l + jnp.sum(p, axis=-1), acc
 
-    _, l, acc = jax.lax.fori_loop(
-        0, (context_len + bk - 1) // bk, block,
-        (jnp.full((H, Tq), floor, F32), jnp.zeros((H, Tq), F32),
-         jnp.zeros((H, Tq, dv), F32)))
-    return (acc / jnp.where(l == 0.0, 1.0, l)[..., None]).astype(dt)
+def latent_context_pairs(abs_pos, context_len, allowed=None):
+    """The (query, key) pairs one call of :func:`latent_context_attention`
+    lets through a head, float32 (a chunk of 2,048 over 16,384 keys passes
+    2**24): a query at ``abs_pos`` sees the positions up to its own and
+    below ``context_len``, of those ``allowed`` names where given."""
+    if allowed is None:
+        return jnp.sum(jnp.clip(jnp.minimum(abs_pos + 1, context_len), 0)
+                       .astype(F32))
+    at = jnp.arange(allowed.shape[1], dtype=jnp.int32)[None, :]
+    return jnp.sum(allowed & (at <= abs_pos[:, None]) & (at < context_len),
+                   dtype=F32)
 
 
 @jax.named_scope("shared_expert")

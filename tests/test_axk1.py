@@ -374,6 +374,46 @@ def test_paged_steps_against_the_reference_logits(attn_impl, chunks):
     assert np.abs(off[27:] - want[27:]).max() > 100 * F32_TOL
 
 
+@pytest.mark.parametrize("chunks", [(27,), (16, 11), (8, 8, 11)],
+                         ids=["whole", "prefix_hit", "chunked"])
+def test_a_prefill_counts_the_rows_its_kernel_walked_and_the_pairs_it_let(
+        chunks):
+    """A prefill's counters against counts made in numpy: from nothing
+    (``ds_flash_fwd`` over the prompt's own keys) the context kernel does
+    not run and both read 0; after a prefix the context's rows
+    ``ds_latent_context_attn`` walked, a layer each, and the (query, key)
+    pairs the causal rule let through a head (a query at position p sees p
+    + 1 keys, a padding row of the bucket none), a layer each, float32;
+    ``latent_kv_tokens`` stays the tick's."""
+    cfg = dataclasses.replace(TINY, attn_impl="flash")
+    model, params = AxK1Model(cfg), _params(cfg)
+    prompt = _tokens((27,), 4)
+    spec = PagedKVCacheSpec(
+        layers=cfg.n_layer, slots=1, heads=cfg.n_kv_head, pages=13,
+        page_len=8, head_dim=cfg.d_head, max_pages=12, dtype=jnp.float32,
+        v_head_dim=cfg.d_head_v, values_in_keys=cfg.values_in_keys)
+    pool = init_paged_cache(spec)["k"]
+    row = np.zeros((12,), np.int32)
+    row[:4] = 1 + np.arange(4)
+    prefill = jax.jit(lambda *a: model.prefill_paged(*a, None, aux=True))
+    done = 0
+    from deepspeed_tpu.ops.pallas.runtime import interpret_scope
+    with interpret_scope(True):
+        for n in chunks:
+            padded = np.zeros((1, 32), np.int32)
+            padded[0, :n] = prompt[done:done + n]
+            _, pool, _, aux = prefill(
+                params, padded, np.int32(n), np.int32(done), row, pool)
+            paged = cfg.n_layer * (done > 0)
+            done += n
+            assert aux["latent_context_rows"].dtype == jnp.int32
+            assert int(aux["latent_context_rows"]) == paged * done
+            assert aux["latent_context_pairs"].dtype == jnp.float32
+            assert float(aux["latent_context_pairs"]) \
+                == paged * (1 + np.arange(done - n, done)).sum()
+            assert int(aux["latent_kv_tokens"]) == 0
+
+
 @pytest.mark.parametrize("serving", [
     {}, {"prefix_cache": True}, {"prefill_chunk_len": 8}],
     ids=["plain", "prefix_cache", "chunked"])

@@ -967,9 +967,13 @@ def test_axk1_programs_hold_their_kernels_and_one_pool(program, one_chip):
     pool (6.04 GB) passes through aliased to the output and nothing of
     its size is a temporary; the arguments are the weights and that pool
     and no second array of latents; the compiler's own counts are the
-    ones the configuration's ``reduced_why`` states; all the chip must
+    ones the configuration's ``reduced_why`` states (but the prefill's
+    temporaries, which it states as the XLA loop's, 1.091 GB: a
+    ``benchmark`` PR's to edit, ``PERF.md`` section 7); all the chip must
     hold at once fits its 16.91e9 bytes."""
     from deepspeed_tpu.moe import dropless
+    from deepspeed_tpu.ops.pallas.context_attention import \
+        LATENT_CONTEXT_ATTN_KERNEL
     from deepspeed_tpu.ops.pallas.decode_attention import \
         LATENT_DECODE_ATTN_KERNEL
     compiled = _axk1_program(program, one_chip)
@@ -977,22 +981,23 @@ def test_axk1_programs_hold_their_kernels_and_one_pool(program, one_chip):
     experts = {dropless.MOE_GATE_UP_KERNEL, dropless.MOE_DOWN_KERNEL}
     assert names == experts | ({LATENT_DECODE_ATTN_KERNEL}
                                if program == "serve_decode"
-                               else {"ds_flash_fwd"}), names
+                               else {"ds_flash_fwd",
+                                     LATENT_CONTEXT_ATTN_KERNEL}), names
     mem = compiled.memory_analysis()
     pool = 6 * AXK1_PAGES * AXK1_PAGE_LEN * 640 * 2
     assert mem.alias_size_in_bytes >= pool
     weights = sum(a.size * a.dtype.itemsize
                   for a in jax.tree.leaves(compiled.in_avals[0][0]))
     assert abs(mem.argument_size_in_bytes - weights - pool) < 1 << 20
-    limit = 0.06e9 if program == "serve_decode" else 1.2e9
+    limit = 0.06e9 if program == "serve_decode" else 1.1e9
     assert mem.temp_size_in_bytes < limit, mem.temp_size_in_bytes
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.6e9
     _, file = _axk1_model()
-    said = {"serve_decode": "temporaries %.3f GB (decode)",
-            "serve_prefill": "%.3f GB (prefill"}[program]
     assert "arguments %.3f GB" % (mem.argument_size_in_bytes / 1e9) \
         in file["reduced_why"]
-    assert said % (mem.temp_size_in_bytes / 1e9) in file["reduced_why"]
+    if program == "serve_decode":
+        assert "temporaries %.3f GB (decode)" \
+            % (mem.temp_size_in_bytes / 1e9) in file["reduced_why"]
 
 
 def test_axk1_decode_tick_reads_each_layers_matrices_where_they_lie(
@@ -1097,6 +1102,39 @@ def test_sparse_kernel_reads_a_context_under_the_picks_mask(one_chip):
         < 2 * GLM_SLOTS * cap * 4
 
 
+@pytest.mark.parametrize("rung", [2048, 1024])
+def test_context_kernel_walks_a_chunks_context_where_it_lies(rung, one_chip):
+    """A prefill rung's queries (64 heads of 192 + 64 as the keys are laid
+    out, 256 lanes) against a request's 384 pages of 64 rows 640 wide
+    under the picks' mask ``[rung, 24,576]``: 16 pages (1,024 keys) a grid
+    step and as many heads as ``CONTEXT_VMEM_BUDGET`` allows, the step's
+    VMEM and the body's allowance inside what a core has beside the
+    compiler's own 24 MiB; the pool stays in HBM and the one temporary of
+    size is the mask as the kernel reads it (int8)."""
+    from deepspeed_tpu.ops.pallas import context_attention as ca
+    assert ca.LATENT_CONTEXT_ATTN_KERNEL == "ds_latent_context_attn"
+    cap = GLM_MAX_PAGES * GLM_PAGE_LEN
+    shape = (rung, 256, 256, 640, 512, 1024, 2, True)
+    heads = ca.context_heads_per_step(64, ca.CONTEXT_VMEM_BUDGET, *shape)
+    assert heads == {2048: 4, 1024: 8}[rung]
+    assert ca.context_vmem_bytes(heads, *shape) + ca._BODY_VMEM \
+        <= (128 - 24) << 20
+    compiled = _compile(
+        lambda q, k_w, v_w, pool, ids, pos, n, allowed:
+        ca.latent_context_attention(q, k_w, v_w, pool, ids, pos, n,
+                                    sm_scale=0.0625, allowed=allowed,
+                                    interpret=False),
+        one_chip, _sds((64, rung, 256)), _sds((64, 640, 256)),
+        _sds((64, 512, 256)), _sds((7 * GLM_PAGES, GLM_PAGE_LEN, 640)),
+        _sds((GLM_MAX_PAGES,), jnp.int32), _sds((rung,), jnp.int32),
+        _sds((), jnp.int32), _sds((rung, cap), jnp.bool_))
+    names = _kernel_names(compiled)
+    assert [n.split(".")[0] for n in names] == [ca.LATENT_CONTEXT_ATTN_KERNEL]
+    print("context kernel temporaries", rung,
+          compiled.memory_analysis().temp_size_in_bytes)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * rung * cap
+
+
 @functools.lru_cache(maxsize=None)
 def _glm_program(program, one_chip, bucket=2048):
     """The model's paged step as the engine calls it: both arrays donated,
@@ -1137,12 +1175,16 @@ def test_glm_programs_hold_their_kernels_and_both_arrays(program, one_chip):
     paged arrays (4.346 GB) pass through aliased to the outputs and nothing
     of their size is a temporary; the arguments are the weights and those
     arrays; the compiler's own counts are the ones the configuration's
-    ``reduced_why`` states (but the tick's temporaries, which it states as
-    the gathering tick of PR 49 had them, 0.123 GB: a ``benchmark`` PR's to
-    edit, ``PERF.md`` section 7); all the chip must hold at once fits its
-    16.91e9 bytes."""
+    ``reduced_why`` states (but the temporaries: it states the tick's as
+    the gathering tick of PR 49 had them, 0.123 GB, and the prefill's as
+    the XLA loop's, 0.543: a ``benchmark`` PR's to edit, ``PERF.md``
+    section 7); all the chip must hold at once fits its 16.91e9 bytes.  The
+    prefill's are the expert layer's (a rung's rows gathered for 8 experts
+    each, 0.2 GB twice) and the picks' mask, not the attention's."""
     from deepspeed_tpu.utils.hlo import parameter_rewrites
     from deepspeed_tpu.moe import dropless
+    from deepspeed_tpu.ops.pallas.context_attention import \
+        LATENT_CONTEXT_ATTN_KERNEL
     from deepspeed_tpu.ops.pallas.decode_attention import (
         INDEX_SCORE_KERNEL, SPARSE_LATENT_DECODE_ATTN_KERNEL)
     compiled = _glm_program(program, one_chip)
@@ -1151,7 +1193,7 @@ def test_glm_programs_hold_their_kernels_and_both_arrays(program, one_chip):
     assert names == experts | ({INDEX_SCORE_KERNEL,
                                 SPARSE_LATENT_DECODE_ATTN_KERNEL}
                                if program == "serve_decode"
-                               else set()), names
+                               else {LATENT_CONTEXT_ATTN_KERNEL}), names
     mem = compiled.memory_analysis()
     arrays = GLM_PAGES * GLM_PAGE_LEN * (7 * 640 + 2 * 128) * 2
     assert mem.alias_size_in_bytes >= arrays
@@ -1164,9 +1206,7 @@ def test_glm_programs_hold_their_kernels_and_both_arrays(program, one_chip):
     assert "arguments %.3f GB" % (mem.argument_size_in_bytes / 1e9) \
         in file["reduced_why"]
     if program == "serve_prefill":
-        assert mem.temp_size_in_bytes < 0.6e9, mem.temp_size_in_bytes
-        assert "%.3f GB (prefill" % (mem.temp_size_in_bytes / 1e9) \
-            in file["reduced_why"]
+        assert mem.temp_size_in_bytes < 0.56e9, mem.temp_size_in_bytes
         return
     assert mem.temp_size_in_bytes < 0.03e9, mem.temp_size_in_bytes
     # a layer of the indexer's keys is 0.117 GB and one of rows 0.587: no

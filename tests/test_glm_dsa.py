@@ -324,6 +324,49 @@ def test_paged_steps_and_their_picks_against_the_reference(attn_impl,
             assert np.abs(off[n:] - want[0, n:]).max() > 10 * F32_TOL
 
 
+@pytest.mark.parametrize("chunks", [(27,), (16, 11), (8, 8, 11)],
+                         ids=["whole", "two_chunks", "three_chunks"])
+def test_a_prefill_counts_the_rows_its_kernel_walked_and_the_pairs_it_let(
+        chunks):
+    """A prefill's counters against counts made in numpy: the context's
+    rows ``ds_latent_context_attn`` walked, a layer each; the (query, key)
+    pairs its masks let through a head (a query at position p sees the
+    ``min(index_topk, p + 1)`` keys its full layer picked, a padding row of
+    the bucket none), a layer each, float32; and what the tick's kernels
+    count stays 0 (the benchmark divides by ``latent_kv_tokens``)."""
+    cfg = dataclasses.replace(TINY, attn_impl="flash")
+    model, params = GlmDsaModel(cfg), _params(cfg)
+    prompt = _tokens((sum(chunks),), 4)
+    spec = PagedKVCacheSpec(
+        layers=cfg.n_layer, slots=1, heads=1, pages=13, page_len=8,
+        head_dim=cfg.d_head, max_pages=12, dtype=jnp.float32,
+        v_head_dim=cfg.d_head_v, values_in_keys=True,
+        index_layers=cfg.n_index_layer, index_dim=cfg.d_index)
+    cache = init_paged_cache(spec)
+    pool, keys = cache["k"], cache["index_k"]
+    row = np.zeros((12,), np.int32)
+    row[:4] = 1 + np.arange(4)
+    prefill = jax.jit(lambda p, t, n, pre, r, k, ik: model.prefill_paged(
+        p, t, n, pre, r, k, None, index_pool=ik, aux=True))
+    done = 0
+    with interpret_scope(True):
+        for n in chunks:
+            padded = np.zeros((1, 32), np.int32)
+            padded[0, :n] = prompt[done:done + n]
+            _, pool, _, keys, aux = prefill(
+                params, padded, np.int32(n), np.int32(done), row, pool, keys)
+            done += n
+            assert aux["latent_context_rows"].dtype == jnp.int32
+            assert int(aux["latent_context_rows"]) == cfg.n_layer * done
+            seen = np.minimum(TOPK, 1 + np.arange(done - n, done))
+            assert aux["latent_context_pairs"].dtype == jnp.float32
+            assert float(aux["latent_context_pairs"]) \
+                == cfg.n_layer * seen.sum()
+            assert [int(aux[k]) for k in (
+                "latent_kv_tokens", "index_scored_rows",
+                "index_selected_rows")] == [0, 0, 0]
+
+
 def test_a_shared_layer_of_the_tick_takes_its_full_layers_mask(monkeypatch):
     """The tick, traced: layers 1-2 read under the very mask layer
     0 made and layer 4 under layer 3's; the two masks are two selections."""

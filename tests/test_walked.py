@@ -114,54 +114,195 @@ def test_a_row_that_is_not_kept_is_written_back_as_it_was():
     np.testing.assert_array_equal(got, want)
 
 
-@pytest.mark.parametrize("masked", [False, True], ids=["causal", "allowed"])
-def test_latent_context_attention_reads_the_pages_under_a_mask(masked):
-    """Queries at positions 20..29 over 30 cached rows on pages out of
-    order, two blocks of pages: the blocked online softmax is the dense
-    one; ``allowed`` (learned sparse attention's picks) takes keys out
-    beside the causal rule, and all-true is None."""
-    rng = np.random.default_rng(0)
-    H, nope, rot, C, dv, page_len, Tq = 2, 8, 4, 16, 8, 8, 10
+def _latent_case(rng, *, H=2, nope=8, rot=4, C=16, dv=8, page_len=8,
+                 pad=4, keys=30, page_ids=(5, 2, 7, 1, 0, 0, 0, 0)):
+    """Weights, ``keys`` cached rows on the first pages of ``page_ids``
+    (out of order in the pool, ``pad`` lanes of zeros after the rope's),
+    and what lies past them in the last page LARGE: a key the masks let
+    through by mistake shows."""
     ap = {"k_b_w": jnp.asarray(rng.normal(size=(H, nope, C)), jnp.float32),
           "v_b_w": jnp.asarray(rng.normal(size=(H, C, dv)), jnp.float32)}
-    width = walked.whole_tiles(C + rot)
-    assert (width, walked.whole_tiles(576), walked.whole_tiles(128)) \
-        == (20, 640, 128)
-    c_kv = jnp.asarray(rng.normal(size=(30, C)), jnp.float32)
-    k_rope = jnp.asarray(rng.normal(size=(30, rot)), jnp.float32)
-    rows = walked.latent_rows(c_kv, k_rope, width + 4)   # 4 lanes of zeros
-    assert rows.shape == (30, 24) and not np.asarray(rows[:, 20:]).any()
-    page_ids = np.array([5, 2, 7, 1, 0, 0, 0, 0], np.int32)
-    pool = np.zeros((9, page_len, 24), np.float32)
-    padded = np.concatenate([np.asarray(rows), np.zeros((2, 24))])
-    pool[page_ids[:4]] = padded.reshape(4, page_len, 24)
-    q_nope = jnp.asarray(rng.normal(size=(H, Tq, nope)), jnp.float32)
-    q_rope = jnp.asarray(rng.normal(size=(H, Tq, rot)), jnp.float32)
+    width = walked.whole_tiles(C + rot) + pad
+    c_kv = jnp.asarray(rng.normal(size=(keys, C)), jnp.float32)
+    k_rope = jnp.asarray(rng.normal(size=(keys, rot)), jnp.float32)
+    rows = np.asarray(walked.latent_rows(c_kv, k_rope, width))
+    assert not rows[:, C + rot:].any()
+    page_ids = np.asarray(page_ids, np.int32)
+    used = -(-keys // page_len)
+    padded = np.full((used * page_len, width), 50.0, np.float32)
+    padded[:keys] = rows
+    pool = np.zeros((page_ids.max() + 2, page_len, width), np.float32)
+    pool[page_ids[:used]] = padded.reshape(used, page_len, width)
+    return ap, c_kv, k_rope, jnp.asarray(pool), page_ids
+
+
+def _dense_latent_attention(ap, q_nope, q_rope, c_kv, k_rope, ok, scale):
+    """The float32 formula over the cached rows; ``ok`` [Tq, keys]; a
+    query with no key gives zeros."""
+    k_nope, v = walked.expand_latents(ap, c_kv, jnp.float32)
+    s = (jnp.einsum("htn,hkn->htk", q_nope, k_nope)
+         + jnp.einsum("htr,kr->htk", q_rope, k_rope)) * scale
+    p = np.asarray(jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))) * ok[None]
+    total = p.sum(-1, keepdims=True)
+    return np.einsum("htk,hkv->htv", p / np.where(total == 0, 1, total), v)
+
+
+def _with_blocks(monkeypatch, block_q, block_k):
+    """``walked.latent_context_attention`` through the kernel at these
+    block sizes (its own are a chip's: one block at the tests' sizes)."""
+    import functools
+    from deepspeed_tpu.ops.pallas import context_attention
+    monkeypatch.setattr(
+        context_attention, "latent_context_attention", functools.partial(
+            context_attention.latent_context_attention, block_q=block_q,
+            block_k=block_k))
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["causal", "allowed"])
+def test_latent_context_attention_reads_the_pages_under_a_mask(masked,
+                                                               monkeypatch):
+    """Queries at positions 20..29 over 30 cached rows on pages out of
+    order, two key blocks of two pages: the kernel's blocked online
+    softmax is the dense one; ``allowed`` (learned sparse attention's
+    picks) takes keys out beside the causal rule, and all-true is None.
+    The context ends inside the second key block, what the page holds
+    after it is never seen."""
+    rng = np.random.default_rng(0)
+    C, Tq = 16, 10
+    assert (walked.whole_tiles(C + 4), walked.whole_tiles(576),
+            walked.whole_tiles(128)) == (20, 640, 128)
+    ap, c_kv, k_rope, pool, page_ids = _latent_case(rng)
+    assert pool.shape == (9, 8, 24)
+    q_nope = jnp.asarray(rng.normal(size=(2, Tq, 8)), jnp.float32)
+    q_rope = jnp.asarray(rng.normal(size=(2, Tq, 4)), jnp.float32)
     abs_pos = 20 + jnp.arange(Tq, dtype=jnp.int32)
     allowed = rng.random((Tq, 64)) < 0.6
     allowed[np.arange(Tq), 20 + np.arange(Tq)] = True     # its own key
 
-    def attend(mask, block):
-        walked._CONTEXT_BLOCK, was = block, walked._CONTEXT_BLOCK
-        try:
-            return np.asarray(walked.latent_context_attention(
-                ap, q_nope, q_rope, jnp.asarray(pool), page_ids, abs_pos,
-                jnp.int32(30), kv_rank=C, sm_scale=0.3,
-                allowed=None if mask is None else jnp.asarray(mask)))
-        finally:
-            walked._CONTEXT_BLOCK = was
+    def attend(mask):
+        return np.asarray(walked.latent_context_attention(
+            ap, q_nope, q_rope, pool, page_ids, abs_pos, jnp.int32(30),
+            kv_rank=C, sm_scale=0.3,
+            allowed=None if mask is None else jnp.asarray(mask)))
 
-    got = attend(allowed if masked else None, 16)
-    k_nope, v = walked.expand_latents(ap, c_kv, jnp.float32)
-    s = (jnp.einsum("htn,hkn->htk", q_nope, k_nope)
-         + jnp.einsum("htr,kr->htk", q_rope, k_rope)) * 0.3
+    whole = attend(allowed if masked else None)          # one block of all
+    _with_blocks(monkeypatch, 32, 16)
+    got = attend(allowed if masked else None)
     ok = np.arange(30)[None, :] <= np.asarray(abs_pos)[:, None]
     if masked:
         ok &= allowed[:, :30]
-    p = np.asarray(jnp.exp(s)) * ok[None]
-    want = np.einsum("htk,hkv->htv", p / p.sum(-1, keepdims=True), v)
+    want = _dense_latent_attention(ap, q_nope, q_rope, c_kv, k_rope, ok, 0.3)
     np.testing.assert_allclose(got, want, atol=1e-5)
+    np.testing.assert_allclose(whole, got, atol=1e-5)
     if not masked:
-        np.testing.assert_array_equal(
-            attend(np.ones((Tq, 64), bool), 16), got)
-        np.testing.assert_allclose(attend(None, 32), got, atol=1e-5)
+        np.testing.assert_array_equal(attend(np.ones((Tq, 64), bool)), got)
+
+
+def test_latent_context_attention_gives_zeros_to_a_row_that_sees_no_key(
+        monkeypatch):
+    """A query whose mask names no key under the causal rule, one at a
+    position below 0 (a bucket's padding row) and one whose only allowed
+    keys lie past the context: exact zeros, the rows beside them whole."""
+    rng = np.random.default_rng(1)
+    C, Tq = 16, 6
+    ap, c_kv, k_rope, pool, page_ids = _latent_case(rng)
+    q_nope = jnp.asarray(rng.normal(size=(2, Tq, 8)), jnp.float32)
+    q_rope = jnp.asarray(rng.normal(size=(2, Tq, 4)), jnp.float32)
+    abs_pos = np.array([24, 25, -1, 27, 28, 31], np.int32)
+    allowed = rng.random((Tq, 64)) < 0.5
+    allowed[0, :] = False
+    allowed[1, :26] = False             # only keys after its own
+    allowed[5, :30] = False             # only keys past the context
+    allowed[5, 30:] = True
+    _with_blocks(monkeypatch, 32, 16)
+    got = np.asarray(walked.latent_context_attention(
+        ap, q_nope, q_rope, pool, page_ids, jnp.asarray(abs_pos),
+        jnp.int32(30), kv_rank=C, sm_scale=0.3, allowed=jnp.asarray(allowed)))
+    assert not got[:, [0, 1, 2, 5]].any()
+    ok = (np.arange(30)[None, :] <= abs_pos[:, None]) & allowed[:, :30]
+    want = _dense_latent_attention(ap, q_nope, q_rope, c_kv, k_rope, ok, 0.3)
+    assert np.abs(want[:, [3, 4]]).min() > 0
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    # and the same rows counted: the pairs the masks let through a head
+    assert float(walked.latent_context_pairs(
+        jnp.asarray(abs_pos), jnp.int32(30), jnp.asarray(allowed))) \
+        == ok.sum()
+    assert float(walked.latent_context_pairs(
+        jnp.asarray(abs_pos), jnp.int32(30))) \
+        == np.clip(np.minimum(abs_pos + 1, 30), 0, None).sum()
+
+
+@pytest.mark.parametrize("masked", [False, True], ids=["causal", "allowed"])
+def test_latent_context_attention_skips_the_blocks_above_the_diagonal(
+        masked, monkeypatch):
+    """A whole prompt of 64 from position 0, two query blocks of 32 over
+    four key blocks of 16 on pages out of order: the first query block
+    never reaches key blocks 2 and 3 (its running max and sum stay what
+    they were), every row is the dense one."""
+    rng = np.random.default_rng(2)
+    C, Tq = 16, 64
+    ap, c_kv, k_rope, pool, page_ids = _latent_case(
+        rng, keys=64, page_ids=(6, 1, 8, 3, 5, 2, 7, 4))
+    q_nope = jnp.asarray(rng.normal(size=(2, Tq, 8)), jnp.float32)
+    q_rope = jnp.asarray(rng.normal(size=(2, Tq, 4)), jnp.float32)
+    abs_pos = jnp.arange(Tq, dtype=jnp.int32)
+    ok = np.tril(np.ones((Tq, Tq), bool))
+    allowed = None
+    if masked:
+        allowed = rng.random((Tq, 64)) < 0.3
+        allowed[np.arange(Tq), np.arange(Tq)] = True
+        ok &= allowed
+        allowed = jnp.asarray(allowed)
+    _with_blocks(monkeypatch, 32, 16)
+    got = np.asarray(walked.latent_context_attention(
+        ap, q_nope, q_rope, pool, page_ids, abs_pos, jnp.int32(Tq),
+        kv_rank=C, sm_scale=0.3, allowed=allowed))
+    want = _dense_latent_attention(ap, q_nope, q_rope, c_kv, k_rope, ok, 0.3)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+def test_latent_context_attention_at_axk1s_widths_without_a_mask(
+        monkeypatch):
+    """A.X-K1's widths (heads of 128 + 64 against rows 640 wide of rank
+    512, values 128 wide; the keys' 192 lanes padded to 256), four heads in
+    groups the VMEM budget makes two of, bfloat16, no mask: the kernel is
+    its module's own dense reference, and the float32 formula to
+    bfloat16's rounding."""
+    from deepspeed_tpu.ops.pallas import context_attention
+    rng = np.random.default_rng(3)
+    H, nope, rot, C, dv, Tq, keys = 4, 128, 64, 512, 128, 24, 40
+    ap, c_kv, k_rope, pool, page_ids = _latent_case(
+        rng, H=H, nope=nope, rot=rot, C=C, dv=dv, pad=0, keys=keys)
+    bf = jnp.bfloat16
+    ap = {k: (v * 0.05).astype(bf) for k, v in ap.items()}
+    pool = pool.astype(bf)
+    assert pool.shape[-1] == 640
+    q_nope = jnp.asarray(rng.normal(size=(H, Tq, nope)), bf)
+    q_rope = jnp.asarray(rng.normal(size=(H, Tq, rot)), bf)
+    abs_pos = 16 + jnp.arange(Tq, dtype=jnp.int32)
+    seen = {}
+    kernel = context_attention.latent_context_attention
+
+    def spy(q, k_w, v_w, *args, **kw):
+        seen.update(q=q, k_w=k_w, v_w=v_w, args=args, kw=kw)
+        return kernel(q, k_w, v_w, *args, block_q=32, block_k=16, **kw)
+
+    monkeypatch.setattr(context_attention, "latent_context_attention", spy)
+    monkeypatch.setattr(context_attention, "CONTEXT_VMEM_BUDGET", 3 << 20)
+    shape = (32, 256, dv, 640, C, 16, 2, False)
+    assert context_attention.context_heads_per_step(H, 3 << 20, *shape) == 2
+    got = walked.latent_context_attention(
+        ap, q_nope, q_rope, pool, page_ids, abs_pos, jnp.int32(keys),
+        kv_rank=C, sm_scale=0.07)
+    assert seen["q"].shape == (H, Tq, 256) and seen["k_w"].shape \
+        == (H, 640, 256)
+    want = context_attention.latent_context_reference(
+        seen["q"], seen["k_w"], seen["v_w"], *seen["args"], **seen["kw"])
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=2e-2)
+    ok = np.arange(keys)[None, :] <= np.asarray(abs_pos)[:, None]
+    f32 = lambda t: jnp.asarray(t, jnp.float32)
+    dense = _dense_latent_attention(
+        {k: f32(v) for k, v in ap.items()}, f32(q_nope), f32(q_rope),
+        f32(c_kv.astype(bf)), f32(k_rope.astype(bf)), ok, 0.07)
+    np.testing.assert_allclose(np.asarray(got, np.float32), dense, atol=3e-2)

@@ -219,8 +219,9 @@ def _refuse_unsupported(model, serving) -> None:
             + (" (it keeps request state by slot, 'serving_state': a page "
                "of keys without the state at its boundary is no prefix, "
                "and a parked session or a drafted token needs a snapshot "
-               "of it; chunks of a prompt need none, if the model's "
-               "prefill reads what the chunk before left in the slot)"
+               "of it; chunks of a prompt need none, and a stateful "
+               "model may prefill in chunks if its prefill_paged reads "
+               "what the chunk before left in the slot, state= at slot=)"
                if stateful else ""))
 
 
@@ -1071,14 +1072,14 @@ class ServeEngine:
         self.state_bytes = {
             k: int(np.prod(v.shape)) * jnp.dtype(v.dtype).itemsize
             for k, v in self._state_spec.items()}
-        if self.state_bytes:
-            self.state_bytes["kv"] = self.kv_bytes
-        elif self.paged and self.cache_spec.values_in_keys:
+        if self.paged and self.cache_spec.values_in_keys:
             index_bytes = (self.cache_spec.pages
                            * self.cache_spec.index_page_bytes)
             self.state_bytes["latent"] = self.kv_bytes - index_bytes
             if index_bytes:
                 self.state_bytes["index_k"] = index_bytes
+        elif self.state_bytes:
+            self.state_bytes["kv"] = self.kv_bytes
         if self.spec_k:
             self.param_bytes += param_nbytes(self.draft_params)
             self.kv_bytes += self.draft_cache_spec.bytes
@@ -1180,7 +1181,8 @@ class ServeEngine:
                     "serve_state_bytes",
                     "device bytes a stateful model's requests hold by "
                     "kind: each serving_state leaf (ssm, conv; window_k, "
-                    "window_v) and the page pool (kv; latent where it is "
+                    "window_v; kda, kda_conv) and the page pool (kv; latent "
+                    "where it is "
                     "one pool of latent rows, index_k the indexer keys "
                     "paged beside them)")
                 for kind, nbytes in self.state_bytes.items():

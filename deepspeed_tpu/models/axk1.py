@@ -84,10 +84,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from .walked import (F32, PagePool, ServedConfig, WalkedModel, at,
-                     causal_self_attention, decode_index, dense_ffn,
-                     draw_layers, expand_latents, held_expert_counters,
-                     latent_context_attention, latent_context_pairs,
-                     latent_projections, latent_rows, lm_head, merge_heads,
+                     decode_index, dense_ffn, draw_layers,
+                     held_expert_counters, latent_context_attention,
+                     latent_context_pairs, latent_projections, latent_rows,
+                     latent_self_attention, lm_head, merge_heads,
                      prefill_index, rms_norm, routed_experts, shared_expert,
                      stacked_experts, whole_tiles)
 
@@ -434,17 +434,10 @@ def _cached_rows(cfg: AxK1Config, c_kv, k_rope):
 
 
 def _self_attention(cfg: AxK1Config, ap, q_nope, q_rope, c_kv, k_rope):
-    """The expanded form over whole sequences from position 0: q_* [B, H,
-    T, .], c_kv [B, T, C], k_rope [B, T, rot] -> [B, H, T, v_head_dim]."""
-    k_nope, v = expand_latents(ap, c_kv, q_nope.dtype)
-    k_rope = jnp.broadcast_to(k_rope[:, None], q_rope.shape)
-    # the two widths at rest in whole lane tiles (192 -> 256), zeros in
-    # the upper lanes: scores do not change, every matmul is aligned
-    pad = ((0, 0),) * 3 + ((0, whole_tiles(cfg.qk_head_dim)
-                            - cfg.qk_head_dim),)
-    q = jnp.pad(jnp.concatenate([q_nope, q_rope], axis=-1), pad)
-    k = jnp.pad(jnp.concatenate([k_nope, k_rope], axis=-1), pad)
-    return causal_self_attention(q, k, v, cfg.attn_impl == "flash",
+    """The expanded form over whole sequences from position 0
+    (``walked.latent_self_attention``)."""
+    return latent_self_attention(ap, q_nope, q_rope, c_kv, k_rope,
+                                 flash=cfg.attn_impl == "flash",
                                  sm_scale=softmax_scale(cfg))
 
 

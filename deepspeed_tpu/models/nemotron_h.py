@@ -179,8 +179,11 @@ class NemotronHConfig(ServedConfig):
 class NemotronHModel(WalkedModel):
     #: the engine refuses the prefix cache, KV tiering and migration for
     #: any model with ``serving_state``; the rest are arms these paged
-    #: steps do not have (chunked prefill: the prefill takes no prefix,
-    #: and the scan would have to start from the slot's state)
+    #: steps do not have.  Chunked prefill among them, by THIS model's
+    #: choice: a stateful model may prefill in chunks if its
+    #: ``prefill_paged`` reads ``state=`` at ``slot=`` (``models/
+    #: kimi_linear.py`` does); here ``ssd_chunked`` starts from a zero
+    #: state and the prefill takes no prefix: this file's edit to make
     serving_unsupported = WalkedModel.serving_unsupported + (
         "prefill_chunk_len",)
 

@@ -1,10 +1,10 @@
 """What the served families whose layers are WALKED share: ``olmoe.py``
 (the one that scans), ``nemotron_h.py``, ``mimo_v2.py``, ``axk1.py``,
-``cohere2_moe.py``, ``glm_dsa.py``.  A family's file holds what is its
-own: its config under the source's keys, ``init`` and the parameter tree,
-its projections,
-latents and mixers, its list of layer kinds, and two paged steps that read
-as that list walked over the pieces here.  No family imports another; the
+``cohere2_moe.py``, ``glm_dsa.py``, ``kimi_linear.py``.  A family's file
+holds what is its own: its config under the source's keys, ``init`` and
+the parameter tree, its projections, latents and mixers, its list of layer
+kinds, and two paged steps that read as that list walked over the pieces
+here.  No family imports another; the
 next one imports this module, ``ops`` and ``moe``.
 
 The functions know no family and take VALUES, never a config (the configs
@@ -14,8 +14,8 @@ families call; the index preludes of the two paged steps
 (:func:`decode_index`, :func:`prefill_index`); the cache's bookkeeping as
 two pairs of write and attend (:class:`PagePool`, :class:`Rings`); the
 routed-expert call (:func:`routed_experts`); latent attention's
-projections, rows at rest and expanded form over a paged context
-(``axk1.py``, ``glm_dsa.py``).  ONE rule for what is not
+projections, rows at rest and expanded form, from nothing or over a paged
+context (``axk1.py``, ``glm_dsa.py``, ``kimi_linear.py``).  ONE rule for what is not
 live: an inactive slot and a padded prompt row name page 0, the engine's
 scratch page, are kept out of every write and attend over length 0.
 :class:`ServedConfig` and :class:`WalkedModel` are what ``ServeEngine``
@@ -217,21 +217,28 @@ def whole_tiles(width: int) -> int:
 
 def latent_projections(ap, h, positions, *, heads: int, nope: int,
                        kv_rank: int, eps: float, theta: float,
-                       inv_freq=None):
+                       inv_freq=None, low_rank_q: bool = True,
+                       rotate: bool = True):
     """h [B, T, d] (normed), positions [B, T] -> c_q [B, T, q_lora_rank]
     (normed), q_nope [B, H, T, nope], q_rope [B, H, T, rot] (rotated),
     c_kv [B, T, kv_rank] (normed), k_rope [B, T, rot] (rotated): what the
-    cache keeps is the last two."""
+    cache keeps is the last two.  ``low_rank_q`` false: the query comes
+    straight from ``ap["q_w"]`` and ``c_q`` is None; ``rotate`` false:
+    nothing is rotated and ``positions`` is not read."""
+    def turned(t):
+        return rope(t, positions, theta, inv_freq=inv_freq) if rotate else t
+
     with jax.named_scope("latent_q"):
-        c_q = rms_norm(h @ ap["q_a_w"].astype(h.dtype), ap["q_a_norm"], eps)
-        q = project_heads(c_q, ap["q_b_w"], heads)
+        c_q = rms_norm(h @ ap["q_a_w"].astype(h.dtype), ap["q_a_norm"],
+                       eps) if low_rank_q else None
+        q = project_heads(c_q, ap["q_b_w"], heads) if low_rank_q \
+            else project_heads(h, ap["q_w"], heads)
         q_nope = q[..., :nope]
-        q_rope = rope(q[..., nope:], positions, theta, inv_freq=inv_freq)
+        q_rope = turned(q[..., nope:])
     with jax.named_scope("latent_kv"):
         kv = h @ ap["kv_a_w"].astype(h.dtype)
         c_kv = rms_norm(kv[..., :kv_rank], ap["kv_a_norm"], eps)
-        k_rope = rope(kv[:, None, :, kv_rank:], positions, theta,
-                      inv_freq=inv_freq)[:, 0]
+        k_rope = turned(kv[:, None, :, kv_rank:])[:, 0]
     return c_q, q_nope, q_rope, c_kv, k_rope
 
 
@@ -250,6 +257,21 @@ def expand_latents(ap, c_kv, dtype):
     c_kv = c_kv.astype(dtype)
     return (jnp.einsum("...tc,hnc->...htn", c_kv, ap["k_b_w"].astype(dtype)),
             jnp.einsum("...tc,hcv->...htv", c_kv, ap["v_b_w"].astype(dtype)))
+
+
+def latent_self_attention(ap, q_nope, q_rope, c_kv, k_rope, *, flash: bool,
+                          sm_scale: float):
+    """The expanded form over whole sequences from position 0: q_* [B, H,
+    T, .], c_kv [B, T, C], k_rope [B, T, rot] -> [B, H, T, v_head_dim]."""
+    k_nope, v = expand_latents(ap, c_kv, q_nope.dtype)
+    k_rope = jnp.broadcast_to(k_rope[:, None], q_rope.shape)
+    # the two widths at rest in whole lane tiles (192 -> 256), zeros in
+    # the upper lanes: scores do not change, every matmul is aligned
+    width = q_nope.shape[-1] + q_rope.shape[-1]
+    pad = ((0, 0),) * 3 + ((0, whole_tiles(width) - width),)
+    q = jnp.pad(jnp.concatenate([q_nope, q_rope], axis=-1), pad)
+    k = jnp.pad(jnp.concatenate([k_nope, k_rope], axis=-1), pad)
+    return causal_self_attention(q, k, v, flash, sm_scale=sm_scale)
 
 
 def latent_context_attention(ap, q_nope, q_rope, pool_pages, page_ids,

@@ -1675,3 +1675,174 @@ def test_saved_flash_results_stay_with_their_rows_on_four_chips(topo):
     assert f"bf16[{layers},192,{SEQ},{DH}]" not in kept
     assert sorted((c.op, c.shapes, c.times) for c in collectives(kept)) == \
         sorted((c.op, c.shapes, c.times) for c in collectives(bare))
+
+
+# ---------------------------------------------------------------------------
+# Kimi Linear at its cell's sizes (benchmark/configs/kimi-linear-48b-a3b
+# .json: 6 KDA layers + 2 latent ones, 32 of 256 experts held at hidden
+# 2,304, 256 slots of delta-rule state, 24,577 pages of 64 rows 640 wide
+# over the latent layers alone): the KDA decode update, and both serve
+# programs, the prefill with its prefix length traced (a chunk)
+# ---------------------------------------------------------------------------
+
+KIMI_SLOTS, KIMI_PAGE_LEN, KIMI_PAGES, KIMI_MAX_PAGES = 256, 64, 24577, 448
+
+
+def _kimi_model():
+    import json
+    from deepspeed_tpu.models.kimi_linear import (KimiLinearConfig,
+                                                  KimiLinearModel)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "kimi-linear-48b-a3b.json")) as f:
+        file = json.load(f)
+    serving = file["serving"]
+    assert (serving["slots"], serving["page_len"], serving["pages"],
+            -(-serving["max_seq_len"] // serving["page_len"])) == (
+        KIMI_SLOTS, KIMI_PAGE_LEN, KIMI_PAGES, KIMI_MAX_PAGES)
+    fields = {f.name for f in dataclasses.fields(KimiLinearConfig)}
+    keys = {k: v for k, v in file.items() if k in fields}
+    keys["num_experts"] = file["published"]["num_experts"]
+    keys["experts_held"] = tuple(keys["experts_held"])
+    return KimiLinearModel(KimiLinearConfig(
+        **keys, param_dtype=file["dtype"])), file
+
+
+def test_kda_decode_kernel_keeps_its_name_and_the_state_in_place(one_chip):
+    """256 slots x 6 layers of [32, 128, 128] float32 (3.2 GB) aliased
+    through; a grid step's blocks and the body inside the kernel's VMEM
+    limit; nothing of the state's size a temporary."""
+    from deepspeed_tpu.ops.pallas.kda import KDA_DECODE_KERNEL, kda_decode
+    assert KDA_DECODE_KERNEL == "ds_kda_decode"
+    s, h, d = KIMI_SLOTS, 32, 128
+    f32 = jnp.float32
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (_sds((6 * s, h, d, d), f32), _sds((s, h, d), f32),
+         _sds((s, h, d), f32), _sds((s, h, d), f32), _sds((s, h, d), f32),
+         _sds((s, h), f32), _sds((s,), jnp.bool_), _sds((), jnp.int32)))
+    compiled = jax.jit(
+        lambda st, a, k, v, q, b, act, base: kda_decode(
+            st, a, k, v, q, b, act, base=base, interpret=False),
+        donate_argnums=(0,)).lower(*args).compile()
+    names = _kernel_names(compiled)
+    assert [n.split(".")[0] for n in names] == [KDA_DECODE_KERNEL]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 6 * s * h * d * d * 4
+    assert mem.temp_size_in_bytes < 4 << 20
+
+
+@functools.lru_cache(maxsize=None)
+def _kimi_program(program, one_chip, bucket=4096):
+    """The model's paged step as the engine calls it: the one pool and the
+    state donated, None where a second pool would be; a prefill at
+    ``bucket`` tokens with its prefix length TRACED (a chunk)."""
+    model, _ = _kimi_model()
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    pool = _sds((2, KIMI_PAGES, 1, KIMI_PAGE_LEN, 640))
+    state = model.serving_state(KIMI_SLOTS)
+    i32, s = _sds((), jnp.int32), KIMI_SLOTS
+    if program == "serve_decode":
+        def fn(p, t, k, tab, ln, act, st):
+            return model.decode_step_paged(p, t, k, None, tab, ln, act,
+                                           state=st, impl="pallas", aux=True)
+        shapes = (params, _sds((s,), jnp.int32), pool,
+                  _sds((s, KIMI_MAX_PAGES), jnp.int32),
+                  _sds((s,), jnp.int32), _sds((s,), jnp.bool_), state)
+        donate = (2, 6)
+    else:
+        def fn(p, t, n, pre, row, k, st, slot):
+            return model.prefill_paged(p, t, n, pre, row, k, None, state=st,
+                                       slot=slot, aux=True)
+        shapes = (params, _sds((1, bucket), jnp.int32), i32, i32,
+                  _sds((KIMI_MAX_PAGES,), jnp.int32), pool, state, i32)
+        donate = (5, 6)
+    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), shapes)
+    with interpret_scope(False):
+        return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+
+
+@pytest.mark.parametrize("program", ["serve_decode", "serve_prefill"])
+def test_kimi_programs_hold_their_kernels_and_no_copy_of_the_state(
+        program, one_chip):
+    """Every Mosaic call of both serve programs starts ``ds_``; a tick
+    runs ``ds_kda_decode`` once a KDA layer and the latent kernel once a
+    latent layer; the one pool (4.03 GB) and the state (3.33 GB) pass
+    through aliased to the outputs and nothing of their size is a
+    temporary; the arguments are the weights, the pool and the state; the
+    compiler's own counts are the ones the configuration's ``reduced_why``
+    states; all the chip must hold at once fits its 16.91e9 bytes, over
+    60 % of them arguments."""
+    from deepspeed_tpu.moe import dropless
+    from deepspeed_tpu.ops.pallas.context_attention import \
+        LATENT_CONTEXT_ATTN_KERNEL
+    from deepspeed_tpu.ops.pallas.decode_attention import \
+        LATENT_DECODE_ATTN_KERNEL
+    from deepspeed_tpu.ops.pallas.kda import KDA_DECODE_KERNEL
+    from deepspeed_tpu.utils.hlo import kernel_calls
+    compiled = _kimi_program(program, one_chip)
+    calls = kernel_calls(compiled.as_text())
+    experts = {dropless.MOE_GATE_UP_KERNEL: 7, dropless.MOE_DOWN_KERNEL: 7}
+    assert calls == {**experts, **(
+        {KDA_DECODE_KERNEL: 6, LATENT_DECODE_ATTN_KERNEL: 2}
+        if program == "serve_decode"
+        else {LATENT_CONTEXT_ATTN_KERNEL: 2})}, calls
+    mem = compiled.memory_analysis()
+    pool = 2 * KIMI_PAGES * KIMI_PAGE_LEN * 640 * 2
+    state = 6 * KIMI_SLOTS * (32 * 128 * 128 * 4 + 3 * 12288 * 2)
+    assert mem.alias_size_in_bytes >= pool + state
+    weights = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves(compiled.in_avals[0][0]))
+    assert abs(mem.argument_size_in_bytes - weights - pool - state) < 1 << 20
+    assert mem.argument_size_in_bytes > 0.6 * 16.91e9
+    # the tick's: the convolutions' tails stacked once (0.113 GB) + 9 MB
+    limit = 0.15e9 if program == "serve_decode" else 2.0e9
+    assert mem.temp_size_in_bytes < limit, mem.temp_size_in_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 14.5e9
+    if program == "serve_decode":
+        # the convolutions' tails are read from the leaf as it came and
+        # written once, into a buffer of their own: no UPDATE of the
+        # donated leaf is an instruction the compiler runs a second time
+        # (``.remat``; a layer's ``.at[i].set`` was, in place, and its
+        # second run read the first one's rows: wrong from the second tick)
+        again = [line for line in compiled.as_text().splitlines()
+                 if ".remat = " in line and "%st__kda_conv__" in line
+                 and "scatter" in line]
+        assert not again, again
+    _, file = _kimi_model()
+    if program == "serve_decode":
+        assert "arguments %.3f GB" % (mem.argument_size_in_bytes / 1e9) \
+            in file["reduced_why"]
+        assert "temporaries %.3f GB (decode" \
+            % (mem.temp_size_in_bytes / 1e9) in file["reduced_why"]
+    else:
+        assert "%.3f GB (prefill" % (mem.temp_size_in_bytes / 1e9) \
+            in file["reduced_why"]
+
+
+def test_kimi_decode_tick_reads_each_layers_matrices_where_they_lie(
+        one_chip):
+    """A leaf a layer (``models/mimo_v2.py``'s rule): no fusion of the
+    tick's entry computation writes a weight again, and what is copied is
+    a matrix's one read into the layout its dot takes.  (``share`` 0.5:
+    at 256 slots the [256, 12288] float32 + bfloat16 results of ``h
+    W_qkv`` fused with its convolution are a third of that weight's
+    bytes, and are activations.)"""
+    from deepspeed_tpu.utils.hlo import parameter_rewrites
+    compiled = _kimi_program("serve_decode", one_chip)
+    weights = len(jax.tree.leaves(compiled.in_avals[0][0]))
+    assert weights == 3 + 6 * 12 + 2 * 7 + 4 + 7 * 6 + 3
+    moved = [r for r in parameter_rewrites(compiled.as_text(), weights,
+                                           share=0.5)
+             if r.bytes >= 1 << 20]
+    assert [r for r in moved if r.op != "copy" or r.hbm_bytes] == [], moved
+
+
+def test_kimi_lower_prefill_rung_compiles_under_the_top_rungs_peak(one_chip):
+    from deepspeed_tpu.inference.engine import prefill_ladder
+    assert prefill_ladder(4096) == (2048, 4096)
+    top = _kimi_program("serve_prefill", one_chip).memory_analysis()
+    rung = _kimi_program("serve_prefill", one_chip, 2048).memory_analysis()
+    assert rung.alias_size_in_bytes == top.alias_size_in_bytes
+    assert rung.temp_size_in_bytes < top.temp_size_in_bytes

@@ -22,7 +22,7 @@ from deepspeed_tpu.models import walked
 MODELS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "deepspeed_tpu", "models")
 FAMILIES = ("olmoe", "nemotron_h", "mimo_v2", "axk1", "cohere2_moe",
-            "glm_dsa")
+            "glm_dsa", "kimi_linear")
 
 
 def _imported_modules(path):
@@ -52,6 +52,47 @@ def test_no_model_imports_from_a_sibling_family(family):
     # and the family itself stands on the shared module
     assert "walked" in set(_imported_modules(
         os.path.join(MODELS, family + ".py")))
+
+
+@pytest.mark.parametrize("low_rank_q,rotate", [(True, True), (False, True),
+                                               (False, False)])
+def test_latent_projections_without_a_low_rank_query_or_a_rotation(
+        low_rank_q, rotate):
+    """The two arguments its first two callers do not pass: a query
+    straight from ``q_w`` (``c_q`` None), and nothing rotated (positions
+    not read); the default is the low-rank, rotated form it always was."""
+    rng = np.random.default_rng(0)
+    d, H, nope, rot, rank, rq = 24, 2, 8, 4, 16, 12
+
+    def w(*shape):
+        return jnp.asarray(rng.normal(size=shape) * 0.2, jnp.float32)
+
+    ap = {"q_a_w": w(d, rq), "q_a_norm": jnp.ones((rq,)),
+          "q_b_w": w(rq, H * (nope + rot)), "q_w": w(d, H * (nope + rot)),
+          "kv_a_w": w(d, rank + rot), "kv_a_norm": jnp.ones((rank,))}
+    h = w(1, 5, d)
+    positions = jnp.arange(5, dtype=jnp.int32)[None]
+    c_q, q_nope, q_rope, c_kv, k_rope = walked.latent_projections(
+        ap, h, positions if rotate else None, heads=H, nope=nope,
+        kv_rank=rank, eps=1e-6, theta=1e4, low_rank_q=low_rank_q,
+        rotate=rotate)
+    src = walked.rms_norm(h @ ap["q_a_w"], ap["q_a_norm"], 1e-6) \
+        if low_rank_q else h
+    q = walked.project_heads(src, ap["q_b_w" if low_rank_q else "q_w"], H)
+    kv = h @ ap["kv_a_w"]
+
+    def turned(t):
+        return walked.rope(t, positions, 1e4) if rotate else t
+
+    assert (c_q is None) == (not low_rank_q)
+    np.testing.assert_array_equal(q_nope, q[..., :nope])
+    np.testing.assert_array_equal(q_rope, turned(q[..., nope:]))
+    np.testing.assert_array_equal(c_kv, walked.rms_norm(
+        kv[..., :rank], ap["kv_a_norm"], 1e-6))
+    np.testing.assert_array_equal(k_rope,
+                                  turned(kv[:, None, :, rank:])[:, 0])
+    if rotate:      # the rotation does turn them
+        assert np.abs(np.asarray(q_rope - q[..., nope:])).max() > 1e-3
 
 
 def test_decode_index_sends_an_inactive_slot_to_page_0_and_length_0():

@@ -129,20 +129,31 @@ _FROM_DEVICE = -1
 _FROM_PREFILL = -2
 
 
+#: the shortest rung the prefill ladder builds below the half of
+#: ``prefill_len``.  A rung costs the host a fixed second or two of
+#: lowering and loading at set-up whatever its length, and saves device
+#: time in proportion to its length: at 1,024 tokens 38-53 ms a call on
+#: the families read so far (``PERF.md`` section 6, PRs 37 and 53); at 256
+#: it saved ~5 ms a call and cost a short set-up 9 % of a 10 % bound, and
+#: was cut (PR 37)
+LADDER_FLOOR = 1024
+
+
 def prefill_ladder(prefill_len: int) -> tuple:
     """The lengths the prefill program is built at, ascending:
-    ``prefill_len`` and, where that is a whole multiple of 256, its half
-    (docs/serving.md "The prefill ladder").  256 tokens is where a bf16
-    matmul stops being bound by reading its weights, so a shorter program
-    would save nothing, and it is a multiple of every unit a prefill
-    program has (page, window, scan chunk, flash block).  Two rungs and
-    not more: each costs the host a second or two of lowering and loading
-    at set-up, which a short set-up cannot hide (``PERF.md`` section 6,
-    PR 37).  Under 512 the ladder is ``(prefill_len,)``."""
-    prefill_len = int(prefill_len)
-    if prefill_len % 512:
-        return (prefill_len,)
-    return (prefill_len // 2, prefill_len)
+    ``prefill_len``; where that is a whole multiple of 512, its half; and
+    below the half each further half that is a whole multiple of 256 and
+    at least ``LADDER_FLOOR`` tokens long (docs/serving.md "The prefill
+    ladder"): 4,096 -> (1,024, 2,048, 4,096), 1,024 -> (512, 1,024).
+    256 tokens is where a bf16 matmul stops being bound by reading its
+    weights, so a shorter program would save nothing, and it is a multiple
+    of every unit a prefill program has (page, window, scan chunk, flash
+    block).  Under 512 the ladder is ``(prefill_len,)``."""
+    ladder = [int(prefill_len)]
+    while ladder[0] % 512 == 0 and (
+            len(ladder) == 1 or ladder[0] // 2 >= LADDER_FLOOR):
+        ladder.insert(0, ladder[0] // 2)
+    return tuple(ladder)
 
 
 class _Tick(NamedTuple):
@@ -364,8 +375,8 @@ class ServeEngine:
         #: for each rung of the prefill ladder ``lower:<rung>`` and
         #: ``compile:<rung>``
         #: (its executable's load or compile) on the ladder's thread,
-        #: ``rungs_wait`` (the first prefill call waiting for that
-        #: thread) and ``first_call:<program>[:<rung>]`` (a program's
+        #: ``rungs_wait:<rung>`` (a prefill call waiting for that thread
+        #: to reach its rung) and ``first_call:<program>[:<rung>]`` (a program's
         #: first call until it returns: trace, compile or load, enqueue)
         self.setup_log: List[tuple] = []
         self._called: set = set()
@@ -385,7 +396,7 @@ class ServeEngine:
                 "serve_setup_seconds",
                 "the engine's own set-up by phase: params, cache, feed, "
                 "lower:<rung> and compile:<rung> of the prefill ladder, "
-                "rungs_wait, first_call:<program>[:<rung>]")
+                "rungs_wait:<rung>, first_call:<program>[:<rung>]")
             device_ctr = reg.counter(
                 "serve_device_seconds_total",
                 "the device's time by program and by rung (prefill) or "
@@ -526,9 +537,9 @@ class ServeEngine:
         #: rung -> prefill calls that were one chunk of a longer prompt
         #: (counter ``serve_prefill_chunks_total{bucket=}``)
         self.prefill_chunk_calls = {r: 0 for r in self.prefill_buckets}
-        #: the rungs' executables on their way (``_build_prefill_rungs``);
-        #: None for a ladder of one rung, which is the jitted program
-        self._prefill_build: Optional[Future] = None
+        #: rung -> its executable on its way (``_build_prefill_rung``);
+        #: {} for a ladder of one rung, which is the jitted program
+        self._prefill_build: Dict[int, Future] = {}
         #: request state by slot (class docstring): name -> shape and
         #: dtype, {} for a model that keeps none
         self._state_spec = (dict(model.serving_state(self.slots))
@@ -1268,11 +1279,15 @@ class ServeEngine:
         self._tokens_seen = 0
         if len(self.prefill_buckets) > 1:
             # beside whatever the caller does between building an engine
-            # and its first prompt (probing, warming a tick): the first
-            # prefill call waits for the rungs, and so does close()
+            # and its first prompt (probing, warming a tick), one rung
+            # after the other on ONE thread, the shortest first: a prefill
+            # call waits for the rung it runs, close() for all of them
             pool = ThreadPoolExecutor(
                 1, thread_name_prefix="serve_prefill_rungs")
-            self._prefill_build = pool.submit(self._build_prefill_rungs)
+            operands = self._prefill_rung_operands()
+            self._prefill_build = {
+                r: pool.submit(self._build_prefill_rung, r, *operands)
+                for r in self.prefill_buckets}
             pool.shutdown(wait=False)
 
     # -- speculative decoding: the draft plane --------------------------
@@ -1971,15 +1986,11 @@ class ServeEngine:
             self._prefills_ctr.inc(bucket=str(rung))
         return tokens
 
-    def _build_prefill_rungs(self) -> Dict[int, Any]:
-        """rung -> ``serve_prefill`` compiled at that length, ahead of
-        time, on the shapes of the operands the admission arms hand it
+    def _prefill_rung_operands(self) -> tuple:
+        """(params and cache, the operands after ``tokens``) of
+        ``serve_prefill`` as shapes: what the admission arms hand it
         (``_admit_one_paged`` and ``_prefill_chunk_tick``, or
-        ``_admit_one_slot``).  All rungs are executables of the one jitted
-        function, which keeps the name and an empty cache of its own.
-        Runs on a thread of its own from construction on, so that a
-        harness which warms an engine on a prompt or two finds every rung
-        ready, and pays for them beside its own set-up, not after it."""
+        ``_admit_one_slot``)."""
         def like(x):
             return jax.ShapeDtypeStruct(x.shape, x.dtype,
                                         sharding=x.sharding)
@@ -1994,32 +2005,39 @@ class ServeEngine:
         if self._rng_base is not None:
             rest.append(jax.ShapeDtypeStruct(self._rng_base.shape,
                                              self._rng_base.dtype))
-        held = jax.tree.map(like, (self.params, self.cache))
-        rungs = {}
+        return jax.tree.map(like, (self.params, self.cache)), rest
+
+    def _build_prefill_rung(self, rung: int, held, rest):
+        """``serve_prefill`` compiled at ``rung`` tokens, ahead of time.
+        Every rung is an executable of the one jitted function, which
+        keeps the name and an empty cache of its own.  Runs on the
+        ladder's thread from construction on, so that a harness which
+        warms an engine on a prompt or two finds the rungs ready, and
+        pays for them beside its own set-up, not after it."""
         with self._pallas_scope():
-            for r in self.prefill_buckets:
-                with self._setup("lower", rung=r):
-                    lowered = self._prefill_fn.lower(
-                        *held, jax.ShapeDtypeStruct((1, r), np.int32),
-                        *rest)
-                with self._setup("compile", rung=r):
-                    rungs[r] = lowered.compile()
-        return rungs
+            with self._setup("lower", rung=rung):
+                lowered = self._prefill_fn.lower(
+                    *held, jax.ShapeDtypeStruct((1, rung), np.int32), *rest)
+            with self._setup("compile", rung=rung):
+                return lowered.compile()
 
     def _run_prefill(self, *operands):
         """``serve_prefill`` at the length of its tokens (operand 2): the
         jitted program for a ladder of one rung, as ever; else that rung's
-        executable, once all of them are built, so that no later length
-        compiles anything.  Takes the cache it is handed for the one it
-        returns; gives (first token on the device, the call's counters,
-        its record in the device's queue book)."""
+        executable, waited for if the ladder's thread has not reached it
+        yet (a rung still on its way does not hold up a call of another),
+        so that no length compiles anything in the call.  Takes the cache
+        it is handed for the one it returns; gives (first token on the
+        device, the call's counters, its record in the device's queue
+        book)."""
         rung = operands[2].shape[1]
         fn = self._prefill_fn
         if len(self.prefill_buckets) > 1:
-            if not self._prefill_build.done():
-                with self._setup("rungs_wait"):
-                    self._prefill_build.result()
-            fn = self._prefill_build.result()[rung]
+            built = self._prefill_build[rung]
+            if not built.done():
+                with self._setup("rungs_wait", rung=rung):
+                    wait([built])
+            fn = built.result()
         with self._first_call("serve_prefill", str(rung)):
             self.cache, first, *aux = fn(*operands)
         return first, aux, self.book.sent("serve_prefill", str(rung), first)
@@ -3216,8 +3234,7 @@ class ServeEngine:
         if self._closed:
             return
         self._closed = True
-        if self._prefill_build is not None:
-            wait([self._prefill_build])
+        wait(self._prefill_build.values())
         errors = self._graph.close_all()
         if errors:
             raise errors[0][1]

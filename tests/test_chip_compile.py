@@ -832,30 +832,42 @@ def test_mimo_prefill_rung_holds_half_the_temporaries_of_stacked_leaves(
     assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
 
 
-@pytest.mark.parametrize("family,bucket", [("mimo", 2048),
-                                           ("nemotron", 512)])
+def _family_program(family):
+    return {"mimo": _mimo_program, "nemotron": _nemotron_program,
+            "axk1": _axk1_program, "cmda": _cmda_program,
+            "kimi": _kimi_program}[family]
+
+
+@pytest.mark.parametrize("family,bucket", [
+    ("mimo", 2048), ("nemotron", 512),
+    # the rung under the half, built from 1,024 tokens up (PR 53)
+    ("mimo", 1024), ("axk1", 1024), ("cmda", 1024), ("kimi", 1024)])
 def test_the_lower_rung_of_the_prefill_ladder_compiles_under_the_top_rungs_peak(
         family, bucket, one_chip):
-    """``ServeEngine`` builds ``serve_prefill`` at half of
+    """``ServeEngine`` builds ``serve_prefill`` below
     ``serving.prefill_len`` too (``inference/engine.py::prefill_ladder``:
-    4,096 -> 2,048; 1,024 -> 512).  The shorter rung holds the same
-    kernels, passes the same caches through aliased, and needs fewer
-    temporaries than the top rung: what the chip must hold at once is
+    4,096 -> 1,024 and 2,048; 1,024 -> 512).  A shorter rung holds the
+    same kernels, passes the same caches through aliased, and needs fewer
+    temporaries than the rung above it: what the chip must hold at once is
     still set by ``prefill_len``."""
     from deepspeed_tpu.inference.engine import prefill_ladder
-    build = {"mimo": _mimo_program, "nemotron": _nemotron_program}[family]
+    build = _family_program(family)
     top = build("serve_prefill", one_chip)
     top_len = top.in_avals[0][1].shape[1]
     assert bucket in prefill_ladder(top_len)[:-1]
+    above = build("serve_prefill", one_chip, 2 * bucket)
     rung = build("serve_prefill", one_chip, bucket)
+    assert rung.in_avals[0][1].shape == (1, bucket)
     assert {n.split(".")[0] for n in _kernel_names(rung)} \
         == {n.split(".")[0] for n in _kernel_names(top)}
     mem, top_mem = rung.memory_analysis(), top.memory_analysis()
     assert mem.alias_size_in_bytes == top_mem.alias_size_in_bytes
     assert mem.argument_size_in_bytes <= top_mem.argument_size_in_bytes
+    above_temp = above.memory_analysis().temp_size_in_bytes
     print(f"{family} serve_prefill temporaries: {mem.temp_size_in_bytes} B "
-          f"at {bucket} tokens, {top_mem.temp_size_in_bytes} B at {top_len}")
-    assert mem.temp_size_in_bytes < top_mem.temp_size_in_bytes
+          f"at {bucket} tokens, {above_temp} B at {2 * bucket}, "
+          f"{top_mem.temp_size_in_bytes} B at {top_len}")
+    assert mem.temp_size_in_bytes < above_temp <= top_mem.temp_size_in_bytes
 
 
 # ---------------------------------------------------------------------------
@@ -1017,7 +1029,7 @@ def test_axk1_decode_tick_reads_each_layers_matrices_where_they_lie(
 
 def test_axk1_lower_prefill_rung_compiles_under_the_top_rungs_peak(one_chip):
     from deepspeed_tpu.inference.engine import prefill_ladder
-    assert prefill_ladder(4096) == (2048, 4096)
+    assert prefill_ladder(4096) == (1024, 2048, 4096)
     top = _axk1_program("serve_prefill", one_chip).memory_analysis()
     rung = _axk1_program("serve_prefill", one_chip, 2048).memory_analysis()
     assert rung.alias_size_in_bytes == top.alias_size_in_bytes
@@ -1841,7 +1853,7 @@ def test_kimi_decode_tick_reads_each_layers_matrices_where_they_lie(
 
 def test_kimi_lower_prefill_rung_compiles_under_the_top_rungs_peak(one_chip):
     from deepspeed_tpu.inference.engine import prefill_ladder
-    assert prefill_ladder(4096) == (2048, 4096)
+    assert prefill_ladder(4096) == (1024, 2048, 4096)
     top = _kimi_program("serve_prefill", one_chip).memory_analysis()
     rung = _kimi_program("serve_prefill", one_chip, 2048).memory_analysis()
     assert rung.alias_size_in_bytes == top.alias_size_in_bytes

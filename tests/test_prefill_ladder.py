@@ -1,20 +1,24 @@
 """The prefill ladder (docs/serving.md "The prefill ladder",
 ``inference/engine.py::prefill_ladder``): ``serve_prefill`` is built at
-``serving.prefill_len`` and at its half, and a call runs the smaller rung
-where that holds its tokens.  Proved here by equality and by count, never
+``serving.prefill_len``, at its half and, below the half, at each further
+half of at least 1,024 tokens; a call runs the smallest rung that holds
+its tokens.  Proved here by equality and by count, never
 by speed: the same prompts through an engine with its ladder and through
 the same engine held to its top rung give the same streams, ``kv_len``,
-pages and (MiMo-V2) window rings; the counters say which rung ran; and
-once the first prefill has returned, no length compiles anything.  CPU,
-tiny widths, seeded weights.
+pages and (MiMo-V2) window rings; the counters say which rung ran; a call
+waits for the rung it runs and for no other; and once the ladder's thread
+is through, no length compiles anything.  CPU, tiny widths, seeded weights.
 """
+import threading
+from concurrent.futures import wait
+
 import jax
 import numpy as np
 import pytest
 from jax import monitoring
 
 from deepspeed_tpu.inference import ServeEngine
-from deepspeed_tpu.inference.engine import prefill_ladder
+from deepspeed_tpu.inference.engine import LADDER_FLOOR, prefill_ladder
 from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
 from deepspeed_tpu.models.mimo_v2 import MimoV2Config, MimoV2Model
 
@@ -27,22 +31,31 @@ COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 @pytest.mark.parametrize("prefill_len,ladder", [
     (32, (32,)), (128, (128,)), (256, (256,)), (512, (256, 512)),
-    (1024, (512, 1024)), (4096, (2048, 4096)), (8192, (4096, 8192)),
-    # the half is a rung only as a whole multiple of 256
+    (1024, (512, 1024)), (2048, (1024, 2048)),
+    # below the half a rung is built only from 1,024 tokens up
+    (4096, (1024, 2048, 4096)), (8192, (1024, 2048, 4096, 8192)),
+    (3072, (1536, 3072)), (5120, (1280, 2560, 5120)),
+    # a half is a rung only as a whole multiple of 256
     (768, (768,)), (1000, (1000,)), (1280, (1280,)), (1536, (768, 1536))])
 def test_the_ladder_of_a_prefill_len(prefill_len, ladder):
     assert prefill_ladder(prefill_len) == ladder
-    assert ladder[-1] == prefill_len and len(ladder) <= 2
-    assert all(r % 256 == 0 and 2 * r == prefill_len for r in ladder[:-1])
+    assert ladder[-1] == prefill_len
+    assert all(r % 256 == 0 and 2 * r == up
+               for r, up in zip(ladder, ladder[1:]))
+    assert all(r >= LADDER_FLOOR == 1024 for r in ladder[:-2])
+    # no rung is left out: the one under the lowest would be too short,
+    # or no multiple of 256
+    low = ladder[0]
+    assert low % 512 or (len(ladder) > 1 and low // 2 < LADDER_FLOOR)
 
 
-def _gpt2():
-    return GPT2Model(GPT2Config(vocab_size=128, n_positions=TOP + 8,
+def _gpt2(top=TOP):
+    return GPT2Model(GPT2Config(vocab_size=128, n_positions=top + 8,
                                 d_model=32, n_layer=2, n_head=4, remat=None,
                                 attn_impl="dense"))
 
 
-def _mimo():
+def _mimo(top=TOP):
     return MimoV2Model(MimoV2Config(
         vocab_size=128, hidden_size=32, intermediate_size=48,
         moe_intermediate_size=16, num_hidden_layers=3,
@@ -51,24 +64,24 @@ def _mimo():
         v_head_dim=16, swa_num_attention_heads=4, swa_num_key_value_heads=2,
         swa_head_dim=24, swa_v_head_dim=16, sliding_window=8,
         n_routed_experts=8, num_experts_per_tok=2, experts_held=(0, 8),
-        max_position_embeddings=TOP + 8, attn_impl="dense"))
+        max_position_embeddings=top + 8, attn_impl="dense"))
 
 
 MODELS = {"gpt2": _gpt2, "mimo_v2": _mimo}
 
 
-def _engine(family, one_rung=False, serving=(), **config):
-    model = MODELS[family]()
+def _engine(family, one_rung=False, serving=(), top=TOP, **config):
+    model = MODELS[family](top)
     eng = ServeEngine(model, {
-        "serving": {"slots": 2, "page_len": 16, "max_seq_len": TOP + 8,
-                    "prefill_len": TOP, "prefix_cache": False,
+        "serving": {"slots": 2, "page_len": 16, "max_seq_len": top + 8,
+                    "prefill_len": top, "prefix_cache": False,
                     **dict(serving)}, **config},
         params=model.init(jax.random.PRNGKey(0)))
     if one_rung:
         # the engine as it was before the ladder: held to its top rung
         # from here, in the test; the program has no such option
-        eng.prefill_buckets = (TOP,)
-        eng.prefill_calls = {TOP: 0}
+        eng.prefill_buckets = (top,)
+        eng.prefill_calls = {top: 0}
     return eng
 
 
@@ -154,17 +167,16 @@ def test_under_512_an_engine_builds_one_prefill_program(prefill_len):
         assert eng.prefill_calls == {prefill_len: 2}
         assert eng.prefill_pad_tokens == prefill_len - 3
         assert eng._prefill_fn._cache_size() == 1
-        assert eng._prefill_build is None
+        assert eng._prefill_build == {}
     finally:
         eng.close()
 
 
-@pytest.fixture(scope="module")
-def counted(tmp_path_factory):
-    """A ladder engine with telemetry on: its construction and first
-    prefill (5 tokens), then every other length, with the backend compiles
-    each step caused (``jax.monitoring``, what the benchmark's windows
-    count)."""
+def _counted(tmp_path_factory, lengths, top):
+    """A ladder engine with telemetry on: its construction, first prefill
+    (the first of ``lengths``) and what is left of the ladder's thread,
+    then every other length, with the backend compiles each step caused
+    (``jax.monitoring``, what the benchmark's windows count)."""
     compiles = []
 
     def listen(event, duration, **kw):
@@ -172,22 +184,26 @@ def counted(tmp_path_factory):
             compiles.append(event)
 
     monitoring.register_event_duration_secs_listener(listen)
-    eng = _engine("gpt2", telemetry={
+    eng = _engine("gpt2", top=top, telemetry={
         "enabled": True,
         "output_path": str(tmp_path_factory.mktemp("ladder_tel"))})
     rows = {}
     try:
         reg = eng.telemetry.registry
         before = 0
-        for n in LENGTHS:
-            eng.submit(_prompt(n), max_new_tokens=NEW)
+        for n in lengths:
+            req = eng.submit(_prompt(n), max_new_tokens=NEW)
             eng.run_until_idle()
+            wait(eng._prefill_build.values())
             eng.telemetry.compile_monitor.sample()
             rows[n] = dict(
+                tokens=list(req.tokens),
                 compiles=len(compiles) - before,
                 recompiles=reg.counter("recompiles_total", "").value(
                     program="serve_prefill"),
-                rungs_ready=sorted(eng._prefill_build.result()))
+                rungs_ready=sorted(r for r, built in
+                                   eng._prefill_build.items()
+                                   if built.exception() is None))
             before = len(compiles)
         rows["calls"] = dict(eng.prefill_calls)
         rows["tokens"] = (eng.prefill_tokens, eng.prefill_pad_tokens)
@@ -203,7 +219,12 @@ def counted(tmp_path_factory):
     return rows
 
 
-def test_the_first_prefill_finds_every_rung_ready(counted):
+@pytest.fixture(scope="module")
+def counted(tmp_path_factory):
+    return _counted(tmp_path_factory, LENGTHS, TOP)
+
+
+def test_every_rung_is_built_ahead_from_construction_on(counted):
     first = counted[LENGTHS[0]]
     assert first["rungs_ready"] == [512, 1024]
     # from construction to the first request's end: both rungs and the
@@ -212,7 +233,7 @@ def test_the_first_prefill_finds_every_rung_ready(counted):
 
 
 @pytest.mark.parametrize("n", LENGTHS[1:])
-def test_after_the_first_prefill_no_length_compiles(counted, n):
+def test_once_the_ladder_is_built_no_length_compiles(counted, n):
     assert counted[n]["compiles"] == 0
     assert counted[n]["recompiles"] == counted[LENGTHS[0]]["recompiles"] == 0
 
@@ -317,3 +338,116 @@ def test_a_chunk_runs_the_rung_that_holds_it():
     assert out[False][:2] == out[True][:2]
     assert out[False][2] == {512: 4, 1024: 0}
     assert out[True][2] == {TOP: 4}
+
+
+# -- a ladder of three rungs (prefill_len 4,096: the family cells' ladder) --
+
+TOP3 = 4096
+LENGTHS3 = (7, 1000, 1024, 1025, 2048, 2049, 3000, 4096)
+RUNG3 = {7: 1024, 1000: 1024, 1024: 1024, 1025: 2048, 2048: 2048,
+         2049: 4096, 3000: 4096, 4096: 4096}
+
+
+@pytest.fixture(scope="module")
+def three_rungs(tmp_path_factory):
+    """Every length through a ladder of three rungs, counted as
+    ``counted`` counts, and through the same engine held to its top
+    rung."""
+    rows = _counted(tmp_path_factory, LENGTHS3, TOP3)
+    eng = _engine("gpt2", one_rung=True, top=TOP3)
+    try:
+        rows["top_rung_tokens"] = {}
+        for n in LENGTHS3:
+            req = eng.submit(_prompt(n), max_new_tokens=NEW)
+            eng.run_until_idle()
+            rows["top_rung_tokens"][n] = list(req.tokens)
+        rows["top_rung_pad"] = eng.prefill_pad_tokens
+    finally:
+        eng.close()
+    return rows
+
+
+def test_three_rungs_are_built_ahead_from_construction_on(three_rungs):
+    assert three_rungs["buckets"] == (1024, 2048, 4096)
+    first = three_rungs[LENGTHS3[0]]
+    assert first["rungs_ready"] == [1024, 2048, 4096]
+    # three rungs and the decode tick, at least
+    assert first["compiles"] >= 4
+
+
+@pytest.mark.parametrize("n", LENGTHS3)
+def test_a_length_runs_the_smallest_of_three_rungs_that_holds_it(
+        three_rungs, n):
+    got = three_rungs[n]
+    assert got["tokens"] == three_rungs["top_rung_tokens"][n]
+    assert len(got["tokens"]) == NEW
+    assert got["recompiles"] == 0
+    if n != LENGTHS3[0]:
+        assert got["compiles"] == 0
+
+
+def test_three_rungs_count_their_calls_and_their_padding(three_rungs):
+    calls = {r: sum(1 for n in LENGTHS3 if RUNG3[n] == r)
+             for r in (1024, 2048, 4096)}
+    assert calls == {1024: 3, 2048: 2, 4096: 3}
+    assert three_rungs["calls"] == calls
+    assert three_rungs["by_bucket"] == {r: float(c) for r, c in calls.items()}
+    wanted = sum(LENGTHS3)
+    pad = sum(RUNG3[n] - n for n in LENGTHS3)
+    assert three_rungs["tokens"] == (wanted, pad)
+    assert three_rungs["pad_counter"] == pad
+    # the two rungs of before (2,048 and 4,096) would have padded the
+    # three shortest prompts by 1,024 tokens more each
+    assert three_rungs["top_rung_pad"] == len(LENGTHS3) * TOP3 - wanted
+    two = sum((2048 if n <= 2048 else 4096) - n for n in LENGTHS3)
+    assert two - pad == 3 * 1024
+
+
+# -- a call waits for the rung it runs, close() for all of them -------------
+
+@pytest.fixture
+def top_rung_held_back(monkeypatch):
+    """The ladder's thread stops before the top rung until the gate
+    opens."""
+    gate = threading.Event()
+    build = ServeEngine._build_prefill_rung
+
+    def held_back(self, rung, *operands):
+        if rung == TOP:
+            assert gate.wait(120)
+        return build(self, rung, *operands)
+
+    monkeypatch.setattr(ServeEngine, "_build_prefill_rung", held_back)
+    yield gate
+    gate.set()
+
+
+def test_a_call_of_the_lowest_rung_returns_while_the_top_rung_is_building(
+        top_rung_held_back):
+    eng = _engine("gpt2")
+    try:
+        low = eng.submit(_prompt(5), max_new_tokens=NEW)
+        eng.run_until_idle()
+        assert len(low.tokens) == NEW and low.finish_reason == "length"
+        assert eng._prefill_build[512].done()
+        assert not eng._prefill_build[TOP].done()
+        # a call of the top rung waits for that rung
+        threading.Timer(0.3, top_rung_held_back.set).start()
+        top = eng.submit(_prompt(600), max_new_tokens=NEW)
+        eng.run_until_idle()
+        assert len(top.tokens) == NEW and top.finish_reason == "length"
+        phases = [phase for phase, _, _ in eng.setup_log]
+        assert phases.count(f"rungs_wait:{TOP}") == 1
+        assert phases.count("rungs_wait:512") <= 1
+        assert eng.prefill_calls == {512: 1, TOP: 1}
+    finally:
+        top_rung_held_back.set()
+        eng.close()
+
+
+def test_close_waits_for_every_rung(top_rung_held_back):
+    eng = _engine("gpt2")
+    threading.Timer(0.3, top_rung_held_back.set).start()
+    eng.close()
+    assert all(built.done() for built in eng._prefill_build.values())
+    assert sorted(eng._prefill_build) == [512, TOP]

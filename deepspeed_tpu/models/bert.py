@@ -240,8 +240,9 @@ class BertModel(TrainModule):
                 lp, i = xs
                 return body(carry, (gather_layer(lp, layer_specs), i))
 
-            x, _ = jax.lax.scan(
-                remat(body_gather), x, (params["layers"], jnp.arange(L)))
+            with jax.named_scope("layer"):
+                x, _ = jax.lax.scan(
+                    remat(body_gather), x, (params["layers"], jnp.arange(L)))
         else:
             body_fn = remat(body)
             for i in range(L):

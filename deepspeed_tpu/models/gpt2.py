@@ -197,8 +197,9 @@ class GPT2Model(TrainModule):
             def body_stream(carry, i):
                 return body(carry, (fetch(block_params, i), i))
 
-            x, _ = jax.lax.scan(remat(body_stream), x,
-                                jnp.arange(cfg.n_layer))
+            with jax.named_scope("layer"):
+                x, _ = jax.lax.scan(remat(body_stream), x,
+                                    jnp.arange(cfg.n_layer))
         elif cfg.scan_layers:
             # the layer's gather sits INSIDE the remat'd body: under ZeRO
             # the backward gathers the layer again instead of keeping 48
@@ -210,8 +211,9 @@ class GPT2Model(TrainModule):
                 return body(carry, (gather_layer(bp, block_specs), i))
 
             layer_idx = jnp.arange(cfg.n_layer)
-            x, _ = jax.lax.scan(remat(body_gather), x,
-                                (block_params, layer_idx))
+            with jax.named_scope("layer"):
+                x, _ = jax.lax.scan(remat(body_gather), x,
+                                    (block_params, layer_idx))
         else:
             body_fn = remat(body)
             for i in range(cfg.n_layer):
@@ -225,10 +227,11 @@ class GPT2Model(TrainModule):
         tokens = batch["input_ids"] if isinstance(batch, dict) else batch
         logits = self.apply(params, tokens[:, :-1], rng, train)
         targets = tokens[:, 1:]
-        logits = logits.astype(jnp.float32)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)
-        return jnp.mean(nll)
+        with jax.named_scope("lm_head"):
+            logits = logits.astype(jnp.float32)
+            logp = jax.nn.log_softmax(logits, axis=-1)
+            nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)
+            return jnp.mean(nll)
 
     # ---------------- serving entry points ----------------
     def prefill(self, params, tokens):
@@ -656,7 +659,8 @@ def gpt2_prefill(cfg: GPT2Config, params, tokens):
     if cfg.scan_layers:
         def body(x, bp):
             return gpt2_block_prefill(cfg, bp, x)
-        x, (ks, vs) = jax.lax.scan(body, x, block_params)
+        with jax.named_scope("layer"):
+            x, (ks, vs) = jax.lax.scan(body, x, block_params)
     else:
         ks_l, vs_l = [], []
         for i in range(cfg.n_layer):
@@ -700,8 +704,9 @@ def gpt2_decode_step(cfg: GPT2Config, params, tokens, k_cache, v_cache,
             x, kc, vc = gpt2_block_decode(cfg, bp, x, kc, vc, positions,
                                           att_len, active, impl)
             return x, (kc, vc)
-        x, (k_cache, v_cache) = jax.lax.scan(
-            body, x, (block_params, k_cache, v_cache))
+        with jax.named_scope("layer"):
+            x, (k_cache, v_cache) = jax.lax.scan(
+                body, x, (block_params, k_cache, v_cache))
     else:
         kc_l, vc_l = [], []
         for i in range(cfg.n_layer):
@@ -801,8 +806,9 @@ def gpt2_verify_step(cfg: GPT2Config, params, tokens, k_cache, v_cache,
             x, kc, vc = gpt2_block_verify(cfg, bp, x, kc, vc, positions,
                                           row_valid, row_lens, impl)
             return x, (kc, vc)
-        x, (k_cache, v_cache) = jax.lax.scan(
-            body, x, (block_params, k_cache, v_cache))
+        with jax.named_scope("layer"):
+            x, (k_cache, v_cache) = jax.lax.scan(
+                body, x, (block_params, k_cache, v_cache))
     else:
         kc_l, vc_l = [], []
         for i in range(cfg.n_layer):
@@ -885,8 +891,9 @@ def gpt2_verify_step_paged(cfg: GPT2Config, params, tokens, k_pool,
         xs = (block_params, k_pool, v_pool, k_scale, v_scale)
         if lora is not None:
             xs = xs + (lora,)
-        x, (k_pool, v_pool, k_scale, v_scale) = jax.lax.scan(
-            body, x, xs)
+        with jax.named_scope("layer"):
+            x, (k_pool, v_pool, k_scale, v_scale) = jax.lax.scan(
+                body, x, xs)
     else:
         kc_l, vc_l, ks_l, vs_l = [], [], [], []
         for i in range(cfg.n_layer):
@@ -1046,8 +1053,9 @@ def gpt2_decode_step_paged(cfg: GPT2Config, params, tokens, k_pool,
         xs = (block_params, k_pool, v_pool, k_scale, v_scale)
         if lora is not None:
             xs = xs + (lora,)
-        x, (k_pool, v_pool, k_scale, v_scale) = jax.lax.scan(
-            body, x, xs)
+        with jax.named_scope("layer"):
+            x, (k_pool, v_pool, k_scale, v_scale) = jax.lax.scan(
+                body, x, xs)
     else:
         kc_l, vc_l, ks_l, vs_l = [], [], [], []
         for i in range(cfg.n_layer):
@@ -1210,8 +1218,9 @@ def gpt2_prefill_paged(cfg: GPT2Config, params, tokens, delta_len,
         xs = (block_params, k_pool, v_pool, k_scale, v_scale)
         if lora is not None:
             xs = xs + (lora,)
-        x, (k_pool, v_pool, k_scale, v_scale) = jax.lax.scan(
-            body, x, xs)
+        with jax.named_scope("layer"):
+            x, (k_pool, v_pool, k_scale, v_scale) = jax.lax.scan(
+                body, x, xs)
     else:
         kc_l, vc_l, ks_l, vs_l = [], [], [], []
         for i in range(cfg.n_layer):

@@ -1,5 +1,6 @@
-"""``python -m deepspeed_tpu.telemetry summarize events.jsonl`` and
-``python -m deepspeed_tpu.telemetry diagnose <dir>``
+"""``python -m deepspeed_tpu.telemetry summarize events.jsonl``,
+``python -m deepspeed_tpu.telemetry diagnose <dir>`` and
+``python -m deepspeed_tpu.telemetry device <xplane.pb | dir>``
 
 Offline reports over the artifacts the hub writes: ``summarize`` turns
 an events.jsonl into p50/p95/p99 step time, samples/sec, serving
@@ -13,6 +14,10 @@ events.jsonl + ``replica_<id>/`` telemetry subdirs — docs/serving.md
 and the router's request ledger: first-failing replica, failover
 count, and dangling (submitted-but-never-completed) requests.  Both tolerate a torn final line (a killed
 run) and REPORT the skipped count instead of silently dropping it.
+``device`` reduces a captured device trace (the engine's ``profiler``
+block, the anomaly trigger, ``benchmark/run.py --trace 1``) to device
+seconds by program, scope and kind, idle seconds by span and the
+dispatch-to-run lag (telemetry/device_trace.py, imported when asked for).
 This module is pure stdlib, but the ``-m`` entry point imports the
 ``deepspeed_tpu`` package (which imports jax) — on a box without the
 runtime stack, copy this one file and run it directly:
@@ -929,6 +934,28 @@ def diagnose(directory: str, out=None) -> dict:
     return report
 
 
+def device(args, out=None) -> int:
+    from . import device_trace
+    out = out if out is not None else sys.stdout
+    try:
+        if args.trace.endswith(".json"):
+            with open(args.trace) as f:
+                planes = json.load(f)
+        else:
+            planes = device_trace.read(args.trace, window=args.window)
+        summary = device_trace.Reduction(
+            planes, window=args.window, depth=args.depth).summary(
+                by=args.by, top=args.top)
+    except (OSError, ValueError) as e:
+        print(f"error: {e}", file=sys.stderr)
+        return 2
+    if args.json:
+        print(json.dumps(summary), file=out)
+    else:
+        device_trace.render(summary, out)
+    return 0
+
+
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(
         prog="python -m deepspeed_tpu.telemetry",
@@ -950,7 +977,32 @@ def main(argv=None) -> int:
                              "trace.json) or a fleet directory "
                              "(router events.jsonl + replica_<id>/ "
                              "subdirs)")
+    p_dev = sub.add_parser(
+        "device",
+        help="device seconds by program, scope and kind, idle seconds by "
+             "span and the dispatch-to-run lag of a captured .xplane.pb")
+    p_dev.add_argument("trace",
+                       help="an .xplane.pb, a directory searched for the "
+                            "newest one, or an event list (.json) that "
+                            "device_trace.read() gave")
+    p_dev.add_argument("--window", default=None,
+                       help="reduce over the first host annotation of this "
+                            "name (bench/traced_window); default: the "
+                            "whole capture")
+    p_dev.add_argument("--by", choices=("scope", "op", "instruction"),
+                       default="scope",
+                       help="rows of the device table: (program, scope, "
+                            "kind), (operation family, scope) or "
+                            "(program, instruction, scope)")
+    p_dev.add_argument("--depth", type=int, default=2,
+                       help="scope names kept (layer/attn is 2)")
+    p_dev.add_argument("--top", type=int, default=40,
+                       help="rows of the device table printed")
+    p_dev.add_argument("--json", action="store_true",
+                       help="print the tables as one JSON object")
     args = parser.parse_args(argv)
+    if args.cmd == "device":
+        return device(args)
     if args.cmd == "summarize":
         try:
             summarize(args.events)

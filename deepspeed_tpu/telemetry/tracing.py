@@ -174,17 +174,46 @@ def span(tracer: Optional["TraceRecorder"], name: str,
     return SpanHandle(tracer, name, cat, args or None)
 
 
+def _pairs(args: Optional[dict]) -> Optional[tuple]:
+    return tuple(args.items()) if args else None
+
+
+def _as_dict(ev: tuple) -> dict:
+    """The Chrome trace event of a kept tuple, keys in the order the
+    recorder has always written them."""
+    ph, name, cat, pid, tid, ts, extra, args = ev
+    if ph == "X":
+        out = {"name": name, "cat": cat, "ph": ph, "pid": pid, "tid": tid,
+               "ts": ts, "dur": extra}
+    else:
+        out = {"name": name, "cat": cat, "ph": ph, "id": extra, "pid": pid,
+               "tid": tid, "ts": ts}
+        if ph == "f":
+            out["bp"] = "e"  # bind to the enclosing slice, like s/t do
+    if args:
+        out["args"] = dict(args)
+    return out
+
+
 class TraceRecorder:
     """Thread-safe, bounded trace-event buffer.
 
     ``max_events`` bounds memory for long runs; overflow increments a
     drop counter that ``export`` records as metadata instead of silently
-    truncating (the no-silent-caps rule)."""
+    truncating (the no-silent-caps rule).
+
+    An event is kept as a tuple of scalars, ``(ph, name, cat, pid, tid,
+    ts, dur or id, args as a tuple of pairs)``, and becomes a dict only in
+    ``events`` / ``export``: the collector stops tracking a tuple of
+    scalars once it has seen it (the pairs, the args, the event: three
+    passes at most), so a full collection walks one list and not 200,000
+    dicts (PERF.md section 6, PR 54: the walk was
+    what the ``process_stall`` watcher filed as ``gc`` stalls)."""
 
     def __init__(self, process_name: str = "deepspeed_tpu",
                  pid: int = 0, max_events: int = 200_000):
         self._lock = threading.Lock()
-        self._events: List[dict] = []
+        self._events: List[tuple] = []
         self._dropped = 0
         self._origin = time.perf_counter()
         #: the unix time of ts 0, exported so trace.json can be laid
@@ -211,7 +240,7 @@ class TraceRecorder:
             return tid
 
     # -- recording ------------------------------------------------------
-    def _append(self, ev: dict, force: bool = False) -> bool:
+    def _append(self, ev: tuple, force: bool = False) -> bool:
         """``force`` bypasses the cap — used ONLY for flow terminators,
         whose count is bounded by the flow starts already admitted (a
         dropped ``f`` would leave an ``s`` dangling and make diagnose
@@ -225,12 +254,9 @@ class TraceRecorder:
 
     def _emit_complete(self, name: str, cat: str, ts_us: float,
                        dur_us: float, args: Optional[dict]):
-        ev = {"name": name, "cat": cat, "ph": "X", "pid": self.pid,
-              "tid": self._tid(), "ts": round(ts_us, 3),
-              "dur": round(max(dur_us, 0.0), 3)}
-        if args:
-            ev["args"] = args
-        self._append(ev)
+        self._append(("X", name, cat, self.pid, self._tid(),
+                      round(ts_us, 3), round(max(dur_us, 0.0), 3),
+                      _pairs(args)))
 
     def span(self, name: str, cat: str = "runtime", **args) -> SpanHandle:
         return SpanHandle(self, name, cat, args or None)
@@ -240,12 +266,8 @@ class TraceRecorder:
 
     def _emit_async(self, ph: str, name: str, cat: str, span_id: int,
                     args: Optional[dict]):
-        ev = {"name": name, "cat": cat, "ph": ph, "id": int(span_id),
-              "pid": self.pid, "tid": self._tid(),
-              "ts": round(self._now_us(), 3)}
-        if args:
-            ev["args"] = args
-        self._append(ev)
+        self._append((ph, name, cat, self.pid, self._tid(),
+                      round(self._now_us(), 3), int(span_id), _pairs(args)))
 
     def async_begin(self, name: str, span_id: int, cat: str = "runtime",
                     **args) -> AsyncSpan:
@@ -269,13 +291,8 @@ class TraceRecorder:
 
     def _emit_flow(self, ph: str, name: str, cat: str, ctx,
                    args: Optional[dict]) -> bool:
-        ev = {"name": name, "cat": cat, "ph": ph, "id": self._flow_id(ctx),
-              "pid": self.pid, "tid": self._tid(),
-              "ts": round(self._now_us(), 3)}
-        if ph == "f":
-            ev["bp"] = "e"  # bind to the enclosing slice, like s/t do
-        if args:
-            ev["args"] = args
+        ev = (ph, name, cat, self.pid, self._tid(),
+              round(self._now_us(), 3), self._flow_id(ctx), _pairs(args))
         # terminators ride past the cap: an admitted "s" must never be
         # left dangling because its "f" arrived after the buffer filled
         return self._append(ev, force=(ph == "f"))
@@ -314,7 +331,8 @@ class TraceRecorder:
     # -- introspection / export -----------------------------------------
     def events(self) -> List[dict]:
         with self._lock:
-            return list(self._events)
+            kept = list(self._events)
+        return [_as_dict(ev) for ev in kept]
 
     @property
     def dropped(self) -> int:
@@ -324,9 +342,7 @@ class TraceRecorder:
     def export(self, path: str):
         """Write the Chrome trace-event JSON object form."""
         self.flush_flows()
-        with self._lock:
-            events = list(self._events)
-            dropped = self._dropped
+        events, dropped = self.events(), self.dropped
         meta = [{"name": "process_name", "ph": "M", "pid": self.pid,
                  "tid": 0, "ts": 0,
                  "args": {"name": self.process_name}}]

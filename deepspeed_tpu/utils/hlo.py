@@ -1,7 +1,8 @@
 """Read the collectives, the weights a program moves before it uses them,
 the fusions that draw random bits an element, how often each Mosaic
-kernel runs, and which matmuls lie under control flow the device decides,
-out of a compiled program's text.
+kernel runs, which matmuls lie under control flow the device decides, and
+which scope of the layer map every instruction belongs to, out of a
+compiled program's text.
 
 ``compiled.as_text()`` is the program after the SPMD partitioner: what a
 placement rule (runtime/zero.py) really costs is the collectives found
@@ -13,7 +14,10 @@ the threefry rounds fused into whatever reads the mask (``rng_fusions``);
 what a remat policy saves or recomputes is how often a kernel runs a call
 of the program (``kernel_calls``); whether a skip is a branch the device
 takes or a ``select`` over work done anyway is where its matmuls lie
-(``matmuls``).
+(``matmuls``); whose an instruction is, the attention's, the
+experts', the head's or nobody's, is the ``jax.named_scope`` path in its
+``op_name`` (``scopes``, ``scope_cycles``: the no-chip half of
+telemetry/device_trace.py, which groups a capture's time the same way).
 Bytes and counts only — no time is read from a program's text (a fusion's
 ``estimated_cycles`` is the compiler's guess, and is reported as that).
 """
@@ -324,3 +328,168 @@ def matmuls(hlo_text: str) -> List[Matmul]:
             for _, line, type_text, op, _, _, at_run_time in _reached(
                 *_computations(hlo_text))
             if op in ("dot", "convolution")]
+
+
+class Scoped(NamedTuple):
+    instruction: str   # its name where it is called
+    op: str            # opcode; a custom call adds ``:<its target>``
+    scope: str         # scope path of its ``op_name``, '' where it has none
+    op_name: str       # the ``op_name`` itself, '' where the metadata is empty
+    cycles: object     # the compiler's ``estimated_cycles`` of one
+    #                    execution, None where the text states none
+    times: int         # executions a call of the program (loop trip counts)
+
+
+UNSCOPED = "(unscoped)"
+_OP_NAME = re.compile(r'\bop_name="((?:[^"\\]|\\.)*)"')
+_TARGET = re.compile(r'custom_call_target="([^"]+)"')
+_WRAPPED = re.compile(r"^([\w\-]+)\((.*)\)$")
+_SCOPE = re.compile(r"^[A-Za-z_][\w.\-]*$")
+# what JAX's own constructs leave in a name stack: the function a ``jit``
+# names is dropped with it, the others are transparent
+_CALLS = ("jit", "pjit", "xla_call")
+_WRAPPERS = frozenset((
+    "while", "body", "cond", "checkpoint", "remat", "rematted_computation",
+    "closed_call", "core_call", "custom_jvp_call", "custom_vjp_call",
+    "custom_vjp_call_jaxpr", "custom_lin", "shard_map", "named_call",
+    "pallas_call", "run_scoped"))
+_BRANCH = re.compile(r"^branch_\d+(_fun)?$")
+
+
+def _split(path: str) -> List[str]:
+    """``a/jvp(b/c)/d`` -> [``a``, ``jvp(b/c)``, ``d``]."""
+    parts, depth, start = [], 0, 0
+    for i, ch in enumerate(path):
+        if ch == "(":
+            depth += 1
+        elif ch == ")":
+            depth -= 1
+        elif ch == "/" and depth == 0:
+            parts.append(path[start:i])
+            start = i + 1
+    parts.append(path[start:])
+    return parts
+
+
+def _scope_parts(path: str) -> List[str]:
+    parts: List[str] = []
+    for part in _split(path):
+        wrapped = _WRAPPED.match(part)
+        if wrapped:
+            if wrapped.group(1) not in _CALLS:
+                parts += _scope_parts(wrapped.group(2))
+        elif (part and part not in _WRAPPERS and not _BRANCH.match(part)
+              and _SCOPE.match(part)):
+            parts.append(part)
+    return parts
+
+
+def scope_path(op_name: str) -> str:
+    """The ``jax.named_scope`` path of an ``op_name``: the path less its
+    final primitive, less ``jit(...)`` with the function it names, with
+    ``jvp(...)``, ``transpose(...)``, ``vmap(...)`` opened, and less what
+    JAX's constructs add (``while`` / ``body`` / ``cond`` / ``branch_*``,
+    ``checkpoint`` / ``remat``, ``closed_call``, ``custom_vjp_call``,
+    ...), an ``einsum``'s subscripts and a scope a ``checkpoint`` repeats:
+    ``jit(f)/transpose(jvp(layer))/while/body/checkpoint/attn/dot_general``
+    -> ``layer/attn``; '' where nothing is left."""
+    parts = _split(op_name)
+    if not _WRAPPED.match(parts[-1]):
+        parts = parts[:-1]      # the primitive (``jvp(embed)`` is a scope)
+    kept: List[str] = []
+    for part in _scope_parts("/".join(parts)):
+        if not kept or kept[-1] != part:
+            kept.append(part)
+    return "/".join(kept)
+
+
+def cut(scope: str, depth: int) -> str:
+    """A scope path cut to ``depth`` names (each side of a ``mixed:``)."""
+    if scope.startswith("mixed:"):
+        sides = sorted({cut(s, depth) for s in scope[6:].split("+")})
+        return sides[0] if len(sides) == 1 else "mixed:" + "+".join(sides)
+    return "/".join(scope.split("/")[:depth]) or UNSCOPED
+
+
+def scopes(hlo_text: str) -> List[Scoped]:
+    """Every instruction the device runs as one operation (those of the
+    entry and of the loop bodies, conditions and branches it reaches; what
+    a fusion holds is part of the fusion), with the scope path of its
+    ``op_name`` (``scope_path``).  A fusion whose own metadata is empty
+    takes the scope its fused instructions share, ``mixed:<a>+<b>`` where
+    they disagree (nested fusions opened)."""
+    comps, entry = _computations(hlo_text)
+
+    def named(line: str):
+        m = _OP_NAME.search(line)
+        return m.group(1) if m else None
+
+    def fused(computation: str, seen: tuple = ()) -> set:
+        found = set()
+        for line in comps.get(computation, ()):
+            m = _INSTR.match(line)
+            if not m or m.group(2) in ("parameter", "constant"):
+                continue
+            op_name = named(line)
+            callee = _CALLEE.search(line)
+            if op_name is not None:
+                found.add(scope_path(op_name))
+            elif (m.group(2) == "fusion" and callee
+                  and callee.group(1) not in seen):
+                found |= fused(callee.group(1), seen + (computation,))
+        return found
+
+    # computations that are a fusion's (or a reduction's) inside: their
+    # instructions are no operations of their own
+    inner = set()
+    for lines in comps.values():
+        for line in lines:
+            m = _INSTR.match(line)
+            if m and m.group(2) not in ("while", "conditional", "call"):
+                inner.update(_CALLEE.findall(line))
+    found: List[Scoped] = []
+    for comp, line, _, op, times, _, _ in _reached(comps, entry):
+        if comp in inner or op in ("parameter", "constant", "tuple",
+                                   "get-tuple-element", "bitcast"):
+            continue
+        op_name = named(line)
+        scope = scope_path(op_name) if op_name is not None else ""
+        if op_name is None and op == "fusion" and _CALLEE.search(line):
+            shared = sorted(fused(_CALLEE.search(line).group(1)) - {""})
+            scope = (shared[0] if len(shared) == 1
+                     else "mixed:" + "+".join(shared) if shared else "")
+        target = _TARGET.search(line) if op == "custom-call" else None
+        cycles = _CYCLES.search(line)
+        found.append(Scoped(
+            _NAMED.match(line).group(1),
+            op + (":" + target.group(1) if target else ""), scope,
+            op_name or "", int(cycles.group(1)) if cycles else None, times))
+    return found
+
+
+_METADATA = re.compile(r',? ?metadata=\{(?:[^{}"]|"(?:[^"\\]|\\.)*")*\}')
+
+
+def less_metadata(hlo_text: str) -> str:
+    """The program's computations with every ``metadata={...}`` taken out
+    (and without the tables of files, functions and stack frames ahead of
+    them): what is left is what runs.  A ``jax.named_scope`` may change
+    the metadata, never this."""
+    comps, _ = _computations(hlo_text)
+    return "\n".join(
+        name + " {\n" + "\n".join(_METADATA.sub("", line) for line in lines)
+        + "\n}" for name, lines in comps.items())
+
+
+def scope_cycles(hlo_text: str, depth: int = 2) -> Dict[str, int]:
+    """The compiler's ``estimated_cycles`` a call of the program (trip
+    counts multiplied in) by scope cut to ``depth`` names, ``(unscoped)``
+    for what no scope of the layer map owns: the compiler's guess, of the
+    instructions it guesses for (fusions and copies; a Mosaic kernel, a
+    ``while`` and a collective state none)."""
+    total: Dict[str, int] = {}
+    for s in scopes(hlo_text):
+        if s.cycles:
+            key = cut(s.scope, depth)
+            total[key] = total.get(key, 0) + s.cycles * s.times
+    return total

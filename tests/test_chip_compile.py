@@ -1,5 +1,8 @@
 """The main path's kernels and serve programs, compiled for a TPU v5e
 that is described and not attached — at GPT-2 124M and GPT-2 XL widths.
+A whole program is compiled once and kept (``functools.cache`` on its
+builder): the tests that read it, and the ceiling on what no scope of the
+layer map owns at the end of the file, share one compile.
 
 Nothing runs: this guards against what interpret mode cannot see (tile
 alignment, scalar-prefetch and VMEM budgets, unsupported lowerings) at no
@@ -28,7 +31,8 @@ from deepspeed_tpu.ops.pallas.decode_attention import (
     paged_pages_per_block)
 from deepspeed_tpu.ops.pallas.flash_attention import flash_attention
 from deepspeed_tpu.ops.pallas.runtime import interpret_scope
-from deepspeed_tpu.utils.hlo import rng_fusions
+from deepspeed_tpu.utils.hlo import (UNSCOPED, less_metadata, rng_fusions,
+                                     scope_cycles, scopes)
 
 KERNEL = "tpu_custom_call"
 BF16 = jnp.bfloat16
@@ -270,6 +274,7 @@ def _serve_shapes():
     return model, params, pool
 
 
+@functools.cache
 def _gpt2_prefill_program(one_chip, bucket=128):
     model, params, pool = _serve_shapes()
     i32 = _sds((), jnp.int32)
@@ -279,6 +284,7 @@ def _gpt2_prefill_program(one_chip, bucket=128):
                         _sds((MAX_PAGES,), jnp.int32), pool, pool)
 
 
+@functools.cache
 def _gpt2_decode_program(one_chip):
     model, params, pool = _serve_shapes()
     with interpret_scope(False):
@@ -400,6 +406,7 @@ def test_bare_grad_wraps_the_kernel_name(one_chip):
         "transpose_jvp_ds_flash_bwd_dq__"], names
 
 
+@functools.cache
 def _gpt2_train_program(one_chip):
     """Forward + backward of GPT-2 124M's widths through the flash
     kernel, two layers deep under remat and the layer scan (the scan
@@ -450,6 +457,7 @@ def _olmoe_shapes(pages=1 + 4 * OLMOE_MAX_PAGES):
     return model, params, pool
 
 
+@functools.cache
 def _olmoe_decode_program(one_chip):
     model, params, pool = _olmoe_shapes()
     s = OLMOE_SLOTS
@@ -461,6 +469,7 @@ def _olmoe_decode_program(one_chip):
             _sds((s,), jnp.bool_))
 
 
+@functools.cache
 def _olmoe_prefill_program(one_chip):
     model, params, pool = _olmoe_shapes()
     i32 = _sds((), jnp.int32)
@@ -563,6 +572,7 @@ def _nemotron():
 
 
 @functools.lru_cache(maxsize=None)
+@functools.cache
 def _nemotron_program(program, one_chip, bucket=1024):
     """The model's paged step as the engine calls it: pools and state
     donated; a prefill at ``bucket`` tokens."""
@@ -737,6 +747,7 @@ def test_paged_decode_kernel_at_two_widths_keeps_its_name(one_chip):
 
 
 @functools.lru_cache(maxsize=None)
+@functools.cache
 def _mimo_program(program, one_chip, bucket=4096):
     """The model's paged step as the engine calls it: pools and window
     state donated; a prefill at ``bucket`` tokens."""
@@ -944,6 +955,7 @@ def test_moe_kernels_walk_an_expert_in_blocks_at_hidden_7168(tokens,
 
 
 @functools.lru_cache(maxsize=None)
+@functools.cache
 def _axk1_program(program, one_chip, bucket=4096):
     """The model's paged step as the engine calls it: the one pool
     donated, None where a second would be; a prefill at ``bucket`` tokens
@@ -1148,6 +1160,7 @@ def test_context_kernel_walks_a_chunks_context_where_it_lies(rung, one_chip):
 
 
 @functools.lru_cache(maxsize=None)
+@functools.cache
 def _glm_program(program, one_chip, bucket=2048):
     """The model's paged step as the engine calls it: both arrays donated,
     None where a second pool would be; a prefill at ``bucket`` tokens with
@@ -1333,6 +1346,7 @@ def test_moe_kernels_walk_an_expert_in_blocks_at_4096_by_4096(tokens,
 
 
 @functools.lru_cache(maxsize=None)
+@functools.cache
 def _cmda_program(program, one_chip, bucket=4096):
     """The model's paged step as the engine calls it: pool and rings
     donated; a prefill (or a chunk of one) at ``bucket`` tokens with its
@@ -1590,6 +1604,7 @@ def _train_step_text(model, batch, one_chip, saved: bool) -> str:
                         _sds((2,), jnp.uint32)).as_text()
 
 
+@functools.cache
 def _bert_large_step(one_chip, saved):
     from deepspeed_tpu.models.bert import BERT_LARGE, BertModel
     batch = {"input_ids": _sds((BERT_STACK_ROWS, 512), jnp.int32),
@@ -1745,6 +1760,7 @@ def test_kda_decode_kernel_keeps_its_name_and_the_state_in_place(one_chip):
 
 
 @functools.lru_cache(maxsize=None)
+@functools.cache
 def _kimi_program(program, one_chip, bucket=4096):
     """The model's paged step as the engine calls it: the one pool and the
     state donated, None where a second pool would be; a prefill at
@@ -1858,3 +1874,84 @@ def test_kimi_lower_prefill_rung_compiles_under_the_top_rungs_peak(one_chip):
     rung = _kimi_program("serve_prefill", one_chip, 2048).memory_analysis()
     assert rung.alias_size_in_bytes == top.alias_size_in_bytes
     assert rung.temp_size_in_bytes < top.temp_size_in_bytes
+
+
+# ---------------------------------------------------------------------------
+# whose the programs' instructions are (PR 54): ``utils/hlo.py::scope_cycles``
+# over the texts the tests above already hold.  Cycles are the compiler's
+# guess, of the instructions it guesses for (fusions and copies), in programs
+# that donate nothing: no time, and no share of the chip's (PERF.md section 5
+# has those, from traces).  What the ceiling guards is the layer map itself: a
+# refactor that drops a ``jax.named_scope``, or moves work outside one, shows
+# here with no chip.
+# ---------------------------------------------------------------------------
+
+#: program -> (its text, percent of its estimated cycles under
+#: ``(unscoped)`` + ``mixed:`` as read when PR 54 wrote this).  Where a
+#: reading is over 10 the cycles are copies the compiler put in and gave no
+#: ``op_name`` (a pool the test does not donate, a weight laid out again:
+#: ``scopes()`` lists them by instruction); no ``named_scope`` reaches those.
+SCOPED_PROGRAMS = {
+    "gpt2.serve_decode": (lambda c: _gpt2_decode_program(c).as_text(), 0.0),
+    "gpt2.serve_prefill": (lambda c: _gpt2_prefill_program(c).as_text(), 0.2),
+    "gpt2.train": (lambda c: _gpt2_train_program(c).as_text(), 0.2),
+    "bert.train": (lambda c: _bert_large_step(c, saved=True), 0.1),
+    "olmoe.serve_decode": (lambda c: _olmoe_decode_program(c).as_text(), 57.1),
+    "olmoe.serve_prefill": (lambda c: _olmoe_prefill_program(c).as_text(),
+                            9.9),
+    "nemotron.serve_decode": (
+        lambda c: _nemotron_program("serve_decode", c).as_text(), 1.4),
+    "mimo.serve_decode": (
+        lambda c: _mimo_program("serve_decode", c).as_text(), 38.7),
+    "axk1.serve_decode": (
+        lambda c: _axk1_program("serve_decode", c).as_text(), 0.7),
+    "axk1.serve_prefill": (
+        lambda c: _axk1_program("serve_prefill", c).as_text(), 14.2),
+    "cmda.serve_decode": (
+        lambda c: _cmda_program("serve_decode", c).as_text(), 32.4),
+    "glm.serve_decode": (
+        lambda c: _glm_program("serve_decode", c).as_text(), 27.1),
+    "kimi.serve_decode": (
+        lambda c: _kimi_program("serve_decode", c).as_text(), 6.4),
+}
+
+
+@pytest.mark.parametrize("program", sorted(SCOPED_PROGRAMS))
+def test_the_layer_map_owns_the_programs_estimated_cycles(program, one_chip):
+    """At most what it read when written + 2 points of a program's
+    estimated cycles belong to no scope of the layer map (or to two)."""
+    build, read = SCOPED_PROGRAMS[program]
+    text = build(one_chip)
+    cycles = scope_cycles(text)
+    total = sum(cycles.values())
+    assert total > 0
+    loose = sum(n for scope, n in cycles.items()
+                if scope == UNSCOPED or scope.startswith("mixed:"))
+    largest = sorted((s for s in scopes(text) if s.cycles and (
+        not s.scope or s.scope.startswith("mixed:"))),
+        key=lambda s: -s.cycles * s.times)[:6]
+    print(f"{program}: {100.0 * loose / total:.1f} % of {total} estimated "
+          f"cycles unscoped or mixed; largest: " + ", ".join(
+              f"{s.instruction} [{s.op_name}] x{s.times}" for s in largest))
+    assert 100.0 * loose / total <= read + 2.0, largest
+
+
+@pytest.mark.parametrize("program", ["gpt2.serve_decode", "gpt2.train"])
+def test_a_named_scope_changes_names_and_never_instructions(program, one_chip,
+                                                           monkeypatch):
+    """The program compiled with every ``with jax.named_scope(...)`` of
+    the model code a no-op (the ``layer`` around the layer scan and the
+    loss under ``lm_head`` of PR 54 among them) is the same program less
+    its metadata: the scan's slices of the pool, 95 % of the decode
+    tick's estimated cycles, have an owner and not one instruction
+    moved."""
+    import contextlib
+    build = {"gpt2.serve_decode": _gpt2_decode_program,
+             "gpt2.train": _gpt2_train_program}[program]
+    scoped = build(one_chip).as_text()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = build.__wrapped__(one_chip).as_text()
+    assert less_metadata(bare) == less_metadata(scoped)
+    assert scope_cycles(bare).get(UNSCOPED, 0) \
+        > 10 * scope_cycles(scoped).get(UNSCOPED, 0)

@@ -102,6 +102,78 @@ def test_trace_recorder_span_and_export(tmp_path):
     assert marker["ts"] + marker["dur"] < outer["ts"]
 
 
+def _fixed_recorder():
+    """A recorder on a clock that counts, holding one event of every
+    kind."""
+    import itertools
+    tr = TraceRecorder(process_name="golden", pid=7)
+    ticks = itertools.count()
+    tr._now_us = lambda: next(ticks) * 1000.0 + 0.1234
+    tr._origin_unix_ns = 1_700_000_000_000_000_000
+    tr.span("outer", cat="test", step=3, where="a").end(n=1)
+    tr.span("bare").end()
+    req = tr.async_begin("serve/request", 11, cat="serve", rid=11)
+    req.end(tokens=5)
+    tr.flow_start("batch", 21, n=2)
+    tr.flow_step("batch", 21)
+    tr.flow_end("batch", 21, cat="flow", ok=True)
+    tr.flow_start("left_open", 22)
+    tr._origin = 0.0
+    tr.complete("process_stall", 0.5, 0.25, cat="stall", cause="gc")
+    return tr
+
+
+GOLDEN_TRACE = (
+    '{"traceEvents": [{"name": "process_name", "ph": "M", "pid": 7, "tid": '
+    '0, "ts": 0, "args": {"name": "golden"}}, {"name": "outer", "cat": '
+    '"test", "ph": "X", "pid": 7, "tid": 0, "ts": 0.123, "dur": 1000.0, '
+    '"args": {"step": 3, "where": "a", "n": 1}}, {"name": "bare", "cat": '
+    '"runtime", "ph": "X", "pid": 7, "tid": 0, "ts": 2000.123, "dur": '
+    '1000.0}, {"name": "serve/request", "cat": "serve", "ph": "b", "id": 11, '
+    '"pid": 7, "tid": 0, "ts": 4000.123, "args": {"rid": 11}}, {"name": '
+    '"serve/request", "cat": "serve", "ph": "e", "id": 11, "pid": 7, "tid": '
+    '0, "ts": 5000.123, "args": {"tokens": 5}}, {"name": "batch", "cat": '
+    '"flow", "ph": "s", "id": 21, "pid": 7, "tid": 0, "ts": 6000.123, '
+    '"args": {"n": 2}}, {"name": "batch", "cat": "flow", "ph": "t", "id": '
+    '21, "pid": 7, "tid": 0, "ts": 7000.123}, {"name": "batch", "cat": '
+    '"flow", "ph": "f", "id": 21, "pid": 7, "tid": 0, "ts": 8000.123, "bp": '
+    '"e", "args": {"ok": true}}, {"name": "left_open", "cat": "flow", "ph": '
+    '"s", "id": 22, "pid": 7, "tid": 0, "ts": 9000.123}, {"name": '
+    '"process_stall", "cat": "stall", "ph": "X", "pid": 7, "tid": 0, "ts": '
+    '500000.0, "dur": 250000.0, "args": {"cause": "gc"}}, {"name": '
+    '"left_open", "cat": "flow", "ph": "f", "id": 22, "pid": 7, "tid": 0, '
+    '"ts": 10000.123, "bp": "e", "args": {"flushed": true}}], '
+    '"displayTimeUnit": "ms", "otherData": {"origin_unix_ns": '
+    '1700000000000000000}}')
+
+
+def test_trace_json_is_byte_for_byte_what_the_dict_recorder_wrote(tmp_path):
+    """The recorder keeps tuples (PR 54); ``trace.json`` is the file the
+    recorder of dicts wrote, key order and all (the string below was
+    written by the parent commit's recorder from the same calls)."""
+    path = _fixed_recorder().export(str(tmp_path / "trace.json"))
+    assert open(path).read() == GOLDEN_TRACE
+
+
+def test_a_full_recorder_leaves_the_collector_one_list_to_walk():
+    """Kept events are tuples of scalars, which the collector stops
+    tracking once it has seen what they hold (the pairs, then the args,
+    then the event: three passes at most): then none of a buffer's events
+    is a tracked object (a dict each was, so a full collection walked the
+    whole buffer; PERF.md section 6, PR 54)."""
+    import gc
+    tr = TraceRecorder(max_events=2000)
+    for i in range(2000):
+        tr.span("serve/tick", cat="serve", tick=i, active=3).end(produced=1)
+    for _ in range(3):
+        gc.collect()
+    kept = tr._events
+    assert len(kept) == 2000
+    assert not any(gc.is_tracked(ev) for ev in kept)
+    assert not any(gc.is_tracked(ev[-1]) for ev in kept)
+    assert tr.events()[5]["args"] == {"tick": 5, "active": 3, "produced": 1}
+
+
 def test_trace_recorder_bounds_events():
     tr = TraceRecorder(max_events=10)
     for i in range(25):

@@ -246,6 +246,28 @@ _STATE_UNSUPPORTED = ("prefix_cache", "kv_tier", "slot_cache",
                       "speculate_k")
 
 
+def params_at_rest(model, params, shardings):
+    """``params`` as an engine holds them on ``shardings`` (a tree like
+    ``params``): each leaf the caller's own, placed, or, where the model
+    declares a form at rest for it (``serving_layouts(params)``, a tree of
+    None or a form with a traceable ``.of(leaf)``: ``models/walked.py``), a
+    new leaf in that form.  A model without the method declares nothing.
+    Returns (the tree, how many leaves were made anew, their bytes)."""
+    declared = getattr(model, "serving_layouts", None)
+    if declared is None:
+        return jax.tree.map(jax.device_put, params, shardings), 0, 0
+    anew = []
+
+    def hold(x, sharding, form):
+        if form is None:
+            return jax.device_put(x, sharding)
+        anew.append(x.nbytes)
+        return jax.jit(form.of, out_shardings=sharding)(x)
+
+    held = jax.tree.map(hold, params, shardings, declared(params))
+    return held, len(anew), sum(anew)
+
+
 def _percentile(sorted_vals: List[float], q: float) -> Optional[float]:
     from ..telemetry.cli import _percentile as p
     return p(sorted_vals, q)
@@ -263,8 +285,9 @@ class _SetupPhase:
         self._span = tracing.span(eng._tracer, "serve/setup_" + phase,
                                   cat="serve", **args)
 
-    def end(self) -> None:
-        self._span.end()
+    def end(self, **found) -> None:
+        """``found``: what the phase counted, onto its span."""
+        self._span.end(**found)
         eng, dt = self._eng, time.perf_counter() - self._t0
         eng.setup_log.append((self._label, self._t0, dt))
         if eng.telemetry is not None:
@@ -503,9 +526,23 @@ class ServeEngine:
         self._param_shardings = jax.tree.map(
             lambda s: NamedSharding(mesh, s), pspecs,
             is_leaf=lambda s: isinstance(s, P))
-        self.params = jax.tree.map(jax.device_put, params,
-                                   self._param_shardings)
-        phase.end()
+        #: a leaf the model declares a form at rest for
+        #: (``serving_layouts``) is made in it, once: new bytes beside the
+        #: caller's, whose tree is left as it came; the others are the
+        #: caller's own buffers, as ever.  How many those are and their
+        #: bytes: gauges ``serve_params_relaid_leaves`` /
+        #: ``serve_params_relaid_bytes``, args of the
+        #: ``serve/setup_params`` span
+        self.params, self.params_relaid_leaves, self.params_relaid_bytes \
+            = params_at_rest(model, params, self._param_shardings)
+        phase.end(relaid_leaves=self.params_relaid_leaves,
+                  relaid_bytes=self.params_relaid_bytes)
+        if self.params_relaid_leaves:
+            logger.info(
+                "serve params: %d leaves (%.3f GB) made anew in the form %s "
+                "declares for them at rest, beside the caller's",
+                self.params_relaid_leaves, self.params_relaid_bytes / 1e9,
+                type(model).__name__)
         wte = params["wte"] if isinstance(params, dict) else None
         kv_dtype = wte.dtype if wte is not None else jnp.float32
         self.page_len = cfg.serving.page_len
@@ -1137,6 +1174,14 @@ class ServeEngine:
                 "device bytes of the serving params (target + draft; "
                 "int8 weights + scales under quantization)")
             self._param_bytes_gauge.set(self.param_bytes)
+            reg.gauge(
+                "serve_params_relaid_bytes",
+                "device bytes of the param leaves made anew in the form "
+                "their model declares for them at rest (serving_layouts): "
+                "held beside the caller's own").set(self.params_relaid_bytes)
+            reg.gauge(
+                "serve_params_relaid_leaves",
+                "how many leaves those are").set(self.params_relaid_leaves)
             self._kv_bytes_gauge = reg.gauge(
                 "serve_kv_bytes",
                 "device bytes of the KV cache from its spec (both "

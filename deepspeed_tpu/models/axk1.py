@@ -248,6 +248,11 @@ class AxK1Model(WalkedModel):
     #: request keeps pages and nothing else)
     serving_aux = WalkedModel.serving_aux + (
         "latent_kv_tokens", "latent_context_rows", "latent_context_pairs")
+    #: ``query_projections`` stays empty: this tick's compiled text copies
+    #: no ``q_b_w`` ([1536, 12288] at 192 rows: the compiler reads it where
+    #: it lies), so a form at rest (``WalkedModel.serving_layouts``) has
+    #: nothing to remove here, and declared it changed how the tick
+    #: prefetches ``v_b_w`` (described v5e, PR 55)
 
     def serving_cache_layers(self) -> Dict[str, int]:
         """Layers by the kind of cache they keep."""

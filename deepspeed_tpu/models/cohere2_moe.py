@@ -63,7 +63,11 @@ kind in the order they occur, ``full`` and ``window``: ``ln``, ``q_w``,
 each a TUPLE of one array a layer (``models/mimo_v2.py``'s rule: what a
 layer reads by its own index is a leaf of its own); ``experts``:
 ``gate_w`` / ``up_w`` [L, held, d, f], ``down_w`` [L, held, f, d] over ALL
-layers in order, stacked: they reach their kernels whole.
+layers in order, stacked: they reach their kernels whole.  Every matrix is
+input-major; the form a ``q_w`` rests in inside an engine is
+``WalkedModel.serving_layouts``'s to say (output-major: from this one
+the tick wrote all four, 134 MB each, to HBM transposed before the matmul
+that read it, 1.7 ms of ~21; PERF.md section 6, PR 55).
 """
 from __future__ import annotations
 
@@ -192,6 +196,7 @@ class Cohere2MoeModel(WalkedModel):
     serving_aux = WalkedModel.serving_aux + (
         "full_kv_tokens", "window_kv_rows", "window_wrapped_slots",
         "flash_q_rows", "flash_live_keys")
+    query_projections = ("q_w",)      # walked.serving_layouts
 
     def serving_cache_layers(self) -> Dict[str, int]:
         """Layers by the kind of cache they keep."""

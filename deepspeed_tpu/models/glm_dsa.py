@@ -72,6 +72,10 @@ Parameter tree: ``wte``, ``lm_head`` [d, V], ``norm_f``; ``attn`` (as
 [q_lora_rank, J * D], ``wk_w`` [d, D], ``k_norm_w`` / ``k_norm_b`` [D],
 ``weights_proj_w`` [d, J]); ``dense`` and ``moe`` as ``axk1.py``'s, with
 ``router_bias`` [E] float32 (``e_score_correction_bias``, drawn zero).
+The form a ``q_b_w`` rests in inside an engine is
+``WalkedModel.serving_layouts``'s to say (output-major: from this one
+the tick copied all seven, 67 MB each, before the matmul that read it;
+PERF.md section 6, PR 55).
 """
 from __future__ import annotations
 
@@ -262,6 +266,7 @@ class GlmDsaModel(WalkedModel):
     serving_aux = WalkedModel.serving_aux + (
         "latent_kv_tokens", "index_scored_rows", "index_selected_rows",
         "latent_context_rows", "latent_context_pairs")
+    query_projections = ("q_b_w",)      # walked.serving_layouts
 
     def serving_cache_layers(self) -> Dict[str, int]:
         """Layers by the kind of cache they keep."""

@@ -63,7 +63,9 @@ head_dim], ``f_a_w`` / ``f_b_w``, ``A_log`` [H], ``dt_bias``, ``b_w``,
 ``gate_w``, ``up_w``, ``down_w``); ``moe`` (``ln2``, ``router_w`` [d, E],
 ``router_bias`` [E], ``shared_*_w`` and the routed ``gate_w`` / ``up_w``
 [layers, held, d, f], ``down_w``).  Every matrix input-major; a leaf a
-layer, the experts alone stacked (``models/mimo_v2.py``'s rule).
+layer, the experts alone stacked (``models/mimo_v2.py``'s rule); the
+form the ``mla`` layers' ``q_w`` rest in inside an engine is
+``WalkedModel.serving_layouts``'s to say (PR 55).
 """
 from __future__ import annotations
 
@@ -250,6 +252,7 @@ class KimiLinearModel(WalkedModel):
     serving_aux = WalkedModel.serving_aux + (
         "kda_slot_layers", "latent_kv_tokens", "kda_chunk_tokens",
         "latent_context_rows")
+    query_projections = ("q_w",)      # walked.serving_layouts
 
     def serving_cache_layers(self) -> Dict[str, int]:
         """Layers by the kind of cache they keep."""

@@ -70,9 +70,16 @@ slice of one is a copy: XLA wrote every ``q_w`` of a decode tick to HBM
 transposed, and passed every window layer's ``k_w`` and ``v_w`` through
 fast memory, before the matmul read it (0.83 GB a tick at MiMo-V2.5's
 widths, 2 of its 21.5 ms; PERF.md section 6, PR 39).  ``walked.at`` is
-the one place that picks a layer.  The experts alone stay stacked,
-``moe``'s ``gate_w`` / ``up_w`` / ``down_w`` as ``[layers, held, ...]``
-arrays: they reach their kernels whole, every layer's held experts flat
+the one place that picks a layer.  A leaf a layer still left one copy a
+``q_w`` a tick: the compiler wants the wide operand of ``h @ q_w``
+output-major and may not choose an entry parameter's layout.  Where a
+``q_w`` RESTS is decided in one place since PR 55,
+``WalkedModel.serving_layouts`` (``query_projections`` below names the
+leaves): ``ServeEngine`` makes its copy of them output-major once, at
+set-up (``walked.OutputMajor``: ``w.T``), and ``project_heads`` reads
+either; the tree here and its shapes are as they were.  The experts alone
+stay stacked, ``moe``'s ``gate_w`` / ``up_w`` / ``down_w`` as ``[layers,
+held, ...]`` arrays: they reach their kernels whole, every layer's held experts flat
 (``walked.stacked_experts``, a reshape of the leading axes), and the
 kernel finds a layer's by ``expert_offset``.
 """
@@ -244,6 +251,7 @@ class MimoV2Model(WalkedModel):
         "prefill_chunk_len",)
     serving_aux = WalkedModel.serving_aux + ("full_kv_tokens",
                                              "window_kv_rows")
+    query_projections = ("q_w",)      # walked.serving_layouts
 
     def serving_cache_layers(self) -> Dict[str, int]:
         """Layers by the kind of cache they keep."""

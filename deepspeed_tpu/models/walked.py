@@ -23,7 +23,7 @@ reads of every one of them alike.
 """
 from __future__ import annotations
 
-from typing import Dict, Optional
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +31,31 @@ import jax.numpy as jnp
 from ..moe.dropless import dropless_moe, route_sigmoid_topk
 
 F32 = jnp.float32
+
+
+@jax.tree_util.register_pytree_node_class
+class OutputMajor:
+    """A matrix ``w [d, n]`` as it RESTS inside a ``ServeEngine`` where its
+    model says so (:meth:`WalkedModel.serving_layouts`): ``t = w.T``, its
+    second (output) axis major.  A pytree node around the one array, so a
+    step function tells an engine's leaf from a caller's by its TYPE
+    (:func:`project_heads`), not by a name or a shape."""
+    __slots__ = ("t",)
+
+    def __init__(self, t):
+        self.t = t                      # [n, d]
+
+    @classmethod
+    def of(cls, w):
+        """The leaf as a caller holds it -> as it rests (traceable)."""
+        return cls(jnp.transpose(w))
+
+    def tree_flatten(self):
+        return (self.t,), None
+
+    @classmethod
+    def tree_unflatten(cls, _, children):
+        return cls(*children)
 
 
 class ServedConfig:
@@ -80,12 +105,35 @@ class WalkedModel:
     serving_aux = ("moe_experts_hit", "moe_load_imbalance", "moe_rows",
                    "moe_rows_elsewhere")
     refusal_note = ""                   # whose such an arm is, if anyone's
+    #: the family's query projections by leaf name, ``[d, heads * width]``
+    #: read through :func:`project_heads`: what :meth:`serving_layouts`
+    #: declares; a family that names none is placed as ever
+    query_projections: Tuple[str, ...] = ()
 
     def __init__(self, config):
         self.config = config
 
     def param_partition_specs(self, params):
         return None                     # one chip: everything replicated
+
+    def serving_layouts(self, params):
+        """How each leaf of ``params`` RESTS inside a ``ServeEngine``: a
+        tree like ``params`` of None (as the caller holds it) or a form
+        with ``.of(leaf)``.  ONE rule: a query projection rests
+        :class:`OutputMajor`, which is how the TPU compiler wants the wide
+        operand of ``h @ w`` at few rows: from ``[d, n]`` row-major it
+        wrote the whole matrix to HBM transposed before every such matmul,
+        once a layer, every tick (PERF.md section 6, PR 55).  The engine
+        makes ITS copy of these leaves so once (``setup_params``) and its
+        programs read them through :func:`project_heads`; the caller's
+        tree, the leaf names and every program run on the caller's tree
+        are as ever.  A family whose compiled tick says otherwise of its
+        leaves names none (``models/axk1.py``)."""
+        def rests(path, _):
+            named = any(getattr(k, "key", None) in self.query_projections
+                        for k in path)
+            return OutputMajor if named else None
+        return jax.tree_util.tree_map_with_path(rests, params)
 
     def decode_impl(self, impl: Optional[str]) -> str:
         """The decode kernels' arm where the engine names none."""
@@ -132,9 +180,14 @@ def rope(x, positions, theta: float, rotary_dim: Optional[int] = None,
 
 
 def project_heads(h, w, n: int):
-    """h [B, T, d] @ w [d, n * width] -> [B, n, T, width]."""
+    """h [B, T, d] @ w [d, n * width] -> [B, n, T, width]; ``w`` as a
+    caller holds it, or :class:`OutputMajor` as an engine does."""
     B, T, _ = h.shape
-    return (h @ w.astype(h.dtype)).reshape(B, T, n, -1).transpose(0, 2, 1, 3)
+    if isinstance(w, OutputMajor):
+        y = jnp.einsum("btd,nd->btn", h, w.t.astype(h.dtype))
+    else:
+        y = h @ w.astype(h.dtype)
+    return y.reshape(B, T, n, -1).transpose(0, 2, 1, 3)
 
 
 def merge_heads(t):

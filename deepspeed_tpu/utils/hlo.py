@@ -202,7 +202,8 @@ def parameter_rewrites(hlo_text: str, parameters: int,
     the first ``parameters`` entry parameters (the leaves of a jitted
     function's first argument, its weights) and writes between ``share``
     of that parameter's bytes and all of them: the weight, or one layer
-    of a stacked one, moved and not used.  A matmul fused with its
+    of a stacked one, moved and not used (read directly or, a ``copy``,
+    through a ``bitcast`` of the whole parameter: PR 55).  A matmul fused with its
     weight writes activations and is not listed while those are under
     the share or more than it read (a decode tick's are; a prefill's
     are as large as a matrix and do get listed); neither is an
@@ -211,10 +212,17 @@ def parameter_rewrites(hlo_text: str, parameters: int,
     read once more, its ``hbm_bytes`` written and read again."""
     comps, entry = _computations(hlo_text)
     lines = [m.groups() for m in map(_NAMED.match, comps[entry]) if m]
-    held = {}
+    held, viewed = {}, {}
     for name, type_text, op, operands in lines:
         if op == "parameter" and int(operands) < parameters:
             held[name] = (int(operands), _nbytes(_arrays(type_text)))
+        elif op == "bitcast":
+            # the same bytes under another shape: ``copy(bitcast(param))``
+            # is how the compiler writes a transpose of a whole weight (a
+            # FUSION over such a view is a cache's update in place)
+            source = held.get(operands.strip().lstrip("%"))
+            if source is not None:
+                viewed[name] = source
     found = []
     for name, type_text, op, operands in lines:
         if op not in ("fusion", "copy"):
@@ -223,7 +231,8 @@ def parameter_rewrites(hlo_text: str, parameters: int,
         wrote = _nbytes(a for a, _ in results)
         in_hbm = _nbytes(a for a, layout in results if "S(1)" not in layout)
         for operand in _OPERAND.findall(operands):
-            number, size = held.get(operand, (None, 0))
+            number, size = held.get(operand) or (
+                op == "copy" and viewed.get(operand)) or (None, 0)
             if number is not None and share * size <= wrote <= size:
                 found.append(Rewrite(name, op, number, wrote, in_hbm))
     return found

@@ -78,6 +78,21 @@ def _compile(fn, sharding, *shapes):
     return compiled
 
 
+def _program_args(shapes, one_chip, model=None):
+    """``shapes`` (a step's operands, the params first) on the described
+    chip; with ``model``, the params as ``ServeEngine`` holds them
+    (``inference/engine.py::params_at_rest``: each leaf in the form the
+    model declares for it at rest)."""
+    if model is not None:
+        params = shapes[0]
+        held = jax.tree.map(
+            lambda a, form: a if form is None else jax.eval_shape(form.of, a),
+            params, model.serving_layouts(params))
+        shapes = (held,) + tuple(shapes[1:])
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), shapes)
+
+
 def _sds(shape, dtype=BF16):
     return jax.ShapeDtypeStruct(shape, dtype)
 
@@ -748,7 +763,7 @@ def test_paged_decode_kernel_at_two_widths_keeps_its_name(one_chip):
 
 @functools.lru_cache(maxsize=None)
 @functools.cache
-def _mimo_program(program, one_chip, bucket=4096):
+def _mimo_program(program, one_chip, bucket=4096, relaid=False):
     """The model's paged step as the engine calls it: pools and window
     state donated; a prefill at ``bucket`` tokens."""
     from deepspeed_tpu.models.mimo_v2 import MimoV2Config, MimoV2Model
@@ -778,8 +793,7 @@ def _mimo_program(program, one_chip, bucket=4096):
                   _sds((MIMO_MAX_PAGES,), jnp.int32), k_pool, v_pool, state,
                   i32)
         donate = (4, 5, 6)
-    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-        a.shape, a.dtype, sharding=one_chip), shapes)
+    args = _program_args(shapes, one_chip, model if relaid else None)
     with interpret_scope(False):
         return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
 
@@ -819,18 +833,22 @@ def test_mimo_decode_tick_reads_each_layers_matrices_where_they_lie(
     ``slice_bitcast_fusion`` and every window layer's ``k_w`` / ``v_w``
     to fast memory, before a ``copy`` brought it to the matmul: 0.83 GB
     a tick moved once more than the model needs, 0.112 GB of
-    temporaries (PR 39).  Now no fusion of the entry computation writes a weight again;
-    what is left is one ``copy`` a ``q_w`` from the parameter into fast
-    memory in the layout the dot takes, the matrix's one read; and the
-    tick's temporaries are 0.015 GB.  A leaf stacked again trips this."""
+    temporaries (PR 39).  Since then no fusion of the entry computation
+    writes a weight again; PR 39 left one ``copy`` a ``q_w`` (100.7 MB
+    each, 1.19 ms of a tick on the chip), and with the ``q_w`` resting
+    output-major as the engine holds them (PR 55) those are gone too:
+    what is copied is a window layer's ``k_w`` (12.6 MB) and one ``v_w``
+    into fast memory, nothing into HBM; the tick's temporaries are
+    0.015 GB.  A leaf stacked again trips this."""
     from deepspeed_tpu.utils.hlo import parameter_rewrites
-    compiled = _mimo_program("serve_decode", one_chip)
+    compiled = _mimo_program("serve_decode", one_chip, relaid=True)
     weights = len(jax.tree.leaves(compiled.in_avals[0][0]))
     assert weights == 3 + 2 * 5 + 5 * 6 + 4 + 6 * 3 + 3
     moved = [r for r in parameter_rewrites(compiled.as_text(), weights)
              if r.bytes >= 1 << 20]
     assert [r for r in moved if r.op != "copy" or r.hbm_bytes] == [], moved
     assert len({r.parameter for r in moved}) == len(moved) <= 7, moved
+    assert max(r.bytes for r in moved) < 16 << 20, moved
     assert compiled.memory_analysis().temp_size_in_bytes < 0.03e9
 
 
@@ -956,7 +974,7 @@ def test_moe_kernels_walk_an_expert_in_blocks_at_hidden_7168(tokens,
 
 @functools.lru_cache(maxsize=None)
 @functools.cache
-def _axk1_program(program, one_chip, bucket=4096):
+def _axk1_program(program, one_chip, bucket=4096, relaid=False):
     """The model's paged step as the engine calls it: the one pool
     donated, None where a second would be; a prefill at ``bucket`` tokens
     with its prefix length TRACED, so both forms of its attention (from
@@ -979,8 +997,7 @@ def _axk1_program(program, one_chip, bucket=4096):
         shapes = (params, _sds((1, bucket), jnp.int32), i32, i32,
                   _sds((AXK1_MAX_PAGES,), jnp.int32), pool)
         donate = (5,)
-    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-        a.shape, a.dtype, sharding=one_chip), shapes)
+    args = _program_args(shapes, one_chip, model if relaid else None)
     with interpret_scope(False):
         return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
 
@@ -1161,7 +1178,7 @@ def test_context_kernel_walks_a_chunks_context_where_it_lies(rung, one_chip):
 
 @functools.lru_cache(maxsize=None)
 @functools.cache
-def _glm_program(program, one_chip, bucket=2048):
+def _glm_program(program, one_chip, bucket=2048, relaid=False):
     """The model's paged step as the engine calls it: both arrays donated,
     None where a second pool would be; a prefill at ``bucket`` tokens with
     its prefix length TRACED (a whole prompt and a chunk are one
@@ -1188,8 +1205,7 @@ def _glm_program(program, one_chip, bucket=2048):
         shapes = (params, _sds((1, bucket), jnp.int32), i32, i32,
                   _sds((GLM_MAX_PAGES,), jnp.int32), pool, keys)
         donate = (5, 6)
-    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-        a.shape, a.dtype, sharding=one_chip), shapes)
+    args = _program_args(shapes, one_chip, model if relaid else None)
     with interpret_scope(False):
         return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
 
@@ -1347,7 +1363,7 @@ def test_moe_kernels_walk_an_expert_in_blocks_at_4096_by_4096(tokens,
 
 @functools.lru_cache(maxsize=None)
 @functools.cache
-def _cmda_program(program, one_chip, bucket=4096):
+def _cmda_program(program, one_chip, bucket=4096, relaid=False):
     """The model's paged step as the engine calls it: pool and rings
     donated; a prefill (or a chunk of one) at ``bucket`` tokens with its
     prefix length TRACED, so both forms of its attention (from nothing;
@@ -1372,8 +1388,7 @@ def _cmda_program(program, one_chip, bucket=4096):
         shapes = (params, _sds((1, bucket), jnp.int32), i32, i32,
                   _sds((CMDA_MAX_PAGES,), jnp.int32), pool, pool, state, i32)
         donate = (5, 6, 7)
-    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-        a.shape, a.dtype, sharding=one_chip), shapes)
+    args = _program_args(shapes, one_chip, model if relaid else None)
     with interpret_scope(False):
         return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
 
@@ -1415,16 +1430,16 @@ def test_command_a_plus_programs_hold_their_kernels_and_no_copy_of_a_cache(
 def test_command_a_plus_tick_reads_each_layers_matrices_where_they_lie(
         one_chip):
     """A leaf a layer (``models/mimo_v2.py``'s rule), the experts alone
-    stacked: no fusion of the tick's entry computation writes a weight
-    again; what is copied is a matrix's one read into the layout its dot
-    takes."""
+    stacked, the query projections resting output-major as the engine
+    holds them (PR 55): nothing in the tick's entry computation writes a
+    megabyte of a weight again, to HBM or to fast memory."""
     from deepspeed_tpu.utils.hlo import parameter_rewrites
-    compiled = _cmda_program("serve_decode", one_chip)
+    compiled = _cmda_program("serve_decode", one_chip, relaid=True)
     weights = len(jax.tree.leaves(compiled.in_avals[0][0]))
     assert weights == 2 + 4 * 9 + 3
     moved = [r for r in parameter_rewrites(compiled.as_text(), weights)
              if r.bytes >= 1 << 20]
-    assert [r for r in moved if r.op != "copy" or r.hbm_bytes] == [], moved
+    assert moved == [], moved
 
 
 def test_command_a_plus_lower_prefill_rung_compiles_under_the_top_rungs_peak(
@@ -1761,7 +1776,7 @@ def test_kda_decode_kernel_keeps_its_name_and_the_state_in_place(one_chip):
 
 @functools.lru_cache(maxsize=None)
 @functools.cache
-def _kimi_program(program, one_chip, bucket=4096):
+def _kimi_program(program, one_chip, bucket=4096, relaid=False):
     """The model's paged step as the engine calls it: the one pool and the
     state donated, None where a second pool would be; a prefill at
     ``bucket`` tokens with its prefix length TRACED (a chunk)."""
@@ -1785,8 +1800,7 @@ def _kimi_program(program, one_chip, bucket=4096):
         shapes = (params, _sds((1, bucket), jnp.int32), i32, i32,
                   _sds((KIMI_MAX_PAGES,), jnp.int32), pool, state, i32)
         donate = (5, 6)
-    args = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-        a.shape, a.dtype, sharding=one_chip), shapes)
+    args = _program_args(shapes, one_chip, model if relaid else None)
     with interpret_scope(False):
         return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
 
@@ -1874,6 +1888,51 @@ def test_kimi_lower_prefill_rung_compiles_under_the_top_rungs_peak(one_chip):
     rung = _kimi_program("serve_prefill", one_chip, 2048).memory_analysis()
     assert rung.alias_size_in_bytes == top.alias_size_in_bytes
     assert rung.temp_size_in_bytes < top.temp_size_in_bytes
+
+
+# ---------------------------------------------------------------------------
+# the form a query projection rests in (PR 55): ``ServeEngine`` holds the
+# leaves a family declares (``WalkedModel.serving_layouts``) output-major,
+# made once, and ``walked.project_heads`` contracts the last axes of both
+# ---------------------------------------------------------------------------
+
+#: family -> (its builder, the query projection's leaf name, how many the
+#: cell's tick reads)
+RELAID_TICKS = {"cmda": (_cmda_program, "q_w", 4),
+                "glm": (_glm_program, "q_b_w", 7),
+                "mimo": (_mimo_program, "q_w", 7)}
+
+
+@pytest.mark.parametrize("family", sorted(RELAID_TICKS))
+def test_the_tick_copies_every_query_weight_from_the_default_layout_and_none_at_rest(
+        family, one_chip):
+    """From the default layout the compiler copies every query
+    projection whole before the matmul that reads it, once a layer, every
+    tick (Command A+: ``copy(bitcast(param))``, 134 MB each written to HBM
+    transposed; GLM-5.2 and MiMo-V2.5: into fast memory); held as the
+    engine holds them (``inference/engine.py::params_at_rest``: ``w.T``
+    under ``walked.OutputMajor``) the tick copies none, and writes no
+    megabyte of any weight to HBM."""
+    from deepspeed_tpu.utils.hlo import parameter_rewrites
+    build, leaf, count = RELAID_TICKS[family]
+
+    def moved(compiled):
+        flat = jax.tree_util.tree_flatten_with_path(compiled.in_avals[0][0])
+        names = [jax.tree_util.keystr(path) for path, _ in flat[0]]
+        found = [(names[r.parameter], r) for r in parameter_rewrites(
+            compiled.as_text(), len(names)) if r.bytes >= 1 << 20]
+        return ([n for n, r in found if f"['{leaf}']" in n],
+                [n for n, r in found if r.hbm_bytes])
+
+    default = build("serve_decode", one_chip)
+    queries, _ = moved(default)
+    assert len(queries) == len(set(queries)) == count, queries
+    relaid = build("serve_decode", one_chip, relaid=True)
+    assert moved(relaid) == ([], [])
+    # the same leaves, the declared ones transposed
+    turned = [a.shape == d.shape[::-1] != d.shape for a, d in zip(
+        *(jax.tree.leaves(c.in_avals[0][0]) for c in (relaid, default)))]
+    assert turned.count(True) == count
 
 
 # ---------------------------------------------------------------------------

@@ -8,11 +8,19 @@
   page, and attend over / count as length 0;
 * latent attention's expanded form over a paged context, which two
   families share: every key under the causal rule, or the keys a mask
-  allows beside it.
+  allows beside it;
+* the form a leaf rests in inside an engine (``serving_layouts``, PR 55):
+  a family declares its query projections and nothing else, the engine
+  makes its own copy of them so and emits the same tokens, and the
+  caller's tree is the same objects afterwards.
 """
 import ast
+import dataclasses
+import importlib
+import json
 import os
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -347,3 +355,117 @@ def test_latent_context_attention_at_axk1s_widths_without_a_mask(
         {k: f32(v) for k, v in ap.items()}, f32(q_nope), f32(q_rope),
         f32(c_kv.astype(bf)), f32(k_rope.astype(bf)), ok, 0.07)
     np.testing.assert_allclose(np.asarray(got, np.float32), dense, atol=3e-2)
+
+
+# -- the form a leaf rests in (PR 55) ----------------------------------------
+
+#: family -> (its cell's configuration file, its classes' prefix, the leaves
+#: it declares: kind -> name; A.X-K1 declares none, ``models/axk1.py`` says
+#: why)
+LAYOUT_FAMILIES = {
+    "mimo_v2": ("mimo-v2.5", "MimoV2", {"full": "q_w", "window": "q_w"}),
+    "axk1": ("a.x-k1", "AxK1", {}),
+    "cohere2_moe": ("command-a-plus-05-2026", "Cohere2Moe",
+                    {"full": "q_w", "window": "q_w"}),
+    "glm_dsa": ("glm-5.2", "GlmDsa", {"attn": "q_b_w"}),
+    "kimi_linear": ("kimi-linear-48b-a3b", "KimiLinear", {"mla": "q_w"})}
+
+
+def _toy(family):
+    """The family at its cell's rehearsal sizes (``benchmark/configs``):
+    (model, the rehearsal's ``serving`` block)."""
+    name, prefix, _ = LAYOUT_FAMILIES[family]
+    with open(os.path.join(os.path.dirname(MODELS), "..", "benchmark",
+                           "configs", name + ".json")) as f:
+        file = json.load(f)
+    module = importlib.import_module("deepspeed_tpu.models." + family)
+    config = getattr(module, prefix + "Config")
+    fields = {f.name for f in dataclasses.fields(config)}
+    sizes = {k: v for k, v in file.items() if k in fields}
+    sizes.update(file["rehearse"]["sizes"])
+    sizes = {k: tuple(v) if isinstance(v, list) else v
+             for k, v in sizes.items()}
+    return (getattr(module, prefix + "Model")(config(**sizes)),
+            dict(file["rehearse"]["serving"], prefix_cache=False))
+
+
+def test_project_heads_reads_a_projection_as_either_side_holds_it():
+    """``w`` as a caller holds it and ``OutputMajor.of(w)`` as an engine
+    does give the same heads; the form is a pytree node around ``w.T``."""
+    rng = np.random.default_rng(5)
+    h = jnp.asarray(rng.normal(size=(2, 3, 8)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(8, 12)), jnp.float32)
+    held = walked.OutputMajor.of(w)
+    assert [x.shape for x in jax.tree.leaves(held)] == [(12, 8)]
+    got = jax.jit(walked.project_heads, static_argnums=2)(h, held, 4)
+    assert got.shape == (2, 4, 3, 3)
+    np.testing.assert_allclose(got, walked.project_heads(h, w, 4),
+                               rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("family", sorted(LAYOUT_FAMILIES))
+def test_serving_layouts_names_the_query_projections_and_nothing_else(family):
+    model, _ = _toy(family)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    layouts = model.serving_layouts(params)
+    assert jax.tree.structure(layouts, is_leaf=lambda x: x is None) \
+        == jax.tree.structure(params)
+    declared = {jax.tree_util.keystr(path): layout for path, layout
+                in jax.tree_util.tree_flatten_with_path(layouts)[0]}
+    want = {f"['{kind}']['{name}'][{i}]"
+            for kind, name in LAYOUT_FAMILIES[family][2].items()
+            for i in range(len(params[kind][name]))}
+    assert set(declared) == want
+    assert all(form is walked.OutputMajor for form in declared.values())
+    # the rule knows no shape and no name of a model: a family that names
+    # no projection declares nothing
+    assert type(model).query_projections == tuple(
+        sorted(set(LAYOUT_FAMILIES[family][2].values())))
+
+
+@pytest.mark.parametrize("family", sorted(LAYOUT_FAMILIES))
+def test_an_engine_on_relaid_leaves_emits_the_same_tokens(family):
+    """A prefill (in chunks where the cell's rehearsal has them) and 8
+    greedy ticks of three requests: the engine that holds the declared
+    leaves output-major against one whose model declares nothing; the tree
+    the caller handed over holds the same arrays afterwards, none
+    wrapped."""
+    from deepspeed_tpu.inference import ServeEngine
+    model, serving = _toy(family)
+    params = model.init(jax.random.PRNGKey(3))
+    before, structure = jax.tree.flatten(params)
+    plain = type("Undeclared", (type(model),), {"query_projections": ()})(
+        model.config)
+
+    def streams(m):
+        eng = ServeEngine(m, {"serving": serving}, params=params)
+        try:
+            reqs = [eng.submit(list(range(5 + i, 27 + 3 * i)),
+                               max_new_tokens=8) for i in range(3)]
+            eng.run_until_idle()
+            assert eng._decode_fn._cache_size() == 1
+            return ([r.result() for r in reqs], eng.params_relaid_leaves,
+                    eng.params_relaid_bytes, eng.params)
+        finally:
+            eng.close()
+
+    tokens, leaves, nbytes, held = streams(model)
+    declared = jax.tree.leaves(model.serving_layouts(params),
+                               is_leaf=lambda form: form is None)
+    queries = [x for x, form in zip(before, declared) if form]
+    assert leaves == len(queries) and nbytes == sum(
+        x.nbytes for x in queries)
+    assert bool(queries) == bool(LAYOUT_FAMILIES[family][2])
+    # the engine's leaf is the caller's transposed, a new array; every
+    # other leaf has the caller's shape
+    for x, mine, form in zip(before, jax.tree.leaves(held), declared):
+        assert mine.shape == (x.shape[::-1] if form else x.shape)
+        if form:
+            np.testing.assert_array_equal(mine, x.T)
+    for kind, name in LAYOUT_FAMILIES[family][2].items():
+        assert all(isinstance(leaf, walked.OutputMajor)
+                   for leaf in held[kind][name])
+    assert streams(plain)[:3] == (tokens, 0, 0)
+    after, unchanged = jax.tree.flatten(params)
+    assert unchanged == structure and all(
+        a is b for a, b in zip(before, after))

@@ -330,8 +330,9 @@ def test_collectives_reads_the_tpu_compilers_text():
 # the forms of a decode tick compiled for a v5e (tests/test_chip_compile.py
 # reads the real one): a stacked weight cut by a fusion that writes one
 # layer to HBM and one to fast memory, a per-layer weight brought to fast
-# memory by one copy, a weight fused with its matmul, the compiler's own
-# prefetch, and a cache that is no weight
+# memory by one copy, a weight written to HBM transposed through a bitcast,
+# a weight fused with its matmul, the compiler's own prefetch, and a cache
+# that is no weight
 _REWRITES = """\
 HloModule jit_fn, is_scheduled=true
 
@@ -349,6 +350,8 @@ ENTRY %main (w: bf16[2,64,32], q: bf16[64,32], o: bf16[64,32], kv: bf16[64,32]) 
   %slice_bitcast_fusion.remat = (bf16[32,64]{0,1:T(8,128)(2,1)}, bf16[32,64]{0,1:T(8,128)(2,1)S(1)}) fusion(%p__stacked__.1), kind=kLoop, calls=%fused_slice
   %copy.160 = bf16[64,32]{0,1:T(8,128)(2,1)S(1)} copy(%p__q_w___0_.1), sharding={replicated}
   %copy.161 = bf16[64,32]{0,1:T(8,128)(2,1)} copy(%kv.1)
+  %bitcast.9 = bf16[32,64]{0,1:T(8,128)(2,1)} bitcast(%p__o_w___0_.1)
+  %copy.99 = bf16[32,64]{1,0:T(8,128)(2,1)} copy(%bitcast.9)
   %copy-start.2 = (bf16[64,32]{1,0:T(8,128)(2,1)S(1)}, bf16[64,32]{1,0:T(8,128)(2,1)}, u32[]{:S(2)}) copy-start(%p__o_w___0_.1)
   ROOT %fusion.7 = bf16[4,32]{1,0:T(8,128)(2,1)S(1)} fusion(%copy.160, %p__o_w___0_.1), kind=kOutput, calls=%fused_slice
 }
@@ -361,6 +364,8 @@ def test_parameter_rewrites_lists_the_weights_a_program_moves():
         Rewrite("slice_bitcast_fusion", "fusion", 0, 4096, 4096),
         Rewrite("slice_bitcast_fusion.remat", "fusion", 0, 8192, 4096),
         Rewrite("copy.160", "copy", 1, 4096, 0),
+        # a whole weight transposed to HBM: the copy of a bitcast of it
+        Rewrite("copy.99", "copy", 2, 4096, 4096),
     ]
     # the matmul fused with ``o_w`` writes 256 B of activations: under an
     # eighth of the weight; a share of 0 lists it too

@@ -360,7 +360,8 @@ class ServeEngine:
       ``state=`` and return it after the pools (``prefill_paged(...,
       state=, slot=)`` -> ``(logits, k_pool, v_pool, state)``,
       ``decode_step_paged(..., state=)`` -> ``(logits, k_pool, v_pool,
-      state, new_lengths)``), donated with the rest of the cache.  A
+      state, new_lengths)``; a model that also keeps ``index_pool``
+      returns the state after it), donated with the rest of the cache.  A
       prefill OVERWRITES the state of the slot it is told (the slot the
       request is admitted to); a decode tick leaves an inactive slot's
       state alone (a free slot's, and one still prefilling in chunks); no
@@ -835,6 +836,9 @@ class ServeEngine:
                                   for k in aux_keys])
 
             indexed = bool(self.cache_spec.index_layers)
+            # a model that keeps request state AND an indexer's keys hands
+            # the keys back after the pools and the state after the keys
+            state_at = 3 + indexed
 
             def index_kw(cache):
                 """The indexer keys' array for a model that keeps one
@@ -886,7 +890,7 @@ class ServeEngine:
                 if quant_kv:
                     newc["k_scale"], newc["v_scale"] = out[3], out[4]
                 if stateful:
-                    newc["state"] = out[3]
+                    newc["state"] = out[state_at]
                 if aux_kw:
                     return newc, first_tok, pack_aux(out[-1])
                 return newc, first_tok
@@ -910,7 +914,7 @@ class ServeEngine:
                 if quant_kv:
                     newc["k_scale"], newc["v_scale"] = out[3], out[4]
                 if stateful:
-                    newc["state"] = out[3]
+                    newc["state"] = out[state_at]
                 return (newc, next_tok) + stats
 
             # copy-on-write: duplicate one page (src/dst traced — zero
@@ -1205,7 +1209,8 @@ class ServeEngine:
                     "serve_cache_layers",
                     "layers by the kind of cache they keep: full (every "
                     "key, in the page pool), window (the last keys, by "
-                    "slot), latent (one row a token in the page pool) or "
+                    "slot), latent (one row a token in the page pool), "
+                    "window_latent (the last latent rows, by slot) or "
                     "index (an indexer key a token beside the rows)")
                 for kind, n in layers().items():
                     layer_gauge.set(n, kind=kind)
@@ -1237,7 +1242,8 @@ class ServeEngine:
                     "serve_state_bytes",
                     "device bytes a stateful model's requests hold by "
                     "kind: each serving_state leaf (ssm, conv; window_k, "
-                    "window_v; kda, kda_conv) and the page pool (kv; latent "
+                    "window_v; kda, kda_conv; window_latent) and the page "
+                    "pool (kv; latent "
                     "where it is "
                     "one pool of latent rows, index_k the indexer keys "
                     "paged beside them)")

@@ -87,17 +87,12 @@ import jax.numpy as jnp
 
 from .walked import (F32, PagePool, ServedConfig, WalkedModel, at,
                      decode_index, default_scale, dense_ffn, draw_layers,
-                     expand_latents, held_expert_counters,
+                     expand_latents, held_expert_counters, index_projections,
                      latent_context_attention, latent_context_pairs,
                      latent_projections, latent_rows, lm_head, merge_heads,
-                     prefill_index, project_heads, rms_norm, rope,
-                     routed_experts, shared_expert, stacked_experts,
-                     whole_tiles)
-
-#: cached keys a step of :func:`_chunk_index_scores` scores (whole pages)
-_SCORE_BLOCK = 512
-#: queries of a chunk whose picks :func:`_chunk_picks` makes at a time
-_PICK_QUERIES = 512
+                     prefill_index, rms_norm, routed_experts, shared_expert,
+                     stacked_experts, whole_tiles)
+from .walked import chunk_picks as _chunk_picks, pick_mask as _pick_mask
 
 
 def _place(kinds, layer: int) -> int:
@@ -521,119 +516,12 @@ class GlmDsaModel(WalkedModel):
 
 # -- the layer's parts ----------------------------------------------------
 
-def _layer_norm(x, weight, bias, eps: float = 1e-6):
-    """LayerNorm in float32, back to x's type (the indexer key's)."""
-    xf = x.astype(F32)
-    mu = jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
-    y = (xf - mu) * jax.lax.rsqrt(var + eps)
-    return (y * weight.astype(F32) + bias.astype(F32)).astype(x.dtype)
-
-
-@jax.named_scope("index_q")
 def _index_projections(cfg: GlmDsaConfig, ip, h, c_q, positions):
-    """h [B, T, d] (normed), c_q [B, T, q_lora_rank] -> the indexer's
-    queries q_I [B, J, T, D] (rotated), its key k_I [B, T, D] (normed,
-    rotated: what the cache keeps) and the heads' weights w [B, T, J]
-    float32 with both scales folded in."""
-    J, D, rot = cfg.index_n_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
-    q_i = rope(project_heads(c_q, ip["wq_b_w"], J), positions,
-               cfg.rope_theta, rotary_dim=rot)
-    k_i = _layer_norm(h @ ip["wk_w"].astype(h.dtype), ip["k_norm_w"],
-                      ip["k_norm_b"])
-    k_i = rope(k_i[:, None], positions, cfg.rope_theta, rotary_dim=rot)[:, 0]
-    w = (h @ ip["weights_proj_w"].astype(h.dtype)).astype(F32) \
-        * (J ** -0.5 * D ** -0.5)
-    return q_i, k_i, w
-
-
-def _chunk_index_scores(q_i, w, index_pages, page_ids, abs_pos, context_len):
-    """The indexer's scores of a chunk's queries q_i [J, Tq, D], w [Tq, J]
-    at positions ``abs_pos`` [Tq] over the request's cached keys
-    (``index_pages`` [X, page_len, D]; ``page_ids`` [max_pages] its pages
-    of this layer, the chunk's own keys already written) up to
-    ``context_len`` (traced), a block of whole pages at a time.  Returns
-    [Tq, max_pages * page_len] float32, ``-inf`` at a key after the query
-    and past the context."""
-    J, Tq, D = q_i.shape
-    page_len = index_pages.shape[1]
-    ppb = max(1, _SCORE_BLOCK // page_len)
-    bk = ppb * page_len
-    cap = page_ids.shape[0] * page_len
-    ids = jnp.pad(page_ids, (0, (-page_ids.shape[0]) % ppb))
-    wt = w.T[:, :, None]                                     # [J, Tq, 1]
-
-    def block(j, scores):
-        keys = index_pages[jax.lax.dynamic_slice_in_dim(
-            ids, j * ppb, ppb)].reshape(bk, D)
-        s = jnp.einsum("jtd,kd->jtk", q_i, keys.astype(q_i.dtype),
-                       preferred_element_type=F32)
-        s = jnp.sum(jnp.maximum(s, 0.0) * wt, axis=0)        # [Tq, bk]
-        at_key = j * bk + jnp.arange(bk, dtype=jnp.int32)
-        s = jnp.where(at_key[None, :] <= abs_pos[:, None], s, -jnp.inf)
-        return jax.lax.dynamic_update_slice_in_dim(scores, s, j * bk, axis=1)
-
-    scores = jax.lax.fori_loop(
-        0, (context_len + bk - 1) // bk, block,
-        jnp.full((Tq, ids.shape[0] * page_len), -jnp.inf, F32))
-    return scores[:, :cap]
-
-
-def _chunk_picks(q_i, w, index_pages, page_ids, abs_pos, context_len,
-                 k: int):
-    """The picked sets of a chunk's queries as a mask [Tq, max_pages *
-    page_len]: scores (:func:`_chunk_index_scores`), then the ``k``
-    largest of each query (:func:`_pick_mask`), ``_PICK_QUERIES`` queries
-    at a time so that the float32 scores of the whole context and the
-    selection's own temporaries are a block's."""
-    J, Tq, D = q_i.shape
-    bq = min(_PICK_QUERIES, Tq)
-    assert Tq % bq == 0, (Tq, bq)
-
-    def block(args):
-        q, wb, pos = args
-        with jax.named_scope("index_score"):
-            scores = _chunk_index_scores(q, wb, index_pages, page_ids, pos,
-                                         context_len)
-        with jax.named_scope("index_topk"):
-            return _pick_mask(scores, k)
-
-    masks = jax.lax.map(block, (
-        q_i.reshape(J, Tq // bq, bq, D).transpose(1, 0, 2, 3),
-        w.reshape(Tq // bq, bq, J), abs_pos.reshape(Tq // bq, bq)))
-    return masks.reshape(Tq, -1)
-
-
-def _pick_mask(scores, k: int):
-    """scores [..., N] float32 (``-inf``: not a candidate) -> bool [...,
-    N]: the ``min(k, candidates)`` largest of a row, ties to the lower
-    index.  Exact, and no sort: the ``k``-th largest value is found a bit
-    at a time (32 counts over the row, on the floats' bits put in the
-    floats' order), and of the entries equal to it the first so many as
-    are still wanted; that last step costs a running count, taken only
-    where some row has more equal entries than it wants.  (XLA's ``top_k``
-    of 24,576 scores a query sorts them: 14.7 ms for 512 queries on a v5e
-    against 0.6 here; my chip run, PR 49.)"""
-    k = min(k, scores.shape[-1])
-    live = scores > -jnp.inf
-    bits = jax.lax.bitcast_convert_type(
-        jnp.where(scores == 0.0, 0.0, scores), jnp.uint32)     # -0.0 is 0.0
-    order = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
-
-    def bit(i, kth):
-        trial = kth | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
-        enough = jnp.sum(order >= trial[..., None], axis=-1) >= k
-        return jnp.where(enough, trial, kth)
-
-    kth = jax.lax.fori_loop(0, 32, bit, jnp.zeros(scores.shape[:-1],
-                                                  jnp.uint32))[..., None]
-    above = order > kth
-    tie = (order == kth) & live
-    wanted = k - jnp.sum(above, axis=-1, keepdims=True)
-    tie = jax.lax.cond(
-        jnp.any(jnp.sum(tie, axis=-1, keepdims=True) > wanted),
-        lambda: tie & (jnp.cumsum(tie, axis=-1) <= wanted), lambda: tie)
-    return (above & live) | tie
+    """:func:`walked.index_projections` at this config's widths."""
+    return index_projections(
+        ip, h, c_q, positions, heads=cfg.index_n_heads,
+        dim=cfg.index_head_dim, rot=cfg.qk_rope_head_dim,
+        theta=cfg.rope_theta)
 
 
 def _experts(cfg: GlmDsaConfig, ep, stacked, index: int, x, valid):

@@ -1,6 +1,7 @@
 """What the served families whose layers are WALKED share: ``olmoe.py``
 (the one that scans), ``nemotron_h.py``, ``mimo_v2.py``, ``axk1.py``,
-``cohere2_moe.py``, ``glm_dsa.py``, ``kimi_linear.py``.  A family's file
+``cohere2_moe.py``, ``glm_dsa.py``, ``kimi_linear.py``, ``dots3_note.py``.  A
+family's file
 holds what is its own: its config under the source's keys, ``init`` and
 the parameter tree, its projections, latents and mixers, its list of layer
 kinds, and two paged steps that read as that list walked over the pieces
@@ -12,10 +13,13 @@ keep their sources' key names: ``rms_norm_eps``, ``layernorm_epsilon``,
 ``layer_norm_epsilon``, ``layer_norm_eps``): the mathematics two or more
 families call; the index preludes of the two paged steps
 (:func:`decode_index`, :func:`prefill_index`); the cache's bookkeeping as
-two pairs of write and attend (:class:`PagePool`, :class:`Rings`); the
+two pairs of write and attend (:class:`PagePool`, :class:`Rings`) and the
+ring of ONE array of latent rows (:class:`LatentRing`); the
 routed-expert call (:func:`routed_experts`); latent attention's
 projections, rows at rest and expanded form, from nothing or over a paged
-context (``axk1.py``, ``glm_dsa.py``, ``kimi_linear.py``).  ONE rule for what is not
+context (``axk1.py``, ``glm_dsa.py``, ``kimi_linear.py``,
+``dots3_note.py``); the learned indexer's projections and picks
+(``glm_dsa.py``, ``dots3_note.py``).  ONE rule for what is not
 live: an inactive slot and a padded prompt row name page 0, the engine's
 scratch page, are kept out of every write and attend over length 0.
 :class:`ServedConfig` and :class:`WalkedModel` are what ``ServeEngine``
@@ -271,19 +275,23 @@ def whole_tiles(width: int) -> int:
 def latent_projections(ap, h, positions, *, heads: int, nope: int,
                        kv_rank: int, eps: float, theta: float,
                        inv_freq=None, low_rank_q: bool = True,
-                       rotate: bool = True):
+                       rotate: bool = True, q_scale=None, kv_scale=None):
     """h [B, T, d] (normed), positions [B, T] -> c_q [B, T, q_lora_rank]
     (normed), q_nope [B, H, T, nope], q_rope [B, H, T, rot] (rotated),
     c_kv [B, T, kv_rank] (normed), k_rope [B, T, rot] (rotated): what the
     cache keeps is the last two.  ``low_rank_q`` false: the query comes
     straight from ``ap["q_w"]`` and ``c_q`` is None; ``rotate`` false:
-    nothing is rotated and ``positions`` is not read."""
+    nothing is rotated and ``positions`` is not read.  ``q_scale`` /
+    ``kv_scale``: a factor on the normed latent (``c_q``, ``c_kv``) where
+    the source rescales them (``models/dots3_note.py``); None: none."""
     def turned(t):
         return rope(t, positions, theta, inv_freq=inv_freq) if rotate else t
 
     with jax.named_scope("latent_q"):
         c_q = rms_norm(h @ ap["q_a_w"].astype(h.dtype), ap["q_a_norm"],
                        eps) if low_rank_q else None
+        if q_scale is not None:
+            c_q = c_q * jnp.asarray(q_scale, c_q.dtype)
         q = project_heads(c_q, ap["q_b_w"], heads) if low_rank_q \
             else project_heads(h, ap["q_w"], heads)
         q_nope = q[..., :nope]
@@ -291,6 +299,8 @@ def latent_projections(ap, h, positions, *, heads: int, nope: int,
     with jax.named_scope("latent_kv"):
         kv = h @ ap["kv_a_w"].astype(h.dtype)
         c_kv = rms_norm(kv[..., :kv_rank], ap["kv_a_norm"], eps)
+        if kv_scale is not None:
+            c_kv = c_kv * jnp.asarray(kv_scale, c_kv.dtype)
         k_rope = turned(kv[:, None, :, kv_rank:])[:, 0]
     return c_q, q_nope, q_rope, c_kv, k_rope
 
@@ -313,9 +323,10 @@ def expand_latents(ap, c_kv, dtype):
 
 
 def latent_self_attention(ap, q_nope, q_rope, c_kv, k_rope, *, flash: bool,
-                          sm_scale: float):
+                          sm_scale: float, window=None):
     """The expanded form over whole sequences from position 0: q_* [B, H,
-    T, .], c_kv [B, T, C], k_rope [B, T, rot] -> [B, H, T, v_head_dim]."""
+    T, .], c_kv [B, T, C], k_rope [B, T, rot] -> [B, H, T, v_head_dim];
+    ``window``: the last so many keys, the query's own included."""
     k_nope, v = expand_latents(ap, c_kv, q_nope.dtype)
     k_rope = jnp.broadcast_to(k_rope[:, None], q_rope.shape)
     # the two widths at rest in whole lane tiles (192 -> 256), zeros in
@@ -324,7 +335,8 @@ def latent_self_attention(ap, q_nope, q_rope, c_kv, k_rope, *, flash: bool,
     pad = ((0, 0),) * 3 + ((0, whole_tiles(width) - width),)
     q = jnp.pad(jnp.concatenate([q_nope, q_rope], axis=-1), pad)
     k = jnp.pad(jnp.concatenate([k_nope, k_rope], axis=-1), pad)
-    return causal_self_attention(q, k, v, flash, sm_scale=sm_scale)
+    return causal_self_attention(q, k, v, flash, sm_scale=sm_scale,
+                                 window=window)
 
 
 def latent_context_attention(ap, q_nope, q_rope, pool_pages, page_ids,
@@ -379,6 +391,131 @@ def latent_context_pairs(abs_pos, context_len, allowed=None):
     at = jnp.arange(allowed.shape[1], dtype=jnp.int32)[None, :]
     return jnp.sum(allowed & (at <= abs_pos[:, None]) & (at < context_len),
                    dtype=F32)
+
+
+# -- the learned indexer (DeepSeek sparse attention): what its families share --
+
+#: cached keys a step of :func:`chunk_index_scores` scores (whole pages)
+SCORE_BLOCK = 512
+#: queries of a chunk whose picks :func:`chunk_picks` makes at a time
+PICK_QUERIES = 512
+
+
+
+def layer_norm_bias(x, weight, bias, eps: float = 1e-6):
+    """LayerNorm in float32, back to x's type (the indexer key's)."""
+    xf = x.astype(F32)
+    mu = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
+    y = (xf - mu) * jax.lax.rsqrt(var + eps)
+    return (y * weight.astype(F32) + bias.astype(F32)).astype(x.dtype)
+
+
+@jax.named_scope("index_q")
+def index_projections(ip, h, c_q, positions, *, heads: int, dim: int,
+                      rot: int, theta: float):
+    """h [B, T, d] (normed), c_q [B, T, q_lora_rank] -> the indexer's
+    queries q_I [B, J, T, D] (rotated), its key k_I [B, T, D] (normed,
+    rotated: what the cache keeps) and the heads' weights w [B, T, J]
+    float32 with both scales folded in; ``heads`` J of ``dim`` D, the
+    first ``rot`` dims rotated at ``theta``."""
+    q_i = rope(project_heads(c_q, ip["wq_b_w"], heads), positions, theta,
+               rotary_dim=rot)
+    k_i = layer_norm_bias(h @ ip["wk_w"].astype(h.dtype), ip["k_norm_w"],
+                          ip["k_norm_b"])
+    k_i = rope(k_i[:, None], positions, theta, rotary_dim=rot)[:, 0]
+    w = (h @ ip["weights_proj_w"].astype(h.dtype)).astype(F32) \
+        * (heads ** -0.5 * dim ** -0.5)
+    return q_i, k_i, w
+
+
+def chunk_index_scores(q_i, w, index_pages, page_ids, abs_pos, context_len):
+    """The indexer's scores of a chunk's queries q_i [J, Tq, D], w [Tq, J]
+    at positions ``abs_pos`` [Tq] over the request's cached keys
+    (``index_pages`` [X, page_len, D]; ``page_ids`` [max_pages] its pages
+    of this layer, the chunk's own keys already written) up to
+    ``context_len`` (traced), a block of whole pages at a time.  Returns
+    [Tq, max_pages * page_len] float32, ``-inf`` at a key after the query
+    and past the context."""
+    J, Tq, D = q_i.shape
+    page_len = index_pages.shape[1]
+    ppb = max(1, SCORE_BLOCK // page_len)
+    bk = ppb * page_len
+    cap = page_ids.shape[0] * page_len
+    ids = jnp.pad(page_ids, (0, (-page_ids.shape[0]) % ppb))
+    wt = w.T[:, :, None]                                     # [J, Tq, 1]
+
+    def block(j, scores):
+        keys = index_pages[jax.lax.dynamic_slice_in_dim(
+            ids, j * ppb, ppb)].reshape(bk, D)
+        s = jnp.einsum("jtd,kd->jtk", q_i, keys.astype(q_i.dtype),
+                       preferred_element_type=F32)
+        s = jnp.sum(jnp.maximum(s, 0.0) * wt, axis=0)        # [Tq, bk]
+        at_key = j * bk + jnp.arange(bk, dtype=jnp.int32)
+        s = jnp.where(at_key[None, :] <= abs_pos[:, None], s, -jnp.inf)
+        return jax.lax.dynamic_update_slice_in_dim(scores, s, j * bk, axis=1)
+
+    scores = jax.lax.fori_loop(
+        0, (context_len + bk - 1) // bk, block,
+        jnp.full((Tq, ids.shape[0] * page_len), -jnp.inf, F32))
+    return scores[:, :cap]
+
+
+def chunk_picks(q_i, w, index_pages, page_ids, abs_pos, context_len,
+                 k: int):
+    """The picked sets of a chunk's queries as a mask [Tq, max_pages *
+    page_len]: scores (:func:`chunk_index_scores`), then the ``k``
+    largest of each query (:func:`pick_mask`), ``PICK_QUERIES`` queries
+    at a time so that the float32 scores of the whole context and the
+    selection's own temporaries are a block's."""
+    J, Tq, D = q_i.shape
+    bq = min(PICK_QUERIES, Tq)
+    assert Tq % bq == 0, (Tq, bq)
+
+    def block(args):
+        q, wb, pos = args
+        with jax.named_scope("index_score"):
+            scores = chunk_index_scores(q, wb, index_pages, page_ids, pos,
+                                         context_len)
+        with jax.named_scope("index_topk"):
+            return pick_mask(scores, k)
+
+    masks = jax.lax.map(block, (
+        q_i.reshape(J, Tq // bq, bq, D).transpose(1, 0, 2, 3),
+        w.reshape(Tq // bq, bq, J), abs_pos.reshape(Tq // bq, bq)))
+    return masks.reshape(Tq, -1)
+
+
+def pick_mask(scores, k: int):
+    """scores [..., N] float32 (``-inf``: not a candidate) -> bool [...,
+    N]: the ``min(k, candidates)`` largest of a row, ties to the lower
+    index.  Exact, and no sort: the ``k``-th largest value is found a bit
+    at a time (32 counts over the row, on the floats' bits put in the
+    floats' order), and of the entries equal to it the first so many as
+    are still wanted; that last step costs a running count, taken only
+    where some row has more equal entries than it wants.  (XLA's ``top_k``
+    of 24,576 scores a query sorts them: 14.7 ms for 512 queries on a v5e
+    against 0.6 here; my chip run, PR 49.)"""
+    k = min(k, scores.shape[-1])
+    live = scores > -jnp.inf
+    bits = jax.lax.bitcast_convert_type(
+        jnp.where(scores == 0.0, 0.0, scores), jnp.uint32)     # -0.0 is 0.0
+    order = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+    def bit(i, kth):
+        trial = kth | (jnp.uint32(1) << (31 - i).astype(jnp.uint32))
+        enough = jnp.sum(order >= trial[..., None], axis=-1) >= k
+        return jnp.where(enough, trial, kth)
+
+    kth = jax.lax.fori_loop(0, 32, bit, jnp.zeros(scores.shape[:-1],
+                                                  jnp.uint32))[..., None]
+    above = order > kth
+    tie = (order == kth) & live
+    wanted = k - jnp.sum(above, axis=-1, keepdims=True)
+    tie = jax.lax.cond(
+        jnp.any(jnp.sum(tie, axis=-1, keepdims=True) > wanted),
+        lambda: tie & (jnp.cumsum(tie, axis=-1) <= wanted), lambda: tie)
+    return (above & live) | tie
 
 
 @jax.named_scope("shared_expert")
@@ -589,6 +726,33 @@ class Rings(_LayerRows):
         return window_decode_attention(
             q, k, v, att_len, sink, base=layer * self.per_layer,
             sm_scale=sm_scale, impl=impl)
+
+
+class LatentRing(_LayerRows):
+    """ONE array of latent rows kept as a ring BY SLOT, ``[Lw, slots, 1, R,
+    width]`` (request state): a window layer's last ``window`` rows
+    ``[c_kv ; k_rope ; 0]``, shared by every head, position ``p`` at row
+    ``p % window``; the ``R - window`` rows beyond (``R``: whole granules
+    of the kernel's copies) are never written nor read."""
+
+    def __init__(self, ring, window: int, positions, active):
+        slots = ring.shape[1]
+        assert ring.shape[2] == 1 and window <= ring.shape[3], ring.shape
+        self.window = window
+        super().__init__([ring], jnp.arange(slots, dtype=jnp.int32),
+                         positions % window, active)
+
+    def attend(self, layer: int, q, att_len, value_dim: int, *, impl: str,
+               sm_scale: float):
+        """q [S, H, width] (absorbed: ``[q_lat ; q_rope ; 0]``) over
+        window layer ``layer``'s ring of each slot, the tick's row already
+        written -> [S, H, value_dim] (the latent's lanes)."""
+        from ..ops.pallas.decode_attention import \
+            window_latent_decode_attention
+        ring, = self.flat()
+        return window_latent_decode_attention(
+            q, ring[:, 0], jnp.minimum(att_len, self.window), value_dim,
+            base=layer * self.per_layer, sm_scale=sm_scale, impl=impl)
 
 
 def ring_positions(end, W: int):

@@ -1333,7 +1333,8 @@ def latent_decode_attention(q: jnp.ndarray, pool: jnp.ndarray,
                             impl: str = "pallas",
                             interpret: Optional[bool] = None,
                             name: str = LATENT_DECODE_ATTN_KERNEL,
-                            allowed: Optional[jnp.ndarray] = None
+                            allowed: Optional[jnp.ndarray] = None,
+                            pages_per_block: Optional[int] = None
                             ) -> jnp.ndarray:
     """Single-query latent attention (MLA, absorbed form) over ONE paged
     pool, the kernel ``ds_latent_decode_attn`` (or ``name``, for a caller
@@ -1355,6 +1356,8 @@ def latent_decode_attention(q: jnp.ndarray, pool: jnp.ndarray,
         none set included.  The kernel then takes one more operand, a
         block's lanes of the mask a grid step; without it the call is the
         one it always was.
+    pages_per_block: pages a grid step attends; None:
+        :func:`latent_pages_per_block`'s (a power of two).
 
     Returns ``[S, H, value_dim]``; a slot with no key to read (length 0,
     nothing allowed) gives exact zeros.  ``impl='dense'`` is
@@ -1372,7 +1375,8 @@ def latent_decode_attention(q: jnp.ndarray, pool: jnp.ndarray,
                          "'pallas' or 'dense'")
     if interpret is None:
         interpret = _use_interpret()
-    ppb = latent_pages_per_block(page_len, W, pool.dtype.itemsize, max_pages)
+    ppb = pages_per_block or latent_pages_per_block(
+        page_len, W, pool.dtype.itemsize, max_pages)
     nb = -(-max_pages // ppb)
     bk = ppb * page_len
     pt_flat = jnp.pad(page_table.astype(jnp.int32),
@@ -1414,6 +1418,66 @@ def latent_decode_attention(q: jnp.ndarray, pool: jnp.ndarray,
         interpret=interpret,
         name=name,
     )(pt_flat, lengths.astype(jnp.int32), q, *mask, pool)
+
+
+# ---------------------------------------------------------------------------
+# latent attention inside a window: a RING of latent rows by slot
+# ---------------------------------------------------------------------------
+
+WINDOW_LATENT_DECODE_ATTN_KERNEL = "ds_window_latent_decode_attn"
+
+
+def ring_granule(rows: int) -> int:
+    """Rows one copy of :func:`window_latent_decode_attention` brings in:
+    the largest of 64, 32, 16, 8 that divides the ring's ``rows`` at rest
+    (64 at a ring of 576: four bfloat16 tiles)."""
+    for g in (64, 32, 16, 8):
+        if rows % g == 0:
+            return g
+    raise ValueError(f"a latent ring of {rows} rows at rest: not whole "
+                     "granules of 8")
+
+
+def window_latent_decode_attention(
+        q: jnp.ndarray, rings: jnp.ndarray, lengths: jnp.ndarray,
+        value_dim: int, *, base=0, sm_scale: float, impl: str = "pallas",
+        interpret: Optional[bool] = None) -> jnp.ndarray:
+    """Single-query latent attention (absorbed form) over each slot's own
+    RING of latent rows, the kernel ``ds_window_latent_decode_attn``.
+
+    q: [S, H, W] a head's ``[q_lat ; q_rope]``, zeros in the lanes the
+        rows pad.
+    rings: [X, R, W]: slot ``s`` reads ring ``base + s`` (``base``: a
+        layer's place in the stacked rings), whose rows ``0 ..
+        lengths[s] - 1`` are live: one ``[c_kv ; k_rope]`` row a
+        position, the window's positions in ANY order (a softmax does
+        not ask which: position ``p`` lies at row ``p % window``, so a
+        ring that has wrapped is live in all its ``window`` rows and one
+        that has not in its first ``length``).  ``R`` is whole granules
+        (:func:`ring_granule`).
+    lengths: [S] int32, at most the window; 0: the slot is not read.
+
+    It is :func:`latent_decode_attention`'s body under another name, the
+    ring presented as the slot's own pages of one granule each, ALL of
+    them one grid step (a ring of 576 rows of 1,152 lanes is 1.33 MB in
+    bfloat16, 2.65 MB the double buffer: inside ``PAGED_KV_VMEM_BUDGET``;
+    a ring that is not walks in blocks of a power of two of them): float32
+    scores and softmax, the granules past a slot's length neither copied
+    nor (their block) computed, the next live slot's ring in flight while
+    this one's is attended.  Returns ``[S, H, value_dim]``; a slot of
+    length 0 gives exact zeros."""
+    X, R, W = rings.shape
+    S = q.shape[0]
+    g = ring_granule(R)
+    per = R // g
+    table = (base + jnp.arange(S, dtype=jnp.int32))[:, None] * per \
+        + jnp.arange(per, dtype=jnp.int32)[None, :]
+    fits = 2 * R * W * rings.dtype.itemsize <= PAGED_KV_VMEM_BUDGET
+    return latent_decode_attention(
+        q, rings.reshape(X * per, g, W), table, lengths, value_dim,
+        sm_scale=sm_scale, impl=impl, interpret=interpret,
+        name=WINDOW_LATENT_DECODE_ATTN_KERNEL,
+        pages_per_block=per if fits else None)
 
 
 # ---------------------------------------------------------------------------

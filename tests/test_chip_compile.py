@@ -1891,6 +1891,153 @@ def test_kimi_lower_prefill_rung_compiles_under_the_top_rungs_peak(one_chip):
 
 
 # ---------------------------------------------------------------------------
+# dots3-note-prev at its cell's sizes (benchmark/configs/dots3-note-prev.json:
+# a dense full layer and two periods of three sliding layers and a full one,
+# 16 of 256 experts held, 128 slots of six rings of 576 x 1,152, 12,289 pages
+# of latent rows 640 wide with the indexer's keys beside them): the ring
+# kernel, the decode tick and ONE prefill rung
+# ---------------------------------------------------------------------------
+
+DOTS_SLOTS, DOTS_PAGE_LEN, DOTS_PAGES, DOTS_MAX_PAGES = 128, 64, 12289, 384
+
+
+@functools.cache
+def _dots_model():
+    import json
+    from deepspeed_tpu.models.dots3_note import (Dots3NoteConfig,
+                                                 Dots3NoteModel)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "dots3-note-prev.json")) as f:
+        file = json.load(f)
+    serving = file["serving"]
+    assert (serving["slots"], serving["page_len"], serving["pages"],
+            -(-serving["max_seq_len"] // serving["page_len"])) == (
+        DOTS_SLOTS, DOTS_PAGE_LEN, DOTS_PAGES, DOTS_MAX_PAGES)
+    fields = {f.name for f in dataclasses.fields(Dots3NoteConfig)}
+    keys = {k: v for k, v in file.items() if k in fields}
+    keys["n_routed_experts"] = file["published"]["n_routed_experts"]
+    keys["experts_held"] = tuple(file["experts_held"])
+    return Dots3NoteModel(Dots3NoteConfig(**keys,
+                                          param_dtype=file["dtype"])), file
+
+
+def test_window_latent_kernel_reads_a_slots_ring_in_one_grid_step(one_chip):
+    """64 heads' [q_lat ; q_rope] against each slot's ring of 576 rows of
+    1,152 lanes, six layers' rings stacked: the latent kernel's body under
+    a name of its own, all nine granules of 64 rows one grid step (2.65 MB
+    the double buffer, inside the module's VMEM budget), the rings left in
+    HBM."""
+    from deepspeed_tpu.ops.pallas.decode_attention import (
+        WINDOW_LATENT_DECODE_ATTN_KERNEL, ring_granule,
+        window_latent_decode_attention)
+    assert WINDOW_LATENT_DECODE_ATTN_KERNEL == "ds_window_latent_decode_attn"
+    cfg = _dots_model()[0].config
+    rows, width = cfg.ring_rows, cfg.window.row
+    assert (rows, width, ring_granule(rows)) == (576, 1152, 64)
+    assert 2 * rows * width * 2 <= PAGED_KV_VMEM_BUDGET
+    compiled = _compile(
+        lambda q, rings, n: window_latent_decode_attention(
+            q, rings, n, cfg.window.kv_rank, base=2 * DOTS_SLOTS,
+            sm_scale=0.0625, interpret=False),
+        one_chip, _sds((DOTS_SLOTS, 64, width)),
+        _sds((6 * DOTS_SLOTS, rows, width)), _sds((DOTS_SLOTS,), jnp.int32))
+    names = _kernel_names(compiled)
+    assert [n.split(".")[0] for n in names] \
+        == [WINDOW_LATENT_DECODE_ATTN_KERNEL]
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@functools.cache
+def _dots_program(program, one_chip, bucket=2048):
+    """The model's paged step as the engine calls it: the pool, the
+    indexer's keys and the rings donated, None where a second pool would
+    be, the parameters as the engine holds them at rest; a prefill at
+    ``bucket`` tokens with its prefix length and slot TRACED."""
+    model, _ = _dots_model()
+    cfg = model.config
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    pool = _sds((3, DOTS_PAGES, 1, DOTS_PAGE_LEN, cfg.d_head))
+    keys = _sds((3, DOTS_PAGES, 1, DOTS_PAGE_LEN, cfg.d_index))
+    state = model.serving_state(DOTS_SLOTS)
+    i32, s = _sds((), jnp.int32), DOTS_SLOTS
+    if program == "serve_decode":
+        def fn(p, t, k, ik, st, tab, ln, act):
+            return model.decode_step_paged(p, t, k, None, tab, ln, act,
+                                           state=st, impl="pallas", aux=True,
+                                           index_pool=ik)
+        shapes = (params, _sds((s,), jnp.int32), pool, keys, state,
+                  _sds((s, DOTS_MAX_PAGES), jnp.int32),
+                  _sds((s,), jnp.int32), _sds((s,), jnp.bool_))
+        donate = (2, 3, 4)
+    else:
+        def fn(p, t, n, pre, row, k, ik, st, slot):
+            return model.prefill_paged(p, t, n, pre, row, k, None, state=st,
+                                       slot=slot, aux=True, index_pool=ik)
+        shapes = (params, _sds((1, bucket), jnp.int32), i32, i32,
+                  _sds((DOTS_MAX_PAGES,), jnp.int32), pool, keys, state, i32)
+        donate = (5, 6, 7)
+    args = _program_args(shapes, one_chip, model)
+    with interpret_scope(False):
+        return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+
+
+@pytest.mark.parametrize("program", ["serve_decode", "serve_prefill"])
+def test_dots3_programs_hold_their_kernels_and_all_three_caches(program,
+                                                                one_chip):
+    """Every Mosaic call of the tick and of the 2,048 rung starts ``ds_``;
+    the rings (1.019 GB), the pool and the indexer's keys (3.624 GB) pass
+    through aliased to the outputs and nothing of a layer's size is a
+    temporary; the arguments are the weights and those caches, in the
+    compiler's own count as the configuration's ``reduced_why`` states it;
+    all the chip must hold at once fits its 16.91e9 bytes; the tick, its
+    query projections at rest as the engine holds them, copies no weight
+    and no cache."""
+    from deepspeed_tpu.utils.hlo import parameter_rewrites
+    from deepspeed_tpu.moe import dropless
+    from deepspeed_tpu.ops.pallas.context_attention import \
+        LATENT_CONTEXT_ATTN_KERNEL
+    from deepspeed_tpu.ops.pallas.decode_attention import (
+        INDEX_SCORE_KERNEL, SPARSE_LATENT_DECODE_ATTN_KERNEL,
+        WINDOW_LATENT_DECODE_ATTN_KERNEL)
+    from deepspeed_tpu.ops.pallas.flash_attention import (
+        FLASH_FWD_CTX_KERNEL, FLASH_FWD_KERNEL)
+    compiled = _dots_program(program, one_chip)
+    names = {n.split(".")[0] for n in _kernel_names(compiled)}
+    experts = {dropless.MOE_GATE_UP_KERNEL, dropless.MOE_DOWN_KERNEL}
+    assert names == experts | (
+        {INDEX_SCORE_KERNEL, SPARSE_LATENT_DECODE_ATTN_KERNEL,
+         WINDOW_LATENT_DECODE_ATTN_KERNEL} if program == "serve_decode"
+        else {LATENT_CONTEXT_ATTN_KERNEL, FLASH_FWD_KERNEL,
+              FLASH_FWD_CTX_KERNEL}), names
+    mem = compiled.memory_analysis()
+    rings = 6 * DOTS_SLOTS * 576 * 1152 * 2
+    arrays = DOTS_PAGES * DOTS_PAGE_LEN * 3 * (640 + 128) * 2
+    assert mem.alias_size_in_bytes >= rings + arrays
+    weights = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves(compiled.in_avals[0][0]))
+    assert abs(weights - 9.207e9) < 1e6
+    assert abs(mem.argument_size_in_bytes - weights - rings - arrays) \
+        < 1 << 20
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16.0e9
+    _, file = _dots_model()
+    assert "arguments %.3f GB" % (mem.argument_size_in_bytes / 1e9) \
+        in file["reduced_why"]
+    if program == "serve_prefill":
+        assert mem.temp_size_in_bytes < 0.5e9, mem.temp_size_in_bytes
+        return
+    # a layer's rings are 0.170 GB, a layer of rows 1.007: no temporary can
+    # be a copy of either, and no weight is laid out again
+    assert mem.temp_size_in_bytes < 0.06e9, mem.temp_size_in_bytes
+    n = len(jax.tree.leaves(compiled.in_avals[0][0]))
+    text = compiled.as_text()
+    assert parameter_rewrites(text, n, 0.5) == []
+    layer = DOTS_PAGES * DOTS_PAGE_LEN * 128 * 2
+    assert [r for r in parameter_rewrites(text, n + 4, 0.0)
+            if r.parameter > n and r.bytes >= layer] == []
+
+
+# ---------------------------------------------------------------------------
 # the form a query projection rests in (PR 55): ``ServeEngine`` holds the
 # leaves a family declares (``WalkedModel.serving_layouts``) output-major,
 # made once, and ``walked.project_heads`` contracts the last axes of both

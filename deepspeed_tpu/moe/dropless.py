@@ -12,7 +12,18 @@ expert: bound by reading the hit experts' weights):
 2. sort the ``N * k`` assignments by expert and lay each expert's rows out
    from a row-tile boundary (``tm`` rows, 16 at decode, up to 128 at
    prefill), so that a tile belongs to one expert.  At most
-   ``N*k // tm + E`` tiles whatever the routing: a static shape;
+   ``N*k // tm + E`` tiles whatever the routing: a static shape.
+   :func:`routing_plan` builds the layout with whole-array operations
+   only: an expert's count is a sum of compares over ``[E, N*k]``, a
+   tile's expert one over ``[tiles, E]``; the stable sort by expert gives
+   the assignments in row order, :func:`_spread` moves them apart by the
+   padding ahead of their expert (a dozen selects against a shifted
+   copy), and sorting the order back carries each row to its assignment.
+   No index table is gathered and nothing is scattered, because the chip
+   reads the indices of a gather or a scatter one at a time (7-13 ns
+   each) and makes a ``while`` of ``searchsorted`` and of a gather of
+   windows: as index arithmetic a row the plan was four fifths of what a
+   call spent outside its kernels (``PERF.md`` section 6, PRs 57, 58);
 3. two grouped matmuls over the tiles, each a Pallas kernel whose weight
    block is the tile's expert (scalar prefetch): ``ds_moe_gate_up``
    (``silu(x @ gate) * (x @ up)``) and ``ds_moe_down``.  An expert's
@@ -24,7 +35,10 @@ expert: bound by reading the hit experts' weights):
    (:func:`weight_blocks`): every tile for the first block of columns,
    then every tile for the next, so a block is still fetched once an
    expert and the rows once a block;
-4. combine: each token's k rows gathered back, weighted, summed in float32.
+4. combine: each token's k rows gathered back by the plan's row of each
+   assignment, weighted, summed in float32.  This gather and the one of
+   ``x`` into rows are the two the layer keeps: an index fetches a row
+   of ``d`` values, which runs at the memory's bandwidth.
 
 The stacked weights of ALL layers reach the kernels whole
 (``[L*E, d, f]``) with the layer's offset added to the tile's expert, so a
@@ -133,6 +147,17 @@ class HeldMoEStats(NamedTuple):
     rows_elsewhere: jnp.ndarray   # live assignments to experts not held
 
 
+def _picked(table, index):
+    """``take_along_axis(table, index, -1)`` (``table`` [..., E], ``index``
+    [..., k]; 0 where an index is none of 0 .. E-1) as a compare over
+    [..., k, E] and a sum, the same bits: for what the module docstring's
+    step 2 says of a gather an index (the router's weights at 192 x top-22
+    of 512: 39 us gathered, 0.8 so; at 4,096 x top-8 of 256: 341, 17)."""
+    ids = jnp.arange(table.shape[-1], dtype=index.dtype)
+    return jnp.sum(jnp.where(index[..., None] == ids, table[..., None, :], 0),
+                   axis=-1)
+
+
 def route_topk(x, router_w, top_k: int, renormalize: bool = False):
     """x [N, d], router_w [d, E] -> (weights [N, k] float32, experts
     [N, k] int32): softmax over all E in float32, then the k largest."""
@@ -150,13 +175,14 @@ def route_sigmoid_topk(x, router_w, select_bias, top_k: int,
     float32, experts [N, k] int32).  Scores ``sigmoid(x @ router_w)`` in
     float32; the k experts with the largest ``score + select_bias`` are
     chosen (the bias steers the choice only); their weights are their
-    own scores, renormalised to sum 1 if asked, times ``scale``."""
+    own scores (:func:`_picked`), renormalised to sum 1 if asked, times
+    ``scale``."""
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits)
     _, experts = jax.lax.top_k(scores + select_bias.astype(jnp.float32),
                                top_k)
-    weights = jnp.take_along_axis(scores, experts, axis=-1)
+    weights = _picked(scores, experts)
     if renormalize:
         weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
     return weights * scale, experts.astype(jnp.int32)
@@ -251,6 +277,83 @@ def _grouped(kernel, name, rows, weights, tile_expert, n_live, tm, width,
     )(tile_expert, n_live, rows, *weights)
 
 
+def _moved(v, step: int, fill):
+    """``v`` [M] moved ``step`` places up, ``fill`` coming in behind."""
+    return jnp.pad(v, (step, 0), constant_values=fill)[:v.shape[0]]
+
+
+def _spread(values, shift, size: int, hole):
+    """``out[s + shift[s]] = values[s]`` where ``shift[s] >= 0``, ``hole``
+    in the rest of ``out`` [size]: the sorted assignments moved apart to
+    their rows.  The shifts never decrease along ``s`` (an expert's is the
+    padding ahead of it), so moving by the shift's bits, the highest
+    first, never lands two items on one place: ``log2(size - A)`` rounds
+    of a select between an array and itself moved by a constant."""
+    room = size - values.shape[0]
+    out = jnp.pad(jnp.where(shift >= 0, values, hole), (0, room),
+                  constant_values=hole)
+    left = jnp.pad(jnp.maximum(shift, 0), (0, room))   # of the move, to go
+    for bit in reversed(range(room.bit_length())):
+        step = 1 << bit
+        goes = (left & step) != 0
+        came = _moved(goes, step, False)
+        out = jnp.where(came, _moved(out, step, hole),
+                        jnp.where(goes, hole, out))
+        left = jnp.where(came, _moved(left, step, 0),
+                         jnp.where(goes, 0, left))
+    return out
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def routing_plan(flat, n_experts: int, top_k: int, tm: int):
+    """The module docstring's step 2.  ``flat`` [A] int32: each
+    assignment's expert, ``n_experts`` for one that reaches no expert
+    here (a padding token, an expert held elsewhere); assignment ``i`` is
+    token ``i // top_k``'s.  Returns
+
+    * ``tile_expert`` [tiles = A // tm + n_experts]: the expert of each
+      tile of ``tm`` rows (a tile past the live ones repeats the last live
+      expert: the pipeline fetches nothing for a block index that does
+      not move);
+    * ``n_live``: the tiles that hold a row;
+    * ``token`` [tiles * tm]: the token of each row, ``A // top_k`` (one
+      past the last) for a row that holds none;
+    * ``row`` [A]: the row of each assignment, 0 for one that has none;
+    * ``counts`` [n_experts]: the assignments of each expert.
+
+    Compares and sums over [experts, A] and [tiles, experts], two sorts
+    and :func:`_spread`: no loop, no scatter, and no gather.  Jitted by
+    itself so that a program of several expert layers traces and lowers
+    it once (its ~250 operations were 3 s of a 52 s set-up where five
+    unrolled layers and five programs each traced their own: PR 58)."""
+    a, e, i32 = flat.shape[0], n_experts, jnp.int32
+    tiles = a // tm + e
+    ids = jnp.arange(e, dtype=i32)
+    counts = jnp.sum(flat[None, :] == ids[:, None], axis=1, dtype=i32)
+    per = (counts + tm - 1) // tm
+    tile_end = jnp.cumsum(per)
+    n_live = jnp.sum(per)
+    last = jnp.minimum(jnp.arange(tiles, dtype=i32),
+                       jnp.maximum(n_live - 1, 0))
+    tile_expert = jnp.minimum(jnp.sum(
+        tile_end[None, :] <= last[:, None], axis=1, dtype=i32), e - 1)
+
+    # an expert's rows start where its sorted assignments do, moved on by
+    # the padding that filled the last tile of each expert ahead of it
+    spare = per * tm - counts
+    ahead = jnp.cumsum(spare) - spare                           # [E]
+    position = jnp.arange(a, dtype=i32)
+    expert, order = jax.lax.sort((flat, position), num_keys=1,
+                                 is_stable=True)                # [A], [A]
+    shift = jnp.where(expert < e, _picked(ahead, expert), -1)
+    token = _spread(order // top_k, shift, tiles * tm,
+                    jnp.asarray(a // top_k, i32))
+    # sorting the order back to 0 .. A-1 carries each row to its assignment
+    row = jax.lax.sort((order, jnp.where(shift >= 0, position + shift, 0)),
+                       num_keys=1)[1]
+    return tile_expert, n_live, token, row, counts
+
+
 def dropless_moe(x, router_w, gate_w, up_w, down_w, top_k: int, *,
                  expert_offset=0, valid=None, renormalize: bool = False,
                  interpret: Optional[bool] = None, routing=None,
@@ -297,32 +400,11 @@ def dropless_moe(x, router_w, gate_w, up_w, down_w, top_k: int, *,
         if valid is not None:
             flat = jnp.where(jnp.repeat(valid, top_k), flat, e)
         tm = row_tile(share, e)
-        tiles = a // tm + e
         i32 = jnp.int32
 
-        # each expert's rows from a tile boundary, experts in order
-        counts = jnp.zeros((e + 1,), i32).at[flat].add(1)[:e]
-        per = (counts + tm - 1) // tm
-        tile_end = jnp.cumsum(per)
-        n_live = tile_end[-1]
-        t = jnp.arange(tiles, dtype=i32)
-        # a tile past the live ones repeats the last live expert: the
-        # pipeline fetches nothing for a block index that does not move
-        tile_expert = jnp.searchsorted(
-            tile_end, jnp.minimum(t, jnp.maximum(n_live - 1, 0)),
-            side="right").astype(i32)
-        tile_expert = jnp.minimum(tile_expert, e - 1)
-        row_start = (tile_end - per) * tm                   # [E]
-        sorted_start = jnp.cumsum(counts) - counts          # [E]
-
-        order = jnp.argsort(flat, stable=True).astype(i32)  # [A]
-        r = jnp.arange(tiles * tm, dtype=i32)
-        e_r = tile_expert[r // tm]
-        rank = r - row_start[e_r]
-        live_row = (r // tm < n_live) & (rank < counts[e_r])
-        src = jnp.where(live_row, sorted_start[e_r] + rank, a)
-        token = jnp.concatenate([order // top_k, jnp.full((1,), n, i32)])
-        x_rows = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])[token[src]]
+        tile_expert, n_live, token, row, counts = routing_plan(
+            flat, e, top_k, tm)
+        x_rows = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])[token]
 
         te = tile_expert + jnp.asarray(expert_offset, i32)
         live = jnp.reshape(n_live, (1,)).astype(i32)
@@ -335,11 +417,7 @@ def dropless_moe(x, router_w, gate_w, up_w, down_w, top_k: int, *,
         y_rows = _grouped(_down_kernel, MOE_DOWN_KERNEL, h, (down_w,),
                           te, live, tm, d, interpret)
 
-        # each assignment's row, then the weighted sum of a token's k
-        place = jnp.zeros((a,), i32).at[order].set(jnp.arange(a, dtype=i32))
-        e_a = jnp.minimum(flat, e - 1)
-        row = row_start[e_a] + place - sorted_start[e_a]
-        row = jnp.where(flat < e, row, 0)
+        # the weighted sum of a token's k rows
         picked = y_rows[row].reshape(n, top_k, d).astype(jnp.float32)
         keep = (flat < e).reshape(n, top_k, 1)
         y = jnp.sum(jnp.where(keep, picked * weights[..., None], 0.0), axis=1)

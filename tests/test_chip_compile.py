@@ -97,6 +97,33 @@ def _sds(shape, dtype=BF16):
     return jax.ShapeDtypeStruct(shape, dtype)
 
 
+#: A program's temporaries in GB as the compiler counts them since PR 58,
+#: where a configuration's ``reduced_why`` still states the count from
+#: before it (the routing plan's and the router's own arrays moved each:
+#: A.X-K1's tick 0.045 -> 0.043, Command A+'s 0.149 -> 0.142 and its
+#: chunk 1.055 -> 1.053, Kimi Linear's tick 0.122 -> 0.136 and its rung
+#: 1.096 -> 0.965).
+#: The text is a ``benchmark`` PR's to edit (``PERF.md`` section 7); that
+#: PR puts ``"... %.3f GB" % ... in reduced_why`` back in place of this
+#: table.  Until then the text is held as it stands and the count here.
+TEMPORARIES_GB = {
+    ("a.x-k1", "serve_decode"): ("temporaries 0.045 GB (decode)", "0.043"),
+    ("command-a-plus", "serve_decode"): ("temporaries 0.149 GB (decode)",
+                                         "0.142"),
+    ("command-a-plus", "serve_prefill"): ("1.055 GB (a chunk of 4,096",
+                                          "1.053"),
+    ("kimi-linear", "serve_decode"): ("temporaries 0.122 GB (decode",
+                                      "0.136"),
+    ("kimi-linear", "serve_prefill"): ("1.096 GB (prefill", "0.965"),
+}
+
+
+def _holds_its_temporaries(mem, file, family, program):
+    said, compiled = TEMPORARIES_GB[family, program]
+    assert said in file["reduced_why"]
+    assert "%.3f" % (mem.temp_size_in_bytes / 1e9) == compiled
+
+
 # ---------------------------------------------------------------------------
 # flash attention, forward and backward, at the train smoke's shape
 # ---------------------------------------------------------------------------
@@ -1037,8 +1064,7 @@ def test_axk1_programs_hold_their_kernels_and_one_pool(program, one_chip):
     assert "arguments %.3f GB" % (mem.argument_size_in_bytes / 1e9) \
         in file["reduced_why"]
     if program == "serve_decode":
-        assert "temporaries %.3f GB (decode)" \
-            % (mem.temp_size_in_bytes / 1e9) in file["reduced_why"]
+        _holds_its_temporaries(mem, file, "a.x-k1", program)
 
 
 def test_axk1_decode_tick_reads_each_layers_matrices_where_they_lie(
@@ -1420,11 +1446,9 @@ def test_command_a_plus_programs_hold_their_kernels_and_no_copy_of_a_cache(
     limit = 0.2e9 if program == "serve_decode" else 1.2e9
     assert mem.temp_size_in_bytes < limit, mem.temp_size_in_bytes
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.6e9
-    said = {"serve_decode": "temporaries %.3f GB (decode)",
-            "serve_prefill": "%.3f GB (a chunk of 4,096"}[program]
     assert "arguments %.3f GB" % (mem.argument_size_in_bytes / 1e9) \
         in file["reduced_why"]
-    assert said % (mem.temp_size_in_bytes / 1e9) in file["reduced_why"]
+    _holds_its_temporaries(mem, file, "command-a-plus", program)
 
 
 def test_command_a_plus_tick_reads_each_layers_matrices_where_they_lie(
@@ -1856,11 +1880,7 @@ def test_kimi_programs_hold_their_kernels_and_no_copy_of_the_state(
     if program == "serve_decode":
         assert "arguments %.3f GB" % (mem.argument_size_in_bytes / 1e9) \
             in file["reduced_why"]
-        assert "temporaries %.3f GB (decode" \
-            % (mem.temp_size_in_bytes / 1e9) in file["reduced_why"]
-    else:
-        assert "%.3f GB (prefill" % (mem.temp_size_in_bytes / 1e9) \
-            in file["reduced_why"]
+    _holds_its_temporaries(mem, file, "kimi-linear", program)
 
 
 def test_kimi_decode_tick_reads_each_layers_matrices_where_they_lie(
@@ -2102,7 +2122,9 @@ SCOPED_PROGRAMS = {
     "gpt2.serve_prefill": (lambda c: _gpt2_prefill_program(c).as_text(), 0.2),
     "gpt2.train": (lambda c: _gpt2_train_program(c).as_text(), 0.2),
     "bert.train": (lambda c: _bert_large_step(c, saved=True), 0.1),
-    "olmoe.serve_decode": (lambda c: _olmoe_decode_program(c).as_text(), 57.1),
+    # 57.1 before PR 58: the same unscoped copies (of the pool the test
+    # does not donate) over fewer cycles under ``layer/moe``
+    "olmoe.serve_decode": (lambda c: _olmoe_decode_program(c).as_text(), 59.3),
     "olmoe.serve_prefill": (lambda c: _olmoe_prefill_program(c).as_text(),
                             9.9),
     "nemotron.serve_decode": (

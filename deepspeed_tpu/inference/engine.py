@@ -677,6 +677,10 @@ class ServeEngine:
                          jnp.dtype(kv_dtype).itemsize)
                 grouped = ({"q_heads": mcfg.n_head}
                            if kv_heads != mcfg.n_head else {})
+                # a model whose pages rest [H, page_len, Dh] at a group
+                # of one (``walked.PagePool`` on ungrouped keys)
+                if getattr(model, "pool_head_major", False):
+                    grouped["head_major"] = True
                 self._pages_per_block = paged_pages_per_block(
                     *shape, self.max_pages, v_head_dim=v_dim, **grouped)
                 self.paged_decode_arm = paged_decode_arm(*shape, **grouped)
@@ -1242,7 +1246,8 @@ class ServeEngine:
                     "serve_state_bytes",
                     "device bytes a stateful model's requests hold by "
                     "kind: each serving_state leaf (ssm, conv; window_k, "
-                    "window_v; kda, kda_conv; window_latent) and the page "
+                    "window_v; kda, kda_conv; gdn, gdn_conv; window_latent) "
+                    "and the page "
                     "pool (kv; latent "
                     "where it is "
                     "one pool of latent rows, index_k the indexer keys "

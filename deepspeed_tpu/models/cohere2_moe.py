@@ -78,10 +78,11 @@ import jax
 import jax.numpy as jnp
 
 from .walked import (F32, PagePool, Rings, ServedConfig, WalkedModel, at,
-                     causal_self_attention, decode_index, default_scale,
-                     draw_layers, held_expert_counters, merge_heads,
-                     prefill_index, project_heads, ring_positions,
-                     routed_experts, stacked_experts, swiglu, write_slot_state)
+                     causal_self_attention, context_attention, decode_index,
+                     default_scale, draw_layers, held_expert_counters,
+                     merge_heads, prefill_index, prefix_keys, project_heads,
+                     ring_positions, routed_experts, stacked_experts, swiglu,
+                     write_slot_state)
 
 _KINDS = {"full_attention": "full", "sliding_attention": "window"}
 
@@ -377,7 +378,7 @@ class Cohere2MoeModel(WalkedModel):
                 def context():
                     with jax.named_scope("chunk_context"):
                         ctx_k, ctx_v = (
-                            _prefix_keys(t[i], page_row, prefix_len)
+                            prefix_keys(t[i], page_row, prefix_len)
                             for t in pool.arrays())
                     return _context_attention(
                         cfg, kind, q, k, v, ctx_k, ctx_v,
@@ -452,35 +453,12 @@ def _self_attention(cfg: Cohere2MoeConfig, kind: str, q, k, v):
 
 def _context_attention(cfg: Cohere2MoeConfig, kind: str, q, k, v, ctx_k,
                        ctx_v, live):
-    """A chunk's attention with keys ahead of it: ``ctx_k`` / ``ctx_v``
-    [Hkv, Tc, D] of which the LAST ``live`` (traced) are the positions
-    just before the chunk, in order."""
-    window = cfg.sliding_window if kind == "window" else None
-    scale = default_scale(cfg.head_dim)
-    keys = jnp.concatenate([ctx_k[None].astype(k.dtype), k], axis=2)
-    values = jnp.concatenate([ctx_v[None].astype(v.dtype), v], axis=2)
-    if cfg.attn_impl == "flash":
-        from ..ops.pallas.flash_attention import flash_attention_fwd
-        return flash_attention_fwd(q, keys, values, window=window,
-                                   sm_scale=scale, ctx_live=live)
-    return _dense_context_attention(q, keys, values, live, window, scale)
-
-
-def _dense_context_attention(q, k, v, live, window, sm_scale):
-    """The dense (XLA) arm of :func:`_context_attention`."""
-    Tq, Tk = q.shape[2], k.shape[2]
-    Tc = Tk - Tq
-    rep = q.shape[1] // k.shape[1]
-    k, v = (jnp.repeat(t, rep, axis=1) for t in (k, v))
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
-                   preferred_element_type=F32) * sm_scale
-    kk, qq = jnp.arange(Tk)[None, :], Tc + jnp.arange(Tq)[:, None]
-    ok = (kk <= qq) & (kk >= Tc - live)
-    if window is not None:
-        ok &= kk > qq - window
-    s = jnp.where(ok[None, None], s, jnp.finfo(F32).min)
-    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
-    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+    """A chunk's attention with keys ahead of it
+    (``walked.context_attention``), a window layer's inside its window."""
+    return context_attention(
+        q, k, v, ctx_k, ctx_v, live, cfg.attn_impl == "flash",
+        window=cfg.sliding_window if kind == "window" else None,
+        sm_scale=default_scale(cfg.head_dim))
 
 
 @jax.named_scope("shared_expert")
@@ -575,15 +553,3 @@ def _live_pairs(delta_len, prefix_len, window=None):
     over = jnp.maximum(p + n - window, 0) - jnp.maximum(p - window, 0)
     under = n - over                # queries whose whole past is in reach
     return under * p + under * (under + 1) / 2 + over * window
-
-
-def _prefix_keys(layer_pool, page_row, prefix_len):
-    """A full layer's keys ahead of a chunk, for
-    :func:`_context_attention`: ``layer_pool`` [pages, Hkv, page_len, D]
-    -> [Hkv, cap, D], positions ``0 .. prefix_len - 1`` at the END (the
-    request's pages gathered in order, as ``models/olmoe.py`` gathers
-    them, then rolled)."""
-    got = layer_pool[page_row]                  # [max_pages, Hkv, pl, D]
-    hkv, d = got.shape[1], got.shape[3]
-    flat = got.transpose(1, 0, 2, 3).reshape(hkv, -1, d)
-    return jnp.roll(flat, flat.shape[1] - prefix_len, axis=1)

@@ -79,11 +79,11 @@ import jax.numpy as jnp
 from ..ops.pallas.kda import kda_chunked, kda_decode
 from .walked import (F32, PagePool, ServedConfig, WalkedModel, at,
                      decode_index, default_scale, dense_ffn, draw_layers,
-                     held_expert_counters, latent_context_attention,
+                     held_expert_counters, l2_norm, latent_context_attention,
                      latent_projections, latent_rows, latent_self_attention,
                      lm_head, merge_heads, prefill_index, rms_norm,
-                     routed_experts, shared_expert, stacked_experts,
-                     whole_tiles, write_slot_state)
+                     routed_experts, shared_expert, shift_tail, silu_conv,
+                     stacked_experts, whole_tiles, write_slot_state)
 
 
 #: the range ``init`` draws a KDA channel's step from (log-uniform)
@@ -412,18 +412,14 @@ class KimiLinearModel(WalkedModel):
         shape = state["kda"].shape
         # every layer's slots in one row, as ``kda_decode`` takes them;
         # the tails are read from the leaf as it came and written once,
-        # stacked, at the end: a layer's ``.at[i].set`` of a shifted
-        # read of the same rows is an in-place update XLA rematerialised
-        # on the chip, and the second run read the first one's rows
+        # stacked, at the end (``walked.shift_tail`` says why)
         new = {"kda": state["kda"].reshape((-1,) + shape[2:]),
                "kda_conv": []}
 
         def kda(i, kp, h):
             qkv = _kda_qkv(kp, h)                           # [S, 1, 3C]
-            tail = state["kda_conv"][i]
-            window = jnp.concatenate([tail, qkv.astype(tail.dtype)], axis=1)
-            new["kda_conv"].append(jnp.where(
-                active[:, None, None], window[:, 1:], tail))
+            window, kept = shift_tail(state["kda_conv"][i], qkv, active)
+            new["kda_conv"].append(kept)
             q, k, v = _kda_heads(cfg, _kda_conv(
                 kp, [window[:, j] for j in range(cfg.conv_kernel)]))
             g, b, gate = _kda_gates(cfg, kp, h[:, 0])
@@ -553,15 +549,7 @@ def _kda_conv(kp, taps):
     """taps: the K rows under the filter, oldest first, each [..., 3C] ->
     silu(conv) in float32 [..., 3C]."""
     with jax.named_scope("kda_conv"):
-        w = kp["conv_w"].astype(F32)
-        return jax.nn.silu(sum(t.astype(F32) * w[j]
-                               for j, t in enumerate(taps)))
-
-
-def _l2(x):
-    """x / |x| over the last axis (the family's ``l2norm``, eps 1e-6
-    under the root)."""
-    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+        return silu_conv(kp["conv_w"], taps)
 
 
 def _kda_heads(cfg: KimiLinearConfig, conv_out):
@@ -570,7 +558,7 @@ def _kda_heads(cfg: KimiLinearConfig, conv_out):
     lead = conv_out.shape[:-1]
     q, k, v = (t.reshape(lead + (cfg.kda_heads, cfg.kda_head_dim))
                for t in jnp.split(conv_out, 3, axis=-1))
-    return _l2(q) * cfg.kda_head_dim ** -0.5, _l2(k), v
+    return l2_norm(q) * cfg.kda_head_dim ** -0.5, l2_norm(k), v
 
 
 def _kda_gates(cfg: KimiLinearConfig, kp, h):

@@ -1,7 +1,7 @@
 """What the served families whose layers are WALKED share: ``olmoe.py``
 (the one that scans), ``nemotron_h.py``, ``mimo_v2.py``, ``axk1.py``,
-``cohere2_moe.py``, ``glm_dsa.py``, ``kimi_linear.py``, ``dots3_note.py``.  A
-family's file
+``cohere2_moe.py``, ``glm_dsa.py``, ``kimi_linear.py``, ``dots3_note.py``,
+``olmo_hybrid.py``.  A family's file
 holds what is its own: its config under the source's keys, ``init`` and
 the parameter tree, its projections, latents and mixers, its list of layer
 kinds, and two paged steps that read as that list walked over the pieces
@@ -15,7 +15,10 @@ families call; the index preludes of the two paged steps
 (:func:`decode_index`, :func:`prefill_index`); the cache's bookkeeping as
 two pairs of write and attend (:class:`PagePool`, :class:`Rings`) and the
 ring of ONE array of latent rows (:class:`LatentRing`); the
-routed-expert call (:func:`routed_experts`); latent attention's
+routed-expert call (:func:`routed_experts`); a chunk's attention over keys
+gathered from the pool ahead of it (:func:`context_attention`); the
+delta-rule mixers' small parts (``kimi_linear.py``, ``olmo_hybrid.py``);
+latent attention's
 projections, rows at rest and expanded form, from nothing or over a paged
 context (``axk1.py``, ``glm_dsa.py``, ``kimi_linear.py``,
 ``dots3_note.py``); the learned indexer's projections and picks
@@ -241,6 +244,81 @@ def causal_self_attention(q, k, v, flash: bool, *, window=None, sink=None,
                                    sm_scale=sm_scale, **blocks)
     return grouped_causal_attention(q, k, v, window=window, sink=sink,
                                     sm_scale=sm_scale)
+
+
+def context_attention(q, k, v, ctx_k, ctx_v, live, flash: bool, *,
+                      window=None, sm_scale=None):
+    """A chunk's attention with keys ahead of it: q [B, Hq, Tq, D], the
+    chunk's own k, v [B, Hkv, Tq, D]; ``ctx_k`` / ``ctx_v`` [Hkv, Tc, D] of
+    which the LAST ``live`` (traced) are the positions just before the
+    chunk, in order (:func:`prefix_keys`).  The flash forward kernel over
+    ``[context ; chunk]`` (``ctx_live=``) or the dense arm."""
+    keys = jnp.concatenate([ctx_k[None].astype(k.dtype), k], axis=2)
+    values = jnp.concatenate([ctx_v[None].astype(v.dtype), v], axis=2)
+    if flash:
+        from ..ops.pallas.flash_attention import flash_attention_fwd
+        return flash_attention_fwd(q, keys, values, window=window,
+                                   sm_scale=sm_scale, ctx_live=live)
+    return _dense_context_attention(q, keys, values, live, window, sm_scale)
+
+
+def _dense_context_attention(q, k, v, live, window, sm_scale):
+    """The dense (XLA) arm of :func:`context_attention`."""
+    Tq, Tk = q.shape[2], k.shape[2]
+    Tc = Tk - Tq
+    rep = q.shape[1] // k.shape[1]
+    k, v = (jnp.repeat(t, rep, axis=1) for t in (k, v))
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   preferred_element_type=F32) * sm_scale
+    kk, qq = jnp.arange(Tk)[None, :], Tc + jnp.arange(Tq)[:, None]
+    ok = (kk <= qq) & (kk >= Tc - live)
+    if window is not None:
+        ok &= kk > qq - window
+    s = jnp.where(ok[None, None], s, jnp.finfo(F32).min)
+    p = jax.nn.softmax(s, axis=-1).astype(q.dtype)
+    return jnp.einsum("bhqk,bhkd->bhqd", p, v)
+
+
+def prefix_keys(layer_pool, page_row, prefix_len):
+    """A full layer's keys ahead of a chunk, for
+    :func:`context_attention`: ``layer_pool`` [pages, Hkv, page_len, D]
+    (one layer's, or every layer's in one row, :meth:`PagePool.flat`,
+    with the layer's base in ``page_row``) -> [Hkv, cap, D], positions ``0
+    .. prefix_len - 1`` at the END (the request's pages gathered in order,
+    as ``models/olmoe.py`` gathers them, then rolled)."""
+    got = layer_pool[page_row]                  # [max_pages, Hkv, pl, D]
+    hkv, d = got.shape[1], got.shape[3]
+    flat = got.transpose(1, 0, 2, 3).reshape(hkv, -1, d)
+    return jnp.roll(flat, flat.shape[1] - prefix_len, axis=1)
+
+
+# -- the delta-rule mixers' parts (``kimi_linear.py``, ``olmo_hybrid.py``) --
+
+def l2_norm(x):
+    """x / |x| over the last axis (flash-linear-attention's ``l2norm``,
+    eps 1e-6 under the root)."""
+    return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+
+def silu_conv(conv_w, taps):
+    """A depthwise causal convolution as its taps: ``conv_w`` [K, C];
+    ``taps``: the K rows under the filter, oldest first, each [..., C] ->
+    silu(conv) in float32 [..., C]."""
+    w = conv_w.astype(F32)
+    return jax.nn.silu(sum(t.astype(F32) * w[j] for j, t in enumerate(taps)))
+
+
+def shift_tail(tail, new, active):
+    """A decode tick's convolution window: ``tail`` [S, K - 1, C] (the
+    slots' last rows, one layer's, as the state's leaf holds them) and the
+    tick's rows ``new`` [S, 1, C] -> (window [S, K, C], the tail to keep
+    [S, K - 1, C]: shifted where ``active``).  The caller STACKS the kept
+    tails of its layers and writes the leaf once at the end of the tick: a
+    layer's ``.at[i].set`` of a shifted read of the same rows is an
+    in-place update XLA rematerialised on the chip, and the second run read
+    the first one's rows (PERF.md section 6, PR 52)."""
+    window = jnp.concatenate([tail, new.astype(tail.dtype)], axis=1)
+    return window, jnp.where(active[:, None, None], window[:, 1:], tail)
 
 
 def swiglu(x, gate_w, up_w, down_w):
@@ -701,9 +779,11 @@ class PagePool(_LayerRows):
         tick's keys already written -> [S, Hq, Dv]."""
         from ..ops.pallas.decode_attention import decode_attention_paged
         k, v = self.flat()
+        # a page rests [Hkv, page_len, D], as ``write`` lays it: said
+        # for the family with as many key heads as query heads
         return decode_attention_paged(
             q, k, v, page_table + layer * self.per_layer, att_len,
-            sm_scale=sm_scale, impl=impl)
+            sm_scale=sm_scale, impl=impl, head_major=True)
 
 
 class Rings(_LayerRows):

@@ -534,16 +534,26 @@ def _paged_block_layout(heads, page_len, head_dim, itemsize):
     return fold, head_rows, page_len // fold * head_rows, fold * head_dim
 
 
+def _pages_by_head(heads: int, q_heads: Optional[int],
+                   head_major: bool) -> bool:
+    """Whether a page rests ``[H, page_len, Dh]``, as the pool's shape
+    says: always under grouped keys; with as many key heads as query
+    heads where the caller says so (``head_major``)."""
+    return head_major or q_heads not in (None, heads)
+
+
 def paged_decode_arm(heads: int, page_len: int, head_dim: int,
-                     itemsize: int, q_heads: Optional[int] = None) -> str:
+                     itemsize: int, q_heads: Optional[int] = None,
+                     head_major: bool = False) -> str:
     """Which body of the fp paged kernel a pool of this shape runs.
     ``'direct'`` where a page at rest, ``[page_len, H, Dh]``, already is
     the rows of the packed buffer (no fold, no padded head rows, whole
     lanes): the fetched page is the matmul operand.  ``'packed'``
     everywhere else.  A function of the pool's shape alone.  Grouped
     keys (``q_heads`` query heads on ``heads`` key heads) have the
-    direct body only: their page at rest is ``[H, page_len, Dh]``."""
-    if q_heads not in (None, heads):
+    direct body only: their page at rest is ``[H, page_len, Dh]``; so has
+    a pool that rests that way at a group of one (``head_major``)."""
+    if _pages_by_head(heads, q_heads, head_major):
         return "direct"
     fold, head_rows, _, _ = _paged_block_layout(
         heads, page_len, head_dim, itemsize)
@@ -567,14 +577,15 @@ def paged_page_vmem_bytes(heads: int, page_len: int, head_dim: int,
 def paged_pages_per_block(heads: int, page_len: int, head_dim: int,
                           itemsize: int, max_pages: int,
                           q_heads: Optional[int] = None,
-                          v_head_dim: Optional[int] = None) -> int:
+                          v_head_dim: Optional[int] = None,
+                          head_major: bool = False) -> int:
     """Pages one grid step of the fp paged kernel attends: the largest
     power of two that fits ``PAGED_KV_VMEM_BUDGET``, at most
     ``max_pages``.  A function of the pool's shape alone.  With grouped
-    keys a page in flight is its own bytes (K and V, double-buffered):
-    ``[H, page_len, Dh]`` pads nothing; there the values may be
-    ``v_head_dim`` wide where the keys are ``head_dim``."""
-    if q_heads not in (None, heads):
+    keys (or ``head_major``) a page in flight is its own bytes (K and V,
+    double-buffered): ``[H, page_len, Dh]`` pads nothing; there the
+    values may be ``v_head_dim`` wide where the keys are ``head_dim``."""
+    if _pages_by_head(heads, q_heads, head_major):
         page_bytes = 2 * heads * page_len * itemsize * (
             head_dim + (v_head_dim or head_dim))
     else:
@@ -934,7 +945,7 @@ def _decode_paged_grouped_pallas(q, k_pages, v_pages, page_table, lengths, *,
     S, max_pages = page_table.shape
     Hq = q.shape[1]
     ppb = paged_pages_per_block(H, page_len, Dh, k_pages.dtype.itemsize,
-                                max_pages, q_heads=Hq, v_head_dim=Dv)
+                                max_pages, v_head_dim=Dv, head_major=True)
     nb = -(-max_pages // ppb)
     pt_flat = jnp.pad(page_table,
                       ((0, 0), (0, nb * ppb - max_pages))).reshape(-1)
@@ -1087,8 +1098,8 @@ def decode_attention_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
                            impl: str = "pallas",
                            interpret: Optional[bool] = None,
                            k_scale: Optional[jnp.ndarray] = None,
-                           v_scale: Optional[jnp.ndarray] = None
-                           ) -> jnp.ndarray:
+                           v_scale: Optional[jnp.ndarray] = None,
+                           head_major: bool = False) -> jnp.ndarray:
     """Single-query attention over a PAGED KV pool (docs/serving.md).
 
     q: [S, H, Dh] — one new query token per slot; ``[S, Hq, Dh]``
@@ -1112,6 +1123,14 @@ def decode_attention_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
         pool's per-row scale sidecars (serving.quantization.kv='int8';
         the pool is then int8 and dequant fuses into the kernel).
         None = the fp pool, byte-identical to the pre-quant programs.
+    head_major: with ``Hq == H``, the pool RESTS as its shape says,
+        ``[P, H, page_len, Dh]`` (``models/walked.py::PagePool`` writes it
+        so), and the body that reads a page that way runs (grouped keys'
+        own, at a group of one: nothing is transposed, and a head count
+        that is no whole sublane tile pads no row).  Without it the pool
+        of an ungrouped model is taken to rest ``[P, page_len, H, Dh]``
+        under this shape (``models/olmoe.py``, GPT-2), and the transpose
+        below cancels the caller's.  Grouped keys always rest by head.
 
     ``impl='dense'`` gathers the pool dense with ``jnp.take`` and runs
     :func:`decode_attention_reference` — values identical to the
@@ -1133,7 +1152,7 @@ def decode_attention_paged(q: jnp.ndarray, k_pages: jnp.ndarray,
     _check_quant_args(k_pages, k_scale, v_scale, "decode_attention_paged")
     if sm_scale is None:
         sm_scale = _default_scale(Dh)
-    if Hq != H:
+    if Hq != H or head_major:
         if k_scale is not None:
             raise NotImplementedError(
                 "decode_attention_paged: grouped keys have no int8 arm")

@@ -2103,6 +2103,158 @@ def test_the_tick_copies_every_query_weight_from_the_default_layout_and_none_at_
 
 
 # ---------------------------------------------------------------------------
+# Olmo Hybrid at its cell's sizes (benchmark/configs/olmo-hybrid-7b.json: 6
+# linear_attention layers + 2 full ones at hidden 3,840, 256 slots of
+# delta-rule state 30 x 96 x 192 at rest [96, 5,760], 3,073 pages of 64 keys
+# on 30 key heads): the scalar-decay decode update and both serve programs,
+# the decode tick and ONE prefill rung
+# ---------------------------------------------------------------------------
+
+OLMO_SLOTS, OLMO_PAGE_LEN, OLMO_PAGES, OLMO_MAX_PAGES = 256, 64, 3073, 48
+
+
+@functools.cache
+def _olmo_hybrid_model():
+    import json
+    from deepspeed_tpu.models.olmo_hybrid import (OlmoHybridConfig,
+                                                  OlmoHybridModel)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "olmo-hybrid-7b.json")) as f:
+        file = json.load(f)
+    serving = file["serving"]
+    assert (serving["slots"], serving["page_len"], serving["pages"],
+            -(-serving["max_seq_len"] // serving["page_len"])) == (
+        OLMO_SLOTS, OLMO_PAGE_LEN, OLMO_PAGES, OLMO_MAX_PAGES)
+    fields = {f.name for f in dataclasses.fields(OlmoHybridConfig)}
+    keys = {k: v for k, v in file.items() if k in fields}
+    return OlmoHybridModel(OlmoHybridConfig(
+        **keys, param_dtype=file["dtype"])), file
+
+
+def test_gdn_decode_kernel_keeps_its_name_and_the_state_in_place(one_chip):
+    """256 slots x 6 layers of [96, 5,760] float32 aliased through: the
+    state rests with NO padding (45 lane tiles a row, 12 sublane tiles:
+    the compiler's own count of the leaf is the published 30 x 96 x 192 x
+    4 B a slot and layer), a grid step's blocks and the body inside the
+    kernel's VMEM limit, nothing of the state's size a temporary."""
+    from deepspeed_tpu.ops.pallas.kda import GDN_DECODE_KERNEL, gdn_decode
+    assert GDN_DECODE_KERNEL == "ds_gdn_decode"
+    s, h, dk, dv = OLMO_SLOTS, 30, 96, 192
+    f32 = jnp.float32
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (_sds((6 * s, dk, h * dv), f32), _sds((s, h), f32),
+         _sds((s, h, dk), f32), _sds((s, h, dv), f32), _sds((s, h, dk), f32),
+         _sds((s, h), f32), _sds((s,), jnp.bool_), _sds((), jnp.int32)))
+    compiled = jax.jit(
+        lambda st, a, k, v, q, b, act, base: gdn_decode(
+            st, a, k, v, q, b, act, base=base, interpret=False),
+        donate_argnums=(0,)).lower(*args).compile()
+    names = _kernel_names(compiled)
+    assert [n.split(".")[0] for n in names] == [GDN_DECODE_KERNEL]
+    mem = compiled.memory_analysis()
+    state = 6 * s * 2211840
+    assert mem.alias_size_in_bytes == state
+    assert f"f32[{6 * s},{dk},{h * dv}]{{2,1,0:T(8,128)}}" \
+        in compiled.as_text()
+    assert mem.temp_size_in_bytes < 4 << 20
+
+
+@functools.cache
+def _olmo_hybrid_program(program, one_chip, bucket=1024):
+    """The model's paged step as the engine calls it: both pools and the
+    state donated; a prefill at ``bucket`` tokens with its prefix length
+    TRACED (a chunk)."""
+    model, _ = _olmo_hybrid_model()
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    pool = _sds((2, OLMO_PAGES, 30, OLMO_PAGE_LEN, 128))
+    state = model.serving_state(OLMO_SLOTS)
+    i32, s = _sds((), jnp.int32), OLMO_SLOTS
+    if program == "serve_decode":
+        def fn(p, t, k, v, tab, ln, act, st):
+            return model.decode_step_paged(p, t, k, v, tab, ln, act,
+                                           state=st, impl="pallas", aux=True)
+        shapes = (params, _sds((s,), jnp.int32), pool, pool,
+                  _sds((s, OLMO_MAX_PAGES), jnp.int32),
+                  _sds((s,), jnp.int32), _sds((s,), jnp.bool_), state)
+        donate = (2, 3, 7)
+    else:
+        def fn(p, t, n, pre, row, k, v, st, slot):
+            return model.prefill_paged(p, t, n, pre, row, k, v, state=st,
+                                       slot=slot, aux=True)
+        shapes = (params, _sds((1, bucket), jnp.int32), i32, i32,
+                  _sds((OLMO_MAX_PAGES,), jnp.int32), pool, pool, state, i32)
+        donate = (5, 6, 7)
+    args = _program_args(shapes, one_chip, model)
+    with interpret_scope(False):
+        return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+
+
+@pytest.mark.parametrize("program", ["serve_decode", "serve_prefill"])
+def test_olmo_hybrid_programs_hold_their_kernels_and_no_copy_of_the_state(
+        program, one_chip):
+    """A tick runs ``ds_gdn_decode`` once a linear layer and
+    ``ds_paged_decode_attn`` once a full layer (its pages read as they
+    rest, ``[30, 64, 128]``: no transpose of a pool); a rung holds the
+    flash forward for a first chunk and ``ds_flash_fwd_ctx`` for a later
+    one, a layer each; both pools (6.04 GB) and the state (3.50 GB) pass
+    through aliased to the outputs and nothing of their size is a
+    temporary (a chunk gathers its request's pages out of the flat pool: a
+    layer sliced out first was 1.5 GB); the arguments are the weights, the
+    pools and the state; the compiler's own counts are the ones the
+    configuration's ``reduced_why`` states, and fit the chip with the
+    issue's 15.6 GB to spare for nothing: no page had to go."""
+    from deepspeed_tpu.ops.pallas.flash_attention import (
+        FLASH_FWD_CTX_KERNEL, FLASH_FWD_KERNEL)
+    from deepspeed_tpu.ops.pallas.kda import GDN_DECODE_KERNEL
+    from deepspeed_tpu.utils.hlo import kernel_calls
+    compiled = _olmo_hybrid_program(program, one_chip)
+    calls = kernel_calls(compiled.as_text())
+    assert calls == ({GDN_DECODE_KERNEL: 6, PAGED_DECODE_ATTN_KERNEL: 2}
+                     if program == "serve_decode"
+                     else {FLASH_FWD_KERNEL: 2, FLASH_FWD_CTX_KERNEL: 2}), calls
+    mem = compiled.memory_analysis()
+    pools = 2 * 2 * OLMO_PAGES * 30 * OLMO_PAGE_LEN * 128 * 2
+    state = 6 * OLMO_SLOTS * (2211840 + 3 * 11520 * 2)
+    assert mem.alias_size_in_bytes >= pools + state
+    weights = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves(compiled.in_avals[0][0]))
+    assert abs(mem.argument_size_in_bytes - weights - pools - state) < 1 << 20
+    assert mem.argument_size_in_bytes > 0.8 * 16.91e9
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.6e9
+    _, file = _olmo_hybrid_model()
+    said = {"serve_decode": "temporaries %.3f GB (decode",
+            "serve_prefill": "%.3f GB (the 1,024 rung"}[program]
+    assert "arguments %.3f GB" % (mem.argument_size_in_bytes / 1e9) \
+        in file["reduced_why"]
+    assert said % (mem.temp_size_in_bytes / 1e9) in file["reduced_why"]
+    if program == "serve_decode":
+        # the tails are written once, stacked (``walked.shift_tail``)
+        again = [line for line in compiled.as_text().splitlines()
+                 if ".remat = " in line and "%st__gdn_conv__" in line
+                 and "scatter" in line]
+        assert not again, again
+
+
+def test_olmo_hybrid_decode_tick_reads_each_layers_matrices_where_they_lie(
+        one_chip):
+    """A leaf a layer: no fusion or copy of the tick's entry computation
+    writes a weight again (``share`` 0.5: at 256 slots the float32 +
+    bfloat16 results of ``x W_qkv`` fused with its convolution are a fifth
+    of that weight's bytes, and are activations), so no leaf of this model
+    asks for another form at rest (``query_projections`` names none)."""
+    from deepspeed_tpu.utils.hlo import parameter_rewrites
+    compiled = _olmo_hybrid_program("serve_decode", one_chip)
+    weights = len(jax.tree.leaves(compiled.in_avals[0][0]))
+    assert weights == 3 + 6 * 10 + 2 * 7 + 8 * 4
+    moved = [r for r in parameter_rewrites(compiled.as_text(), weights,
+                                           share=0.5)
+             if r.bytes >= 1 << 20]
+    assert [r for r in moved if r.op != "copy" or r.hbm_bytes] == [], moved
+
+
+# ---------------------------------------------------------------------------
 # whose the programs' instructions are (PR 54): ``utils/hlo.py::scope_cycles``
 # over the texts the tests above already hold.  Cycles are the compiler's
 # guess, of the instructions it guesses for (fusions and copies), in programs
@@ -2141,6 +2293,8 @@ SCOPED_PROGRAMS = {
         lambda c: _glm_program("serve_decode", c).as_text(), 27.1),
     "kimi.serve_decode": (
         lambda c: _kimi_program("serve_decode", c).as_text(), 6.4),
+    "olmo_hybrid.serve_decode": (
+        lambda c: _olmo_hybrid_program("serve_decode", c).as_text(), 2.1),
 }
 
 

@@ -1,8 +1,9 @@
-"""Kimi Linear (``deepspeed_tpu/models/kimi_linear.py``): the KDA decode
-kernel in interpret mode against its plain form with dead slots untouched,
-the chunked (WY) form against the token-by-token recurrence from a
-non-zero state (a padded tail; decays under which a naive ``e^-G``
-overflows), the model against the benchmark's plain float32 reference,
+"""Kimi Linear (``deepspeed_tpu/models/kimi_linear.py``): the two delta-rule
+decode kernels of ``ops/pallas/kda.py`` (a decay a key channel, KDA's; one
+decay a head, Olmo Hybrid's) in interpret mode against their plain form
+with dead slots untouched, both chunked (WY) forms against the
+token-by-token recurrence from a non-zero state (a padded tail; decays
+under which a naive ``e^-G`` overflows) and against each other, the model against the benchmark's plain float32 reference,
 prefill then decode through the one pool and the state by slot, a prompt
 prefilled whole against the same prompt in chunks with other slots' ticks
 between them in a slot that held a state, the shares of the experts with
@@ -26,13 +27,15 @@ from deepspeed_tpu.inference.kv_cache import (PagedKVCacheSpec,
 from deepspeed_tpu.models import kimi_linear
 from deepspeed_tpu.models.kimi_linear import (KimiLinearConfig,
                                               KimiLinearModel)
-from deepspeed_tpu.ops.pallas.kda import (kda_chunked, kda_decode,
+from deepspeed_tpu.ops.pallas.kda import (gdn_chunked, gdn_decode,
+                                          gdn_decode_reference, gdn_heads,
+                                          gdn_rest, kda_chunked, kda_decode,
                                           kda_decode_reference)
 from deepspeed_tpu.ops.pallas.runtime import interpret_scope
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "benchmark"))
-from lib import kimi_linear_reference  # noqa: E402
+from lib import kimi_linear_reference, olmo_hybrid_reference  # noqa: E402
 
 LIN = {"kda_layers": [1, 2, 3, 5], "full_attn_layers": [4], "head_dim": 16,
        "num_heads": 4, "short_conv_kernel_size": 4}
@@ -73,43 +76,69 @@ def _tokens(shape, seed=0):
 
 
 # -- the recurrence -------------------------------------------------------
+# The two forms of ``ops/pallas/kda.py`` under one set of tests: "channel"
+# (KDA: a decay a key channel, ``b`` in (0, 1)) and "head" (Gated DeltaNet,
+# Olmo Hybrid: ONE decay a head, ``b`` in (0, 2), the state at rest ``[dk,
+# H dv]``).  A form: (decode on states BY HEAD, the chunked form, the
+# benchmark's token-by-token recurrence).
 
-def kda_recurrence(q, k, v, g, b, state0):
+def _gdn_decode_by_head(state, a, k, v, q, b, active, **kw):
+    new, o = gdn_decode(gdn_rest(jnp.asarray(state)), a, k, v, q, b, active,
+                        **kw)
+    return gdn_heads(new, state.shape[1]), o
+
+
+FORMS = {
+    "channel": (kda_decode, kda_decode_reference, kda_chunked,
+                kimi_linear_reference.recurrence),
+    "head": (_gdn_decode_by_head, gdn_decode_reference, gdn_chunked,
+             olmo_hybrid_reference.recurrence),
+}
+forms = pytest.mark.parametrize("form", sorted(FORMS))
+
+
+def recurrence(form, q, k, v, g, b, state0):
     """The benchmark's token-by-token delta rule (the oracle of the
     chunked form): -> (o, final state)."""
     with jax.default_matmul_precision("highest"):
-        final, o = kimi_linear_reference.recurrence(q, k, v, g, b, h0=state0)
+        final, o = FORMS[form][3](q, k, v, g, b, h0=state0)
     return o, final
 
 
-def _recurrence_inputs(T, H=3, dk=16, dv=8, decay=0.1, seed=0):
+def _recurrence_inputs(form, T, H=3, dk=16, dv=8, decay=0.1, seed=0):
+    """q, k [T, H, dk], v [T, H, dv], the log-decay (a channel or a head),
+    the step (below 1, or below 2 with every other position above 1) and a
+    drawn state [H, dk, dv]; dk != dv and H no multiple of 8."""
     rng = np.random.default_rng(seed)
 
     def unit(x):
         return x / np.linalg.norm(x, axis=-1, keepdims=True)
 
     f32 = np.float32
-    return (unit(rng.normal(size=(T, H, dk))).astype(f32) * dk ** -0.5,
-            unit(rng.normal(size=(T, H, dk))).astype(f32),
-            rng.normal(size=(T, H, dv)).astype(f32),
-            -rng.uniform(0.0, decay, size=(T, H, dk)).astype(f32),
-            rng.uniform(0, 1, size=(T, H)).astype(f32),
-            rng.normal(size=(H, dk, dv)).astype(f32))
+    q, k, v = (unit(rng.normal(size=(T, H, dk))).astype(f32) * dk ** -0.5,
+               unit(rng.normal(size=(T, H, dk))).astype(f32),
+               rng.normal(size=(T, H, dv)).astype(f32))
+    g = -rng.uniform(0.0, decay, size=(T, H, dk)).astype(f32)
+    b = rng.uniform(0, 1, size=(T, H)).astype(f32)
+    if form == "head":
+        g, b = g[..., 0], b + (np.arange(T) % 2)[:, None].astype(f32)
+    return q, k, v, g, b, rng.normal(size=(H, dk, dv)).astype(f32)
 
 
-def test_kda_decode_kernel_rewrites_the_live_slots_and_no_other():
+@forms
+def test_kda_decode_kernel_rewrites_the_live_slots_and_no_other(form):
     """Two layers' slots in one row, the second layer's updated: the live
     slots are the plain form's, the dead ones and the other layer bit for
     bit what they were, a dead slot's output 0."""
     S = 5
-    q, k, v, g, b, _ = _recurrence_inputs(S, seed=1)
+    decode, plain = FORMS[form][:2]
+    q, k, v, g, b, _ = _recurrence_inputs(form, S, seed=1)
     state = np.random.default_rng(2).normal(
         size=(2 * S, 3, 16, 8)).astype(np.float32)
     active = np.array([True, False, True, True, False])
-    new, o = kda_decode(jnp.asarray(state), np.exp(g), k, v, q, b, active,
-                        base=S, interpret=True)
-    want, want_o = kda_decode_reference(state[S:], np.exp(g), k, v, q, b,
-                                        active)
+    new, o = decode(jnp.asarray(state), np.exp(g), k, v, q, b, active,
+                    base=S, interpret=True)
+    want, want_o = plain(state[S:], np.exp(g), k, v, q, b, active)
     new = np.asarray(new)
     np.testing.assert_allclose(new[S:], want, atol=1e-6)
     np.testing.assert_allclose(o, want_o, atol=1e-6)
@@ -117,31 +146,55 @@ def test_kda_decode_kernel_rewrites_the_live_slots_and_no_other():
     np.testing.assert_array_equal(new[S:][~active], state[S:][~active])
     assert not np.asarray(o)[~active].any()
     # the kernel's plain form is the recurrence's one step
-    o_t, s_t = kda_recurrence(q[:1], k[:1], v[:1], g[:1], b[:1], state[S])
+    o_t, s_t = recurrence(form, q[:1], k[:1], v[:1], g[:1], b[:1], state[S])
     np.testing.assert_allclose(want[0], s_t, atol=1e-6)
     np.testing.assert_allclose(want_o[0], o_t[0], atol=1e-6)
 
 
-def test_kda_decode_kernel_with_no_live_slot_moves_nothing():
+@forms
+def test_kda_decode_kernel_with_no_live_slot_moves_nothing(form):
     S = 3
-    q, k, v, g, b, _ = _recurrence_inputs(S, seed=3)
+    q, k, v, g, b, _ = _recurrence_inputs(form, S, seed=3)
     state = np.random.default_rng(4).normal(
         size=(S, 3, 16, 8)).astype(np.float32)
-    new, o = kda_decode(jnp.asarray(state), np.exp(g), k, v, q, b,
-                        np.zeros((S,), bool), interpret=True)
+    new, o = FORMS[form][0](jnp.asarray(state), np.exp(g), k, v, q, b,
+                            np.zeros((S,), bool), interpret=True)
     np.testing.assert_array_equal(new, state)
     assert not np.asarray(o).any()
 
 
+@pytest.mark.parametrize("heads,dk,dv", [(4, 8, 96), (5, 16, 128)],
+                         ids=["heads_from_mid_tile", "a_head_a_tile"])
+def test_gdn_decode_kernel_walks_lane_tiles_across_heads(heads, dk, dv):
+    """Widths whose lanes are whole tiles (4 x 96 = 3 tiles: heads 1 and 3
+    start in the middle of one): a tile's columns of ``k`` and ``q`` change
+    at the lane where the next head starts."""
+    S = 4
+    q, k, v, g, b, _ = _recurrence_inputs("head", S, H=heads, dk=dk, dv=dv,
+                                          decay=1.0, seed=6)
+    state = np.random.default_rng(7).normal(
+        size=(S, heads, dk, dv)).astype(np.float32)
+    active = np.array([True, True, False, True])
+    new, o = _gdn_decode_by_head(state, np.exp(g), k, v, q, b, active,
+                                 interpret=True)
+    want, want_o = gdn_decode_reference(state, np.exp(g), k, v, q, b, active)
+    np.testing.assert_allclose(new, want, atol=2e-6)
+    np.testing.assert_allclose(o, want_o, atol=2e-6)
+    np.testing.assert_array_equal(np.asarray(new)[2], state[2])
+
+
+@forms
 @pytest.mark.parametrize("decay", [0.1, 5.0], ids=["mild", "strong"])
-def test_chunked_form_is_the_recurrence_from_a_state_that_is_not_zero(decay):
+def test_chunked_form_is_the_recurrence_from_a_state_that_is_not_zero(
+        form, decay):
     """150 positions (two chunks and 22 rows of a third) from a drawn
     state.  At the strong decay a channel's log-decay over a chunk passes
     -300: ``e^-G`` is inf in float32, and the form that divides by it
     gives nothing finite."""
-    q, k, v, g, b, s0 = _recurrence_inputs(150, decay=decay)
-    o, final = jax.jit(kda_chunked)(q, k, v, g, b, s0)
-    want_o, want = kda_recurrence(q, k, v, g, b, s0)
+    chunked = FORMS[form][2]
+    q, k, v, g, b, s0 = _recurrence_inputs(form, 150, decay=decay)
+    o, final = jax.jit(chunked)(q, k, v, g, b, s0)
+    want_o, want = recurrence(form, q, k, v, g, b, s0)
     assert np.isfinite(np.asarray(o)).all()
     np.testing.assert_allclose(o, want_o, atol=2e-5 * np.abs(want_o).max())
     np.testing.assert_allclose(final, want, atol=2e-5 * np.abs(want).max())
@@ -149,22 +202,61 @@ def test_chunked_form_is_the_recurrence_from_a_state_that_is_not_zero(decay):
     with np.errstate(over="ignore"):
         assert (decay < 1) == bool(np.isfinite(np.exp(np.float32(worst))))
     # from zeros the first outputs are others: the start is read
-    other, _ = jax.jit(kda_chunked)(q, k, v, g, b, np.zeros_like(s0))
+    other, _ = jax.jit(chunked)(q, k, v, g, b, np.zeros_like(s0))
     assert np.abs(np.asarray(other)[:4] - want_o[:4]).max() > 1e-2
 
 
-def test_a_padded_tail_leaves_the_state_at_the_true_length():
+@forms
+def test_a_padded_tail_leaves_the_state_at_the_true_length(form):
     """Positions with ``g = 0`` and ``b = 0`` (a padded rung's) neither
     decay the state nor feed it."""
-    q, k, v, g, b, s0 = _recurrence_inputs(128, seed=5)
+    q, k, v, g, b, s0 = _recurrence_inputs(form, 128, seed=5)
     n = 77
     live = np.arange(128) < n
-    g_pad, b_pad = g * live[:, None, None], b * live[:, None]
-    o, final = jax.jit(kda_chunked)(q, k, v, g_pad, b_pad, s0)
-    want_o, want = kda_recurrence(q[:n], k[:n], v[:n], g[:n], b[:n], s0)
+    g_pad = g * live.reshape((-1,) + (1,) * (g.ndim - 1))
+    b_pad = b * live[:, None]
+    o, final = jax.jit(FORMS[form][2])(q, k, v, g_pad, b_pad, s0)
+    want_o, want = recurrence(form, q[:n], k[:n], v[:n], g[:n], b[:n], s0)
     np.testing.assert_allclose(final, want, atol=2e-5 * np.abs(want).max())
     np.testing.assert_allclose(o[:n], want_o,
                                atol=2e-5 * np.abs(want_o).max())
+
+
+@pytest.mark.parametrize("common", [1.0, 1e3], ids=["alike", "one_token"])
+def test_one_decay_a_head_survives_keys_that_resemble_each_other(common):
+    """Keys with a common part (cosine ~0.5 between any two), and a run of
+    ONE key (a prompt that repeats a token: every convolution window the
+    same), 150 of them, at steps up to 2.  The inverse of a chunk's
+    triangular system as the product ``(I + N)(I + N^2)...`` (KDA's, and
+    this form's as first written) read 3e7 times the recurrence's largest
+    output on the first and ``inf`` on the second: its powers ``N^k`` grow
+    like ``C(64, k)`` and cancel; by halves it is the recurrence's to
+    1e-5.  (KDA's form keeps the product, and its weakness on a run of one
+    token, until its cell is read with the other: PERF.md section 7.)"""
+    form = "head"
+    q, k, v, g, b, s0 = _recurrence_inputs(form, 150, decay=0.05, seed=9)
+    shared = np.random.default_rng(10).normal(size=(1,) + k.shape[1:])
+    k = k + common * shared.astype(np.float32)
+    k = (k / np.linalg.norm(k, axis=-1, keepdims=True)).astype(np.float32)
+    assert (k[0] * k[1]).sum(-1).min() > (0.99 if common > 1 else 0.3)
+    o, final = jax.jit(FORMS[form][2])(q, k, v, g, b, s0)
+    want_o, want = recurrence(form, q, k, v, g, b, s0)
+    np.testing.assert_allclose(o, want_o, atol=2e-5 * np.abs(want_o).max())
+    np.testing.assert_allclose(final, want, atol=2e-5 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("decay", [0.1, 5.0], ids=["mild", "strong"])
+def test_one_decay_a_head_is_kda_with_the_decay_spread_over_the_channels(
+        decay):
+    """The tie that keeps the two chunked forms one mathematics: the
+    scalar-decay form against ``kda_chunked`` fed the same decay broadcast
+    over a head's channels, steps up to 2, from a drawn state."""
+    q, k, v, g, b, s0 = _recurrence_inputs("head", 150, decay=decay, seed=8)
+    o, final = jax.jit(gdn_chunked)(q, k, v, g, b, s0)
+    spread = np.broadcast_to(g[..., None], k.shape)
+    want_o, want = jax.jit(kda_chunked)(q, k, v, spread, b, s0)
+    np.testing.assert_allclose(o, want_o, atol=2e-5 * np.abs(want_o).max())
+    np.testing.assert_allclose(final, want, atol=2e-5 * np.abs(want).max())
 
 
 # -- the model against the reference --------------------------------------

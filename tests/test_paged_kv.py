@@ -308,10 +308,12 @@ def test_paged_kernel_grid_at_xl_serving_shapes():
     assert _paged_call_grid(**{**x, "page_len": 64, "max_pages": 16,
                                "P": 209}) == (32, 8)
     # shapes only: q_heads (PR 34) is the query heads over grouped keys,
-    # v_head_dim (PR 36) the values' width where it is not the keys'
+    # v_head_dim (PR 36) the values' width where it is not the keys',
+    # head_major (PR 59) that a page rests [H, page_len, Dh] at a group of
+    # one too: how the pool lies, not a switch of the environment
     assert list(inspect.signature(paged_pages_per_block).parameters) == [
         "heads", "page_len", "head_dim", "itemsize", "max_pages", "q_heads",
-        "v_head_dim"]
+        "v_head_dim", "head_major"]
     source = inspect.getsource(module)
     assert "environ" not in source and "getenv" not in source
 
@@ -329,7 +331,14 @@ def test_paged_arm_follows_the_pool_shape_alone():
     import inspect
     # shapes only (q_heads, PR 34: the query heads over grouped keys)
     assert list(inspect.signature(paged_decode_arm).parameters) == [
-        "heads", "page_len", "head_dim", "itemsize", "q_heads"]
+        "heads", "page_len", "head_dim", "itemsize", "q_heads", "head_major"]
+    # 30 heads on 30 (Olmo Hybrid, PR 59): no whole sublane tile of heads,
+    # so a pool that rested by key would take the packed arm (every page
+    # copied into a buffer whose heads are padded to 32 rows); read as
+    # ``walked.PagePool`` lays it, by head, a page is the operand
+    assert paged_decode_arm(30, 64, 128, 2) == "packed"
+    assert paged_decode_arm(30, 64, 128, 2, head_major=True) == "direct"
+    assert paged_pages_per_block(30, 64, 128, 2, 48, head_major=True) == 2
     x, o = XL_SERVING, OLMOE_SERVING
     assert paged_decode_arm(x["H"], x["page_len"], x["Dh"], 2) == "packed"
     assert paged_pages_per_block(x["H"], x["page_len"], x["Dh"], 2,

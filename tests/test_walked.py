@@ -30,7 +30,7 @@ from deepspeed_tpu.models import walked
 MODELS = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "deepspeed_tpu", "models")
 FAMILIES = ("olmoe", "nemotron_h", "mimo_v2", "axk1", "cohere2_moe",
-            "glm_dsa", "kimi_linear")
+            "glm_dsa", "kimi_linear", "olmo_hybrid")
 
 
 def _imported_modules(path):

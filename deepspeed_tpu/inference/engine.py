@@ -129,13 +129,19 @@ _FROM_DEVICE = -1
 _FROM_PREFILL = -2
 
 
-#: the shortest rung the prefill ladder builds below the half of
-#: ``prefill_len``.  A rung costs the host a fixed second or two of
-#: lowering and loading at set-up whatever its length, and saves device
-#: time in proportion to its length: at 1,024 tokens 38-53 ms a call on
-#: the families read so far (``PERF.md`` section 6, PRs 37 and 53); at 256
-#: it saved ~5 ms a call and cost a short set-up 9 % of a 10 % bound, and
-#: was cut (PR 37)
+#: below the half of ``prefill_len`` the prefill ladder builds ONE further
+#: rung whatever its length (the quarter: the ladder's third rung), and
+#: past it only rungs of at least this many tokens.  A rung costs the host
+#: a fixed second or two of lowering and loading at set-up whatever its
+#: length, and saves device time in proportion to its length: at 1,024
+#: tokens 38-53 ms a call on the families read so far (``PERF.md`` section
+#: 6, PRs 37 and 53).  PR 37 read a 256 rung under 512 at ~5 ms a call and
+#: +2.5 % on one cell and cut it for set-up alone: the first prefill call
+#: then waited for the WHOLE ladder, and a short set-up read +9 % of a
+#: 10 % bound.  Since PR 53 a call waits for the rung it runs, and since
+#: PR 61 a rung under this floor is built last and never waited for
+#: (``late_rungs``, ``ServeEngine._prefill_operand``), so it costs a
+#: caller nothing but the thread's own work: the quarter stands
 LADDER_FLOOR = 1024
 
 
@@ -143,17 +149,26 @@ def prefill_ladder(prefill_len: int) -> tuple:
     """The lengths the prefill program is built at, ascending:
     ``prefill_len``; where that is a whole multiple of 512, its half; and
     below the half each further half that is a whole multiple of 256 and
-    at least ``LADDER_FLOOR`` tokens long (docs/serving.md "The prefill
-    ladder"): 4,096 -> (1,024, 2,048, 4,096), 1,024 -> (512, 1,024).
-    256 tokens is where a bf16 matmul stops being bound by reading its
-    weights, so a shorter program would save nothing, and it is a multiple
-    of every unit a prefill program has (page, window, scan chunk, flash
-    block).  Under 512 the ladder is ``(prefill_len,)``."""
+    is the ladder's third rung or at least ``LADDER_FLOOR`` tokens long
+    (docs/serving.md "The prefill ladder"): 1,024 -> (256, 512, 1,024),
+    2,048 -> (512, 1,024, 2,048), 4,096 -> (1,024, 2,048, 4,096), 512 ->
+    (256, 512).  256 tokens is where a bf16 matmul stops being bound by
+    reading its weights, so a shorter program would save nothing, and it
+    is a multiple of every unit a prefill program has (page, window, scan
+    chunk, flash block).  Under 512 the ladder is ``(prefill_len,)``."""
     ladder = [int(prefill_len)]
     while ladder[0] % 512 == 0 and (
-            len(ladder) == 1 or ladder[0] // 2 >= LADDER_FLOOR):
+            len(ladder) <= 2 or ladder[0] // 2 >= LADDER_FLOOR):
         ladder.insert(0, ladder[0] // 2)
     return tuple(ladder)
+
+
+def late_rungs(ladder: tuple) -> tuple:
+    """The rungs of ``ladder`` that are built after every other and that
+    no call waits for while a longer one is built: those below the half
+    that are shorter than ``LADDER_FLOOR`` (a quarter of 256, 512 or 768
+    tokens; none in a ladder whose ``prefill_len`` is 4,096 or more)."""
+    return tuple(r for r in ladder[:-2] if r < LADDER_FLOOR)
 
 
 class _Tick(NamedTuple):
@@ -561,7 +576,7 @@ class ServeEngine:
         self.aux_log: deque = deque(maxlen=65536)
         #: the lengths the prefill program is built at, ascending
         #: (``prefill_ladder``): a call runs the smallest that holds its
-        #: tokens, the longest is ``prefill_len``
+        #: tokens and is built, the longest is ``prefill_len``
         self.prefill_buckets = prefill_ladder(self.prefill_len)
         #: rung -> prefill calls that ran it (counter
         #: ``serve_prefills_total{bucket=}``)
@@ -572,11 +587,17 @@ class ServeEngine:
         #: ``serve_prefill_pad_tokens_total``)
         self.prefill_pad_tokens = 0
         self.prefill_tokens = 0
+        #: prefill calls that ran a longer rung because the smallest that
+        #: holds their tokens was not built yet (counter
+        #: ``serve_prefill_rung_pending_total``): how long a late rung
+        #: took to arrive, in calls
+        self.prefill_rung_pending = 0
         #: rung -> prefill calls that were one chunk of a longer prompt
         #: (counter ``serve_prefill_chunks_total{bucket=}``)
         self.prefill_chunk_calls = {r: 0 for r in self.prefill_buckets}
-        #: rung -> its executable on its way (``_build_prefill_rung``);
-        #: {} for a ladder of one rung, which is the jitted program
+        #: rung -> its executable on its way (``_build_prefill_rung``),
+        #: in the order the ladder's thread builds them; {} for a ladder
+        #: of one rung, which is the jitted program
         self._prefill_build: Dict[int, Future] = {}
         #: request state by slot (class docstring): name -> shape and
         #: dtype, {} for a model that keeps none
@@ -1203,6 +1224,10 @@ class ServeEngine:
                 "serve_prefills_total",
                 "prefill calls by the rung of the prefill ladder "
                 "(tokens of the program) they ran")
+            self._prefill_pending_ctr = reg.counter(
+                "serve_prefill_rung_pending_total",
+                "prefill calls that ran a longer rung because the "
+                "smallest that holds their tokens was not built yet")
             self._prefill_chunks_ctr = reg.counter(
                 "serve_prefill_chunks_total",
                 "of those, the calls that were one chunk of a prompt "
@@ -1336,14 +1361,18 @@ class ServeEngine:
         if len(self.prefill_buckets) > 1:
             # beside whatever the caller does between building an engine
             # and its first prompt (probing, warming a tick), one rung
-            # after the other on ONE thread, the shortest first: a prefill
-            # call waits for the rung it runs, close() for all of them
+            # after the other on ONE thread, the shortest first and the
+            # late ones (the quarter) last: a prefill call waits only
+            # where no rung that holds its tokens is built
+            # (_prefill_operand), close() for all of them
             pool = ThreadPoolExecutor(
                 1, thread_name_prefix="serve_prefill_rungs")
             operands = self._prefill_rung_operands()
+            late = late_rungs(self.prefill_buckets)
             self._prefill_build = {
                 r: pool.submit(self._build_prefill_rung, r, *operands)
-                for r in self.prefill_buckets}
+                for r in sorted(self.prefill_buckets,
+                                key=lambda r: (r in late, r))}
             pool.shutdown(wait=False)
 
     # -- speculative decoding: the draft plane --------------------------
@@ -2029,9 +2058,21 @@ class ServeEngine:
     def _prefill_operand(self, wanted) -> np.ndarray:
         """A prefill call's ``tokens`` for the ``wanted`` ones (a prompt,
         a delta, a chunk): right-padded to the smallest rung that holds
-        them, the call and its padding counted."""
+        them and is built; where none that holds them is built yet, to
+        the one of them the ladder's thread reaches first (never a late
+        rung), which ``_run_prefill`` then waits for.  The call and its
+        padding are counted for the rung it runs."""
         n = len(wanted)
-        rung = next(r for r in self.prefill_buckets if r >= n)
+        holds = [r for r in self.prefill_buckets if r >= n]
+        build = self._prefill_build
+        rung = next((r for r in holds if r in build and build[r].done()),
+                    None)
+        if rung is None:
+            rung = next((r for r in build if r in holds), holds[0])
+        if rung != holds[0]:
+            self.prefill_rung_pending += 1
+            if self.telemetry is not None:
+                self._prefill_pending_ctr.inc()
         tokens = np.zeros((1, rung), np.int32)
         tokens[0, :n] = wanted
         self.prefill_calls[rung] += 1
@@ -2081,11 +2122,12 @@ class ServeEngine:
         """``serve_prefill`` at the length of its tokens (operand 2): the
         jitted program for a ladder of one rung, as ever; else that rung's
         executable, waited for if the ladder's thread has not reached it
-        yet (a rung still on its way does not hold up a call of another),
-        so that no length compiles anything in the call.  Takes the cache
-        it is handed for the one it returns; gives (first token on the
-        device, the call's counters, its record in the device's queue
-        book)."""
+        yet (``_prefill_operand`` chose it: no built rung holds the
+        tokens; a rung still on its way does not hold up a call of
+        another), so that no length compiles anything in the call.  Takes
+        the cache it is handed for the one it returns; gives (first token
+        on the device, the call's counters, its record in the device's
+        queue book)."""
         rung = operands[2].shape[1]
         fn = self._prefill_fn
         if len(self.prefill_buckets) > 1:

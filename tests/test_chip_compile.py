@@ -512,13 +512,13 @@ def _olmoe_decode_program(one_chip):
 
 
 @functools.cache
-def _olmoe_prefill_program(one_chip):
+def _olmoe_prefill_program(one_chip, bucket=1024):
     model, params, pool = _olmoe_shapes()
     i32 = _sds((), jnp.int32)
     with interpret_scope(False):
         return _compile(
             lambda *a: model.prefill_paged(*a, aux=True), one_chip, params,
-            _sds((1, 1024), jnp.int32), i32, i32,
+            _sds((1, bucket), jnp.int32), i32, i32,
             _sds((OLMOE_MAX_PAGES,), jnp.int32), pool, pool)
 
 
@@ -889,23 +889,40 @@ def test_mimo_prefill_rung_holds_half_the_temporaries_of_stacked_leaves(
 
 
 def _family_program(family):
+    if family == "olmoe":
+        return lambda program, one_chip, bucket=1024: \
+            _olmoe_prefill_program(one_chip, bucket)
     return {"mimo": _mimo_program, "nemotron": _nemotron_program,
             "axk1": _axk1_program, "cmda": _cmda_program,
-            "kimi": _kimi_program}[family]
+            "kimi": _kimi_program, "glm": _glm_program,
+            "dots": _dots_program,
+            "olmo_hybrid": _olmo_hybrid_program}[family]
 
 
 @pytest.mark.parametrize("family,bucket", [
     ("mimo", 2048), ("nemotron", 512),
     # the rung under the half, built from 1,024 tokens up (PR 53)
-    ("mimo", 1024), ("axk1", 1024), ("cmda", 1024), ("kimi", 1024)])
+    ("mimo", 1024), ("axk1", 1024), ("cmda", 1024), ("kimi", 1024),
+    # the quarter of a ladder of two rungs, whatever its length (PR 61):
+    # a new length for a scan chunk of 64 ...
+    ("olmo_hybrid", 256),
+    # ... and of 128, for an indexer that picks 2,048 rows and for a ring
+    # of 513.  Slow: each compiles two more programs at published widths
+    # (~35-45 s a case) in the one file that sets the suite's wall time
+    # and must stay on one worker (ROADMAP D10); run by hand with
+    # `-m slow -k lower_rung` after touching a prefill or the ladder
+    pytest.param("nemotron", 256, marks=pytest.mark.slow),
+    pytest.param("olmoe", 256, marks=pytest.mark.slow),
+    pytest.param("glm", 512, marks=pytest.mark.slow),
+    pytest.param("dots", 512, marks=pytest.mark.slow)])
 def test_the_lower_rung_of_the_prefill_ladder_compiles_under_the_top_rungs_peak(
         family, bucket, one_chip):
     """``ServeEngine`` builds ``serve_prefill`` below
     ``serving.prefill_len`` too (``inference/engine.py::prefill_ladder``:
-    4,096 -> 1,024 and 2,048; 1,024 -> 512).  A shorter rung holds the
-    same kernels, passes the same caches through aliased, and needs fewer
-    temporaries than the rung above it: what the chip must hold at once is
-    still set by ``prefill_len``."""
+    4,096 -> 1,024 and 2,048; 2,048 -> 512 and 1,024; 1,024 -> 256 and
+    512).  A shorter rung holds the same kernels, passes the same caches
+    through aliased, and needs fewer temporaries than the rung above it:
+    what the chip must hold at once is still set by ``prefill_len``."""
     from deepspeed_tpu.inference.engine import prefill_ladder
     build = _family_program(family)
     top = build("serve_prefill", one_chip)
@@ -1289,7 +1306,7 @@ def test_glm_programs_hold_their_kernels_and_both_arrays(program, one_chip):
 
 def test_glm_lower_prefill_rung_compiles_under_the_top_rungs_peak(one_chip):
     from deepspeed_tpu.inference.engine import prefill_ladder
-    assert prefill_ladder(2048) == (1024, 2048)
+    assert prefill_ladder(2048) == (512, 1024, 2048)
     top = _glm_program("serve_prefill", one_chip).memory_analysis()
     rung = _glm_program("serve_prefill", one_chip, 1024).memory_analysis()
     assert rung.alias_size_in_bytes == top.alias_size_in_bytes

@@ -13,6 +13,7 @@ import json
 import sys
 import time
 import types
+from concurrent.futures import wait
 
 import jax
 import numpy as np
@@ -411,6 +412,8 @@ def test_setup_log_stamps_construction_the_ladder_and_first_calls(tmp_path):
         eng.submit(_prompt(0), max_new_tokens=3)
         eng.submit(_prompt(1, 600), max_new_tokens=2)
         eng.run_until_idle()
+        # the quarter is built last and no call waited for it
+        wait(eng._prefill_build.values())
         phases = [phase for phase, _, _ in eng.setup_log]
         assert phases[:3] == ["params", "cache", "feed"]
         for want in ("lower:512", "compile:512", "lower:1024",
@@ -419,7 +422,9 @@ def test_setup_log_stamps_construction_the_ladder_and_first_calls(tmp_path):
                      "first_call:serve_decode"):
             assert phases.count(want) == 1, want
         assert phases.index("lower:512") < phases.index("compile:512") \
-            < phases.index("lower:1024")
+            < phases.index("lower:1024") < phases.index("compile:1024") \
+            < phases.index("lower:256") < phases.index("compile:256")
+        assert "rungs_wait:256" not in phases
         assert all(seconds >= 0 and started > 0
                    for _, started, seconds in eng.setup_log)
         gauge = eng.telemetry.registry.gauge("serve_setup_seconds")
@@ -429,7 +434,7 @@ def test_setup_log_stamps_construction_the_ladder_and_first_calls(tmp_path):
         eng.close()
     with open(tmp_path / "trace.json") as f:
         names = [e["name"] for e in json.load(f)["traceEvents"]]
-    assert names.count("serve/setup_lower") == 2
+    assert names.count("serve/setup_lower") == 3
     assert names.count("serve/setup_first_call") == 3
     assert {"serve/setup_params", "serve/setup_cache", "serve/setup_compile",
             "serve/prefill_wait", "serve/prefill_run"} <= set(names)

@@ -1,13 +1,16 @@
 """The prefill ladder (docs/serving.md "The prefill ladder",
 ``inference/engine.py::prefill_ladder``): ``serve_prefill`` is built at
-``serving.prefill_len``, at its half and, below the half, at each further
-half of at least 1,024 tokens; a call runs the smallest rung that holds
-its tokens.  Proved here by equality and by count, never
+``serving.prefill_len``, at its half, below the half at ONE further half
+whatever its length (the quarter) and past it at each further half of at
+least 1,024 tokens; a call runs the smallest rung that holds its tokens
+and is built.  Proved here by equality and by count, never
 by speed: the same prompts through an engine with its ladder and through
 the same engine held to its top rung give the same streams, ``kv_len``,
-pages and (MiMo-V2) window rings; the counters say which rung ran; a call
-waits for the rung it runs and for no other; and once the ladder's thread
-is through, no length compiles anything.  CPU, tiny widths, seeded weights.
+pages and (MiMo-V2) window rings; the counters say which rung ran; a rung
+under 1,024 tokens below the half is built last and no call waits for it
+while a longer one is built; a call waits only where no rung that holds
+it is built; and once the ladder's thread is through, no length compiles
+anything.  CPU, tiny widths, seeded weights.
 """
 import threading
 from concurrent.futures import wait
@@ -18,23 +21,27 @@ import pytest
 from jax import monitoring
 
 from deepspeed_tpu.inference import ServeEngine
-from deepspeed_tpu.inference.engine import LADDER_FLOOR, prefill_ladder
+from deepspeed_tpu.inference.engine import (LADDER_FLOOR, late_rungs,
+                                            prefill_ladder)
 from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2Model
 from deepspeed_tpu.models.mimo_v2 import MimoV2Config, MimoV2Model
 
 TOP = 1024
-LENGTHS = (5, 250, 257, 512, 513, 600, 1024)
-RUNG = {n: 512 if n <= 512 else 1024 for n in LENGTHS}
+LENGTHS = (5, 250, 256, 257, 512, 513, 600, 1024)
+RUNG = {n: 256 if n <= 256 else 512 if n <= 512 else 1024 for n in LENGTHS}
+CALLS = {r: sum(1 for n in LENGTHS if RUNG[n] == r) for r in (256, 512, 1024)}
 NEW = 3
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 @pytest.mark.parametrize("prefill_len,ladder", [
     (32, (32,)), (128, (128,)), (256, (256,)), (512, (256, 512)),
-    (1024, (512, 1024)), (2048, (1024, 2048)),
-    # below the half a rung is built only from 1,024 tokens up
+    # a ladder of two rungs gets its quarter, whatever its length
+    (1024, (256, 512, 1024)), (2048, (512, 1024, 2048)),
+    (3072, (768, 1536, 3072)),
+    # past the quarter a rung is built only from 1,024 tokens up
     (4096, (1024, 2048, 4096)), (8192, (1024, 2048, 4096, 8192)),
-    (3072, (1536, 3072)), (5120, (1280, 2560, 5120)),
+    (5120, (1280, 2560, 5120)),
     # a half is a rung only as a whole multiple of 256
     (768, (768,)), (1000, (1000,)), (1280, (1280,)), (1536, (768, 1536))])
 def test_the_ladder_of_a_prefill_len(prefill_len, ladder):
@@ -42,11 +49,16 @@ def test_the_ladder_of_a_prefill_len(prefill_len, ladder):
     assert ladder[-1] == prefill_len
     assert all(r % 256 == 0 and 2 * r == up
                for r, up in zip(ladder, ladder[1:]))
-    assert all(r >= LADDER_FLOOR == 1024 for r in ladder[:-2])
-    # no rung is left out: the one under the lowest would be too short,
-    # or no multiple of 256
+    # below the quarter every rung is at least the floor long
+    assert all(r >= LADDER_FLOOR == 1024 for r in ladder[:-3])
+    # no rung is left out: the one under the lowest would be no multiple
+    # of 256, or a fourth rung or further under the floor
     low = ladder[0]
-    assert low % 512 or (len(ladder) > 1 and low // 2 < LADDER_FLOOR)
+    assert low % 512 or (len(ladder) > 2 and low // 2 < LADDER_FLOOR)
+    # the rungs built last and never waited for: a quarter under the floor
+    late = late_rungs(ladder)
+    assert late == tuple(r for r in ladder[:-2] if r < 1024)
+    assert late in ((), ladder[:1]) and (not late or len(ladder) == 3)
 
 
 def _gpt2(top=TOP):
@@ -70,13 +82,18 @@ def _mimo(top=TOP):
 MODELS = {"gpt2": _gpt2, "mimo_v2": _mimo}
 
 
-def _engine(family, one_rung=False, serving=(), top=TOP, **config):
+def _engine(family, one_rung=False, serving=(), top=TOP, built=True,
+            **config):
+    """``built``: the ladder's thread is through before the first prompt,
+    so that which rung a call runs does not hang on the thread's pace."""
     model = MODELS[family](top)
     eng = ServeEngine(model, {
         "serving": {"slots": 2, "page_len": 16, "max_seq_len": top + 8,
                     "prefill_len": top, "prefix_cache": False,
                     **dict(serving)}, **config},
         params=model.init(jax.random.PRNGKey(0)))
+    if built:
+        wait(eng._prefill_build.values())
     if one_rung:
         # the engine as it was before the ladder: held to its top rung
         # from here, in the test; the program has no such option
@@ -144,7 +161,7 @@ def test_a_rung_gives_what_the_top_rung_gives(served, n):
 
 
 def test_the_rungs_are_one_program_under_one_name(served):
-    assert served[False]["calls"] == {512: 4, 1024: 3}
+    assert served[False]["calls"] == CALLS == {256: 3, 512: 2, 1024: 3}
     assert served[True]["calls"] == {TOP: len(LENGTHS)}
     assert served[False]["programs"] == served[True]["programs"]
     assert served[False]["programs"].count("serve_prefill") == 1
@@ -170,6 +187,14 @@ def test_under_512_an_engine_builds_one_prefill_program(prefill_len):
         assert eng._prefill_build == {}
     finally:
         eng.close()
+
+
+def _build_order(eng):
+    """The ladder's thread, once through: its futures and its ``lower``
+    phases, each in the order they came."""
+    return dict(order=list(eng._prefill_build),
+                lowered=[phase for phase, _, _ in eng.setup_log
+                         if phase.startswith("lower:")])
 
 
 def _counted(tmp_path_factory, lengths, top):
@@ -213,6 +238,10 @@ def _counted(tmp_path_factory, lengths, top):
             r: reg.counter("serve_prefills_total", "").value(bucket=str(r))
             for r in eng.prefill_buckets}
         rows["buckets"] = eng.prefill_buckets
+        rows.update(_build_order(eng))
+        rows["pending"] = (
+            eng.prefill_rung_pending,
+            reg.counter("serve_prefill_rung_pending_total", "").value())
     finally:
         eng.close()
         monitoring.unregister_event_duration_listener(listen)
@@ -226,10 +255,10 @@ def counted(tmp_path_factory):
 
 def test_every_rung_is_built_ahead_from_construction_on(counted):
     first = counted[LENGTHS[0]]
-    assert first["rungs_ready"] == [512, 1024]
-    # from construction to the first request's end: both rungs and the
-    # decode tick, at least
-    assert first["compiles"] >= 3
+    assert first["rungs_ready"] == [256, 512, 1024]
+    # from construction to the first request's end: the three rungs and
+    # the decode tick, at least
+    assert first["compiles"] >= 4
 
 
 @pytest.mark.parametrize("n", LENGTHS[1:])
@@ -239,15 +268,20 @@ def test_once_the_ladder_is_built_no_length_compiles(counted, n):
 
 
 def test_the_counters_say_which_rung_ran(counted):
-    assert counted["buckets"] == (512, 1024)
-    assert counted["calls"] == {512: 4, 1024: 3}
-    assert counted["by_bucket"] == {512: 4.0, 1024: 3.0}
+    assert counted["buckets"] == (256, 512, 1024)
+    assert counted["calls"] == CALLS
+    assert counted["by_bucket"] == {r: float(c) for r, c in CALLS.items()}
     wanted = sum(LENGTHS)
     pad = sum(RUNG[n] - n for n in LENGTHS)
     assert counted["tokens"] == (wanted, pad)
     assert counted["pad_counter"] == pad
-    # against the one bucket: 7 x 1,024 less the tokens wanted
+    # every prompt found all three rungs built: none ran a longer rung
+    # than it had to
+    assert counted["pending"] == (0, 0.0)
+    # against the one bucket: 8 x 1,024 less the tokens wanted; and the
+    # three prompts of the quarter would have padded 256 more each at 512
     assert pad < len(LENGTHS) * TOP - wanted
+    assert sum(max(RUNG[n], 512) - n for n in LENGTHS) - pad == 3 * 256
 
 
 @pytest.mark.parametrize("family", sorted(MODELS))
@@ -273,7 +307,7 @@ def test_a_full_engines_admission_still_goes_behind_its_prefill(family):
             eng.close()
     assert out[False][0] == out[True][0]
     assert out[False][1] == out[True][1] and out[False][1]["behind"] >= 2
-    assert out[False][2] == {512: 3, 1024: 2}
+    assert out[False][2] == {256: 1, 512: 2, 1024: 2}
 
 
 ARMS = {
@@ -316,15 +350,16 @@ def test_every_admission_arm_takes_the_ladder(arm):
             eng.close()
     if arm != "sampling":
         assert out[False][0] == out[True][0]
+    # 30 and (a prefix hit's delta) 40 tokens run the quarter, 400 the half
     delta = arm == "prefix_delta"
-    assert out[False][1] == {512: 3 if delta else 2,
+    assert out[False][1] == {256: 2 if delta else 1, 512: 1,
                              1024: 1 if delta else 2}
     assert out[True][1] == {TOP: 4}
 
 
 def test_a_chunk_runs_the_rung_that_holds_it():
     """Chunked prefill takes the ladder as is: a chunk of 64 tokens runs
-    the lower rung, not the whole ``prefill_len``; same stream."""
+    the lowest rung, not the whole ``prefill_len``; same stream."""
     out = {}
     for one_rung in (False, True):
         eng = _engine("gpt2", one_rung, serving={"prefill_chunk_len": 64})
@@ -336,7 +371,7 @@ def test_a_chunk_runs_the_rung_that_holds_it():
         finally:
             eng.close()
     assert out[False][:2] == out[True][:2]
-    assert out[False][2] == {512: 4, 1024: 0}
+    assert out[False][2] == {256: 4, 512: 0, 1024: 0}
     assert out[True][2] == {TOP: 4}
 
 
@@ -403,51 +438,156 @@ def test_three_rungs_count_their_calls_and_their_padding(three_rungs):
     assert two - pad == 3 * 1024
 
 
-# -- a call waits for the rung it runs, close() for all of them -------------
+# -- what a call waits for, and what it never waits for ---------------------
+
+QUARTER, HALF = 256, 512
+
 
 @pytest.fixture
-def top_rung_held_back(monkeypatch):
-    """The ladder's thread stops before the top rung until the gate
-    opens."""
+def held_back(monkeypatch):
+    """``held_back(rung)``: the ladder's thread stops before ``rung``
+    until the gate it returns opens (one rung a test)."""
     gate = threading.Event()
     build = ServeEngine._build_prefill_rung
 
-    def held_back(self, rung, *operands):
-        if rung == TOP:
-            assert gate.wait(120)
-        return build(self, rung, *operands)
+    def hold(rung):
+        def held(self, r, *operands):
+            if r == rung:
+                assert gate.wait(120)
+            return build(self, r, *operands)
 
-    monkeypatch.setattr(ServeEngine, "_build_prefill_rung", held_back)
-    yield gate
+        monkeypatch.setattr(ServeEngine, "_build_prefill_rung", held)
+        return gate
+
+    yield hold
     gate.set()
 
 
-def test_a_call_of_the_lowest_rung_returns_while_the_top_rung_is_building(
-        top_rung_held_back):
-    eng = _engine("gpt2")
+def _waits(eng):
+    return [phase for phase, _, _ in eng.setup_log
+            if phase.startswith("rungs_wait")]
+
+
+@pytest.mark.parametrize("top,order", [
+    (1024, [512, 1024, 256]), (2048, [1024, 2048, 512]),
+    (4096, [1024, 2048, 4096])])
+def test_the_threads_order_is_half_whole_quarter(top, order, counted,
+                                                 three_rungs):
+    """The rungs the ladder had are built first, ascending as ever; a
+    quarter under 1,024 tokens last."""
+    if top == 2048:
+        eng = _engine("gpt2", top=top)
+        try:
+            rows = _build_order(eng)
+        finally:
+            eng.close()
+    else:
+        rows = counted if top == TOP else three_rungs
+    assert rows["order"] == order
+    assert rows["lowered"] == [f"lower:{r}" for r in order]
+    ladder = prefill_ladder(top)
+    assert sorted(order) == list(ladder)
+    assert order[len(order) - len(late_rungs(ladder)):] == \
+        list(late_rungs(ladder))
+
+
+def test_a_call_that_fits_the_quarter_runs_the_half_while_it_is_building(
+        held_back, tmp_path):
+    """With the quarter's build held back, a call that fits it runs the
+    half without waiting, is charged the half's padding and counts one
+    ``prefill_rung_pending``; once the quarter is there the same prompt
+    takes it, and gives the same stream."""
+    gate = held_back(QUARTER)
+    eng = _engine("gpt2", built=False, telemetry={
+        "enabled": True, "output_path": str(tmp_path)})
+    try:
+        reg = eng.telemetry.registry
+        wait([eng._prefill_build[HALF], eng._prefill_build[TOP]])
+        assert not eng._prefill_build[QUARTER].done()
+        first = eng.submit(_prompt(5), max_new_tokens=NEW)
+        eng.run_until_idle()
+        assert not eng._prefill_build[QUARTER].done() and _waits(eng) == []
+        assert eng.prefill_calls == {QUARTER: 0, HALF: 1, TOP: 0}
+        assert eng.prefill_rung_pending == 1
+        assert (eng.prefill_tokens, eng.prefill_pad_tokens) == (5, HALF - 5)
+        # a prompt only the half and the whole hold is not pending on it
+        eng.submit(_prompt(300), max_new_tokens=NEW)
+        eng.run_until_idle()
+        assert eng.prefill_rung_pending == 1 and _waits(eng) == []
+
+        gate.set()
+        wait([eng._prefill_build[QUARTER]])
+        again = eng.submit(_prompt(5), max_new_tokens=NEW)
+        eng.run_until_idle()
+        assert eng.prefill_calls == {QUARTER: 1, HALF: 2, TOP: 0}
+        assert eng.prefill_rung_pending == 1 and _waits(eng) == []
+        assert eng.prefill_pad_tokens == \
+            (HALF - 5) + (HALF - 300) + (QUARTER - 5)
+        assert list(again.tokens) == list(first.tokens)
+        assert len(first.tokens) == NEW
+        assert reg.counter(
+            "serve_prefill_rung_pending_total", "").value() == 1
+        assert {r: reg.counter("serve_prefills_total", "").value(
+            bucket=str(r)) for r in (QUARTER, HALF)} == {QUARTER: 1.0,
+                                                         HALF: 2.0}
+        assert reg.counter("serve_prefill_pad_tokens_total",
+                           "").value() == eng.prefill_pad_tokens
+    finally:
+        gate.set()
+        eng.close()
+
+
+def test_a_call_waits_only_when_no_rung_that_holds_it_is_built(held_back):
+    """Nothing is built: a prompt that fits the quarter waits for the
+    HALF, the first rung the thread reaches that holds it (never for the
+    quarter, which is built last), runs it, and is counted pending."""
+    gate = held_back(HALF)
+    eng = _engine("gpt2", built=False)
+    try:
+        assert not any(b.done() for b in eng._prefill_build.values())
+        threading.Timer(0.3, gate.set).start()
+        req = eng.submit(_prompt(5), max_new_tokens=NEW)
+        eng.run_until_idle()
+        assert len(req.tokens) == NEW and req.finish_reason == "length"
+        assert _waits(eng) == [f"rungs_wait:{HALF}"]
+        assert eng.prefill_calls[HALF] == 1 and eng.prefill_calls[QUARTER] == 0
+        assert eng.prefill_rung_pending == 1
+    finally:
+        gate.set()
+        eng.close()
+
+
+def test_a_call_of_the_half_returns_while_the_top_rung_is_building(
+        held_back):
+    gate = held_back(TOP)
+    eng = _engine("gpt2", built=False)
     try:
         low = eng.submit(_prompt(5), max_new_tokens=NEW)
         eng.run_until_idle()
         assert len(low.tokens) == NEW and low.finish_reason == "length"
-        assert eng._prefill_build[512].done()
+        assert eng._prefill_build[HALF].done()
         assert not eng._prefill_build[TOP].done()
+        assert not eng._prefill_build[QUARTER].done()   # behind the top
         # a call of the top rung waits for that rung
-        threading.Timer(0.3, top_rung_held_back.set).start()
+        threading.Timer(0.3, gate.set).start()
         top = eng.submit(_prompt(600), max_new_tokens=NEW)
         eng.run_until_idle()
         assert len(top.tokens) == NEW and top.finish_reason == "length"
-        phases = [phase for phase, _, _ in eng.setup_log]
-        assert phases.count(f"rungs_wait:{TOP}") == 1
-        assert phases.count("rungs_wait:512") <= 1
-        assert eng.prefill_calls == {512: 1, TOP: 1}
+        waits = _waits(eng)
+        assert waits.count(f"rungs_wait:{TOP}") == 1
+        assert waits.count(f"rungs_wait:{HALF}") <= 1
+        assert f"rungs_wait:{QUARTER}" not in waits
+        assert eng.prefill_calls == {QUARTER: 0, HALF: 1, TOP: 1}
+        assert eng.prefill_rung_pending == 1
     finally:
-        top_rung_held_back.set()
+        gate.set()
         eng.close()
 
 
-def test_close_waits_for_every_rung(top_rung_held_back):
-    eng = _engine("gpt2")
-    threading.Timer(0.3, top_rung_held_back.set).start()
+def test_close_waits_for_every_rung(held_back):
+    gate = held_back(TOP)
+    eng = _engine("gpt2", built=False)
+    threading.Timer(0.3, gate.set).start()
     eng.close()
     assert all(built.done() for built in eng._prefill_build.values())
-    assert sorted(eng._prefill_build) == [512, TOP]
+    assert sorted(eng._prefill_build) == [QUARTER, HALF, TOP]

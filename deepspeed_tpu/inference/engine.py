@@ -638,26 +638,14 @@ class ServeEngine:
                 pages = 1 + self.slots * self.max_pages
                 dp = mesh.shape.get(DATA_AXIS, 1)
                 pages += (-pages) % dp
-            # grouped keys: the pool holds the KEY heads
-            kv_heads = getattr(mcfg, "n_kv_head", mcfg.n_head)
-            # two widths: the values' where they are not the keys'
-            v_dim = getattr(mcfg, "d_head_v", None)
-            # one pool: the values are the first d_head_v lanes of the
-            # rows (latent attention), and the cache has no "v"
-            one_pool = bool(getattr(mcfg, "values_in_keys", False))
-            # a second paged array under the same page ids: an indexer
-            # key a token on the layers that score (learned sparse
-            # attention); the model's paged steps take and return it as
-            # ``index_pool`` after the pools
-            index_layers = int(getattr(mcfg, "n_index_layer", 0))
-            self.cache_spec = PagedKVCacheSpec(
-                layers=mcfg.n_layer, slots=self.slots,
-                heads=kv_heads, pages=pages, page_len=self.page_len,
-                head_dim=mcfg.d_head, max_pages=self.max_pages,
-                dtype=(jnp.int8 if self.quant_kv else kv_dtype),
-                quant=self.quant_kv, v_head_dim=v_dim,
-                values_in_keys=one_pool, index_layers=index_layers,
-                index_dim=mcfg.d_index if index_layers else 0)
+            self.cache_spec = PagedKVCacheSpec.for_model(
+                mcfg, slots=self.slots, pages=pages, page_len=self.page_len,
+                max_seq_len=self.max_seq_len, dtype=kv_dtype,
+                quant=self.quant_kv)
+            kv_heads = self.cache_spec.heads
+            v_dim = self.cache_spec.v_head_dim
+            one_pool = self.cache_spec.values_in_keys
+            index_layers = self.cache_spec.index_layers
             validate_paged_cache_mesh(mesh, self.cache_spec)
             self._cache_shardings = paged_cache_shardings(
                 mesh, quant=self.quant_kv, values_in_keys=one_pool,

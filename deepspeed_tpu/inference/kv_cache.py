@@ -186,6 +186,32 @@ class PagedKVCacheSpec:
                 f"(got {self.v_head_dim}) of the rows' {self.head_dim} "
                 "lanes, and such a pool has no int8 arm")
 
+    @classmethod
+    def for_model(cls, mcfg, *, slots: int, pages: int, page_len: int,
+                  max_seq_len: int, dtype, quant: bool = False
+                  ) -> "PagedKVCacheSpec":
+        """The cache a model's config asks for (``dtype``: the master
+        dtype the rows rest in; int8 with ``quant``)."""
+        # a second paged array under the same page ids: an indexer key a
+        # token on the layers that score (learned sparse attention); the
+        # model's paged steps take and return it as ``index_pool`` after
+        # the pools
+        index_layers = int(getattr(mcfg, "n_index_layer", 0))
+        return cls(
+            layers=mcfg.n_layer, slots=slots,
+            # grouped keys: the pool holds the KEY heads
+            heads=getattr(mcfg, "n_kv_head", mcfg.n_head),
+            pages=pages, page_len=page_len, head_dim=mcfg.d_head,
+            max_pages=-(-max_seq_len // page_len),
+            dtype=jnp.int8 if quant else dtype, quant=quant,
+            # two widths: the values' where they are not the keys'
+            v_head_dim=getattr(mcfg, "d_head_v", None),
+            # one pool: the values are the first d_head_v lanes of the
+            # rows (latent attention), and the cache has no "v"
+            values_in_keys=bool(getattr(mcfg, "values_in_keys", False)),
+            index_layers=index_layers,
+            index_dim=mcfg.d_index if index_layers else 0)
+
     @property
     def value_dim(self) -> int:
         return self.head_dim if self.v_head_dim is None else self.v_head_dim

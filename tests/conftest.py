@@ -36,8 +36,8 @@ os.environ.setdefault("DS_DISK_FSYNC", "0")
 # temporary directory and removed at its end (``pytest_sessionfinish``),
 # so no run ever reads what another left.  The two thresholds are JAX's
 # own: keep every program, however quick to compile or small.
-# ``tests/test_chip_compile.py`` switches the cache off for its
-# described-device compiles.
+# ``tests/chip.py::topo`` switches the cache off for the described-device
+# compiles.
 _RUN_OWNS_CACHE = "PYTEST_XDIST_WORKER" not in os.environ
 if _RUN_OWNS_CACHE:
     import tempfile
@@ -195,3 +195,7 @@ def pytest_sessionfinish(session, exitstatus):
                 print(line)
         if session.exitstatus == 0:
             session.exitstatus = 1
+
+
+# The described chip of ``tests/test_chip_*.py`` (``tests/chip.py``).
+from chip import one_chip, topo  # noqa: E402,F401

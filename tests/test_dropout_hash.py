@@ -227,7 +227,7 @@ def _cpu_text(fn):
 def test_rng_fusions_tells_a_threefry_draw_from_the_hash(generator):
     """The CPU's compiler rolls threefry's twenty rotations into a loop of
     five with four in its body (the TPU's unrolls them into the fusion
-    that reads the mask: ``tests/test_chip_compile.py``), so here a body
+    that reads the mask: ``tests/test_chip_training.py``), so here a body
     of four rounds is what is looked for; the hash has three shifts."""
     def drawn(x, key):
         keep = jax.random.bernoulli(key, 0.9, x.shape)

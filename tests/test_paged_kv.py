@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+import chip
 from deepspeed_tpu.config import DeepSpeedConfigError
 from deepspeed_tpu.inference import (PagedKVCacheSpec, ServeEngine,
                                      init_paged_cache, shard_cache)
@@ -981,6 +982,72 @@ def test_paged_cache_mesh_validation():
             build_mesh(dp=1, tp=2, devices=jax.devices()[:2]), spec2)
     assert spec.page_bytes == 2 * 2 * 4 * 4 * 8 * 4
     assert spec.bytes == spec.page_bytes * spec.pages
+
+
+#: benchmark/configs/<name>.json -> (pool names, their shapes, bytes) of the
+#: cache ``ServeEngine`` made for the cell when PR 62 moved the rules into
+#: ``PagedKVCacheSpec.for_model``
+CELL_CACHES = {
+    "a.x-k1": (("k",), {"k": (6, 12289, 1, 64, 640)}, 6040289280),
+    "command-a-plus-05-2026": (("k", "v"), {
+        "k": (1, 12289, 8, 64, 128), "v": (1, 12289, 8, 64, 128)},
+        3221487616),
+    "dots3-note-prev": (("k", "index_k"), {
+        "k": (3, 12289, 1, 64, 640), "index_k": (3, 12289, 1, 64, 128)},
+        3624173568),
+    "glm-5.2": (("k", "index_k"), {
+        "k": (7, 7169, 1, 64, 640), "index_k": (2, 7169, 1, 64, 128)},
+        4345905152),
+    "gpt2-xl": (("k", "v"), {
+        "k": (48, 833, 25, 16, 64), "v": (48, 833, 25, 16, 64)}, 4094361600),
+    "kimi-linear-48b-a3b": (("k",), {"k": (2, 24577, 1, 64, 640)},
+                            4026695680),
+    "mimo-v2.5": (("k", "v"), {
+        "k": (2, 13825, 4, 64, 256), "v": (2, 13825, 4, 64, 128)},
+        5436211200),
+    "nemotron-3-super-120b-a12b": (("k", "v"), {
+        "k": (1, 24577, 2, 16, 128), "v": (1, 24577, 2, 16, 128)}, 402669568),
+    "olmo-hybrid-7b": (("k", "v"), {
+        "k": (2, 3073, 30, 64, 128), "v": (2, 3073, 30, 64, 128)},
+        6041763840),
+    "olmoe-1b-7b": (("k", "v"), {
+        "k": (12, 3457, 16, 16, 128), "v": (12, 3457, 16, 16, 128)},
+        5437390848),
+}
+_CONFIGS = os.path.join(chip.ROOT, "benchmark", "configs")
+
+
+def test_every_served_configuration_has_its_cache_pinned():
+    served = set()
+    for name in os.listdir(_CONFIGS):
+        with open(os.path.join(_CONFIGS, name)) as f:
+            if "serving" in json.load(f):
+                served.add(name[:-len(".json")])
+    assert served == set(CELL_CACHES)
+
+
+@pytest.mark.parametrize("name", sorted(CELL_CACHES))
+def test_spec_for_model_is_the_cells_cache(name):
+    """``PagedKVCacheSpec.for_model`` on the model the benchmark builds
+    from the file, under the file's ``serving`` block: the pools the
+    engine had, by name, shape and bytes, and a table row a slot.  No
+    engine is built and nothing is allocated."""
+    model, file = chip.served_model(name, cut=False)
+    spec, cache, _ = chip.served_cache(name, cut=False)
+    serving = file["serving"]
+    names, shapes, nbytes = CELL_CACHES[name]
+    assert spec.pool_names == names
+    assert {k: cache[k].shape for k in names} == shapes
+    assert all(cache[k].dtype == jnp.dtype(file["dtype"]) for k in names)
+    assert spec.bytes == nbytes
+    assert cache["lengths"].shape == (serving["slots"],)
+    assert spec.max_pages * spec.page_len >= serving["max_seq_len"] \
+        > (spec.max_pages - 1) * spec.page_len
+    quant = PagedKVCacheSpec.for_model(
+        model.config, slots=1, pages=2, page_len=spec.page_len,
+        max_seq_len=spec.page_len, dtype=jnp.float32,
+        quant=not (spec.values_in_keys or spec.index_layers))
+    assert quant.dtype == (jnp.int8 if quant.quant else jnp.float32)
 
 
 def test_paged_tp_dp_sharded_matches_single_device():

@@ -327,8 +327,8 @@ def test_collectives_reads_the_tpu_compilers_text():
         collectives("%lonely (a: f32[]) -> f32[] {\n}\n")
 
 
-# the forms of a decode tick compiled for a v5e (tests/test_chip_compile.py
-# reads the real one): a stacked weight cut by a fusion that writes one
+# the forms of a decode tick compiled for a v5e (tests/test_chip_*.py
+# read the real ones): a stacked weight cut by a fusion that writes one
 # layer to HBM and one to fast memory, a per-layer weight brought to fast
 # memory by one copy, a weight written to HBM transposed through a bitcast,
 # a weight fused with its matmul, the compiler's own prefetch, and a cache

@@ -26,6 +26,36 @@ lanes ``[S, H, N]``, ``dt x`` with the channels on the sublanes ``[S, P,
 H]`` (a head's column is a static lane slice), ``B`` and ``C`` a row a
 group ``[S, G, N]``; ``y`` comes back ``[S, P, H]``.
 
+``y = h C`` is summed ACROSS heads, never over a head's own lanes.  A
+head's tile ``[P, N]`` has ``N`` on the lanes, so a sum a head is one
+cross-lane reduction a register, 1,024 a slot and layer at 128 heads of
+64 channels, and was the only part of the body the block's DMA did not
+hide (PERF.md section 6, PR 64: 14.6 ms a tick of this kernel alone with
+it, 12.2 without it, which is what a plain copy through the same blocks
+takes).  Instead the heads' products ``new * C`` (each head with its own
+group's ``C``, so heads of any two groups pair) are merged two and two:
+with ``low`` the lanes whose bit ``w`` is clear, ``where(low, a, b) +
+roll(where(low, b, a), w)`` is one tile that holds both heads' partial
+sums, ``a``'s on the ``low`` lanes and ``b``'s on the others.  Level by
+level, ``w`` = 1, 2, 4, ... ``N / 2`` IN THAT ORDER (a lane keeps its low
+bits through every later rotation; the falling order mixes two heads'
+sums), ``N`` heads end as ONE tile with head ``h``'s sum at lane ``h``:
+``y``'s block as it is stored, with no reduction, no select of a column
+into ``y`` and nothing to put back in order afterwards.  A node of the
+tree is a head's WHOLE tile (``P / 8`` registers): a merge's add waits
+for its rotation to come back from the cross-lane unit, and what hides
+that wait is the ``P / 8`` independent rotations a merge has in flight
+(the same 1,016 rotations a step read 34.9 ms a tick with ONE register a
+node, 12.3 with eight).  The pairs are formed as the heads are walked,
+as a binary counter carries, so at most one node a level is live; fewer
+heads than lanes finish with rotations of the tile on itself, more are a
+tree every ``N`` heads.  Only the ORDER of a sum's ``N`` float32 adds
+changes (a balanced tree: nearer the exact sum than a running one); the
+state is stored before the product is taken, and its bits are what they
+were.  The matrix unit at ``HIGHEST`` (``new`` against 128 rows of ``C``)
+reads the same time at these widths and was not taken: its work grows
+with the SQUARE of the heads.
+
 **Prefill** (:func:`ssd_chunked`).  The chunked ("state-space duality")
 form at the published chunk of 128: within a chunk the output is a masked
 matrix product, across chunks a recurrence over a handful of chunk
@@ -67,17 +97,37 @@ def _ssm_decode_kernel(rows_ref, ids_ref, n_ref, h_ref, da_ref, dtx_ref, b_ref, 
     @pl.when(live)
     def _():
         dtx = dtx_ref[0]                                    # [P, H]
-        lane = jax.lax.broadcasted_iota(jnp.int32, dtx.shape, 1)
-        y = jnp.zeros(dtx.shape, F32)
-        for h in range(heads):
-            g = h // group
-            new = (h_ref[0, h] * da_ref[0, h:h + 1, :]
-                   + dtx[:, h:h + 1] * b_ref[0, g:g + 1, :])     # [P, N]
-            o_ref[0, h] = new
-            col = jnp.sum(new * c_ref[0, g:g + 1, :], axis=-1,
-                          keepdims=True)                    # [P, 1]
-            y = jnp.where(lane == h, col, y)
-        y_ref[0] = y
+        P, N = dtx.shape[0], c_ref.shape[2]
+        lane = jax.lax.broadcasted_iota(jnp.int32, (P, N), 1)
+
+        def merge(a, b, w):
+            """Two nodes of ``w`` heads each -> one of ``2 w``: the
+            lanes whose bit ``w`` is clear keep ``a``'s partial sums,
+            the others ``b``'s, each with the half that lay ``w`` lanes
+            below added."""
+            low = (lane & w) == 0
+            return jnp.where(low, a, b) + pltpu.roll(jnp.where(low, b, a),
+                                                     w, 1)
+
+        for h0 in range(0, heads, N):       # as many heads as lanes a tree
+            h1 = min(h0 + N, heads)
+            stack = []                      # [(heads merged, node [P, N])]
+            for h in range(h0, h1):
+                g = h // group
+                new = (h_ref[0, h] * da_ref[0, h:h + 1, :]
+                       + dtx[:, h:h + 1] * b_ref[0, g:g + 1, :])     # [P, N]
+                o_ref[0, h] = new
+                w, node = 1, new * c_ref[0, g:g + 1, :]
+                # a binary counter: one node a level is live at most
+                while stack and stack[-1][0] == w:
+                    node = merge(stack.pop()[1], node, w)
+                    w *= 2
+                stack.append((w, node))
+            (w, node), = stack
+            while w < N:                    # fewer heads than lanes: a
+                node = node + pltpu.roll(node, w, 1)    # tile on itself
+                w *= 2
+            y_ref[0, :, h0:h1] = node[:, :h1 - h0]
 
     @pl.when((n_ref[0] == 0) & (pl.program_id(0) == 0))
     def _():
@@ -96,9 +146,20 @@ def ssm_decode(state, decay, dtx, b, c, active, *, base=0,
     dtx [S, H, P] = ``dt x``, b / c [S, G, N], active [S] bool.
     Returns (state, y [S, H, P] float32); ``state`` is the operand,
     rewritten in place for the active slots and untouched for the
-    others, whose ``y`` is 0."""
+    others, whose ``y`` is 0.
+
+    Refused at trace time: a state width ``N`` that is no power of two
+    (a lane keeps its head through the rotations only by its low bits),
+    and a head count that is neither a power of two nor whole ``N``s
+    (a tree of merges wants full pairs at every level)."""
     X, H, P, N = state.shape
     S, G = b.shape[0], b.shape[1]
+    if N & (N - 1) or (H & (H - 1) and H % N):
+        raise ValueError(
+            f"{SSM_DECODE_KERNEL}: y = h C is merged across heads by lane "
+            f"rotations, which takes a state width that is a power of two "
+            f"and heads that are a power of two or a multiple of the width; "
+            f"got N = {N}, H = {H}")
     if interpret is None:
         interpret = use_interpret()
     i32 = jnp.int32

@@ -5,6 +5,7 @@ against the reference's full forward, the share of the experts against the
 uncut layer, grouped keys against repeated keys, and the refusals.  CPU,
 tiny widths, seeded weights.  (Its cell's rehearsal:
 tests/test_benchmark_cells.py.)"""
+import collections
 import dataclasses
 import os
 import subprocess
@@ -148,6 +149,97 @@ def test_decode_kernel_step_equals_the_recurrence_by_hand():
             jnp.asarray(c[t], jnp.float32)[None], on)
         np.testing.assert_allclose(y[0], ys[t], atol=1e-4)
     np.testing.assert_allclose(state[0], h, atol=1e-5)
+
+
+# heads, channels, groups, width of a state: the two small shapes of the
+# tests above (8 and 2 register tiles a group) and the published one (128)
+CONTRACTION_SHAPES = [(8, 16, 2, 128), (4, 8, 2, 128), (128, 64, 8, 128)]
+_contraction_ids = ["8x16", "4x8", "published-128x64"]
+
+
+@pytest.mark.parametrize("H,P,G,N", CONTRACTION_SHAPES, ids=_contraction_ids)
+def test_decode_kernel_contracts_y_for_every_head_and_channel(H, P, G, N):
+    """``y = h C`` leaves the kernel merged across heads by lane rotations
+    (PR 64): every (head, channel) against the plain sum over the lanes,
+    two slots of which one is idle, at the tolerances of the tests above."""
+    k = jax.random.split(jax.random.PRNGKey(64), 5)
+    S = 2
+    state = jax.random.normal(k[0], (S, H, P, N), jnp.float32)
+    decay = jax.random.uniform(k[1], (S, H))
+    dtx = jax.random.normal(k[2], (S, H, P))
+    b, c = (jax.random.normal(kk, (S, G, N)) for kk in k[3:])
+    active = jnp.asarray([False, True])
+    new, y = ssm_decode(state, decay, dtx, b, c, active)
+    want, want_y = ssm_decode_reference(state, decay, dtx, b, c, active)
+    np.testing.assert_allclose(new, want, atol=1e-5)
+    np.testing.assert_allclose(y, want_y, atol=1e-4)
+    np.testing.assert_array_equal(new[0], state[0])
+    assert not np.asarray(y[0]).any() and np.asarray(y[1]).all()
+
+
+@pytest.mark.parametrize(
+    "H,P,G,N", CONTRACTION_SHAPES + [(256, 8, 4, 128), (8, 8, 2, 16)],
+    ids=_contraction_ids + ["two-trees-256x8", "tiny-models-width-16"])
+def test_decode_kernel_is_exact_on_operands_that_round_nowhere(H, P, G, N):
+    """Small whole numbers and decays of 1, 1/2, 1/4: every product and
+    every sum is exact in float32 in ANY order, so the new state is bit
+    for bit ``state * decay + dt x (outer) B`` and ``y`` bit for bit its
+    sum against ``C``; a head that landed at another head's lane, twice,
+    or beside another's partial sum would show in the bits."""
+    rng = np.random.default_rng(H * P + N)
+    S = 2
+    state = rng.integers(-8, 9, (S, H, P, N)).astype(np.float32)
+    decay = rng.choice([1.0, 0.5, 0.25], (S, H)).astype(np.float32)
+    dtx = rng.integers(-4, 5, (S, H, P)).astype(np.float32)
+    b = rng.integers(-4, 5, (S, G, N)).astype(np.float32)
+    c = rng.integers(-2, 3, (S, G, N)).astype(np.float32)
+    new, y = ssm_decode(*(jnp.asarray(t) for t in (state, decay, dtx, b, c)),
+                        jnp.asarray([True, False]))
+    bh, ch = (np.repeat(t.astype(np.float64), H // G, axis=1) for t in (b, c))
+    want = state * decay[..., None, None] + dtx[..., None] * bh[:, :, None, :]
+    want_y = (want * ch[:, :, None, :]).sum(-1)
+    assert np.abs(want_y[0]).max() > 100        # not a sum of nothing
+    np.testing.assert_array_equal(new[0], want[0])
+    np.testing.assert_array_equal(y[0], want_y[0])
+    np.testing.assert_array_equal(new[1], state[1])
+    assert not np.asarray(y[1]).any()
+
+
+def _primitive_counts(jaxpr):
+    """primitive name -> uses in ``jaxpr``, the bodies of its calls and
+    branches included."""
+    counts = collections.Counter(e.primitive.name for e in jaxpr.eqns)
+    for eqn in jaxpr.eqns:
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            counts += _primitive_counts(sub)
+    return counts
+
+
+def test_decode_kernel_sums_over_no_lanes_at_the_published_widths():
+    """The kernel's own jaxpr at 128 heads of 64 x 128: 127 lane
+    rotations of a head's tile (a tree over the heads) and no
+    ``reduce_sum`` at all, where the body before PR 64 held 128 over the
+    lanes (1,024 cross-lane reductions a slot and layer on the chip)."""
+    S, H, P, N, G = 4, 128, 64, 128, 8
+    f32 = jnp.float32
+    sds = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(ssm_decode)(
+        sds((S, H, P, N), f32), sds((S, H), f32), sds((S, H, P), f32),
+        sds((S, G, N), f32), sds((S, G, N), f32), sds((S,), jnp.bool_))
+    kernel, = (e for e in jaxpr.eqns if e.primitive.name == "pallas_call")
+    counts = _primitive_counts(kernel.params["jaxpr"])
+    assert counts["reduce_sum"] == 0, counts
+    assert counts["roll"] == H - 1, counts
+
+
+@pytest.mark.parametrize("H,N", [(6, 128), (192, 128), (8, 96)],
+                         ids=["6-heads", "192-heads", "96-wide"])
+def test_decode_kernel_refuses_by_name_a_state_it_cannot_merge(H, N):
+    S, P, G = 2, 8, 2
+    z = jnp.zeros
+    with pytest.raises(ValueError, match="ds_ssm_decode.*power of two"):
+        ssm_decode(z((S, H, P, N)), z((S, H)), z((S, H, P)), z((S, G, N)),
+                   z((S, G, N)), jnp.ones((S,), bool))
 
 
 def test_sigmoid_router_by_hand():

@@ -1,7 +1,7 @@
 """What the served families whose layers are WALKED share: ``olmoe.py``
 (the one that scans), ``nemotron_h.py``, ``mimo_v2.py``, ``axk1.py``,
 ``cohere2_moe.py``, ``glm_dsa.py``, ``kimi_linear.py``, ``dots3_note.py``,
-``olmo_hybrid.py``.  A family's file
+``olmo_hybrid.py``, ``lfm2_moe.py``.  A family's file
 holds what is its own: its config under the source's keys, ``init`` and
 the parameter tree, its projections, latents and mixers, its list of layer
 kinds, and two paged steps that read as that list walked over the pieces
@@ -13,8 +13,9 @@ keep their sources' key names: ``rms_norm_eps``, ``layernorm_epsilon``,
 ``layer_norm_epsilon``, ``layer_norm_eps``): the mathematics two or more
 families call; the index preludes of the two paged steps
 (:func:`decode_index`, :func:`prefill_index`); the cache's bookkeeping as
-two pairs of write and attend (:class:`PagePool`, :class:`Rings`) and the
-ring of ONE array of latent rows (:class:`LatentRing`); the
+two pairs of write and attend (:class:`PagePool`, :class:`Rings`), the
+pool of grouped keys narrower than the lanes (:class:`PairedPagePool`) and
+the ring of ONE array of latent rows (:class:`LatentRing`); the
 routed-expert call (:func:`routed_experts`); a chunk's attention over keys
 gathered from the pool ahead of it (:func:`context_attention`); the
 delta-rule mixers' small parts (``kimi_linear.py``, ``olmo_hybrid.py``);
@@ -300,12 +301,18 @@ def l2_norm(x):
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
 
 
-def silu_conv(conv_w, taps):
+def conv_taps(conv_w, taps):
     """A depthwise causal convolution as its taps: ``conv_w`` [K, C];
     ``taps``: the K rows under the filter, oldest first, each [..., C] ->
-    silu(conv) in float32 [..., C]."""
+    the convolution in float32 [..., C], no activation
+    (``models/lfm2_moe.py`` calls it bare)."""
     w = conv_w.astype(F32)
-    return jax.nn.silu(sum(t.astype(F32) * w[j] for j, t in enumerate(taps)))
+    return sum(t.astype(F32) * w[j] for j, t in enumerate(taps))
+
+
+def silu_conv(conv_w, taps):
+    """silu(:func:`conv_taps`): the delta-rule mixers' convolution."""
+    return jax.nn.silu(conv_taps(conv_w, taps))
 
 
 def shift_tail(tail, new, active):
@@ -319,6 +326,21 @@ def shift_tail(tail, new, active):
     the first one's rows (PERF.md section 6, PR 52)."""
     window = jnp.concatenate([tail, new.astype(tail.dtype)], axis=1)
     return window, jnp.where(active[:, None, None], window[:, 1:], tail)
+
+
+def shift_tail_lanes(tail, new, active):
+    """:func:`shift_tail` for tails that rest with their rows SIDE BY SIDE
+    on the lanes: ``tail`` [S, (K - 1) C] and the tick's rows ``new`` [S, C]
+    -> (the K taps, oldest first, each [S, C]: aligned slices of the lanes;
+    the tail to keep [S, (K - 1) C]).  A leaf ``[layers, slots, K - 1, C]``
+    with ``K - 1`` = 2 rests in tiles of 2 sublanes, and the tick's one
+    write of it was a copy into that layout the compiler priced at a twelfth
+    of the tick (described-v5e compile, PR 65); ``[layers, slots, (K - 1)
+    C]`` rests in whole tiles."""
+    C = new.shape[-1]
+    window = jnp.concatenate([tail, new.astype(tail.dtype)], axis=-1)
+    taps = [window[:, j * C:(j + 1) * C] for j in range(window.shape[-1] // C)]
+    return taps, jnp.where(active[:, None], window[:, C:], tail)
 
 
 def swiglu(x, gate_w, up_w, down_w):
@@ -338,6 +360,15 @@ def lm_head(x, norm_w, head_w, eps: float):
     """The final RMSNorm, then the untied head [d, V]."""
     x = rms_norm(x, norm_w, eps)
     return x @ head_w.astype(x.dtype)
+
+
+@jax.named_scope("lm_head")
+def tied_head(x, norm_w, wte, eps: float):
+    """The final RMSNorm, then the head that IS the embedding ``wte`` [V,
+    d], read where it lies: one ``dot_general`` over the last axes of both,
+    no transpose of the table."""
+    x = rms_norm(x, norm_w, eps)
+    return jnp.einsum("...d,vd->...v", x, wte.astype(x.dtype))
 
 
 # -- latent attention (MLA): what its families share --------------------------
@@ -784,6 +815,68 @@ class PagePool(_LayerRows):
         return decode_attention_paged(
             q, k, v, page_table + layer * self.per_layer, att_len,
             sm_scale=sm_scale, impl=impl, head_major=True)
+
+
+def lane_pairs(kv_heads: int, head_dim: int) -> int:
+    """Key heads that rest side by side in one row of the pool, for
+    grouped keys whose head is narrower than the 128 lanes: a token's
+    ``kv_heads x head_dim`` keys are contiguous, so ``pairs`` heads of
+    ``head_dim`` ARE one head of ``pairs * head_dim`` (a reshape of ``x
+    W_k``).  The most heads that fit the lanes and divide ``kv_heads``
+    (8 heads of 64: 2); 1 where the head fills the lanes."""
+    fit = max(_LANES // head_dim, 1)
+    return max(p for p in range(1, fit + 1) if kv_heads % p == 0)
+
+
+class PairedPagePool(PagePool):
+    """The POOL pair of grouped keys at a head under 128 (:func:`lane_pairs`
+    of them a row): the engine's pools are ``[L, pages, Hkv / pairs,
+    page_len, pairs * D]``, the PUBLISHED bytes, and every copy the decode
+    kernel makes of a page is whole lane tiles wide (Mosaic copies no
+    window of an HBM array whose last dimension is under 128:
+    ``ops/pallas/decode_attention.py``).  ``write`` and ``attend`` take and
+    give the model's own heads; a query head's ``D`` values ride the lanes
+    its key head owns with zeros in the others (the scores are the same
+    sums; ``pairs`` times the multiply-adds of a kernel its bytes bound),
+    and its output is those lanes of the paired row."""
+
+    def __init__(self, arrays, page_ids, offs, keep, *, kv_heads: int):
+        super().__init__(arrays, page_ids, offs, keep)
+        assert kv_heads % self.heads == 0, (kv_heads, self.heads)
+        self.kv_heads, self.pairs = kv_heads, kv_heads // self.heads
+
+    def write(self, layer: int, *new):
+        """``new``: one [n, Hkv, D] for each array."""
+        super().write(layer, *(t.reshape(t.shape[0], self.heads, -1)
+                               for t in new))
+
+    def _own(self, q_heads: int, dtype):
+        """For each of the row's ``pairs`` parts, [Hq, 1]: 1 where a query
+        head's key head owns that part of the lanes."""
+        part = jnp.arange(q_heads) // (q_heads // self.kv_heads) % self.pairs
+        return [(part == j).astype(dtype)[:, None] for j in range(self.pairs)]
+
+    def attend(self, layer: int, q, page_table, att_len, *, impl: str,
+               sm_scale: float):
+        """q [S, Hq, D] -> [S, Hq, D]; ``sm_scale`` is the caller's (the
+        pool's rows are ``pairs`` heads wide)."""
+        if self.pairs == 1:
+            return super().attend(layer, q, page_table, att_len, impl=impl,
+                                  sm_scale=sm_scale)
+        D, own = q.shape[-1], self._own(q.shape[1], q.dtype)
+        wide = jnp.concatenate([q * on for on in own], axis=-1)
+        out = super().attend(layer, wide, page_table, att_len, impl=impl,
+                             sm_scale=sm_scale)
+        return sum(out[..., j * D:(j + 1) * D] * on
+                   for j, on in enumerate(own))
+
+    def unpaired(self, rows):
+        """Rows out of the pool [Hkv / pairs, T, pairs * D] (a context
+        gathered by :func:`prefix_keys`) -> the model's heads [Hkv, T,
+        D]."""
+        H, T, _ = rows.shape
+        return rows.reshape(H, T, self.pairs, -1).transpose(
+            0, 2, 1, 3).reshape(self.kv_heads, T, -1)
 
 
 class Rings(_LayerRows):

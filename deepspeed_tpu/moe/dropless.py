@@ -170,21 +170,26 @@ def route_topk(x, router_w, top_k: int, renormalize: bool = False):
 
 
 def route_sigmoid_topk(x, router_w, select_bias, top_k: int,
-                       scale: float = 1.0, renormalize: bool = True):
+                       scale: float = 1.0, renormalize: bool = True,
+                       eps: float = 0.0):
     """x [N, d], router_w [d, E], select_bias [E] -> (weights [N, k]
     float32, experts [N, k] int32).  Scores ``sigmoid(x @ router_w)`` in
     float32; the k experts with the largest ``score + select_bias`` are
     chosen (the bias steers the choice only); their weights are their
-    own scores (:func:`_picked`), renormalised to sum 1 if asked, times
-    ``scale``."""
+    own scores (:func:`_picked`), renormalised to sum 1 if asked (over
+    ``sum + eps`` where the source guards the division: HF
+    ``Lfm2MoeSparseMoeBlock``'s 1e-6), times ``scale``.  ``select_bias``
+    None: the scores alone choose."""
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits)
-    _, experts = jax.lax.top_k(scores + select_bias.astype(jnp.float32),
-                               top_k)
+    chosen_by = scores if select_bias is None \
+        else scores + select_bias.astype(jnp.float32)
+    _, experts = jax.lax.top_k(chosen_by, top_k)
     weights = _picked(scores, experts)
     if renormalize:
-        weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+        total = jnp.sum(weights, axis=-1, keepdims=True)
+        weights = weights / (total + eps if eps else total)
     return weights * scale, experts.astype(jnp.int32)
 
 

@@ -1002,6 +1002,11 @@ CELL_CACHES = {
         "k": (48, 833, 25, 16, 64), "v": (48, 833, 25, 16, 64)}, 4094361600),
     "kimi-linear-48b-a3b": (("k",), {"k": (2, 24577, 1, 64, 640)},
                             4026695680),
+    # 8 key heads of 64 rest as 4 paired heads of 128
+    # (``walked.PairedPagePool``): the published bytes
+    "lfm2-24b-a2b": (("k", "v"), {
+        "k": (2, 11265, 4, 64, 128), "v": (2, 11265, 4, 64, 128)},
+        2 * 2 * 11265 * 4 * 64 * 128 * 2),
     "mimo-v2.5": (("k", "v"), {
         "k": (2, 13825, 4, 64, 256), "v": (2, 13825, 4, 64, 128)},
         5436211200),
